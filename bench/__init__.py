@@ -1,4 +1,4 @@
-"""Relay-resilient benchmark harness (ISSUE 6).
+"""Per-section benchmark harness.
 
 ``bench.py`` at the repo root is the CLI entry point; this package is
 the implementation:
@@ -8,6 +8,6 @@ the implementation:
                   retry/degradation ladder, resume, merged output
 - ``heartbeat`` — child progress spool + parent watchdog
 - ``results``   — partial-result JSON, per-section status, merging
-- ``child``     — the per-section child / backend-probe entry points
+- ``child``     — the per-section child entry point
 - ``workload``  — shared signature/header fixtures
 """

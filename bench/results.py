@@ -1,6 +1,6 @@
 """Partial-result JSON: the on-disk evidence trail of a bench round.
 
-The contract that makes the harness relay-resilient: each section's
+The contract that makes one failed section cost only itself: each section's
 result is persisted (atomically: tmp + rename) the moment the section
 completes, so a later hang/SIGKILL/reboot cannot destroy earlier
 evidence. The final ``BENCH_rNN.json`` is a *merge* of the partial

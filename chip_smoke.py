@@ -1,0 +1,903 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — does the verify path still start on the chip?
+
+    python3 chip_smoke.py
+
+drives the system's main path once on a TPU, through the entry points a
+user calls, at the sizes ``BASELINE.json`` takes from upstream, and
+fails unless the DEVICE did the work. It is the quickest proof that the
+program still runs on the chip; it measures nothing (compile and wall
+seconds are printed as set-up information only).
+
+Two phases, each a process that owns the chip alone, one after the
+other. The parent never initialises a JAX backend: it builds commits,
+starts children and talks to the daemon over ``verifyd.client`` (whose
+shm transport imports the package's device-byte ledger, and with it
+jax, but touches no device).
+
+- **library** (a child, run twice): 150- and 10,000-validator commits
+  checked with ``types.validation.verify_commit`` -> ``crypto.batch``
+  -> ``ops.verify_batch``: the lanes once with no validator set known
+  (legacy kernel), a few heights over the same set (resident tables,
+  device hashing on fixed-width sign-bytes), then a commit with a
+  flipped ``R``, a flipped ``s`` byte and an ``s >= L`` whose failing
+  lanes must be attributed exactly; beside them one direct
+  ``ops.verify_batch`` call on ZIP-215 edge vectors. Verdicts are
+  compared with the host oracle (``crypto/ed25519_ref.py``). The second
+  run proves the compile cache: it may add no entry.
+- **served** (driven from the parent): ``python -m tendermint_tpu
+  verifyd`` started through the CLI, warmed one request at a time, then
+  four concurrent ``verifyd.client`` clients built with
+  ``fallback=False`` send commit requests in class consensus, one with a
+  tampered lane; then the daemon's stats, SIGTERM and a clean exit.
+
+What may hide the device is counted and checked: platform must be
+``tpu``; the health machine healthy with zero failures and zero host
+fallbacks; lanes dispatched == lanes sent; device hashing, the resident
+store and (on more than one chip) sharding must have served lanes where
+``auto`` turns them on; server ``host_direct_lanes`` /
+``admission_rejections`` / ``deadline_expired`` and client
+``fallback_calls`` all zero. Python warnings are errors in every
+process. sr25519 and the mixed committee: not run.
+
+Exit code 0 and, as the last line of stdout, one JSON object
+``{"ok": true, "device": {...}}`` only if every phase passed. Any
+failure — no accelerator, a phase raising, a check failing — exits
+non-zero, says why on stderr, and prints no result.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import re
+import shutil
+import signal
+import subprocess
+import sys
+import tempfile
+import threading
+import time
+import warnings
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+SEED = 21
+# BASELINE.json: config 2 (upstream's VerifyCommit size; pads to the 256
+# bucket, below the mesh floor) and the size BASELINE.md tracks
+# VerifyCommit p50 at (three 4096-lane chunks, ~10 MiB of resident tables).
+SIZES = (150, 10_000)
+HEIGHTS = 3  # heights verified over the same set after the cold pass
+SERVED_VALS = 150
+CLIENTS = 4
+REQUESTS_PER_CLIENT = 3
+# One request at a time before the concurrent ones. The first few
+# compile their shapes inside the call; the rest let the admission
+# controller's per-lane service-time average, which counted those
+# compiles, come back down (x0.8 per flush) so that it does not read
+# four clients' worth of queue as overload.
+WARMUP_REQUESTS = 32
+DEADLINE_S = 300.0  # compile is inside the call; protocol ceiling is 600 s
+BUDGET_S = 1150.0  # whole smoke, under the contract's 1200 s
+
+# Every warning is an error, in every process of the smoke, so that a
+# ``warnings.warn("... falling back ...")`` cannot pass. The one let
+# through is jax's own start-up note about the TPU VM's image.
+_HUGEPAGES = "Transparent hugepages are not enabled"
+WARNING_FLAGS = ["-W", "error", "-W", "ignore:%s:UserWarning" % _HUGEPAGES]
+
+# Vote timestamps whose nanoseconds all encode as a 5-byte varint
+# (2^28 <= nanos < 2^29 for every validator index), so a commit's
+# sign-bytes are fixed-width and device hashing applies to it.
+TIME_NS = 1_700_000_000_000_000_000 + 500_000_000
+
+
+class SmokeFailure(Exception):
+    """A check of the smoke did not hold."""
+
+
+def check(cond: bool, msg: str, *args) -> None:
+    if not cond:
+        raise SmokeFailure(msg % args if args else msg)
+
+
+def say(msg: str) -> None:
+    print("chip_smoke: " + msg, flush=True)
+
+
+def _auto_on(env_name: str, platform: str) -> bool:
+    """What an ``auto (on for tpu) | on | off`` switch of ops/ resolves
+    to — for the parent, which cannot ask ops/ without initialising a
+    backend."""
+    mode = os.environ.get(env_name, "auto").lower()
+    if mode in ("1", "on", "true", "yes", "all"):
+        return True
+    if mode in ("0", "off", "none", "false"):
+        return False
+    return platform == "tpu"
+
+
+# --- workload ----------------------------------------------------------------
+
+
+def build_set(n_vals: int, heights: int):
+    """Seeded ed25519 validator set + one signed commit per height,
+    fixed-width sign-bytes. Keys differ between sizes so that no size
+    finds the other's tables."""
+    from bench.workload import load_helpers
+    from tendermint_tpu.crypto.keys import Ed25519PrivKey
+
+    helpers = load_helpers()
+    offset = n_vals * 1000
+    privs, vset = helpers.make_validators(
+        n_vals,
+        key_factory=lambda i: Ed25519PrivKey.from_seed(
+            (offset + i).to_bytes(32, "big")
+        ),
+    )
+    commits = {
+        h: helpers.make_commit(
+            helpers.make_block_id(b"chip-smoke-%d-%d-%d" % (SEED, n_vals, h)),
+            h, 0, vset, privs, time_ns=TIME_NS,
+        )
+        for h in range(1, heights + 1)
+    }
+    return helpers, vset, commits
+
+
+def commit_lanes(helpers, vset, commit):
+    pks = [v.pub_key.bytes() for v in vset.validators]
+    msgs = [
+        commit.vote_sign_bytes(helpers.CHAIN_ID, i) for i in range(len(pks))
+    ]
+    sigs = [cs.signature for cs in commit.signatures]
+    check(
+        len({len(m) for m in msgs}) == 1,
+        "sign-bytes are not fixed-width: device hashing would not apply",
+    )
+    return pks, msgs, sigs
+
+
+def tamper(commit) -> dict:
+    """Break three lanes of ``commit`` in place: a flipped R bit, a
+    flipped s bit, and s + L (the curve equation still holds; only the
+    canonicity check refuses it). Returns {lane index: kind}."""
+    from tendermint_tpu.crypto.ed25519_ref import L
+
+    n = len(commit.signatures)
+    picks = {7 % n: "R", n // 2: "s", n - 3: "s>=L"}
+    check(len(picks) == 3, "commit too small to tamper three lanes")
+    for idx, kind in picks.items():
+        sig = bytearray(commit.signatures[idx].signature)
+        if kind == "R":
+            sig[3] ^= 0x01
+        elif kind == "s":
+            sig[32] ^= 0x01
+        else:
+            s = int.from_bytes(sig[32:], "little") + L
+            sig[32:] = s.to_bytes(32, "little")
+        commit.signatures[idx].signature = bytes(sig)
+    return picks
+
+
+def edge_vectors():
+    """The ZIP-215 edge cases tests/test_ed25519_ref.py and
+    tests/test_ops_ed25519.py hold (small-order and identity keys,
+    non-canonical y, s >= L, off-curve), as one well-formed-length batch
+    with ordinary lanes between them. Not among them: x = 0 with the
+    sign bit set, on which the oracle's two layers disagree with each
+    other (PERF.md, open questions)."""
+    from tendermint_tpu.crypto import ed25519_ref as ref
+
+    s = 12345
+    r_sb = ref.pt_compress(ref.pt_mul(s, ref.B_POINT))
+    sig_sb = r_sb + s.to_bytes(32, "little")
+    le = lambda v: v.to_bytes(32, "little")
+    ident, order4 = le(1), le(0)
+    lanes = [
+        (ident, b"x", sig_sb),  # identity key: R = [s]B verifies
+        (le(ref.P + 1), b"x", sig_sb),  # the same point, y >= p
+        (order4, b"x", sig_sb),  # small-order key (y = 0)
+        (le(ref.P), b"x", sig_sb),  # the same point, y >= p
+        (ident, b"x", r_sb + le(s + ref.L)),  # s >= L
+        (ident, b"y", order4 + le(0)),  # small-order R, s = 0
+        (ident, b"y", le(ref.P) + le(0)),  # the same R, y >= p
+        (le(2), b"x", sig_sb),  # not on the curve
+    ]
+    for i in range(12):
+        priv, pub = ref.keypair_from_seed(bytes([i + 1]) * 32)
+        msg = b"chip-smoke edge filler %d" % i
+        sig = ref.sign(priv, msg)
+        if i % 4 == 3:
+            msg = b"tampered"
+        lanes.append((pub, msg, sig))
+    return [list(col) for col in zip(*lanes)]
+
+
+# --- library phase ------------------------------------------------------------
+
+
+def _counters() -> dict:
+    from tendermint_tpu.ops import hash512, resident
+    from tendermint_tpu.ops.device_policy import shared as health
+    from tendermint_tpu.parallel import mesh
+
+    h = health.snapshot()
+    r = resident.stats()
+    return {
+        "fallback_batches": h["fallback_batches"],
+        "failures": sum(h["failures"].values()),
+        "hash_device_lanes": hash512.stats()["device_lanes"],
+        "resident_hits": r["hits"],
+        "resident_misses": r["misses"],
+        "resident_uploads": r["uploads"],
+        "gathered_h2d_bytes": r["gathered_h2d_bytes"],
+        "mesh_dispatches": mesh.manager.snapshot()["dispatches"],
+    }
+
+
+def _delta(before: dict) -> dict:
+    after = _counters()
+    return {k: after[k] - before[k] for k in after}
+
+
+def _check_health(d: dict, what: str) -> None:
+    check(
+        d["fallback_batches"] == 0 and d["failures"] == 0,
+        "%s: the health machine counted failures or host fallbacks: %r",
+        what, d,
+    )
+
+
+def _drain_spans() -> list:
+    from tendermint_tpu.libs import tracing
+
+    doc = tracing.tracer.export(clear=True)
+    check(
+        doc["otherData"]["dropped"] == 0,
+        "trace ring overflowed: span counts would be wrong",
+    )
+    return [e for e in doc["traceEvents"] if e.get("ph") == "X"]
+
+
+def _check_dispatch(spans: list, sent: dict, what: str) -> None:
+    """Lanes dispatched to and collected from the device, per job kind,
+    equal the lanes sent; nothing went to the host oracle."""
+    check(
+        not [e for e in spans if e["name"] == "host_fallback"],
+        "%s: lanes were answered by the host oracle", what,
+    )
+    for stage in ("dispatch_chunk", "collect_chunk"):
+        got = {}
+        for e in spans:
+            if e["name"] == stage:
+                kind = e["args"]["kind"]
+                got[kind] = got.get(kind, 0) + int(e["args"]["lanes"])
+        check(
+            got == sent,
+            "%s: %s lanes by kind %r != lanes sent %r", what, stage, got, sent,
+        )
+
+
+def _compiles(spans: list, impl: str) -> list:
+    """(implementation, kernel, lanes, seconds) for each kernel the
+    window compiled (or loaded from the cache). The legacy and table
+    kernels must be the implementation ``auto`` resolved to."""
+    out = []
+    for e in spans:
+        if e["name"] != "kernel_compile":
+            continue
+        a = e["args"]
+        ran = "pallas" if a.get("engine") == "pallas" else "xla"
+        if a.get("kernel") in ("verify", "verify_tables"):
+            check(
+                ran == ("pallas" if impl == "pallas" else "xla"),
+                "active_impl() is %r but the %s kernel at %s lanes ran %r",
+                impl, a.get("kernel"), a.get("lanes"), ran,
+            )
+        out.append(
+            [ran, a.get("kernel"), a.get("lanes"), round(e["dur"] / 1e6, 2)]
+        )
+    return out
+
+
+def _batch_verify(pub_key, pks, msgs, sigs) -> list:
+    """crypto.BatchVerifier as a caller without a validator set uses it."""
+    from tendermint_tpu.crypto import batch as crypto_batch
+    from tendermint_tpu.crypto.keys import Ed25519PubKey
+
+    bv = crypto_batch.create_batch_verifier(pub_key)
+    for pk, msg, sig in zip(pks, msgs, sigs):
+        bv.add(Ed25519PubKey(pk), msg, sig)
+    _, verdicts = bv.verify()
+    return verdicts
+
+
+def _check_oracle(pks, msgs, sigs, verdicts, rows, what: str) -> None:
+    from tendermint_tpu.crypto.ed25519_ref import verify_zip215
+
+    wrong = [
+        i for i in rows
+        if bool(verdicts[i]) != verify_zip215(pks[i], msgs[i], sigs[i])
+    ]
+    check(not wrong, "%s: device and oracle disagree on lanes %r", what, wrong[:16])
+
+
+def _run_size(n: int, heights: int, dev: dict, impl: str, paths: dict) -> dict:
+    import numpy as np
+
+    from tendermint_tpu.parallel import mesh
+    from tendermint_tpu.types.validation import InvalidCommitError, verify_commit
+
+    what = "%d validators" % n
+    t0 = time.monotonic()
+    helpers, vset, commits = build_set(n, heights + 1)
+    setup_s = time.monotonic() - t0
+    before = _counters()
+    _drain_spans()
+    proposer = vset.validators[0].pub_key
+    walls = {}
+
+    # Cold: the lanes through crypto.BatchVerifier before any validator
+    # set is known -> no tables -> the legacy kernel builds them on device.
+    t0 = time.monotonic()
+    pks, msgs, sigs = commit_lanes(helpers, vset, commits[1])
+    check(
+        all(_batch_verify(proposer, pks, msgs, sigs)),
+        what + ": cold pass refused a valid lane",
+    )
+    walls["cold_s"] = round(time.monotonic() - t0, 2)
+
+    # The same set, height after height, through verify_commit.
+    walls["heights_s"] = []
+    gathered_after_first = None
+    for h in range(1, heights + 1):
+        t0 = time.monotonic()
+        verify_commit(helpers.CHAIN_ID, vset, commits[h].block_id, h, commits[h])
+        walls["heights_s"].append(round(time.monotonic() - t0, 2))
+        if gathered_after_first is None:
+            gathered_after_first = _counters()["gathered_h2d_bytes"]
+
+    # Tampered commit: verify_commit names the first bad signature; the
+    # per-lane verdicts name all of them, and agree with the oracle.
+    t0 = time.monotonic()
+    bad_h = heights + 1
+    bad = commits[bad_h]
+    picks = tamper(bad)
+    try:
+        verify_commit(helpers.CHAIN_ID, vset, bad.block_id, bad_h, bad)
+    except InvalidCommitError as exc:
+        m = re.search(r"#(\d+)", str(exc))
+        check(
+            m is not None and int(m.group(1)) == min(picks),
+            "%s: verify_commit blamed %r, first tampered lane is %d",
+            what, str(exc)[:60], min(picks),
+        )
+    else:
+        raise SmokeFailure(what + ": verify_commit accepted a tampered commit")
+    pks, msgs, sigs = commit_lanes(helpers, vset, bad)
+    verdicts = _batch_verify(proposer, pks, msgs, sigs)
+    refused = [i for i, v in enumerate(verdicts) if not v]
+    check(
+        refused == sorted(picks),
+        "%s: refused lanes %r, tampered lanes %r",
+        what, refused[:16], sorted(picks),
+    )
+    rng = np.random.default_rng(SEED)
+    rows = sorted(set(picks) | set(rng.permutation(n)[:512].tolist()))
+    _check_oracle(pks, msgs, sigs, verdicts, rows, what)
+    walls["tampered_s"] = round(time.monotonic() - t0, 2)
+
+    # What served the lanes.
+    spans = _drain_spans()
+    d = _delta(before)
+    table_lanes = n * (heights + 2)
+    table_kind = "resident" if paths["resident"] else "tables"
+    _check_dispatch(spans, {"legacy": n, table_kind: table_lanes}, what)
+    _check_health(d, what)
+    if paths["device_hash"]:
+        check(
+            d["hash_device_lanes"] == n * (heights + 3),
+            "%s: device hashing served %d of %d lanes",
+            what, d["hash_device_lanes"], n * (heights + 3),
+        )
+    if paths["resident"]:
+        check(
+            d["resident_hits"] == table_lanes and d["resident_misses"] == 0,
+            "%s: resident store hit %d / missed %d of %d lanes",
+            what, d["resident_hits"], d["resident_misses"], table_lanes,
+        )
+        check(
+            d["resident_uploads"] == 1,
+            "%s: %d table uploads, want 1", what, d["resident_uploads"],
+        )
+        check(
+            _counters()["gathered_h2d_bytes"] == gathered_after_first,
+            what + ": gathered-table H2D grew after the first height",
+        )
+    # Sharding is the default wherever more than one device is visible
+    # and the batch reaches the mesh floor: every device gets an equal
+    # slab there, and below the floor the batch stays on one device.
+    shards = {}
+    for e in spans:
+        if e["name"] == "collect_device":
+            dev_id = e["args"]["device"]
+            shards[dev_id] = shards.get(dev_id, 0) + int(e["args"]["lanes"])
+    # (job kind, devices, padded lanes) of the sharded dispatches; the
+    # sharded kernels are the XLA graph only (parallel/sharding.py).
+    sharded = sorted(
+        {
+            (e["args"]["kind"], e["args"]["devices"], e["args"]["lanes"])
+            for e in spans
+            if e["name"] == "mesh_dispatch"
+        }
+    )
+    if dev["count"] >= 2 and n >= mesh.MIN_MESH_LANES:
+        check(
+            len(shards) == dev["count"] and len(set(shards.values())) == 1,
+            "%s: lanes per device %r over %d devices",
+            what, shards, dev["count"],
+        )
+        check(d["mesh_dispatches"] > 0, what + ": no sharded dispatch")
+    else:
+        check(
+            not shards and d["mesh_dispatches"] == 0,
+            "%s: sharded below the mesh floor: %r", what, shards,
+        )
+    return {
+        "validators": n,
+        "setup_s": round(setup_s, 2),
+        "walls": walls,
+        "counters": d,
+        "compiles": _compiles(spans, impl),
+        "lanes_per_device": shards,
+        "sharded_xla": sharded,
+    }
+
+
+def library_phase(expect_platform: str, sizes=SIZES, heights: int = HEIGHTS) -> dict:
+    """The library phase, in this process. Raises SmokeFailure."""
+    from tendermint_tpu.ops import backend as ops_backend
+
+    dev = ops_backend.device_identity()
+    say("device: platform=%(platform)s kind=%(kind)s count=%(count)d" % dev)
+    check(
+        dev["platform"] == expect_platform,
+        "JAX gave platform %r, want %r (JAX_PLATFORMS=%r)"
+        % (dev["platform"], expect_platform, os.environ.get("JAX_PLATFORMS")),
+    )
+
+    from tendermint_tpu.crypto import hashing
+    from tendermint_tpu.libs import tracing
+    from tendermint_tpu.ops import (
+        autotune, ed25519_batch, hash512, precompute, resident,
+    )
+    from tendermint_tpu.ops.device_policy import HEALTHY, shared as health
+
+    check(
+        not precompute.result_cache_enabled(),
+        "result cache is on: repeats would not reach the device",
+    )
+    check(
+        not os.environ.get("TENDERMINT_TPU_VERIFY_REMOTE"),
+        "a verifyd remote is configured: lanes would leave this process",
+    )
+    host_hash = hashing.host_hash_impl()
+    say("host hashing: %s" % host_hash)
+    check(
+        host_hash == "native",
+        "native/sha512_batch.c did not build: host hashing fell to hashlib",
+    )
+    impl = ed25519_batch.active_impl()
+    paths = {
+        "device_hash": hash512.device_hash_enabled(),
+        "resident": resident.enabled(),
+        "autotune": autotune.enabled(),
+    }
+    say("verify implementation: %s; auto paths: %r" % (impl, paths))
+    if expect_platform == "tpu":
+        check(all(paths.values()), "a path auto turns on for tpu is off: %r", paths)
+
+    prev_mode = tracing.tracer.mode
+    tracing.configure("ring")
+    try:
+        report = {
+            "device": dev, "impl": impl, "paths": paths, "host_hash": host_hash,
+        }
+
+        # ZIP-215 edge vectors straight into ops.verify_batch.
+        _drain_spans()
+        before = _counters()
+        pks, msgs, sigs = edge_vectors()
+        verdicts = ed25519_batch.verify_batch(pks, msgs, sigs)
+        _check_oracle(pks, msgs, sigs, verdicts, range(len(pks)), "edge vectors")
+        spans = _drain_spans()
+        _check_dispatch(spans, {"legacy": len(pks)}, "edge vectors")
+        _check_health(_delta(before), "edge vectors")
+        report["edge"] = {
+            "lanes": len(pks),
+            "accepted": int(sum(map(bool, verdicts))),
+            "compiles": _compiles(spans, impl),
+        }
+        say(
+            "edge vectors: %(lanes)d lanes, %(accepted)d accepted, as the "
+            "oracle; compiled %(compiles)r" % report["edge"]
+        )
+
+        report["sizes"] = []
+        for n in sizes:
+            rep = _run_size(n, heights, dev, impl, paths)
+            report["sizes"].append(rep)
+            say("%(validators)d validators: set-up %(setup_s)ss, walls %(walls)r" % rep)
+            say("  counters %(counters)r" % rep)
+            say("  compiled %(compiles)r" % rep)
+            say(
+                "  lanes/device %(lanes_per_device)r sharded (xla) "
+                "%(sharded_xla)r" % rep
+            )
+
+        snap = health.snapshot()
+        check(snap["state"] == HEALTHY, "device health ended %r", snap["state"])
+        tuned = autotune.stats()
+        report["autotune"] = {k: tuned[k] for k in ("selections", "timings_ms")}
+        say(
+            "autotune selections: %(selections)r timings_ms %(timings_ms)r"
+            % report["autotune"]
+        )
+        report["sr25519"] = report["mixed_committee"] = "not run"
+        return report
+    finally:
+        tracing.configure(prev_mode)
+
+
+# --- served phase (parent side) -----------------------------------------------
+
+
+def _start_daemon(workdir: str):
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [HERE] + [p for p in env.get("PYTHONPATH", "").split(os.pathsep) if p]
+    )
+    env["TENDERMINT_TPU_RESULT_CACHE"] = "0"
+    env["TENDERMINT_TPU_FLIGHTREC_DIR"] = os.path.join(workdir, "flightrec")
+    out = open(os.path.join(workdir, "verifyd.out"), "w+")
+    err = open(os.path.join(workdir, "verifyd.err"), "w+")
+    proc = subprocess.Popen(
+        [sys.executable, *WARNING_FLAGS, "-m", "tendermint_tpu", "verifyd",
+         "--listen", "127.0.0.1:0"],
+        stdout=out, stderr=err, env=env, cwd=workdir,
+    )
+    return proc, out, err
+
+
+def _read(f) -> str:
+    f.flush()
+    f.seek(0)
+    return f.read()
+
+
+def _await_banner(proc, out, err, timeout: float) -> str:
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        m = re.search(r"verifyd serving on (\S+:\d+) \((.*)\)", _read(out))
+        if m:
+            say("daemon: " + m.group(0))
+            return m.group(1)
+        check(
+            proc.poll() is None,
+            "verifyd exited %r at start-up: %s", proc.returncode, _read(err)[-2000:],
+        )
+        time.sleep(0.2)
+    raise SmokeFailure("verifyd printed no banner in %.0fs" % timeout)
+
+
+def _check_served(snap: dict, pool: list, expect_platform: str, requests: int):
+    """The daemon's stats and the clients' counters: the device served
+    the requests, and nothing on either side fell back."""
+    stats, health = snap["stats"], snap["device_health"]
+    dev = stats["device"]
+    check(
+        dev is not None and dev["platform"] == expect_platform,
+        "daemon runs on %r, want platform %r", dev, expect_platform,
+    )
+    for key in ("host_direct_lanes", "admission_rejections", "deadline_expired"):
+        check(stats[key] == 0, "daemon %s = %r", key, stats[key])
+    check(
+        health["state"] == "healthy"
+        and health["fallback_batches"] == 0
+        and sum(health["failures"].values()) == 0,
+        "daemon device health: %r", health,
+    )
+    check(
+        stats["requests_served"] == requests,
+        "daemon served %r of %d requests", stats["requests_served"], requests,
+    )
+    # Not "== lanes sent": the scheduler may cut a flush under 16 lanes,
+    # which crypto.batch verifies on the host by design.
+    if _auto_on("TENDERMINT_TPU_DEVICE_HASH", dev["platform"]):
+        check(
+            snap["hash512"]["device_lanes"] > 0,
+            "daemon hashed no lanes on the device: %r", snap["hash512"],
+        )
+    if _auto_on("TENDERMINT_TPU_RESIDENT", dev["platform"]):
+        r = snap["resident"]
+        check(
+            r["hits"] > 0 and r["misses"] == 0 and r["gathered_h2d_bytes"] == 0,
+            "daemon resident store served no lanes, or tables were shipped "
+            "per batch: %r", r,
+        )
+    for c in pool:
+        check(
+            c.fallback_calls == 0 and not c.rejected,
+            "a client fell back or was rejected: %r %r", c.stats(), c.rejected,
+        )
+
+
+def served_phase(
+    expect_platform: str,
+    n_vals: int = SERVED_VALS,
+    clients: int = CLIENTS,
+    per_client: int = REQUESTS_PER_CLIENT,
+    warmups: int = WARMUP_REQUESTS,
+) -> dict:
+    """Start the CLI daemon, drive it with verifyd.client, read its
+    stats, stop it. Runs in the caller's process, which needs no
+    device: the daemon is the one process that holds it."""
+    from tendermint_tpu.crypto.ed25519_ref import verify_zip215
+    from tendermint_tpu.verifyd.client import VerifydClient
+    from tendermint_tpu.verifyd.protocol import CLASS_CONSENSUS
+
+    helpers, vset, commits = build_set(n_vals, 1 + clients * per_client)
+    picks = tamper(commits[2])  # client 0's first request
+    lanes = {h: commit_lanes(helpers, vset, c) for h, c in commits.items()}
+    want = {
+        h: [verify_zip215(*lane) for lane in zip(*lanes[h])] for h in lanes
+    }
+    check(
+        [i for i, v in enumerate(want[2]) if not v] == sorted(picks),
+        "oracle does not refuse exactly the tampered lanes",
+    )
+
+    workdir = tempfile.mkdtemp(prefix="chip_smoke_verifyd_")
+    proc, out, err = _start_daemon(workdir)
+    pool = []
+    try:
+        addr = _await_banner(proc, out, err, timeout=180.0)
+
+        def new_client():
+            c = VerifydClient(addr, fallback=False, timeout=DEADLINE_S)
+            pool.append(c)
+            return c
+
+        def call(client, h):
+            got = client.verify(
+                *lanes[h], klass=CLASS_CONSENSUS, deadline=DEADLINE_S
+            )
+            check(got == want[h], "height %d: served verdicts differ from the oracle", h)
+
+        t0 = time.monotonic()
+        warm = new_client()
+        for _ in range(warmups):
+            call(warm, 1)
+        warm_s = time.monotonic() - t0
+
+        errors = []
+
+        def worker(k):
+            try:
+                client = new_client()
+                for j in range(per_client):
+                    call(client, 2 + k * per_client + j)
+            except Exception as exc:  # re-raised in the caller's thread
+                errors.append(exc)
+
+        t0 = time.monotonic()
+        threads = [
+            threading.Thread(target=worker, args=(k,)) for k in range(clients)
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=DEADLINE_S * per_client + 30)
+            check(not t.is_alive(), "a client thread did not finish")
+        if errors:
+            raise errors[0]
+        burst_s = time.monotonic() - t0
+
+        snap = warm.server_stats(timeout=30.0)
+        requests = warmups + clients * per_client
+        _check_served(snap, pool, expect_platform, requests)
+
+        proc.send_signal(signal.SIGTERM)
+        try:
+            rc = proc.wait(timeout=60)
+        except subprocess.TimeoutExpired:
+            raise SmokeFailure("verifyd did not exit within 60s of SIGTERM")
+        check(rc == 0, "verifyd exited %r: %s", rc, _read(err)[-2000:])
+        stderr = "\n".join(
+            line for line in _read(err).splitlines()
+            if _HUGEPAGES not in line and "warnings.warn(" not in line
+        )
+        check(
+            "Traceback" not in stderr and "Warning" not in stderr,
+            "verifyd stderr: %s", stderr[-2000:],
+        )
+        stats, health = snap["stats"], snap["device_health"]
+        stat_keys = (
+            "requests_served", "host_direct_lanes", "admission_rejections",
+            "deadline_expired", "cross_client_flushes", "compile_events",
+            "scheduler",
+        )
+        client_keys = ("transport", "calls", "fallback_calls", "shm_fallbacks")
+        report = {
+            "device": stats["device"],
+            "requests": requests,
+            "lanes": requests * n_vals,
+            "warmup_s": round(warm_s, 2),
+            "burst_s": round(burst_s, 2),
+            "stats": {k: stats[k] for k in stat_keys},
+            "device_health": {
+                k: health[k] for k in ("state", "fallback_batches", "failures")
+            },
+            "hash512": snap["hash512"],
+            "resident": snap["resident"],
+            "brownout": snap["brownout"],
+            "clients": [
+                {k: cs[k] for k in client_keys} for cs in (c.stats() for c in pool)
+            ],
+        }
+        say(
+            "served: %d requests / %d lanes as the oracle, tampered lanes %r "
+            "attributed" % (requests, report["lanes"], sorted(picks))
+        )
+        say(
+            "  warm-up %(warmup_s)ss, concurrent clients %(burst_s)ss; "
+            "stats %(stats)r" % report
+        )
+        say(
+            "  health %(device_health)r hash %(hash512)r resident %(resident)r"
+            % report
+        )
+        say("  brownout %(brownout)r clients %(clients)r" % report)
+        return report
+    finally:
+        for c in pool:
+            c.close()
+        if proc.poll() is None:
+            proc.kill()
+            proc.wait()
+        out.close()
+        err.close()
+        shutil.rmtree(workdir, ignore_errors=True)
+
+
+# --- entry points -------------------------------------------------------------
+
+
+def _child_library(report_path: str) -> int:
+    """``--phase library``: the library phase in a process of its own."""
+    os.environ["TENDERMINT_TPU_RESULT_CACHE"] = "0"
+    report = library_phase("tpu")
+    with open(report_path, "w") as f:
+        json.dump(report, f, indent=1, sort_keys=True)
+    return 0
+
+
+def _cache_dir() -> str:
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        HERE, ".jax_cache"
+    )
+
+
+def _cache_entries() -> int:
+    total = 0
+    for _root, _dirs, files in os.walk(_cache_dir()):
+        total += sum(1 for f in files if not f.endswith("-atime"))
+    return total
+
+
+def _run_library_child(run: int, workdir: str, deadline: float) -> dict:
+    report_path = os.path.join(workdir, "library_run%d.json" % run)
+    env = dict(os.environ)
+    # jax keeps only compiles over a second by default; the smoke keeps
+    # all of them, so that "the second run adds no entry" is exact.
+    env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+    t0 = time.monotonic()
+    try:
+        rc = subprocess.run(
+            [sys.executable, *WARNING_FLAGS, os.path.abspath(__file__),
+             "--phase", "library", "--report", report_path],
+            env=env, cwd=HERE, timeout=max(1.0, deadline - time.monotonic()),
+        ).returncode
+    except subprocess.TimeoutExpired:
+        raise SmokeFailure(
+            "library run %d ran out of the smoke's time budget" % run
+        )
+    check(rc == 0, "library run %d exited %d", run, rc)
+    with open(report_path) as f:
+        report = json.load(f)
+    report["wall_s"] = round(time.monotonic() - t0, 1)
+    parts = [report["edge"]] + report["sizes"]
+    report["compile_s"] = round(
+        sum(c[3] for part in parts for c in part["compiles"]), 1
+    )
+    return report
+
+
+def _keep(reports: dict) -> None:
+    """Leave the reports where the chip tool brings files back from."""
+    out = os.path.join(HERE, "chiprun_out", "chip_smoke")
+    os.makedirs(out, exist_ok=True)
+    with open(os.path.join(out, "report.json"), "w") as f:
+        json.dump(reports, f, indent=1, sort_keys=True)
+
+
+def run_smoke() -> dict:
+    deadline = time.monotonic() + BUDGET_S
+    check(
+        os.path.isdir(os.path.join(HERE, "tendermint_tpu"))
+        and os.path.isfile(os.path.join(HERE, "tests", "helpers.py")),
+        "the repository is not beside chip_smoke.py (%s)" % HERE,
+    )
+    sys.path.insert(0, HERE)
+    workdir = tempfile.mkdtemp(prefix="chip_smoke_")
+    try:
+        say("compile cache: %s (%d entries)" % (_cache_dir(), _cache_entries()))
+        run1 = _run_library_child(1, workdir, deadline)
+        entries1 = _cache_entries()
+        run2 = _run_library_child(2, workdir, deadline)
+        entries2 = _cache_entries()
+        for run, rep, entries in ((1, run1, entries1), (2, run2, entries2)):
+            say(
+                "library run %d: %.1fs wall, %.1fs in first calls of kernels, "
+                "cache %d entries"
+                % (run, rep["wall_s"], rep["compile_s"], entries)
+            )
+        check(
+            entries1 > 0,
+            "run 1 left no entry in the compile cache at %s", _cache_dir(),
+        )
+        check(
+            entries2 == entries1,
+            "run 2 added %d entries to a cache run 1 had filled",
+            entries2 - entries1,
+        )
+        check(
+            time.monotonic() < deadline,
+            "out of the smoke's time budget before the served phase",
+        )
+        served = served_phase("tpu")
+        check(
+            served["device"] == run1["device"],
+            "daemon device %r != library device %r",
+            served["device"], run1["device"],
+        )
+        _keep({"library_run1": run1, "library_run2": run2, "served": served,
+               "cache_entries": [entries1, entries2]})
+        return run1["device"]
+    finally:
+        shutil.rmtree(workdir, ignore_errors=True)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--phase", choices=("library",), help=argparse.SUPPRESS)
+    ap.add_argument("--report", help=argparse.SUPPRESS)
+    args = ap.parse_args(argv)
+    warnings.simplefilter("error")
+    warnings.filterwarnings("ignore", message=_HUGEPAGES, category=UserWarning)
+    try:
+        if args.phase == "library":
+            sys.path.insert(0, HERE)
+            return _child_library(args.report)
+        device = run_smoke()
+    except SmokeFailure as exc:
+        print("chip_smoke: FAILED: %s" % exc, file=sys.stderr, flush=True)
+        return 1
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -2,7 +2,7 @@
 
 Diffs two bench result JSONs and renders a per-section verdict table::
 
-    python -m scripts.bench_diff BENCH_r01.json BENCH_r05.json
+    python -m scripts.bench_diff old.json new.json
     python -m scripts.bench_diff --tolerance 10 old.json new.json
 
 Accepted input shapes (auto-detected, mixable — a partial can be
@@ -12,7 +12,7 @@ diffed against a full merged round):
 - ``tendermint-tpu-bench-partial/1`` (the resumable evidence file;
   only sections with status ``ok`` contribute metrics)
 - the legacy driver wrapper ``{n, cmd, rc, tail, parsed}`` whose
-  ``parsed`` payload is a merged-style doc (BENCH_r01..r05 on disk)
+  ``parsed`` payload is a merged-style doc
 
 Each numeric leaf becomes a dotted metric path grouped into a section
 (top-level scalars -> ``headline``; nested objects -> their key).
@@ -242,7 +242,7 @@ def summarize(rows: List[dict]) -> dict:
 def verdict_line(
     base_path: str, cand_path: str, rows: List[dict], tolerance_pct: float
 ) -> str:
-    """The one-line verdict appended to scripts/TPU_PROBE_LOG.md."""
+    """The one-line verdict bench.py --baseline prints to stderr."""
     s = summarize(rows)
     word = "REGRESSION" if s["regressions"] else "ok"
     return (

@@ -100,7 +100,7 @@ print("bench multichip smoke ok: %s" % mc["sigs_per_s"])
 EOF
 
 echo "== bench smoke (section runner vs a hanging section) =="
-# The relay-resilience contract (ISSUE 6): one deliberately-hanging
+# The per-section isolation contract: one deliberately-hanging
 # section must NOT zero the round. Tiny no-jax sections keep this
 # stage fast; the injected hang must die by heartbeat watchdog (well
 # under the 60s wall budget), the run must not end in a whole-run
@@ -111,7 +111,6 @@ timeout -k 10 120 env JAX_PLATFORMS=cpu \
     BENCH_HEARTBEAT_TIMEOUT=5 BENCH_SECTION_TIMEOUT=60 \
     BENCH_SECTION_ATTEMPTS=1 BENCH_HOST_REF_SIGS=4 \
     BENCH_PARTIAL=/tmp/_bench_smoke/partial.json \
-    BENCH_PROBE_LOG=/tmp/_bench_smoke/probe.md \
     TENDERMINT_TPU_FLIGHTREC_DIR=/tmp/_bench_smoke/flightrec \
     python bench.py > /tmp/_bench_smoke/out.json 2>/tmp/_bench_smoke/err.log
 bench_rc=$?
@@ -491,9 +490,9 @@ echo "== introspection: sanitized suites + sentinel + profiler overhead =="
 # run under happens-before race detection — the ledger is written from
 # resident-store refresh, shm register/unregister, and compile paths
 # concurrently, so a missing lock is a real race. (b) The bench_diff
-# sentinel's documented acceptance pair: r01 -> r05 shows the relay
-# throughput collapse and MUST exit 4 (regression); the identity diff
-# MUST exit 0. (c) Profiler overhead: the host_ref throughput section
+# sentinel's acceptance pair (synthetic fixtures under tests/fixtures):
+# base -> regressed shows a headline collapse and MUST exit 4
+# (regression); the identity diff MUST exit 0. (c) Profiler overhead: the host_ref throughput section
 # with the profiler on must land within 5% of a profiler-off run, and
 # the merged JSON must carry the profile fragment.
 rm -f /tmp/_tpusan_introspect.log
@@ -506,13 +505,15 @@ if grep -q "DATA RACE" /tmp/_tpusan_introspect.log; then
     echo "introspect: data race detected (stacks above)" >&2
     rc_total=1
 fi
-python -m scripts.bench_diff BENCH_r01.json BENCH_r05.json \
+python -m scripts.bench_diff tests/fixtures/bench_diff_base.json \
+    tests/fixtures/bench_diff_regressed.json \
     > /tmp/_bench_diff_accept.log 2>&1
 if [ "$?" -ne 4 ]; then
-    echo "bench_diff acceptance: r01 -> r05 must exit 4 (regression)" >&2
+    echo "bench_diff acceptance: base -> regressed must exit 4 (regression)" >&2
     rc_total=1
 fi
-python -m scripts.bench_diff BENCH_r05.json BENCH_r05.json >/dev/null \
+python -m scripts.bench_diff tests/fixtures/bench_diff_regressed.json \
+    tests/fixtures/bench_diff_regressed.json >/dev/null \
     || { echo "bench_diff acceptance: identity diff must exit 0" >&2; \
          rc_total=1; }
 rm -rf /tmp/_bench_prof && mkdir -p /tmp/_bench_prof

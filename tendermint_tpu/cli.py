@@ -202,7 +202,6 @@ def cmd_start(args) -> int:
     try:
         node = _build_node(cfg)
         node.start()
-        verify_banner = ""
         if cfg.ops.verify_remote:
             from tendermint_tpu.verifyd.client import remote_transport
 
@@ -210,6 +209,25 @@ def cmd_start(args) -> int:
             verify_banner = (
                 f", verify {cfg.ops.verify_remote} via {transport}"
             )
+        else:
+            # This node verifies in-process, so it says which device it
+            # got. A chip belongs to one process: where several nodes
+            # share a machine with one, a verifyd owns it and the nodes
+            # set [ops] verify_remote. A backend that cannot come up is
+            # not fatal — the health machine keeps the node live on the
+            # host oracle — but it is said here, once, at start-up.
+            from tendermint_tpu.ops import backend as ops_backend
+
+            try:
+                dev = ops_backend.device_identity()
+                verify_banner = (
+                    f", verify local on {dev['platform']} "
+                    f"{dev['kind']} x{dev['count']}"
+                )
+            except RuntimeError as exc:
+                verify_banner = (
+                    f", verify local: NO DEVICE ({exc}); host oracle"
+                )
         print(
             f"node {node.node_key.node_id} started "
             f"(p2p {cfg.p2p.laddr}, rpc {cfg.rpc.laddr}{verify_banner})",
@@ -683,10 +701,13 @@ def cmd_verifyd(args) -> int:
     shm_banner = server.shm_socket_path or "off"
     # the RESOLVED scheduler knobs (post mesh sizing, post controller):
     # what A/B runs should record as the config actually under test
-    knobs = server.stats().get("scheduler") or {}
+    stats = server.stats()
+    knobs = stats.get("scheduler") or {}
+    dev = stats["device"]
     print(
         f"verifyd serving on {shost}:{sport} "
-        f"(max_batch={knobs.get('max_batch', server.max_batch)}, "
+        f"(device={dev['platform']} {dev['kind']} x{dev['count']}, "
+        f"max_batch={knobs.get('max_batch', server.max_batch)}, "
         f"max_delay={knobs.get('max_delay', args.max_delay)}s, "
         f"admission_cap={args.admission_cap}, "
         f"continuous={server.scheduler.continuous}, "

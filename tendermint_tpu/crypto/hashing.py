@@ -85,6 +85,14 @@ def _lib() -> Optional[ctypes.CDLL]:
     return _LIB
 
 
+def host_hash_impl() -> str:
+    """Which host hashing path is live: ``"native"`` (the C extension
+    built and loaded) or ``"hashlib"`` (no source, no compiler, or a
+    failed build). The choice is otherwise silent; chip_smoke.py prints
+    it and treats ``hashlib`` as a failed build."""
+    return "native" if _lib() is not None else "hashlib"
+
+
 def sha512_batch(msgs: Sequence[bytes]) -> np.ndarray:
     """N messages -> (N, 64) uint8 digests."""
     n = len(msgs)

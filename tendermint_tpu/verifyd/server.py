@@ -466,6 +466,13 @@ class VerifydServer:
         self.dyn_batch = (
             dyn_batch_default() if dyn_batch is None else bool(dyn_batch)
         )
+        # What this process got, for anyone looking from outside
+        # (stats(), the CLI banner): filled by start() when the server
+        # verifies on the real engine. An injected verify_fn (tests,
+        # modeled bench shards) never touches a backend — and must not:
+        # a chip belongs to one process — so it reports None.
+        self._owns_engine = verify_fn is None
+        self._device: Optional[Dict[str, object]] = None
         self._verify_fns = {
             ALGO_ED25519: (
                 verify_fn or crypto_batch.tiered_verify_ed25519,
@@ -556,6 +563,13 @@ class VerifydServer:
         return self._scheduler_for(ALGO_ED25519)
 
     def start(self) -> None:
+        if self._owns_engine:
+            # Take the device now, not at the first device-worthy batch:
+            # a daemon that cannot get its backend fails to start, and
+            # one that came up on the CPU says so in stats().
+            from tendermint_tpu.ops import backend as ops_backend
+
+            self._device = ops_backend.device_identity()
         self._scheduler_for(ALGO_ED25519)  # eager: first request is hot
         self._grpc.start()
         with self._shm_mtx:
@@ -670,6 +684,9 @@ class VerifydServer:
         with self._stats_mtx:
             return {
                 "shard_id": self.shard_id,
+                # platform / device kind / device count as jax reported
+                # them at start(); None when no real engine is attached
+                "device": self._device,
                 "misroutes": self.misroutes,
                 "route_epoch_seen": self.route_epoch_seen,
                 "requests_served": self.requests_served,
@@ -984,17 +1001,24 @@ class VerifydServer:
     def _handle_stats(self, payload: bytes) -> bytes:
         """STATS_PATH unary: one JSON snapshot of everything a
         federation client (or ``verifyd stats``) needs to gossip — wire
-        counters, per-tenant SLO view, brownout level, and this shard's
-        pinned resident-table slice. The request payload is ignored
-        (reserved), so any client version can poll any server version."""
+        counters (with the device this daemon got), per-tenant SLO view,
+        brownout level, the device health machine's failure/fallback
+        counters, and this shard's pinned resident-table slice. The
+        request payload is ignored (reserved), so any client version
+        can poll any server version."""
         del payload
-        from tendermint_tpu.ops import resident
+        from tendermint_tpu.ops import device_policy, hash512, resident
 
         snap = {
             "shard_id": self.shard_id,
             "stats": self.stats(),
             "tenants": self.tenant_stats(),
             "brownout": self.brownout.snapshot(),
+            # lanes the engine answered from the host oracle after a
+            # device failure never show in the wire counters above;
+            # the health machine's own counters do
+            "device_health": device_policy.shared.snapshot(),
+            "hash512": hash512.stats(),
             "resident": resident.stats(),
             "pinned_keys": resident.pinned_keys(),
         }

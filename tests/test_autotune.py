@@ -122,12 +122,17 @@ def test_corrupt_cache_file_re_times(monkeypatch, tmp_path):
     assert autotune.mul_impl_for(None, 64) == "vpu"
 
 
-def test_measure_failure_falls_back(monkeypatch):
+def test_measure_failure_raises(monkeypatch):
+    """A timing kernel that cannot compile or run is a device failure:
+    it reaches the caller (the dispatching engine's health machine), it
+    is not turned into the default multiplier."""
+
     def explode(backend, lanes):
         raise RuntimeError("backend cannot time")
 
     monkeypatch.setattr(autotune, "_measure", explode)
-    assert autotune.mul_impl_for(None, 64) == field32.get_mul_impl()
+    with pytest.raises(RuntimeError, match="cannot time"):
+        autotune.mul_impl_for(None, 64)
     assert autotune.stats()["selections"] == {}
 
 

@@ -2,8 +2,9 @@
 
 Pins the documented contract: regressions detected, noise tolerated,
 partial-vs-full handled without false alarms, and the 0/2/4 exit-code
-scheme — including an acceptance run against the checked-in
-BENCH_r01.json / BENCH_r05.json fixtures.
+scheme — including an acceptance run against small synthetic fixtures
+(tests/fixtures/bench_diff_*.json) in the document shapes the sentinel
+accepts: two legacy driver wrappers and one partial.
 """
 
 import json
@@ -13,10 +14,12 @@ import pytest
 
 from scripts import bench_diff
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-R01 = os.path.join(REPO, "BENCH_r01.json")
-R05 = os.path.join(REPO, "BENCH_r05.json")
-PARTIAL = os.path.join(REPO, "BENCH_partial.json")
+FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures")
+# wrapper with a headline only / wrapper with nested sections and a
+# tenfold lower headline / partial with one ok and one crashed section
+BASE = os.path.join(FIXTURES, "bench_diff_base.json")
+REGRESSED = os.path.join(FIXTURES, "bench_diff_regressed.json")
+PARTIAL = os.path.join(FIXTURES, "bench_diff_partial.json")
 
 
 def _merged(**metrics):
@@ -135,9 +138,9 @@ class TestMissing:
 
 class TestNormalize:
     def test_legacy_wrapper_unwraps_parsed(self):
-        with open(R01) as f:
-            sections = bench_diff.normalize(json.load(f), "r01")
-        assert sections["headline"]["value"] == pytest.approx(20821.7)
+        with open(BASE) as f:
+            sections = bench_diff.normalize(json.load(f), "base")
+        assert sections["headline"]["value"] == pytest.approx(1000.0)
         # wrapper bookkeeping (n, rc, cmd, tail) must not leak in
         assert "n" not in sections.get("headline", {})
         assert "rc" not in sections.get("headline", {})
@@ -170,17 +173,17 @@ class TestNormalize:
 
 
 class TestCLI:
-    def test_acceptance_r01_vs_r05_regresses(self, capsys):
-        """ISSUE 18 acceptance: the checked-in r01 -> r05 pair shows the
-        throughput collapse and exits 4 with a verdict table."""
-        rc = bench_diff.main([R01, R05])
+    def test_acceptance_pair_regresses(self, capsys):
+        """ISSUE 18 acceptance: a pair whose headline collapses exits 4
+        with a verdict table."""
+        rc = bench_diff.main([BASE, REGRESSED])
         out = capsys.readouterr().out
         assert rc == bench_diff.EXIT_REGRESSION == 4
         assert "REGRESSION" in out
         assert "verdict" in out  # table header rendered
 
     def test_identity_diff_is_clean(self, capsys):
-        rc = bench_diff.main([R05, R05])
+        rc = bench_diff.main([REGRESSED, REGRESSED])
         out = capsys.readouterr().out
         assert rc == bench_diff.EXIT_OK == 0
         assert "0 regressed" in out
@@ -188,14 +191,14 @@ class TestCLI:
     def test_partial_vs_full_never_false_alarms(self):
         # disjoint section sets: everything is missing/new, nothing
         # regressed, exit stays 0
-        assert bench_diff.main([PARTIAL, R05]) == bench_diff.EXIT_OK
+        assert bench_diff.main([PARTIAL, REGRESSED]) == bench_diff.EXIT_OK
 
     def test_unreadable_input_is_usage_error(self, tmp_path, capsys):
         bad = tmp_path / "bad.json"
         bad.write_text("{not json")
         missing = tmp_path / "nope.json"
-        assert bench_diff.main([str(bad), R05]) == bench_diff.EXIT_USAGE == 2
-        assert bench_diff.main([str(missing), R05]) == bench_diff.EXIT_USAGE
+        assert bench_diff.main([str(bad), REGRESSED]) == bench_diff.EXIT_USAGE == 2
+        assert bench_diff.main([str(missing), REGRESSED]) == bench_diff.EXIT_USAGE
         assert "bench_diff:" in capsys.readouterr().err
 
     def test_tolerance_env_sets_default(self, monkeypatch):
@@ -207,7 +210,7 @@ class TestCLI:
         )
 
     def test_json_output_mode(self, capsys):
-        rc = bench_diff.main(["--json", R05, R05])
+        rc = bench_diff.main(["--json", REGRESSED, REGRESSED])
         assert rc == 0
         doc = json.loads(capsys.readouterr().out)
         assert doc["summary"]["regressions"] == 0

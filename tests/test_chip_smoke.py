@@ -1,0 +1,205 @@
+"""chip_smoke.py on the CPU: the checks that make a hidden device fail.
+
+The smoke itself only passes on a TPU. Here its phase functions run
+with the expected platform passed as ``"cpu"`` so that the counter
+checks execute, and the properties the bring-up PR established are
+pinned: no CPU mode in ``main()``, a failing kernel fails the phase
+instead of passing on the oracle, a failing implementation is counted
+by the health machine rather than switched, and the compile cache can
+be placed from outside.
+
+The two tests that compile the table/resident kernels and start the
+daemon are marked ``slow``: tier-1 already runs into its time limit on
+a cold compile cache, and every second spent here would push a test
+off its end.
+"""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+from tendermint_tpu.ops import device_policy, ed25519_batch  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def _clean_engine_state(monkeypatch):
+    """Result cache off (repeats must reach the device) and a pristine
+    health machine before and after."""
+    monkeypatch.setenv("TENDERMINT_TPU_RESULT_CACHE", "0")
+    monkeypatch.delenv("TENDERMINT_TPU_VERIFY_REMOTE", raising=False)
+    device_policy.shared.reset()
+    yield
+    device_policy.shared.reset()
+
+
+def _run_main(cwd, script):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    return subprocess.run(
+        [sys.executable, script], cwd=cwd, env=env,
+        capture_output=True, text=True, timeout=120,
+    )
+
+
+# --- main(): no CPU mode, no result on failure --------------------------------
+
+
+def test_main_exits_nonzero_off_tpu_and_names_jax_platforms():
+    proc = _run_main(REPO, os.path.join(REPO, "chip_smoke.py"))
+    assert proc.returncode != 0
+    assert "want 'tpu'" in proc.stderr and "JAX_PLATFORMS='cpu'" in proc.stderr
+    assert '"ok"' not in proc.stdout
+
+
+def test_main_exits_nonzero_alone_in_a_directory(tmp_path):
+    script = shutil.copy(os.path.join(REPO, "chip_smoke.py"), str(tmp_path))
+    proc = _run_main(str(tmp_path), script)
+    assert proc.returncode != 0
+    assert "repository is not beside chip_smoke.py" in proc.stderr
+    assert '"ok"' not in proc.stdout
+
+
+def test_phase_fails_on_the_wrong_platform():
+    with pytest.raises(chip_smoke.SmokeFailure, match="want 'tpu'"):
+        chip_smoke.library_phase("tpu", sizes=())
+
+
+# --- the library phase's checks, live, on the CPU -----------------------------
+
+
+def test_edge_vectors_phase_passes_on_cpu():
+    """The phase's first step — ZIP-215 edge vectors through
+    ops.verify_batch, lane for lane against the oracle, lanes
+    dispatched == lanes sent, health counters flat — on the 64-lane
+    legacy kernel other suites compile anyway."""
+    report = chip_smoke.library_phase("cpu", sizes=())
+    assert report["device"]["platform"] == "cpu"
+    assert report["impl"] == "xla" and report["host_hash"] == "native"
+    edge = report["edge"]
+    assert 0 < edge["accepted"] < edge["lanes"]
+    assert report["sr25519"] == "not run"
+
+
+def test_kernel_failure_fails_the_phase_instead_of_passing_on_the_oracle(
+    monkeypatch,
+):
+    """With a dead kernel every verdict still comes out right — from
+    the host oracle. That is exactly what the smoke must not accept."""
+
+    def boom(n, backend, mul_impl="vpu"):
+        raise RuntimeError("injected kernel failure")
+
+    monkeypatch.setattr(ed25519_batch, "_compiled_kernel", boom)
+    with pytest.warns(UserWarning, match="CPU fallback"):
+        with pytest.raises(chip_smoke.SmokeFailure, match="host oracle"):
+            chip_smoke.library_phase("cpu", sizes=())
+
+
+def test_failing_implementation_is_counted_not_switched(monkeypatch):
+    """``pallas`` asked for and failing: the chunk goes to the health
+    machine and the host oracle like any device failure; the XLA graph
+    is NOT tried behind the caller's back."""
+    from tendermint_tpu.crypto import ed25519_ref as ref
+    from tendermint_tpu.ops import pallas_verify
+
+    def boom(n, block=256, interpret=False):
+        raise RuntimeError("mosaic unavailable")
+
+    def no_xla(*args, **kwargs):
+        raise AssertionError("switched silently to the XLA graph")
+
+    monkeypatch.setenv(ed25519_batch._IMPL_ENV, "pallas")
+    monkeypatch.setattr(pallas_verify, "compiled_verify", boom)
+    monkeypatch.setattr(ed25519_batch, "_compiled_kernel", no_xla)
+    pks, msgs, sigs = [], [], []
+    for i in range(8):
+        priv, pub = ref.keypair_from_seed(bytes([i + 1]) * 32)
+        pks.append(pub)
+        msgs.append(b"vote %d" % i)
+        sigs.append(ref.sign(priv, msgs[-1]))
+    sigs[3] = sigs[3][:32] + bytes(32)
+    want = [True] * 8
+    want[3] = False
+    with pytest.warns(UserWarning, match="mosaic unavailable"):
+        assert ed25519_batch.verify_batch(pks, msgs, sigs) == want
+    snap = device_policy.shared.snapshot()
+    assert snap["failures"]["transient"] == 1
+    assert snap["fallback_batches"] == 1
+    assert snap["state"] == device_policy.DEGRADED
+
+
+# --- §5: a compile cache that can be placed from outside ----------------------
+
+
+def _cache_updates(monkeypatch):
+    import jax
+
+    calls = []
+    monkeypatch.setattr(
+        jax.config, "update", lambda name, value: calls.append((name, value))
+    )
+    ed25519_batch._enable_persistent_cache()
+    return calls
+
+
+def test_cache_dir_from_outside_is_left_alone(monkeypatch, tmp_path):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert _cache_updates(monkeypatch) == []
+
+
+def test_cache_dir_defaults_to_the_checkout(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert _cache_updates(monkeypatch) == [
+        ("jax_compilation_cache_dir", os.path.join(REPO, ".jax_cache"))
+    ]
+
+
+# --- the whole phases at 40 lanes (compile-heavy: outside tier-1) -------------
+
+
+@pytest.fixture()
+def _auto_paths_on(monkeypatch):
+    """What ``auto`` turns on for tpu, turned on here, so that the
+    device-hash and resident-store checks are live on the CPU."""
+    from tendermint_tpu.ops import hash512, precompute, resident
+
+    monkeypatch.setenv("TENDERMINT_TPU_DEVICE_HASH", "on")
+    monkeypatch.setenv("TENDERMINT_TPU_RESIDENT", "on")
+    precompute.reset()
+    resident.reset()
+    hash512.reset_stats()
+    yield
+    precompute.reset()
+    resident.reset()
+    hash512.reset_stats()
+
+
+@pytest.mark.slow
+def test_library_phase_at_40_validators(_auto_paths_on):
+    report = chip_smoke.library_phase("cpu", sizes=(40,), heights=2)
+    (size,) = report["sizes"]
+    c = size["counters"]
+    assert c["hash_device_lanes"] == 40 * 5
+    assert c["resident_hits"] == 40 * 4 and c["resident_uploads"] == 1
+    assert c["gathered_h2d_bytes"] == 0 and c["fallback_batches"] == 0
+    json.dumps(report)  # what the child writes for the parent
+
+
+@pytest.mark.slow
+def test_served_phase_at_40_validators(_auto_paths_on):
+    report = chip_smoke.served_phase(
+        "cpu", n_vals=40, clients=2, per_client=2, warmups=4
+    )
+    assert report["device"]["platform"] == "cpu"
+    assert report["requests"] == 8 and report["lanes"] == 320
+    assert report["stats"]["host_direct_lanes"] == 0
+    assert report["device_health"]["fallback_batches"] == 0
+    assert report["resident"]["hits"] > 0
+    assert all(c["fallback_calls"] == 0 for c in report["clients"])

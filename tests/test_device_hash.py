@@ -26,13 +26,11 @@ BOUNDARY_LENGTHS = (0, 55, 56, 64, 111, 112, 128)
 
 @pytest.fixture(autouse=True)
 def _device_hash_on(monkeypatch):
-    """Force the fused path on (auto keeps CPU off) and reset the
-    sticky-broken flag and lane counter between tests."""
+    """Force the fused path on (auto keeps CPU off) and reset the lane
+    counter between tests."""
     monkeypatch.setenv("TENDERMINT_TPU_DEVICE_HASH", "1")
-    monkeypatch.setattr(hash512, "_BROKEN", False)
     hash512.reset_stats()
     yield
-    monkeypatch.setattr(hash512, "_BROKEN", False)
     hash512.reset_stats()
 
 
@@ -132,18 +130,21 @@ def test_env_off_disables(monkeypatch):
     assert hash512.try_challenge_device(prefix, msgs) is None
 
 
-def test_kernel_failure_is_sticky_and_warns():
+def test_kernel_failure_raises_and_is_not_sticky():
+    """A failing hash kernel is a device failure: it raises to the
+    engine's chunk prep (-> health machine) instead of quietly becoming
+    host hashing, and nothing is remembered once the kernel works."""
+
     def boom(backend):
         raise RuntimeError("injected compile failure")
 
     prefix, msgs = _challenge_case(4, 32, 9)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(hash512, "_compiled_challenge", boom)
-        with pytest.warns(UserWarning, match="falls back"):
-            assert hash512.try_challenge_device(prefix, msgs) is None
-        assert hash512.stats()["broken"] is True
-    # Sticky: even with the kernel healthy again the process stays host.
-    assert hash512.try_challenge_device(prefix, msgs) is None
+        with pytest.raises(RuntimeError, match="injected compile failure"):
+            hash512.try_challenge_device(prefix, msgs)
+        assert hash512.stats()["device_lanes"] == 0
+    assert hash512.try_challenge_device(prefix, msgs) is not None
 
 
 def test_verify_batch_parity_with_device_hash():

@@ -139,31 +139,16 @@ def test_pallas_table_path_parity(batch8):
     assert pallas_verify_batch_tables(pks, msgs, sigs) == want
 
 
-def test_dispatch_prefers_pallas_on_tpu(monkeypatch):
-    """active_impl routes TPU platforms to the Pallas kernel, CPU to XLA."""
+def test_auto_resolves_to_one_impl_per_platform(monkeypatch):
+    """``auto`` means exactly one implementation per platform (pallas on
+    tpu, the XLA graph on cpu); the env switch overrides it."""
+    from tendermint_tpu.ops import backend as backend_mod
+
     monkeypatch.delenv(ed25519_batch._IMPL_ENV, raising=False)
-    monkeypatch.setattr(ed25519_batch, "_PALLAS_BROKEN", False)
-    monkeypatch.setattr(ed25519_batch, "_platform", lambda b: "tpu")
+    monkeypatch.setattr(backend_mod, "platform", lambda b=None: "tpu")
     assert ed25519_batch.active_impl() == "pallas"
-    monkeypatch.setattr(ed25519_batch, "_platform", lambda b: "cpu")
+    monkeypatch.setattr(backend_mod, "platform", lambda b=None: "cpu")
     assert ed25519_batch.active_impl() == "xla"
-    monkeypatch.setenv(ed25519_batch._IMPL_ENV, "pallas")
-    assert ed25519_batch.active_impl() == "pallas"
-    monkeypatch.setattr(ed25519_batch, "_PALLAS_BROKEN", True)
-    assert ed25519_batch.active_impl() == "xla"
-
-
-def test_dispatch_falls_back_when_pallas_fails(monkeypatch, batch8):
-    """A Pallas failure degrades to the XLA graph instead of erroring."""
-    pks, msgs, sigs = batch8
-    monkeypatch.setattr(ed25519_batch, "_PALLAS_BROKEN", False)
-    monkeypatch.setenv(ed25519_batch._IMPL_ENV, "pallas")
-
-    def boom(n, block=256, interpret=False):
-        raise RuntimeError("mosaic unavailable")
-
-    monkeypatch.setattr(pallas_verify, "compiled_verify", boom)
-    with pytest.warns(UserWarning, match="falling back"):
-        assert ed25519_batch.verify_batch(pks, msgs, sigs) == [True] * 8
-    assert ed25519_batch._PALLAS_BROKEN
-    monkeypatch.setattr(ed25519_batch, "_PALLAS_BROKEN", False)
+    for mode in ("pallas", "xla", "mxu"):
+        monkeypatch.setenv(ed25519_batch._IMPL_ENV, mode)
+        assert ed25519_batch.active_impl() == mode
