@@ -16,6 +16,7 @@ from tendermint_tpu.crypto.keys import (
     SR25519_KEY_TYPE,
     PubKey,
 )
+from tendermint_tpu.libs import tracing
 
 
 # Host/device crossover: below this many signatures a device launch
@@ -66,7 +67,7 @@ def tiered_verify_ed25519(pks, msgs, sigs) -> List[bool]:
     return list(verify_batch(pks, msgs, sigs))
 
 
-def note_validator_set(vals) -> None:
+def note_validator_set(vals) -> bool:
     """Register the active validator set with the device precompute
     cache (ops/precompute.py): its ed25519 keys become eligible for
     per-validator table caching, and stale keys from rotated-out sets
@@ -75,15 +76,16 @@ def note_validator_set(vals) -> None:
     whole committee's traffic pins tables on ONE shard (partitioned,
     not replicated). Never raises — cache warm-up must not be able to
     fail a verification — and stays a no-op when the ops engine is
-    absent.
+    absent. Returns True when the cache had not seen the set before.
     """
+    newly_active = False
     try:
         from tendermint_tpu.ops import precompute
     except ImportError:
         precompute = None
     if precompute is not None:
         try:
-            precompute.activate_validator_set(vals)
+            newly_active = precompute.activate_validator_set(vals)
         except Exception:
             pass  # cache warm-up must never fail a verification
     # federation routing hook: same best-effort contract
@@ -96,6 +98,7 @@ def note_validator_set(vals) -> None:
             vfederation.note_validator_set(sorted(keys))
     except Exception:
         pass  # routing locality is an optimization, never a failure
+    return newly_active
 
 
 class BatchVerifier:
@@ -147,6 +150,15 @@ class Ed25519BatchVerifier(BatchVerifier):
         return len(self._pks)
 
     def verify(self) -> Tuple[bool, List[bool]]:
+        with tracing.span(
+            "batch_verify",
+            key_type=ED25519_KEY_TYPE,
+            lanes=len(self._pks),
+            route="host",
+        ) as span:
+            return self._verify(span)
+
+    def _verify(self, span) -> Tuple[bool, List[bool]]:
         n = len(self._pks)
         if n == 0:
             return False, []
@@ -160,6 +172,7 @@ class Ed25519BatchVerifier(BatchVerifier):
             # transport failure, so verdicts never hang on the wire).
             remote = remote_verify_backend()
             if remote is not None:
+                span.set(route="remote")
                 oks = remote(self._pks, self._msgs, self._sigs)
                 return all(oks), list(oks)
             try:
@@ -167,6 +180,7 @@ class Ed25519BatchVerifier(BatchVerifier):
             except ImportError:  # device engine unavailable: fail safe to host
                 use_device = False
             else:
+                span.set(route="device")
                 oks = verify_batch(self._pks, self._msgs, self._sigs)
         if not use_device:
             oks = host_verify_ed25519(self._pks, self._msgs, self._sigs)
@@ -226,8 +240,9 @@ class MultiBatchVerifier(BatchVerifier):
         for kt, sub in self._subs.items():
             _, oks = sub.verify()
             results[kt] = oks
-        merged = [bool(results[kt][i]) for kt, i in self._order]
-        return all(merged), merged
+        with tracing.span("merge_verdicts", lanes=len(self._order)):
+            merged = [bool(results[kt][i]) for kt, i in self._order]
+            return all(merged), merged
 
 
 import threading as _threading

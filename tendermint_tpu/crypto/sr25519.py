@@ -47,6 +47,7 @@ from tendermint_tpu.crypto.ristretto import (
     scalar_from_canonical,
     scalar_from_wide,
 )
+from tendermint_tpu.libs import tracing
 
 PUBKEY_SIZE = 32
 SIGNATURE_SIZE = 64
@@ -263,6 +264,15 @@ class Sr25519BatchVerifier:
         return len(self._entries)
 
     def verify(self) -> Tuple[bool, List[bool]]:
+        with tracing.span(
+            "batch_verify",
+            key_type=SR25519_KEY_TYPE,
+            lanes=len(self._entries),
+            route="host",
+        ) as span:
+            return self._verify(span)
+
+    def _verify(self, span) -> Tuple[bool, List[bool]]:
         n = len(self._entries)
         if n == 0:
             return False, []
@@ -289,6 +299,7 @@ class Sr25519BatchVerifier:
                 # verify_batch_sr handles device failures itself
                 # (warn + shared sticky policy) and returns host-oracle
                 # verdicts on fallback.
+                span.set(route="device")
                 oks = verify_batch_sr(
                     [e[0] for e in self._entries],
                     [e[1] for e in self._entries],

@@ -595,8 +595,9 @@ class OpsMetrics(_NopMixin):
         )
         self.kernel_bucket_seconds = reg.histogram(
             _name(s, "kernel_bucket_seconds"),
-            "Kernel dispatch wall time by engine and power-of-two"
-            " batch bucket (continuous profiler).",
+            "Host time to enqueue one kernel dispatch (the dispatch_chunk"
+            " span; not the kernel's run time on the device), by engine and"
+            " power-of-two batch bucket (continuous profiler).",
             labels=("engine", "bucket"),
             buckets=(
                 0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005,

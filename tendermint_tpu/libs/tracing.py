@@ -40,15 +40,35 @@ splices a remote parent into the local thread's span stack;
 ``scripts/trace_merge.py`` fuses per-process exports (each export
 records ``epoch_unix_us``, the wall-clock anchor of its perf-counter
 epoch, for clock-skew correction).
+
+What the host timeline would otherwise lack (ISSUE 24), all of it only
+while the tracer records:
+
+- per-lane steps as *phase totals*: ``sp.timed("sign_bytes", f)`` wraps
+  a callable once outside a loop, and the span records
+  ``sign_bytes_us`` / ``sign_bytes_n`` on completion — one event for
+  10,000 lanes. On the no-op span ``timed`` returns ``f`` itself.
+- ``gc_pause`` spans from ``gc.callbacks`` (args ``generation``,
+  ``collected``), on the thread that collected.
+- ``xla_compile`` spans from a ``jax.monitoring`` duration listener
+  (arg ``event``; ``ts`` = now - duration), children of whatever span
+  was open. Hooked only once ``jax`` is in ``sys.modules``: this module
+  never imports it first. Both kinds only for intervals of
+  ``EXTERNAL_SPAN_MIN_S`` or longer.
+- every span also enters a ``jax.profiler.TraceAnnotation`` of its
+  name, so a ``jax.profiler`` capture holds the program's spans on the
+  trace's own clock beside "XLA Ops".
 """
 
 from __future__ import annotations
 
 import atexit
+import gc
 import itertools
 import json
 import os
 import struct
+import sys
 import threading
 import time
 from collections import deque
@@ -144,6 +164,7 @@ class _NopSpan:
     two no-op calls — no allocation, no clock reads."""
 
     __slots__ = ()
+    live = False  # callers skip work done only to fill span arguments
 
     def __enter__(self) -> "_NopSpan":
         return self
@@ -154,8 +175,24 @@ class _NopSpan:
     def set(self, **tags: Any) -> None:
         pass
 
+    def timed(self, phase: str, fn: Callable) -> Callable:
+        return fn
+
 
 NOP_SPAN = _NopSpan()
+
+# Intervals that somebody else timed (a collection, a jax trace or
+# compile) leave a span only if they lasted this long. A young
+# collection takes ~0.1 ms and a busy thread runs hundreds a second;
+# tracing one kernel reports thousands of nested sub-millisecond
+# traces. Either would push everything else out of the ring, and what
+# stalls a call (a full collection, a retrace, a compile) is far above.
+EXTERNAL_SPAN_MIN_S = 0.001
+
+# jax.monitoring duration events recorded as ``xla_compile`` spans:
+# tracing a jaxpr, lowering it, and the backend compile (or its load
+# from the persistent cache).
+_COMPILE_EVENT_PREFIX = "/jax/core/compile/"
 
 
 class _Span:
@@ -171,7 +208,10 @@ class _Span:
         "span_id",
         "parent_span_id",
         "_remote",
+        "_phases",
+        "_annotation",
     )
+    live = True
 
     def __init__(
         self,
@@ -189,10 +229,36 @@ class _Span:
         self.span_id = ""
         self.parent_span_id = ""
         self._remote = remote
+        self._phases: Optional[Dict[str, Callable]] = None
+        self._annotation: Any = None
 
     def set(self, **tags: Any) -> None:
         """Attach tags discovered mid-span (hit counts, verdicts)."""
         self.args.update(tags)
+
+    def timed(self, phase: str, fn: Callable) -> Callable:
+        """``fn`` wrapped to add each call's time to this span's phase
+        ``phase``: recorded on completion as ``<phase>_us`` and
+        ``<phase>_n``. Wrap once, outside the loop that calls it, one
+        callable a phase. The wrapper passes positional arguments only:
+        it runs once a lane, and a quarter of a microsecond is what it
+        may cost."""
+        total = 0.0
+        count = 0
+        clock = time.perf_counter
+
+        def timed_call(*args: Any) -> Any:
+            nonlocal total, count
+            t0 = clock()
+            out = fn(*args)
+            total += clock() - t0
+            count += 1
+            return out
+
+        if self._phases is None:
+            self._phases = {}
+        self._phases[phase] = lambda: (total, count)
+        return timed_call
 
     def context(self) -> TraceContext:
         """Propagation context naming this span as the remote parent."""
@@ -215,11 +281,20 @@ class _Span:
             self.trace_id = _new_trace_id()
         self.span_id = _new_span_id()
         stack.append(self)
+        self._annotation = self._tracer._annotate(self.name)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
         t1 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        if self._phases:
+            for phase, read in self._phases.items():
+                seconds, count = read()
+                if count:  # a phase this call never reached is left out
+                    self.args[phase + "_us"] = round(seconds * 1e6, 3)
+                    self.args[phase + "_n"] = count
         stack = self._tracer._stack()
         # Pop self specifically: a sibling span leaked across a generator
         # boundary must not tear another thread of the stack.
@@ -235,7 +310,10 @@ class Tracer:
     """Thread-safe span recorder with a bounded ring of completed spans."""
 
     def __init__(self, cap: int = DEFAULT_CAP):
-        self._lock = threading.Lock()
+        # re-entrant: a collection can start between two bytecodes of a
+        # block that holds the lock, and its gc_pause lands in the ring
+        # from the same thread
+        self._lock = threading.RLock()
         self._tls = threading.local()
         self._ring: deque = deque(maxlen=cap)  # guarded-by: _lock
         # mode/path/recording/observer are written under _lock but read
@@ -257,6 +335,13 @@ class Tracer:
         self._atexit_registered = False  # guarded-by: _lock
         self.recorded = 0  # guarded-by: _lock
         self.dropped = 0  # guarded-by: _lock
+        # the hooks recording installs (and `off` removes): one bound
+        # method each, so removal finds the object that was registered
+        self._gc_hook = self._on_gc
+        self._jax_hook = self._on_jax_duration
+        self._jax_hooked = False  # guarded-by: _lock
+        # jax.profiler.TraceAnnotation once jax is hooked, else None
+        self._annotation_cls: Any = None  # guarded-by: none(racy hot-path read)
 
     # --- configuration -------------------------------------------------------
 
@@ -280,7 +365,40 @@ class Tracer:
             if self._path and not self._atexit_registered:
                 self._atexit_registered = True
                 atexit.register(self.flush)
+            self._sync_hooks_locked()
         return self
+
+    def _sync_hooks_locked(self) -> None:
+        """Recording installs the collector callback and (if jax is
+        already imported) the compile listener; ``off`` removes both,
+        so a tracer that is off leaves nothing of its own behind."""
+        if self._recording:
+            if self._gc_hook not in gc.callbacks:
+                gc.callbacks.append(self._gc_hook)
+            self._hook_jax_locked()
+            return
+        if self._gc_hook in gc.callbacks:
+            gc.callbacks.remove(self._gc_hook)
+        if self._jax_hooked:
+            sys.modules["jax"].monitoring.unregister_event_duration_listener(
+                self._jax_hook
+            )
+            self._jax_hooked = False
+            self._annotation_cls = None
+
+    def _hook_jax_locked(self) -> None:
+        """Never the first to import jax: a process that verifies on
+        the host alone (or a tool that only merges traces) stays free
+        of it, and one that imports it later is hooked by the first
+        span after that."""
+        if self._jax_hooked or "jax" not in sys.modules:
+            return
+        import jax.monitoring
+        import jax.profiler
+
+        jax.monitoring.register_event_duration_secs_listener(self._jax_hook)
+        self._annotation_cls = jax.profiler.TraceAnnotation
+        self._jax_hooked = True
 
     @property
     def mode(self) -> str:
@@ -377,6 +495,80 @@ class Tracer:
         if not top.trace_id:
             return None
         return TraceContext(top.trace_id, top.span_id, 1)
+
+    def _annotate(self, name: str) -> Any:
+        """An entered ``jax.profiler.TraceAnnotation`` for a span that
+        is being recorded (tens of ns while no profiler session is
+        open), else None."""
+        if not self._recording:
+            return None
+        cls = self._annotation_cls
+        if cls is None:
+            if "jax" not in sys.modules:
+                return None
+            with self._lock:
+                self._hook_jax_locked()
+                cls = self._annotation_cls
+            if cls is None:  # configure("off") won the race
+                return None
+        annotation = cls(name)
+        annotation.__enter__()
+        return annotation
+
+    def _on_gc(self, phase: str, info: Dict[str, Any]) -> None:
+        """``gc.callbacks`` entry: one ``gc_pause`` span per collection
+        of ``EXTERNAL_SPAN_MIN_S`` or longer, on the thread that ran it (the
+        collector is not re-entrant, so one start time a thread is
+        enough)."""
+        if phase == "start":
+            self._tls.gc_t0 = time.perf_counter()
+            return
+        t1 = time.perf_counter()
+        t0 = getattr(self._tls, "gc_t0", None)
+        if t0 is None or not self._recording:
+            return
+        self._record_interval(
+            "gc_pause",
+            t0,
+            t1,
+            {"generation": info["generation"], "collected": info["collected"]},
+        )
+
+    def _on_jax_duration(self, event: str, duration: float, **_: Any) -> None:
+        """``jax.monitoring`` duration listener: jax reports a trace,
+        a lowering or a backend compile when it ends, so the span is
+        placed backwards from now. A jitted function traced inside
+        another's trace nests inside it."""
+        if not self._recording or not event.startswith(_COMPILE_EVENT_PREFIX):
+            return
+        t1 = time.perf_counter()
+        self._record_interval("xla_compile", t1 - duration, t1, {"event": event})
+
+    def _record_interval(
+        self, name: str, t0: float, t1: float, args: Dict[str, Any]
+    ) -> None:
+        """A completed interval somebody else timed, recorded as a
+        child of this thread's innermost open span."""
+        if t1 - t0 < EXTERNAL_SPAN_MIN_S:
+            return
+        ev = {
+            "name": name,
+            "ph": "X",
+            "pid": self._pid,
+            "tid": threading.get_ident(),
+            "ts": round((t0 - self._epoch) * 1e6, 3),
+            "dur": round((t1 - t0) * 1e6, 3),
+            "args": args,
+        }
+        stack = self._stack()
+        if stack:
+            top = stack[-1]
+            args["parent"] = top.name
+            if top.trace_id:
+                ev["trace_id"] = top.trace_id
+                ev["span_id"] = _new_span_id()
+                ev["parent_span_id"] = top.span_id
+        self._append(ev)
 
     def instant(self, name: str, **args: Any) -> None:
         """Zero-duration event (device health transitions etc.)."""

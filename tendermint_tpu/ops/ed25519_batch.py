@@ -968,6 +968,17 @@ def _chunk_rows(rows: np.ndarray, span: int = CHUNK) -> List[np.ndarray]:
     return [rows[lo : lo + span] for lo in range(0, len(rows), span)]
 
 
+def _chunk_h2d_bytes(inputs: dict) -> int:
+    """Bytes a prepared chunk hands to its kernel from the host: every
+    per-batch array, the resident chunk's gather indices among them.
+    The resident store is already on the device and does not count."""
+    return sum(
+        int(v.nbytes)
+        for name, v in inputs.items()
+        if name != "store" and isinstance(v, np.ndarray)
+    )
+
+
 def _mesh_collect_retry(job: "_Job", backend: Optional[str], exc: Exception):
     """A sharded chunk died at materialization. If the failure is
     attributable to one device, exclude it, rebuild a smaller mesh, and
@@ -1051,9 +1062,9 @@ def verify_batch(
         return []
     with tracing.span("verify_batch", engine="ed25519", lanes=n):
         if not precompute.result_cache_enabled():
-            return [
-                bool(v) for v in _verify_uncached(pubkeys, msgs, sigs, backend)
-            ]
+            verdicts = _verify_uncached(pubkeys, msgs, sigs, backend)
+            with tracing.span("merge_results", lanes=n):
+                return [bool(v) for v in verdicts]
         verdicts = np.zeros(n, dtype=bool)
         pending = []
         with tracing.span(
@@ -1076,12 +1087,14 @@ def verify_batch(
                     [sigs[i] for i in pending],
                 )
             out = _verify_uncached(sub[0], sub[1], sub[2], backend)
-            for j, i in enumerate(pending):
-                verdicts[i] = out[j]
-                precompute.results.put(
-                    pubkeys[i], msgs[i], sigs[i], bool(out[j])
-                )
-        return [bool(v) for v in verdicts]
+            with tracing.span("cache_store", lanes=len(pending)):
+                for j, i in enumerate(pending):
+                    verdicts[i] = out[j]
+                    precompute.results.put(
+                        pubkeys[i], msgs[i], sigs[i], bool(out[j])
+                    )
+        with tracing.span("merge_results", lanes=n):
+            return [bool(v) for v in verdicts]
 
 
 def _verify_uncached(
@@ -1112,53 +1125,63 @@ def _verify_uncached(
         entries, has_table = precompute.tables.gather(pubkeys)
     except Exception:  # cache trouble never blocks verification
         entries, has_table = None, np.zeros(n, dtype=bool)
-    if entries is not None:
-        well_formed = np.fromiter(
-            (len(pk) == 32 and len(sg) == 64 for pk, sg in zip(pubkeys, sigs)),
-            dtype=bool,
-            count=n,
-        )
-        has_table &= well_formed
-    if entries is None or not has_table.any():
-        has_table = np.zeros(n, dtype=bool)
-        entries = None
-
-    # Mesh plan for this batch: when one exists, chunks span all its
-    # devices — span and padding scale by the device count so each chip
-    # still sees bucket-size slabs. A plan degraded mid-batch replaces
-    # `plan` so later chunks ride the smaller mesh.
-    plan = _mesh_plan(n)
-    span = CHUNK * plan.n_dev if plan is not None else CHUNK
-    mesh_used = False
-
-    # Resident routing: lanes whose key already lives in the device-
-    # resident store ship only gather indices — zero per-batch table
-    # H2D. Any trouble leaves every cached lane on the gathered path.
-    res_idx = res_ok_cols = res_tab = res_mesh_key = None
-    res_mask = np.zeros(n, dtype=bool)
-    if entries is not None:
-        try:
-            from tendermint_tpu.ops import resident
-
-            res = resident.acquire(
-                pubkeys, has_table, plan=plan, backend=backend
+    # From the gather's answer to the job list: which lanes ride which
+    # kernel, on which devices, in which chunks.
+    with tracing.span("route_lanes", lanes=n) as rsp:
+        if entries is not None:
+            well_formed = np.fromiter(
+                (len(pk) == 32 and len(sg) == 64 for pk, sg in zip(pubkeys, sigs)),
+                dtype=bool,
+                count=n,
             )
-        except Exception:  # resident path is an optimization, never a gate
-            res = None
-        if res is not None:
-            res_mask, res_idx, res_ok_cols, res_tab, res_mesh_key = res
-    table_mask = has_table & ~res_mask
+            has_table &= well_formed
+        if entries is None or not has_table.any():
+            has_table = np.zeros(n, dtype=bool)
+            entries = None
 
-    jobs = [
-        _Job("resident", rows)
-        for rows in _chunk_rows(np.nonzero(res_mask)[0], span)
-    ]
-    jobs += [
-        _Job("tables", rows) for rows in _chunk_rows(np.nonzero(table_mask)[0], span)
-    ]
-    jobs += [
-        _Job("legacy", rows) for rows in _chunk_rows(np.nonzero(~has_table)[0], span)
-    ]
+        # Mesh plan for this batch: when one exists, chunks span all its
+        # devices — span and padding scale by the device count so each chip
+        # still sees bucket-size slabs. A plan degraded mid-batch replaces
+        # `plan` so later chunks ride the smaller mesh.
+        plan = _mesh_plan(n)
+        span = CHUNK * plan.n_dev if plan is not None else CHUNK
+        mesh_used = False
+
+        # Resident routing: lanes whose key already lives in the device-
+        # resident store ship only gather indices — zero per-batch table
+        # H2D. Any trouble leaves every cached lane on the gathered path.
+        res_idx = res_ok_cols = res_tab = res_mesh_key = None
+        res_mask = np.zeros(n, dtype=bool)
+        if entries is not None:
+            try:
+                from tendermint_tpu.ops import resident
+
+                res = resident.acquire(
+                    pubkeys, has_table, plan=plan, backend=backend
+                )
+            except Exception:  # resident path is an optimization, never a gate
+                res = None
+            if res is not None:
+                res_mask, res_idx, res_ok_cols, res_tab, res_mesh_key = res
+        table_mask = has_table & ~res_mask
+
+        jobs = [
+            _Job("resident", rows)
+            for rows in _chunk_rows(np.nonzero(res_mask)[0], span)
+        ]
+        jobs += [
+            _Job("tables", rows) for rows in _chunk_rows(np.nonzero(table_mask)[0], span)
+        ]
+        jobs += [
+            _Job("legacy", rows) for rows in _chunk_rows(np.nonzero(~has_table)[0], span)
+        ]
+        if rsp.live:
+            rsp.set(
+                resident=int(res_mask.sum()),
+                tables=int(table_mask.sum()),
+                legacy=int(n - has_table.sum()),
+                jobs=len(jobs),
+            )
 
     def prep_job(job: _Job) -> Tuple[dict, np.ndarray]:
         with tracing.span(
@@ -1245,7 +1268,12 @@ def _verify_uncached(
                         engine="ed25519",
                         kind=job.kind,
                         lanes=len(job.rows),
-                    ):
+                    ) as dsp:
+                        if dsp.live:
+                            dsp.set(
+                                padded_lanes=int(inputs["r"].shape[0]),
+                                h2d_bytes=_chunk_h2d_bytes(inputs),
+                            )
                         job.out, job.plan = runner(inputs, backend, plan)
                     if job.plan is not None:
                         mesh_used = True
@@ -1288,7 +1316,7 @@ def _verify_uncached(
                     engine="ed25519",
                     kind=job.kind,
                     lanes=len(job.rows),
-                ):
+                ) as csp:
                     fault_injection.fire("ed25519.collect")
                     if job.plan is not None:
                         from tendermint_tpu.parallel import (
@@ -1298,6 +1326,8 @@ def _verify_uncached(
                         ok = mesh_sharding.collect_sharded(job.out, "ed25519")
                     else:
                         ok = np.asarray(job.out)
+                    if csp.live:
+                        csp.set(d2h_bytes=int(ok.nbytes))
                 device_chunks_ok += 1
                 if job.plan is not None:
                     _mesh_on_success(job.plan)

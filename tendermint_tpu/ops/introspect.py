@@ -22,9 +22,14 @@ one process-wide singleton each:
     A continuous low-overhead profiler fed from the tracer's third
     sink slot (:func:`tendermint_tpu.libs.tracing.Tracer.
     set_profile_sink`): per-(engine, batch-bucket) rolling windows of
-    kernel wall time (``dispatch_chunk`` spans) and compile time
-    (``kernel_compile`` spans), exported as p50/p95/p99 digests in the
-    ``profile`` fragment bench/child.py attaches to every section.
+    ``dispatch`` (``dispatch_chunk`` spans: the host's time to ENQUEUE
+    a chunk — JAX dispatch is asynchronous, so this is not how long
+    the kernel runs), ``device_wait`` (``collect_chunk`` spans: the
+    host's wait for the chunk's verdicts, which is where the kernel's
+    run time shows) and ``compile`` (``kernel_compile`` spans),
+    exported as p50/p95/p99 digests in the ``profile`` fragment
+    bench/child.py attaches to every section. A kernel's own time on
+    the device comes only from a profiler trace (``chipbench``).
     Buckets are power-of-two lane counts only, capped with an
     ``other`` overflow (:func:`bucket_label`), so the metric-label
     cardinality is bounded by construction — tpulint TPM004 audits
@@ -114,19 +119,29 @@ class _Series:
 
 @instrument_attrs
 class KernelProfiler:
-    """Rolling per-(engine, bucket) kernel wall + compile digests.
+    """Rolling per-(engine, bucket) dispatch (= enqueue), device-wait
+    and compile digests.
 
     Installed as the tracer's profile sink (a third slot beside the
     metrics observer and the flight sink); the sink call is the whole
     hot-path cost: one dict lookup + deque append under a lock, only
-    for ``dispatch_chunk`` / ``kernel_compile`` spans. The bench
-    harness keeps it on by default and proves the overhead ≤5% in CI.
+    for ``dispatch_chunk`` / ``collect_chunk`` / ``kernel_compile``
+    spans. The bench harness keeps it on by default and proves the
+    overhead ≤5% in CI.
     """
+
+    # span name -> digest it feeds
+    _DIGEST_OF = {
+        "dispatch_chunk": "dispatch",
+        "collect_chunk": "device_wait",
+        "kernel_compile": "compile",
+    }
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
-        self._kernel: Dict[Tuple[str, str], _Series] = {}  # guarded-by: _lock
-        self._compile: Dict[Tuple[str, str], _Series] = {}  # guarded-by: _lock
+        self._series: Dict[str, Dict[Tuple[str, str], _Series]] = {  # guarded-by: _lock
+            digest: {} for digest in self._DIGEST_OF.values()
+        }
         self._enabled = _env_on()  # guarded-by: none(racy bool read)
         self._metrics = None  # guarded-by: none(racy hot-path read)
 
@@ -153,17 +168,16 @@ class KernelProfiler:
 
     def sink(self, name: str, args: Dict[str, Any], seconds: float) -> None:
         """(name, args, seconds) for every completed span — same shape
-        as the metrics observer. Anything that is not a dispatch or
-        compile span returns in two compares."""
-        if name not in ("dispatch_chunk", "kernel_compile"):
+        as the metrics observer. Anything that is not a dispatch,
+        collect or compile span returns after one dict probe."""
+        digest = self._DIGEST_OF.get(name)
+        if digest is None:
             return
         engine = str(args.get("engine", "unknown"))
         bucket = bucket_label(args.get("lanes"))
         key = (engine, bucket)
         with self._lock:
-            table = (
-                self._kernel if name == "dispatch_chunk" else self._compile
-            )
+            table = self._series[digest]
             series = table.get(key)
             if series is None:
                 series = table[key] = _Series()
@@ -183,22 +197,21 @@ class KernelProfiler:
         """The ``profile`` fragment: per-series digests keyed
         ``<engine>/b<bucket>``."""
         with self._lock:
-            kernel = {k: s.digest() for k, s in self._kernel.items()}
-            comp = {k: s.digest() for k, s in self._compile.items()}
-        return {
-            "enabled": self._enabled,
-            "kernel": {
-                "%s/b%s" % key: d for key, d in sorted(kernel.items())
-            },
-            "compile": {
-                "%s/b%s" % key: d for key, d in sorted(comp.items())
-            },
-        }
+            digests = {
+                digest: {k: s.digest() for k, s in table.items()}
+                for digest, table in self._series.items()
+            }
+        out: Dict[str, Any] = {"enabled": self._enabled}
+        for digest, table in digests.items():
+            out[digest] = {
+                "%s/b%s" % key: d for key, d in sorted(table.items())
+            }
+        return out
 
     def clear(self) -> None:
         with self._lock:
-            self._kernel.clear()
-            self._compile.clear()
+            for table in self._series.values():
+                table.clear()
 
 
 @instrument_attrs

@@ -274,6 +274,16 @@ class ResidentTableStore:
         """
         if not self.enabled(backend):
             return None
+        with tracing.span(
+            "resident_acquire",
+            engine="ed25519",
+            lanes=len(pubkeys),
+            hits=0,
+            misses=0,
+        ) as sp:
+            return self._acquire(pubkeys, has_table, plan, backend, sp)
+
+    def _acquire(self, pubkeys, has_table, plan, backend, sp):
         n = len(pubkeys)
         want_key = self._context_key(plan, backend)
         with self._lock:
@@ -316,6 +326,7 @@ class ResidentTableStore:
             self.hits += hits
             self.misses += misses
             metrics = self._metrics
+        sp.set(hits=hits, misses=misses)
         if metrics is not None:
             if hits:
                 metrics.table_resident_hits.inc(hits)

@@ -178,56 +178,71 @@ def _verify_commit_batch(
     batch_sig_idxs = []
     # Make this set's keys eligible for the device precompute cache —
     # the second commit from the same validators skips its table builds.
-    crypto_batch.note_validator_set(vals)
+    with tracing.span("note_validator_set", validators=len(vals)) as nsp:
+        nsp.set(newly_active=crypto_batch.note_validator_set(vals))
     # Mixed validator sets sub-batch per key type (BASELINE config 5);
     # an unsupported key (secp256k1) raises on add -> single fallback.
     bv = crypto_batch.MultiBatchVerifier()
-    for idx, commit_sig in enumerate(commit.signatures):
-        if ignore_sig(commit_sig):
-            continue
-        if look_up_by_index:
-            val = vals.validators[idx]
-        else:
-            val_idx, val = vals.get_by_address(commit_sig.validator_address)
-            if val is None:
+    unbatchable = False
+    # One span for the whole loop; its per-lane steps are phase totals
+    # in the span's arguments (wrapped once here: the loop itself holds
+    # no tracing call, and on the no-op span these are the callables
+    # themselves).
+    with tracing.span("build_lanes") as lsp:
+        sign_bytes = lsp.timed("sign_bytes", commit.vote_sign_bytes)
+        batch_add = lsp.timed("batch_add", bv.add)
+        val_lookup = lsp.timed("val_lookup", vals.get_by_address)
+        for idx, commit_sig in enumerate(commit.signatures):
+            if ignore_sig(commit_sig):
                 continue
-            if val_idx in seen_vals:
-                raise InvalidCommitError(
-                    f"double vote from validator {val_idx} "
-                    f"({seen_vals[val_idx]} and {idx})"
-                )
-            seen_vals[val_idx] = idx
-        vote_sign_bytes = commit.vote_sign_bytes(chain_id, idx)
-        try:
-            bv.add(val.pub_key, vote_sign_bytes, commit_sig.signature)
-        except ValueError:
-            return _verify_commit_single(
-                chain_id,
-                vals,
-                commit,
-                voting_power_needed,
-                ignore_sig,
-                count_sig,
-                count_all_signatures,
-                look_up_by_index,
-            )
-        batch_sig_idxs.append(idx)
-        if count_sig(commit_sig):
-            tallied += val.voting_power
-        if not count_all_signatures and tallied > voting_power_needed:
-            break
+            if look_up_by_index:
+                val = vals.validators[idx]
+            else:
+                val_idx, val = val_lookup(commit_sig.validator_address)
+                if val is None:
+                    continue
+                if val_idx in seen_vals:
+                    raise InvalidCommitError(
+                        f"double vote from validator {val_idx} "
+                        f"({seen_vals[val_idx]} and {idx})"
+                    )
+                seen_vals[val_idx] = idx
+            vote_sign_bytes = sign_bytes(chain_id, idx)
+            try:
+                batch_add(val.pub_key, vote_sign_bytes, commit_sig.signature)
+            except ValueError:
+                unbatchable = True
+                break
+            batch_sig_idxs.append(idx)
+            if count_sig(commit_sig):
+                tallied += val.voting_power
+            if not count_all_signatures and tallied > voting_power_needed:
+                break
+        lsp.set(lanes=len(batch_sig_idxs))
+    if unbatchable:
+        return _verify_commit_single(
+            chain_id,
+            vals,
+            commit,
+            voting_power_needed,
+            ignore_sig,
+            count_sig,
+            count_all_signatures,
+            look_up_by_index,
+        )
     if tallied <= voting_power_needed:
         raise NotEnoughVotingPowerError(got=tallied, needed=voting_power_needed)
     ok, valid_sigs = bv.verify()
     if ok:
         return
-    for i, sig_ok in enumerate(valid_sigs):
-        if not sig_ok:
-            idx = batch_sig_idxs[i]
-            sig = commit.signatures[idx]
-            raise InvalidCommitError(
-                f"wrong signature (#{idx}): {sig.signature.hex().upper()}"
-            )
+    with tracing.span("merge_verdicts", lanes=len(valid_sigs), scan="first_bad"):
+        for i, sig_ok in enumerate(valid_sigs):
+            if not sig_ok:
+                idx = batch_sig_idxs[i]
+                sig = commit.signatures[idx]
+                raise InvalidCommitError(
+                    f"wrong signature (#{idx}): {sig.signature.hex().upper()}"
+                )
     raise InvalidCommitError(
         "BUG: batch verification failed with no invalid signatures"
     )
@@ -246,30 +261,35 @@ def _verify_commit_single(
     """validation.go:262-330."""
     tallied = 0
     seen_vals = {}
-    for idx, commit_sig in enumerate(commit.signatures):
-        if ignore_sig(commit_sig):
-            continue
-        if look_up_by_index:
-            val = vals.validators[idx]
-        else:
-            val_idx, val = vals.get_by_address(commit_sig.validator_address)
-            if val is None:
+    # The batch path's steps, less the batch: the same phase names on
+    # one span, the signature checks themselves the span's own time.
+    with tracing.span("single_verify", lanes=len(commit.signatures)) as lsp:
+        sign_bytes = lsp.timed("sign_bytes", commit.vote_sign_bytes)
+        val_lookup = lsp.timed("val_lookup", vals.get_by_address)
+        for idx, commit_sig in enumerate(commit.signatures):
+            if ignore_sig(commit_sig):
                 continue
-            if val_idx in seen_vals:
+            if look_up_by_index:
+                val = vals.validators[idx]
+            else:
+                val_idx, val = val_lookup(commit_sig.validator_address)
+                if val is None:
+                    continue
+                if val_idx in seen_vals:
+                    raise InvalidCommitError(
+                        f"double vote from validator {val_idx} "
+                        f"({seen_vals[val_idx]} and {idx})"
+                    )
+                seen_vals[val_idx] = idx
+            vote_sign_bytes = sign_bytes(chain_id, idx)
+            if not val.pub_key.verify_signature(vote_sign_bytes, commit_sig.signature):
                 raise InvalidCommitError(
-                    f"double vote from validator {val_idx} "
-                    f"({seen_vals[val_idx]} and {idx})"
+                    f"wrong signature (#{idx}): {commit_sig.signature.hex().upper()}"
                 )
-            seen_vals[val_idx] = idx
-        vote_sign_bytes = commit.vote_sign_bytes(chain_id, idx)
-        if not val.pub_key.verify_signature(vote_sign_bytes, commit_sig.signature):
-            raise InvalidCommitError(
-                f"wrong signature (#{idx}): {commit_sig.signature.hex().upper()}"
-            )
-        if count_sig(commit_sig):
-            tallied += val.voting_power
-        if not count_all_signatures and tallied > voting_power_needed:
-            return
+            if count_sig(commit_sig):
+                tallied += val.voting_power
+            if not count_all_signatures and tallied > voting_power_needed:
+                return
     if tallied <= voting_power_needed:
         raise NotEnoughVotingPowerError(got=tallied, needed=voting_power_needed)
 

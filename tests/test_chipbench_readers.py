@@ -1,0 +1,146 @@
+"""The span readers ISSUE 24 adds to the benchmark, reduced on
+hand-made spans (times in microseconds, as the tracer exports them).
+
+One call: verify_commit 0..1000 holding note_validator_set 10..60,
+build_lanes 100..400 (phase totals 200 + 40 of its 300), batch_verify
+400..900 holding verify_batch 420..880, merge_verdicts 900..950.
+"""
+
+import pytest
+
+from chipbench.readers import (
+    span_arg_per_call,
+    span_time_per_later_call,
+    span_unnamed_per_call,
+)
+
+
+class Evidence:
+    def __init__(self, spans, calls=1):
+        self.spans = spans
+        self.calls = [{}] * calls
+
+
+def span(name, ts, dur, **args):
+    return {"name": name, "ts": float(ts), "dur": float(dur), "args": args}
+
+
+def one_call(at=0.0):
+    return [
+        span("verify_commit", at, 1000),
+        span("note_validator_set", at + 10, 50),
+        span(
+            "build_lanes", at + 100, 300,
+            lanes=4, sign_bytes_us=200.0, sign_bytes_n=4,
+            batch_add_us=40.0, batch_add_n=4,
+        ),
+        span("batch_verify", at + 400, 500),
+        span("verify_batch", at + 420, 460),
+        span("dispatch_chunk", at + 500, 20, lanes=4, h2d_bytes=8192),
+        span("dispatch_chunk", at + 600, 20, lanes=4, h2d_bytes=4096),
+        span("merge_verdicts", at + 900, 50),
+    ]
+
+
+CHILDREN = ["note_validator_set", "build_lanes", "batch_verify", "merge_verdicts"]
+PHASED = {"build_lanes": ["sign_bytes", "batch_add", "val_lookup"]}
+
+
+@pytest.mark.parametrize(
+    "arg,scale,want",
+    [
+        ("sign_bytes_us", 0.001, 0.2),
+        ("batch_add_us", 0.001, 0.04),
+        ("sign_bytes_n", 1.0, 4.0),
+    ],
+)
+def test_span_arg_per_call_sums_a_phase_total(arg, scale, want):
+    ev = Evidence(one_call() + one_call(at=5000.0), calls=2)
+    got = span_arg_per_call.read(ev, span="build_lanes", arg=arg, scale=scale)
+    assert got == pytest.approx(want)
+
+
+def test_span_arg_per_call_sums_over_the_chunks_of_a_call():
+    ev = Evidence(one_call())
+    got = span_arg_per_call.read(ev, span="dispatch_chunk", arg="h2d_bytes")
+    assert got == 8192 + 4096
+
+
+@pytest.mark.parametrize(
+    "spans",
+    [
+        [],  # nothing recorded
+        [span("build_lanes", 0, 10, lanes=4)],  # the parent's program: no phases
+        [span("other", 0, 10, sign_bytes_us=1.0)],  # the argument on another span
+    ],
+)
+def test_span_arg_per_call_reads_nothing_where_nothing_is(spans):
+    ev = Evidence(spans)
+    assert span_arg_per_call.read(ev, span="build_lanes", arg="sign_bytes_us") is None
+
+
+def test_span_unnamed_is_self_time_plus_the_loop_outside_its_phases():
+    ev = Evidence(one_call())
+    # verify_commit 1000 less its children 50 + 300 + 500 + 50 = 100;
+    # build_lanes 300 less its phases 240 = 60
+    got = span_unnamed_per_call.read(
+        ev, span="verify_commit", children=CHILDREN, phased=PHASED
+    )
+    assert got == pytest.approx((100 + 60) / 1000.0)
+
+
+def test_span_unnamed_divides_by_the_calls_of_the_window():
+    ev = Evidence(one_call() + one_call(at=5000.0), calls=2)
+    got = span_unnamed_per_call.read(
+        ev, span="verify_commit", children=CHILDREN, phased=PHASED
+    )
+    assert got == pytest.approx(0.16)
+
+
+@pytest.mark.parametrize(
+    "spans",
+    [
+        [],
+        # the parent's program: verify_commit and verify_batch alone
+        [span("verify_commit", 0, 1000), span("verify_batch", 420, 460)],
+        # a loop span with no verify_commit around it
+        [span("build_lanes", 100, 300, sign_bytes_us=200.0)],
+    ],
+)
+def test_span_unnamed_reads_nothing_without_the_new_spans(spans):
+    ev = Evidence(spans)
+    got = span_unnamed_per_call.read(
+        ev, span="verify_commit", children=CHILDREN, phased=PHASED
+    )
+    assert got is None
+
+
+def test_the_parts_of_the_entry_add_up_to_its_self_time():
+    """ISSUE 24's identity: sign_bytes + batch_add + note_set + unnamed
+    + merge_verdicts + batch_verify's own time = verify_commit less
+    verify_batch (what entry_host_ms reads)."""
+    from chipbench.readers import span_self_time_per_call, span_time_per_call
+
+    ev = Evidence(one_call())
+    parts = (
+        span_arg_per_call.read(ev, "build_lanes", "sign_bytes_us", 0.001)
+        + span_arg_per_call.read(ev, "build_lanes", "batch_add_us", 0.001)
+        + span_time_per_call.read(ev, ["note_validator_set", "merge_verdicts"])
+        + span_unnamed_per_call.read(ev, "verify_commit", CHILDREN, PHASED)
+        + span_self_time_per_call.read(ev, "batch_verify", ["verify_batch"])
+    )
+    entry_host = span_self_time_per_call.read(ev, "verify_commit", ["verify_batch"])
+    assert parts == pytest.approx(entry_host)
+
+
+def test_later_calls_leave_out_what_ran_before_the_window():
+    """run.py collects garbage itself between set-up and the window;
+    that pause is drained with the first call and is no call's."""
+    first = {"spans": [span("gc_pause", 0, 90000, generation=2), span("verify_commit", 100000, 1000)]}
+    second = {"spans": [span("verify_commit", 200000, 1000), span("gc_pause", 200100, 300, generation=1)]}
+    third = {"spans": [span("verify_commit", 300000, 1000)]}
+    ev = Evidence([])
+    ev.calls = [first, second, third]
+    assert span_time_per_later_call.read(ev, ["gc_pause"]) == pytest.approx(0.15)
+    ev.calls = [first]
+    assert span_time_per_later_call.read(ev, ["gc_pause"]) is None

@@ -194,10 +194,40 @@ class TestProfiler:
             snap = introspect.profiler.snapshot()
         finally:
             introspect.profiler.configure("off")
-        k = snap["kernel"]["ed25519/b128"]
+        # the enqueue, named as what it is: no digest calls it the kernel
+        assert "kernel" not in snap
+        k = snap["dispatch"]["ed25519/b128"]
         assert k["count"] == 4
         assert k["p50_ms"] >= 0.0 and k["p99_ms"] >= k["p50_ms"]
         assert snap["compile"]["ed25519/b128"]["count"] == 1
+        assert snap["device_wait"] == {}
+
+    def test_device_wait_digest_fed_from_collect_spans(self):
+        import time
+
+        from tendermint_tpu.libs import tracing
+
+        introspect.profiler.configure("on")
+        try:
+            with tracing.tracer.span(
+                "dispatch_chunk", stage="dispatch", engine="ed25519",
+                kind="resident", lanes=4096,
+            ):
+                pass  # the enqueue returns at once
+            for _ in range(3):
+                with tracing.tracer.span(
+                    "collect_chunk", stage="collect", engine="ed25519",
+                    kind="resident", lanes=4096,
+                ):
+                    time.sleep(0.002)  # the host waits for the verdicts here
+            snap = introspect.profiler.snapshot()
+        finally:
+            introspect.profiler.configure("off")
+        wait = snap["device_wait"]["ed25519/b4096"]
+        assert wait["count"] == 3
+        assert wait["p50_ms"] >= 2.0
+        assert snap["dispatch"]["ed25519/b4096"]["count"] == 1
+        assert snap["dispatch"]["ed25519/b4096"]["p50_ms"] < wait["p50_ms"]
 
     def test_profile_sink_keeps_spans_live_when_ring_off(self):
         """The tracer's NOP gate must treat the profile sink as a
@@ -224,7 +254,8 @@ class TestProfiler:
     def test_non_kernel_spans_ignored(self):
         introspect.profiler.sink("verify_batch", {"lanes": 8}, 0.001)
         snap = introspect.profiler.snapshot()
-        assert snap["kernel"] == {} and snap["compile"] == {}
+        assert snap["dispatch"] == {} and snap["device_wait"] == {}
+        assert snap["compile"] == {}
 
 
 # --- compile accounting -------------------------------------------------------

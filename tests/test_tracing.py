@@ -305,9 +305,14 @@ def test_verify_commit_traced_end_to_end(ring, monkeypatch):
         assert ev["args"]["round"] == round_
         assert ev["args"]["sigs"] == 24
 
-    # nested under it: the engine batch, then the cache lookup
+    # nested under it: the key type's batch verifier, the engine
+    # batch, then the cache lookup
     assert all(
         ev["args"]["parent"] == "verify_commit"
+        for ev in by_name["batch_verify"]
+    )
+    assert all(
+        ev["args"]["parent"] == "batch_verify"
         for ev in by_name["verify_batch"]
     )
     lookups = by_name["cache_lookup"]
@@ -342,6 +347,9 @@ def test_scheduler_spans_nest_assembly_and_flush(ring):
     pk = priv.pub_key().bytes()
     msg = b"sched-traced"
     sig = priv.sign(msg)
+    # the kernel's first call compiles for longer than verify()'s wait
+    assert ed25519_batch.verify_batch([pk], [msg], [sig]) == [True]
+    ring.clear()
     sched = VerifyScheduler(ed25519_batch.verify_batch, max_delay=0.01)
     sched.start()
     try:
@@ -397,3 +405,287 @@ def test_file_mode_flush_writes_chrome_trace(tmp_path):
     finally:
         tracing.configure("off")
         tracing.tracer.clear()
+
+
+# --- ISSUE 24: the host time the spans stopped short of ---------------------
+
+N_LANES = 64
+
+
+@pytest.fixture(scope="module")
+def commit_capture():
+    """One warm 64-validator ``verify_commit`` recorded in ring mode:
+    the events of that call alone, and what ``off`` leaves behind."""
+    import gc
+
+    from tendermint_tpu.ops import precompute
+    from tendermint_tpu.ops import resident as resident_mod
+    from tendermint_tpu.types import validation
+
+    mp = pytest.MonkeyPatch()
+    mp.setenv(precompute._RESULT_ENV, "1")
+    # on a CPU the resident store is off unless asked for; the chip's
+    # path is the one to show
+    mp.setenv(resident_mod._ENV, "on")
+    mp.delenv(tracing.CAP_ENV, raising=False)
+    precompute.reset()
+    try:
+        privs, vset = make_validators(N_LANES)
+        block_id = make_block_id(b"issue-24")
+        warm = make_commit(block_id, 7, 0, vset, privs)
+        validation.verify_commit(CHAIN_ID, vset, block_id, 7, warm)
+        commit = make_commit(block_id, 8, 0, vset, privs)
+        tracing.tracer.set_metrics_observer(None)
+        tracing.configure("ring")
+        tracing.tracer.clear()
+        validation.verify_commit(CHAIN_ID, vset, block_id, 8, commit)
+        events = _complete_events(tracing.tracer.export(clear=True))
+        tracing.configure("off")
+        recorded = tracing.tracer.recorded
+        off_span = tracing.span("verify_commit", sigs=N_LANES)
+        commit = make_commit(block_id, 9, 0, vset, privs)
+        validation.verify_commit(CHAIN_ID, vset, block_id, 9, commit)
+        off = {
+            "span": off_span,
+            "recorded_during": tracing.tracer.recorded - recorded,
+            "ring": len(tracing.tracer),
+            "gc_hooked": tracing.tracer._gc_hook in gc.callbacks,
+            "jax_hooked": tracing.tracer._jax_hooked,
+        }
+    finally:
+        tracing.configure("off")
+        tracing.tracer.clear()
+        precompute.reset()
+        resident_mod.store.clear()
+        mp.undo()
+    return {"events": events, "off": off}
+
+
+@pytest.mark.parametrize(
+    "name,parent",
+    [
+        ("note_validator_set", "verify_commit"),
+        ("build_lanes", "verify_commit"),
+        ("batch_verify", "verify_commit"),
+        ("merge_verdicts", "verify_commit"),
+        ("verify_batch", "batch_verify"),
+        ("route_lanes", "verify_batch"),
+        ("resident_acquire", "route_lanes"),
+        ("cache_store", "verify_batch"),
+        ("merge_results", "verify_batch"),
+    ],
+)
+def test_commit_yields_each_new_span_once_under_its_parent(
+    commit_capture, name, parent
+):
+    found = [e for e in commit_capture["events"] if e["name"] == name]
+    assert len(found) == 1, [e["name"] for e in commit_capture["events"]]
+    (ev,) = found
+    assert ev["args"]["parent"] == parent
+    by_id = {e["span_id"]: e for e in commit_capture["events"]}
+    up = by_id[ev["parent_span_id"]]
+    assert up["name"] == parent
+    # same thread, inside the parent's interval
+    assert up["tid"] == ev["tid"]
+    assert up["ts"] <= ev["ts"]
+    assert ev["ts"] + ev["dur"] <= up["ts"] + up["dur"] + 1e-3
+
+
+@pytest.mark.parametrize(
+    "name,args",
+    [
+        ("note_validator_set", {"validators": N_LANES, "newly_active": False}),
+        ("build_lanes", {"lanes": N_LANES}),
+        ("batch_verify", {"key_type": "ed25519", "lanes": N_LANES, "route": "device"}),
+        ("merge_verdicts", {"lanes": N_LANES}),
+        ("route_lanes", {"lanes": N_LANES, "resident": N_LANES, "tables": 0, "legacy": 0, "jobs": 1}),
+        ("resident_acquire", {"lanes": N_LANES, "hits": N_LANES, "misses": 0}),
+        ("cache_store", {"lanes": N_LANES}),
+        ("merge_results", {"lanes": N_LANES}),
+        ("dispatch_chunk", {"lanes": N_LANES, "padded_lanes": 64, "kind": "resident"}),
+        ("collect_chunk", {"lanes": N_LANES, "d2h_bytes": 64}),
+    ],
+)
+def test_commit_span_arguments(commit_capture, name, args):
+    (ev,) = [e for e in commit_capture["events"] if e["name"] == name]
+    assert {k: ev["args"].get(k) for k in args} == args
+
+
+def test_build_lanes_phase_totals(commit_capture):
+    (ev,) = [e for e in commit_capture["events"] if e["name"] == "build_lanes"]
+    a = ev["args"]
+    assert a["sign_bytes_n"] == a["batch_add_n"] == a["lanes"] == N_LANES
+    assert a["sign_bytes_us"] > 0 and a["batch_add_us"] > 0
+    assert a["sign_bytes_us"] + a["batch_add_us"] <= ev["dur"]
+    # the index path never looks a validator up by address
+    assert "val_lookup_us" not in a and "val_lookup_n" not in a
+
+
+def test_commit_emits_few_events(commit_capture):
+    """No event per lane: the ring holds 4,096 and a 10,000-lane call
+    must fit as this one does."""
+    assert len(commit_capture["events"]) < 60
+    per_lane = [
+        e for e in commit_capture["events"]
+        if e["name"] in ("sign_bytes", "batch_add", "val_lookup")
+    ]
+    assert per_lane == []
+
+
+def test_off_mode_leaves_nothing_behind(commit_capture):
+    off = commit_capture["off"]
+    assert off["span"] is tracing.NOP_SPAN
+    assert off["recorded_during"] == 0 and off["ring"] == 0
+    assert off["gc_hooked"] is False and off["jax_hooked"] is False
+    assert tracing.NOP_SPAN.live is False
+    f = len
+    assert tracing.NOP_SPAN.timed("sign_bytes", f) is f
+
+
+def test_off_mode_holds_no_jax_listener():
+    import gc
+
+    from jax._src import monitoring
+
+    tracing.configure("ring")
+    try:
+        with tracing.span("hook"):
+            pass
+        assert tracing.tracer._gc_hook in gc.callbacks
+        listeners = monitoring.get_event_duration_listeners()
+        assert tracing.tracer._jax_hook in listeners
+    finally:
+        tracing.configure("off")
+        tracing.tracer.clear()
+    assert tracing.tracer._gc_hook not in gc.callbacks
+    assert tracing.tracer._jax_hook not in monitoring.get_event_duration_listeners()
+    assert tracing.tracer._annotate("x") is None
+
+
+def test_timed_phase_accumulates_on_the_span(ring):
+    with tracing.span("loop", lanes=3) as sp:
+        step = sp.timed("step", lambda x: x + 1)
+        assert [step(i) for i in range(3)] == [1, 2, 3]
+        sp.timed("never", len)  # wrapped, not called: left out
+    (ev,) = _complete_events(ring.export())
+    assert ev["args"]["step_n"] == 3
+    assert 0 <= ev["args"]["step_us"] <= ev["dur"]
+    assert "never_n" not in ev["args"]
+
+
+def test_gc_collect_inside_a_span_yields_nested_gc_pause(ring, monkeypatch):
+    import gc
+
+    monkeypatch.setattr(tracing, "EXTERNAL_SPAN_MIN_S", 0.0)
+    with tracing.span("stalled") as sp:
+        gc.collect()
+    events = _complete_events(ring.export())
+    pauses = [
+        e for e in events
+        if e["name"] == "gc_pause" and e["args"].get("parent") == "stalled"
+    ]
+    assert pauses, [e["name"] for e in events]
+    full = [e for e in pauses if e["args"]["generation"] == 2]
+    assert len(full) == 1
+    assert full[0]["args"]["collected"] >= 0
+    assert full[0]["parent_span_id"] == sp.span_id
+    (outer,) = [e for e in events if e["name"] == "stalled"]
+    assert outer["ts"] <= full[0]["ts"]
+    assert full[0]["ts"] + full[0]["dur"] <= outer["ts"] + outer["dur"] + 1e-3
+
+
+def test_short_external_intervals_are_dropped(ring):
+    ring._record_interval("gc_pause", 1.0, 1.0 + tracing.EXTERNAL_SPAN_MIN_S / 2, {})
+    assert len(ring) == 0
+    ring._record_interval("gc_pause", 1.0, 1.0 + tracing.EXTERNAL_SPAN_MIN_S * 2, {})
+    assert len(ring) == 1
+
+
+def test_first_call_of_a_fresh_jit_yields_xla_compile_child(ring, monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(tracing, "EXTERNAL_SPAN_MIN_S", 0.0)
+    fresh = jax.jit(lambda x: x * 3 + 1)
+    with tracing.span("first_call") as sp:
+        fresh(jnp.arange(5)).block_until_ready()
+    with tracing.span("second_call"):
+        fresh(jnp.arange(5)).block_until_ready()
+    events = _complete_events(ring.export())
+    compiles = [e for e in events if e["name"] == "xla_compile"]
+    assert compiles
+    assert {e["args"]["parent"] for e in compiles} == {"first_call"}
+    assert all(e["parent_span_id"] == sp.span_id for e in compiles)
+    kinds = {e["args"]["event"] for e in compiles}
+    assert "/jax/core/compile/backend_compile_duration" in kinds
+    assert "/jax/core/compile/jaxpr_trace_duration" in kinds
+
+
+def _raw_lanes(n):
+    from tendermint_tpu.crypto.keys import Ed25519PrivKey
+
+    pks, msgs, sigs = [], [], []
+    for i in range(n):
+        priv = Ed25519PrivKey.from_seed(bytes([i + 1]) * 32)
+        msg = b"h2d-%d" % i
+        pks.append(priv.pub_key().bytes())
+        msgs.append(msg)
+        sigs.append(priv.sign(msg))
+    return pks, msgs, sigs
+
+
+@pytest.mark.parametrize("kind", ["legacy_dispatch", "resident_inputs"])
+def test_h2d_bytes_is_the_summed_nbytes_of_the_chunk(ring, kind):
+    import numpy as np
+
+    from tendermint_tpu.ops import ed25519_batch
+
+    if kind == "resident_inputs":
+        # hand-built: what _prep_resident_chunk hands over, at 64 lanes
+        inputs = dict(
+            store=object(),  # on the device already: not counted
+            mesh_key=None,
+            idx=np.zeros(64, dtype=np.int32),
+            ok=np.ones(64, dtype=np.uint8),
+            r=np.zeros((64, 32), dtype=np.uint8),
+            s=np.zeros((64, 32), dtype=np.uint8),
+            k=np.zeros((64, 32), dtype=np.uint8),
+        )
+        assert ed25519_batch._chunk_h2d_bytes(inputs) == 64 * 4 + 64 + 3 * 64 * 32
+        return
+    pks, msgs, sigs = _raw_lanes(3)
+    inputs, _ = ed25519_batch.prepare_batch(pks, msgs, sigs)
+    want = sum(a.nbytes for a in inputs.values())
+    assert want == 4 * 64 * 32  # pk, r, s, k padded to the 64-lane bucket
+    assert ed25519_batch.verify_batch(pks, msgs, sigs) == [True] * 3
+    (ev,) = [e for e in _complete_events(ring.export()) if e["name"] == "dispatch_chunk"]
+    assert ev["args"]["h2d_bytes"] == want
+    assert ev["args"]["padded_lanes"] == 64 and ev["args"]["lanes"] == 3
+
+
+def test_profiler_capture_holds_the_program_spans(ring, tmp_path):
+    """One clock with the device trace: a jax.profiler capture taken
+    while the tracer records shows the program's spans as host
+    annotations, beside the operations XLA ran."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from tendermint_tpu.ops import ed25519_batch
+
+    pks, msgs, sigs = _raw_lanes(3)
+    ed25519_batch.verify_batch(pks, msgs, sigs)  # compile outside the capture
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        assert ed25519_batch.verify_batch(pks, msgs, sigs) == [True] * 3
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    names = set()
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                names.update(e.name for e in line.events)
+    assert {"verify_batch", "prep_chunk", "dispatch_chunk", "collect_chunk"} <= names
