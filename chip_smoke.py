@@ -282,15 +282,18 @@ def _check_dispatch(spans: list, sent: dict, what: str) -> None:
 
 def _compiles(spans: list, impl: str) -> list:
     """(implementation, kernel, lanes, seconds) for each kernel the
-    window compiled (or loaded from the cache). The legacy and table
-    kernels must be the implementation ``auto`` resolved to."""
+    window compiled (or loaded from the cache). On one device the
+    legacy, table and resident kernels must be the implementation
+    ``auto`` resolved to. The sharded kernels are exempt: they are the
+    XLA graph only and record no ``kernel_compile`` span (they show
+    under ``sharded_xla``)."""
     out = []
     for e in spans:
         if e["name"] != "kernel_compile":
             continue
         a = e["args"]
         ran = "pallas" if a.get("engine") == "pallas" else "xla"
-        if a.get("kernel") in ("verify", "verify_tables"):
+        if a.get("kernel") in ("verify", "verify_tables", "verify_resident"):
             check(
                 ran == ("pallas" if impl == "pallas" else "xla"),
                 "active_impl() is %r but the %s kernel at %s lanes ran %r",

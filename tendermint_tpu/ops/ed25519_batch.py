@@ -455,15 +455,14 @@ def _compiled_kernel_resident(n: int, backend: Optional[str], mul_impl: str = "v
 # caller's back.
 
 _IMPL_ENV = "TENDERMINT_TPU_VERIFY_IMPL"
-# What ``auto`` means per platform. tpu: both Pallas entry points
+# What ``auto`` means per platform. tpu: the Pallas entry points
 # compiled on a TPU v5e at 64/256/1024/4096 lanes and agreed with the
 # host oracle lane for lane (PR 21's chip run; chip_smoke.py repeats
 # it). CPU stays on the XLA graph (Pallas interpret mode is a test
 # vehicle, far too slow for real batches). Note what ``pallas`` covers:
-# legacy and gathered-table chunks on one device. Resident-store chunks
-# (_run_chunk_resident) and every mesh-sharded chunk
-# (parallel/sharding.py) have only an XLA-graph kernel and run that
-# whatever this says.
+# legacy, gathered-table and resident-store chunks on one device. Every
+# mesh-sharded chunk (parallel/sharding.py) has only an XLA-graph
+# kernel and runs that whatever this says.
 _AUTO_IMPL = {"tpu": "pallas", "cpu": "xla"}
 # Device-vs-host fallback state lives in ops/device_policy.py, shared
 # with the sr25519 engine so a broken backend is broken once.
@@ -569,7 +568,10 @@ def _run_chunk_tables(inputs: dict, backend: Optional[str], plan=None):
 def _run_chunk_resident(inputs: dict, backend: Optional[str], plan=None):
     """Dispatch one padded resident-store chunk: only gather indices
     ship per batch, the table tensor already lives on device. Same
-    ``(result, plan_used)`` contract as :func:`_run_chunk`.
+    ``(result, plan_used)`` contract as :func:`_run_chunk`, and on one
+    device the same choice by ``active_impl``: ``pallas`` gathers on
+    the device and runs the Pallas table kernel, anything else the XLA
+    resident graph. A sharded chunk runs the XLA graph whatever it says.
 
     The store tensor is committed to the context it was uploaded for
     (one mesh, or one single device). When that context is gone —
@@ -607,6 +609,10 @@ def _run_chunk_resident(inputs: dict, backend: Optional[str], plan=None):
             jnp.asarray(inputs["s"]),
             jnp.asarray(inputs["k"]),
         )
+        if impl == "pallas":
+            from tendermint_tpu.ops import pallas_verify
+
+            return pallas_verify.compiled_verify_resident(m)(*args), None
         return _compiled_kernel_resident(m, backend, mul_impl)(*args), None
     # Context mismatch: materialize the needed columns and take the
     # gathered-table kernel (counted as real per-batch table H2D).
@@ -979,6 +985,13 @@ def _chunk_h2d_bytes(inputs: dict) -> int:
     )
 
 
+def _chunk_impl(backend: Optional[str], plan_used) -> str:
+    """The implementation a dispatched chunk was handed to: the sharded
+    kernels are the XLA graph only (parallel/sharding.py); on one
+    device every runner follows :func:`active_impl`."""
+    return "xla" if plan_used is not None else active_impl(backend)
+
+
 def _mesh_collect_retry(job: "_Job", backend: Optional[str], exc: Exception):
     """A sharded chunk died at materialization. If the failure is
     attributable to one device, exclude it, rebuild a smaller mesh, and
@@ -1275,6 +1288,8 @@ def _verify_uncached(
                                 h2d_bytes=_chunk_h2d_bytes(inputs),
                             )
                         job.out, job.plan = runner(inputs, backend, plan)
+                        if dsp.live:
+                            dsp.set(impl=_chunk_impl(backend, job.plan))
                     if job.plan is not None:
                         mesh_used = True
                         if job.plan is not plan:

@@ -446,6 +446,7 @@ def _exec_cache_entries() -> Dict[str, int]:
             out["pallas"] = (
                 pl.compiled_verify.cache_info().currsize
                 + pl.compiled_verify_tables.cache_info().currsize
+                + pl.compiled_verify_resident.cache_info().currsize
             )
         except Exception:
             pass  # cache introspection is best-effort; report what we can

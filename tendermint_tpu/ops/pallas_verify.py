@@ -24,10 +24,12 @@ table are MXU matmuls (exact: both operands are small integers); the
 per-lane table lives in an ``(8, 128, block)`` VMEM scratch — half the
 footprint and half the select bandwidth of the unsigned scheme.
 
-Two entry points: :func:`compiled_verify` builds the lane tables
+Three entry points: :func:`compiled_verify` builds the lane tables
 in-kernel; :func:`compiled_verify_tables` takes the gathered
 ``(8, 4, 32, N)`` table input from the validator-set precompute cache
-(ops/precompute.py) and skips decompression of A and the table build.
+(ops/precompute.py) and skips decompression of A and the table build;
+:func:`compiled_verify_resident` gathers that input on the device from
+the resident store (ops/resident.py) and runs the same table kernel.
 
 Reference semantics: crypto/ed25519/ed25519.go:24-31 (ZIP-215 verify
 options), crypto/ed25519/ed25519.go:198-233 (batch verifier),
@@ -651,6 +653,19 @@ def verify_tables_fn(tab, a_ok, r_bytes, s_bytes, k_bytes, *, block: int, interp
     return out[0] != 0.0
 
 
+def verify_resident_fn(
+    tab_store, idx, a_ok, r_bytes, s_bytes, k_bytes, *, block: int, interpret: bool
+):
+    """Resident-store path: (8, 4, 32, K) uint8 store + (N,) int32
+    column indices (pad lanes index column 0) -> (N,) bool. The gather
+    runs on the device ahead of the kernel, so a batch ships 4 bytes a
+    lane of table input; everything after it is :func:`verify_tables_fn`."""
+    tab = jnp.take(tab_store, idx, axis=3)
+    return verify_tables_fn(
+        tab, a_ok, r_bytes, s_bytes, k_bytes, block=block, interpret=interpret
+    )
+
+
 def _trace_first_call(fn, kernel: str, n: int):
     """Wrap a jitted kernel so its FIRST invocation — the one that pays
     Pallas trace + XLA compile — records a ``kernel_compile`` span;
@@ -707,5 +722,22 @@ def compiled_verify_tables(n: int, block: int = BLOCK, interpret: bool = False):
             )
         ),
         "verify_tables",
+        n,
+    )
+
+
+@lru_cache(maxsize=8)
+def compiled_verify_resident(n: int, block: int = BLOCK, interpret: bool = False):
+    """Jitted resident-store verify for a fixed padded batch size n;
+    jit re-traces per store width K, as the XLA entry does."""
+    blk = min(block, n)
+    assert n % blk == 0, (n, blk)
+    return _trace_first_call(
+        jax.jit(
+            lambda store, idx, ok, r, s, k: verify_resident_fn(
+                store, idx, ok, r, s, k, block=blk, interpret=interpret
+            )
+        ),
+        "verify_resident",
         n,
     )

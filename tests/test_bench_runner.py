@@ -36,7 +36,14 @@ def bench_env(monkeypatch, tmp_path):
     monkeypatch.setenv("BENCH_HOST_REF_SIGS", "4")
     monkeypatch.delenv("BENCH_SECTIONS", raising=False)
     monkeypatch.delenv("BENCH_CHAOS", raising=False)
-    return {"partial": str(partial)}
+    yield {"partial": str(partial)}
+    # runner.run() installs the process-wide flight recorder; left in,
+    # it records every span of whatever test this worker runs next
+    # (tpusan then reports its pre-sanitizer lock as a data race)
+    from tendermint_tpu.libs import flightrec
+
+    flightrec.recorder.uninstall()
+    os.environ.pop(flightrec.DIR_ENV, None)
 
 
 # --- registry ----------------------------------------------------------------
@@ -298,7 +305,9 @@ def test_heartbeat_silence_triggers_watchdog_kill(bench_env, monkeypatch):
     """A section that goes silent (sleeping child) dies by heartbeat
     watchdog within the configured window — long before the 60s wall
     budget — and lands as ``timeout``."""
-    monkeypatch.setenv("BENCH_HEARTBEAT_TIMEOUT", "2")
+    # well above the child's own start-up: between its first beat and
+    # the section's it imports jax, ~2 s of CPU on this host
+    monkeypatch.setenv("BENCH_HEARTBEAT_TIMEOUT", "6")
     t0 = time.monotonic()
     merged, code = _run(("host_ref", "_chaos"), BENCH_CHAOS="hang")
     elapsed = time.monotonic() - t0

@@ -135,6 +135,28 @@ def test_failing_implementation_is_counted_not_switched(monkeypatch):
     assert snap["state"] == device_policy.DEGRADED
 
 
+@pytest.mark.parametrize("kernel", ["verify", "verify_tables", "verify_resident"])
+def test_compiles_holds_one_device_kernels_to_the_resolved_impl(kernel):
+    """A one-device kernel that ran another implementation than ``auto``
+    resolved to fails the smoke: the resident kernel too."""
+
+    def span(engine):
+        return {
+            "name": "kernel_compile",
+            "dur": 2.5e6,
+            "args": {"engine": engine, "kernel": kernel, "lanes": 256},
+        }
+
+    assert chip_smoke._compiles([span("pallas")], "pallas") == [
+        ["pallas", kernel, 256, 2.5]
+    ]
+    assert chip_smoke._compiles([span("ed25519")], "xla") == [
+        ["xla", kernel, 256, 2.5]
+    ]
+    with pytest.raises(chip_smoke.SmokeFailure, match=kernel + " kernel at 256"):
+        chip_smoke._compiles([span("ed25519")], "pallas")
+
+
 # --- §5: a compile cache that can be placed from outside ----------------------
 
 

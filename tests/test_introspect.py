@@ -275,6 +275,20 @@ class TestCompileAccounting:
         assert after.get("ed25519", 0) == before + 1
         assert calls == [1, 2, 3]
 
+    def test_pallas_exec_cache_counts_every_entry_point(self):
+        from tendermint_tpu.ops import pallas_verify
+
+        factories = (
+            pallas_verify.compiled_verify,
+            pallas_verify.compiled_verify_tables,
+            pallas_verify.compiled_verify_resident,
+        )
+        # builds the jitted wrapper; nothing compiles until it is called
+        pallas_verify.compiled_verify_resident(16, block=8, interpret=True)
+        want = sum(f.cache_info().currsize for f in factories)
+        assert pallas_verify.compiled_verify_resident.cache_info().currsize >= 1
+        assert introspect._exec_cache_entries()["pallas"] == want
+
     def test_counter_mirrors(self):
         ops = OpsMetrics(Registry())
         introspect.bind_metrics(ops)

@@ -139,6 +139,216 @@ def test_pallas_table_path_parity(batch8):
     assert pallas_verify_batch_tables(pks, msgs, sigs) == want
 
 
+def _resident_store(pks):
+    """The (8, 4, 32, K) uint8 tensor ops/resident.py uploads: column 0
+    the pad key's table, then one column a key."""
+    from tendermint_tpu.ops import precompute
+
+    tabs, oks = zip(*(precompute.build_table(pk) for pk in pks))
+    cols = [ed25519_batch._pad_table()] + list(tabs)
+    store = np.ascontiguousarray(np.stack(cols).transpose(1, 2, 3, 0))
+    return store, list(tabs), np.asarray(oks, dtype=np.uint8)
+
+
+def _resident_chunk(batch, n, pad):
+    """The first n lanes of a batch as _prep_resident_chunk hands them
+    over, padded to ``pad`` lanes, beside the gathered-table chunk of
+    the same lanes."""
+    pks, msgs, sigs = (list(x[:n]) for x in batch)
+    store, tabs, oks = _resident_store(pks)
+    res, host_ok = ed25519_batch._prep_resident_chunk(
+        pks, msgs, sigs, np.arange(1, n + 1), oks, store, None, pad_to=pad
+    )
+    gathered, _ = ed25519_batch._prep_table_chunk(
+        pks, msgs, sigs, tabs, list(oks), pad_to=pad
+    )
+    return res, gathered, host_ok
+
+
+def _resident_args(inputs):
+    return (
+        jnp.asarray(inputs["store"]),
+        jnp.asarray(inputs["idx"]),
+        jnp.asarray(inputs["ok"]),
+        jnp.asarray(inputs["r"]),
+        jnp.asarray(inputs["s"]),
+        jnp.asarray(inputs["k"]),
+    )
+
+
+@pytest.mark.slow  # the table kernel's interpret-mode compile, as above
+def test_pallas_resident_path_parity(batch8):
+    """The resident entry: same edge lanes as the table path, seven
+    lanes so that the eighth is a pad lane on column 0."""
+    pks, msgs, sigs = (list(x) for x in batch8)
+    pks[0] = bytes([2] + [0] * 31)  # off-curve: identity table, ok=False
+    sigs[1] = sigs[1][:33] + bytes([sigs[1][33] ^ 1]) + sigs[1][34:]
+    msgs[2] = b"tampered"
+    pks[3] = (ref.P + 1).to_bytes(32, "little")  # non-canonical encoding
+    want = [ref.verify_zip215(pk, m, s) for pk, m, s in zip(pks, msgs, sigs)]
+    inputs, _, host_ok = _resident_chunk((pks, msgs, sigs), 7, 8)
+    fn = pallas_verify.compiled_verify_resident(8, block=8, interpret=True)
+    out = np.asarray(fn(*_resident_args(inputs)))
+    assert list(np.logical_and(out[:7], host_ok)) == want[:7]
+    assert out[7]  # the pad lane verifies: it can mask nothing
+
+
+@pytest.fixture
+def resident_entry(batch8, monkeypatch):
+    """One first call of the resident entry point with the kernel behind
+    it stubbed (no Pallas compile): what reached ``verify_tables_fn``,
+    the events of the call, and the program jit was given."""
+    import jax
+
+    from tendermint_tpu.libs import tracing
+
+    def kernel_inputs(tab, a_ok, r, s, k, *, block, interpret):
+        n = r.shape[0]
+        # the layout verify_tables_fn hands _verify_tables_kernel
+        return tab.astype(jnp.float32).reshape(8, 4 * pallas_verify.NLIMBS, n), a_ok
+
+    jitted = []
+    real_jit = jax.jit
+
+    def spy_jit(fun, *args, **kwargs):
+        jitted.append(real_jit(fun, *args, **kwargs))
+        return jitted[-1]
+
+    monkeypatch.setattr(pallas_verify, "verify_tables_fn", kernel_inputs)
+    monkeypatch.setattr(jax, "jit", spy_jit)
+    inputs, gathered, _ = _resident_chunk(batch8, 5, 8)
+    args = _resident_args(inputs)
+    # past the lru_cache: a stubbed program must not stay in it
+    fn = pallas_verify.compiled_verify_resident.__wrapped__(8)
+    monkeypatch.setattr(jax, "jit", real_jit)
+    from tendermint_tpu.ops import introspect
+
+    def counted():
+        return introspect.accountant.snapshot()["compile_events"].get("pallas", 0)
+
+    before = counted()
+    tracing.tracer.set_metrics_observer(None)
+    tracing.configure("ring")
+    tracing.tracer.clear()
+    try:
+        first = fn(*args)
+        second = fn(*args)
+        events = [
+            e for e in tracing.tracer.export(clear=True)["traceEvents"]
+            if e.get("ph") == "X"
+        ]
+    finally:
+        tracing.configure("off")
+        tracing.tracer.clear()
+    (program,) = jitted
+    return {
+        "first": first, "second": second, "events": events,
+        "gathered": gathered, "program": program, "args": args,
+        "compile_events": counted() - before,
+    }
+
+
+def test_resident_entry_gathers_what_the_host_gather_ships(resident_entry):
+    """take + reshape on the device == _prep_table_chunk's tensor in the
+    kernel's (8, 128, n) layout, pad lanes (column 0) included."""
+    gathered = resident_entry["gathered"]
+    want = gathered["tab"].astype(np.float32).reshape(8, 128, 8)
+    for tab, a_ok in (resident_entry["first"], resident_entry["second"]):
+        np.testing.assert_array_equal(np.asarray(tab), want)
+        np.testing.assert_array_equal(np.asarray(a_ok), gathered["ok"])
+    # lanes 5..7 are pad lanes: the pad key's table, a_ok true
+    pad = ed25519_batch._pad_table().astype(np.float32).reshape(8, 128)
+    np.testing.assert_array_equal(want[:, :, 7], pad)
+
+
+def test_resident_entry_keeps_the_names_the_benchmark_reads(resident_entry):
+    """chipbench finds the padded width by ``kernel="verify_resident"``
+    and the program's device time by the name ``jit__lambda*``."""
+    import fnmatch
+    import re
+
+    compiles = [e for e in resident_entry["events"] if e["name"] == "kernel_compile"]
+    assert len(compiles) == 1  # the first call only
+    assert resident_entry["compile_events"] == 1
+    a = compiles[0]["args"]
+    assert (a["kernel"], a["engine"], a["lanes"]) == ("verify_resident", "pallas", 8)
+    text = resident_entry["program"].lower(*resident_entry["args"]).as_text()
+    name = re.search(r"module @(\S+)", text).group(1)
+    assert fnmatch.fnmatch(name, "jit__lambda*"), name
+
+
+def _stub_kernel(calls, name):
+    def factory(n, *args, **kwargs):
+        def kernel(*kargs):
+            calls.append((name, n, kargs))
+            return "verdicts of " + name
+
+        return kernel
+
+    return factory
+
+
+@pytest.mark.parametrize("case", ["pallas", "xla", "mxu", "mesh", "context_mismatch"])
+def test_run_chunk_resident_picks_the_kernel(batch8, monkeypatch, case):
+    """On one device the resident runner follows active_impl like the
+    other two; a sharded chunk and a store committed elsewhere never
+    reach the Pallas entry."""
+    from types import SimpleNamespace
+
+    from tendermint_tpu.parallel import sharding
+
+    calls = []
+    impl = case if case in ("xla", "mxu") else "pallas"
+    monkeypatch.setattr(ed25519_batch, "active_impl", lambda backend=None: impl)
+    monkeypatch.setattr(
+        ed25519_batch, "_mul_impl_for_chunk", lambda impl, backend, lanes: "vpu"
+    )
+    monkeypatch.setattr(
+        pallas_verify, "compiled_verify_resident", _stub_kernel(calls, "pallas")
+    )
+    monkeypatch.setattr(
+        ed25519_batch, "_compiled_kernel_resident", _stub_kernel(calls, "xla")
+    )
+    monkeypatch.setattr(
+        ed25519_batch,
+        "_run_chunk_tables",
+        lambda inputs, backend, plan=None: (
+            calls.append(("tables", inputs["r"].shape[0], inputs)),
+            None,
+        ),
+    )
+    monkeypatch.setattr(
+        sharding,
+        "run_chunk_mesh",
+        lambda kind, inputs, mul_impl, plan, site: (
+            calls.append(("mesh", inputs["r"].shape[0], kind)),
+            plan,
+        ),
+    )
+    inputs, gathered, _ = _resident_chunk(batch8, 5, 8)
+    plan = None
+    if case == "mesh":
+        plan = SimpleNamespace(device_ids=(0, 1))
+        inputs["mesh_key"] = (0, 1)
+    elif case == "context_mismatch":
+        inputs["mesh_key"] = (0, 1)  # uploaded for a mesh that is gone
+    out, used = ed25519_batch._run_chunk_resident(inputs, None, plan)
+    ((name, n, got),) = calls
+    assert n == 8 and used is plan
+    if case == "mesh":
+        assert (name, got) == ("mesh", "resident")
+    elif case == "context_mismatch":
+        assert name == "tables"
+        np.testing.assert_array_equal(got["tab"], gathered["tab"])
+    else:
+        assert name == ("pallas" if case == "pallas" else "xla")
+        assert out == "verdicts of " + name
+        assert got[0] is inputs["store"]
+        for arg, key in zip(got[1:], ("idx", "ok", "r", "s", "k")):
+            assert arg.shape[0] == 8
+            np.testing.assert_array_equal(np.asarray(arg), inputs[key])
+
+
 def test_auto_resolves_to_one_impl_per_platform(monkeypatch):
     """``auto`` means exactly one implementation per platform (pallas on
     tpu, the XLA graph on cpu); the env switch overrides it."""

@@ -502,7 +502,7 @@ def test_commit_yields_each_new_span_once_under_its_parent(
         ("resident_acquire", {"lanes": N_LANES, "hits": N_LANES, "misses": 0}),
         ("cache_store", {"lanes": N_LANES}),
         ("merge_results", {"lanes": N_LANES}),
-        ("dispatch_chunk", {"lanes": N_LANES, "padded_lanes": 64, "kind": "resident"}),
+        ("dispatch_chunk", {"lanes": N_LANES, "padded_lanes": 64, "kind": "resident", "impl": "xla"}),
         ("collect_chunk", {"lanes": N_LANES, "d2h_bytes": 64}),
     ],
 )
@@ -661,6 +661,55 @@ def test_h2d_bytes_is_the_summed_nbytes_of_the_chunk(ring, kind):
     (ev,) = [e for e in _complete_events(ring.export()) if e["name"] == "dispatch_chunk"]
     assert ev["args"]["h2d_bytes"] == want
     assert ev["args"]["padded_lanes"] == 64 and ev["args"]["lanes"] == 3
+
+
+@pytest.mark.parametrize("mode", ["ring", "off"])
+def test_dispatch_chunk_names_the_implementation_only_when_live(mode, monkeypatch):
+    """``impl`` is an argument of the live span; with the tracer off the
+    engine does not even ask which implementation it was."""
+    from tendermint_tpu.ops import ed25519_batch
+
+    asked = []
+    real = ed25519_batch._chunk_impl
+
+    def counting(backend, plan_used):
+        asked.append(plan_used)
+        return real(backend, plan_used)
+
+    monkeypatch.setattr(ed25519_batch, "_chunk_impl", counting)
+    pks, msgs, sigs = _raw_lanes(3)
+    tracing.tracer.set_metrics_observer(None)
+    tracing.configure(mode)
+    tracing.tracer.clear()
+    try:
+        assert ed25519_batch.verify_batch(pks, msgs, sigs) == [True] * 3
+        events = _complete_events(tracing.tracer.export(clear=True))
+    finally:
+        tracing.configure("off")
+        tracing.tracer.clear()
+    if mode == "off":
+        assert asked == [] and events == []
+        return
+    (ev,) = [e for e in events if e["name"] == "dispatch_chunk"]
+    assert asked == [None]
+    assert ev["args"]["impl"] == ed25519_batch.active_impl() == "xla"
+
+
+@pytest.mark.parametrize(
+    "active,sharded,want",
+    [
+        ("pallas", False, "pallas"),
+        ("mxu", False, "mxu"),
+        ("xla", False, "xla"),
+        ("pallas", True, "xla"),  # the sharded kernels are the XLA graph only
+    ],
+)
+def test_chunk_impl_is_what_the_chunk_was_handed_to(monkeypatch, active, sharded, want):
+    from tendermint_tpu.ops import ed25519_batch
+
+    monkeypatch.setenv(ed25519_batch._IMPL_ENV, active)
+    plan_used = object() if sharded else None
+    assert ed25519_batch._chunk_impl(None, plan_used) == want
 
 
 def test_profiler_capture_holds_the_program_spans(ring, tmp_path):
