@@ -22,7 +22,10 @@ jax, but touches no device).
   device hashing on fixed-width sign-bytes), then a commit with a
   flipped ``R``, a flipped ``s`` byte and an ``s >= L`` whose failing
   lanes must be attributed exactly; beside them one direct
-  ``ops.verify_batch`` call on ZIP-215 edge vectors. Verdicts are
+  ``ops.verify_batch`` call on ZIP-215 edge vectors; then two blocksync
+  windows of 16 commits at 500 validators through
+  ``parallel/pipeline.verify_commits_pipelined`` (one sound, one with a
+  block tampered on both sides of its early exit). Verdicts are
   compared with the host oracle (``crypto/ed25519_ref.py``). The second
   run proves the compile cache: it may add no entry.
 - **served** (driven from the parent): ``python -m tendermint_tpu
@@ -69,6 +72,8 @@ SEED = 21
 # VerifyCommit p50 at (three 4096-lane chunks, ~10 MiB of resident tables).
 SIZES = (150, 10_000)
 HEIGHTS = 3  # heights verified over the same set after the cold pass
+SYNC_VALS = 500  # the blocksync window: BASELINE.json config 4's committee
+SYNC_WINDOW = 16  # blocksync/syncer.DEFAULT_VERIFY_WINDOW
 SERVED_VALS = 150
 CLIENTS = 4
 REQUESTS_PER_CLIENT = 3
@@ -459,7 +464,83 @@ def _run_size(n: int, heights: int, dev: dict, impl: str, paths: dict) -> dict:
     }
 
 
-def library_phase(expect_platform: str, sizes=SIZES, heights: int = HEIGHTS) -> dict:
+def _run_pipelined_windows(n: int, window: int, paths: dict, impl: str) -> dict:
+    """Two windows of ``window`` commits over one ``n``-validator set
+    through ``verify_commits_pipelined``, called as the block syncer
+    calls it. Light semantics: each block sends the votes that pass 2/3
+    and no more. One block of the second window is tampered as the
+    commits above are: the pipeline must refuse that block at the first
+    tampered commit index, accept the others, and never look at the
+    tampered signature past the block's early exit."""
+    from tendermint_tpu.parallel.pipeline import CommitTask, verify_commits_pipelined
+
+    what = "%d-commit windows at %d validators" % (window, n)
+    t0 = time.monotonic()
+    helpers, vset, commits = build_set(n, 2 * window)
+    setup_s = time.monotonic() - t0
+    quorum = n * 2 // 3 + 1  # equal powers, every validator signs
+    bad_block = window // 2
+    bad = commits[window + 1 + bad_block]
+    picks = tamper(bad)
+    bad_idx = min(picks)
+    check(
+        bad_idx < quorum <= max(picks),
+        "%s: tampered lanes %r do not lie on both sides of the early exit at %d",
+        what, sorted(picks), quorum,
+    )
+    before = _counters()
+    _drain_spans()
+    walls = []
+    for first in (1, window + 1):
+        tasks = [
+            CommitTask(helpers.CHAIN_ID, vset, commits[h].block_id, h, commits[h])
+            for h in range(first, first + window)
+        ]
+        t0 = time.monotonic()
+        verdicts = verify_commits_pipelined(tasks)
+        walls.append(round(time.monotonic() - t0, 2))
+        refused = {i: str(v.error) for i, v in enumerate(verdicts) if not v.ok}
+        if first == 1:
+            check(not refused, "%s: sound window refused %r", what, refused)
+        else:
+            check(
+                list(refused) == [bad_block]
+                and "(#%d)" % bad_idx in refused[bad_block],
+                "%s: refused %r, want block %d at commit index %d",
+                what, {i: e[:60] for i, e in refused.items()}, bad_block, bad_idx,
+            )
+    pks, msgs, sigs = commit_lanes(helpers, vset, bad)
+    _check_oracle(
+        pks, msgs, sigs, [i not in picks for i in range(n)], range(n), what
+    )
+    spans = _drain_spans()
+    d = _delta(before)
+    lanes = 2 * window * quorum
+    _check_dispatch(spans, {"resident" if paths["resident"] else "tables": lanes}, what)
+    _check_health(d, what)
+    if paths["resident"]:
+        check(
+            d["resident_hits"] == lanes and d["resident_misses"] == 0
+            and d["resident_uploads"] == 1,
+            "%s: resident store hit %d / missed %d of %d lanes in %d uploads",
+            what, d["resident_hits"], d["resident_misses"], lanes, d["resident_uploads"],
+        )
+    calls = [e for e in spans if e["name"] == "verify_commits_pipelined"]
+    check(
+        [c["args"]["lanes"] for c in calls] == [window * quorum] * 2,
+        "%s: pipeline spans %r", what, [c["args"] for c in calls],
+    )
+    return {
+        "validators": n, "window": window, "lanes_per_window": window * quorum,
+        "setup_s": round(setup_s, 2), "walls_s": walls, "counters": d,
+        "compiles": _compiles(spans, impl),
+    }
+
+
+def library_phase(
+    expect_platform: str, sizes=SIZES, heights: int = HEIGHTS,
+    sync=(SYNC_VALS, SYNC_WINDOW),
+) -> dict:
     """The library phase, in this process. Raises SmokeFailure."""
     from tendermint_tpu.ops import backend as ops_backend
 
@@ -539,6 +620,17 @@ def library_phase(expect_platform: str, sizes=SIZES, heights: int = HEIGHTS) -> 
                 "  lanes/device %(lanes_per_device)r sharded (xla) "
                 "%(sharded_xla)r" % rep
             )
+
+        if sync:
+            rep = _run_pipelined_windows(sync[0], sync[1], paths, impl)
+            report["pipelined"] = rep
+            say(
+                "%(window)d-commit windows at %(validators)d validators: "
+                "%(lanes_per_window)d lanes a window, set-up %(setup_s)ss, "
+                "walls %(walls_s)r" % rep
+            )
+            say("  counters %(counters)r" % rep)
+            say("  compiled %(compiles)r" % rep)
 
         snap = health.snapshot()
         check(snap["state"] == HEALTHY, "device health ended %r", snap["state"])

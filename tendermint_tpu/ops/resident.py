@@ -61,6 +61,10 @@ _HOT_TRACK_CAP = 4096
 # even on CPU (where the device upload never happens).
 TABLE_BYTES_PER_KEY = 8 * 4 * 32
 
+# Narrowest device tensor :meth:`ResidentTableStore.refresh` uploads, in
+# columns (the narrowest kernel bucket's lanes).
+_MIN_STORE_WIDTH = 64
+
 
 @instrument_attrs
 class ResidentTableStore:
@@ -140,16 +144,27 @@ class ResidentTableStore:
         mesh_key, backend_key = self._context_key(plan, backend)
         with self._lock:
             version = self._version
-        cols = [ed25519_batch._pad_table()]
+        pad = ed25519_batch._pad_table()
+        cols = [pad]
         oks = [True]
         index: Dict[bytes, int] = {}
         for pk, table, ok in snap:
             index[pk] = len(cols)
             cols.append(table)
             oks.append(ok)
+        # The kernels are compiled for the store's width, and the host
+        # cache holds a table only for keys some batch has carried: a
+        # light commit stops at 2/3, so a validator past that point
+        # shows up heights later, one key at a time. Widths are powers
+        # of two (pad-table columns behind the real ones), so that a
+        # key joining the store re-uploads it but recompiles nothing
+        # until the width doubles.
+        width = max(_MIN_STORE_WIDTH, 1 << (len(cols) - 1).bit_length())
+        cols += [pad] * (width - len(cols))
+        oks += [True] * (width - len(oks))
         host_tab = np.ascontiguousarray(
             np.stack(cols).transpose(1, 2, 3, 0)
-        )  # (8, 4, 32, K)
+        )  # (8, 4, 32, width)
         nbytes = int(host_tab.nbytes)
         try:
             with tracing.span(

@@ -68,7 +68,7 @@ def test_main_exits_nonzero_alone_in_a_directory(tmp_path):
 
 def test_phase_fails_on_the_wrong_platform():
     with pytest.raises(chip_smoke.SmokeFailure, match="want 'tpu'"):
-        chip_smoke.library_phase("tpu", sizes=())
+        chip_smoke.library_phase("tpu", sizes=(), sync=None)
 
 
 # --- the library phase's checks, live, on the CPU -----------------------------
@@ -79,7 +79,7 @@ def test_edge_vectors_phase_passes_on_cpu():
     ops.verify_batch, lane for lane against the oracle, lanes
     dispatched == lanes sent, health counters flat — on the 64-lane
     legacy kernel other suites compile anyway."""
-    report = chip_smoke.library_phase("cpu", sizes=())
+    report = chip_smoke.library_phase("cpu", sizes=(), sync=None)
     assert report["device"]["platform"] == "cpu"
     assert report["impl"] == "xla" and report["host_hash"] == "native"
     edge = report["edge"]
@@ -99,7 +99,7 @@ def test_kernel_failure_fails_the_phase_instead_of_passing_on_the_oracle(
     monkeypatch.setattr(ed25519_batch, "_compiled_kernel", boom)
     with pytest.warns(UserWarning, match="CPU fallback"):
         with pytest.raises(chip_smoke.SmokeFailure, match="host oracle"):
-            chip_smoke.library_phase("cpu", sizes=())
+            chip_smoke.library_phase("cpu", sizes=(), sync=None)
 
 
 def test_failing_implementation_is_counted_not_switched(monkeypatch):
@@ -205,12 +205,15 @@ def _auto_paths_on(monkeypatch):
 
 @pytest.mark.slow
 def test_library_phase_at_40_validators(_auto_paths_on):
-    report = chip_smoke.library_phase("cpu", sizes=(40,), heights=2)
+    report = chip_smoke.library_phase("cpu", sizes=(40,), heights=2, sync=(20, 4))
     (size,) = report["sizes"]
     c = size["counters"]
     assert c["hash_device_lanes"] == 40 * 5
     assert c["resident_hits"] == 40 * 4 and c["resident_uploads"] == 1
     assert c["gathered_h2d_bytes"] == 0 and c["fallback_batches"] == 0
+    p = report["pipelined"]
+    assert p["lanes_per_window"] == 4 * 14
+    assert p["counters"]["resident_hits"] == 2 * 4 * 14 and p["counters"]["fallback_batches"] == 0
     json.dumps(report)  # what the child writes for the parent
 
 

@@ -1,0 +1,175 @@
+"""The benchmark's part of the ``sync500`` deployment, without a chip:
+the plain light reference on hand-made blocks, the Pipeline metrics'
+files reduced on hand-made spans, ``BENCHMARK.json`` against its files,
+and the cell's tiny twin rehearsed end to end on the CPU (a rehearsal
+proves paths, never numbers).
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+from chipbench import reference_light, selftest, spec, workload
+
+ABSENT, COMMIT, NIL = 1, 2, 3
+
+
+def block(n, flags, bad=(), powers=None):
+    """(validators, signatures) signed by seeded keys; ``flags[i]`` is
+    validator i's; signatures at the indices in ``bad`` are tampered."""
+    signers = workload.make_signers(7, "ref-light", n)
+    validators = [(s.pub, (powers or [10] * n)[i]) for i, s in enumerate(signers)]
+    signatures = []
+    for i, (s, flag) in enumerate(zip(signers, flags)):
+        if flag == ABSENT:
+            signatures.append((ABSENT, b"", b""))
+            continue
+        msg = b"vote %d flag %d" % (i, flag)
+        sig = s.sign(msg)
+        signatures.append((flag, msg, workload.tamper_signature(sig, "s") if i in bad else sig))
+    return validators, signatures
+
+
+@pytest.mark.parametrize(
+    "flags,bad,powers,want",
+    [
+        # 6 equal votes: needed 40, the fifth passes it
+        ([COMMIT] * 6, (), None, reference_light.OK),
+        # the sixth is past the early exit: never looked at
+        ([COMMIT] * 6, (5,), None, reference_light.OK),
+        # an included one names its index in the commit
+        ([COMMIT] * 6, (4,), None, ("wrong signature", 4)),
+        # ... which an absent and a nil vote before it move off its lane
+        ([ABSENT, COMMIT, NIL, COMMIT, COMMIT, COMMIT, COMMIT, COMMIT], (5,), None, ("wrong signature", 5)),
+        # the first of two
+        ([COMMIT] * 6, (1, 3), None, ("wrong signature", 1)),
+        # exactly 2/3 is not more than 2/3, bad signature or not
+        ([COMMIT] * 4 + [NIL, ABSENT], (), None, reference_light.INSUFFICIENT),
+        ([COMMIT] * 4 + [NIL, ABSENT], (0,), None, reference_light.INSUFFICIENT),
+        # unequal powers: 50 of 60 pass 2/3 at the first vote
+        ([COMMIT] * 3, (1, 2), [50, 5, 5], reference_light.OK),
+    ],
+)
+def test_reference_light_verify_block(flags, bad, powers, want):
+    assert reference_light.verify_block(*block(len(flags), flags, bad, powers)) == want
+
+
+def test_reference_light_blocks_do_not_look_at_their_neighbours():
+    good = block(6, [COMMIT] * 6)
+    bad = block(6, [COMMIT] * 6, bad=(2,))
+    short = block(6, [COMMIT] * 3 + [ABSENT] * 3)
+    assert reference_light.verify_window([good, bad, short, good]) == [
+        reference_light.OK, ("wrong signature", 2), reference_light.INSUFFICIENT, reference_light.OK,
+    ]
+
+
+def test_benchmark_files_agree():
+    selftest.test_files()
+    real = spec.Spec(os.path.join(spec.ROOT, "BENCHMARK.json"))
+    cell = real.cell("sync500-catchup")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == ("sync500", "catchup-windows", 1)
+    config = real.config("sync500")
+    assert config["validators"] == 500 and config["verify_window"] == 16
+    assert list(config["reduced"]) == ["blocks"]
+    quorum = config["validators"] * 2 // 3 + 1
+    assert config["lanes_per_call"] == config["verify_window"] * quorum == 5344
+    from chipbench.generators import cycle_length
+
+    windows = cycle_length(real.traffic("catchup-windows"), 5344, 65536)
+    assert windows >= 14 and config["blocks"] == windows * 16
+    assert [m["name"] for m in real.metrics_for("end_to_end", "sync500-catchup")] == ["sigs_per_s", "setup_s"]
+
+
+def test_pipeline_metrics_add_up_on_nested_spans():
+    """One call: verify_commits_pipelined 0..1000 holding build_lanes
+    10..500 (phases 300 + 20; two note_validator_set spans of 40 inside
+    it), verify_batch 510..900, merge_verdicts 910..950."""
+
+    class Evidence:
+        calls = [{}]
+
+    def span(name, ts, dur, **args):
+        return {"name": name, "ts": float(ts), "dur": float(dur), "args": args}
+
+    ev = Evidence()
+    ev.spans = [
+        span("verify_commits_pipelined", 0, 1000, tasks=2, lanes=8),
+        span("build_lanes", 10, 490, lanes=8, sign_bytes_us=300.0, sign_bytes_n=8,
+             basic_checks_us=20.0, basic_checks_n=2),
+        span("note_validator_set", 20, 40), span("note_validator_set", 260, 40),
+        span("verify_batch", 510, 390),
+        span("merge_verdicts", 910, 40),
+    ]
+
+    def read(name):
+        doc = spec.layer_metric(name)
+        return spec.reader(doc["reader"]).read(ev, **doc["args"])
+
+    assert read("pipeline_host_ms") == pytest.approx(0.610)
+    assert read("sign_bytes_ms.sync") == pytest.approx(0.300)
+    assert read("note_set_ms.sync") == pytest.approx(0.080)
+    # gaps 10 + 10 + 10 + 50 = 80 outside the children; the loop's 490
+    # less its phases 320 and the 80 of the spans inside it = 90
+    assert read("pipeline_unnamed_ms") == pytest.approx(0.170)
+    named = 0.300 + 0.020 + 0.080 + 0.040  # sign-bytes, basic checks, note, merge
+    assert read("pipeline_unnamed_ms") + named == pytest.approx(read("pipeline_host_ms"))
+    # the parent's program records none of these spans: nothing to read
+    ev.spans = [span("verify_batch", 510, 390)]
+    for name in ("pipeline_host_ms", "sign_bytes_ms.sync", "pipeline_unnamed_ms"):
+        assert read(name) is None
+
+
+BENCH = os.path.join(spec.HERE, "testdata", "tiny-sync-benchmark.json")
+
+
+def rehearse(trace: int, *extra):
+    """(result line, standard output) of one rehearsal of the tiny twin."""
+    proc = subprocess.run(
+        [sys.executable, "-m", "chipbench.run", "--workload", "tiny-sync-catchup",
+         "--seed", str(2**31 + 26), "--seconds", "1", "--trace", str(trace),
+         "--rehearse", "--bench-file", BENCH, *extra],
+        cwd=spec.ROOT, capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stdout
+
+
+def test_tiny_twin_of_sync500_catchup_rehearses_on_the_cpu():
+    out, said = rehearse(1)
+    assert out["correct"] is True and out["failed"] == 0 and out["attempted"] > 0
+    tiny = spec.Spec(BENCH)
+    want = {m["name"] for m in tiny.metrics_for("per_layer", "tiny-sync-catchup")}
+    assert len(want) == 19
+    for name in want:
+        assert isinstance(out["metrics"][name]["value"], float), name
+    assert out["metrics"]["resident_hit_share.sync"]["value"] == 100.0
+    for name in ("verdict_cache_hits_in_window", "compilations_in_window", "timed_blocks_refused",
+                 "windows_with_a_wrong_block_verdict", "lanes_where_reference_disagrees"):
+        assert "compared: %s = 0 (limit 0)" % name in said, name
+
+
+@pytest.mark.parametrize(
+    "brk,over",
+    [
+        # one lane's verdict inverted where the engine returns it
+        ("flip_verdict", ["timed_blocks_refused", "windows_with_a_wrong_block_verdict",
+                          "lanes_where_reference_disagrees"]),
+        # the engine's s < L check off: the included s + L lane verifies
+        ("no_canonical_s", ["windows_with_a_wrong_block_verdict", "lanes_where_reference_disagrees"]),
+    ],
+)
+def test_tiny_twin_broken_on_purpose_comes_out_not_correct(brk, over):
+    """The controls (``breaks.py``) have to show in the cell's own
+    comparisons, not in the harness's two."""
+    out, said = rehearse(0, "--break", brk)
+    assert out["correct"] is False
+    assert over == [
+        ln.split("compared: ", 1)[1].split(" = ")[0]
+        for ln in said.splitlines() if ln.endswith("<-- over")
+    ]

@@ -93,7 +93,9 @@ class TestResidentBytes:
 
         # committee growth: the re-upload replaces the install; the
         # ledger must track the NEW tensor size, not accumulate
-        p2, m2, s2 = _batch(4, seed=120)
+        # (60 more keys: past the first upload's 64 columns, so the
+        # new tensor is really larger than the one it replaces)
+        p2, m2, s2 = _batch(60, seed=120)
         precompute.pin_pubkeys(p2)
         assert all(
             ed25519_batch.verify_batch(pks + p2, msgs + m2, sigs + s2)

@@ -108,6 +108,35 @@ def test_committee_growth_refreshes_store_once():
     assert _h2d_total() == before
 
 
+@pytest.mark.parametrize(
+    "first,then,widths",
+    [(4, 6, (64, 64)), (62, 63, (64, 64)), (63, 64, (64, 128))],
+    ids=["few", "fills-the-width", "crosses-the-width"],
+)
+def test_store_width_is_a_power_of_two_so_growth_rarely_changes_its_shape(first, then, widths):
+    """The kernels compile for the store's width. A key that joins the
+    store (a validator first carried heights after its set was seen: a
+    light commit stops at 2/3) must not change that width, or the next
+    batch waits for a compile; the width holds the pad column, doubles
+    when full, and the columns behind the real keys are pad tables."""
+    pks = [ref.keypair_from_seed(i.to_bytes(2, "big") * 16)[1] for i in range(then)]
+    has_table = np.ones(then, dtype=bool)
+    precompute.pin_pubkeys(pks[:first])
+    precompute.tables.gather(pks[:first])
+    got = resident.acquire(pks[:first], has_table[:first])
+    assert got is not None and got[3].shape == (8, 4, 32, widths[0])
+    precompute.pin_pubkeys(pks)
+    precompute.tables.gather(pks)
+    res_mask, idx, ok, tab_dev, _ = resident.acquire(pks, has_table)
+    assert tab_dev.shape == (8, 4, 32, widths[1]) and len(ok) == widths[1]
+    assert res_mask.all() and sorted(idx) == list(range(1, then + 1))
+    s = resident.stats()
+    assert s["uploads"] == 2 and s["resident_keys"] == then
+    pad = ed25519_batch._pad_table()
+    host = np.asarray(tab_dev)
+    assert (host[..., then + 1:] == pad[..., None]).all() and (host[..., 0] == pad).all()
+
+
 # --- invalidation in lockstep with the host cache ---------------------------
 
 
