@@ -14,15 +14,19 @@ PartSetHeader inside CanonicalBlockID.
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import Callable, Dict, NamedTuple, Optional
 
 from tendermint_tpu.encoding.proto import (
+    WIRE_BYTES,
+    WIRE_VARINT,
     encode_bytes_field,
     encode_message_field,
     encode_sfixed64_field,
     encode_string_field,
+    encode_varint,
     encode_varint_field,
     length_delimited,
+    tag,
 )
 
 # SignedMsgType values (proto/tendermint/types/types.proto)
@@ -69,24 +73,71 @@ def encode_canonical_block_id(
     return encode_bytes_field(1, hash_) + encode_message_field(2, psh, always=True)
 
 
-def canonical_vote_bytes(
-    chain_id: str,
-    msg_type: int,
-    height: int,
-    round_: int,
-    block_id: Optional[bytes],
-    timestamp: Timestamp,
-) -> bytes:
-    """Encoded CanonicalVote (NOT length-prefixed); ``block_id`` is the
-    pre-encoded canonical block ID or None."""
-    out = encode_varint_field(1, msg_type)
-    out += encode_sfixed64_field(2, height)
-    out += encode_sfixed64_field(3, round_)
-    if block_id is not None:
-        out += encode_message_field(4, block_id, always=True)
-    out += encode_message_field(5, timestamp.encode(), always=True)
-    out += encode_string_field(6, chain_id)
-    return out
+_NANOS_TAG = tag(2, WIRE_VARINT)  # Timestamp.nanos
+
+
+class VoteSignBytesEncoder:
+    """Sign-bytes of votes that share chain id, type, height and round
+    (the votes of one commit, or one vote).
+
+    What they share is encoded once here; a canonical block id once per
+    :meth:`for_block_id`; per vote only the timestamp, the two length
+    bytes that follow its width, and one join. The one encoding of
+    ``CanonicalVote`` in the tree: field order and presence as in
+    canonical.pb.go:590-640 (type 1, height 2 and round 3 as sfixed64,
+    block id 4 left out when nil, timestamp 5 always, chain id 6).
+    """
+
+    __slots__ = ("_head", "_tail", "prefixes")
+
+    def __init__(self, chain_id: str, msg_type: int, height: int, round_: int):
+        self._head = (
+            encode_varint_field(1, msg_type)
+            + encode_sfixed64_field(2, height)
+            + encode_sfixed64_field(3, round_)
+        )
+        self._tail = encode_string_field(6, chain_id)
+        self.prefixes = 0  # block-id prefixes built so far
+
+    def for_block_id(
+        self, block_id_hash: bytes, psh_total: int, psh_hash: bytes
+    ) -> Callable[[Timestamp], bytes]:
+        """``timestamp -> sign-bytes`` for this encoder's votes for one
+        block id (all-empty arguments: a nil vote)."""
+        self.prefixes += 1
+        bid = encode_canonical_block_id(block_id_hash, psh_total, psh_hash)
+        # everything up to the timestamp's own length byte
+        head = self._head
+        if bid is not None:
+            head += encode_message_field(4, bid, always=True)
+        head += tag(5, WIRE_BYTES)
+        tail = self._tail
+        rest = len(head) + 1 + len(tail)  # the message less the timestamp's body
+        # a commit's votes fall in a second or two: seconds -> its field
+        seconds_fields: Dict[int, bytes] = {}
+        # timestamp body length -> message length prefix + head + body
+        # length byte; a body is at most 22 bytes, so its length is one
+        fronts: Dict[int, bytes] = {}
+
+        def encode(timestamp: Timestamp) -> bytes:
+            seconds, nanos = timestamp
+            try:
+                seconds_field = seconds_fields[seconds]
+            except KeyError:
+                seconds_field = encode_varint_field(1, seconds)
+                seconds_fields[seconds] = seconds_field
+            if nanos:
+                body = seconds_field + _NANOS_TAG + encode_varint(nanos)
+            else:
+                body = seconds_field
+            n = len(body)
+            try:
+                front = fronts[n]
+            except KeyError:
+                front = fronts[n] = encode_varint(rest + n) + head + bytes((n,))
+            return front + body + tail
+
+        return encode
 
 
 def vote_sign_bytes(
@@ -100,10 +151,8 @@ def vote_sign_bytes(
     timestamp: Timestamp,
 ) -> bytes:
     """types.VoteSignBytes equivalent: delimited canonical vote."""
-    bid = encode_canonical_block_id(block_id_hash, psh_total, psh_hash)
-    return length_delimited(
-        canonical_vote_bytes(chain_id, msg_type, height, round_, bid, timestamp)
-    )
+    encoder = VoteSignBytesEncoder(chain_id, msg_type, height, round_)
+    return encoder.for_block_id(block_id_hash, psh_total, psh_hash)(timestamp)
 
 
 def proposal_sign_bytes(

@@ -171,6 +171,10 @@ def _plan_candidate(
         plan.fallback = True
         return plan
 
+    # both checks below read this commit's votes: what they share is
+    # encoded once for the two loops
+    sign_bytes = commit.sign_bytes_encoder(chain_id).lane
+
     # --- trusting check (verify_commit_light_trusting, batch path) ----------
     if not adjacent:
         try:
@@ -204,7 +208,7 @@ def _plan_candidate(
                 lanes.append(
                     (
                         val.pub_key.bytes(),
-                        commit.vote_sign_bytes(chain_id, idx),
+                        sign_bytes(idx),
                         cs.signature,
                     )
                 )
@@ -242,7 +246,7 @@ def _plan_candidate(
             lanes2.append(
                 (
                     val.pub_key.bytes(),
-                    commit.vote_sign_bytes(chain_id, idx),
+                    sign_bytes(idx),
                     cs.signature,
                 )
             )

@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import hashlib
 from dataclasses import dataclass, field as dc_field
-from typing import List, Optional
+from typing import Callable, Dict, List, Optional
 
 from tendermint_tpu.crypto import merkle
 from tendermint_tpu.crypto.keys import ADDRESS_LEN, PubKey
@@ -23,6 +23,7 @@ from tendermint_tpu.encoding.canonical import (
     SIGNED_MSG_TYPE_PREVOTE,
     SIGNED_MSG_TYPE_PROPOSAL,
     Timestamp,
+    VoteSignBytesEncoder,
     proposal_sign_bytes,
     vote_extension_sign_bytes,
     vote_sign_bytes,
@@ -299,6 +300,45 @@ class CommitSig:
 MAX_SIGNATURE_SIZE = 64  # ed25519/sr25519; secp256k1 is also 64 here
 
 
+class CommitSignBytes:
+    """Canonical sign-bytes of one commit's precommits, for one loop.
+
+    What the votes share (chain id, type, height, round) is encoded when
+    this is built, the canonical block id once per BlockIDFlag met (the
+    commit's own for COMMIT, none for NIL), and per lane only the
+    timestamp (encoding/canonical.VoteSignBytesEncoder). It reads the
+    commit as it is now and lives as long as its loop: nothing of it is
+    kept on the Commit, so the next pass does the work again.
+    """
+
+    __slots__ = ("_signatures", "_block_id", "_encoder", "_by_flag")
+
+    def __init__(self, commit: "Commit", chain_id: str):
+        self._signatures = commit.signatures
+        self._block_id = commit.block_id
+        self._encoder = VoteSignBytesEncoder(
+            chain_id, SIGNED_MSG_TYPE_PRECOMMIT, commit.height, commit.round
+        )
+        self._by_flag: Dict[int, Callable[[Timestamp], bytes]] = {}
+
+    @property
+    def prefixes(self) -> int:
+        """Shared prefixes built so far: one per BlockIDFlag met."""
+        return self._encoder.prefixes
+
+    def lane(self, val_idx: int) -> bytes:
+        """types/block.go:851-868: canonical sign-bytes for signature i."""
+        cs = self._signatures[val_idx]
+        try:
+            encode = self._by_flag[cs.block_id_flag]
+        except KeyError:
+            bid = cs.block_id(self._block_id)  # ValueError on an unknown flag
+            encode = self._by_flag[cs.block_id_flag] = self._encoder.for_block_id(
+                bid.hash, bid.part_set_header.total, bid.part_set_header.hash
+            )
+        return encode(cs.timestamp)
+
+
 @dataclass
 class Commit:
     """types/block.go:815-828; signatures ordered by validator index."""
@@ -326,20 +366,14 @@ class Commit:
             signature=cs.signature,
         )
 
+    def sign_bytes_encoder(self, chain_id: str) -> "CommitSignBytes":
+        """The sign-bytes of this commit's votes for one pass over them:
+        take it once before the loop, call its ``lane(idx)`` per vote."""
+        return CommitSignBytes(self, chain_id)
+
     def vote_sign_bytes(self, chain_id: str, val_idx: int) -> bytes:
         """types/block.go:851-868: canonical sign-bytes for signature i."""
-        cs = self.signatures[val_idx]
-        bid = cs.block_id(self.block_id)
-        return vote_sign_bytes(
-            chain_id,
-            SIGNED_MSG_TYPE_PRECOMMIT,
-            self.height,
-            self.round,
-            bid.hash,
-            bid.part_set_header.total,
-            bid.part_set_header.hash,
-            cs.timestamp,
-        )
+        return self.sign_bytes_encoder(chain_id).lane(val_idx)
 
     def validate_basic(self) -> None:
         if self.height < 0:

@@ -189,7 +189,8 @@ def _verify_commit_batch(
     # no tracing call, and on the no-op span these are the callables
     # themselves).
     with tracing.span("build_lanes") as lsp:
-        sign_bytes = lsp.timed("sign_bytes", commit.vote_sign_bytes)
+        encoder = commit.sign_bytes_encoder(chain_id)
+        sign_bytes = lsp.timed("sign_bytes", encoder.lane)
         batch_add = lsp.timed("batch_add", bv.add)
         val_lookup = lsp.timed("val_lookup", vals.get_by_address)
         for idx, commit_sig in enumerate(commit.signatures):
@@ -207,7 +208,7 @@ def _verify_commit_batch(
                         f"({seen_vals[val_idx]} and {idx})"
                     )
                 seen_vals[val_idx] = idx
-            vote_sign_bytes = sign_bytes(chain_id, idx)
+            vote_sign_bytes = sign_bytes(idx)
             try:
                 batch_add(val.pub_key, vote_sign_bytes, commit_sig.signature)
             except ValueError:
@@ -218,7 +219,7 @@ def _verify_commit_batch(
                 tallied += val.voting_power
             if not count_all_signatures and tallied > voting_power_needed:
                 break
-        lsp.set(lanes=len(batch_sig_idxs))
+        lsp.set(lanes=len(batch_sig_idxs), sign_bytes_prefixes=encoder.prefixes)
     if unbatchable:
         return _verify_commit_single(
             chain_id,
@@ -264,7 +265,7 @@ def _verify_commit_single(
     # The batch path's steps, less the batch: the same phase names on
     # one span, the signature checks themselves the span's own time.
     with tracing.span("single_verify", lanes=len(commit.signatures)) as lsp:
-        sign_bytes = lsp.timed("sign_bytes", commit.vote_sign_bytes)
+        sign_bytes = lsp.timed("sign_bytes", commit.sign_bytes_encoder(chain_id).lane)
         val_lookup = lsp.timed("val_lookup", vals.get_by_address)
         for idx, commit_sig in enumerate(commit.signatures):
             if ignore_sig(commit_sig):
@@ -281,7 +282,7 @@ def _verify_commit_single(
                         f"({seen_vals[val_idx]} and {idx})"
                     )
                 seen_vals[val_idx] = idx
-            vote_sign_bytes = sign_bytes(chain_id, idx)
+            vote_sign_bytes = sign_bytes(idx)
             if not val.pub_key.verify_signature(vote_sign_bytes, commit_sig.signature):
                 raise InvalidCommitError(
                     f"wrong signature (#{idx}): {commit_sig.signature.hex().upper()}"

@@ -17,6 +17,7 @@ from tendermint_tpu.encoding.canonical import (
 from tendermint_tpu.types import (
     BLOCK_ID_FLAG_ABSENT,
     BLOCK_ID_FLAG_COMMIT,
+    BLOCK_ID_FLAG_NIL,
     Block,
     BlockID,
     Commit,
@@ -107,6 +108,134 @@ class TestCommit:
         c.validate_basic()
         with pytest.raises(ValueError, match="nil block"):
             Commit(height=2, block_id=BlockID(), signatures=[]).validate_basic()
+
+
+class TestCommitSignBytes:
+    """ISSUE 27: what a commit's votes share is encoded once a loop and
+    the timestamp spliced per lane; nothing of it outlives the loop."""
+
+    ABSENT = {3, 40}
+    NIL = {5, 41, 63}
+
+    @pytest.fixture(scope="class")
+    def signed(self):
+        privs, vset = make_validators(64)
+        block_id = make_block_id(b"issue-27")
+        commit = make_commit(
+            block_id, 9, 1, vset, privs, absent=self.ABSENT, nil_votes=self.NIL
+        )
+        return vset, block_id, commit
+
+    @pytest.fixture
+    def block_ids_encoded(self, monkeypatch):
+        """Calls of the canonical block id's encoder, as (hash, ...)."""
+        from tendermint_tpu.encoding import canonical
+
+        calls = []
+        real = canonical.encode_canonical_block_id
+
+        def counting(*args):
+            calls.append(args)
+            return real(*args)
+
+        monkeypatch.setattr(canonical, "encode_canonical_block_id", counting)
+        return calls
+
+    @pytest.mark.parametrize(
+        "flag", [BLOCK_ID_FLAG_COMMIT, BLOCK_ID_FLAG_NIL], ids=["for_block", "nil"]
+    )
+    def test_one_lane_equals_the_encoders_bytes(self, signed, flag):
+        vset, _, commit = signed
+        encoder = commit.sign_bytes_encoder(CHAIN_ID)
+        lanes = [i for i, cs in enumerate(commit.signatures) if cs.block_id_flag == flag]
+        assert len(lanes) == (len(self.NIL) if flag == BLOCK_ID_FLAG_NIL else 59)
+        for i in lanes:
+            sign_bytes = encoder.lane(i)
+            assert sign_bytes == commit.vote_sign_bytes(CHAIN_ID, i)
+            assert sign_bytes == commit.get_vote(i).sign_bytes(CHAIN_ID)
+            assert vset.validators[i].pub_key.verify_signature(
+                sign_bytes, commit.signatures[i].signature
+            )
+        assert encoder.prefixes == 1
+
+    def test_a_pass_over_every_vote_builds_two_prefixes(self, signed, block_ids_encoded):
+        _, block_id, commit = signed
+        encoder = commit.sign_bytes_encoder(CHAIN_ID)
+        for i, cs in enumerate(commit.signatures):
+            if cs.block_id_flag != BLOCK_ID_FLAG_ABSENT:
+                encoder.lane(i)
+        assert encoder.prefixes == 2
+        assert sorted(args[0] for args in block_ids_encoded) == [b"", block_id.hash]
+
+    @pytest.mark.parametrize("through", ["vote_sign_bytes", "encoder"])
+    def test_unknown_block_id_flag_raises(self, through):
+        commit = Commit(
+            height=3, block_id=make_block_id(), signatures=[CommitSig(7, b"a" * 20, _ts(), b"s")]
+        )
+        with pytest.raises(ValueError, match="unknown BlockIDFlag: 7"):
+            if through == "encoder":
+                commit.sign_bytes_encoder(CHAIN_ID).lane(0)
+            else:
+                commit.vote_sign_bytes(CHAIN_ID, 0)
+
+    @pytest.mark.parametrize(
+        "entry,prefixes",
+        [
+            ("verify_commit", 2),  # nil votes are verified too
+            ("verify_commit_light", 1),
+            ("verify_commit_light_trusting", 1),
+            ("single", 2),
+            ("pipelined", 1),
+        ],
+    )
+    def test_each_call_encodes_the_block_id_once_and_keeps_nothing(
+        self, signed, block_ids_encoded, entry, prefixes
+    ):
+        from tendermint_tpu.parallel.pipeline import CommitTask, verify_commits_pipelined
+        from tendermint_tpu.types import validation
+
+        vset, block_id, commit = signed
+
+        def call():
+            if entry == "pipelined":
+                task = CommitTask(CHAIN_ID, vset, block_id, 9, commit)
+                (verdict,) = verify_commits_pipelined([task])
+                assert verdict.ok, verdict.error
+            elif entry == "verify_commit_light_trusting":
+                validation.verify_commit_light_trusting(
+                    CHAIN_ID, vset, commit, validation.Fraction(1, 3)
+                )
+            elif entry == "single":
+                validation._verify_commit_single(
+                    CHAIN_ID, vset, commit, vset.total_voting_power() * 2 // 3,
+                    lambda cs: cs.block_id_flag == BLOCK_ID_FLAG_ABSENT,
+                    lambda cs: cs.block_id_flag == BLOCK_ID_FLAG_COMMIT,
+                    True, True,
+                )
+            else:
+                getattr(validation, entry)(CHAIN_ID, vset, block_id, 9, commit)
+
+        def state():
+            return [sorted(vars(commit))] + [sorted(vars(cs)) for cs in commit.signatures]
+
+        before = state()
+        call()
+        assert len(block_ids_encoded) == prefixes
+        assert state() == before
+        # nothing was kept: the second call does the work again
+        call()
+        assert len(block_ids_encoded) == 2 * prefixes
+        assert state() == before
+
+    def test_the_encoder_reads_the_commit_as_it_is_when_built(self, signed):
+        _, _, commit = signed
+        before = commit.vote_sign_bytes(CHAIN_ID, 0)
+        commit.round += 1
+        try:
+            after = commit.vote_sign_bytes(CHAIN_ID, 0)
+        finally:
+            commit.round -= 1
+        assert after != before and commit.vote_sign_bytes(CHAIN_ID, 0) == before
 
 
 class TestVote:

@@ -19,6 +19,7 @@ import pytest
 from chipbench import reference_light, workload
 from tendermint_tpu.crypto.keys import Ed25519PrivKey
 from tendermint_tpu.libs import tracing
+from tendermint_tpu.parallel import pipeline as pipeline_mod
 from tendermint_tpu.parallel.pipeline import CommitTask, verify_commits_pipelined
 from tendermint_tpu.types import (
     BLOCK_ID_FLAG_ABSENT,
@@ -295,6 +296,8 @@ def test_pipeline_spans_nest_and_count_what_was_sent(sets, ring):
     # phase totals lie inside the loop's span
     a = loop["args"]
     assert a["basic_checks_n"] == 4 and a["sign_bytes_n"] == a["lanes"] == outer["args"]["lanes"]
+    # one encoder a task; its nil votes are skipped before they are encoded
+    assert a["sign_bytes_prefixes"] == 4
     assert a["sign_bytes_us"] + a["basic_checks_us"] + sum(s["dur"] for s in notes) <= loop["dur"]
     # every vote for a block was either sent or skipped past the early exit
     present = sum(
@@ -345,7 +348,11 @@ def test_tracer_off_the_pipeline_makes_three_tracing_calls_whatever_the_lanes(
         opened.append(name)
         return real(name, *args, **kwargs)
 
+    def no_wrapper(*args):
+        raise AssertionError("tracer off: the lane loop calls the encoder itself")
+
     monkeypatch.setattr(tracing, "span", counting)
+    monkeypatch.setattr(pipeline_mod, "partial", no_wrapper)
     assert pipelined(tasks) == [OK] * window
     ours = ("verify_commits_pipelined", "build_lanes", "note_validator_set", "merge_verdicts")
     assert [name for name in opened if name in ours] == [

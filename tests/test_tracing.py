@@ -495,7 +495,7 @@ def test_commit_yields_each_new_span_once_under_its_parent(
     "name,args",
     [
         ("note_validator_set", {"validators": N_LANES, "newly_active": False}),
-        ("build_lanes", {"lanes": N_LANES}),
+        ("build_lanes", {"lanes": N_LANES, "sign_bytes_prefixes": 1}),
         ("batch_verify", {"key_type": "ed25519", "lanes": N_LANES, "route": "device"}),
         ("merge_verdicts", {"lanes": N_LANES}),
         ("route_lanes", {"lanes": N_LANES, "resident": N_LANES, "tables": 0, "legacy": 0, "jobs": 1}),
@@ -540,6 +540,57 @@ def test_off_mode_leaves_nothing_behind(commit_capture):
     assert tracing.NOP_SPAN.live is False
     f = len
     assert tracing.NOP_SPAN.timed("sign_bytes", f) is f
+
+
+@pytest.mark.parametrize("n", [12, 48])
+def test_off_mode_the_lane_loop_calls_the_encoder_itself(monkeypatch, n):
+    """Tracer off, ``build_lanes`` hands its loop the commit encoder's
+    own bound method (no wrapper, no tracing call per lane) and opens
+    the same spans whatever the lanes."""
+    from tendermint_tpu.types import validation
+    from tendermint_tpu.types.block import CommitSignBytes
+
+    assert tracing.tracer.mode == "off"
+    privs, vset = make_validators(n)
+    block_id = make_block_id(b"issue-27-off")
+    commit = make_commit(block_id, 3, 0, vset, privs, nil_votes={1})
+    opened, phases = [], {}
+    real_span = tracing.span
+
+    def counting(name, *args, **kwargs):
+        opened.append(name)
+        return real_span(name, *args, **kwargs)
+
+    def timed(self, phase, fn):
+        phases[phase] = fn
+        return fn
+
+    monkeypatch.setattr(tracing, "span", counting)
+    monkeypatch.setattr(tracing._NopSpan, "timed", timed)
+    validation.verify_commit(CHAIN_ID, vset, block_id, 3, commit)
+    assert phases["sign_bytes"].__func__ is CommitSignBytes.lane
+    ours = ("note_validator_set", "build_lanes", "single_verify", "sign_bytes")
+    assert [name for name in opened if name in ours] == ["note_validator_set", "build_lanes"]
+    assert len(opened) < 40
+
+
+def test_build_lanes_counts_a_second_prefix_where_a_nil_vote_is_sent():
+    from tendermint_tpu.types import validation
+
+    privs, vset = make_validators(N_LANES)
+    block_id = make_block_id(b"issue-27-nil")
+    commit = make_commit(block_id, 4, 0, vset, privs, absent={2}, nil_votes={1, 9})
+    tracing.configure("ring")
+    tracing.tracer.clear()
+    try:
+        validation.verify_commit(CHAIN_ID, vset, block_id, 4, commit)
+        events = _complete_events(tracing.tracer.export(clear=True))
+    finally:
+        tracing.configure("off")
+        tracing.tracer.clear()
+    (loop,) = [e for e in events if e["name"] == "build_lanes"]
+    a = loop["args"]
+    assert (a["lanes"], a["sign_bytes_n"], a["sign_bytes_prefixes"]) == (63, 63, 2)
 
 
 def test_off_mode_holds_no_jax_listener():
