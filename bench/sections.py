@@ -158,7 +158,11 @@ def run_stages(beat) -> dict:
             from tendermint_tpu.ops import field32
 
             mul_impl = "mxu" if impl == "mxu" else field32.get_mul_impl()
-            fns.append(ed25519_batch._compiled_kernel(n_chunk, None, mul_impl))
+            fns.append(
+                ed25519_batch._compiled_kernel(
+                    ed25519_batch.KINDS["legacy"], n_chunk, None, mul_impl
+                )
+            )
     beat("kernel warmup")
     outs = [fn(*args) for fn, args in zip(fns, dev)]  # warmup/compile
     for o in outs:

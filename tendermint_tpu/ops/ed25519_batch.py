@@ -15,13 +15,17 @@ loop, so the computation is pure SIMD over the batch — the TPU analog
 of the reference's CPU multi-scalar batch verify
 (crypto/ed25519/ed25519.go:198-233, types/validation.go:154).
 
-Two kernel entry points: :func:`verify_kernel` decompresses A and
-builds the lane tables on device; :func:`verify_kernel_tables` accepts
-a gathered ``(8, 4, 32, N)`` table input from the validator-set-aware
-precompute cache (ops/precompute.py) and skips both. verify_batch
-partitions lanes between them, consults the digest-keyed result cache
-first, and double-buffers chunk dispatch (host prep of chunk i+1
-overlaps the kernel of chunk i).
+Three kernel entry points, one chunk kind each (:data:`KINDS`):
+:func:`verify_kernel` decompresses A and builds the lane tables on
+device; :func:`verify_kernel_tables` accepts a gathered
+``(8, 4, 32, N)`` table input from the validator-set-aware precompute
+cache (ops/precompute.py) and skips both; :func:`verify_kernel_resident`
+gathers that input on device from the resident store. verify_batch
+consults the digest-keyed result cache first, partitions lanes between
+the kinds, and hands the chunks to :func:`_run_jobs`, the dispatch loop
+it shares with sr25519, which double-buffers (host prep of chunk i+1
+overlaps the kernel of chunk i) and reaches every kernel through
+:func:`_run_chunk`.
 
 Layout is transfer-minimal: the host uploads only the raw 32-byte
 strings (A, R, S, and the SHA-512 challenge k reduced mod L) as uint8;
@@ -47,7 +51,16 @@ import numpy as np
 
 from tendermint_tpu.crypto.hashing import L, sha512_batch_mod_l
 from tendermint_tpu.libs import tracing
-from tendermint_tpu.ops import curve32 as curve, field32 as field
+from tendermint_tpu.ops import (
+    curve32 as curve,
+    device_policy,
+    fault_injection,
+    field32 as field,
+    introspect,
+    resident,
+)
+from tendermint_tpu.ops.chunk_kinds import ChunkInput, ChunkKind
+from tendermint_tpu.parallel import mesh as mesh_mod, sharding as mesh_sharding
 
 _L_BYTES_BE = np.frombuffer(L.to_bytes(32, "big"), dtype=np.uint8)
 
@@ -387,57 +400,25 @@ def _enable_persistent_cache() -> None:
 _enable_persistent_cache()
 
 
-@lru_cache(maxsize=16)
-def _compiled_kernel(n: int, backend: Optional[str], mul_impl: str = "vpu"):
-    """One compiled verifier per (padded size, backend, field-mul impl).
+@lru_cache(maxsize=64)
+def _compiled_kernel(kind: ChunkKind, n: int, backend: Optional[str], mul_impl: str):
+    """One compiled XLA verifier per (chunk kind, padded size, backend,
+    field-mul impl), for both engines.
 
     The field-mul impl ("vpu" f32 shifts vs "mxu" int8 dot_general —
     see ops/field_mxu.py) is a trace-time switch on field32, so it is
     pinned here around the trace — under field32's trace lock, so
     concurrent first compilations can't interleave their set/restore —
-    and must be part of the cache key.
+    and must be part of the cache key. A kind whose store input has no
+    lanes re-traces per store width K inside jit.
     """
 
-    def run(pk, r, s, k):
+    def run(*args):
         with field.pinned_mul_impl(mul_impl):
-            return verify_kernel(pk, r, s, k)
-
-    from tendermint_tpu.ops import introspect
+            return kind.kernel(*args)
 
     return introspect.traced_first_call(
-        jax.jit(run, backend=backend), "ed25519", "verify", n
-    )
-
-
-@lru_cache(maxsize=16)
-def _compiled_kernel_tables(n: int, backend: Optional[str], mul_impl: str = "vpu"):
-    """Compiled table-input verifier (cache-hit lanes); same keying
-    rules as :func:`_compiled_kernel`."""
-
-    def run(tab, ok, r, s, k):
-        with field.pinned_mul_impl(mul_impl):
-            return verify_kernel_tables(tab, ok, r, s, k)
-
-    from tendermint_tpu.ops import introspect
-
-    return introspect.traced_first_call(
-        jax.jit(run, backend=backend), "ed25519", "verify_tables", n
-    )
-
-
-@lru_cache(maxsize=16)
-def _compiled_kernel_resident(n: int, backend: Optional[str], mul_impl: str = "vpu"):
-    """Compiled resident-store verifier; jit re-traces per store width K
-    internally, the lru key pins (lane count, backend, mul impl)."""
-
-    def run(tab_store, idx, ok, r, s, k):
-        with field.pinned_mul_impl(mul_impl):
-            return verify_kernel_resident(tab_store, idx, ok, r, s, k)
-
-    from tendermint_tpu.ops import introspect
-
-    return introspect.traced_first_call(
-        jax.jit(run, backend=backend), "ed25519", "verify_resident", n
+        jax.jit(run, backend=backend), kind.engine, kind.kernel_name, n
     )
 
 
@@ -493,136 +474,62 @@ def _mul_impl_for_chunk(impl: str, backend: Optional[str], lanes: int) -> str:
     return autotune.mul_impl_for(backend, lanes)
 
 
-def _run_chunk(inputs: dict, backend: Optional[str], plan=None):
-    """Dispatch one padded legacy chunk on the active implementation.
+def _run_chunk(kind: ChunkKind, inputs: dict, backend: Optional[str], plan=None):
+    """Dispatch one padded chunk: the one place a kernel is chosen.
 
-    Returns ``(result, plan_used)``: ``plan_used`` is the (possibly
-    degraded) mesh plan when the chunk went out lane-sharded, else
-    None. With a plan, a mesh that loses all usable devices falls
-    through to the single-device dispatch below — never to host."""
-    from tendermint_tpu.ops import fault_injection
+    Returns ``(result, plan_used, impl)``: ``plan_used`` is the (possibly
+    degraded) mesh plan when the chunk went out lane-sharded, else None;
+    ``impl`` is what the chunk was actually handed to. A usable plan
+    sends the chunk to the mesh, whose kernels are the XLA graph only;
+    a mesh that loses all usable devices falls through to the single-
+    device dispatch below — never to host. On one device ``pallas``
+    takes the kind's Pallas entry point, anything else the XLA graph.
 
+    A resident chunk's store is committed to the context it was
+    uploaded for (``mesh_key``: one mesh's devices, or None for one
+    single device). When that context is gone — mesh degraded mid-
+    batch, or run_chunk_mesh gave up — the store's columns are pulled
+    to host, gathered per lane, and the chunk re-enters as a gathered-
+    table chunk (rare, and still device compute).
+    """
     # TENDERMINT_TPU_VERIFY_IMPL=mxu forces the int8 contraction; the
     # autotuned (or field-level default) impl is honored otherwise.
     impl = active_impl(backend)
-    mul_impl = _mul_impl_for_chunk(impl, backend, inputs["pk"].shape[0])
-    if plan is not None:
-        from tendermint_tpu.parallel import sharding as mesh_sharding
-
+    if impl == "pallas" and kind.pallas is None:
+        impl = "xla"
+    m = kind.lanes(inputs)
+    mul_impl = _mul_impl_for_chunk(impl, backend, m)
+    bound = kind.store_bound
+    if plan is not None and (
+        not bound or inputs["mesh_key"] == tuple(plan.device_ids)
+    ):
         try:
-            return mesh_sharding.run_chunk_mesh(
-                "ed25519", inputs, mul_impl, plan, "ed25519.chunk"
-            )
+            out, used = mesh_sharding.run_chunk_mesh(kind, inputs, mul_impl, plan)
+            return out, used, "xla"
         except mesh_sharding.MeshUnavailableError:
             # Every device excluded: degrade to THIS backend's single-
             # device dispatch below; host fallback stays with the caller.
             pass
-    fault_injection.fire("ed25519.chunk")
-    args = (
-        jnp.asarray(inputs["pk"]),
-        jnp.asarray(inputs["r"]),
-        jnp.asarray(inputs["s"]),
-        jnp.asarray(inputs["k"]),
-    )
-    m = inputs["pk"].shape[0]
-    if impl == "pallas":
-        from tendermint_tpu.ops import pallas_verify
-
-        return pallas_verify.compiled_verify(m)(*args), None
-    return _compiled_kernel(m, backend, mul_impl)(*args), None
-
-
-def _run_chunk_tables(inputs: dict, backend: Optional[str], plan=None):
-    """Dispatch one padded cache-hit chunk through the table kernel.
-    Same ``(result, plan_used)`` contract as :func:`_run_chunk`."""
-    from tendermint_tpu.ops import fault_injection
-
-    impl = active_impl(backend)
-    mul_impl = _mul_impl_for_chunk(impl, backend, inputs["r"].shape[0])
-    if plan is not None:
-        from tendermint_tpu.parallel import sharding as mesh_sharding
-
-        try:
-            return mesh_sharding.run_chunk_mesh(
-                "tables", inputs, mul_impl, plan, "ed25519.chunk"
-            )
-        except mesh_sharding.MeshUnavailableError:
-            # Every device excluded: single-device path, not host.
-            pass
-    fault_injection.fire("ed25519.chunk")
-    args = (
-        jnp.asarray(inputs["tab"]),
-        jnp.asarray(inputs["ok"]),
-        jnp.asarray(inputs["r"]),
-        jnp.asarray(inputs["s"]),
-        jnp.asarray(inputs["k"]),
-    )
-    m = inputs["r"].shape[0]
-    if impl == "pallas":
-        from tendermint_tpu.ops import pallas_verify
-
-        return pallas_verify.compiled_verify_tables(m)(*args), None
-    return _compiled_kernel_tables(m, backend, mul_impl)(*args), None
-
-
-def _run_chunk_resident(inputs: dict, backend: Optional[str], plan=None):
-    """Dispatch one padded resident-store chunk: only gather indices
-    ship per batch, the table tensor already lives on device. Same
-    ``(result, plan_used)`` contract as :func:`_run_chunk`, and on one
-    device the same choice by ``active_impl``: ``pallas`` gathers on
-    the device and runs the Pallas table kernel, anything else the XLA
-    resident graph. A sharded chunk runs the XLA graph whatever it says.
-
-    The store tensor is committed to the context it was uploaded for
-    (one mesh, or one single device). When that context is gone —
-    mesh degraded mid-batch, or run_chunk_mesh gave up — the chunk
-    falls back to the gathered-table kernel: the store's columns are
-    pulled to host, gathered per lane, and shipped the old way (rare,
-    and still device compute).
-    """
-    from tendermint_tpu.ops import fault_injection, resident
-
-    impl = active_impl(backend)
-    mul_impl = _mul_impl_for_chunk(impl, backend, inputs["r"].shape[0])
-    mesh_ok = plan is not None and inputs.get("mesh_key") == tuple(
-        plan.device_ids
-    )
-    if mesh_ok:
-        from tendermint_tpu.parallel import sharding as mesh_sharding
-
-        try:
-            return mesh_sharding.run_chunk_mesh(
-                "resident", inputs, mul_impl, plan, "ed25519.chunk"
-            )
-        except mesh_sharding.MeshUnavailableError:
-            # The store is committed to the dead mesh; gathered-table
-            # fallback below re-ships this chunk's columns explicitly.
-            pass
-    fault_injection.fire("ed25519.chunk")
-    m = inputs["r"].shape[0]
-    if plan is None and inputs.get("mesh_key") is None:
-        args = (
-            inputs["store"],
-            jnp.asarray(inputs["idx"]),
-            jnp.asarray(inputs["ok"]),
-            jnp.asarray(inputs["r"]),
-            jnp.asarray(inputs["s"]),
-            jnp.asarray(inputs["k"]),
+    fault_injection.fire(kind.engine + ".chunk")
+    if bound and (plan is not None or inputs["mesh_key"] is not None):
+        # Context mismatch: materialize the needed columns and take the
+        # gathered-table kernel (counted as real per-batch table H2D).
+        tab_host = np.asarray(inputs["store"])
+        tab = np.ascontiguousarray(tab_host[:, :, :, np.asarray(inputs["idx"])])
+        resident.note_table_h2d(tab.nbytes)
+        gathered = dict(
+            tab=tab, ok=inputs["ok"], r=inputs["r"], s=inputs["s"], k=inputs["k"]
         )
-        if impl == "pallas":
-            from tendermint_tpu.ops import pallas_verify
-
-            return pallas_verify.compiled_verify_resident(m)(*args), None
-        return _compiled_kernel_resident(m, backend, mul_impl)(*args), None
-    # Context mismatch: materialize the needed columns and take the
-    # gathered-table kernel (counted as real per-batch table H2D).
-    tab_host = np.asarray(inputs["store"])
-    tab = np.ascontiguousarray(tab_host[:, :, :, np.asarray(inputs["idx"])])
-    resident.note_table_h2d(tab.nbytes)
-    ginputs = dict(
-        tab=tab, ok=inputs["ok"], r=inputs["r"], s=inputs["s"], k=inputs["k"]
+        return _run_chunk(KINDS["tables"], gathered, backend, None)
+    args = tuple(
+        inputs[i.name] if i.lane_axis is None else jnp.asarray(inputs[i.name])
+        for i in kind.inputs
     )
-    return _run_chunk_tables(ginputs, backend, None)
+    if impl == "pallas":
+        from tendermint_tpu.ops import pallas_verify
+
+        return getattr(pallas_verify, kind.pallas)(m)(*args), None, impl
+    return _compiled_kernel(kind, m, backend, mul_impl)(*args), None, impl
 
 
 # --- host-side preparation --------------------------------------------------
@@ -646,12 +553,9 @@ def _mesh_bucket(n: int, n_dev: int) -> int:
 
 def _mesh_plan(lanes: int):
     """A mesh plan (parallel/mesh.MeshPlan) when the sharded path
-    should serve this batch, else None. Any trouble building one —
-    parallel package unavailable, no backend — means 'unsharded',
-    never a verification error."""
+    should serve this batch, else None. Any trouble building one (no
+    backend) means 'unsharded', never a verification error."""
     try:
-        from tendermint_tpu.parallel import mesh as mesh_mod
-
         return mesh_mod.plan_for_lanes(lanes)
     except Exception:  # sharding is an optimization; never block verify
         return None
@@ -659,8 +563,6 @@ def _mesh_plan(lanes: int):
 
 def _mesh_on_success(plan) -> None:
     try:
-        from tendermint_tpu.parallel import mesh as mesh_mod
-
         mesh_mod.manager.on_success(plan)
     except Exception:  # health bookkeeping must never fail verification
         pass
@@ -668,8 +570,6 @@ def _mesh_on_success(plan) -> None:
 
 def _mesh_abandon(plan) -> None:
     try:
-        from tendermint_tpu.parallel import mesh as mesh_mod
-
         mesh_mod.manager.abandon(plan)
     except Exception:  # health bookkeeping must never fail verification
         pass
@@ -698,20 +598,21 @@ def _pad_k() -> bytes:
     return _PAD_K
 
 
-# Padding rows as ready-made (1, 32) uint8 arrays, decoded once instead
-# of np.frombuffer over the pad triple on every padded prepare call.
-_PAD_ROWS: Optional[Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]] = None
+# Pad lanes as ready-made arrays, decoded once instead of np.frombuffer
+# over the pad triple on every padded prepare call.
+_PAD_ROWS: Optional[dict] = None
 _PAD_TABLE: Optional[np.ndarray] = None
 
 
-def _pad_rows() -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+def _pad_row(name: str) -> np.ndarray:
+    """(32,) uint8 pad lane of the input ``pk`` / ``r`` / ``s`` / ``k``."""
     global _PAD_ROWS
     if _PAD_ROWS is None:
-        _PAD_ROWS = tuple(
-            np.frombuffer(b, dtype=np.uint8).reshape(1, 32).copy()
-            for b in (_PAD_PK, _PAD_SIG[:32], _PAD_SIG[32:], _pad_k())
-        )
-    return _PAD_ROWS
+        raw = (_PAD_PK, _PAD_SIG[:32], _PAD_SIG[32:], _pad_k())
+        _PAD_ROWS = {
+            n: np.frombuffer(b, dtype=np.uint8) for n, b in zip(("pk", "r", "s", "k"), raw)
+        }
+    return _PAD_ROWS[name]
 
 
 def _pad_table() -> np.ndarray:
@@ -722,6 +623,40 @@ def _pad_table() -> np.ndarray:
 
         _PAD_TABLE = precompute.build_table(_PAD_PK)[0]
     return _PAD_TABLE
+
+
+# --- the chunk kinds of this engine -----------------------------------------
+#
+# What each kernel takes, in its argument order, and what a pad lane of
+# each input is (ops/chunk_kinds.py says what the fields mean). A pad
+# lane of ``idx`` is column 0, the pad-key table reserved at upload.
+
+
+def _byte_rows(*names: str) -> Tuple[ChunkInput, ...]:
+    return tuple(ChunkInput(n, 0, lambda n=n: _pad_row(n)) for n in names)
+
+
+_OK = ChunkInput("ok", 0, lambda: np.uint8(1))
+KINDS = {
+    kind.name: kind
+    for kind in (
+        ChunkKind(
+            "legacy", "ed25519", "verify", verify_kernel, "compiled_verify",
+            _byte_rows("pk", "r", "s", "k"),
+        ),
+        ChunkKind(
+            "tables", "ed25519", "verify_tables", verify_kernel_tables,
+            "compiled_verify_tables",
+            (ChunkInput("tab", 3, _pad_table), _OK) + _byte_rows("r", "s", "k"),
+        ),
+        ChunkKind(
+            "resident", "ed25519", "verify_resident", verify_kernel_resident,
+            "compiled_verify_resident",
+            (ChunkInput("store", None), ChunkInput("idx", 0, lambda: np.int32(0)), _OK)
+            + _byte_rows("r", "s", "k"),
+        ),
+    )
+}
 
 
 def canonical_lt(arr_le: np.ndarray, bound_be: np.ndarray) -> np.ndarray:
@@ -780,6 +715,26 @@ def _challenge_k(
     return k_arr
 
 
+def _prep_rows(
+    pks: Sequence[bytes],
+    msgs: Sequence[bytes],
+    sigs: Sequence[bytes],
+    backend: Optional[str],
+    stage_times: Optional[dict] = None,
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """The head every kind's prep shares, for well-formed lanes: two
+    joins + one prefixed C hash call, no per-signature Python work.
+    Returns ``(pk, r, s, k, host_ok)``: (n, 32) uint8 each and the
+    (n,) bool s < L verdicts."""
+    n = len(pks)
+    pk_arr = np.frombuffer(b"".join(pks), dtype=np.uint8).reshape(n, 32)
+    sig_arr = np.frombuffer(b"".join(sigs), dtype=np.uint8).reshape(n, 64)
+    r_arr, s_arr = sig_arr[:, :32], sig_arr[:, 32:]
+    prefix = np.concatenate([r_arr, pk_arr], axis=1)  # (n, 64) = R || A
+    k_arr = _challenge_k(prefix, msgs, backend, stage_times)
+    return pk_arr, r_arr, s_arr, k_arr, _s_canonical(s_arr)
+
+
 def prepare_batch(
     pubkeys: Sequence[bytes],
     msgs: Sequence[bytes],
@@ -793,16 +748,11 @@ def prepare_batch(
     Returns (device inputs dict of (M,32) uint8 arrays, host_ok (N,)
     bool of structural checks: lengths and s < L canonicity)."""
     n = len(pubkeys)
-    len_ok = all(len(pk) == 32 and len(sg) == 64 for pk, sg in zip(pubkeys, sigs))
-    if len_ok:
-        # Fast path (every batch from commit verification): two joins +
-        # one prefixed C hash call — no per-signature Python work.
-        pk_arr = np.frombuffer(b"".join(pubkeys), dtype=np.uint8).reshape(n, 32)
-        sig_arr = np.frombuffer(b"".join(sigs), dtype=np.uint8).reshape(n, 64)
-        r_arr, s_arr = sig_arr[:, :32], sig_arr[:, 32:]
-        host_ok = _s_canonical(s_arr)
-        prefix = np.concatenate([r_arr, pk_arr], axis=1)  # (n, 64) = R || A
-        k_arr = _challenge_k(prefix, msgs, backend, stage_times)
+    if all(len(pk) == 32 and len(sg) == 64 for pk, sg in zip(pubkeys, sigs)):
+        # Fast path (every batch from commit verification).
+        pk_arr, r_arr, s_arr, k_arr, host_ok = _prep_rows(
+            pubkeys, msgs, sigs, backend, stage_times
+        )
     else:
         host_ok = np.ones(n, dtype=bool)
         pk_arr = np.zeros((n, 32), dtype=np.uint8)
@@ -828,17 +778,9 @@ def prepare_batch(
                 -1, 32
             )
 
-    m = pad_to if pad_to is not None else _bucket(n)
-    if m > n:
-        pk_row, r_row, s_row, k_row = _pad_rows()
-        reps = (m - n, 1)
-        pk_arr = np.concatenate([pk_arr, np.tile(pk_row, reps)])
-        r_arr = np.concatenate([r_arr, np.tile(r_row, reps)])
-        s_arr = np.concatenate([s_arr, np.tile(s_row, reps)])
-        k_arr = np.concatenate([k_arr, np.tile(k_row, reps)])
-
     inputs = dict(pk=pk_arr, r=r_arr, s=s_arr, k=k_arr)
-    return inputs, host_ok
+    m = pad_to if pad_to is not None else _bucket(n)
+    return KINDS["legacy"].pad_lanes(inputs, m - n), host_ok
 
 
 def _prep_table_chunk(
@@ -849,38 +791,25 @@ def _prep_table_chunk(
     oks: Sequence[bool],
     pad_to: int,
     backend: Optional[str] = None,
-    stage_times: Optional[dict] = None,
 ) -> Tuple[dict, np.ndarray]:
     """Host prep for a cache-hit chunk: hash challenges, stack the
     gathered per-key table columns into the kernel's (8, 4, 32, M)
     uint8 input. Lengths are pre-validated by the caller (ill-formed
     lanes stay on the legacy path)."""
     n = len(pks)
-    pk_arr = np.frombuffer(b"".join(pks), dtype=np.uint8).reshape(n, 32)
-    sig_arr = np.frombuffer(b"".join(sigs), dtype=np.uint8).reshape(n, 64)
-    r_arr, s_arr = sig_arr[:, :32], sig_arr[:, 32:]
-    host_ok = _s_canonical(s_arr)
-    prefix = np.concatenate([r_arr, pk_arr], axis=1)  # (n, 64) = R || A
-    k_arr = _challenge_k(prefix, msgs, backend, stage_times)
-    tab = np.stack(tabs)  # (n, 8, 4, 32) uint8
-    a_ok = np.fromiter(oks, dtype=bool, count=n).astype(np.uint8)
-    if pad_to > n:
-        _, r_row, s_row, k_row = _pad_rows()
-        reps = (pad_to - n, 1)
-        r_arr = np.concatenate([r_arr, np.tile(r_row, reps)])
-        s_arr = np.concatenate([s_arr, np.tile(s_row, reps)])
-        k_arr = np.concatenate([k_arr, np.tile(k_row, reps)])
-        tab = np.concatenate(
-            [tab, np.broadcast_to(_pad_table()[None], (pad_to - n, TABLE_WIDTH, 4, 32))]
-        )
-        a_ok = np.concatenate([a_ok, np.ones(pad_to - n, dtype=np.uint8)])
-    tab = np.ascontiguousarray(tab.transpose(1, 2, 3, 0))  # (8, 4, 32, M)
+    _, r_arr, s_arr, k_arr, host_ok = _prep_rows(pks, msgs, sigs, backend)
+    inputs = dict(
+        tab=np.stack(tabs).transpose(1, 2, 3, 0),  # (n, 8, 4, 32) -> lanes last
+        ok=np.fromiter(oks, dtype=bool, count=n).astype(np.uint8),
+        r=r_arr,
+        s=s_arr,
+        k=k_arr,
+    )
+    inputs = KINDS["tables"].pad_lanes(inputs, pad_to - n)
+    inputs["tab"] = np.ascontiguousarray(inputs["tab"])
     # every gathered chunk re-ships its table tensor; the resident store
     # accounts it so benches can prove the steady-state delta
-    from tendermint_tpu.ops import resident
-
-    resident.note_table_h2d(tab.nbytes)
-    inputs = dict(tab=tab, ok=a_ok, r=r_arr, s=s_arr, k=k_arr)
+    resident.note_table_h2d(inputs["tab"].nbytes)
     return inputs, host_ok
 
 
@@ -894,50 +823,21 @@ def _prep_resident_chunk(
     mesh_key,
     pad_to: int,
     backend: Optional[str] = None,
-    stage_times: Optional[dict] = None,
 ) -> Tuple[dict, np.ndarray]:
     """Host prep for a resident-store chunk: the table tensor is already
     on device, so the per-batch payload is the (M,) int32 gather index
-    vector plus the usual r/s/k rows. Pad lanes index column 0 — the
-    pad-key table reserved at upload."""
-    n = len(pks)
-    pk_arr = np.frombuffer(b"".join(pks), dtype=np.uint8).reshape(n, 32)
-    sig_arr = np.frombuffer(b"".join(sigs), dtype=np.uint8).reshape(n, 64)
-    r_arr, s_arr = sig_arr[:, :32], sig_arr[:, 32:]
-    host_ok = _s_canonical(s_arr)
-    prefix = np.concatenate([r_arr, pk_arr], axis=1)  # (n, 64) = R || A
-    k_arr = _challenge_k(prefix, msgs, backend, stage_times)
-    idx = np.asarray(idxs, dtype=np.int32)
-    a_ok = np.asarray(oks, dtype=np.uint8)
-    if pad_to > n:
-        _, r_row, s_row, k_row = _pad_rows()
-        reps = (pad_to - n, 1)
-        r_arr = np.concatenate([r_arr, np.tile(r_row, reps)])
-        s_arr = np.concatenate([s_arr, np.tile(s_row, reps)])
-        k_arr = np.concatenate([k_arr, np.tile(k_row, reps)])
-        idx = np.concatenate([idx, np.zeros(pad_to - n, dtype=np.int32)])
-        a_ok = np.concatenate([a_ok, np.ones(pad_to - n, dtype=np.uint8)])
+    vector plus the usual r/s/k rows."""
+    _, r_arr, s_arr, k_arr, host_ok = _prep_rows(pks, msgs, sigs, backend)
     inputs = dict(
         store=store_tab,
         mesh_key=mesh_key,
-        idx=idx,
-        ok=a_ok,
+        idx=np.asarray(idxs, dtype=np.int32),
+        ok=np.asarray(oks, dtype=np.uint8),
         r=r_arr,
         s=s_arr,
         k=k_arr,
     )
-    return inputs, host_ok
-
-
-def _host_verify_lanes(
-    pubkeys: Sequence[bytes],
-    msgs: Sequence[bytes],
-    sigs: Sequence[bytes],
-    lo: int,
-    hi: int,
-) -> np.ndarray:
-    """CPU oracle over lanes [lo, hi) of the original (unpadded) batch."""
-    return _host_verify_rows(pubkeys, msgs, sigs, range(lo, hi))
+    return KINDS["resident"].pad_lanes(inputs, pad_to - len(pks)), host_ok
 
 
 def _host_verify_rows(
@@ -946,7 +846,7 @@ def _host_verify_rows(
     sigs: Sequence[bytes],
     rows,
 ) -> np.ndarray:
-    """CPU oracle over an arbitrary row subset of the original batch."""
+    """CPU oracle over a row subset of the original (unpadded) batch."""
     from tendermint_tpu.crypto.ed25519_ref import verify_zip215
 
     return np.array(
@@ -955,14 +855,16 @@ def _host_verify_rows(
     )
 
 
+# --- the dispatch loop (both engines) ---------------------------------------
+
+
 class _Job:
-    """One padded chunk of the batch: either legacy (build tables on
-    device) or cache-hit (gathered table input). ``rows`` are original
-    batch indices; the padded tail is sliced off at scatter time."""
+    """One padded chunk of a batch. ``rows`` are original batch indices;
+    the padded tail is sliced off at scatter time."""
 
     __slots__ = ("kind", "rows", "prepped", "out", "plan")
 
-    def __init__(self, kind: str, rows: np.ndarray):
+    def __init__(self, kind: ChunkKind, rows: np.ndarray):
         self.kind = kind
         self.rows = rows
         self.prepped = None  # (inputs dict, host_ok) once prep ran
@@ -970,29 +872,20 @@ class _Job:
         self.plan = None  # mesh plan this chunk dispatched on (or None)
 
 
-def _chunk_rows(rows: np.ndarray, span: int = CHUNK) -> List[np.ndarray]:
+def _chunk_rows(rows: np.ndarray, span: int) -> List[np.ndarray]:
     return [rows[lo : lo + span] for lo in range(0, len(rows), span)]
 
 
-def _chunk_h2d_bytes(inputs: dict) -> int:
-    """Bytes a prepared chunk hands to its kernel from the host: every
-    per-batch array, the resident chunk's gather indices among them.
-    The resident store is already on the device and does not count."""
-    return sum(
-        int(v.nbytes)
-        for name, v in inputs.items()
-        if name != "store" and isinstance(v, np.ndarray)
-    )
+def _mesh_span(lanes: int):
+    """``(plan, span)`` for a batch: the mesh plan serving it (or None)
+    and the lanes one job may hold. With a plan, chunks span all its
+    devices — span and padding scale by the device count so each chip
+    still sees bucket-size slabs."""
+    plan = _mesh_plan(lanes)
+    return plan, CHUNK * (plan.n_dev if plan is not None else 1)
 
 
-def _chunk_impl(backend: Optional[str], plan_used) -> str:
-    """The implementation a dispatched chunk was handed to: the sharded
-    kernels are the XLA graph only (parallel/sharding.py); on one
-    device every runner follows :func:`active_impl`."""
-    return "xla" if plan_used is not None else active_impl(backend)
-
-
-def _mesh_collect_retry(job: "_Job", backend: Optional[str], exc: Exception):
+def _mesh_collect_retry(job: _Job, backend: Optional[str], exc: Exception):
     """A sharded chunk died at materialization. If the failure is
     attributable to one device, exclude it, rebuild a smaller mesh, and
     re-dispatch THIS chunk on it — 'a sick chip degrades the mesh, not
@@ -1000,9 +893,6 @@ def _mesh_collect_retry(job: "_Job", backend: Optional[str], exc: Exception):
     verdict array, or None so the caller keeps its ordinary host
     fallback (unattributed failure, or the retry failed as well)."""
     try:
-        from tendermint_tpu.parallel import mesh as mesh_mod
-        from tendermint_tpu.parallel import sharding as mesh_sharding
-
         culprit = mesh_mod.manager.on_failure(job.plan, exc)
         if culprit is None:
             return None
@@ -1012,28 +902,176 @@ def _mesh_collect_retry(job: "_Job", backend: Optional[str], exc: Exception):
         import warnings
 
         warnings.warn(
-            f"sharded chunk ({job.kind}) failed at collect ({exc!r}); "
+            f"sharded chunk ({job.kind.name}) failed at collect ({exc!r}); "
             f"device {culprit} excluded, retrying on a {nxt.n_dev}-device mesh"
         )
-        inputs, _ = job.prepped
-        if job.kind == "tables":
-            runner = _run_chunk_tables
-        elif job.kind == "resident":
-            runner = _run_chunk_resident
+        out, used, _ = _run_chunk(job.kind, job.prepped[0], backend, nxt)
+        if used is None:
+            ok = np.asarray(out)
         else:
-            runner = _run_chunk
-        out, used = runner(inputs, backend, nxt)
-        ok = (
-            mesh_sharding.collect_sharded(out, "ed25519")
-            if used is not None
-            else np.asarray(out)
-        )
-        if used is not None:
+            ok = mesh_sharding.collect_sharded(out, job.kind.engine)
             _mesh_on_success(used)
         job.plan = used
         return ok
     except Exception:  # retry is best-effort; host fallback covers the chunk
         return None
+
+
+def _run_jobs(
+    engine: str, n: int, jobs: List[_Job], prep_job, host_verify, backend, plan, attempt
+) -> np.ndarray:
+    """Prepare, dispatch and collect ``jobs``: the one loop both
+    engines reach the device through. Returns the (n,) bool verdicts.
+
+    ``prep_job(job, pad_to)`` is the engine's host prep: it returns
+    ``(inputs, host_ok)``, the chunk's kernel inputs padded to
+    ``pad_to`` lanes and the structural verdicts of its rows.
+    ``host_verify(rows)`` is the engine's CPU oracle. ``plan`` is the
+    batch's mesh plan (a plan degraded mid-batch replaces it, so later
+    chunks ride the smaller mesh) and ``attempt`` the health machine's
+    admission of this call.
+
+    Dispatch is double-buffered: job j's kernel is enqueued (JAX async
+    dispatch), then job j+1's host prep runs while the device crunches
+    job j. A job whose prep, dispatch or materialization fails is
+    re-verified on the oracle while the rest stay on the device, if the
+    health machine (ops/device_policy.py) still admits them.
+    """
+    import warnings
+
+    health = device_policy.shared
+    results = np.ones(n, dtype=bool)
+    host_ok_all = np.ones(n, dtype=bool)
+    mesh_used = False
+
+    def prep(job: _Job) -> None:
+        nonlocal attempt
+        lanes = len(job.rows)
+        try:
+            with tracing.span(
+                "prep_chunk", stage="prep", engine=engine, kind=job.kind.name, lanes=lanes
+            ):
+                pad_to = (
+                    _mesh_bucket(lanes, plan.n_dev) if plan is not None else _bucket(lanes)
+                )
+                job.prepped = prep_job(job, pad_to)
+        except Exception as exc:
+            # Host prep failed before any device work for this job. Never
+            # take the node down over infrastructure — its lanes degrade to
+            # the host oracle at collect time.
+            health.record_failure(exc, attempt)
+            attempt = None
+            warnings.warn(
+                f"chunk prepare failed ({exc!r}); CPU fallback for "
+                f"{lanes} lanes (device state={health.state})"
+            )
+
+    for j, job in enumerate(jobs):
+        if j == 0:
+            prep(job)
+        if job.prepped is not None:
+            inputs, host_ok = job.prepped
+            host_ok_all[job.rows] = host_ok[: len(job.rows)]
+            if attempt is None:
+                attempt = health.begin_attempt(engine)
+            if attempt is not None:
+                try:
+                    with tracing.span(
+                        "dispatch_chunk",
+                        stage="dispatch",
+                        engine=engine,
+                        kind=job.kind.name,
+                        lanes=len(job.rows),
+                    ) as dsp:
+                        if dsp.live:
+                            dsp.set(
+                                padded_lanes=job.kind.lanes(inputs),
+                                h2d_bytes=job.kind.h2d_bytes(inputs),
+                            )
+                        job.out, job.plan, impl = _run_chunk(
+                            job.kind, inputs, backend, plan
+                        )
+                        if dsp.live:
+                            dsp.set(impl=impl)
+                    if job.plan is not None:
+                        mesh_used = True
+                        plan = job.plan  # degraded: later chunks follow
+                    health.note_inflight(engine, len(job.rows))
+                except Exception as exc:
+                    health.record_failure(exc, attempt)
+                    attempt = None
+                    warnings.warn(
+                        f"device chunk ({job.kind.name}, {len(job.rows)} lanes) "
+                        f"dispatch failed ({exc!r}); CPU fallback for the "
+                        f"chunk (device state={health.state})"
+                    )
+        if j + 1 < len(jobs):
+            prep(jobs[j + 1])
+
+    if plan is not None and not mesh_used:
+        # Planned but never dispatched sharded (e.g. the shared health
+        # machine denied every chunk): release probe reservations.
+        _mesh_abandon(plan)
+
+    # Collect phase: JAX dispatch is async, so runtime errors can
+    # surface at materialization; those too degrade per chunk.
+    fallback_lanes = 0
+    device_chunks_ok = 0
+    for job in jobs:
+        ok = None
+        if job.out is not None:
+            try:
+                with tracing.span(
+                    "collect_chunk",
+                    stage="collect",
+                    engine=engine,
+                    kind=job.kind.name,
+                    lanes=len(job.rows),
+                ) as csp:
+                    fault_injection.fire(engine + ".collect")
+                    if job.plan is not None:
+                        ok = mesh_sharding.collect_sharded(job.out, engine)
+                    else:
+                        ok = np.asarray(job.out)
+                    if csp.live:
+                        csp.set(d2h_bytes=int(ok.nbytes))
+                device_chunks_ok += 1
+                if job.plan is not None:
+                    _mesh_on_success(job.plan)
+            except Exception as exc:
+                if job.plan is not None:
+                    ok = _mesh_collect_retry(job, backend, exc)
+                if ok is not None:
+                    device_chunks_ok += 1
+                else:
+                    health.record_failure(exc, attempt)
+                    attempt = None
+                    warnings.warn(
+                        f"device chunk ({job.kind.name}, {len(job.rows)} lanes) "
+                        f"failed at collect ({exc!r}); CPU fallback for the "
+                        f"chunk (device state={health.state})"
+                    )
+            finally:
+                health.note_inflight(engine, -len(job.rows))
+        if not len(job.rows):
+            continue
+        if ok is None:
+            fallback_lanes += len(job.rows)
+            with tracing.span(
+                "host_fallback", stage="fallback", engine=engine, lanes=len(job.rows)
+            ):
+                results[job.rows] = host_verify(job.rows)
+            host_ok_all[job.rows] = True  # oracle verdicts are final
+        else:
+            results[job.rows] = ok[: len(job.rows)]
+
+    if fallback_lanes:
+        health.count_fallback(engine, fallback_lanes)
+    if attempt is not None and device_chunks_ok:
+        # No failure consumed the attempt and device work round-tripped:
+        # re-promote (clears DEGRADED, completes a half-open probe).
+        health.record_success(attempt)
+    return np.logical_and(results, host_ok_all)
 
 
 def verify_batch(
@@ -1117,9 +1155,9 @@ def _verify_uncached(
     backend: Optional[str] = None,
 ) -> np.ndarray:
     """Device verification of lanes the result cache could not answer."""
-    from tendermint_tpu.ops import fault_injection, precompute
-    from tendermint_tpu.ops.device_policy import shared as health
+    from tendermint_tpu.ops import precompute
 
+    health = device_policy.shared
     n = len(pubkeys)
     attempt = health.begin_attempt("ed25519")
     if attempt is None:
@@ -1129,7 +1167,7 @@ def _verify_uncached(
         with tracing.span(
             "host_fallback", stage="fallback", engine="ed25519", lanes=n
         ):
-            return _host_verify_lanes(pubkeys, msgs, sigs, 0, n)
+            return _host_verify_rows(pubkeys, msgs, sigs, range(n))
 
     # Partition: lanes whose key has a cached (or eligible, host-built)
     # table take the table kernel; ill-formed lanes must stay on the
@@ -1152,13 +1190,7 @@ def _verify_uncached(
             has_table = np.zeros(n, dtype=bool)
             entries = None
 
-        # Mesh plan for this batch: when one exists, chunks span all its
-        # devices — span and padding scale by the device count so each chip
-        # still sees bucket-size slabs. A plan degraded mid-batch replaces
-        # `plan` so later chunks ride the smaller mesh.
-        plan = _mesh_plan(n)
-        span = CHUNK * plan.n_dev if plan is not None else CHUNK
-        mesh_used = False
+        plan, span = _mesh_span(n)
 
         # Resident routing: lanes whose key already lives in the device-
         # resident store ship only gather indices — zero per-batch table
@@ -1167,8 +1199,6 @@ def _verify_uncached(
         res_mask = np.zeros(n, dtype=bool)
         if entries is not None:
             try:
-                from tendermint_tpu.ops import resident
-
                 res = resident.acquire(
                     pubkeys, has_table, plan=plan, backend=backend
                 )
@@ -1179,14 +1209,13 @@ def _verify_uncached(
         table_mask = has_table & ~res_mask
 
         jobs = [
-            _Job("resident", rows)
-            for rows in _chunk_rows(np.nonzero(res_mask)[0], span)
-        ]
-        jobs += [
-            _Job("tables", rows) for rows in _chunk_rows(np.nonzero(table_mask)[0], span)
-        ]
-        jobs += [
-            _Job("legacy", rows) for rows in _chunk_rows(np.nonzero(~has_table)[0], span)
+            _Job(KINDS[name], rows)
+            for name, mask in (
+                ("resident", res_mask),
+                ("tables", table_mask),
+                ("legacy", ~has_table),
+            )
+            for rows in _chunk_rows(np.nonzero(mask)[0], span)
         ]
         if rsp.live:
             rsp.set(
@@ -1196,194 +1225,28 @@ def _verify_uncached(
                 jobs=len(jobs),
             )
 
-    def prep_job(job: _Job) -> Tuple[dict, np.ndarray]:
-        with tracing.span(
-            "prep_chunk",
-            stage="prep",
-            engine="ed25519",
-            kind=job.kind,
-            lanes=len(job.rows),
-        ):
-            pks = [pubkeys[i] for i in job.rows]
-            ms = [msgs[i] for i in job.rows]
-            sgs = [sigs[i] for i in job.rows]
-            pad_to = (
-                _mesh_bucket(len(job.rows), plan.n_dev)
-                if plan is not None
-                else _bucket(len(job.rows))
+    def prep_job(job: _Job, pad_to: int) -> Tuple[dict, np.ndarray]:
+        pks = [pubkeys[i] for i in job.rows]
+        ms = [msgs[i] for i in job.rows]
+        sgs = [sigs[i] for i in job.rows]
+        if job.kind.name == "resident":
+            idxs = res_idx[job.rows]
+            return _prep_resident_chunk(
+                pks, ms, sgs, idxs, res_ok_cols[idxs], res_tab, res_mesh_key,
+                pad_to, backend=backend,
             )
-            if job.kind == "resident":
-                idxs = res_idx[job.rows]
-                return _prep_resident_chunk(
-                    pks,
-                    ms,
-                    sgs,
-                    idxs,
-                    res_ok_cols[idxs],
-                    res_tab,
-                    res_mesh_key,
-                    pad_to,
-                    backend=backend,
-                )
-            if job.kind == "tables":
-                return _prep_table_chunk(
-                    pks,
-                    ms,
-                    sgs,
-                    [entries[i][0] for i in job.rows],
-                    [entries[i][1] for i in job.rows],
-                    pad_to,
-                    backend=backend,
-                )
-            return prepare_batch(pks, ms, sgs, pad_to=pad_to, backend=backend)
+        if job.kind.name == "tables":
+            return _prep_table_chunk(
+                pks, ms, sgs,
+                [entries[i][0] for i in job.rows],
+                [entries[i][1] for i in job.rows],
+                pad_to, backend=backend,
+            )
+        return prepare_batch(pks, ms, sgs, pad_to=pad_to, backend=backend)
 
-    results = np.ones(n, dtype=bool)
-    host_ok_all = np.ones(n, dtype=bool)
+    def host_verify(rows) -> np.ndarray:
+        return _host_verify_rows(pubkeys, msgs, sigs, rows)
 
-    def note_prep_failure(job: _Job, exc: Exception) -> None:
-        nonlocal attempt
-        # Host prep failed before any device work for this job. Never
-        # take the node down over infrastructure — its lanes degrade to
-        # the host oracle at collect time.
-        health.record_failure(exc, attempt)
-        attempt = None
-        import warnings
-
-        warnings.warn(
-            f"chunk prepare failed ({exc!r}); CPU fallback for "
-            f"{len(job.rows)} lanes (device state={health.state})"
-        )
-
-    # Double-buffered dispatch: enqueue job j's kernel (async), then run
-    # job j+1's host prep while the device crunches job j.
-    for j, job in enumerate(jobs):
-        if j == 0:
-            try:
-                job.prepped = prep_job(job)
-            except Exception as exc:
-                note_prep_failure(job, exc)
-        if job.prepped is not None:
-            inputs, host_ok = job.prepped
-            host_ok_all[job.rows] = host_ok[: len(job.rows)]
-            if attempt is None:
-                attempt = health.begin_attempt("ed25519")
-            if attempt is not None:
-                try:
-                    if job.kind == "tables":
-                        runner = _run_chunk_tables
-                    elif job.kind == "resident":
-                        runner = _run_chunk_resident
-                    else:
-                        runner = _run_chunk
-                    with tracing.span(
-                        "dispatch_chunk",
-                        stage="dispatch",
-                        engine="ed25519",
-                        kind=job.kind,
-                        lanes=len(job.rows),
-                    ) as dsp:
-                        if dsp.live:
-                            dsp.set(
-                                padded_lanes=int(inputs["r"].shape[0]),
-                                h2d_bytes=_chunk_h2d_bytes(inputs),
-                            )
-                        job.out, job.plan = runner(inputs, backend, plan)
-                        if dsp.live:
-                            dsp.set(impl=_chunk_impl(backend, job.plan))
-                    if job.plan is not None:
-                        mesh_used = True
-                        if job.plan is not plan:
-                            plan = job.plan  # degraded: later chunks follow
-                    health.note_inflight("ed25519", len(job.rows))
-                except Exception as exc:
-                    health.record_failure(exc, attempt)
-                    attempt = None
-                    import warnings
-
-                    warnings.warn(
-                        f"device chunk ({job.kind}, {len(job.rows)} lanes) "
-                        f"dispatch failed ({exc!r}); CPU fallback for the "
-                        f"chunk (device state={health.state})"
-                    )
-        if j + 1 < len(jobs):
-            nxt = jobs[j + 1]
-            try:
-                nxt.prepped = prep_job(nxt)
-            except Exception as exc:
-                note_prep_failure(nxt, exc)
-
-    if plan is not None and not mesh_used:
-        # Planned but never dispatched sharded (e.g. the shared health
-        # machine denied every chunk): release probe reservations.
-        _mesh_abandon(plan)
-
-    # Collect phase: JAX dispatch is async, so runtime errors can
-    # surface at materialization; those too degrade per chunk.
-    fallback_lanes = 0
-    device_chunks_ok = 0
-    for job in jobs:
-        ok = None
-        if job.out is not None:
-            try:
-                with tracing.span(
-                    "collect_chunk",
-                    stage="collect",
-                    engine="ed25519",
-                    kind=job.kind,
-                    lanes=len(job.rows),
-                ) as csp:
-                    fault_injection.fire("ed25519.collect")
-                    if job.plan is not None:
-                        from tendermint_tpu.parallel import (
-                            sharding as mesh_sharding,
-                        )
-
-                        ok = mesh_sharding.collect_sharded(job.out, "ed25519")
-                    else:
-                        ok = np.asarray(job.out)
-                    if csp.live:
-                        csp.set(d2h_bytes=int(ok.nbytes))
-                device_chunks_ok += 1
-                if job.plan is not None:
-                    _mesh_on_success(job.plan)
-            except Exception as exc:
-                if job.plan is not None:
-                    ok = _mesh_collect_retry(job, backend, exc)
-                if ok is not None:
-                    device_chunks_ok += 1
-                else:
-                    health.record_failure(exc, attempt)
-                    attempt = None
-                    import warnings
-
-                    warnings.warn(
-                        f"device chunk ({job.kind}, {len(job.rows)} lanes) "
-                        f"failed at collect ({exc!r}); CPU fallback for the "
-                        f"chunk (device state={health.state})"
-                    )
-            finally:
-                health.note_inflight("ed25519", -len(job.rows))
-        if not len(job.rows):
-            continue
-        if ok is None:
-            fallback_lanes += len(job.rows)
-            with tracing.span(
-                "host_fallback",
-                stage="fallback",
-                engine="ed25519",
-                lanes=len(job.rows),
-            ):
-                results[job.rows] = _host_verify_rows(
-                    pubkeys, msgs, sigs, job.rows
-                )
-            host_ok_all[job.rows] = True  # oracle verdicts are final
-        else:
-            results[job.rows] = ok[: len(job.rows)]
-
-    if fallback_lanes:
-        health.count_fallback("ed25519", fallback_lanes)
-    if attempt is not None and device_chunks_ok:
-        # No failure consumed the attempt and device work round-tripped:
-        # re-promote (clears DEGRADED, completes a half-open probe).
-        health.record_success(attempt)
-    return np.logical_and(results, host_ok_all)
+    return _run_jobs(
+        "ed25519", n, jobs, prep_job, host_verify, backend, plan, attempt
+    )

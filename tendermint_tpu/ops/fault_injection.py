@@ -8,10 +8,11 @@ latency, raise a transient error shape, or raise a permanent one.
 
 Sites currently instrumented:
 
-- ``ed25519.chunk``  — one CHUNK-size kernel dispatch in
-  ops/ed25519_batch._run_chunk
-- ``ed25519.collect`` — materialization of a dispatched chunk's result
-- ``sr25519.chunk``  — one kernel dispatch in ops/sr25519_batch
+- ``ed25519.chunk`` / ``sr25519.chunk`` — one chunk's kernel dispatch
+  in ops/ed25519_batch._run_chunk (one device) or
+  parallel/sharding.run_chunk_mesh (a mesh)
+- ``ed25519.collect`` / ``sr25519.collect`` — materialization of a
+  dispatched chunk's result in ops/ed25519_batch._run_jobs
 
 When no plan is installed the hook is a single global read — zero
 overhead on the hot path. Plans are process-global and thread-safe

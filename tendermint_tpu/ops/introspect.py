@@ -46,6 +46,7 @@ stays None so the hot path pays nothing.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
@@ -401,6 +402,7 @@ def traced_first_call(fn: Callable, engine: str, kernel: str, lanes: int):
     graph engines' version."""
     state = {"first": True}
 
+    @functools.wraps(fn)  # keeps the jitted program's name; ``__wrapped__`` is it
     def wrapper(*args, **kwargs):
         if state["first"]:
             state["first"] = False
@@ -427,17 +429,8 @@ def _exec_cache_entries() -> Dict[str, int]:
     ed = sys.modules.get("tendermint_tpu.ops.ed25519_batch")
     if ed is not None:
         try:
-            out["ed25519"] = (
-                ed._compiled_kernel.cache_info().currsize
-                + ed._compiled_kernel_tables.cache_info().currsize
-                + ed._compiled_kernel_resident.cache_info().currsize
-            )
-        except Exception:
-            pass  # cache introspection is best-effort; report what we can
-    sr = sys.modules.get("tendermint_tpu.ops.sr25519_batch")
-    if sr is not None:
-        try:
-            out["sr25519"] = sr._compiled_kernel_sr.cache_info().currsize
+            # the one XLA factory, serving every chunk kind of both engines
+            out["xla"] = ed._compiled_kernel.cache_info().currsize
         except Exception:
             pass  # cache introspection is best-effort; report what we can
     pl = sys.modules.get("tendermint_tpu.ops.pallas_verify")

@@ -25,23 +25,20 @@ re-verification is never needed on this path.
 
 from __future__ import annotations
 
-from functools import lru_cache
 from typing import List, Optional, Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from tendermint_tpu.libs import tracing
-from tendermint_tpu.ops import curve32 as curve, field32 as field
+from tendermint_tpu.ops import curve32 as curve, device_policy, field32 as field
+from tendermint_tpu.ops.chunk_kinds import ChunkInput, ChunkKind
 from tendermint_tpu.ops.ed25519_batch import (
-    CHUNK,
-    _bucket,
     _bytes_to_fe,
-    _mesh_abandon,
-    _mesh_bucket,
-    _mesh_on_success,
-    _mesh_plan,
+    _chunk_rows,
+    _Job,
+    _mesh_span,
+    _run_jobs,
     _to_windows_signed,
     canonical_lt,
     straus_sb_minus_ka,
@@ -151,21 +148,6 @@ def verify_kernel_sr(
     return is_ident & a_ok & r_ok
 
 
-@lru_cache(maxsize=16)
-def _compiled_kernel_sr(n: int, backend: Optional[str], mul_impl: str = "vpu"):
-    def run(pk, r, s, k):
-        # Under field32's trace lock: a concurrent ed25519 first-compile
-        # must not interleave its set/restore with ours.
-        with field.pinned_mul_impl(mul_impl):
-            return verify_kernel_sr(pk, r, s, k)
-
-    from tendermint_tpu.ops import introspect
-
-    return introspect.traced_first_call(
-        jax.jit(run, backend=backend), "sr25519", "verify_sr", n
-    )
-
-
 # --- host-side preparation --------------------------------------------------
 
 
@@ -176,7 +158,8 @@ def verify_batch_sr(
     backend: Optional[str] = None,
 ) -> List[bool]:
     """Per-entry schnorrkel batch verification on the device, host
-    Merlin challenges. Chunk dispatch is double-buffered: the Merlin
+    Merlin challenges. Chunks go through the dispatch loop shared with
+    ed25519 (ops/ed25519_batch._run_jobs), double-buffered: the Merlin
     transcript challenges of chunk j+1 — the expensive, sequential
     host work on this path — are computed while the device crunches
     chunk j (JAX async dispatch), instead of hashing the whole batch
@@ -189,9 +172,8 @@ def verify_batch_sr(
         _signing_transcript,
         verify as verify_host,
     )
-    from tendermint_tpu.ops import fault_injection
-    from tendermint_tpu.ops.device_policy import shared as health
 
+    health = device_policy.shared
     n = len(pubkeys)
     if n == 0:
         return []
@@ -226,234 +208,31 @@ def verify_batch_sr(
         host_ok &= canonical_lt(enc, _P_BYTES_BE)
         host_ok &= (enc[:, 0] & 1) == 0
 
-    try:
-        # Mesh plan: when one exists, chunk span and padding scale by
-        # the device count (same policy as ed25519's _verify_uncached);
-        # a plan degraded mid-batch replaces `plan` for later chunks.
-        plan = _mesh_plan(n)
-        span = CHUNK * plan.n_dev if plan is not None else CHUNK
-        m = _mesh_bucket(n, plan.n_dev) if plan is not None else _bucket(n)
-        mesh_used = False
-        pad = _pad_entry() if m > n else None
-        from tendermint_tpu.ops.ed25519_batch import (
-            _mul_impl_for_chunk,
-            active_impl,
-        )
-
-        impl = active_impl(backend)
-        mul_impl = _mul_impl_for_chunk(impl, backend, m)
-    except Exception as exc:
-        # Host-side prep failure before any device work.
-        health.record_failure(exc, attempt)
-        import warnings
-
-        warnings.warn(
-            f"sr25519 batch prepare failed ({exc!r}); host fallback "
-            f"(device state={health.state})"
-        )
-        health.count_fallback("sr25519", n)
-        return [verify_host(p, m, s) for p, m, s in zip(pubkeys, msgs, sigs)]
-
-    def prep_chunk(lo: int, hi: int):
-        """Merlin challenges + padding for lanes [lo, hi) — the host
+    def prep_job(job: _Job, pad_to: int) -> Tuple[dict, np.ndarray]:
+        """Merlin challenges + padding for one chunk's rows — the host
         half of the double buffer."""
-        with tracing.span(
-            "prep_chunk", stage="prep", engine="sr25519", lanes=hi - lo
-        ):
-            top = min(hi, n)
-            k_c = np.zeros((hi - lo, 32), dtype=np.uint8)
-            for i in range(lo, top):
-                if has_fields[i]:
-                    k = _challenge(
-                        _signing_transcript(msgs[i]), pubkeys[i], sigs[i][:32]
-                    )
-                    k_c[i - lo] = np.frombuffer(
-                        k.to_bytes(32, "little"), dtype=np.uint8
-                    )
-            if hi > top:
-                pad_pk, pad_r, pad_s, pad_k = pad
-                npad = hi - top
-                pk_c = np.concatenate(
-                    [pk_arr[lo:top], np.tile(pad_pk, (npad, 1))]
+        rows = job.rows
+        k_c = np.zeros((len(rows), 32), dtype=np.uint8)
+        for j, i in enumerate(rows):
+            if has_fields[i]:
+                k = _challenge(
+                    _signing_transcript(msgs[i]), pubkeys[i], sigs[i][:32]
                 )
-                r_c = np.concatenate([r_arr[lo:top], np.tile(pad_r, (npad, 1))])
-                s_c = np.concatenate([s_arr[lo:top], np.tile(pad_s, (npad, 1))])
-                k_c[top - lo :] = pad_k
-            else:
-                pk_c, r_c, s_c = pk_arr[lo:hi], r_arr[lo:hi], s_arr[lo:hi]
-            return pk_c, r_c, s_c, k_c
+                k_c[j] = np.frombuffer(k.to_bytes(32, "little"), dtype=np.uint8)
+        inputs = dict(pk=pk_arr[rows], r=r_arr[rows], s=s_arr[rows], k=k_c)
+        return SR25519.pad_lanes(inputs, pad_to - len(rows)), host_ok[rows]
 
-    # Double-buffered dispatch: enqueue chunk j's kernel (async), then
-    # hash chunk j+1's challenges while the device crunches chunk j. A
-    # failing chunk falls back to the host oracle for ITS lanes only;
-    # the health machine decides whether the remaining chunks may still
-    # use the device.
-    bounds = [(lo, min(lo + span, m)) for lo in range(0, m, span)]
-    preps: List[Optional[tuple]] = [None] * len(bounds)
-    chunks = []  # (lo, hi, device result or None, mesh plan or None)
-    for ci, (lo, hi) in enumerate(bounds):
-        if ci == 0:
-            try:
-                preps[0] = prep_chunk(lo, hi)
-            except Exception as exc:
-                health.record_failure(exc, attempt)
-                attempt = None
-                import warnings
+    def host_verify(rows) -> np.ndarray:
+        return np.array(
+            [verify_host(pubkeys[i], msgs[i], sigs[i]) for i in rows], dtype=bool
+        )
 
-                warnings.warn(
-                    f"sr25519 chunk [{lo}:{hi}] prepare failed ({exc!r}); "
-                    f"CPU fallback for the chunk (device state={health.state})"
-                )
-        out = None
-        chunk_plan = None
-        if preps[ci] is not None:
-            if attempt is None:
-                attempt = health.begin_attempt("sr25519")
-            if attempt is not None:
-                try:
-                    with tracing.span(
-                        "dispatch_chunk",
-                        stage="dispatch",
-                        engine="sr25519",
-                        lanes=hi - lo,
-                    ):
-                        if plan is not None:
-                            from tendermint_tpu.parallel import (
-                                sharding as mesh_sharding,
-                            )
-
-                            pk_c, r_c, s_c, k_c = preps[ci]
-                            try:
-                                out, chunk_plan = mesh_sharding.run_chunk_mesh(
-                                    "sr25519",
-                                    dict(pk=pk_c, r=r_c, s=s_c, k=k_c),
-                                    mul_impl,
-                                    plan,
-                                    "sr25519.chunk",
-                                )
-                                mesh_used = True
-                                if chunk_plan is not plan:
-                                    plan = chunk_plan  # degraded: later
-                                    # chunks ride the smaller mesh
-                            except mesh_sharding.MeshUnavailableError:
-                                # Every device excluded: single-device
-                                # dispatch below, not host fallback.
-                                plan = None
-                        if out is None:
-                            fault_injection.fire("sr25519.chunk")
-                            out = _compiled_kernel_sr(
-                                len(preps[ci][0]), backend, mul_impl
-                            )(*(jnp.asarray(a) for a in preps[ci]))
-                    health.note_inflight("sr25519", hi - lo)
-                except Exception as exc:
-                    health.record_failure(exc, attempt)
-                    attempt = None
-                    import warnings
-
-                    warnings.warn(
-                        f"sr25519 device chunk [{lo}:{hi}] dispatch failed "
-                        f"({exc!r}); CPU fallback for the chunk "
-                        f"(device state={health.state})"
-                    )
-        preps[ci] = None  # free the buffers once dispatched
-        chunks.append((lo, hi, out, chunk_plan))
-        if ci + 1 < len(bounds):
-            nlo, nhi = bounds[ci + 1]
-            try:
-                preps[ci + 1] = prep_chunk(nlo, nhi)
-            except Exception as exc:
-                health.record_failure(exc, attempt)
-                attempt = None
-                import warnings
-
-                warnings.warn(
-                    f"sr25519 chunk [{nlo}:{nhi}] prepare failed ({exc!r}); "
-                    f"CPU fallback for the chunk (device state={health.state})"
-                )
-
-    if plan is not None and not mesh_used:
-        # Planned but never dispatched sharded: release probe slots.
-        _mesh_abandon(plan)
-
-    # Collect phase: async dispatch surfaces runtime errors here too.
-    results = np.ones(m, dtype=bool)
-    fallback_lanes = 0
-    device_chunks_ok = 0
-    for lo, hi, out, chunk_plan in chunks:
-        ok = None
-        if out is not None:
-            try:
-                with tracing.span(
-                    "collect_chunk",
-                    stage="collect",
-                    engine="sr25519",
-                    lanes=hi - lo,
-                ):
-                    if chunk_plan is not None:
-                        from tendermint_tpu.parallel import (
-                            sharding as mesh_sharding,
-                        )
-
-                        # Sharded re-pad may exceed hi - lo (e.g. a
-                        # degraded 7-way mesh); pad lanes verify true.
-                        ok = mesh_sharding.collect_sharded(out, "sr25519")[
-                            : hi - lo
-                        ]
-                    else:
-                        ok = np.asarray(out)
-                device_chunks_ok += 1
-                if chunk_plan is not None:
-                    _mesh_on_success(chunk_plan)
-            except Exception as exc:
-                culprit = None
-                if chunk_plan is not None:
-                    try:
-                        from tendermint_tpu.parallel import mesh as mesh_mod
-
-                        culprit = mesh_mod.manager.on_failure(chunk_plan, exc)
-                    except Exception:  # attribution is best-effort
-                        culprit = None
-                if culprit is None:
-                    # Unattributed: punish the shared machine as before.
-                    # (Attributed failures cooled the culprit device
-                    # only; the chunk still host-falls-back here — its
-                    # prep buffers were freed at dispatch, so there is
-                    # nothing left to re-dispatch, unlike ed25519.)
-                    health.record_failure(exc, attempt)
-                    attempt = None
-                import warnings
-
-                warnings.warn(
-                    f"sr25519 device chunk [{lo}:{hi}] failed at collect "
-                    f"({exc!r}); CPU fallback (device state={health.state})"
-                )
-            finally:
-                health.note_inflight("sr25519", -(hi - lo))
-        if ok is None:
-            ok = np.ones(hi - lo, dtype=bool)
-            top = min(hi, n)  # padded lanes need no host verify
-            if lo < top:
-                fallback_lanes += top - lo
-                with tracing.span(
-                    "host_fallback",
-                    stage="fallback",
-                    engine="sr25519",
-                    lanes=top - lo,
-                ):
-                    ok[: top - lo] = np.array(
-                        [
-                            verify_host(pubkeys[i], msgs[i], sigs[i])
-                            for i in range(lo, top)
-                        ],
-                        dtype=bool,
-                    )
-        results[lo:hi] = ok
-
-    if fallback_lanes:
-        health.count_fallback("sr25519", fallback_lanes)
-    if attempt is not None and device_chunks_ok:
-        health.record_success(attempt)
-    return [bool(v) for v in np.logical_and(results[:n], host_ok)]
+    plan, span = _mesh_span(n)
+    jobs = [_Job(SR25519, rows) for rows in _chunk_rows(np.arange(n), span)]
+    verdicts = _run_jobs(
+        "sr25519", n, jobs, prep_job, host_verify, backend, plan, attempt
+    )
+    return [bool(v) for v in verdicts]
 
 
 _PAD: Optional[Tuple[np.ndarray, ...]] = None
@@ -483,3 +262,14 @@ def _pad_entry() -> Tuple[np.ndarray, ...]:
             np.frombuffer(k.to_bytes(32, "little"), dtype=np.uint8),
         )
     return _PAD
+
+
+# What the kernel takes, and its pad lanes (ops/chunk_kinds.py). No
+# Pallas entry point: on every platform it is the XLA graph.
+SR25519 = ChunkKind(
+    "sr25519", "sr25519", "verify_sr", verify_kernel_sr, None,
+    tuple(
+        ChunkInput(name, 0, lambda i=i: _pad_entry()[i])
+        for i, name in enumerate(("pk", "r", "s", "k"))
+    ),
+)

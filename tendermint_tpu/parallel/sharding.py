@@ -1,16 +1,14 @@
 """Sharded batch verification over a device mesh.
 
 The TPU analog of the reference's task-level concurrency inventory
-(SURVEY.md §2.4): signature lanes are the data-parallel axis. All three
-kernel entry points — ed25519 build-on-device (ops/ed25519_batch
-.verify_kernel), the table-input cache-hit variant
-(verify_kernel_tables, with the gathered ``(8, 4, 32, N)`` precompute
-tensor sharded ``P(None, None, None, 'sig')`` so each device holds only
-its own lanes' tables), and sr25519 (ops/sr25519_batch
-.verify_kernel_sr) — are lane-local with no cross-signature
-communication, so sharding the lane axis over an ICI mesh partitions
-with zero collectives; XLA emits per-device slices and the only sync is
-the final per-lane bool gather.
+(SURVEY.md §2.4): signature lanes are the data-parallel axis. Every
+verify kernel — the ed25519 chunk kinds of ops/ed25519_batch.KINDS and
+sr25519's (ops/sr25519_batch.SR25519) — is lane-local with no
+cross-signature communication, so sharding the lane axis over an ICI
+mesh partitions with zero collectives; XLA emits per-device slices and
+the only sync is the final per-lane bool gather. What a kernel takes
+and where its lanes lie comes from its :class:`ChunkKind` record
+(ops/chunk_kinds.py); nothing here names a kind.
 
 This module is the mechanism half of the mesh engine: compile-cached
 sharded kernels, slab padding to a device multiple, the
@@ -32,14 +30,15 @@ from __future__ import annotations
 
 import warnings
 from functools import lru_cache
-from typing import List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tendermint_tpu.libs import tracing
-from tendermint_tpu.ops import ed25519_batch, field32 as field
+from tendermint_tpu.ops import fault_injection, field32 as field
+from tendermint_tpu.ops.chunk_kinds import ChunkKind
 from tendermint_tpu.parallel import mesh as mesh_mod
 from tendermint_tpu.parallel.mesh import SIG_AXIS
 
@@ -62,145 +61,48 @@ def make_mesh(n_devices: Optional[int] = None) -> Mesh:
 
 
 @lru_cache(maxsize=32)
-def _sharded_kernel(mesh: Mesh, kind: str, mul_impl: str):
-    """Jitted lane-sharded kernel per (mesh, entry point, field-mul
+def _sharded_kernel(mesh: Mesh, kind: ChunkKind, mul_impl: str):
+    """Jitted lane-sharded kernel per (mesh, chunk kind, field-mul
     impl). The mul impl is a trace-time switch on field32, pinned inside
     the traced fn (same rules as ops/ed25519_batch._compiled_kernel) and
-    therefore part of the cache key."""
-    rows = NamedSharding(mesh, P(SIG_AXIS, None))
-    lane = NamedSharding(mesh, P(SIG_AXIS))
-    if kind == "tables":
-        # (8, 4, 32, N): lanes on the LAST axis — each device gathers
-        # and holds only its own lanes' precompute tables.
-        tab = NamedSharding(mesh, P(None, None, None, SIG_AXIS))
+    therefore part of the cache key.
 
-        def run_tables(t, ok, r, s, k):
-            with field.pinned_mul_impl(mul_impl):
-                return ed25519_batch.verify_kernel_tables(t, ok, r, s, k)
+    Each input is sharded along its lane axis, so a device holds only
+    its own lanes' rows (and, for the gathered ``(8, 4, 32, N)`` table
+    input, its own lanes' tables). An input without lanes — the
+    resident store, keyed by distinct pubkey (a committee is ~100 KiB)
+    — is replicated, so the per-lane take inside the kernel is device-
+    local and comes out lane-sharded."""
 
-        return jax.jit(
-            run_tables,
-            in_shardings=(tab, lane, rows, rows, rows),
-            out_shardings=lane,
-        )
-    if kind == "resident":
-        # The resident store (8, 4, 32, K) is keyed by distinct pubkey,
-        # not lane: replicate it (a committee is ~100 KiB) so the
-        # per-lane take is device-local; the gathered tensor inside the
-        # kernel comes out lane-sharded like the "tables" input.
-        tab_rep = NamedSharding(mesh, P(None, None, None, None))
+    def spec(axis: Optional[int]) -> NamedSharding:
+        if axis is None:
+            return NamedSharding(mesh, P())
+        return NamedSharding(mesh, P(*(None,) * axis, SIG_AXIS))
 
-        def run_resident(t, idx, ok, r, s, k):
-            with field.pinned_mul_impl(mul_impl):
-                return ed25519_batch.verify_kernel_resident(t, idx, ok, r, s, k)
+    def run(*args):
+        with field.pinned_mul_impl(mul_impl):
+            return kind.kernel(*args)
 
-        return jax.jit(
-            run_resident,
-            in_shardings=(tab_rep, lane, lane, rows, rows, rows),
-            out_shardings=lane,
-        )
-    if kind == "sr25519":
-        from tendermint_tpu.ops import sr25519_batch
-
-        def run(pk, r, s, k):
-            with field.pinned_mul_impl(mul_impl):
-                return sr25519_batch.verify_kernel_sr(pk, r, s, k)
-
-    else:
-
-        def run(pk, r, s, k):
-            with field.pinned_mul_impl(mul_impl):
-                return ed25519_batch.verify_kernel(pk, r, s, k)
-
-    return jax.jit(run, in_shardings=(rows,) * 4, out_shardings=lane)
+    return jax.jit(
+        run,
+        in_shardings=tuple(spec(i.lane_axis) for i in kind.inputs),
+        out_shardings=spec(0),
+    )
 
 
 def sharded_verify_fn(mesh: Mesh):
     """Jitted ed25519 verify kernel with lane-axis sharding over
     ``mesh`` (back-compat entry point; see :func:`_sharded_kernel`)."""
-    return _sharded_kernel(mesh, "ed25519", field.get_mul_impl())
+    from tendermint_tpu.ops import ed25519_batch
 
-
-# --- slab padding -------------------------------------------------------------
-
-
-def _pad_for_mesh(kind: str, inputs: dict, n_dev: int) -> Tuple[dict, int]:
-    """Pad a prepped chunk to a multiple of ``n_dev`` lanes so every
-    device gets an identical slab. The engines already pad to
-    ``_mesh_bucket`` multiples for the planned mesh; this re-pad covers
-    dispatch on a DEGRADED mesh (8-way prep retried 7-way: 512 -> 518).
-    Pad lanes verify true and are sliced off at collect."""
-    m = int(inputs["r"].shape[0])
-    target = -(-m // n_dev) * n_dev
-    if target == m:
-        return inputs, m
-    extra = target - m
-    out = dict(inputs)
-    if kind == "sr25519":
-        from tendermint_tpu.ops import sr25519_batch
-
-        for key, row in zip(("pk", "r", "s", "k"), sr25519_batch._pad_entry()):
-            out[key] = np.concatenate(
-                [np.asarray(inputs[key]), np.tile(row.reshape(1, 32), (extra, 1))]
-            )
-        return out, target
-    if kind == "resident":
-        # the store tensor is untouched — pad lanes index column 0 (the
-        # pad-key table reserved at upload)
-        idx = np.asarray(inputs["idx"])
-        out["idx"] = np.concatenate([idx, np.zeros(extra, dtype=idx.dtype)])
-        ok = np.asarray(inputs["ok"])
-        out["ok"] = np.concatenate([ok, np.ones(extra, dtype=ok.dtype)])
-        for key, row in zip(("r", "s", "k"), ed25519_batch._pad_rows()[1:]):
-            out[key] = np.concatenate(
-                [np.asarray(inputs[key]), np.tile(row, (extra, 1))]
-            )
-        return out, target
-    if kind == "tables":
-        pad_tab = ed25519_batch._pad_table()  # (8, 4, 32) uint8
-        out["tab"] = np.concatenate(
-            [
-                np.asarray(inputs["tab"]),
-                np.broadcast_to(pad_tab[..., None], pad_tab.shape + (extra,)),
-            ],
-            axis=3,
-        )
-        ok = np.asarray(inputs["ok"])
-        out["ok"] = np.concatenate([ok, np.ones(extra, dtype=ok.dtype)])
-        keys = ("r", "s", "k")
-        pad_rows = ed25519_batch._pad_rows()[1:]
-    else:
-        keys = ("pk", "r", "s", "k")
-        pad_rows = ed25519_batch._pad_rows()
-    for key, row in zip(keys, pad_rows):
-        out[key] = np.concatenate([np.asarray(inputs[key]), np.tile(row, (extra, 1))])
-    return out, target
-
-
-def _kernel_args(kind: str, inputs: dict) -> tuple:
-    if kind == "resident":
-        return (
-            inputs["store"],
-            inputs["idx"],
-            inputs["ok"],
-            inputs["r"],
-            inputs["s"],
-            inputs["k"],
-        )
-    if kind == "tables":
-        return (inputs["tab"], inputs["ok"], inputs["r"], inputs["s"], inputs["k"])
-    return (inputs["pk"], inputs["r"], inputs["s"], inputs["k"])
+    return _sharded_kernel(mesh, ed25519_batch.KINDS["legacy"], field.get_mul_impl())
 
 
 # --- dispatch / collect -------------------------------------------------------
 
 
 def run_chunk_mesh(
-    kind: str,
-    inputs: dict,
-    mul_impl: str,
-    plan: "mesh_mod.MeshPlan",
-    fault_site: str,
+    kind: ChunkKind, inputs: dict, mul_impl: str, plan: "mesh_mod.MeshPlan"
 ):
     """Dispatch one prepped chunk lane-sharded across ``plan``'s mesh.
 
@@ -213,24 +115,27 @@ def run_chunk_mesh(
     and re-raises unattributed failures for the engine's ordinary
     per-chunk handling.
     """
-    from tendermint_tpu.ops import fault_injection
-
     mgr = mesh_mod.manager
-    engine = "sr25519" if kind == "sr25519" else "ed25519"
+    lanes = kind.lanes(inputs)
     while True:
-        padded, m = _pad_for_mesh(kind, inputs, plan.n_dev)
+        # Every device gets an identical slab. The engines already pad to
+        # ``_mesh_bucket`` multiples for the planned mesh; this re-pad
+        # covers dispatch on a DEGRADED mesh (8-way prep retried 7-way:
+        # 512 -> 518).
+        m = -(-lanes // plan.n_dev) * plan.n_dev
+        padded = kind.pad_lanes(inputs, m - lanes)
         fn = _sharded_kernel(plan.mesh, kind, mul_impl)
         try:
             with tracing.span(
                 "mesh_dispatch",
                 stage="mesh_dispatch",
-                engine=engine,
-                kind=kind,
+                engine=kind.engine,
+                kind=kind.name,
                 devices=plan.n_dev,
                 lanes=m,
             ):
-                fault_injection.fire(fault_site)
-                out = fn(*_kernel_args(kind, padded))
+                fault_injection.fire(kind.engine + ".chunk")
+                out = fn(*kind.args(padded))
         except Exception as exc:
             culprit = mgr.on_failure(plan, exc)
             if culprit is None:
@@ -240,7 +145,7 @@ def run_chunk_mesh(
                 raise MeshUnavailableError(
                     f"device {culprit} excluded and no usable mesh remains"
                 ) from exc
-            if kind == "resident":
+            if kind.store_bound:
                 # the resident store tensor is committed to THIS mesh;
                 # a rebuilt smaller mesh can't consume it — hand back so
                 # the engine re-ships this chunk's columns explicitly
@@ -249,7 +154,7 @@ def run_chunk_mesh(
                     "to the dead mesh"
                 ) from exc
             warnings.warn(
-                f"sharded {kind} chunk failed on device {culprit} ({exc!r}); "
+                f"sharded {kind.name} chunk failed on device {culprit} ({exc!r}); "
                 f"retrying on a {nxt.n_dev}-device mesh"
             )
             plan = nxt
@@ -258,7 +163,7 @@ def run_chunk_mesh(
         per_dev = m // plan.n_dev
         for did in plan.device_ids:
             tracing.instant(
-                "mesh_device_dispatch", device=did, engine=engine, lanes=per_dev
+                "mesh_device_dispatch", device=did, engine=kind.engine, lanes=per_dev
             )
         return out, plan
 
@@ -310,6 +215,8 @@ def verify_batch_sharded(
     parity tests and warmup). With ``mesh=None`` the engines plan
     against the configured mesh themselves.
     """
+    from tendermint_tpu.ops import ed25519_batch
+
     n = len(pubkeys)
     if n == 0:
         return []

@@ -93,7 +93,7 @@ def test_kernel_failure_fails_the_phase_instead_of_passing_on_the_oracle(
     """With a dead kernel every verdict still comes out right — from
     the host oracle. That is exactly what the smoke must not accept."""
 
-    def boom(n, backend, mul_impl="vpu"):
+    def boom(kind, n, backend, mul_impl):
         raise RuntimeError("injected kernel failure")
 
     monkeypatch.setattr(ed25519_batch, "_compiled_kernel", boom)

@@ -144,3 +144,67 @@ def test_later_calls_leave_out_what_ran_before_the_window():
     assert span_time_per_later_call.read(ev, ["gc_pause"]) == pytest.approx(0.15)
     ev.calls = [first]
     assert span_time_per_later_call.read(ev, ["gc_pause"]) is None
+
+
+# --- the contract between the program and the benchmark's readers -----------
+
+
+@pytest.mark.parametrize("kind", ["legacy", "tables", "resident"])
+def test_program_still_emits_what_the_readers_match(kind, monkeypatch):
+    """What ``chipbench`` reads from a verify call, held on one CPU
+    ``verify_batch`` a chunk kind: the ``dispatch_chunk`` span names the
+    kind and carries ``lanes``/``padded_lanes``/``h2d_bytes``/``impl``;
+    the ``kernel_compile`` span names the kernel
+    ``pad_lane_share.KERNEL_OF_KIND`` maps the kind to; and the jitted
+    program is called ``run…``, which is what ``kernel_ms.*`` and
+    ``verify_roofline.*`` find on the device trace (``jit_run*``). A
+    refactor that renames any of these fails here, not on the chip."""
+    from chipbench.readers.pad_lane_share import KERNEL_OF_KIND
+    from tendermint_tpu.crypto.keys import Ed25519PrivKey
+    from tendermint_tpu.libs import tracing
+    from tendermint_tpu.ops import ed25519_batch, precompute, resident
+
+    pks, msgs, sigs = [], [], []
+    for i in range(3):
+        priv = Ed25519PrivKey.from_seed(bytes([i + 71]) * 32)
+        msgs.append(b"contract-%d" % i)
+        pks.append(priv.pub_key().bytes())
+        sigs.append(priv.sign(msgs[-1]))
+
+    # an uncached factory: every chunk builds (and names) its program anew,
+    # without emptying the cache the rest of the suite is using
+    made = []
+    build = ed25519_batch._compiled_kernel.__wrapped__
+
+    def factory(*key):
+        made.append(build(*key))
+        return made[-1]
+
+    monkeypatch.setattr(ed25519_batch, "_compiled_kernel", factory)
+    monkeypatch.setenv(precompute._RESULT_ENV, "0")
+    if kind == "resident":
+        monkeypatch.setenv("TENDERMINT_TPU_RESIDENT", "on")
+    precompute.reset()
+    resident.reset()
+    if kind != "legacy":
+        precompute.pin_pubkeys(set(pks))
+    tracing.tracer.set_metrics_observer(None)
+    tracing.configure("ring")
+    tracing.tracer.clear()
+    try:
+        assert ed25519_batch.verify_batch(pks, msgs, sigs) == [True] * 3
+        events = tracing.tracer.export(clear=True)["traceEvents"]
+    finally:
+        tracing.configure("off")
+        tracing.tracer.clear()
+        precompute.reset()
+        resident.reset()
+    (chunk,) = [e["args"] for e in events if e.get("name") == "dispatch_chunk"]
+    assert chunk["kind"] == kind and chunk["lanes"] == 3
+    assert chunk["padded_lanes"] == 64 and chunk["impl"] == "xla"
+    assert chunk["h2d_bytes"] > 0
+    (compiled,) = [e["args"] for e in events if e.get("name") == "kernel_compile"]
+    assert compiled["kernel"] == KERNEL_OF_KIND[kind]
+    assert (compiled["engine"], compiled["lanes"]) == ("ed25519", 64)
+    (fn,) = made
+    assert fn.__name__.startswith("run") and fn.__wrapped__.__name__ == fn.__name__

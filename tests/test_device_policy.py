@@ -340,92 +340,135 @@ def test_env_plan_parsing():
 
 
 # --- acceptance: the real verify path under injected faults ------------------
+#
+# Both engines reach the device through one loop (ops/ed25519_batch
+# ._run_jobs), so each of these runs once an engine: ``eng.name`` is the
+# engine's fault-site prefix, ``eng.verify`` its batch entry point and
+# ``eng.batch(bad=...)`` well-formed lanes with wrong signatures at
+# ``bad`` (sr25519 batches are small: its host prep is pure Python).
 
 
-def test_transient_fault_zero_failed_verifications_and_recovery(monkeypatch):
+class _Engine:
+    def __init__(self, name, verify, lanes, make):
+        self.name, self.verify, self.lanes, self._make = name, verify, lanes, make
+
+    def batch(self, n=None, bad=()):
+        return self._make(self.lanes if n is None else n, bad)
+
+
+def _make_batch_sr(n, bad=()):
+    from tendermint_tpu.crypto.sr25519 import Sr25519PrivKey
+
+    pks, msgs, sigs = [], [], []
+    for i in range(n):
+        priv = Sr25519PrivKey.from_secret(b"policy-%d" % i)
+        pks.append(priv.pub_key().bytes())
+        msgs.append(b"tampered" if i in bad else b"vote-%d" % i)
+        sigs.append(priv.sign(b"vote-%d" % i))
+    return pks, msgs, sigs
+
+
+def _verify_batch_sr(pks, msgs, sigs):
+    from tendermint_tpu.ops.sr25519_batch import verify_batch_sr
+
+    return verify_batch_sr(pks, msgs, sigs)
+
+
+@pytest.fixture(
+    params=[
+        _Engine("ed25519", verify_batch, 20, make_batch),
+        _Engine("sr25519", _verify_batch_sr, 8, _make_batch_sr),
+    ],
+    ids=lambda e: e.name,
+)
+def eng(request):
+    return request.param
+
+
+def test_transient_fault_zero_failed_verifications_and_recovery(monkeypatch, eng):
     """ISSUE acceptance: a transient device failure mid-run costs ZERO
     failed verifications (CPU fallback absorbs the chunk) and the
     machine recovers HEALTHY -> COOLDOWN -> HEALTHY automatically."""
     clk = FakeClock()
     h = DeviceHealth(retry_budget=1, cooldown_base=1.0, clock=clk)
     monkeypatch.setattr(device_policy, "shared", h)
-    pks, msgs, sigs = make_batch(20)
+    pks, msgs, sigs = eng.batch()
 
-    with fault_injection.inject(site="ed25519", fail_calls=(1,)):
+    with fault_injection.inject(site=eng.name, fail_calls=(1,)):
         with pytest.warns(UserWarning):
-            oks = verify_batch(pks, msgs, sigs)
+            oks = eng.verify(pks, msgs, sigs)
     assert all(oks), "CPU fallback must absorb the injected fault"
     assert h.state == COOLDOWN  # retry_budget=1: straight to cooldown
     assert (HEALTHY, COOLDOWN) in h.transitions
 
     # during cooldown the whole batch takes the CPU path instantly
     before = h.snapshot()["fallback_batches"]
-    assert all(verify_batch(pks, msgs, sigs))
+    assert all(eng.verify(pks, msgs, sigs))
     assert h.snapshot()["fallback_batches"] > before
     assert h.state == COOLDOWN
 
     # backoff expires -> the next batch is the half-open probe -> HEALTHY
     clk.advance(1.5)
-    assert all(verify_batch(pks, msgs, sigs))
+    assert all(eng.verify(pks, msgs, sigs))
     assert h.state == HEALTHY
     assert h.transitions == [(HEALTHY, COOLDOWN), (COOLDOWN, HEALTHY)]
 
 
-def test_transient_fault_still_rejects_bad_signatures(monkeypatch):
+def test_transient_fault_still_rejects_bad_signatures(monkeypatch, eng):
     """The CPU fallback is a verifier, not a rubber stamp."""
     h = DeviceHealth(retry_budget=1, clock=FakeClock())
     monkeypatch.setattr(device_policy, "shared", h)
-    pks, msgs, sigs = make_batch(20, bad=(3, 7))
-    with fault_injection.inject(site="ed25519", fail_from=1, fail_count=100):
+    pks, msgs, sigs = eng.batch(bad=(3, 7))
+    with fault_injection.inject(site=eng.name, fail_from=1, fail_count=100):
         with pytest.warns(UserWarning):
-            oks = verify_batch(pks, msgs, sigs)
+            oks = eng.verify(pks, msgs, sigs)
     assert oks[3] is False and oks[7] is False
-    assert sum(oks) == 18
+    assert sum(oks) == eng.lanes - 2
 
 
-def test_permanent_fault_disables_and_completes_on_cpu(monkeypatch):
+def test_permanent_fault_disables_and_completes_on_cpu(monkeypatch, eng):
     """ISSUE acceptance: a permanent failure leaves every verification
     answered (on CPU), the machine DISABLED, and metrics exposing it."""
     reg = Registry()
     h = DeviceHealth(clock=FakeClock())
     h.bind_metrics(OpsMetrics(reg))
     monkeypatch.setattr(device_policy, "shared", h)
-    pks, msgs, sigs = make_batch(20, bad=(5,))
+    pks, msgs, sigs = eng.batch(bad=(5,))
 
-    with fault_injection.inject(site="ed25519", fail_calls=(1,), permanent=True):
+    with fault_injection.inject(site=eng.name, fail_calls=(1,), permanent=True):
         with pytest.warns(UserWarning):
-            oks = verify_batch(pks, msgs, sigs)
-    assert sum(oks) == 19 and oks[5] is False
+            oks = eng.verify(pks, msgs, sigs)
+    assert sum(oks) == eng.lanes - 1 and oks[5] is False
     assert h.state == DISABLED and h.broken
 
     # later batches never touch the device again, still all answered
-    oks = verify_batch(pks, msgs, sigs)
-    assert sum(oks) == 19
+    oks = eng.verify(pks, msgs, sigs)
+    assert sum(oks) == eng.lanes - 1
     text = reg.expose()
     assert "tendermint_ops_device_health_state 3" in text
     assert 'tendermint_ops_device_failures_total{kind="permanent"} 1' in text
-    assert 'tendermint_ops_device_fallbacks_total{engine="ed25519"}' in text
+    assert 'tendermint_ops_device_fallbacks_total{engine="%s"}' % eng.name in text
 
 
-def test_collect_phase_fault_patched_per_chunk(monkeypatch):
+def test_collect_phase_fault_patched_per_chunk(monkeypatch, eng):
     """Async dispatch surfaces runtime errors at materialization; a
     collect-phase fault must be absorbed chunk-locally too."""
     h = DeviceHealth(retry_budget=5, clock=FakeClock())
     monkeypatch.setattr(device_policy, "shared", h)
-    pks, msgs, sigs = make_batch(20)
-    with fault_injection.inject(site="ed25519.collect", fail_calls=(1,)):
+    pks, msgs, sigs = eng.batch()
+    with fault_injection.inject(site=eng.name + ".collect", fail_calls=(1,)):
         with pytest.warns(UserWarning):
-            oks = verify_batch(pks, msgs, sigs)
+            oks = eng.verify(pks, msgs, sigs)
     assert all(oks)
     assert h.failure_counts[TRANSIENT] == 1
 
 
-def test_injected_latency_does_not_fail_calls(monkeypatch):
+def test_injected_latency_does_not_fail_calls(monkeypatch, eng):
     h = DeviceHealth(clock=FakeClock())
     monkeypatch.setattr(device_policy, "shared", h)
-    pks, msgs, sigs = make_batch(4)
-    with fault_injection.inject(site="ed25519", latency=0.01) as plan:
-        oks = verify_batch(pks, msgs, sigs)
+    pks, msgs, sigs = eng.batch(4)
+    with fault_injection.inject(site=eng.name, latency=0.01) as plan:
+        oks = eng.verify(pks, msgs, sigs)
     assert all(oks)
     assert plan.calls >= 1 and plan.faults_raised == 0
     assert h.state == HEALTHY

@@ -291,6 +291,15 @@ class TestCompileAccounting:
         assert pallas_verify.compiled_verify_resident.cache_info().currsize >= 1
         assert introspect._exec_cache_entries()["pallas"] == want
 
+    def test_xla_exec_cache_reads_the_one_factory(self):
+        from tendermint_tpu.ops import ed25519_batch
+
+        # builds the jitted wrapper; nothing compiles until it is called
+        ed25519_batch._compiled_kernel(ed25519_batch.KINDS["tables"], 8, None, "vpu")
+        size = ed25519_batch._compiled_kernel.cache_info().currsize
+        assert size >= 1
+        assert introspect._exec_cache_entries()["xla"] == size
+
     def test_counter_mirrors(self):
         ops = OpsMetrics(Registry())
         introspect.bind_metrics(ops)

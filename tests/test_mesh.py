@@ -59,6 +59,23 @@ def triples():
     return pks, msgs, sigs
 
 
+@pytest.fixture(scope="module")
+def sr_triples():
+    """300 sr25519 lanes: they pad to the same 512-lane 8-way slab as
+    the ed25519 runs."""
+    from tendermint_tpu.crypto.sr25519 import Sr25519PrivKey
+
+    privs = [Sr25519PrivKey.from_secret(b"mesh-sr" + bytes([i])) for i in range(4)]
+    pks, msgs, sigs = [], [], []
+    for i in range(300):
+        p = privs[i % 4]
+        m = b"sr-mesh-%d" % i
+        pks.append(p.pub_key().bytes())
+        msgs.append(m)
+        sigs.append(p.sign(m))
+    return pks, msgs, sigs
+
+
 # --- attribution -----------------------------------------------------------
 
 
@@ -235,21 +252,12 @@ def test_scheduler_super_batch_sharded(ring, triples):
 # --- parity: sr25519 and the table kernel ----------------------------------
 
 
-def test_sr25519_sharded_parity(monkeypatch):
+def test_sr25519_sharded_parity(monkeypatch, sr_triples):
     """Sharded sr25519 verdicts == single-device verdicts, bad lanes
-    isolated. 300 lanes pad to the same 512-lane 8-way slab as the
-    ed25519 runs."""
-    from tendermint_tpu.crypto.sr25519 import Sr25519PrivKey
+    isolated."""
     from tendermint_tpu.ops.sr25519_batch import verify_batch_sr
 
-    privs = [Sr25519PrivKey.from_secret(b"mesh-sr" + bytes([i])) for i in range(4)]
-    pks, msgs, sigs = [], [], []
-    for i in range(300):
-        p = privs[i % 4]
-        m = b"sr-mesh-%d" % i
-        pks.append(p.pub_key().bytes())
-        msgs.append(m)
-        sigs.append(p.sign(m))
+    pks, msgs, sigs = (list(x) for x in sr_triples)
     sigs[5] = bytes(64)
     sigs[250] = sigs[249]
     sharded = sharding.verify_batch_sharded_sr(
@@ -325,21 +333,25 @@ def test_resident_kernel_sharded_parity(ring, triples, monkeypatch):
 # --- degradation: sick chip -> smaller mesh, never host --------------------
 
 
-def test_sick_device_degrades_to_seven_way(ring, triples):
+@pytest.mark.parametrize("engine", ["ed25519", "sr25519"])
+def test_sick_device_degrades_to_seven_way(ring, triples, sr_triples, engine):
     """Acceptance: killing one device mid-run rebuilds a 7-device mesh
     and continues sharded — no host fallback, no shared-health damage,
-    every lane correct."""
-    pks, msgs, sigs = (list(x) for x in triples)
+    every lane correct. Both engines: they share the runner."""
+    from tendermint_tpu.ops.sr25519_batch import verify_batch_sr
+
+    verify = ed25519_batch.verify_batch if engine == "ed25519" else verify_batch_sr
+    pks, msgs, sigs = (list(x) for x in (triples if engine == "ed25519" else sr_triples))
     sigs[100] = bytes(64)
     fb_before = shared_health.snapshot()["fallback_batches"]
     with fault_injection.inject(
-        site="ed25519.chunk",
+        site=engine + ".chunk",
         fail_from=1,
         fail_count=1,
         error_factory=lambda: DeviceFault("sick chip", device=3),
     ):
-        oks = ed25519_batch.verify_batch(pks, msgs, sigs)
-    assert not oks[100] and sum(oks) == LANES - 1
+        oks = verify(pks, msgs, sigs)
+    assert not oks[100] and sum(oks) == len(pks) - 1
     snap = mesh.manager.snapshot()
     assert snap["excluded"] == [3]
     assert snap["exclusions"] == 1

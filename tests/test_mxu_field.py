@@ -123,8 +123,9 @@ def test_mxu_verify_kernel_end_to_end(batch12):
     msgs[9] = b"a different message"
     inputs, host_ok = eb.prepare_batch(pks, msgs, sigs, pad_to=64)
     args = tuple(jnp.asarray(inputs[k]) for k in ("pk", "r", "s", "k"))
-    got_vpu = np.asarray(eb._compiled_kernel(64, None, "vpu")(*args))[:12]
-    got_mxu = np.asarray(eb._compiled_kernel(64, None, "mxu")(*args))[:12]
+    legacy = eb.KINDS["legacy"]
+    got_vpu = np.asarray(eb._compiled_kernel(legacy, 64, None, "vpu")(*args))[:12]
+    got_mxu = np.asarray(eb._compiled_kernel(legacy, 64, None, "mxu")(*args))[:12]
     want = [ref.verify_zip215(p, m, s) for p, m, s in zip(pks, msgs, sigs)]
     assert list(np.logical_and(got_mxu, host_ok[:12])) == want
     assert list(got_mxu) == list(got_vpu)

@@ -108,7 +108,8 @@ def test_pallas_off_curve_and_mutations(batch8):
 
 def pallas_verify_batch_tables(pks, msgs, sigs):
     """Table-input kernel: host-built precompute columns, no in-kernel
-    table construction. Mirrors _run_chunk_tables' pallas branch."""
+    table construction. What _run_chunk does with a ``tables`` chunk under
+    ``pallas``."""
     from tendermint_tpu.ops import precompute
 
     n = len(pks)
@@ -288,11 +289,19 @@ def _stub_kernel(calls, name):
     return factory
 
 
-@pytest.mark.parametrize("case", ["pallas", "xla", "mxu", "mesh", "context_mismatch"])
-def test_run_chunk_resident_picks_the_kernel(batch8, monkeypatch, case):
-    """On one device the resident runner follows active_impl like the
-    other two; a sharded chunk and a store committed elsewhere never
-    reach the Pallas entry."""
+_RUNNER_CASES = [
+    (kind, case)
+    for kind in ("legacy", "tables", "resident")
+    for case in ("pallas", "xla", "mxu", "mesh")
+] + [("resident", "context_mismatch")]
+
+
+@pytest.mark.parametrize("kind,case", _RUNNER_CASES)
+def test_run_chunk_picks_the_kernel(batch8, monkeypatch, kind, case):
+    """The one runner, for every chunk kind: on one device it follows
+    active_impl; a sharded chunk and a resident store committed
+    elsewhere never reach the kind's own Pallas entry; and the ``impl``
+    it returns is what the chunk was handed to."""
     from types import SimpleNamespace
 
     from tendermint_tpu.parallel import sharding
@@ -303,50 +312,56 @@ def test_run_chunk_resident_picks_the_kernel(batch8, monkeypatch, case):
     monkeypatch.setattr(
         ed25519_batch, "_mul_impl_for_chunk", lambda impl, backend, lanes: "vpu"
     )
-    monkeypatch.setattr(
-        pallas_verify, "compiled_verify_resident", _stub_kernel(calls, "pallas")
-    )
-    monkeypatch.setattr(
-        ed25519_batch, "_compiled_kernel_resident", _stub_kernel(calls, "xla")
-    )
+    for k in ed25519_batch.KINDS.values():
+        monkeypatch.setattr(
+            pallas_verify, k.pallas, _stub_kernel(calls, "pallas:" + k.name)
+        )
     monkeypatch.setattr(
         ed25519_batch,
-        "_run_chunk_tables",
-        lambda inputs, backend, plan=None: (
-            calls.append(("tables", inputs["r"].shape[0], inputs)),
-            None,
-        ),
+        "_compiled_kernel",
+        lambda k, n, backend, mul_impl: _stub_kernel(calls, "xla:" + k.name)(n),
     )
     monkeypatch.setattr(
         sharding,
         "run_chunk_mesh",
-        lambda kind, inputs, mul_impl, plan, site: (
-            calls.append(("mesh", inputs["r"].shape[0], kind)),
+        lambda k, inputs, mul_impl, plan: (
+            calls.append(("mesh", k.lanes(inputs), k.name)),
             plan,
         ),
     )
-    inputs, gathered, _ = _resident_chunk(batch8, 5, 8)
+    resident, gathered, _ = _resident_chunk(batch8, 5, 8)
+    if kind == "legacy":
+        pks, msgs, sigs = (list(x[:5]) for x in batch8)
+        inputs, _ = ed25519_batch.prepare_batch(pks, msgs, sigs, pad_to=8)
+    else:
+        inputs = resident if kind == "resident" else gathered
     plan = None
     if case == "mesh":
         plan = SimpleNamespace(device_ids=(0, 1))
-        inputs["mesh_key"] = (0, 1)
+        if kind == "resident":
+            inputs["mesh_key"] = (0, 1)
     elif case == "context_mismatch":
         inputs["mesh_key"] = (0, 1)  # uploaded for a mesh that is gone
-    out, used = ed25519_batch._run_chunk_resident(inputs, None, plan)
+    chunk_kind = ed25519_batch.KINDS[kind]
+    out, used, got_impl = ed25519_batch._run_chunk(chunk_kind, inputs, None, plan)
     ((name, n, got),) = calls
     assert n == 8 and used is plan
     if case == "mesh":
-        assert (name, got) == ("mesh", "resident")
+        assert (name, got, got_impl) == ("mesh", kind, "xla")
     elif case == "context_mismatch":
-        assert name == "tables"
-        np.testing.assert_array_equal(got["tab"], gathered["tab"])
+        # re-gathered on the host and re-entered as a gathered-table chunk
+        assert (name, got_impl) == ("pallas:tables", "pallas")
+        np.testing.assert_array_equal(np.asarray(got[0]), gathered["tab"])
     else:
-        assert name == ("pallas" if case == "pallas" else "xla")
-        assert out == "verdicts of " + name
-        assert got[0] is inputs["store"]
-        for arg, key in zip(got[1:], ("idx", "ok", "r", "s", "k")):
-            assert arg.shape[0] == 8
-            np.testing.assert_array_equal(np.asarray(arg), inputs[key])
+        assert name == ("pallas:" if case == "pallas" else "xla:") + kind
+        assert out == "verdicts of " + name and got_impl == impl
+        assert len(got) == len(chunk_kind.inputs)
+        for arg, spec in zip(got, chunk_kind.inputs):
+            if spec.lane_axis is None:
+                assert arg is inputs[spec.name]  # the store, as uploaded
+                continue
+            assert arg.shape[spec.lane_axis] == 8
+            np.testing.assert_array_equal(np.asarray(arg), inputs[spec.name])
 
 
 def test_auto_resolves_to_one_impl_per_platform(monkeypatch):

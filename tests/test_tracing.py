@@ -702,7 +702,8 @@ def test_h2d_bytes_is_the_summed_nbytes_of_the_chunk(ring, kind):
             s=np.zeros((64, 32), dtype=np.uint8),
             k=np.zeros((64, 32), dtype=np.uint8),
         )
-        assert ed25519_batch._chunk_h2d_bytes(inputs) == 64 * 4 + 64 + 3 * 64 * 32
+        resident = ed25519_batch.KINDS["resident"]
+        assert resident.h2d_bytes(inputs) == 64 * 4 + 64 + 3 * 64 * 32
         return
     pks, msgs, sigs = _raw_lanes(3)
     inputs, _ = ed25519_batch.prepare_batch(pks, msgs, sigs)
@@ -716,18 +717,20 @@ def test_h2d_bytes_is_the_summed_nbytes_of_the_chunk(ring, kind):
 
 @pytest.mark.parametrize("mode", ["ring", "off"])
 def test_dispatch_chunk_names_the_implementation_only_when_live(mode, monkeypatch):
-    """``impl`` is an argument of the live span; with the tracer off the
-    engine does not even ask which implementation it was."""
+    """``impl`` is an argument of the live span, and it is what the
+    runner says it handed the chunk to; with the tracer off nothing is
+    recorded."""
     from tendermint_tpu.ops import ed25519_batch
 
-    asked = []
-    real = ed25519_batch._chunk_impl
+    handed = []
+    real = ed25519_batch._run_chunk
 
-    def counting(backend, plan_used):
-        asked.append(plan_used)
-        return real(backend, plan_used)
+    def recording(kind, inputs, backend, plan=None):
+        out = real(kind, inputs, backend, plan)
+        handed.append(out[2])
+        return out
 
-    monkeypatch.setattr(ed25519_batch, "_chunk_impl", counting)
+    monkeypatch.setattr(ed25519_batch, "_run_chunk", recording)
     pks, msgs, sigs = _raw_lanes(3)
     tracing.tracer.set_metrics_observer(None)
     tracing.configure(mode)
@@ -738,12 +741,12 @@ def test_dispatch_chunk_names_the_implementation_only_when_live(mode, monkeypatc
     finally:
         tracing.configure("off")
         tracing.tracer.clear()
+    assert handed == ["xla"] == [ed25519_batch.active_impl()]
     if mode == "off":
-        assert asked == [] and events == []
+        assert events == []
         return
     (ev,) = [e for e in events if e["name"] == "dispatch_chunk"]
-    assert asked == [None]
-    assert ev["args"]["impl"] == ed25519_batch.active_impl() == "xla"
+    assert ev["args"]["impl"] == "xla"
 
 
 @pytest.mark.parametrize(
@@ -756,11 +759,33 @@ def test_dispatch_chunk_names_the_implementation_only_when_live(mode, monkeypatc
     ],
 )
 def test_chunk_impl_is_what_the_chunk_was_handed_to(monkeypatch, active, sharded, want):
-    from tendermint_tpu.ops import ed25519_batch
+    """The runner returns the implementation it chose; nothing derives
+    it a second time."""
+    from types import SimpleNamespace
+
+    from tendermint_tpu.ops import ed25519_batch, pallas_verify
+    from tendermint_tpu.parallel import sharding
 
     monkeypatch.setenv(ed25519_batch._IMPL_ENV, active)
-    plan_used = object() if sharded else None
-    assert ed25519_batch._chunk_impl(None, plan_used) == want
+    monkeypatch.setattr(
+        pallas_verify, "compiled_verify", lambda n: lambda *args: "pallas"
+    )
+    monkeypatch.setattr(
+        ed25519_batch,
+        "_compiled_kernel",
+        lambda kind, n, backend, mul_impl: lambda *args: mul_impl,
+    )
+    monkeypatch.setattr(
+        sharding, "run_chunk_mesh", lambda kind, inputs, mul_impl, plan: ("mesh", plan)
+    )
+    pks, msgs, sigs = _raw_lanes(3)
+    inputs, _ = ed25519_batch.prepare_batch(pks, msgs, sigs)
+    plan = SimpleNamespace(device_ids=(0, 1)) if sharded else None
+    out, used, impl = ed25519_batch._run_chunk(
+        ed25519_batch.KINDS["legacy"], inputs, None, plan
+    )
+    assert impl == want and used is plan
+    assert out == ("mesh" if sharded else {"pallas": "pallas", "mxu": "mxu"}.get(active, "vpu"))
 
 
 def test_profiler_capture_holds_the_program_spans(ring, tmp_path):
