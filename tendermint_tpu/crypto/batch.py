@@ -67,7 +67,7 @@ def tiered_verify_ed25519(pks, msgs, sigs) -> List[bool]:
     return list(verify_batch(pks, msgs, sigs))
 
 
-def note_validator_set(vals) -> bool:
+def note_validator_set(vals) -> Tuple[bool, bool]:
     """Register the active validator set with the device precompute
     cache (ops/precompute.py): its ed25519 keys become eligible for
     per-validator table caching, and stale keys from rotated-out sets
@@ -76,29 +76,32 @@ def note_validator_set(vals) -> bool:
     whole committee's traffic pins tables on ONE shard (partitioned,
     not replicated). Never raises — cache warm-up must not be able to
     fail a verification — and stays a no-op when the ops engine is
-    absent. Returns True when the cache had not seen the set before.
+    absent. Returns ``(newly_active, recognised)``: whether the cache
+    had not seen the set before, and whether it knew a live set by its
+    keys without hashing it (``precompute.activate_validator_set``).
     """
-    newly_active = False
     try:
         from tendermint_tpu.ops import precompute
     except ImportError:
-        precompute = None
-    if precompute is not None:
-        try:
-            newly_active = precompute.activate_validator_set(vals)
-        except Exception:
-            pass  # cache warm-up must never fail a verification
-    # federation routing hook: same best-effort contract
+        return False, False
+    noted = (False, False)
     try:
-        from tendermint_tpu.ops.precompute import _vset_ed25519_keys
+        noted = precompute.activate_validator_set(vals)
+    except Exception:
+        pass  # cache warm-up must never fail a verification
+    # federation routing hook: same best-effort contract; the key list
+    # is built and sorted only where a client exists to take it
+    try:
         from tendermint_tpu.verifyd import federation as vfederation
 
-        keys = _vset_ed25519_keys(vals)
-        if keys:
-            vfederation.note_validator_set(sorted(keys))
+        client = vfederation.federation_client()
+        if client is not None:
+            keys = precompute._vset_ed25519_keys(vals)
+            if keys:
+                client.note_validator_set(sorted(keys))
     except Exception:
         pass  # routing locality is an optimization, never a failure
-    return newly_active
+    return noted
 
 
 class BatchVerifier:

@@ -1,7 +1,8 @@
 """Batched host-side hashing for the device verifier.
 
 ``sha512_batch`` hashes N variable-length messages through a small C
-extension (``native/sha512_batch.c``, OpenMP-parallel, built lazily
+extension (``native/sha512_batch.c``, OpenMP-parallel from 1,024
+messages up, built lazily
 with the system compiler and loaded via ctypes) with a pure-hashlib
 fallback. ``sha512_batch_mod_l`` additionally reduces each 512-bit
 digest mod the ed25519 group order L with a vectorized numpy Barrett

@@ -4,7 +4,8 @@
  * k = SHA-512(R || A || M); everything else lives on device. This file
  * implements FIPS 180-4 SHA-512 from the spec and exposes one batch
  * entry point that hashes N variable-length messages (concatenated
- * buffer + offsets) into N 64-byte digests, parallelized with OpenMP.
+ * buffer + offsets) into N 64-byte digests, parallelized with OpenMP
+ * from PARALLEL_MIN_BATCH messages up.
  *
  * Replaces the reference's reliance on Go's crypto/sha512 inside
  * curve25519-voi's batch verifier (crypto/ed25519/ed25519.go:198-233).
@@ -18,6 +19,15 @@
 #ifdef _OPENMP
 #include <omp.h>
 #endif
+
+/* Below this many messages a batch is hashed by the calling thread
+ * alone. A parallel region wakes one worker a core, and libgomp's
+ * workers spin for milliseconds after it ends: on a host whose cores
+ * they fill, the accelerator runtime's transfer thread then waits ~4.5
+ * ms for a core in about half the calls (a 150-signature commit read
+ * 7.3 or 11.9 ms for it; PERF.md section 6, PR 29). Hashing 1,024 vote
+ * messages serially takes about a millisecond. */
+#define PARALLEL_MIN_BATCH 1024
 
 static const uint64_t K[80] = {
     0x428a2f98d728ae22ULL, 0x7137449123ef65cdULL, 0xb5c0fbcfec4d3b2fULL,
@@ -112,7 +122,7 @@ static void sha512_one(const uint8_t *msg, uint64_t len, uint8_t out[64]) {
 void sha512_batch(const uint8_t *buf, const uint64_t *offsets, int64_t n,
                   uint8_t *out) {
 #ifdef _OPENMP
-#pragma omp parallel for schedule(static)
+#pragma omp parallel for schedule(static) if (n >= PARALLEL_MIN_BATCH)
 #endif
   for (int64_t i = 0; i < n; i++) {
     sha512_one(buf + offsets[i], offsets[i + 1] - offsets[i],
@@ -182,7 +192,7 @@ static void sha512_final(sha512_ctx *c, uint8_t out[64]) {
 void sha512_batch_prefixed(const uint8_t *prefix, const uint8_t *buf,
                            const uint64_t *offsets, int64_t n, uint8_t *out) {
 #ifdef _OPENMP
-#pragma omp parallel for schedule(static)
+#pragma omp parallel for schedule(static) if (n >= PARALLEL_MIN_BATCH)
 #endif
   for (int64_t i = 0; i < n; i++) {
     sha512_ctx c;

@@ -45,6 +45,7 @@ import os
 import threading
 import time
 from collections import OrderedDict
+from operator import attrgetter
 from typing import Dict, FrozenSet, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -152,6 +153,9 @@ def build_table(pk: bytes) -> Tuple[np.ndarray, bool]:
     return tab, True
 
 
+_pub_key_of = attrgetter("pub_key")
+
+
 def _vset_ed25519_keys(vset) -> FrozenSet[bytes]:
     """Raw 32-byte ed25519 pubkeys of a ValidatorSet (best effort)."""
     keys = set()
@@ -177,9 +181,12 @@ class PrecomputeCache:
         self._entries: "OrderedDict[bytes, Tuple[np.ndarray, bool]]" = (
             OrderedDict()
         )  # guarded-by: _lock
-        self._active_sets: "OrderedDict[bytes, FrozenSet[bytes]]" = (
-            OrderedDict()
-        )  # guarded-by: _lock
+        # set hash -> (the set's pub_key objects in order, or None where
+        # the set could not be read; its raw ed25519 keys): one entry, so
+        # eviction at the cap and clear() drop both together.
+        self._active_sets: (
+            "OrderedDict[bytes, Tuple[Optional[tuple], FrozenSet[bytes]]]"
+        ) = OrderedDict()  # guarded-by: _lock
         self._eligible: FrozenSet[bytes] = frozenset()  # guarded-by: _lock
         self._pinned: set = set()  # guarded-by: _lock
         self._metrics = None  # guarded-by: _lock
@@ -189,6 +196,8 @@ class PrecomputeCache:
         self.evictions = 0  # guarded-by: _lock
         self.invalidations = 0  # guarded-by: _lock
         self.build_seconds = 0.0  # guarded-by: _lock
+        self.active_set_recognised = 0  # guarded-by: _lock
+        self.active_set_hashed = 0  # guarded-by: _lock
         self._pending_events: List[Tuple[str, tuple]] = []  # guarded-by: _lock
 
     # --- configuration ------------------------------------------------------
@@ -231,31 +240,56 @@ class PrecomputeCache:
 
     # --- validator-set awareness -------------------------------------------
 
-    def activate_validator_set(self, vset) -> bool:
+    def activate_validator_set(self, vset) -> Tuple[bool, bool]:
         """Mark a validator set live: its keys become table-eligible.
+        Returns ``(newly_active, recognised)``.
 
-        Re-activating a known set is a cheap LRU touch.  Activating a
-        new one registers its key set, retires the oldest live set
-        beyond the bound, and drops cached tables for keys that no
-        longer belong to any live set (committee rotation).  Returns
-        True when the set was newly registered.
+        A live set is recognised by the tuple of its validators'
+        ``pub_key`` objects, rebuilt on every call from what the set
+        holds now and compared with the live sets' tuples, newest first:
+        never by hashing it, never by a value remembered on the set.
+        Tuple equality tries identity before ``PubKey.__eq__`` and
+        ``Validator.copy()`` shares the key object (an immutable value),
+        so a set, its ``copy()`` and a copy with other priorities or
+        powers compare by pointers; a set decoded anew compares key by
+        key. Recognising one is an LRU touch. Eligibility depends on the
+        keys alone, so equal ordered keys under other powers touch the
+        live entry.
+
+        Any other set (a key replaced, added, removed or reordered; a
+        set this pass cannot read) is hashed: a Merkle root over every
+        validator's proto bytes, two orders of magnitude dearer than
+        recognising it (PERF.md §6, PR 29). A new hash registers the key
+        set, retires the oldest live set beyond the bound, and drops
+        cached tables for keys that no longer belong to any live set
+        (committee rotation).
         """
+        try:
+            pub_keys = tuple(map(_pub_key_of, vset.validators))
+        except (AttributeError, TypeError):  # only its hash() can tell
+            pub_keys = None
+        if pub_keys is not None:
+            with self._lock:
+                for vhash, (live, _) in reversed(self._active_sets.items()):
+                    if live == pub_keys:
+                        self._active_sets.move_to_end(vhash)
+                        self.active_set_recognised += 1
+                        return False, True
         try:
             vhash = vset.hash()
         except Exception:
-            return False
+            return False, False
         with self._lock:
+            self.active_set_hashed += 1
             if vhash in self._active_sets:
                 self._active_sets.move_to_end(vhash)
-                return False
-            keys = _vset_ed25519_keys(vset)
-            self._active_sets[vhash] = keys
+                return False, False
+            self._active_sets[vhash] = (pub_keys, _vset_ed25519_keys(vset))
             while len(self._active_sets) > _ACTIVE_SETS_CAP:
                 self._active_sets.popitem(last=False)
             self._recompute_eligible_locked()
-            newly = True
         self._flush_events()
-        return newly
+        return True, False
 
     def pin(self, pubkeys: Iterable[bytes]) -> None:
         """Make specific keys table-eligible outside any validator set."""
@@ -266,7 +300,7 @@ class PrecomputeCache:
 
     def _recompute_eligible_locked(self) -> None:
         eligible = set(self._pinned)
-        for keys in self._active_sets.values():
+        for _, keys in self._active_sets.values():
             eligible |= keys
         self._eligible = frozenset(eligible)
         if _mode() == "auto":
@@ -411,6 +445,8 @@ class PrecomputeCache:
                 "evictions": self.evictions,
                 "invalidations": self.invalidations,
                 "build_seconds": self.build_seconds,
+                "active_set_recognised": self.active_set_recognised,
+                "active_set_hashed": self.active_set_hashed,
             }
 
     def reset_stats(self) -> None:
@@ -418,6 +454,7 @@ class PrecomputeCache:
             self.hits = self.misses = self.builds = 0
             self.evictions = self.invalidations = 0
             self.build_seconds = 0.0
+            self.active_set_recognised = self.active_set_hashed = 0
 
     def clear(self) -> None:
         with self._lock:
@@ -521,7 +558,7 @@ tables = PrecomputeCache()
 results = ResultCache()
 
 
-def activate_validator_set(vset) -> bool:
+def activate_validator_set(vset) -> Tuple[bool, bool]:
     return tables.activate_validator_set(vset)
 
 

@@ -52,11 +52,10 @@ class CommitVerdict:
     error: Optional[Exception] = None
 
 
-def _note_validator_set_traced(vals: ValidatorSet) -> bool:
+def _note_validator_set_traced(vals: ValidatorSet) -> None:
     with tracing.span("note_validator_set", validators=len(vals)) as nsp:
-        newly_active = crypto_batch.note_validator_set(vals)
-        nsp.set(newly_active=newly_active)
-    return newly_active
+        newly_active, recognised = crypto_batch.note_validator_set(vals)
+        nsp.set(newly_active=newly_active, recognised=recognised)
 
 
 def _verify_light_alone(task: CommitTask) -> CommitVerdict:
@@ -111,8 +110,10 @@ def verify_commits_pipelined(
                     verdicts[t_i] = CommitVerdict(False, e)
                     refused_early += 1
                     continue
-                # Eligibility for the device precompute cache; a blocksync
-                # window reuses one validator set across most of its blocks.
+                # Eligibility for the device precompute cache, once a task:
+                # a blocksync window reuses one validator set across most
+                # of its blocks, and a live set is recognised by its key
+                # objects (precompute.activate_validator_set), not hashed.
                 note_set(task.vals)
                 needed = task.vals.total_voting_power() * 2 // 3
                 validators = task.vals.validators

@@ -7,8 +7,8 @@ fleet. This module scales the verification tier out: N verifyd shards
 (same host first; the addresses generalise to multi-host) with
 **client-side consistent-hash routing keyed by validator-set digest**.
 
-Routing key. ``note_validator_set`` (forwarded from
-``crypto/batch.note_validator_set``) digests each activated committee
+Routing key. ``FederationClient.note_validator_set`` (called by
+``crypto/batch.note_validator_set``) digests each noted committee
 (sha256 over its sorted pubkeys) and remembers which digest owns each
 key. A verify batch is partitioned by owning digest — every lane of a
 committee rides to the SAME shard, so that shard's ``note_hot_keys``
@@ -635,11 +635,3 @@ def federation_client() -> Optional[FederationClient]:
             _fed_client = FederationClient(shards)
             _fed_client_key = shards
         return _fed_client
-
-
-def note_validator_set(pubkeys: Sequence[bytes]) -> None:
-    """Routing hook for ``crypto/batch.note_validator_set``: keep the
-    committee's keys on one shard. No-op when unfederated."""
-    client = federation_client()
-    if client is not None:
-        client.note_validator_set(pubkeys)

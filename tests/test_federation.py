@@ -610,3 +610,55 @@ class TestBackendWiring:
             federation.reset_federation()
             for s in servers:
                 s.stop()
+
+    @pytest.mark.parametrize("live_before", [True, False], ids=["recognised_set", "new_set"])
+    @pytest.mark.parametrize("federated", [False, True], ids=["unfederated", "federated"])
+    def test_set_hook_asks_for_a_client_before_it_builds_the_keys(
+        self, monkeypatch, federated, live_before
+    ):
+        """``crypto.batch.note_validator_set``: with no federation the
+        routing hook builds no key list (the only one built is the
+        registry's own, for a set it had to register); with a client
+        it hands over the sorted keys on every call, whether the set
+        was recognised as live or not."""
+        from tendermint_tpu.crypto import batch as crypto_batch
+        from tendermint_tpu.ops import precompute
+        from tests.helpers import make_validators
+
+        _, vset = make_validators(5)
+        want = sorted(v.pub_key.bytes() for v in vset.validators)
+        servers = []
+        monkeypatch.delenv(federation.SHARDS_ENV, raising=False)
+        if federated:
+            servers, addrs = start_shards(2)
+            monkeypatch.setenv(federation.SHARDS_ENV, ",".join(addrs))
+        federation.reset_federation()
+        precompute.reset()
+        built, handed = [], []
+        real_keys = precompute._vset_ed25519_keys
+        monkeypatch.setattr(
+            precompute, "_vset_ed25519_keys", lambda v: built.append(1) or real_keys(v)
+        )
+        monkeypatch.setattr(
+            FederationClient,
+            "note_validator_set",
+            lambda self, pubkeys: handed.append(list(pubkeys)),
+        )
+        try:
+            if live_before:
+                assert precompute.activate_validator_set(vset) == (True, False)
+                del built[:]
+            noted = crypto_batch.note_validator_set(vset.copy())
+            assert noted == ((False, True) if live_before else (True, False))
+            registry = 0 if live_before else 1
+            assert len(built) == registry + federated
+            assert handed == ([want] if federated else [])
+            # every later call: nothing built unfederated, the same call federated
+            assert crypto_batch.note_validator_set(vset) == (False, True)
+            assert len(built) == registry + 2 * federated
+            assert handed == ([want, want] if federated else [])
+        finally:
+            federation.reset_federation()
+            precompute.reset()
+            for s in servers:
+                s.stop()

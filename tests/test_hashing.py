@@ -3,6 +3,7 @@
 import hashlib
 
 import numpy as np
+import pytest
 
 from tendermint_tpu.crypto import hashing
 
@@ -28,6 +29,20 @@ def test_sha512_batch_large_n():
     got = hashing.sha512_batch(msgs)
     for i in (0, 1, 499, 999):
         assert got[i].tobytes() == hashlib.sha512(msgs[i]).digest()
+
+
+@pytest.mark.parametrize("n", [1, 150, 1023, 1024, 1025, 4096])
+def test_both_batch_entries_match_hashlib_on_each_side_of_the_parallel_threshold(n):
+    """Batches under ``PARALLEL_MIN_BATCH`` (1,024) are hashed by the
+    caller's thread, larger ones by the OpenMP team: same digests."""
+    rng = np.random.default_rng(n)
+    msgs = [rng.bytes(100 + (i % 40)) for i in range(n)]
+    prefix = rng.integers(0, 256, (n, 64), dtype=np.uint8)
+    plain = hashing.sha512_batch(msgs)
+    prefixed = hashing.sha512_batch_prefixed(prefix, msgs)
+    for i in sorted({0, n // 3, n // 2, n - 2 if n > 1 else 0, n - 1}):
+        assert plain[i].tobytes() == hashlib.sha512(msgs[i]).digest(), i
+        assert prefixed[i].tobytes() == hashlib.sha512(prefix[i].tobytes() + msgs[i]).digest(), i
 
 
 def test_reduce_mod_l_random_and_edges():

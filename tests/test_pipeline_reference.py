@@ -293,6 +293,8 @@ def test_pipeline_spans_nest_and_count_what_was_sent(sets, ring):
     assert len(notes) == 4 and all(inside(s, loop) for s in notes)
     assert all(s["args"]["validators"] == 64 for s in notes)
     assert [s["args"]["newly_active"] for s in notes][1:] == [False] * 3
+    assert [s["args"]["recognised"] for s in notes][1:] == [True] * 3
+    assert all(s["args"]["recognised"] != s["args"]["newly_active"] for s in notes)
     # phase totals lie inside the loop's span
     a = loop["args"]
     assert a["basic_checks_n"] == 4 and a["sign_bytes_n"] == a["lanes"] == outer["args"]["lanes"]
@@ -308,6 +310,44 @@ def test_pipeline_spans_nest_and_count_what_was_sent(sets, ring):
     assert outer["args"]["tasks"] == 4 and outer["args"]["refused_early"] == 0
     m = merge["args"]
     assert (m["lanes"], m["blocks"], m["scan"]) == (4 * 43, 4, "first_bad_per_block")
+
+
+@pytest.mark.parametrize(
+    "carried, live_before, recognised",
+    [
+        ("one_object", False, 15),
+        ("one_object", True, 16),
+        ("a_copy_a_height", False, 15),
+        ("a_copy_a_height", True, 16),
+    ],
+)
+def test_a_window_over_one_set_hashes_it_at_most_once(sets, ring, carried, live_before, recognised):
+    """Sixteen tasks over one validator set, as a blocksync window
+    carries them (a node's state copies its set at every height): the
+    set is hashed by the first task that finds it unknown, and every
+    other task recognises it by its keys."""
+    from tendermint_tpu.ops import precompute
+
+    privs, vset = sets(12, False)
+    tasks = make_window(privs, vset, 16, seed=61)
+    if carried == "a_copy_a_height":
+        for task in tasks:
+            task.vals = task.vals.copy()
+    precompute.reset()
+    try:
+        if live_before:
+            assert precompute.activate_validator_set(vset) == (True, False)
+        before = precompute.tables.stats()
+        assert pipelined(tasks) == [OK] * 16
+        after = precompute.tables.stats()
+    finally:
+        precompute.reset()
+    notes = [s["args"] for s in spans_named(ring, "note_validator_set")]
+    assert len(notes) == 16
+    assert sum(a["recognised"] for a in notes) == recognised
+    assert sum(a["newly_active"] for a in notes) == 16 - recognised
+    assert after["active_set_hashed"] - before["active_set_hashed"] == 16 - recognised <= 1
+    assert after["active_set_recognised"] - before["active_set_recognised"] == recognised
 
 
 def test_blocksyncer_reaches_the_pipeline_with_windows_of_at_most_16(ring):

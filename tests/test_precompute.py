@@ -15,6 +15,8 @@ import pytest
 from tendermint_tpu.crypto import ed25519_ref as ref
 from tendermint_tpu.crypto.keys import Ed25519PrivKey
 from tendermint_tpu.ops import ed25519_batch, precompute, verify_batch
+from tendermint_tpu.types.validator import Validator
+from tendermint_tpu.types.validator_set import ValidatorSet
 from tests.helpers import CHAIN_ID, make_block_id, make_commit, make_validators
 
 
@@ -99,8 +101,8 @@ def test_auto_mode_gates_on_eligibility(monkeypatch):
 
 def test_activate_validator_set_makes_keys_eligible():
     privs, vset = _vset(1)
-    assert precompute.activate_validator_set(vset) is True
-    assert precompute.activate_validator_set(vset) is False  # LRU touch
+    assert precompute.activate_validator_set(vset) == (True, False)
+    assert precompute.activate_validator_set(vset) == (False, True)  # LRU touch
     pks = [v.pub_key.bytes() for v in vset.validators]
     entries, has = precompute.tables.gather(pks)
     assert has.all()
@@ -120,6 +122,170 @@ def test_rotation_invalidates_dropped_keys():
     assert len(precompute.tables) == 0
     assert precompute.tables.stats()["invalidations"] == 1
     assert precompute.tables.lookup(pk0) is None
+
+
+# --- a live set is recognised by its keys, never by hashing it (PR 29) ------
+
+
+def _other_key(i=0):
+    return Ed25519PrivKey.from_seed((7_000_000 + i).to_bytes(32, "big")).pub_key()
+
+
+def _reprioritised(v):
+    out = v.copy()
+    out.increment_proposer_priority(3)
+    return out
+
+
+def _other_powers(v):
+    out = v.copy()
+    for i, val in enumerate(out.validators):
+        val.voting_power += 1 + i
+    return out
+
+
+def _one_replaced(v):
+    out = v.copy()
+    gone = out.validators[1].copy()
+    gone.voting_power = 0
+    out.update_with_change_set([gone, Validator(_other_key(), 10)])
+    return out
+
+
+def _appended(v):
+    v.validators.append(Validator(_other_key(), 10))
+    return v
+
+
+def _popped(v):
+    v.validators.pop()
+    return v
+
+
+def _key_reassigned(v):
+    v.validators[0].pub_key = _other_key()
+    return v
+
+
+def _cleared(v):
+    precompute.tables.clear()
+    return v
+
+
+def _pushed_out_by_eight_newer_sets(v):
+    for off in range(2, 2 + precompute._ACTIVE_SETS_CAP):
+        assert precompute.activate_validator_set(_vset(off)[1]) == (True, False)
+    return v
+
+
+@pytest.fixture
+def cache_events():
+    events = []
+
+    def observer(kind, payload):
+        events.append((kind, payload))
+
+    precompute.register_observer(observer)
+    yield events
+    precompute.unregister_observer(observer)
+
+
+@pytest.mark.parametrize(
+    "derive, recognised",
+    [
+        pytest.param(lambda v: v, True, id="same_object"),
+        pytest.param(lambda v: v.copy(), True, id="copy"),
+        pytest.param(_reprioritised, True, id="copy_other_priorities"),
+        pytest.param(
+            lambda v: ValidatorSet.from_proto_bytes(v.to_proto_bytes()),
+            True,
+            id="decoded_anew_equal_keys_fresh_objects",
+        ),
+        pytest.param(_other_powers, True, id="same_ordered_keys_other_powers"),
+        pytest.param(_one_replaced, False, id="one_validator_replaced"),
+        pytest.param(_appended, False, id="validators_append_in_place"),
+        pytest.param(_popped, False, id="validators_pop_in_place"),
+        pytest.param(_key_reassigned, False, id="pub_key_reassigned"),
+        pytest.param(_cleared, False, id="clear_then_same_set"),
+        pytest.param(
+            _pushed_out_by_eight_newer_sets, False, id="ninth_set_evicts_the_first"
+        ),
+    ],
+)
+def test_live_set_is_recognised_by_its_current_keys(
+    derive, recognised, monkeypatch, cache_events
+):
+    """A set whose ordered keys are those of a live set is an LRU touch
+    that never calls ``ValidatorSet.hash``; a set whose keys changed in
+    any way, or that left the live window, is hashed and newly active
+    on the first call that carries it."""
+    _, vset = _vset(1, n=4)
+    assert precompute.activate_validator_set(vset) == (True, False)
+    pk0 = vset.validators[0].pub_key.bytes()
+    precompute.tables.gather([pk0])
+    assert precompute.tables.lookup(pk0) is not None
+    evicting = derive is _pushed_out_by_eight_newer_sets
+
+    candidate = derive(vset)
+
+    if evicting:  # tuple and key set went together, tables with them
+        assert ("rotation", (pk0,)) in cache_events
+        assert precompute.tables.lookup(pk0) is None
+        assert precompute.tables.stats()["active_sets"] == precompute._ACTIVE_SETS_CAP
+    hashed = []
+    real_hash = ValidatorSet.hash
+    monkeypatch.setattr(
+        ValidatorSet, "hash", lambda self: hashed.append(1) or real_hash(self)
+    )
+    before = precompute.tables.stats()
+    newly_active, was_recognised = precompute.activate_validator_set(candidate)
+    after = precompute.tables.stats()
+    if recognised:
+        assert (newly_active, was_recognised) == (False, True)
+        assert hashed == []
+        assert after["active_sets"] == before["active_sets"] == 1  # no new slot
+        assert after["active_set_recognised"] == before["active_set_recognised"] + 1
+        assert after["active_set_hashed"] == before["active_set_hashed"]
+        assert precompute.tables.lookup(pk0) is not None
+    else:
+        assert (newly_active, was_recognised) == (True, False)
+        assert hashed == [1]
+        assert after["active_set_hashed"] == before["active_set_hashed"] + 1
+        assert after["active_set_recognised"] == before["active_set_recognised"]
+        # and from now on it is the live set it says it is
+        assert precompute.activate_validator_set(candidate) == (False, True)
+        assert hashed == [1]
+        keys = [v.pub_key.bytes() for v in candidate.validators]
+        assert precompute.tables.gather(keys)[1].all()
+
+
+def test_recognition_touches_the_entry_it_found():
+    """A recognised set moves to the newest end of the live window, as a
+    hashed hit always did: seven more sets do not push it out."""
+    _, first = _vset(1)
+    _, second = _vset(2)
+    precompute.activate_validator_set(first)
+    precompute.activate_validator_set(second)
+    assert precompute.activate_validator_set(first.copy()) == (False, True)
+    for off in range(3, 2 + precompute._ACTIVE_SETS_CAP):
+        precompute.activate_validator_set(_vset(off)[1])
+    assert precompute.activate_validator_set(first) == (False, True)
+    assert precompute.activate_validator_set(second) == (True, False)
+
+
+def test_unreadable_set_takes_the_hash_path():
+    """An object with a ``hash()`` but no ``validators`` list is known
+    by that hash alone, and never recognised."""
+
+    class Opaque:
+        def hash(self):
+            return b"\x07" * 32
+
+    assert precompute.activate_validator_set(Opaque()) == (True, False)
+    assert precompute.activate_validator_set(Opaque()) == (False, False)
+    s = precompute.tables.stats()
+    assert s["active_set_hashed"] == 2 and s["active_set_recognised"] == 0
+    assert precompute.activate_validator_set(object()) == (False, False)
 
 
 def test_lru_eviction_bound(monkeypatch):
@@ -166,6 +332,59 @@ def test_concurrent_gather_is_threadsafe(monkeypatch):
     assert not errors
     # every key built exactly once, ever (gather serializes on the lock)
     assert precompute.tables.stats()["builds"] == len(pks)
+
+
+def test_concurrent_activation_counts_every_call_and_keeps_the_window():
+    """Sixteen threads activate copies of two live sets while two rotate
+    ten new ones through the window: every call is counted once, as
+    recognised or as hashed, and the window never outgrows its cap."""
+    import sys
+
+    live = [_vset(off)[1] for off in (1, 2)]
+    rotating = [_vset(off)[1] for off in range(3, 13)]
+    for v in live:
+        precompute.activate_validator_set(v)
+    precompute.tables.reset_stats()
+    results, errors = [], []
+
+    def reader(v):
+        try:
+            for _ in range(200):
+                results.append(precompute.activate_validator_set(v.copy()))
+                assert precompute.tables.stats()["active_sets"] <= precompute._ACTIVE_SETS_CAP
+        except Exception as exc:  # pragma: no cover - failure path
+            errors.append(exc)
+
+    def rotator(sets):
+        try:
+            for v in sets:
+                results.append(precompute.activate_validator_set(v))
+        except Exception as exc:  # pragma: no cover - failure path
+            errors.append(exc)
+
+    threads = [threading.Thread(target=reader, args=(live[i % 2],)) for i in range(16)]
+    threads += [threading.Thread(target=rotator, args=(rotating[i::2],)) for i in range(2)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors and not any(t.is_alive() for t in threads)
+    s = precompute.tables.stats()
+    assert len(results) == 16 * 200 + 10
+    assert s["active_set_recognised"] + s["active_set_hashed"] == len(results)
+    assert s["active_set_recognised"] == sum(r == (False, True) for r in results)
+    # a hashed set was unknown (newly active: each rotating set once, and
+    # a live one again if the rotation pushed it out between two reads)
+    # or registered by another thread in between
+    newly = sum(r == (True, False) for r in results)
+    assert newly >= 10
+    assert s["active_set_hashed"] == newly + sum(r == (False, False) for r in results)
+    assert s["active_sets"] == precompute._ACTIVE_SETS_CAP
 
 
 # --- result cache -----------------------------------------------------------

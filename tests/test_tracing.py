@@ -494,7 +494,7 @@ def test_commit_yields_each_new_span_once_under_its_parent(
 @pytest.mark.parametrize(
     "name,args",
     [
-        ("note_validator_set", {"validators": N_LANES, "newly_active": False}),
+        ("note_validator_set", {"validators": N_LANES, "newly_active": False, "recognised": True}),
         ("build_lanes", {"lanes": N_LANES, "sign_bytes_prefixes": 1}),
         ("batch_verify", {"key_type": "ed25519", "lanes": N_LANES, "route": "device"}),
         ("merge_verdicts", {"lanes": N_LANES}),
