@@ -332,12 +332,24 @@ def _check_oracle(pks, msgs, sigs, verdicts, rows, what: str) -> None:
     check(not wrong, "%s: device and oracle disagree on lanes %r", what, wrong[:16])
 
 
+def _fresh_node() -> None:
+    """Every committee below is a node's own, the first set its process
+    meets: the table cache builds that one at first sight and any later
+    one only in the third batch that carries a key
+    (``precompute.BUILD_AT_SIGHTING``). Dropping the cache (and with it
+    the device store, its observer) starts the next node."""
+    from tendermint_tpu.ops import precompute
+
+    precompute.reset()
+
+
 def _run_size(n: int, heights: int, dev: dict, impl: str, paths: dict) -> dict:
     import numpy as np
 
     from tendermint_tpu.parallel import mesh
     from tendermint_tpu.types.validation import InvalidCommitError, verify_commit
 
+    _fresh_node()
     what = "%d validators" % n
     t0 = time.monotonic()
     helpers, vset, commits = build_set(n, heights + 1)
@@ -474,6 +486,7 @@ def _run_pipelined_windows(n: int, window: int, paths: dict, impl: str) -> dict:
     tampered signature past the block's early exit."""
     from tendermint_tpu.parallel.pipeline import CommitTask, verify_commits_pipelined
 
+    _fresh_node()
     what = "%d-commit windows at %d validators" % (window, n)
     t0 = time.monotonic()
     helpers, vset, commits = build_set(n, 2 * window)

@@ -67,7 +67,7 @@ def tiered_verify_ed25519(pks, msgs, sigs) -> List[bool]:
     return list(verify_batch(pks, msgs, sigs))
 
 
-def note_validator_set(vals) -> Tuple[bool, bool]:
+def note_validator_set(vals, vhash: Optional[bytes] = None) -> Tuple[bool, bool]:
     """Register the active validator set with the device precompute
     cache (ops/precompute.py): its ed25519 keys become eligible for
     per-validator table caching, and stale keys from rotated-out sets
@@ -79,6 +79,9 @@ def note_validator_set(vals) -> Tuple[bool, bool]:
     absent. Returns ``(newly_active, recognised)``: whether the cache
     had not seen the set before, and whether it knew a live set by its
     keys without hashing it (``precompute.activate_validator_set``).
+    ``vhash`` is ``vals.hash()`` where the caller already holds it (the
+    light client has checked it against the header): a set that is not
+    recognised is then registered without being hashed again.
     """
     try:
         from tendermint_tpu.ops import precompute
@@ -86,7 +89,7 @@ def note_validator_set(vals) -> Tuple[bool, bool]:
         return False, False
     noted = (False, False)
     try:
-        noted = precompute.activate_validator_set(vals)
+        noted = precompute.activate_validator_set(vals, vhash)
     except Exception:
         pass  # cache warm-up must never fail a verification
     # federation routing hook: same best-effort contract; the key list

@@ -168,6 +168,9 @@ class _Pending:
     # t_dispatch, collect = respond time - t_done (server-side).
     t_dispatch: float = 0.0
     t_done: float = 0.0
+    # what ``submit_many(whole=True)`` put in together and ``_run`` must
+    # not cut: one token shared by the group's entries, else None
+    group: Optional[object] = None
 
     def due(self, max_delay: float) -> float:
         """Absolute monotonic time this entry must be flushed by."""
@@ -446,21 +449,32 @@ class VerifyScheduler:
         tag: Optional[object] = None,
         tenant: Optional[str] = None,
         trace: Optional[tracing.TraceContext] = None,
+        whole: bool = False,
     ) -> List[_Pending]:
         """Atomically enqueue a whole lane group under ONE lock round and
         ONE accumulator wake-up. This is the super-batch entry point for
-        callers that assemble many signatures at once (the light client's
-        bisection ladder): all-or-nothing against ``max_pending``, so a
-        half-admitted group can never split across two flushes on the
-        admission boundary. Pair with ``flush_by=time.monotonic()`` to
-        pull the flush immediately and spend exactly one device call on
-        the group."""
+        callers that assemble many signatures at once: all-or-nothing
+        against ``max_pending``, so a half-admitted group can never
+        split across two flushes on the admission boundary.
+
+        Against ``max_batch`` a group is cut like single submissions
+        (``_run``): a request longer than the limit leaves in pieces,
+        and a more urgent class that arrives behind it overtakes between
+        them (verifyd's requests, up to 4,096 lanes each). ``whole=True``
+        says the group is one unit of work whose caller waits for all of
+        it and planned it as one batch (the light client's round): the
+        accumulator then sends it in one flush however long it is,
+        beside whatever else fitted before it, and whoever arrives
+        behind it waits for that one call. Pair with
+        ``flush_by=time.monotonic()`` to pull the flush immediately and
+        spend exactly one ``verify_fn`` call on the group."""
         now = time.monotonic()
         if trace is None:
             trace = tracing.current_context()
+        group = object() if whole else None
         entries = [
-            _Pending(pk, msg, sig, now, priority=priority,
-                     flush_by=flush_by, tag=tag, tenant=tenant, trace=trace)
+            _Pending(pk, msg, sig, now, priority=priority, flush_by=flush_by,
+                     tag=tag, tenant=tenant, trace=trace, group=group)
             for pk, msg, sig in lanes
         ]
         with self._wake:
@@ -614,7 +628,15 @@ class VerifyScheduler:
                         self._pending,
                         key=lambda p: (p.priority, p.submitted),
                     )
-                    batch = order[:limit]
+                    # the cut never falls inside a group submitted
+                    # whole (its entries sort side by side): the one at
+                    # the cut leaves in this flush, however long it is
+                    end = limit
+                    group = order[end - 1].group
+                    if group is not None:
+                        while end < len(order) and order[end].group is group:
+                            end += 1
+                    batch = order[:end]
                     taken = {id(p) for p in batch}
                     self._pending = [
                         p for p in self._pending if id(p) not in taken

@@ -3,7 +3,10 @@
 Mirrors light/client.go: trust options anchor the first block (height +
 hash from a social-consensus source); VerifyLightBlockAtHeight then walks
 forward sequentially or by skipping (bisection against the trust level),
-or backwards via the hash chain. After verification the new block is
+or backwards via the hash chain. Skipping verification decides its walk
+on tallies and sends the signatures of the hops it took, each once, in
+one scheduler super-batch a round (light/batch.py): exactly those
+upstream's loop checks. After verification the new block is
 cross-checked against witness providers (light/detector.go); a
 conflicting header yields LightClientAttackEvidence reported to all
 providers.
@@ -79,7 +82,7 @@ class LightClient:
         sequential: bool = False,
         pruning_size: int = DEFAULT_PRUNING_SIZE,
         now: Optional[Callable[[], Timestamp]] = None,
-        bisect_batching: Optional[bool] = None,
+        bisect_batching: bool = True,
         metrics: Optional[LightMetrics] = None,
     ):
         trust_options.validate()
@@ -93,15 +96,17 @@ class LightClient:
         self.store = store or LightStore()
         self.sequential = sequential
         self.pruning_size = pruning_size
-        # one-super-batch-per-round bisection (light/batch.py); None
-        # defers to the TENDERMINT_TPU_LIGHT_BATCH env gate
-        self.bisect_batching = (
-            light_batch.batching_enabled()
-            if bisect_batching is None
-            else bisect_batching
-        )
+        # the walk planned on tallies, one super-batch a round
+        # (light/batch.py); False keeps upstream's one-call-per-pivot
+        # loop, the reference the parity tests hold the planner to
+        self.bisect_batching = bisect_batching
         self.metrics = metrics or LightMetrics.nop()
         self._now = now or (lambda: Timestamp.from_unix_ns(_time.time_ns()))
+        # what the last forward verification did (the light_verify span)
+        self._last_walk = dict.fromkeys(
+            ("hops", "refused_by_tally", "lanes", "merged"), 0
+        )
+        self._set_hashes: dict = {}
         self._initialize(trust_options)
 
     # --- initialization ------------------------------------------------------
@@ -150,34 +155,55 @@ class LightClient:
         """client.go VerifyLightBlockAtHeight:413."""
         if height <= 0:
             raise ValueError("height must be positive")
-        now = now or self._now()
-        existing = self.store.light_block(height)
-        if existing is not None:
-            return existing
-        latest = self.store.latest_light_block()
-        if latest is None:
-            raise LightClientError("no trusted state; initialize first")
-        if height < latest.height:
-            return self._backwards(latest, height)
-        target = self._fetch_from_primary(height)
-        self.verify_header(target, now)
-        return target
+        with tracing.span("light_verify", target=height) as sp:
+            now = now or self._now()
+            existing = self.store.light_block(height)
+            if existing is not None:
+                return existing
+            latest = self.store.latest_light_block()
+            if latest is None:
+                raise LightClientError("no trusted state; initialize first")
+            sp.set(base=latest.height)
+            if height < latest.height:
+                return self._backwards(latest, height)
+            target = self._fetch_from_primary(height)
+            try:
+                self._verify_header_from(latest, target, now)
+            finally:
+                sp.set(**self._last_walk)
+            return target
 
     def verify_header(self, new_block: LightBlock, now: Timestamp) -> None:
         """client.go VerifyHeader: forward verification + detector."""
         trusted = self.store.latest_light_block()
         if trusted is None:
             raise LightClientError("no trusted state")
+        self._verify_header_from(trusted, new_block, now)
+
+    def _verify_header_from(
+        self, trusted: LightBlock, new_block: LightBlock, now: Timestamp
+    ) -> None:
+        """``verify_header`` from the latest trusted block, which the
+        caller has read from the store (decoding one costs as much as
+        planning a hop)."""
         if new_block.height <= trusted.height:
             raise LightClientError(
                 f"height {new_block.height} is not above trusted "
                 f"{trusted.height}"
             )
-        new_block.validate_basic(self.chain_id)
-        if self.sequential:
-            self._verify_sequential(trusted, new_block, now)
-        else:
-            self._verify_skipping(trusted, new_block, now)
+        # id(validator set) -> (the set, its hash) for the blocks
+        # validated in this call (light/batch.SuperBatch)
+        self._set_hashes = {}
+        self._note_validated(new_block)
+        for key in self._last_walk:
+            self._last_walk[key] = 0
+        try:
+            if self.sequential:
+                self._verify_sequential(trusted, new_block, now)
+            else:
+                self._verify_skipping(trusted, new_block, now)
+        finally:
+            self._set_hashes = {}
         self._detect_divergence(new_block, now)
         self.store.save_light_block(new_block)
         if self.store.size() > self.pruning_size:
@@ -211,9 +237,10 @@ class LightClient:
     ) -> None:
         """client.go verifySkipping:647: bisection. Trust the target if
         trustLevel of the current trusted valset signed it; otherwise
-        bisect towards the trusted block. Batched by default: the whole
-        pivot ladder of a round rides one scheduler super-batch
-        (light/batch.py) instead of one device call per pivot."""
+        bisect towards the trusted block. Planned by default: the walk
+        is decided on tallies and its signatures ride one scheduler
+        super-batch (light/batch.py) instead of one device call per
+        pivot."""
         if self.bisect_batching:
             return self._verify_skipping_batched(trusted, new_block, now)
         return self._verify_skipping_sequential(trusted, new_block, now)
@@ -222,82 +249,103 @@ class LightClient:
         self, trusted: LightBlock, new_block: LightBlock, now: Timestamp
     ) -> None:
         """Same accept/reject decisions as the sequential loop, proved
-        by the parity suite: each round plans the full descending pivot
-        ladder [target, mid, mid-of-mid, ...] down to base+1, verifies
-        every candidate in ONE super-batch, then accepts the first
-        (shallowest) candidate that verifies — exactly the candidate the
-        sequential descent would have accepted. Hard errors surface at
-        the first candidate the sequential walk would have visited;
-        verdicts of deeper candidates are ignored past that point."""
-        pivots = {}  # height -> prefetched pivot, reused across rounds
-        trace_base = trusted
-        current = new_block
+        by the parity suite, with the signatures of a whole walk sent
+        once: a round follows upstream's loop on tallies alone — a
+        candidate the trusted set does not cover sends nothing and the
+        midpoint is tried, a covered one becomes the next base — until
+        the target is covered (``light_batch.Walk``), sends the lanes of
+        the hops it took in ONE super-batch, and folds the verdicts back
+        hop by hop: each verified pivot is saved as upstream saves it,
+        the first hop that fails raises upstream's error, and what the
+        walk owes beyond its hops (a pivot that could not be fetched,
+        "cannot split further") is raised once they have verified. A
+        second round runs only past a candidate the planner cannot
+        express, which ``verifier.verify`` judges on its own."""
+        base, current = trusted, new_block
         rounds = 0
+        stats = self._last_walk
         try:
             while True:
-                base = trace_base
-                candidates = [current]
-                # the exception owed if evaluation descends off the ladder:
-                # a pivot fetch/validate failure, or "cannot split further"
-                ladder_stop: Optional[Exception] = None
-                while ladder_stop is None:
-                    pivot_height = (base.height + candidates[-1].height) // 2
-                    if pivot_height in (base.height, candidates[-1].height):
-                        ladder_stop = LightClientError(
-                            "bisection failed: cannot split further"
-                        )
-                        break
-                    pivot = pivots.get(pivot_height)
-                    if pivot is None:
-                        try:
-                            pivot = self._fetch_from_primary(pivot_height)
-                            pivot.validate_basic(self.chain_id)
-                        except Exception as exc:
-                            ladder_stop = exc
-                            break
-                        pivots[pivot_height] = pivot
-                    candidates.append(pivot)
                 rounds += 1
                 with tracing.span(
                     "light_round",
                     round=rounds,
                     base=base.height,
-                    target=current.height,
-                    candidates=len(candidates),
-                ):
-                    outcomes = light_batch.evaluate_candidates(
-                        self.chain_id,
-                        base,
-                        candidates,
-                        self.trusting_period,
-                        now,
-                        self.max_clock_drift,
-                        self.trust_level,
-                    )
-                accepted = None
-                for cand, out in zip(candidates, outcomes):
-                    if out.kind == light_batch.OK:
-                        accepted = cand
-                        break
-                    if out.kind == light_batch.BISECT:
-                        continue
+                    target=new_block.height,
+                ) as rsp:
+                    with tracing.span("light_plan") as psp:
+                        walk = light_batch.Walk(
+                            self.chain_id,
+                            self.trusting_period,
+                            now,
+                            self.max_clock_drift,
+                            self.trust_level,
+                            psp,
+                            self._set_hashes,
+                        )
+                        walk.plan(base, current, new_block, self._fetch_pivot)
+                        psp.set(
+                            hops=len(walk.hops),
+                            refused_by_tally=walk.refused,
+                            lanes=len(walk.batch.lanes),
+                            merged=walk.batch.merged,
+                        )
+                    rsp.set(candidates=len(walk.hops) + walk.refused)
+                    stats["refused_by_tally"] += walk.refused
+                    stats["lanes"] += len(walk.batch.lanes)
+                    stats["merged"] += walk.batch.merged
+                    outcomes = walk.verify()
+                for plan, out in zip(walk.hops, outcomes):
+                    if out.kind != light_batch.OK:
+                        raise out.error
+                    stats["hops"] += 1
+                    if plan.cand.height == new_block.height:
+                        return
+                    self.store.save_light_block(plan.cand)
+                    base = plan.cand
+                if walk.stop is not None:
+                    raise walk.stop
+                # the walk met a candidate only the sequential verifier
+                # can judge: upstream's step for that one, then plan on
+                base, current = walk.unplanned
+                out = light_batch._resolve_sequential(
+                    self.chain_id, base, current, self.trusting_period, now,
+                    self.max_clock_drift, self.trust_level,
+                )
+                if out.kind == light_batch.BISECT:
+                    stats["refused_by_tally"] += 1
+                    current = self._fetch_pivot(base, current)
+                    continue
+                if out.kind != light_batch.OK:
                     raise out.error
-                if accepted is None:
-                    # every candidate needs a deeper pivot and there is none
-                    raise ladder_stop
-                if accepted.height == new_block.height:
+                stats["hops"] += 1
+                if current.height == new_block.height:
                     return
-                trace_base = accepted
-                self.store.save_light_block(accepted)
-                current = new_block
+                self.store.save_light_block(current)
+                base, current = current, new_block
         finally:
             self.metrics.bisection_rounds.observe(rounds)
+
+    def _fetch_pivot(self, base: LightBlock, current: LightBlock) -> LightBlock:
+        """The validated block half-way between the two, as upstream's
+        loop fetches it when ``current`` cannot be trusted from ``base``."""
+        pivot_height = (base.height + current.height) // 2
+        if pivot_height in (base.height, current.height):
+            raise LightClientError("bisection failed: cannot split further")
+        pivot = self._fetch_from_primary(pivot_height)
+        self._note_validated(pivot)
+        return pivot
+
+    def _note_validated(self, lb: LightBlock) -> None:
+        """``lb.validate_basic``, keeping the set's hash it computed."""
+        vals_hash = lb.validate_basic(self.chain_id)
+        self._set_hashes[id(lb.validator_set)] = (lb.validator_set, vals_hash)
 
     def _verify_skipping_sequential(
         self, trusted: LightBlock, new_block: LightBlock, now: Timestamp
     ) -> None:
         """The reference's one-call-per-pivot loop, kept verbatim as the
-        parity baseline (TENDERMINT_TPU_LIGHT_BATCH=off)."""
+        parity baseline (``bisect_batching=False``)."""
         verification_trace = [trusted]
         current = new_block
         while True:
@@ -315,14 +363,7 @@ class LightClient:
                 )
             except verifier.NewValSetCantBeTrustedError:
                 # Not enough trusted power: bisect to the midpoint.
-                pivot_height = (base.height + current.height) // 2
-                if pivot_height in (base.height, current.height):
-                    raise LightClientError(
-                        "bisection failed: cannot split further"
-                    )
-                pivot = self._fetch_from_primary(pivot_height)
-                pivot.validate_basic(self.chain_id)
-                current = pivot
+                current = self._fetch_pivot(base, current)
                 continue
             # Verified against base.
             if current.height == new_block.height:
@@ -350,7 +391,10 @@ class LightClient:
         bad witness and gets dropped (detector.go examineConflictingHeader)."""
         if not self.witnesses:
             return
-        trusted = self.store.light_block_before(new_block.height)
+        with tracing.span("light_detect", witnesses=len(self.witnesses)):
+            self._cross_check(new_block, now)
+
+    def _cross_check(self, new_block: LightBlock, now: Timestamp) -> None:
         # Gather every conflicting witness header first, then verify all
         # of them against the trusted root in ONE scheduler super-batch
         # (batched mode) — a round of witness cross-checks costs one
@@ -374,6 +418,12 @@ class LightClient:
                 continue
             conflicts.append((i, witness, w_block, True))
         outcomes = {}
+        # the trusted root is read only where a witness disagrees
+        trusted = (
+            self.store.light_block_before(new_block.height)
+            if any(c[3] for c in conflicts)
+            else None
+        )
         to_verify = [
             c for c in conflicts if c[3] and trusted is not None
         ]

@@ -62,14 +62,14 @@ def _check_required_header_fields(h: SignedHeader) -> None:
         raise InvalidHeaderError("trusted header missing required fields")
 
 
-def _verify_new_header_and_vals(
+def _check_new_header(
     untrusted: SignedHeader,
-    untrusted_vals: ValidatorSet,
     trusted: SignedHeader,
     now: Timestamp,
     max_clock_drift: float,
 ) -> None:
-    """light/verifier.go:236-292."""
+    """light/verifier.go:236-285: everything ``verifyNewHeaderAndVals``
+    checks before it hashes the supplied validator set."""
     untrusted.validate_basic(trusted.chain_id)
     if untrusted.header.height <= trusted.header.height:
         raise InvalidHeaderError(
@@ -86,10 +86,26 @@ def _verify_new_header_and_vals(
         raise InvalidHeaderError(
             "new header has a time from the future"
         )
-    if untrusted.header.validators_hash != untrusted_vals.hash():
+
+
+def _check_vals_hash(untrusted: SignedHeader, vals_hash: bytes) -> None:
+    """light/verifier.go:286-292, given the supplied set's hash."""
+    if untrusted.header.validators_hash != vals_hash:
         raise InvalidHeaderError(
             "expected new header validators to match those that were supplied"
         )
+
+
+def _verify_new_header_and_vals(
+    untrusted: SignedHeader,
+    untrusted_vals: ValidatorSet,
+    trusted: SignedHeader,
+    now: Timestamp,
+    max_clock_drift: float,
+) -> None:
+    """light/verifier.go:236-292."""
+    _check_new_header(untrusted, trusted, now, max_clock_drift)
+    _check_vals_hash(untrusted, untrusted_vals.hash())
 
 
 def verify_non_adjacent(

@@ -25,6 +25,15 @@ tables, so one-off keys from ad-hoc batches cannot thrash the cache.
 Activating a new set invalidates entries for keys that left every
 active set (validator-set rotation).
 
+A table is ~1.2 ms of host big-integer work and saves a fraction of a
+microsecond a lane: it pays for a committee, whose keys a node verifies
+height after height, and not for a key met once or twice (a light
+client's pivot). So an eligible key gets its table in the
+``BUILD_AT_SIGHTING``-th batch that carries it, and goes table-less on
+the legacy kernel until then. The keys of the first set the cache meets
+(the node's own committee: nothing else is live yet) and pinned keys are
+built in the first.
+
 Env knobs::
 
     TENDERMINT_TPU_PRECOMPUTE          auto (default) | all | off
@@ -61,6 +70,11 @@ _RESULT_ENV = "TENDERMINT_TPU_RESULT_CACHE"
 _RESULT_CAP_ENV = "TENDERMINT_TPU_RESULT_CACHE_CAP"
 
 _ACTIVE_SETS_CAP = 8  # distinct validator sets considered live at once
+
+# The batch in which an eligible key's table is built, counted over the
+# batches that carry it (module docstring). A light client's pivot key
+# is carried by two batches at most (PERF.md section 6, PR 30).
+BUILD_AT_SIGHTING = 3
 
 # Cache-event observers (device-resident mirrors register here so host
 # invalidation propagates to device copies in lockstep).  This module
@@ -182,12 +196,18 @@ class PrecomputeCache:
             OrderedDict()
         )  # guarded-by: _lock
         # set hash -> (the set's pub_key objects in order, or None where
-        # the set could not be read; its raw ed25519 keys): one entry, so
-        # eviction at the cap and clear() drop both together.
+        # the set could not be read; its raw ed25519 keys; whether no
+        # other set was live when it was activated): one entry, so
+        # eviction at the cap and clear() drop all three together.
         self._active_sets: (
-            "OrderedDict[bytes, Tuple[Optional[tuple], FrozenSet[bytes]]]"
+            "OrderedDict[bytes, Tuple[Optional[tuple], FrozenSet[bytes], bool]]"
         ) = OrderedDict()  # guarded-by: _lock
         self._eligible: FrozenSet[bytes] = frozenset()  # guarded-by: _lock
+        # the part of _eligible built at first sight: pinned keys and the
+        # keys of the set activated while no other was live
+        self._founders: FrozenSet[bytes] = frozenset()  # guarded-by: _lock
+        # batches that carried an eligible key and built no table for it
+        self._sightings: Dict[bytes, int] = {}  # guarded-by: _lock
         self._pinned: set = set()  # guarded-by: _lock
         self._metrics = None  # guarded-by: _lock
         self.hits = 0  # guarded-by: _lock
@@ -198,6 +218,8 @@ class PrecomputeCache:
         self.build_seconds = 0.0  # guarded-by: _lock
         self.active_set_recognised = 0  # guarded-by: _lock
         self.active_set_hashed = 0  # guarded-by: _lock
+        self.sets_retired = 0  # guarded-by: _lock
+        self.builds_deferred = 0  # guarded-by: _lock
         self._pending_events: List[Tuple[str, tuple]] = []  # guarded-by: _lock
 
     # --- configuration ------------------------------------------------------
@@ -240,9 +262,14 @@ class PrecomputeCache:
 
     # --- validator-set awareness -------------------------------------------
 
-    def activate_validator_set(self, vset) -> Tuple[bool, bool]:
+    def activate_validator_set(
+        self, vset, vhash: Optional[bytes] = None
+    ) -> Tuple[bool, bool]:
         """Mark a validator set live: its keys become table-eligible.
-        Returns ``(newly_active, recognised)``.
+        Returns ``(newly_active, recognised)``. ``vhash`` is the set's
+        ``hash()`` where the caller has just computed it (the light
+        client checks it against the header); it is taken only for a
+        set that is not recognised.
 
         A live set is recognised by the tuple of its validators'
         ``pub_key`` objects, rebuilt on every call from what the set
@@ -262,7 +289,8 @@ class PrecomputeCache:
         recognising it (PERF.md §6, PR 29). A new hash registers the key
         set, retires the oldest live set beyond the bound, and drops
         cached tables for keys that no longer belong to any live set
-        (committee rotation).
+        (committee rotation). The keys of a set activated while no other
+        is live are built at first sight (module docstring).
         """
         try:
             pub_keys = tuple(map(_pub_key_of, vset.validators))
@@ -270,23 +298,27 @@ class PrecomputeCache:
             pub_keys = None
         if pub_keys is not None:
             with self._lock:
-                for vhash, (live, _) in reversed(self._active_sets.items()):
+                for known, (live, _, _) in reversed(self._active_sets.items()):
                     if live == pub_keys:
-                        self._active_sets.move_to_end(vhash)
+                        self._active_sets.move_to_end(known)
                         self.active_set_recognised += 1
                         return False, True
-        try:
-            vhash = vset.hash()
-        except Exception:
-            return False, False
+        if vhash is None:
+            try:
+                vhash = vset.hash()
+            except Exception:
+                return False, False
         with self._lock:
             self.active_set_hashed += 1
             if vhash in self._active_sets:
                 self._active_sets.move_to_end(vhash)
                 return False, False
-            self._active_sets[vhash] = (pub_keys, _vset_ed25519_keys(vset))
+            self._active_sets[vhash] = (
+                pub_keys, _vset_ed25519_keys(vset), not self._active_sets
+            )
             while len(self._active_sets) > _ACTIVE_SETS_CAP:
                 self._active_sets.popitem(last=False)
+                self.sets_retired += 1
             self._recompute_eligible_locked()
         self._flush_events()
         return True, False
@@ -300,9 +332,16 @@ class PrecomputeCache:
 
     def _recompute_eligible_locked(self) -> None:
         eligible = set(self._pinned)
-        for _, keys in self._active_sets.values():
+        founders = set(self._pinned)
+        for _, keys, first in self._active_sets.values():
             eligible |= keys
+            if first:
+                founders |= keys
         self._eligible = frozenset(eligible)
+        self._founders = frozenset(founders)
+        self._sightings = {
+            pk: n for pk, n in self._sightings.items() if pk in eligible
+        }
         if _mode() == "auto":
             stale = [pk for pk in self._entries if pk not in self._eligible]
             for pk in stale:
@@ -313,11 +352,20 @@ class PrecomputeCache:
                 if self._metrics is not None:
                     self._metrics.precompute_invalidations.inc(len(stale))
 
-    def _eligible_for_build_locked(self, pk: bytes) -> bool:
-        mode = _mode()
-        if mode == "all":
+    def _due_for_build_locked(self, pk: bytes) -> bool:
+        """Whether the batch that carries table-less ``pk`` builds its
+        table (module docstring); counts the batch where it does not."""
+        if _mode() == "all" or pk in self._founders:
             return True
-        return pk in self._eligible
+        if pk not in self._eligible:
+            return False
+        sightings = self._sightings.get(pk, 0) + 1
+        if sightings >= BUILD_AT_SIGHTING:
+            self._sightings.pop(pk, None)
+            return True
+        self._sightings[pk] = sightings
+        self.builds_deferred += 1
+        return False
 
     # --- cache body ---------------------------------------------------------
 
@@ -365,9 +413,11 @@ class PrecomputeCache:
         ``(table, ok)`` pair for lane i (None when the lane must take the
         legacy build-on-device path) and ``has_table`` is the (N,) bool
         partition mask.  Cache-hit lanes reuse the stored column;
-        eligible miss lanes are built on host (timed + counted) and
-        inserted; ineligible lanes stay on the legacy kernel so ad-hoc
-        batches cannot evict the live committee.
+        eligible miss lanes due for their table (module docstring) are
+        built on host (timed + counted) and inserted; the others, like
+        ineligible lanes, stay on the legacy kernel, so neither ad-hoc
+        batches nor a light client's pivots evict the live committee or
+        stall a call on table builds.
         """
         n = len(pubkeys)
         has_table = np.zeros(n, dtype=bool)
@@ -381,6 +431,7 @@ class PrecomputeCache:
                 metrics = self._metrics
                 hits = misses = builds = 0
                 build_time = 0.0
+                deferred = self.builds_deferred
                 seen: Dict[bytes, int] = {}
                 for i, pk in enumerate(pubkeys):
                     pk = bytes(pk)
@@ -392,9 +443,9 @@ class PrecomputeCache:
                         # duplicate signer inside one batch: one build serves
                         # every lane, and only the first counts as a miss.
                         entry = entries[seen[pk]]
-                        if entry is None:  # first occurrence was ineligible
+                        if entry is None:  # first occurrence got no table
                             continue
-                    elif self._eligible_for_build_locked(pk):
+                    elif self._due_for_build_locked(pk):
                         misses += 1
                         t0 = time.perf_counter()
                         table, ok = build_table(pk)
@@ -410,11 +461,12 @@ class PrecomputeCache:
                     entries[i] = entry
                     has_table[i] = True
                     seen.setdefault(pk, i)
+                deferred = self.builds_deferred - deferred
                 self.hits += hits
                 self.misses += misses
                 self.builds += builds
                 self.build_seconds += build_time
-            tspan.set(hits=hits, misses=misses, builds=builds)
+            tspan.set(hits=hits, misses=misses, builds=builds, deferred=deferred)
             if metrics is not None:
                 if hits:
                     metrics.precompute_hits.inc(hits)
@@ -447,6 +499,8 @@ class PrecomputeCache:
                 "build_seconds": self.build_seconds,
                 "active_set_recognised": self.active_set_recognised,
                 "active_set_hashed": self.active_set_hashed,
+                "sets_retired": self.sets_retired,
+                "builds_deferred": self.builds_deferred,
             }
 
     def reset_stats(self) -> None:
@@ -455,13 +509,15 @@ class PrecomputeCache:
             self.evictions = self.invalidations = 0
             self.build_seconds = 0.0
             self.active_set_recognised = self.active_set_hashed = 0
+            self.sets_retired = self.builds_deferred = 0
 
     def clear(self) -> None:
         with self._lock:
             self._entries.clear()
             self._active_sets.clear()
             self._pinned.clear()
-            self._eligible = frozenset()
+            self._eligible = self._founders = frozenset()
+            self._sightings.clear()
             self._pending_events.append(("clear", ()))
         self._flush_events()
         self.reset_stats()
@@ -558,8 +614,8 @@ tables = PrecomputeCache()
 results = ResultCache()
 
 
-def activate_validator_set(vset) -> Tuple[bool, bool]:
-    return tables.activate_validator_set(vset)
+def activate_validator_set(vset, vhash: Optional[bytes] = None) -> Tuple[bool, bool]:
+    return tables.activate_validator_set(vset, vhash)
 
 
 def pin_pubkeys(pubkeys: Iterable[bytes]) -> None:

@@ -18,6 +18,7 @@ from typing import Callable, Dict, List, Optional
 
 from tendermint_tpu.crypto import merkle
 from tendermint_tpu.crypto.keys import ADDRESS_LEN, PubKey
+from tendermint_tpu.libs import tracing
 from tendermint_tpu.encoding.canonical import (
     SIGNED_MSG_TYPE_PRECOMMIT,
     SIGNED_MSG_TYPE_PREVOTE,
@@ -948,6 +949,10 @@ class Header:
         """Merkle tree over the 14 encoded fields (types/block.go:447-490)."""
         if not self.validators_hash:
             return b""
+        with tracing.span("header_hash"):
+            return self._merkle_root()
+
+    def _merkle_root(self) -> bytes:
         return merkle.hash_from_byte_slices(
             [
                 self.version.to_proto_bytes(),

@@ -10,6 +10,7 @@ from dataclasses import dataclass
 from typing import Optional
 
 from tendermint_tpu.encoding.proto import Reader, encode_message_field, encode_varint_field
+from tendermint_tpu.libs import tracing
 from tendermint_tpu.types.block import Commit, Header
 from tendermint_tpu.types.validator_set import ValidatorSet
 
@@ -93,18 +94,24 @@ class LightBlock:
     def hash(self) -> bytes:
         return self.signed_header.hash() if self.signed_header else b""
 
-    def validate_basic(self, chain_id: str) -> None:
-        """types/light.go LightBlock.ValidateBasic."""
+    def validate_basic(self, chain_id: str) -> bytes:
+        """types/light.go LightBlock.ValidateBasic. Returns the validator
+        set's hash, which it has just computed and found in the header:
+        a caller that goes on to verify the block need not hash the set
+        again."""
         if self.signed_header is None:
             raise ValueError("missing signed header")
         if self.validator_set is None:
             raise ValueError("missing validator set")
-        self.signed_header.validate_basic(chain_id)
-        self.validator_set.validate_basic()
-        if self.signed_header.header.validators_hash != self.validator_set.hash():
+        with tracing.span("light_block_checks"):
+            self.signed_header.validate_basic(chain_id)
+            self.validator_set.validate_basic()
+        vals_hash = self.validator_set.hash()
+        if self.signed_header.header.validators_hash != vals_hash:
             raise ValueError(
                 "expected validator hash of header to match validator set hash"
             )
+        return vals_hash
 
     def to_proto_bytes(self) -> bytes:
         out = b""

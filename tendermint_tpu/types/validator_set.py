@@ -16,6 +16,7 @@ import math
 from typing import List, Optional, Tuple
 
 from tendermint_tpu.crypto import merkle
+from tendermint_tpu.libs import tracing
 from tendermint_tpu.types.validator import (
     INT64_MAX,
     INT64_MIN,
@@ -94,7 +95,10 @@ class ValidatorSet:
 
     def hash(self) -> bytes:
         """Merkle root of SimpleValidator leaves (validator_set.go:344-350)."""
-        return merkle.hash_from_byte_slices([v.bytes() for v in self.validators])
+        with tracing.span("valset_hash", validators=len(self.validators)):
+            return merkle.hash_from_byte_slices(
+                [v.bytes() for v in self.validators]
+            )
 
     def validate_basic(self) -> None:
         if self.is_nil_or_empty():

@@ -205,8 +205,12 @@ def _auto_paths_on(monkeypatch):
 
 @pytest.mark.slow
 def test_library_phase_at_40_validators(_auto_paths_on):
-    report = chip_smoke.library_phase("cpu", sizes=(40,), heights=2, sync=(20, 4))
-    (size,) = report["sizes"]
+    # two sizes and the windows, as on the chip: each is a node of its
+    # own (chip_smoke._fresh_node), so each gets its tables at once
+    report = chip_smoke.library_phase("cpu", sizes=(24, 40), heights=2, sync=(20, 4))
+    small, size = report["sizes"]
+    assert small["counters"]["resident_hits"] == 24 * 4
+    assert small["counters"]["resident_uploads"] == 1
     c = size["counters"]
     assert c["hash_device_lanes"] == 40 * 5
     assert c["resident_hits"] == 40 * 4 and c["resident_uploads"] == 1

@@ -1,0 +1,324 @@
+"""The benchmark's part of the ``light1k`` deployment, without a chip:
+the plain skipping reference against the program's own hashes and
+sign-bytes and on seeded chains, the program's client against it (walk,
+store contents, the four faults), the Light and Scheduler metrics'
+files reduced on hand-made spans, ``BENCHMARK.json`` against its files,
+and the cell's tiny twin rehearsed end to end on the CPU (a rehearsal
+proves paths, never numbers).
+"""
+
+from __future__ import annotations
+
+import hashlib
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+from chipbench import reference_lightclient as plain
+from chipbench import selftest, spec, workload
+from chipbench.generators import lightclient
+
+BENCH = os.path.join(spec.HERE, "testdata", "tiny-light-benchmark.json")
+CELL = "tiny-light-chain"
+N, JUMP, SHORT = 40, 28, 27  # the tiny twin's: 27 pass 2/3, 14 pass 1/3
+
+
+@pytest.fixture(scope="module")
+def chain():
+    """Heights 1..1+2*JUMP of a seeded chain of N validators, one
+    replaced a height, light blocks at every height a test asks for."""
+    c = lightclient.Chain(30, N, 2 * JUMP + 3)
+    for h in (1, 1 + JUMP // 4, 1 + JUMP // 2, 2 + JUMP // 2, 1 + SHORT, 1 + SHORT // 2,
+              1 + JUMP, 1 + JUMP + JUMP // 2, 1 + 2 * JUMP):
+        c.build(h)
+    return c
+
+
+def params(chain, check_signatures=True):
+    now_ns = workload.BASE_NS + (max(chain.blocks) + 1) * workload.SECOND_NS
+    return plain.Params(workload.CHAIN_ID, 14 * 86400 * 10**9, now_ns, 10 * 10**9,
+                        (1, 3), check_signatures)
+
+
+# --- the plain reference against the program's encodings ----------------------
+
+
+def test_merkle_root_is_rfc6962():
+    assert plain.merkle_root([]) == hashlib.sha256(b"").digest()
+    leaf = lambda b: hashlib.sha256(b"\x00" + b).digest()  # noqa: E731
+    node = lambda l, r: hashlib.sha256(b"\x01" + l + r).digest()  # noqa: E731
+    a, b, c, d, e = (bytes([i]) * 3 for i in range(5))
+    assert plain.merkle_root([a]) == leaf(a)
+    assert plain.merkle_root([a, b, c]) == node(node(leaf(a), leaf(b)), leaf(c))
+    # five leaves split 4 + 1: the largest power of two below the count
+    assert plain.merkle_root([a, b, c, d, e]) == node(
+        node(node(leaf(a), leaf(b)), node(leaf(c), leaf(d))), leaf(e)
+    )
+
+
+def test_reference_hashes_and_sign_bytes_are_the_programs(chain):
+    block = chain.blocks[1 + JUMP // 2]
+    doc = chain.plain(1 + JUMP // 2)
+    assert plain.validators_hash(doc["validators"]) == block.validator_set.hash()
+    assert plain.header_hash(doc["header"]) == block.signed_header.header.hash()
+    commit = block.signed_header.commit
+    for idx in (0, 7, N - 1):
+        assert plain.vote_sign_bytes(workload.CHAIN_ID, doc["commit"], idx) == (
+            commit.vote_sign_bytes(workload.CHAIN_ID, idx)
+        )
+
+
+def test_generated_sets_are_in_the_programs_canonical_order(chain):
+    from tendermint_tpu.types import ValidatorSet
+
+    vset = chain.validator_set(5)
+    rebuilt = ValidatorSet([v.copy() for v in vset.validators])
+    assert [v.address for v in rebuilt.validators] == [v.address for v in vset.validators]
+    # one validator a height: sets d heights apart share N - d keys
+    a = {v.address for v in chain.validator_set(1).validators}
+    b = {v.address for v in chain.validator_set(1 + JUMP).validators}
+    assert len(a & b) == N - JUMP
+
+
+# --- the walk ---------------------------------------------------------------------
+
+
+def test_reference_walk_refuses_the_target_by_tally_then_takes_two_hops(chain):
+    out = plain.verify_skipping(chain.plain(1), 1 + JUMP, chain.plain, params(chain))
+    mid = 1 + JUMP // 2
+    assert out["verdict"] == plain.OK
+    assert out["fetched"] == [1 + JUMP, mid]
+    assert out["refused"] == [1 + JUMP]
+    assert out["accepted"] == [mid, 1 + JUMP]
+    # each hop: the 2/3 lanes of its commit, the trusting lanes inside them
+    assert len(out["checked"]) == 2 * 27
+    assert len(set(out["checked"])) == len(out["checked"])
+    assert {h for h, _, _ in out["checked"]} == {mid, 1 + JUMP}
+    # tallies alone decide the walk: the same without a signature checked
+    dry = plain.verify_skipping(chain.plain(1), 1 + JUMP, chain.plain, params(chain, False))
+    assert dry == out
+
+
+def test_reference_walk_one_short_of_a_third_bisects(chain):
+    # 13 shared validators: 130 of 400 is not more than 133
+    out = plain.verify_skipping(chain.plain(1), 1 + SHORT, chain.plain, params(chain, False))
+    assert (out["verdict"], out["refused"]) == (plain.OK, [1 + SHORT])
+    assert out["accepted"] == [1 + SHORT // 2, 1 + SHORT]
+    # one more shared validator and the target is trusted at once
+    out = plain.verify_skipping(chain.plain(2 + JUMP // 2), 1 + JUMP + JUMP // 2,
+                                chain.plain, params(chain, False))
+    assert out["verdict"] == plain.OK and out["refused"] == [1 + JUMP + JUMP // 2]
+    near = plain.verify_skipping(chain.plain(1 + JUMP // 2), 1 + JUMP, chain.plain, params(chain, False))
+    assert (near["refused"], near["accepted"]) == ([], [1 + JUMP])
+
+
+def test_reference_walk_faults(chain):
+    def tampered(height, idx, kind):
+        doc = chain.plain(height)
+        doc = dict(doc, commit=dict(doc["commit"], signatures=list(doc["commit"]["signatures"])))
+        flag, addr, t, sig = doc["commit"]["signatures"][idx]
+        doc["commit"]["signatures"][idx] = (flag, addr, t, workload.tamper_signature(sig, kind))
+        return doc
+
+    mid, target = 1 + JUMP // 2, 1 + JUMP
+    sound = plain.verify_skipping(chain.plain(1), target, chain.plain, params(chain))
+    first_mid = next(i for h, i, _ in sound["checked"] if h == mid)
+    blocks = {mid: tampered(mid, first_mid, "s>=L"), target: chain.plain(target)}
+    out = plain.verify_skipping(chain.plain(1), target, blocks.get, params(chain))
+    assert (out["verdict"], out["detail"], out["accepted"]) == ("wrong signature", (mid, first_mid), [])
+    # past the 2/3 exit nothing is looked at
+    blocks = {mid: chain.plain(mid), target: tampered(target, N - 1, "R")}
+    out = plain.verify_skipping(chain.plain(1), target, blocks.get, params(chain))
+    assert out["verdict"] == plain.OK and out["accepted"] == [mid, target]
+    # a header that names another set than the one supplied
+    wrong = dict(chain.plain(mid), validators=chain.plain(target)["validators"])
+    out = plain.verify_skipping(chain.plain(1), target, {mid: wrong, target: chain.plain(target)}.get,
+                                params(chain))
+    assert (out["verdict"], out["accepted"]) == ("validators_hash", [])
+    # a provider without the midpoint
+    out = plain.verify_skipping(chain.plain(1), target, {target: chain.plain(target)}.get, params(chain))
+    assert (out["verdict"], out["detail"]) == ("no block", mid)
+    # an expired trust root
+    late = params(chain)
+    late.now_ns = workload.BASE_NS + 10**9 + late.trusting_period_ns + 1
+    assert plain.verify_skipping(chain.plain(1), target, chain.plain, late)["verdict"] == (
+        "trusted header expired"
+    )
+
+
+# --- the files ----------------------------------------------------------------------
+
+
+def test_benchmark_files_agree():
+    selftest.test_files()
+    real = spec.Spec(os.path.join(spec.ROOT, "BENCHMARK.json"))
+    cell = real.cell("light1k-chain")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == ("light1k", "skipping-updates", 1)
+    config, traffic = real.config("light1k"), real.traffic("skipping-updates")
+    assert config["validators"] == 1000 and config["trust_level"] == [1, 3]
+    assert list(config["reduced"]) == ["signed_heights"]
+    assert list(config["env"]) == ["TENDERMINT_TPU_FIELD_MUL"]
+    quorum = config["validators"] * 2 // 3 + 1
+    assert config["lanes_per_call"] == 2 * quorum == 1334
+    assert (traffic["jump_heights"], traffic["short_of_trust_jump"], traffic["warm_up_calls"]) == (800, 667, 5)
+    from chipbench.generators import cycle_length
+
+    assert cycle_length(traffic, 1334, 65536) == 53
+    assert [m["name"] for m in real.metrics_for("end_to_end", "light1k-chain")] == ["sigs_per_s", "setup_s"]
+    ours = [m for m in real.doc["per_layer"] if m.get("workloads") == ["light1k-chain"]]
+    assert len(ours) == 29 == len(real.metrics_for("per_layer", "light1k-chain"))
+    assert {m["layer"] for m in ours} == {"Light", "Scheduler", "Tables", "Engine", "Kernels", "Device"}
+    tiny = spec.Spec(BENCH)
+    assert [m["name"] for m in tiny.doc["per_layer"]] == [m["name"] for m in ours]
+    # the reference imports nothing of the program
+    with open(os.path.join(spec.HERE, "reference_lightclient.py")) as fh:
+        assert "tendermint_tpu" not in fh.read().replace("tendermint v0.35.9", "")
+
+
+def test_light_metrics_add_up_on_nested_spans():
+    """One call: light_verify 0..2000 holding the trusted block's load
+    10..60, the target's light_block_checks 60..95 and valset_hash
+    100..200 (the client's validate_basic), light_round 300..1900 with
+    light_plan 310..900 (phases 60 + 40 + 200; inside it a pivot's
+    light_block_checks 370..390 and valset_hash 400..480, and two
+    note_validator_set of 30) and light_super_batch 910..1890, in which
+    scheduler_dispatch 950..1850 holds sched_assemble 960..1000 and
+    verify_batch 1010..1800; then the pivot's save 1900..1950, the
+    detector 1950..1960 and the target's save 1960..1995."""
+
+    class Evidence:
+        calls = [{}]
+
+    def span(name, ts, dur, **args):
+        return {"name": name, "ts": float(ts), "dur": float(dur), "args": args}
+
+    ev = Evidence()
+    ev.spans = [
+        span("light_verify", 0, 2000, target=801, hops=2),
+        span("light_store_load", 10, 50, bytes=200000),
+        span("light_block_checks", 60, 35),
+        span("valset_hash", 100, 100),
+        span("light_round", 300, 1600),
+        span("light_block_checks", 370, 20),
+        span("light_plan", 310, 590, header_checks_us=60.0, header_checks_n=3,
+             valset_hash_us=80.0, valset_hash_n=1, tally_us=40.0, tally_n=5,
+             sign_bytes_us=200.0, sign_bytes_n=54),
+        span("valset_hash", 400, 80),
+        span("note_validator_set", 500, 30), span("note_validator_set", 700, 30),
+        span("light_super_batch", 910, 980, lanes=54),
+        span("scheduler_dispatch", 950, 900, lanes=54),
+        span("sched_assemble", 960, 40),
+        span("sched_flush", 1005, 800),
+        span("verify_batch", 1010, 790),
+        span("gather_tables", 1020, 10, builds=0), span("gather_tables", 1040, 30, builds=2),
+        span("light_store_save", 1900, 50, height=401),
+        span("light_detect", 1950, 10, witnesses=1),
+        span("light_store_save", 1960, 35, height=801),
+    ]
+
+    def read(name):
+        doc = spec.layer_metric(name)
+        return spec.reader(doc["reader"]).read(ev, **doc["args"])
+
+    assert read("light_host_ms") == pytest.approx(1.210)
+    assert read("valset_hash_ms") == pytest.approx(0.180)
+    assert read("note_set_ms.light") == pytest.approx(0.060)
+    assert read("sign_bytes_ms.light") == pytest.approx(0.200)
+    assert read("tally_ms") == pytest.approx(0.040)
+    assert read("header_checks_ms") == pytest.approx(0.060)
+    assert read("sched_handoff_ms") == pytest.approx(0.080)
+    assert read("sched_assemble_ms") == pytest.approx(0.040)
+    assert read("table_build_ms.light") == pytest.approx(0.030)
+    assert read("store_save_ms") == pytest.approx(0.085)
+    assert read("store_load_ms") == pytest.approx(0.050)
+    assert read("block_checks_ms") == pytest.approx(0.055)
+    assert read("detector_ms") == pytest.approx(0.010)
+    # outside plan and super-batch 2000 - 590 - 980 = 430, less the
+    # hash, checks, load, saves and detector there (100 + 35 + 50 + 85
+    # + 10) = 150; the plan's 590 less its phases 300 and the 160 of
+    # the spans inside it = 130
+    assert read("light_unnamed_ms") == pytest.approx(0.280)
+    # the identity: the call's host time is its named parts, the
+    # scheduler's share (the super-batch less the engine) and the rest
+    scheduler = (980 - 790) / 1000.0
+    named = (read("valset_hash_ms") + read("note_set_ms.light") + read("sign_bytes_ms.light")
+             + read("tally_ms") + read("header_checks_ms") + read("store_save_ms")
+             + read("store_load_ms") + read("block_checks_ms") + read("detector_ms") + scheduler)
+    assert named + read("light_unnamed_ms") == pytest.approx(read("light_host_ms"))
+    # a program without the light spans (the parent): nothing to read
+    ev.spans = [span("verify_batch", 1010, 790), span("gather_tables", 1020, 10, builds=0)]
+    for name in ("light_host_ms", "sign_bytes_ms.light", "tally_ms", "light_unnamed_ms", "sched_handoff_ms",
+                 "store_save_ms", "store_load_ms", "block_checks_ms", "detector_ms"):
+        assert read(name) is None, name
+    assert read("table_build_ms.light") == 0.0
+
+
+# --- the program against the reference, end to end ---------------------------------------
+
+
+def rehearse(trace: int, *extra, seed=2**31 + 30):
+    """(result line, standard output) of one rehearsal of the tiny twin."""
+    proc = subprocess.run(
+        [sys.executable, "-m", "chipbench.run", "--workload", CELL,
+         "--seed", str(seed), "--seconds", "1", "--trace", str(trace),
+         "--rehearse", "--bench-file", BENCH, *extra],
+        cwd=spec.ROOT, capture_output=True, text=True, timeout=420,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stdout
+
+
+COMPARED = ("verdict_cache_hits_in_window", "compilations_in_window", "timed_calls_refused",
+            "calls_with_a_wrong_walk", "fault_calls_with_a_wrong_verdict",
+            "lanes_where_reference_disagrees")
+
+
+def test_tiny_twin_of_light1k_chain_rehearses_on_the_cpu():
+    """The traced rehearsal is the comparison the chip run makes at full
+    size: every timed call's block and store against the reference's
+    walk, the four faults, the sampled lanes; and lanes dispatched =
+    the reference's distinct checked signatures (``failed`` 0)."""
+    out, said = rehearse(1)
+    assert out["correct"] is True and out["failed"] == 0 and out["attempted"] > 0
+    tiny = spec.Spec(BENCH)
+    want = {m["name"] for m in tiny.metrics_for("per_layer", CELL)}
+    assert len(want) == 29
+    for name in want:
+        assert isinstance(out["metrics"][name]["value"], float), name
+    for name in COMPARED:
+        assert "compared: %s = 0 (limit 0)" % name in said, name
+    m = {k: v["value"] for k, v in out["metrics"].items()}
+    # pivots' keys are met twice at most and get no table, and neither
+    # does the anchor of the client restarted where the cycle starts
+    # over: the window builds none and no lane finds one
+    assert m["resident_hit_share.light"] == 0.0 and m["table_build_ms.light"] == 0.0
+    assert m["light_unnamed_ms"] < m["light_host_ms"]
+
+
+@pytest.mark.parametrize(
+    "brk,over",
+    [
+        # one lane's verdict inverted where the engine returns it: a
+        # timed call is refused, the walks after it start further back
+        # (and may meet a kernel shape the warm-up did not)
+        ("flip_verdict", ["compilations_in_window", "timed_calls_refused", "calls_with_a_wrong_walk",
+                          "fault_calls_with_a_wrong_verdict", "lanes_where_reference_disagrees"]),
+        # the engine's s < L check off: the trusting lane's s + L verifies
+        ("no_canonical_s", ["fault_calls_with_a_wrong_verdict", "lanes_where_reference_disagrees"]),
+    ],
+)
+def test_tiny_twin_broken_on_purpose_comes_out_not_correct(brk, over):
+    """The controls (``breaks.py``) have to show in the cell's own
+    comparisons, not in the harness's two."""
+    out, said = rehearse(0, "--break", brk)
+    assert out["correct"] is False
+    got = [
+        ln.split("compared: ", 1)[1].split(" = ")[0]
+        for ln in said.splitlines() if ln.endswith("<-- over")
+    ]
+    assert set(got) <= set(over) and got, got
+    assert set(got) & {"fault_calls_with_a_wrong_verdict", "timed_calls_refused"}

@@ -1,5 +1,6 @@
 """PR 9 serving-tier tests: batched-vs-sequential skipping parity over
-a rotating validator set, the one-super-batch-per-round pin, the
+a rotating validator set, the one-super-batch-per-round pin (since PR 30
+one round a walk), the
 verified-header cache (LRU + divergence invalidation), lightd serving
 semantics, provider retry/backoff, and the scheduler super-batch entry
 points."""
@@ -40,6 +41,7 @@ from tendermint_tpu.rpc.server import RPCError
 from tendermint_tpu.types import (
     BlockID,
     Consensus,
+    Fraction,
     Header,
     LightBlock,
     PartSetHeader,
@@ -193,8 +195,9 @@ class TestBatchParity:
         assert outcomes[1].kind == light_batch.OK
 
     def test_one_super_batch_per_round(self):
-        """Acceptance pin: a bisection round = at most ONE scheduler
-        super-batch (one device call), regardless of ladder width."""
+        """Acceptance pin: a round = at most ONE scheduler super-batch
+        (one device call), and since PR 30 a walk the planner can
+        express is one round however many hops it takes."""
         blocks = build_rotating_chain(17)
         client = make_client(blocks, batching=True)
         tracing.configure("ring")
@@ -206,10 +209,265 @@ class TestBatchParity:
             tracing.configure("off")
         rounds = [e for e in events if e.get("name") == "light_round"]
         batches = [e for e in events if e.get("name") == "light_super_batch"]
-        assert len(rounds) >= 2  # rotation forces real multi-round bisection
-        assert len(batches) <= len(rounds)
-        for b in batches:
-            assert b["args"]["lanes"] > 0
+        walks = [e for e in events if e.get("name") == "light_verify"]
+        assert len(rounds) == 1 and len(batches) == 1 and len(walks) == 1
+        # rotation forces a real multi-hop walk
+        assert walks[0]["args"]["hops"] >= 3
+        assert walks[0]["args"]["refused_by_tally"] >= 2
+        assert batches[0]["args"]["lanes"] == walks[0]["args"]["lanes"] > 0
+
+
+def _traced_walk(blocks, target, batching=True, height=1):
+    """(client, light_verify span args, lanes in dispatch-side spans)
+    of one ``verify_light_block_at_height(target)`` from ``height``."""
+    client = make_client(blocks, batching=batching, height=height)
+    tracing.configure("ring")
+    tracing.tracer.clear()
+    try:
+        client.verify_light_block_at_height(target)
+        events = tracing.tracer.export()["traceEvents"]
+    finally:
+        tracing.configure("off")
+    walk = [e for e in events if e.get("name") == "light_verify"][-1]["args"]
+    flushed = sum(
+        e["args"]["lanes"] for e in events if e.get("name") == "sched_flush"
+    )
+    return client, walk, flushed
+
+
+def _reference_walk(blocks, target, height=1):
+    from chipbench import reference_lightclient as plain
+    from chipbench.generators.lightclient import plain_block
+
+    by_height = {b.height: plain_block(b) for b in blocks}
+    params = plain.Params(
+        CHAIN_ID, int(10 * HOUR * 1e9), now_at().to_unix_ns(), int(10e9),
+        (1, 3), check_signatures=False,
+    )
+    return plain.verify_skipping(by_height[height], target, by_height.get, params)
+
+
+class TestWalkSendsWhatUpstreamChecks:
+    """PR 30: a call sends exactly the signatures upstream's walk
+    checks, each once. The reference is the benchmark's plain
+    ``verifySkipping`` (``chipbench/reference_lightclient.py``, no import
+    of the program) over the same blocks."""
+
+    # window 6, trust level 1/3: a jump of up to 3 heights is covered
+    @pytest.mark.parametrize(
+        "target, hops, refused, last_hop_adjacent",
+        [
+            (2, 1, 0, True),  # a walk that ends adjacent: the 2/3 rule alone
+            (3, 1, 0, False),  # one hop, both rules, their lanes merged
+            (6, 2, 1, False),  # the target refused by tally, its midpoint taken
+            (10, 4, 4, False),
+            (17, 7, 11, False),
+        ],
+    )
+    def test_lanes_dispatched_are_the_references_checked_signatures(
+        self, target, hops, refused, last_hop_adjacent
+    ):
+        blocks = build_rotating_chain(17)
+        want = _reference_walk(blocks, target)
+        assert want["verdict"] == "ok"
+        assert (len(want["accepted"]), len(want["refused"])) == (hops, refused)
+        trail = [1] + want["accepted"]
+        assert (trail[-1] - trail[-2] == 1) == last_hop_adjacent
+        client, walk, flushed = _traced_walk(blocks, target)
+        assert (walk["hops"], walk["refused_by_tally"]) == (hops, refused)
+        assert walk["lanes"] == flushed == len(want["checked"])
+        assert client.store.heights() == [1] + want["accepted"]
+        # the sequential loop leaves the same store and checks the
+        # merged signatures twice over
+        seq_client, _, _ = _traced_walk(blocks, target, batching=False)
+        assert seq_client.store.heights() == client.store.heights()
+
+    def test_a_signature_both_rules_ask_for_is_one_lane(self):
+        blocks = build_rotating_chain(8)
+        want = _reference_walk(blocks, 3)
+        _, walk, flushed = _traced_walk(blocks, 3)
+        # 1 -> 3: four shared validators; the trusting rule takes the
+        # first three it finds, the 2/3 rule the first five by position
+        assert walk["lanes"] == flushed == len(want["checked"]) == 5
+        assert walk["merged"] >= 2
+        assert walk["lanes"] + walk["merged"] == 3 + 5
+
+    def test_no_lane_for_a_pivot_the_walk_never_visits(self):
+        """The descending ladder [target, mid, mid-of-mid, ...] holds
+        pivots upstream's walk never fetches; a forged commit at one
+        neither fails the call nor costs a lane."""
+        blocks = build_rotating_chain(17)
+        want = _reference_walk(blocks, 17)
+        unvisited = sorted(set(range(2, 17)) - set(want["fetched"]))
+        assert unvisited
+        fetched = []
+        client = make_client(blocks, batching=True)
+        inner = client.primary.light_block
+        client.primary.light_block = lambda h: fetched.append(h) or inner(h)
+        client.verify_light_block_at_height(17)
+        assert fetched == want["fetched"]
+
+    def test_a_verdict_that_never_came_is_a_timeout_not_a_wrong_signature(self):
+        blocks = build_rotating_chain(8)
+        release = threading.Event()
+
+        def slow(pks, msgs, sigs):
+            release.wait(5)
+            return [True] * len(pks)
+
+        sched = VerifyScheduler(slow, max_delay=0.001)
+        sched.start()
+        try:
+            batch = light_batch.SuperBatch()
+            plan = light_batch._plan_candidate(
+                CHAIN_ID, blocks[0], blocks[2], 10 * HOUR, now_at(), 10.0,
+                DEFAULT_TRUST_LEVEL, batch,
+            )
+            assert batch.lanes and plan.outcome is None
+            with pytest.raises(TimeoutError, match="no verdict for 5 of 5"):
+                batch.send(sched, timeout=0.05)
+        finally:
+            release.set()
+            sched.stop()
+
+    def test_bad_signature_in_a_later_hop_keeps_the_hops_before_it(self):
+        blocks = build_rotating_chain(17)
+        want = _reference_walk(blocks, 17)
+        third = want["accepted"][2]
+        idx = next(i for h, i, _ in want["checked"] if h == third)
+        blocks[third - 1].signed_header.commit.signatures[idx].signature = bytes(64)
+        stores = {}
+        for batching in (False, True):
+            client = make_client(blocks, batching)
+            with pytest.raises(InvalidHeaderError, match=r"wrong signature \(#%d\)" % idx):
+                client.verify_light_block_at_height(17)
+            stores[batching] = client.store.heights()
+        assert stores[True] == stores[False] == [1] + want["accepted"][:2]
+
+
+def _refusal_cases():
+    """(name, mutate(blocks) -> (base, cand, now, trusting period,
+    trust level)): every way ``verifier.verify`` refuses a candidate."""
+    from copy import deepcopy
+
+    def case(name, mutate, base=0, cand=2, **kw):
+        return pytest.param(mutate, base, cand, kw, id=name)
+
+    def keep(blocks):
+        return None
+
+    def not_after(blocks):
+        # the trusted side is not held to a commit: set its clock forward
+        blocks[0].signed_header.header.time = blocks[2].signed_header.header.time
+
+    def other_set(blocks):
+        blocks[2].validator_set = deepcopy(blocks[3].validator_set)
+
+    def other_chain(blocks):
+        blocks[2].signed_header.header.chain_id = "another-chain"
+
+    def bad_trusting_sig(blocks):
+        # a validator both sets hold: the trusting rule looks at it first
+        shared = {v.address for v in blocks[0].validator_set.validators}
+        commit = blocks[2].signed_header.commit
+        idx = next(i for i, cs in enumerate(commit.signatures) if cs.validator_address in shared)
+        commit.signatures[idx].signature = bytes(64)
+
+    def bad_full_sig(blocks):
+        shared = {v.address for v in blocks[0].validator_set.validators}
+        commit = blocks[2].signed_header.commit
+        idx = next(i for i, cs in enumerate(commit.signatures) if cs.validator_address not in shared)
+        commit.signatures[idx].signature = bytes(64)
+
+    def short_of_two_thirds(blocks):
+        from tendermint_tpu.types import CommitSig
+
+        # absent votes from validators the trusted set does not hold: the
+        # trusting rule passes, the 2/3 rule runs out of power
+        shared = {v.address for v in blocks[0].validator_set.validators}
+        commit = blocks[2].signed_header.commit
+        for i, cs in enumerate(commit.signatures):
+            if cs.validator_address not in shared:
+                commit.signatures[i] = CommitSig.absent()
+
+    def double_vote(blocks):
+        commit = blocks[2].signed_header.commit
+        shared = {v.address for v in blocks[0].validator_set.validators}
+        idxs = [i for i, cs in enumerate(commit.signatures) if cs.validator_address in shared]
+        commit.signatures[idxs[1]].validator_address = commit.signatures[idxs[0]].validator_address
+
+    def wrong_next_set(blocks):
+        blocks[0].signed_header.header.next_validators_hash = (
+            blocks[2].signed_header.header.validators_hash
+        )
+
+    def commit_for_another_block(blocks):
+        blocks[2].signed_header.commit.block_id = blocks[3].signed_header.commit.block_id
+
+    return [
+        case("sound", keep, sound=True),
+        case("sound_adjacent", keep, cand=1, sound=True),
+        case("cannot_be_trusted", keep, cand=5),
+        case("exactly_a_third", keep, cand=4),
+        case("trusted_header_expired", keep, period=1.0),
+        case("trust_level_below_a_third", keep, level=Fraction(1, 4)),
+        case("height_not_above", keep, base=2, cand=2),
+        case("header_from_the_future", keep, now=Timestamp.from_unix_ns(BASE_NS - 8_000_000_000)),
+        case("time_not_after", not_after),
+        case("supplied_set_is_not_the_headers", other_set),
+        case("another_chain", other_chain),
+        case("bad_signature_the_trusting_rule_sees", bad_trusting_sig),
+        case("bad_signature_only_the_full_rule_sees", bad_full_sig),
+        case("short_of_two_thirds", short_of_two_thirds),
+        case("double_vote", double_vote),
+        case("adjacent_next_validators_mismatch", wrong_next_set, cand=1),
+        case("commit_for_another_block", commit_for_another_block),
+    ]
+
+
+class TestPlannerParity:
+    """``light/batch``'s contract: a planned candidate's outcome is
+    exactly ``verifier.verify``'s, type and message, whatever refuses
+    it."""
+
+    @pytest.mark.parametrize("mutate, base, cand, kw", _refusal_cases())
+    def test_outcome_is_verifier_verify_s(self, mutate, base, cand, kw):
+        from tendermint_tpu.light import verifier
+
+        blocks = build_rotating_chain(8)
+        mutate(blocks)
+        period = kw.get("period", 10 * HOUR)
+        level = kw.get("level", DEFAULT_TRUST_LEVEL)
+        now = kw.get("now", now_at())
+        b, c = blocks[base], blocks[cand]
+        want = None
+        try:
+            verifier.verify(
+                b.signed_header, b.validator_set, c.signed_header, c.validator_set,
+                period, now, 10.0, level,
+            )
+        except Exception as exc:
+            want = exc
+        (got,) = light_batch.evaluate_candidates(
+            CHAIN_ID, b, [c], period, now, 10.0, level
+        )
+        assert (want is None) == kw.get("sound", False)
+        if want is None:
+            assert got.kind == light_batch.OK and got.error is None
+        else:
+            assert type(got.error) is type(want) and str(got.error) == str(want)
+            cant_trust = isinstance(want, NewValSetCantBeTrustedError)
+            assert got.kind == (light_batch.BISECT if cant_trust else light_batch.ERROR)
+        # and so is a walk that meets it as its first candidate
+        walk = light_batch.Walk(CHAIN_ID, period, now, 10.0, level)
+        walk.plan(b, c, c, lambda base, current: (_ for _ in ()).throw(LookupError("no pivot")))
+        if isinstance(want, NewValSetCantBeTrustedError):
+            assert walk.refused == 1 and not walk.hops and isinstance(walk.stop, LookupError)
+        else:
+            (out,) = walk.verify()
+            assert (out.kind == light_batch.OK) == (want is None)
+            if want is not None:
+                assert type(out.error) is type(want) and str(out.error) == str(want)
 
 
 class TestHeaderCache:

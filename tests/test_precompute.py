@@ -124,6 +124,88 @@ def test_rotation_invalidates_dropped_keys():
     assert precompute.tables.lookup(pk0) is None
 
 
+# --- when an eligible key gets its table (PR 30) ------------------------------
+
+
+def _keys(vset):
+    return [v.pub_key.bytes() for v in vset.validators]
+
+
+def test_the_first_set_is_built_at_first_sight_and_later_keys_in_their_third_batch():
+    """The set the cache meets first (a node's own committee) is built
+    the first time a batch carries it. A key that becomes eligible
+    later goes table-less until the ``BUILD_AT_SIGHTING``-th batch that
+    carries it: a light client's pivot, met once or twice, never pays
+    for a table; a validator that joined the committee does, once."""
+    _, first = _vset(1, n=16)
+    assert precompute.activate_validator_set(first) == (True, False)
+    assert precompute.tables.gather(_keys(first))[1].all()
+    assert precompute.tables.stats()["builds"] == 16
+    # a committee that replaced one validator, and a set of another
+    # chain altogether: their new keys wait, the known ones still hit
+    rotated = first.copy()
+    gone = rotated.validators[3].copy()
+    gone.voting_power = 0
+    rotated.update_with_change_set([gone, Validator(_other_key(1), 10)])
+    _, other_chain = _vset(3, n=5)
+    known = set(_keys(first))
+    for vset, new in ((rotated, 1), (other_chain, 5)):
+        assert precompute.activate_validator_set(vset) == (True, False)
+        before = precompute.tables.stats()
+        for sighting in range(1, precompute.BUILD_AT_SIGHTING):
+            has = precompute.tables.gather(_keys(vset))[1]
+            assert [bool(h) for h in has] == [pk in known for pk in _keys(vset)]
+            s = precompute.tables.stats()
+            assert s["builds"] == before["builds"]
+            assert s["builds_deferred"] == before["builds_deferred"] + new * sighting
+        assert precompute.tables.gather(_keys(vset))[1].all()
+        assert precompute.tables.stats()["builds"] == before["builds"] + new
+        known.update(_keys(vset))
+    assert not precompute.tables._sightings
+    # a duplicate signer in one batch is one sighting, and in the batch
+    # that builds it one build with every lane served
+    _, pivot = _vset(2, n=4)
+    precompute.activate_validator_set(pivot)
+    dup = _keys(first)[:6] + [_keys(pivot)[0]] * 2
+    builds = precompute.tables.stats()["builds"]
+    for _ in range(1, precompute.BUILD_AT_SIGHTING):
+        assert list(precompute.tables.gather(dup)[1]) == [True] * 6 + [False] * 2
+    assert precompute.tables.gather(dup)[1].all()
+    assert precompute.tables.stats()["builds"] == builds + 1
+    # a key that leaves every live set takes its count with it
+    assert set(precompute.tables._sightings) == set()
+    precompute.tables.gather(_keys(pivot))
+    assert set(precompute.tables._sightings) == set(_keys(pivot)[1:])
+    for off in range(10, 10 + precompute._ACTIVE_SETS_CAP):
+        precompute.activate_validator_set(_vset(off)[1])
+    assert not precompute.tables._sightings
+
+
+def test_a_set_registered_with_its_hash_is_not_hashed_again(monkeypatch):
+    _, first = _vset(1)
+    _, second = _vset(2)
+    vhash = second.hash()
+    precompute.activate_validator_set(first)
+    monkeypatch.setattr(
+        ValidatorSet, "hash", lambda self: pytest.fail("hashed a set whose hash was handed in")
+    )
+    assert precompute.activate_validator_set(second, vhash) == (True, False)
+    assert precompute.activate_validator_set(second, vhash) == (False, True)
+    assert precompute.activate_validator_set(second.copy(), b"ignored: recognised") == (False, True)
+    assert precompute.tables.stats()["active_set_hashed"] == 2
+
+
+def test_sets_retired_and_tables_dropped_are_counted():
+    _, first = _vset(1)
+    precompute.activate_validator_set(first)
+    precompute.tables.gather(_keys(first))
+    for off in range(2, 3 + precompute._ACTIVE_SETS_CAP):
+        precompute.activate_validator_set(_vset(off)[1])
+    s = precompute.tables.stats()
+    assert (s["sets_retired"], s["invalidations"], s["entries"]) == (2, 3, 0)
+    assert s["active_sets"] == precompute._ACTIVE_SETS_CAP
+
+
 # --- a live set is recognised by its keys, never by hashing it (PR 29) ------
 
 
@@ -255,8 +337,12 @@ def test_live_set_is_recognised_by_its_current_keys(
         # and from now on it is the live set it says it is
         assert precompute.activate_validator_set(candidate) == (False, True)
         assert hashed == [1]
+        # its keys are eligible: each has its table by the batch that
+        # is due to build it
         keys = [v.pub_key.bytes() for v in candidate.validators]
-        assert precompute.tables.gather(keys)[1].all()
+        for _ in range(precompute.BUILD_AT_SIGHTING):
+            has_table = precompute.tables.gather(keys)[1]
+        assert has_table.all()
 
 
 def test_recognition_touches_the_entry_it_found():

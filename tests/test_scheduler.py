@@ -300,6 +300,74 @@ class TestContinuousBatching:
             release.set()
             s.stop()
 
+    @staticmethod
+    def _gated(max_batch, continuous=True):
+        """A scheduler with one dispatch slot, held by a first lane
+        until ``release`` is set; ``flushes`` records every verify_fn
+        call as the messages it carried."""
+        flushes = []
+        release = threading.Event()
+
+        def recording(pks, msgs, sigs):
+            release.wait(timeout=10)
+            flushes.append(list(msgs))
+            return host_verify(pks, msgs, sigs)
+
+        s = VerifyScheduler(
+            recording, max_batch=max_batch, max_delay=0.002,
+            continuous=continuous, pipeline_depth=1,
+        )
+        s.start()
+        gate = s.submit(*_signed(0))  # holds the one dispatch slot
+        deadline = time.monotonic() + 5
+        while s.pending_depth() and time.monotonic() < deadline:
+            time.sleep(0.002)
+        return s, gate, flushes, release
+
+    @pytest.mark.parametrize("continuous", [True, False])
+    def test_group_submitted_whole_leaves_in_one_flush(self, continuous):
+        """PR 30: ``submit_many(whole=True)`` longer than ``max_batch``
+        is one verify_fn call, not ``max_batch``-lane pieces, beside
+        whatever was already pending before it; single submits are
+        still cut at the limit."""
+        s, gate, flushes, release = self._gated(4, continuous)
+        try:
+            singles = [s.submit(*_signed(1 + i)) for i in range(2)]
+            group = s.submit_many([_signed(3 + i) for i in range(11)], whole=True)
+            tail = [s.submit(*_signed(14 + i)) for i in range(5)]
+            release.set()
+            assert all(s.wait_many([gate] + singles + group + tail, timeout=10))
+            # the two singles fit under the limit of 4 with the group's
+            # first two lanes; the cut falls inside the group, which
+            # leaves whole; the five after it are cut at 4 again
+            assert [len(f) for f in flushes] == [1, 13, 4, 1]
+            assert len({id(e.group) for e in group}) == 1 and group[0].group is not None
+        finally:
+            release.set()
+            s.stop()
+
+    @pytest.mark.parametrize("whole,want", [(False, [1, 4, 4, 3]), (True, [1, 11])])
+    def test_urgent_lane_behind_a_long_low_priority_group(self, whole, want):
+        """A long low-priority group ahead of a high-priority submit
+        (verifyd: a bulk rpc request, then a consensus vote). Cut at
+        ``max_batch``, as verifyd submits it, the vote leaves in the
+        next flush with three of the bulk lanes and waits for no other;
+        where the caller submitted the group whole, that flush is the
+        whole group: the vote's wait is one call of its length."""
+        s, gate, flushes, release = self._gated(4)
+        try:
+            bulk = [_signed(1 + i) for i in range(10)]
+            group = s.submit_many(bulk, priority=3, whole=whole)
+            vote = s.submit(*_signed(20), priority=0)
+            release.set()
+            assert all(s.wait_many([gate] + group + [vote], timeout=10))
+            assert [len(f) for f in flushes] == want
+            assert flushes[1][0] == _signed(20)[1]  # the urgent lane first
+            assert all(e.group is None for e in group) != whole
+        finally:
+            release.set()
+            s.stop()
+
     def test_submit_many_groups_race_continuous_dispatcher(self):
         """Many atomic groups racing the dispatch workers: every group
         resolves all-or-nothing and no lane is lost or double-counted."""
