@@ -1112,40 +1112,41 @@ def verify_batch(
     if n == 0:
         return []
     with tracing.span("verify_batch", engine="ed25519", lanes=n):
-        if not precompute.result_cache_enabled():
+        # The verdict cache is asked and filled once a batch
+        # (precompute.ResultCache): the lookup derives the keys, the store
+        # takes them back. ``cached`` None: every lane goes to the device
+        # (what a batch of unseen votes sends), or the cache is off.
+        keys = cached = None
+        if precompute.result_cache_enabled():
+            with tracing.span(
+                "cache_lookup", stage="cache_lookup", engine="ed25519", lanes=n
+            ) as csp:
+                keys, cached = precompute.results.get_many(pubkeys, msgs, sigs)
+                pending = [i for i, v in enumerate(cached or ()) if v is None]
+                csp.set(hits=0 if cached is None else n - len(pending))
+        if cached is None:
             verdicts = _verify_uncached(pubkeys, msgs, sigs, backend)
-            with tracing.span("merge_results", lanes=n):
-                return [bool(v) for v in verdicts]
-        verdicts = np.zeros(n, dtype=bool)
-        pending = []
-        with tracing.span(
-            "cache_lookup", stage="cache_lookup", engine="ed25519", lanes=n
-        ) as csp:
-            for i in range(n):
-                v = precompute.results.get(pubkeys[i], msgs[i], sigs[i])
-                if v is None:
-                    pending.append(i)
-                else:
-                    verdicts[i] = v
-            csp.set(hits=n - len(pending))
-        if pending:
-            if len(pending) == n:
-                sub = (pubkeys, msgs, sigs)
-            else:
-                sub = (
+            if keys is not None:
+                with tracing.span("cache_store", lanes=n) as ssp:
+                    ssp.set(evicted=precompute.results.put_many(keys, verdicts))
+        else:
+            verdicts = np.array([v is True for v in cached], dtype=bool)
+            if pending:
+                out = _verify_uncached(
                     [pubkeys[i] for i in pending],
                     [msgs[i] for i in pending],
                     [sigs[i] for i in pending],
+                    backend,
                 )
-            out = _verify_uncached(sub[0], sub[1], sub[2], backend)
-            with tracing.span("cache_store", lanes=len(pending)):
-                for j, i in enumerate(pending):
-                    verdicts[i] = out[j]
-                    precompute.results.put(
-                        pubkeys[i], msgs[i], sigs[i], bool(out[j])
+                with tracing.span("cache_store", lanes=len(pending)) as ssp:
+                    ssp.set(
+                        evicted=precompute.results.put_many(
+                            [keys[i] for i in pending], out
+                        )
                     )
+                verdicts[pending] = out
         with tracing.span("merge_results", lanes=n):
-            return [bool(v) for v in verdicts]
+            return verdicts.tolist()
 
 
 def _verify_uncached(

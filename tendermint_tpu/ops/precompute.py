@@ -16,7 +16,14 @@ a committee's lifetime:
   ``_build_lane_table`` entirely for hit lanes.
 - :class:`ResultCache` — a bounded LRU over ``(pubkey, sign-bytes
   digest, sig)`` verdicts, so blocksync/light/consensus never re-verify
-  the identical last-commit votes they verified one height ago.
+  the identical last-commit votes they verified one height ago. It has
+  two batch entry points, which ``verify_batch`` calls once each a
+  batch: ``results.get_many(pks, msgs, sigs)`` derives every lane's key
+  once and looks them all up under one hold of the lock, returning the
+  keys beside the verdicts; ``results.put_many(keys, verdicts)`` stores
+  the verified lanes under those keys, again under one hold, and
+  returns how many entries the cap pushed out. ``get`` / ``put`` are
+  their one-lane calls.
 
 Eligibility is validator-set aware: in the default ``auto`` mode only
 keys that belong to an *activated* :class:`~tendermint_tpu.types.\
@@ -531,6 +538,14 @@ class ResultCache:
     key. Consulted before enqueueing lanes so a vote verified at height
     H never costs device time again at H+1 (last-commit re-verification)
     or when flooded in from N peers.
+
+    The cache works a batch at a time: :meth:`get_many` derives each
+    lane's key once and hands the keys back, :meth:`put_many` stores the
+    verdicts under them; each reads the switch (and the cap) once and
+    takes the lock once, whatever the lane count. What they leave
+    behind — contents, LRU order, counters — is what a lane-by-lane
+    ``get`` / ``put`` loop over the same lanes leaves; ``get`` and
+    ``put`` are the one-lane calls of them.
     """
 
     def __init__(self) -> None:
@@ -539,6 +554,7 @@ class ResultCache:
         self._metrics = None  # guarded-by: _lock
         self.hits = 0  # guarded-by: _lock
         self.misses = 0  # guarded-by: _lock
+        self.evictions = 0  # guarded-by: _lock
 
     @property
     def cap(self) -> int:
@@ -552,39 +568,70 @@ class ResultCache:
             self._metrics = metrics
 
     @staticmethod
-    def _key(pk: bytes, msg: bytes, sig: bytes) -> bytes:
-        return b"".join((pk, hashlib.sha256(msg).digest(), sig))
+    def _keys(
+        pks: Sequence[bytes], msgs: Sequence[bytes], sigs: Sequence[bytes]
+    ) -> List[bytes]:
+        sha256 = hashlib.sha256
+        return [pk + sha256(msg).digest() + sig for pk, msg, sig in zip(pks, msgs, sigs)]
 
-    def get(self, pk: bytes, msg: bytes, sig: bytes) -> Optional[bool]:
+    def get_many(
+        self, pks: Sequence[bytes], msgs: Sequence[bytes], sigs: Sequence[bytes]
+    ) -> Tuple[Optional[List[bytes]], Optional[List[Optional[bool]]]]:
+        """Look a batch up: ``(keys, verdicts)``.
+
+        ``keys`` are the lanes' cache keys, to hand to :meth:`put_many`
+        once the missed lanes are verified, or None with the cache
+        switched off. ``verdicts`` holds a lane's cached verdict or None
+        where it missed, and is None itself where every lane missed.
+        """
         if not result_cache_enabled():
-            return None
-        key = self._key(pk, msg, sig)
+            return None, None
+        keys = self._keys(pks, msgs, sigs)
+        verdicts = None
+        hits = 0
         with self._lock:
             metrics = self._metrics
-            if key in self._entries:
-                self._entries.move_to_end(key)
-                self.hits += 1
-                hit = True
-                verdict = self._entries[key]
-            else:
-                self.misses += 1
-                hit = False
-                verdict = None
+            entries = self._entries
+            if not entries.keys().isdisjoint(keys):
+                verdicts = [entries.get(key) for key in keys]
+                for key, verdict in zip(keys, verdicts):
+                    if verdict is not None:
+                        entries.move_to_end(key)
+                        hits += 1
+            self.hits += hits
+            self.misses += len(keys) - hits
         if metrics is not None:
-            (metrics.result_cache_hits if hit else
-             metrics.result_cache_misses).inc()
-        return verdict
+            if hits:
+                metrics.result_cache_hits.inc(hits)
+            if len(keys) > hits:
+                metrics.result_cache_misses.inc(len(keys) - hits)
+        return keys, verdicts
+
+    def put_many(self, keys: Sequence[bytes], verdicts) -> int:
+        """Store ``verdicts`` under the ``keys`` :meth:`get_many` gave
+        for those lanes; returns how many entries the cap pushed out.
+        The whole store runs under one hold of the lock."""
+        if not result_cache_enabled():
+            return 0
+        cap = self.cap
+        evicted = 0
+        with self._lock:
+            entries = self._entries
+            for key, verdict in zip(keys, np.asarray(verdicts, dtype=bool).tolist()):
+                entries[key] = verdict
+                entries.move_to_end(key)
+                while len(entries) > cap:
+                    entries.popitem(last=False)
+                    evicted += 1
+            self.evictions += evicted
+        return evicted
+
+    def get(self, pk: bytes, msg: bytes, sig: bytes) -> Optional[bool]:
+        _, verdicts = self.get_many((pk,), (msg,), (sig,))
+        return None if verdicts is None else verdicts[0]
 
     def put(self, pk: bytes, msg: bytes, sig: bytes, verdict: bool) -> None:
-        if not result_cache_enabled():
-            return
-        key = self._key(pk, msg, sig)
-        with self._lock:
-            self._entries[key] = bool(verdict)
-            self._entries.move_to_end(key)
-            cap = self.cap
-            while len(self._entries) > cap:
-                self._entries.popitem(last=False)
+        self.put_many(self._keys((pk,), (msg,), (sig,)), (verdict,))
 
     def __len__(self) -> int:
         with self._lock:
@@ -596,11 +643,12 @@ class ResultCache:
                 "entries": len(self._entries),
                 "hits": self.hits,
                 "misses": self.misses,
+                "evictions": self.evictions,
             }
 
     def reset_stats(self) -> None:
         with self._lock:
-            self.hits = self.misses = 0
+            self.hits = self.misses = self.evictions = 0
 
     def clear(self) -> None:
         with self._lock:

@@ -208,3 +208,59 @@ def test_program_still_emits_what_the_readers_match(kind, monkeypatch):
     assert (compiled["engine"], compiled["lanes"]) == ("ed25519", 64)
     (fn,) = made
     assert fn.__name__.startswith("run") and fn.__wrapped__.__name__ == fn.__name__
+
+
+def test_program_still_emits_the_cache_spans_the_readers_match(monkeypatch):
+    """``prep_ms.*`` reads ``cache_lookup``, ``cache_store_ms.*`` reads
+    ``cache_store`` and ``engine_unnamed_ms.*`` takes both and
+    ``merge_results`` off ``verify_batch``: one span each a call, with
+    ``hits`` on the lookup and ``lanes`` / ``evicted`` on the store. Held
+    on two CPU calls whose batches carry two repeats: inside one batch a
+    repeat misses (and is stored) like any lane, from an earlier call it
+    hits."""
+    from tendermint_tpu.crypto.keys import Ed25519PrivKey
+    from tendermint_tpu.libs import tracing
+    from tendermint_tpu.ops import ed25519_batch, precompute
+
+    pks, msgs, sigs = [], [], []
+    for i in range(5):
+        priv = Ed25519PrivKey.from_seed(bytes([i + 91]) * 32)
+        msgs.append(b"cache-contract-%d" % i)
+        pks.append(priv.pub_key().bytes())
+        sigs.append(priv.sign(msgs[-1]))
+
+    def lanes(*rows):
+        return [[seq[i] for i in rows] for seq in (pks, msgs, sigs)]
+
+    def spans_of(batch):
+        tracing.tracer.clear()
+        assert ed25519_batch.verify_batch(*batch) == [True] * len(batch[0])
+        events = tracing.tracer.export(clear=True)["traceEvents"]
+        named = {}
+        for e in events:
+            if e.get("name") in ("cache_lookup", "cache_store", "merge_results"):
+                assert e["name"] not in named  # one a call
+                named[e["name"]] = e["args"]
+        return named
+
+    monkeypatch.setenv(precompute._RESULT_ENV, "1")
+    precompute.reset()
+    tracing.tracer.set_metrics_observer(None)
+    tracing.configure("ring")
+    try:
+        first = spans_of(lanes(0, 1, 2, 0, 1))
+        monkeypatch.setenv(precompute._RESULT_CAP_ENV, "2")
+        second = spans_of(lanes(3, 0, 4, 1))
+        third = spans_of(lanes(3, 4))
+        stats = precompute.results.stats()
+    finally:
+        tracing.configure("off")
+        tracing.tracer.clear()
+        precompute.reset()
+    assert (first["cache_lookup"]["lanes"], first["cache_lookup"]["hits"]) == (5, 0)
+    assert (first["cache_store"]["lanes"], first["cache_store"]["evicted"]) == (5, 0)
+    assert first["merge_results"]["lanes"] == 5
+    assert (second["cache_lookup"]["lanes"], second["cache_lookup"]["hits"]) == (4, 2)
+    assert (second["cache_store"]["lanes"], second["cache_store"]["evicted"]) == (2, 3)
+    assert third["cache_lookup"]["hits"] == 2 and "cache_store" not in third
+    assert stats == {"entries": 2, "hits": 4, "misses": 7, "evictions": 3}

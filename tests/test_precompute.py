@@ -7,7 +7,11 @@ verdict caching, and the headline amortization property — the second
 verification of the same commit builds ZERO tables (counter-asserted).
 """
 
+import hashlib
+import random
+import sys
 import threading
+from collections import OrderedDict
 
 import numpy as np
 import pytest
@@ -528,6 +532,268 @@ def test_verify_batch_answers_repeats_from_result_cache(monkeypatch):
     pks2, msgs2, sigs2 = make_batch(1, start=40)
     assert verify_batch(pks + pks2, msgs + msgs2, sigs + sigs2) == want + [True]
     assert calls == [1]
+
+
+# --- the result cache a batch at a time --------------------------------------
+
+
+class LaneByLaneCache:
+    """The per-lane ``get`` / ``put`` the batch forms replace, written
+    out: what ``get_many`` / ``put_many`` must leave behind, entry for
+    entry and count for count."""
+
+    def __init__(self):
+        self.entries = OrderedDict()
+        self.hits = self.misses = self.evictions = 0
+
+    @staticmethod
+    def key(pk, msg, sig):
+        return b"".join((pk, hashlib.sha256(msg).digest(), sig))
+
+    def get(self, pk, msg, sig):
+        key = self.key(pk, msg, sig)
+        if key in self.entries:
+            self.entries.move_to_end(key)
+            self.hits += 1
+            return self.entries[key]
+        self.misses += 1
+        return None
+
+    def put(self, pk, msg, sig, verdict, cap):
+        key = self.key(pk, msg, sig)
+        self.entries[key] = bool(verdict)
+        self.entries.move_to_end(key)
+        while len(self.entries) > cap:
+            self.entries.popitem(last=False)
+            self.evictions += 1
+
+    def batch(self, lanes, verdict_of, cap):
+        """One ``verify_batch`` of the parent: look every lane up, then
+        store the verdict of every lane that missed."""
+        pending = [lane for lane in lanes if self.get(*lane) is None]
+        for lane in pending:
+            self.put(*lane, verdict_of(lane), cap)
+        return len(lanes) - len(pending)
+
+
+class TotalsOnly:
+    """A bound ``metrics``: counters that refuse an empty increment."""
+
+    class Counter:
+        def __init__(self):
+            self.total = 0
+
+        def inc(self, n=1):
+            assert n > 0
+            self.total += n
+
+    def __init__(self):
+        self.result_cache_hits = self.Counter()
+        self.result_cache_misses = self.Counter()
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_batch_forms_leave_what_the_lane_by_lane_loop_leaves(seed, monkeypatch):
+    """Random batches over a dozen triples and a cap of a few entries:
+    hits mixed in, repeats inside a batch, both verdicts, batches larger
+    than the cap. After every batch the cache's contents, their order
+    and the counters equal the per-lane model's."""
+    monkeypatch.setenv(precompute._RESULT_ENV, "1")
+    rng = random.Random(seed)
+    cap = rng.choice([1, 3, 4, 7])
+    monkeypatch.setenv(precompute._RESULT_CAP_ENV, str(cap))
+    universe = [
+        (bytes([i]) * 32, b"m" * (i % 4) + bytes([i // 2]), bytes([i % 5]) * 64)
+        for i in range(12)
+    ]
+
+    def verdict_of(lane):
+        return lane[0][0] % 3 != 0
+
+    rc, model, metrics = precompute.results, LaneByLaneCache(), TotalsOnly()
+    rc.bind_metrics(metrics)
+    try:
+        for _ in range(300):
+            lanes = rng.choices(universe, k=rng.choice([0, 1, 2, 3, 5, 9]))
+            want_hits = model.batch(lanes, verdict_of, cap)
+
+            keys, cached = rc.get_many(*map(list, zip(*lanes))) if lanes else ([], None)
+            assert keys == [model.key(*lane) for lane in lanes]
+            if cached is None:
+                pending = list(range(len(lanes)))
+            else:
+                assert [c for c in cached if c is not None] == [
+                    verdict_of(lane) for lane, c in zip(lanes, cached) if c is not None
+                ]
+                pending = [i for i, c in enumerate(cached) if c is None]
+            assert len(lanes) - len(pending) == want_hits
+            rc.put_many(
+                [keys[i] for i in pending],
+                np.array([verdict_of(lanes[i]) for i in pending], dtype=bool),
+            )
+
+            assert list(rc._entries.items()) == list(model.entries.items())
+            got = rc.stats()
+            assert (got["entries"], got["hits"], got["misses"], got["evictions"]) == (
+                len(model.entries), model.hits, model.misses, model.evictions,
+            )
+            assert metrics.result_cache_hits.total == model.hits
+            assert metrics.result_cache_misses.total == model.misses
+    finally:
+        rc.bind_metrics(None)
+    assert model.hits and model.evictions  # the sequence reached both
+
+
+class CountingLock:
+    def __init__(self):
+        self.acquired = 0
+        self._lock = threading.Lock()
+
+    def __enter__(self):
+        self.acquired += 1
+        return self._lock.__enter__()
+
+    def __exit__(self, *exc):
+        return self._lock.__exit__(*exc)
+
+
+@pytest.fixture
+def stubbed_engine(monkeypatch):
+    """``verify_batch`` with the device taken out (``_verify_uncached``
+    answers from the lane's first byte), SHA-256 counted and the verdict
+    cache's lock counted: ``seen`` holds, for each engine call, (lanes,
+    digests so far, lock acquisitions so far)."""
+    monkeypatch.setenv(precompute._RESULT_ENV, "1")
+    counts = {"sha256": 0}
+    sha256 = hashlib.sha256
+
+    def counted(*args, **kwargs):
+        counts["sha256"] += 1
+        return sha256(*args, **kwargs)
+
+    lock = CountingLock()
+    seen = []
+
+    def engine(pks, msgs, sigs, backend=None):
+        seen.append((len(pks), counts["sha256"], lock.acquired))
+        return np.array([pk[0] % 2 == 0 for pk in pks], dtype=bool)
+
+    monkeypatch.setattr(hashlib, "sha256", counted)
+    monkeypatch.setattr(precompute.results, "_lock", lock)
+    monkeypatch.setattr(ed25519_batch, "_verify_uncached", engine)
+    return counts, lock, seen
+
+
+def random_lanes(n, seed):
+    rng = random.Random(seed)
+    return (
+        [rng.randbytes(32) for _ in range(n)],
+        [rng.randbytes(112 + (i & 1)) for i in range(n)],
+        [rng.randbytes(64) for _ in range(n)],
+    )
+
+
+def test_verify_batch_digests_once_a_lane_and_locks_once_an_operation(stubbed_engine):
+    counts, lock, seen = stubbed_engine
+    n = 3000
+    pks, msgs, sigs = random_lanes(n, 31)
+    want = [pk[0] % 2 == 0 for pk in pks]
+
+    def call(*lanes):
+        """(verdicts, digests taken, holds of the lock) of one call."""
+        before = counts["sha256"], lock.acquired
+        verdicts = verify_batch(*lanes)
+        return verdicts, counts["sha256"] - before[0], lock.acquired - before[1]
+
+    # unseen lanes: one digest a lane and one hold of the lock before the
+    # engine runs, one more hold and no digest to store its answers
+    assert call(pks, msgs, sigs) == (want, n, 2)
+    assert seen == [(n, n, 1)]
+    assert precompute.results.stats() == {
+        "entries": n, "hits": 0, "misses": n, "evictions": 0,
+    }
+
+    # the same lanes again: answered under one hold, nothing stored
+    assert call(pks, msgs, sigs) == (want, n, 1)
+    assert len(seen) == 1
+
+    # hits and misses mixed, a fresh triple carried twice: the engine gets
+    # the misses only (the repeat twice), and the cache one entry for it
+    new = random_lanes(500, 32)
+    mixed = [a[:1000] + b + b[:1] for a, b in zip((pks, msgs, sigs), new)]
+    want_mixed = want[:1000] + [pk[0] % 2 == 0 for pk in mixed[0][1000:]]
+    held = lock.acquired
+    assert call(*mixed) == (want_mixed, 1501, 2)
+    assert seen[1] == (501, 2 * n + 1501, held + 1)
+    assert precompute.results.stats() == {
+        "entries": n + 500, "hits": n + 1000, "misses": n + 501, "evictions": 0,
+    }
+
+
+def test_switch_and_cap_are_read_again_every_batch(stubbed_engine, monkeypatch):
+    counts, lock, seen = stubbed_engine
+    pks, msgs, sigs = random_lanes(40, 33)
+    want = [pk[0] % 2 == 0 for pk in pks]
+    assert verify_batch(pks, msgs, sigs) == want
+    stats = precompute.results.stats()
+    before = (counts["sha256"], lock.acquired)
+
+    # switched off between two calls: the second neither asks nor fills
+    monkeypatch.setenv(precompute._RESULT_ENV, "0")
+    assert verify_batch(pks, msgs, sigs) == want
+    assert [lanes for lanes, _, _ in seen] == [40, 40]
+    assert (counts["sha256"], lock.acquired) == before
+    assert precompute.results.stats() == stats
+    assert precompute.results.get_many(pks, msgs, sigs) == (None, None)
+    assert precompute.results.put_many([b"k"], [True]) == 0
+
+    # the cap lowered between two stores: the second evicts down to it,
+    # oldest first
+    monkeypatch.setenv(precompute._RESULT_ENV, "1")
+    monkeypatch.setenv(precompute._RESULT_CAP_ENV, "3")
+    newest = list(precompute.results._entries)[-2:]
+    assert precompute.results.put_many([b"one more"], [False]) == 38
+    assert list(precompute.results._entries.items()) == [
+        (newest[0], want[-2]), (newest[1], want[-1]), (b"one more", False),
+    ]
+    assert precompute.results.stats()["evictions"] == 38
+
+
+def test_concurrent_batches_lose_no_count(monkeypatch):
+    """More threads than cores, each looking up and storing batches of
+    keys no other thread sends: a lost update would break ``misses`` =
+    lanes asked, ``entries`` + ``evictions`` = lanes stored."""
+    monkeypatch.setenv(precompute._RESULT_ENV, "1")
+    monkeypatch.setenv(precompute._RESULT_CAP_ENV, "64")
+    rc = precompute.results
+    threads, batches, lanes = 12, 150, 20
+    errors = []
+
+    def work(t):
+        try:
+            for b in range(batches):
+                pks = [bytes([t, b, i]) + bytes(29) for i in range(lanes)]
+                keys, cached = rc.get_many(pks, [b"m"] * lanes, [bytes(64)] * lanes)
+                assert cached is None
+                rc.put_many(keys, [True] * lanes)
+        except Exception as e:  # pragma: no cover - surfaced below
+            errors.append(e)
+
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        workers = [threading.Thread(target=work, args=(t,)) for t in range(threads)]
+        for w in workers:
+            w.start()
+        for w in workers:
+            w.join(timeout=60)
+    finally:
+        sys.setswitchinterval(switch)
+    assert not errors and not any(w.is_alive() for w in workers)
+    s = rc.stats()
+    assert (s["hits"], s["misses"]) == (0, threads * batches * lanes)
+    assert s["entries"] == 64
+    assert s["entries"] + s["evictions"] == threads * batches * lanes
 
 
 # --- the headline amortization property -------------------------------------
