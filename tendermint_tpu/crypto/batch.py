@@ -67,7 +67,9 @@ def tiered_verify_ed25519(pks, msgs, sigs) -> List[bool]:
     return list(verify_batch(pks, msgs, sigs))
 
 
-def note_validator_set(vals, vhash: Optional[bytes] = None) -> Tuple[bool, bool]:
+def note_validator_set(
+    vals, vhash: Optional[bytes] = None, span=None
+) -> Tuple[bool, bool]:
     """Register the active validator set with the device precompute
     cache (ops/precompute.py): its ed25519 keys become eligible for
     per-validator table caching, and stale keys from rotated-out sets
@@ -81,7 +83,9 @@ def note_validator_set(vals, vhash: Optional[bytes] = None) -> Tuple[bool, bool]
     keys without hashing it (``precompute.activate_validator_set``).
     ``vhash`` is ``vals.hash()`` where the caller already holds it (the
     light client has checked it against the header): a set that is not
-    recognised is then registered without being hashed again.
+    recognised is then registered without being hashed again. ``span``
+    is handed on to the cache, which tells it what a newly registered
+    set pushed out (:func:`note_validator_set_traced`).
     """
     try:
         from tendermint_tpu.ops import precompute
@@ -89,7 +93,7 @@ def note_validator_set(vals, vhash: Optional[bytes] = None) -> Tuple[bool, bool]
         return False, False
     noted = (False, False)
     try:
-        noted = precompute.activate_validator_set(vals, vhash)
+        noted = precompute.activate_validator_set(vals, vhash, span)
     except Exception:
         pass  # cache warm-up must never fail a verification
     # federation routing hook: same best-effort contract; the key list
@@ -104,6 +108,19 @@ def note_validator_set(vals, vhash: Optional[bytes] = None) -> Tuple[bool, bool]
                 client.note_validator_set(sorted(keys))
     except Exception:
         pass  # routing locality is an optimization, never a failure
+    return noted
+
+
+def note_validator_set_traced(
+    vals, vhash: Optional[bytes] = None
+) -> Tuple[bool, bool]:
+    """:func:`note_validator_set` under the ``note_validator_set`` span
+    every caller records it with: ``validators``, ``newly_active``,
+    ``recognised`` and, where the set was newly registered, ``retired``
+    and ``tables_dropped`` (``precompute.activate_validator_set``)."""
+    with tracing.span("note_validator_set", validators=len(vals)) as nsp:
+        noted = note_validator_set(vals, vhash, nsp)
+        nsp.set(newly_active=noted[0], recognised=noted[1])
     return noted
 
 

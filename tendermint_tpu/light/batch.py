@@ -377,7 +377,7 @@ def _plan_candidate(
                     "int64 overflow while calculating voting power needed"
                 )
             needed = total_mul // trust_level.denominator
-            _note_set(vals_t)
+            crypto_batch.note_validator_set_traced(vals_t)
             idxs, vals, tallied = batch.tally(
                 _tally_trusting, batch.by_address(vals_t), commit, needed
             )
@@ -399,7 +399,7 @@ def _plan_candidate(
             vals_u, commit, sh_u.header.height, commit.block_id
         )
         needed2 = vals_u.total_voting_power() * 2 // 3
-        _note_set(vals_u, vhash_u)
+        crypto_batch.note_validator_set_traced(vals_u, vhash_u)
         idxs2, vals2, tallied2 = batch.tally(_tally_full, vals_u, commit, needed2)
         if tallied2 <= needed2:
             # NotEnoughVotingPowerError is not a ValueError: it escapes
@@ -412,12 +412,6 @@ def _plan_candidate(
     except InvalidCommitError as e:
         plan.steps.append(_RaiseStep(verifier.InvalidHeaderError(str(e))))
     return plan
-
-
-def _note_set(vals, vhash: Optional[bytes] = None) -> None:
-    with tracing.span("note_validator_set", validators=len(vals)) as nsp:
-        newly_active, recognised = crypto_batch.note_validator_set(vals, vhash)
-        nsp.set(newly_active=newly_active, recognised=recognised)
 
 
 def _resolve(plan: _Plan, verdicts: List[bool]) -> Outcome:

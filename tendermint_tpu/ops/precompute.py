@@ -270,13 +270,16 @@ class PrecomputeCache:
     # --- validator-set awareness -------------------------------------------
 
     def activate_validator_set(
-        self, vset, vhash: Optional[bytes] = None
+        self, vset, vhash: Optional[bytes] = None, span=None
     ) -> Tuple[bool, bool]:
         """Mark a validator set live: its keys become table-eligible.
         Returns ``(newly_active, recognised)``. ``vhash`` is the set's
         ``hash()`` where the caller has just computed it (the light
         client checks it against the header); it is taken only for a
-        set that is not recognised.
+        set that is not recognised. ``span`` is the caller's
+        ``note_validator_set`` span: it is told what this activation
+        cost the cache, ``retired`` (live sets pushed out) and
+        ``tables_dropped`` (host tables deleted with them).
 
         A live set is recognised by the tuple of its validators'
         ``pub_key`` objects, rebuilt on every call from what the set
@@ -323,10 +326,14 @@ class PrecomputeCache:
             self._active_sets[vhash] = (
                 pub_keys, _vset_ed25519_keys(vset), not self._active_sets
             )
+            retired = 0
             while len(self._active_sets) > _ACTIVE_SETS_CAP:
                 self._active_sets.popitem(last=False)
-                self.sets_retired += 1
-            self._recompute_eligible_locked()
+                retired += 1
+            self.sets_retired += retired
+            dropped = self._recompute_eligible_locked()
+        if span is not None:
+            span.set(retired=retired, tables_dropped=dropped)
         self._flush_events()
         return True, False
 
@@ -337,7 +344,9 @@ class PrecomputeCache:
             self._recompute_eligible_locked()
         self._flush_events()
 
-    def _recompute_eligible_locked(self) -> None:
+    def _recompute_eligible_locked(self) -> int:
+        """Eligibility from the live sets and the pins; returns how many
+        host tables it deleted (keys that left every live set)."""
         eligible = set(self._pinned)
         founders = set(self._pinned)
         for _, keys, first in self._active_sets.values():
@@ -349,15 +358,17 @@ class PrecomputeCache:
         self._sightings = {
             pk: n for pk, n in self._sightings.items() if pk in eligible
         }
-        if _mode() == "auto":
-            stale = [pk for pk in self._entries if pk not in self._eligible]
-            for pk in stale:
-                del self._entries[pk]
-            if stale:
-                self.invalidations += len(stale)
-                self._pending_events.append(("rotation", tuple(stale)))
-                if self._metrics is not None:
-                    self._metrics.precompute_invalidations.inc(len(stale))
+        if _mode() != "auto":
+            return 0
+        stale = [pk for pk in self._entries if pk not in self._eligible]
+        for pk in stale:
+            del self._entries[pk]
+        if stale:
+            self.invalidations += len(stale)
+            self._pending_events.append(("rotation", tuple(stale)))
+            if self._metrics is not None:
+                self._metrics.precompute_invalidations.inc(len(stale))
+        return len(stale)
 
     def _due_for_build_locked(self, pk: bytes) -> bool:
         """Whether the batch that carries table-less ``pk`` builds its
@@ -662,8 +673,10 @@ tables = PrecomputeCache()
 results = ResultCache()
 
 
-def activate_validator_set(vset, vhash: Optional[bytes] = None) -> Tuple[bool, bool]:
-    return tables.activate_validator_set(vset, vhash)
+def activate_validator_set(
+    vset, vhash: Optional[bytes] = None, span=None
+) -> Tuple[bool, bool]:
+    return tables.activate_validator_set(vset, vhash, span)
 
 
 def pin_pubkeys(pubkeys: Iterable[bytes]) -> None:

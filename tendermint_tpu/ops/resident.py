@@ -128,13 +128,19 @@ class ResidentTableStore:
             return tuple(plan.device_ids), None
         return None, backend
 
-    def refresh(self, plan=None, backend: Optional[str] = None) -> bool:
+    def refresh(
+        self, plan=None, backend: Optional[str] = None, reason: str = "first"
+    ) -> bool:
         """Upload the host cache's live-committee slice to the device.
 
         Builds the ``(8, 4, 32, K)`` tensor on host (column 0 = pad
         table), ships it once, and installs it unless an invalidation
         raced the upload (version check). Returns True when a usable
-        device copy is installed.
+        device copy is installed. ``reason`` goes on the
+        ``resident_upload`` span: ``first`` (no copy yet), ``dropped``
+        (an invalidation took the copy), ``joined`` (a batch carried a
+        host-cached key the copy lacks) or ``context`` (the copy was
+        uploaded for another mesh or backend).
         """
         from tendermint_tpu.ops import ed25519_batch, precompute
 
@@ -173,6 +179,8 @@ class ResidentTableStore:
                 engine="ed25519",
                 keys=len(index),
                 bytes=nbytes,
+                width=width,
+                reason=reason,
             ):
                 tab_dev = self._device_put(host_tab, plan, backend)
         except Exception:  # upload is an optimization; fail safe to gather
@@ -219,8 +227,9 @@ class ResidentTableStore:
             return jax.device_put(host_tab, dev)
         return jax.device_put(host_tab)
 
-    def invalidate(self, pubkeys: Iterable[bytes]) -> None:
-        """Host cache dropped these keys: the device copy dies with them."""
+    def invalidate(self, pubkeys: Iterable[bytes], reason: str = "rotation") -> None:
+        """Host cache dropped these keys (``reason``: ``rotation`` or
+        ``evict``): the device copy dies with them."""
         keys = [bytes(pk) for pk in pubkeys]
         with self._lock:
             # an evicted key leaves the shard's pinned slice whether or
@@ -232,18 +241,34 @@ class ResidentTableStore:
                 return
             if not any(pk in self._index for pk in keys):
                 return
-            self._drop_locked()
+            self._drop_locked(reason, departed=len(keys))
 
     def clear(self) -> None:
         with self._lock:
-            self._drop_locked()
+            self._drop_locked("clear")
             self._hot_counts.clear()
             self._pinned.clear()
             self._account_host_locked()
 
-    def _drop_locked(self) -> None:
-        if self._tab_dev is not None:
+    def _drop_locked(self, reason: str, departed: int = 0) -> None:
+        """Forget the device copy, whole. Where there was one, under a
+        ``resident_drop`` span: ``keys`` the copy held, ``departed``
+        the keys the host cache named, ``reason`` =
+        ``rotation|evict|clear``."""
+        if self._tab_dev is None:
+            self._forget_locked()
+            return
+        with tracing.span(
+            "resident_drop",
+            engine="ed25519",
+            keys=len(self._index),
+            departed=departed,
+            reason=reason,
+        ):
             self.invalidations += 1
+            self._forget_locked()
+
+    def _forget_locked(self) -> None:
         self._index = {}
         self._tab_dev = None
         self._ok_host = None
@@ -301,21 +326,26 @@ class ResidentTableStore:
     def _acquire(self, pubkeys, has_table, plan, backend, sp):
         n = len(pubkeys)
         want_key = self._context_key(plan, backend)
+        # why the device copy cannot serve this batch, if it cannot
+        stale = None
         with self._lock:
-            stale = self._tab_dev is None or (
-                (self._mesh_key, self._backend_key) != want_key
-            )
-            if not stale:
+            if self._tab_dev is None:
+                # no copy: none was sent yet, or a drop took it
+                stale = "dropped" if self.invalidations else "first"
+            elif (self._mesh_key, self._backend_key) != want_key:
+                stale = "context"
+            else:
                 # committee growth: a host-cached key the store has not
                 # seen yet means the upload predates it — refresh once
                 # so new validators join the resident tensor
                 index = self._index
-                stale = any(
+                if any(
                     has_table[i] and bytes(pubkeys[i]) not in index
                     for i in range(n)
-                )
+                ):
+                    stale = "joined"
         if stale:
-            if not self.refresh(plan=plan, backend=backend):
+            if not self.refresh(plan=plan, backend=backend, reason=stale):
                 return None
         with self._lock:
             tab_dev = self._tab_dev
@@ -435,7 +465,7 @@ class ResidentTableStore:
 
     def reset(self) -> None:
         with self._lock:
-            self._drop_locked()
+            self._drop_locked("clear")
             self._hot_counts.clear()
             self._tenant_pins.clear()
             self._pinned.clear()
@@ -454,7 +484,7 @@ store = ResidentTableStore()
 def _on_cache_event(kind: str, payload: tuple) -> None:
     """precompute.py observer: host invalidation -> device invalidation."""
     if kind in ("rotation", "evict"):
-        store.invalidate(payload)
+        store.invalidate(payload, reason=kind)
     elif kind == "clear":
         store.clear()
 

@@ -52,12 +52,6 @@ class CommitVerdict:
     error: Optional[Exception] = None
 
 
-def _note_validator_set_traced(vals: ValidatorSet) -> None:
-    with tracing.span("note_validator_set", validators=len(vals)) as nsp:
-        newly_active, recognised = crypto_batch.note_validator_set(vals)
-        nsp.set(newly_active=newly_active, recognised=recognised)
-
-
 def _verify_light_alone(task: CommitTask) -> CommitVerdict:
     try:
         verify_commit_light(
@@ -99,7 +93,7 @@ def verify_commits_pipelined(
             timed_lane = lsp.timed("sign_bytes", lambda lane, idx: lane(idx))
             prefixes = 0
             note_set = (
-                _note_validator_set_traced
+                crypto_batch.note_validator_set_traced
                 if lsp.live
                 else crypto_batch.note_validator_set
             )

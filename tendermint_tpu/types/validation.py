@@ -178,9 +178,7 @@ def _verify_commit_batch(
     batch_sig_idxs = []
     # Make this set's keys eligible for the device precompute cache —
     # the second commit from the same validators skips its table builds.
-    with tracing.span("note_validator_set", validators=len(vals)) as nsp:
-        newly_active, recognised = crypto_batch.note_validator_set(vals)
-        nsp.set(newly_active=newly_active, recognised=recognised)
+    crypto_batch.note_validator_set_traced(vals)
     # Mixed validator sets sub-batch per key type (BASELINE config 5);
     # an unsupported key (secp256k1) raises on add -> single fallback.
     bv = crypto_batch.MultiBatchVerifier()

@@ -81,3 +81,15 @@ def _fresh_verify_caches(monkeypatch):
     mesh.manager.reset()
     yield
     mesh.manager.reset()
+
+
+@pytest.fixture
+def ring_tracer():
+    """The program's tracer recording into its ring for one test."""
+    from tendermint_tpu.libs import tracing
+
+    tracing.configure("ring")
+    tracing.tracer.clear()
+    yield tracing.tracer
+    tracing.configure("off")
+    tracing.tracer.clear()

@@ -6,7 +6,13 @@ makeCommit / deterministicValidatorSet).
 
 from __future__ import annotations
 
+import fcntl
 import hashlib
+import json
+import os
+import subprocess
+import sys
+import tempfile
 from typing import List, Optional, Tuple
 
 from tendermint_tpu.crypto.keys import Ed25519PrivKey
@@ -81,3 +87,31 @@ def make_commit(
         sigs.append(cs)
     commit.signatures = sigs
     return commit
+
+
+def rehearse_cell(bench: str, cell: str, seed: int, trace: int, *extra, timeout: int = 300):
+    """(result line, standard output) of one CPU rehearsal of a
+    benchmark cell's tiny twin: ``chipbench.run --rehearse`` in a child.
+
+    Every run empties one directory of the checkout (``.chipbench_trace``)
+    before and after its window, and a traced run profiles into it. The
+    rehearsals of several test files, which xdist gives to several
+    workers, therefore share a lock file: a traced run holds it alone,
+    untraced ones hold it together."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cmd = [
+        sys.executable, "-m", "chipbench.run", "--workload", cell, "--seed", str(seed),
+        "--seconds", "1", "--trace", str(trace), "--rehearse", "--bench-file", bench, *extra,
+    ]
+    lock = os.path.join(
+        tempfile.gettempdir(),
+        "chipbench_trace_%s.lock" % hashlib.sha256(root.encode()).hexdigest()[:12],
+    )
+    with open(lock, "w") as turn:
+        fcntl.flock(turn, fcntl.LOCK_EX if trace else fcntl.LOCK_SH)
+        proc = subprocess.run(
+            cmd, cwd=root, capture_output=True, text=True, timeout=timeout,
+            env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stdout

@@ -10,16 +10,14 @@ proves paths, never numbers).
 from __future__ import annotations
 
 import hashlib
-import json
 import os
-import subprocess
-import sys
 
 import pytest
 
 from chipbench import reference_lightclient as plain
 from chipbench import selftest, spec, workload
 from chipbench.generators import lightclient
+from tests.helpers import rehearse_cell
 
 BENCH = os.path.join(spec.HERE, "testdata", "tiny-light-benchmark.json")
 CELL = "tiny-light-chain"
@@ -260,16 +258,7 @@ def test_light_metrics_add_up_on_nested_spans():
 
 
 def rehearse(trace: int, *extra, seed=2**31 + 30):
-    """(result line, standard output) of one rehearsal of the tiny twin."""
-    proc = subprocess.run(
-        [sys.executable, "-m", "chipbench.run", "--workload", CELL,
-         "--seed", str(seed), "--seconds", "1", "--trace", str(trace),
-         "--rehearse", "--bench-file", BENCH, *extra],
-        cwd=spec.ROOT, capture_output=True, text=True, timeout=420,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"),
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stdout
+    return rehearse_cell(BENCH, CELL, seed, trace, *extra, timeout=420)
 
 
 COMPARED = ("verdict_cache_hits_in_window", "compilations_in_window", "timed_calls_refused",

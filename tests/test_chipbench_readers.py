@@ -157,8 +157,14 @@ def test_program_still_emits_what_the_readers_match(kind, monkeypatch):
     the ``kernel_compile`` span names the kernel
     ``pad_lane_share.KERNEL_OF_KIND`` maps the kind to; and the jitted
     program is called ``run…``, which is what ``kernel_ms.*`` and
-    ``verify_roofline.*`` find on the device trace (``jit_run*``). A
-    refactor that renames any of these fails here, not on the chip."""
+    ``verify_roofline.*`` find on the device trace (``jit_run*``). And
+    what the ``.rot`` metrics read of a set change (PR 32):
+    ``route_lanes``' ``legacy``, ``gather_tables``' ``builds``,
+    ``resident_upload``'s ``reason`` and ``width``, and, once eight newer
+    sets have pushed a carried one out, ``note_validator_set``'s
+    ``retired`` / ``tables_dropped`` around a ``valset_hash`` and the
+    ``resident_drop`` span. A refactor that renames any of these fails
+    here, not on the chip."""
     from chipbench.readers.pad_lane_share import KERNEL_OF_KIND
     from tendermint_tpu.crypto.keys import Ed25519PrivKey
     from tendermint_tpu.libs import tracing
@@ -194,6 +200,8 @@ def test_program_still_emits_what_the_readers_match(kind, monkeypatch):
     try:
         assert ed25519_batch.verify_batch(pks, msgs, sigs) == [True] * 3
         events = tracing.tracer.export(clear=True)["traceEvents"]
+        if kind == "resident":
+            rotation = _a_carried_set_pushed_out_by_eight_newer_ones()
     finally:
         tracing.configure("off")
         tracing.tracer.clear()
@@ -208,6 +216,49 @@ def test_program_still_emits_what_the_readers_match(kind, monkeypatch):
     assert (compiled["engine"], compiled["lanes"]) == ("ed25519", 64)
     (fn,) = made
     assert fn.__name__.startswith("run") and fn.__wrapped__.__name__ == fn.__name__
+    (route,) = [e["args"] for e in events if e.get("name") == "route_lanes"]
+    assert route["legacy"] == (3 if kind == "legacy" else 0) and route[kind] == 3
+    (gather,) = [e["args"] for e in events if e.get("name") == "gather_tables"]
+    assert gather["builds"] == (0 if kind == "legacy" else 3)
+    uploads = [e["args"] for e in events if e.get("name") == "resident_upload"]
+    assert [(u["reason"], u["width"], u["keys"]) for u in uploads] == (
+        [("first", 64, 3)] if kind == "resident" else []
+    )
+    if kind != "resident":
+        return
+    notes = [e["args"] for e in rotation if e["name"] == "note_validator_set"]
+    assert [n.get("retired") for n in notes] == [0] * 8 + [1]
+    assert [n.get("tables_dropped") for n in notes] == [0] * 8 + [2]
+    assert sum(1 for e in rotation if e["name"] == "valset_hash") == 9
+    (drop,) = [e["args"] for e in rotation if e["name"] == "resident_drop"]
+    assert (drop["reason"], drop["keys"], drop["departed"]) == ("rotation", 5, 2)
+    (upload,) = [e["args"] for e in rotation if e["name"] == "resident_upload"]
+    assert (upload["reason"], upload["keys"]) == ("joined", 5)
+
+
+def _a_carried_set_pushed_out_by_eight_newer_ones() -> list:
+    """The spans of: a two-validator set noted beside three pinned keys,
+    its tables built and sent to the store, then eight other sets."""
+    from tendermint_tpu.crypto import batch as crypto_batch
+    from tendermint_tpu.crypto.keys import Ed25519PrivKey
+    from tendermint_tpu.libs import tracing
+    from tendermint_tpu.ops import precompute, resident
+    from tests.helpers import make_validators
+
+    sets = [
+        make_validators(
+            2, key_factory=lambda i, o=o: Ed25519PrivKey.from_seed(bytes([101 + 2 * o + i]) * 32)
+        )[1]
+        for o in range(9)
+    ]
+    tracing.tracer.clear()
+    crypto_batch.note_validator_set_traced(sets[0])
+    keys = [v.pub_key.bytes() for v in sets[0].validators]
+    has_table = precompute.tables.gather(keys)[1]  # the first set a cache meets: built at first sight
+    assert has_table.all() and resident.acquire(keys, has_table) is not None
+    for vset in sets[1:]:
+        crypto_batch.note_validator_set_traced(vset)
+    return [e for e in tracing.tracer.export(clear=True)["traceEvents"] if e.get("ph") == "X"]
 
 
 def test_program_still_emits_the_cache_spans_the_readers_match(monkeypatch):

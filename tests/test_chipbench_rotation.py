@@ -1,0 +1,340 @@
+"""The benchmark's part of the ``sync500rot`` deployment, without a chip:
+the plain rotation reference on hand-made sets, the cell's files, the
+generator's windows against the syncer's own on a real chain, the
+old-key-in-the-new-seat fault, the ``.rot`` metrics reduced on hand-made
+spans, and the cell's tiny twin rehearsed end to end on the CPU (a
+rehearsal proves paths, never numbers).
+"""
+
+from __future__ import annotations
+
+import base64
+import os
+
+import pytest
+
+from chipbench import reference, reference_light, reference_rotation, selftest, spec, workload
+from chipbench.run import Context
+from tests.helpers import rehearse_cell
+
+BENCH = os.path.join(spec.HERE, "testdata", "tiny-rotation-benchmark.json")
+CELL = "tiny-sync-rotation"
+SEED = 2**31 + 26
+
+
+# --- the plain reference ------------------------------------------------------
+
+
+def key(i: int) -> bytes:
+    return workload.Signer(bytes([i + 1]) * 32).pub
+
+
+def by_address(keys):
+    return sorted(keys, key=reference_rotation.address)
+
+
+@pytest.mark.parametrize(
+    "genesis,updates,want",
+    [
+        # one leaves, one joins, equal power: address order decides the seat
+        ([(key(i), 10) for i in range(4)], [(key(1), 0), (key(7), 10)],
+         [(k, 10) for k in by_address([key(0), key(2), key(3), key(7)])]),
+        # more power sorts first, whatever the address
+        ([(key(i), 10) for i in range(3)], [(key(5), 30)],
+         [(key(5), 30)] + [(k, 10) for k in by_address([key(0), key(1), key(2)])]),
+        # a power changed in place
+        ([(key(0), 10), (key(1), 10)], [(key(1), 5)], [(key(0), 10), (key(1), 5)]),
+        # removal and addition of one key in one change set is a duplicate
+        ([(key(0), 10), (key(1), 10)], [(key(1), 0), (key(1), 10)], ValueError),
+        ([(key(0), 10)], [(key(3), 0)], ValueError),  # not in the set
+        ([(key(0), 10)], [(key(0), 0)], ValueError),  # would leave no validator
+        ([(key(0), 10)], [(key(1), -1)], ValueError),
+    ],
+)
+def test_reference_rotation_apply_updates(genesis, updates, want):
+    if want is ValueError:
+        with pytest.raises(ValueError):
+            reference_rotation.apply_updates(genesis, updates)
+    else:
+        assert reference_rotation.apply_updates(genesis, updates) == want
+
+
+def test_reference_rotation_chain_answers_the_set_of_each_height():
+    genesis = [(key(i), 10) for i in range(4)]
+    chain = reference_rotation.Chain(
+        genesis, [(3, [(key(0), 0), (key(4), 10)]), (7, [(key(1), 0), (key(5), 10)])]
+    )
+    first = [(k, 10) for k in by_address([key(i) for i in range(4)])]
+    second = [(k, 10) for k in by_address([key(1), key(2), key(3), key(4)])]
+    third = [(k, 10) for k in by_address([key(2), key(3), key(4), key(5)])]
+    assert [chain.validators_at(h) for h in (1, 2, 3, 6, 7, 90)] == [
+        first, first, second, second, third, third,
+    ]
+    assert [chain.first_height_of_set_at(h) for h in (2, 3, 6, 7)] == [1, 3, 3, 7]
+    with pytest.raises(ValueError):
+        reference_rotation.Chain(genesis, [(5, []), (5, [])])
+    with pytest.raises(ValueError):
+        chain.validators_at(0)
+
+
+def test_reference_rotation_imports_nothing_of_the_program():
+    with open(os.path.join(spec.HERE, "reference_rotation.py")) as fh:
+        text = fh.read()
+    assert "tendermint_tpu" not in text.split('"""', 2)[2]
+
+
+# --- the files ------------------------------------------------------------------
+
+
+def test_benchmark_files_agree():
+    selftest.test_files()
+    real = spec.Spec(os.path.join(spec.ROOT, "BENCHMARK.json"))
+    cell = real.cell("sync500-rotation")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == ("sync500rot", "rotating-windows", 1)
+    config, control = real.config("sync500rot"), real.config("sync500")
+    # everything sync500 fixes is kept letter for letter
+    for kept in ("validators", "key_type", "voting_power", "verify_window", "absent_share",
+                 "nil_share", "lanes_per_call", "signing", "sign_bytes", "chips", "env"):
+        assert config[kept] == control[kept], kept
+    assert config["guarantees"][: len(control["guarantees"])] == control["guarantees"]
+    assert list(config["reduced"]) == ["blocks"]
+    assert config["rotate_every"] == config["verify_window"] == 16
+    assert (config["first_change_height"] - 1) % 16  # the first change cuts a window
+    # away for more than the live sets the program remembers
+    from tendermint_tpu.ops import precompute
+
+    assert config["ring_candidates"] - config["ring_seats"] > precompute._ACTIVE_SETS_CAP
+    assert config["ring_seats"] >= precompute.BUILD_AT_SIGHTING
+    traffic = real.traffic("rotating-windows")
+    assert traffic["warm_up_sets"] >= precompute._ACTIVE_SETS_CAP + precompute.BUILD_AT_SIGHTING
+    from chipbench.generators import cycle_length
+
+    windows = max(cycle_length(traffic, 5344, 65536), config["ring_candidates"])
+    assert windows == 18
+    assert config["blocks"] == config["first_change_height"] - 1 + windows * 16
+    assert [m["name"] for m in real.metrics_for("end_to_end", "sync500-rotation")] == ["sigs_per_s", "setup_s"]
+    rot = [m["name"] for m in real.metrics_for("per_layer", "sync500-rotation")]
+    assert len(rot) == 25 and all(name.endswith(".rot") for name in rot)
+    tiny = spec.Spec(BENCH)
+    assert [m["name"] for m in tiny.metrics_for("per_layer", CELL)] == rot
+
+
+# --- the generator, in this process -----------------------------------------------
+
+
+@pytest.fixture
+def tiny(monkeypatch):
+    """The tiny twin's traffic, built as ``run.py`` builds it."""
+    from tendermint_tpu.ops import precompute, resident
+
+    bench = spec.Spec(BENCH)
+    cell = bench.cell(CELL)
+    config = bench.config(cell["config"])
+    for name, value in config["env"].items():
+        monkeypatch.setenv(name, str(value))
+    precompute.reset()
+    resident.reset()
+    ctx = Context(cell, config, bench.traffic(cell["traffic"]), SEED, lambda text: None)
+    yield spec.generator(ctx.traffic["kind"]).build(ctx)
+    precompute.reset()
+    resident.reset()
+
+
+def test_the_generators_sets_are_the_plain_references(tiny):
+    assert tiny.n_sets == tiny.count == 12 and tiny.warm_windows == 11
+    assert len(tiny.opening) == 2 and {len(w) for w in tiny.windows} == {4}
+    members = []
+    for tasks in [tiny.opening] + tiny.windows:
+        vset = tasks[0].vals
+        assert all(t.vals is vset for t in tasks)  # one object a window, as the syncer's
+        for t in tasks:
+            assert [(v.pub_key.bytes(), v.voting_power) for v in vset.validators] == \
+                tiny.chain.validators_at(t.height)
+            assert len(tiny._for_block(t.commit)) >= tiny.quorum
+        members.append(frozenset(v.pub_key.bytes() for v in vset.validators))
+    # consecutive windows differ in one seat, and the cycle closes on itself
+    for before, after in zip(members, members[1:] + members[1:2]):
+        assert len(before - after) == len(after - before) == 1
+    assert members[0] == members[-1]
+    # a key that left is away for more than the eight live sets
+    gone = next(iter(members[1] - members[2]))
+    away = [gone in m for m in members[2:]]
+    assert away.index(True) == tiny.ring_size - tiny.seats > 8
+
+
+def test_old_key_in_the_new_seat_is_refused_at_that_seat(tiny):
+    """After a change the newcomer's seat carries a signature by the
+    key that just left: a sound signature, by a key the block's own set
+    does not seat there."""
+    from tendermint_tpu.parallel.pipeline import verify_commits_pipelined
+
+    tasks, want = tiny._faulted(tiny.warm_windows, "old_key_in_new_seat")
+    s = (tasks[0].height - tiny.first_change) // tiny.every + 1
+    leaves, joins = tiny._leaver(s), tiny._newcomer(s)
+    faults = [(b, w) for b, w in enumerate(want) if w != reference_light.OK]
+    assert len(faults) == 1
+    block, (kind, idx) = faults[0]
+    commit = tasks[block].commit
+    assert kind == "wrong signature"
+    assert tasks[block].vals.validators[idx].pub_key.bytes() == joins.pub
+    assert idx in tiny._for_block(commit)[: tiny.quorum]  # before the 2/3 exit
+    msg, sig = commit.vote_sign_bytes(workload.CHAIN_ID, idx), commit.signatures[idx].signature
+    assert reference.verify(leaves.pub, msg, sig) and not reference.verify(joins.pub, msg, sig)
+    got = [tiny._answer(v) for v in verify_commits_pipelined(tasks, use_device=False)]
+    assert got == want
+    assert [reference_light.verify_block(*tiny._plain(t)) for t in tasks] == want
+
+
+def test_the_generators_windows_are_the_syncers_on_the_same_chain(tiny, monkeypatch):
+    """A real chain with the tiny twin's genesis and schedule (kvstore
+    ``val:`` transactions two heights ahead, as ``state/execution``
+    delays them), caught up by a ``BlockSyncer``: every list of tasks it
+    hands to the verifier is the generator's window of that place — the
+    same heights under the same set."""
+    from tendermint_tpu.blocksync import BlockSyncer, syncer as syncer_mod
+    from tendermint_tpu.types import ExtendedCommit
+    from tests.test_blocksync import FakePeer
+    from tests.test_execution import advance_one_height, make_chain_env
+
+    windows = [tiny.opening] + tiny.windows[:5]
+    top = windows[-1][-1].height + 2
+    genesis = tiny.sets[0]
+    changes = {}
+    for height, updates in tiny.schedule:
+        changes[height - 2] = [
+            ("val:%s!%d" % (base64.b64encode(pub).decode(), power)).encode()
+            for pub, power in updates
+        ]
+    executor, state, _, _, _ = make_chain_env(validators=genesis)
+    ec = ExtendedCommit()
+    for h in range(1, top + 1):
+        vset = state.validators
+        privs = [tiny._by_pub[v.pub_key.bytes()] for v in vset.validators]
+        state, ec = advance_one_height(executor, state, privs, vset, changes.get(h, []), ec)
+
+    seen = []
+    real_verify = syncer_mod.verify_commits_pipelined
+
+    def recording(tasks, mesh=None, use_device=None):
+        seen.append([(t.height, t.vals.hash(), id(t.vals)) for t in tasks])
+        return real_verify(tasks, mesh=mesh, use_device=use_device)
+
+    monkeypatch.setattr(syncer_mod, "verify_commits_pipelined", recording)
+    follower_exec, follower_state, _, _, _ = make_chain_env(validators=genesis)
+    sync = BlockSyncer(
+        follower_state, follower_exec, follower_exec.block_store, transport=None,
+        verify_window=tiny.verify_window, use_device=False,
+    )
+    sync.transport = FakePeer(sync.pool, executor.block_store)
+    sync.pool.set_peer_range("p1", 1, executor.block_store.height())
+    for _ in range(60):
+        sync.step()
+    assert sync.state.last_block_height >= windows[-1][-1].height
+    for ours, theirs in zip(windows, seen):
+        assert [(t.height, t.vals.hash()) for t in ours] == [(h, vh) for h, vh, _ in theirs]
+        assert len({obj for _, _, obj in theirs}) == 1
+
+
+# --- the .rot metrics on hand-made spans ----------------------------------------------
+
+
+def test_rotation_metrics_add_up_on_nested_spans():
+    """One call: verify_commits_pipelined 0..1000 holding build_lanes
+    10..500 (phases 300 + 20; two note_validator_set spans of 40 inside
+    it, the first holding a valset_hash of 25), verify_batch 510..900
+    (a building gather_tables, route_lanes holding resident_upload, the
+    drop inside the first note), merge_verdicts 910..950."""
+
+    class Evidence:
+        calls = [{}]
+
+    def span(name, ts, dur, **args):
+        return {"name": name, "ts": float(ts), "dur": float(dur), "args": args}
+
+    ev = Evidence()
+    ev.spans = [
+        span("verify_commits_pipelined", 0, 1000, tasks=2, lanes=8),
+        span("build_lanes", 10, 490, lanes=8, sign_bytes_us=300.0, sign_bytes_n=8,
+             basic_checks_us=20.0, basic_checks_n=2),
+        span("note_validator_set", 20, 40, recognised=False, retired=1, tables_dropped=1),
+        span("valset_hash", 22, 25, validators=12),
+        span("resident_drop", 50, 5, keys=11, departed=1, reason="rotation"),
+        span("note_validator_set", 260, 40, recognised=True),
+        span("verify_batch", 510, 390),
+        span("gather_tables", 520, 60, builds=1, deferred=2),
+        span("route_lanes", 590, 50, resident=6, tables=0, legacy=2, jobs=2),
+        span("resident_upload", 600, 30, keys=11, width=64, reason="dropped"),
+        span("merge_verdicts", 910, 40),
+    ]
+
+    def read(name):
+        doc = spec.layer_metric(name)
+        return spec.reader(doc["reader"]).read(ev, **doc["args"])
+
+    assert read("pipeline_host_ms.rot") == pytest.approx(0.610)
+    assert read("sign_bytes_ms.rot") == pytest.approx(0.300)
+    assert read("note_set_ms.rot") == pytest.approx(0.080)
+    assert read("valset_hash_ms.rot") == pytest.approx(0.025)
+    named = 0.300 + 0.020 + 0.080 + 0.040  # sign-bytes, basic checks, note, merge
+    assert read("pipeline_unnamed_ms.rot") + named == pytest.approx(read("pipeline_host_ms.rot"))
+    assert read("table_build_ms.rot") == pytest.approx(0.060)
+    assert read("resident_upload_ms.rot") == pytest.approx(0.030)
+    assert read("resident_drop_ms.rot") == pytest.approx(0.005)
+    assert read("legacy_lanes.rot") == 2 and read("tables_dropped.rot") == 1
+    # a program without the new span and arguments (the parent): nothing
+    # to read, or zero, and no error
+    ev.spans = [s for s in ev.spans if s["name"] != "resident_drop"]
+    for s in ev.spans:
+        s["args"].pop("tables_dropped", None)
+    assert read("tables_dropped.rot") is None
+    assert read("resident_drop_ms.rot") == 0.0
+
+
+# --- the tiny twin, end to end ----------------------------------------------------------
+
+
+def rehearse(trace: int, *extra):
+    return rehearse_cell(BENCH, CELL, SEED, trace, *extra)
+
+
+def test_tiny_twin_of_sync500_rotation_rehearses_on_the_cpu():
+    out, said = rehearse(1)
+    assert out["correct"] is True and out["failed"] == 0 and out["attempted"] > 0
+    want = {m["name"] for m in spec.Spec(BENCH).metrics_for("per_layer", CELL)}
+    assert len(want) == 25
+    value = {name: out["metrics"][name]["value"] for name in want}
+    assert all(isinstance(v, float) for v in value.values())
+    # every call shows the mechanism: a table dropped with the retired
+    # set, the store dropped and sent again, a newcomer's table built,
+    # the youngest keys' lanes on the legacy kernel
+    assert value["tables_dropped.rot"] == 1.0
+    assert 0 < value["legacy_lanes.rot"] <= 8
+    assert 0 < value["resident_hit_share.rot"] < 100
+    for name in ("valset_hash_ms.rot", "table_build_ms.rot", "resident_upload_ms.rot", "resident_drop_ms.rot"):
+        assert value[name] > 0, name
+    assert value["valset_hash_ms.rot"] < value["note_set_ms.rot"]
+    for name in ("verdict_cache_hits_in_window", "compilations_in_window", "timed_blocks_refused",
+                 "sets_registered_in_window", "windows_with_a_wrong_block_verdict",
+                 "lanes_where_reference_disagrees"):
+        assert "compared: %s = 0 (limit 0)" % name in said, name
+
+
+@pytest.mark.parametrize(
+    "brk,over",
+    [
+        # one lane's verdict inverted where the engine returns it
+        ("flip_verdict", ["timed_blocks_refused", "windows_with_a_wrong_block_verdict",
+                          "lanes_where_reference_disagrees"]),
+        # the engine's s < L check off: the included s + L lane verifies
+        ("no_canonical_s", ["windows_with_a_wrong_block_verdict", "lanes_where_reference_disagrees"]),
+    ],
+)
+def test_tiny_twin_broken_on_purpose_comes_out_not_correct(brk, over):
+    """The controls (``breaks.py``) have to show in the cell's own
+    comparisons, not in the harness's two."""
+    out, said = rehearse(0, "--break", brk)
+    assert out["correct"] is False
+    assert over == [
+        ln.split("compared: ", 1)[1].split(" = ")[0]
+        for ln in said.splitlines() if ln.endswith("<-- over")
+    ]

@@ -7,14 +7,12 @@ proves paths, never numbers).
 
 from __future__ import annotations
 
-import json
 import os
-import subprocess
-import sys
 
 import pytest
 
 from chipbench import reference_light, selftest, spec, workload
+from tests.helpers import rehearse_cell
 
 ABSENT, COMMIT, NIL = 1, 2, 3
 
@@ -128,16 +126,7 @@ BENCH = os.path.join(spec.HERE, "testdata", "tiny-sync-benchmark.json")
 
 
 def rehearse(trace: int, *extra):
-    """(result line, standard output) of one rehearsal of the tiny twin."""
-    proc = subprocess.run(
-        [sys.executable, "-m", "chipbench.run", "--workload", "tiny-sync-catchup",
-         "--seed", str(2**31 + 26), "--seconds", "1", "--trace", str(trace),
-         "--rehearse", "--bench-file", BENCH, *extra],
-        cwd=spec.ROOT, capture_output=True, text=True, timeout=300,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"),
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stdout
+    return rehearse_cell(BENCH, "tiny-sync-catchup", 2**31 + 26, trace, *extra)
 
 
 def test_tiny_twin_of_sync500_catchup_rehearses_on_the_cpu():

@@ -21,8 +21,11 @@ from tests.helpers import CHAIN_ID, make_commit, make_validators
 BASE_NS = 1_700_000_000_000_000_000
 
 
-def make_chain_env(n_vals=4):
-    privs, vset = make_validators(n_vals)
+def make_chain_env(n_vals=4, validators=None):
+    """``validators``: a ``ValidatorSet`` to start the chain from (the
+    caller then holds its keys: ``privs`` is None), else ``n_vals``
+    seeded ones."""
+    privs, vset = (None, validators) if validators is not None else make_validators(n_vals)
     gen = GenesisDoc(
         chain_id=CHAIN_ID,
         genesis_time=Timestamp.from_unix_ns(BASE_NS),

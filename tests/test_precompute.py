@@ -837,3 +837,141 @@ def test_table_path_agrees_with_oracle(monkeypatch):
     # all lanes rode the table path (eligible in "all" mode)
     s = precompute.tables.stats()
     assert s["builds"] == len(set(pks))
+
+
+# --- a committee that replaces one validator a step (PR 32) -------------------
+
+
+def _ring_key(i):
+    return Ed25519PrivKey.from_seed((7_000_000 + i).to_bytes(32, "big")).pub_key()
+
+
+def _one_seat_changed(vset, leaves, joins):
+    """``vset`` after one validator left and one joined at its power, as
+    ``state/execution`` produces the next set."""
+    out = vset.copy()
+    gone = next(v for v in out.validators if v.pub_key.bytes() == leaves).copy()
+    gone.voting_power = 0
+    out.update_with_change_set([gone, Validator(joins, 10)])
+    return out
+
+
+def _last_note(tracer):
+    notes = [
+        e["args"] for e in tracer.export(clear=True)["traceEvents"]
+        if e.get("ph") == "X" and e["name"] == "note_validator_set"
+    ]
+    assert len(notes) == 1
+    return notes[0]
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_random_one_seat_changes_keep_tables_and_sightings_for_live_keys_only(seed, ring_tracer):
+    """Thirty sets, each the last with one validator replaced by a new
+    key or by one that left earlier. After every batch: a key has a host
+    table exactly if it was built at first sight (the first set's keys,
+    while that set is live) or carried by three batches; a key outside
+    every live set has no table and no count; and the
+    ``note_validator_set`` span says what the counters say."""
+    from tendermint_tpu.crypto import batch as crypto_batch
+
+    rng = random.Random(seed)
+    pool = [_ring_key(100 * seed + i) for i in range(14)]
+    vset = ValidatorSet([Validator(k, 10) for k in pool[:8]])
+    first = set(_keys(vset))
+    live = []  # key sets of the live sets, least lately met first
+    carried, tabled, eligible = {}, set(), set()
+    for step in range(30):
+        if step:
+            seated = _keys(vset)
+            away = [k for k in pool if k.bytes() not in seated]
+            vset = _one_seat_changed(vset, rng.choice(seated), rng.choice(away))
+        before = precompute.tables.stats()
+        members = set(_keys(vset))
+        if members in live:  # a change that undid an earlier one: the set is still live
+            assert crypto_batch.note_validator_set_traced(vset) == (False, True)
+            live.remove(members)
+            live.append(members)
+            assert "retired" not in _last_note(ring_tracer)
+            assert precompute.tables.stats()["sets_retired"] == before["sets_retired"]
+        else:
+            assert crypto_batch.note_validator_set_traced(vset) == (True, False)
+            retired = len(live) == precompute._ACTIVE_SETS_CAP
+            live = (live + [members])[-precompute._ACTIVE_SETS_CAP:]
+            eligible = set().union(*live)
+            dropped = {pk for pk in tabled if pk not in eligible}
+            tabled -= dropped
+            carried = {pk: n for pk, n in carried.items() if pk in eligible}
+            note, after = _last_note(ring_tracer), precompute.tables.stats()
+            assert note["recognised"] is False and note["newly_active"] is True
+            assert note["retired"] == after["sets_retired"] - before["sets_retired"] == retired
+            assert note["tables_dropped"] == after["invalidations"] - before["invalidations"] == len(dropped)
+        founders = first if first in live else set()
+        has = precompute.tables.gather(_keys(vset))[1]
+        for pk in _keys(vset):
+            if pk in tabled:
+                continue
+            carried[pk] = carried.get(pk, 0) + 1
+            if pk in founders or carried[pk] >= precompute.BUILD_AT_SIGHTING:
+                tabled.add(pk)
+                del carried[pk]
+        assert [bool(h) for h in has] == [pk in tabled for pk in _keys(vset)]
+        assert set(precompute.tables._entries) == tabled
+        assert precompute.tables._sightings == carried
+        for k in pool:
+            if k.bytes() not in eligible:
+                assert precompute.tables.lookup(k.bytes()) is None
+                assert k.bytes() not in precompute.tables._sightings
+    # a recognised set says nothing of retirement: nothing was pushed out
+    assert crypto_batch.note_validator_set_traced(vset) == (False, True)
+    assert "retired" not in _last_note(ring_tracer)
+
+
+@pytest.mark.parametrize(
+    "away, known",
+    [
+        pytest.param(7, True, id="back_inside_the_eight_live_sets"),
+        pytest.param(8, False, id="back_as_the_ninth_set"),
+        pytest.param(9, False, id="back_after_nine_sets"),
+    ],
+)
+def test_a_key_that_returns_after_the_live_sets_forgot_it_is_built_at_its_third_batch_again(away, known):
+    """A key seated for three sets (table-less twice, built in its third
+    batch), then away for ``away`` sets that each replace another
+    validator. Back inside the live window it still has its table; once
+    the last set that held it was retired it has neither a table nor a
+    count, and waits for its third batch again."""
+    fill = [_ring_key(i) for i in range(40)]
+    vset = ValidatorSet([Validator(k, 10) for k in fill[:6]])
+    precompute.activate_validator_set(vset)
+    precompute.tables.gather(_keys(vset))
+    ours, nxt = fill[39], 6
+
+    def step(leaves, joins):
+        nonlocal vset
+        vset = _one_seat_changed(vset, leaves, joins)
+        assert precompute.activate_validator_set(vset) == (True, False)
+        return list(precompute.tables.gather(_keys(vset))[1])
+
+    seat = lambda: _keys(vset).index(ours.bytes())
+    assert step(fill[0].bytes(), ours)[seat()] is np.False_
+    for _ in range(2):  # two more sets carry it: the third batch builds
+        had = step(fill[nxt - 5].bytes(), fill[nxt])
+        nxt += 1
+    assert had[seat()] and precompute.tables.stats()["builds"] == 6 + 1
+    step(ours.bytes(), fill[nxt])  # it leaves: the first of the sets without it
+    nxt += 1
+    for _ in range(away - 1):
+        step(fill[nxt - 5].bytes(), fill[nxt])
+        nxt += 1
+    assert (precompute.tables.lookup(ours.bytes()) is not None) is known
+    builds = precompute.tables.stats()["builds"]
+    back = step(fill[nxt - 5].bytes(), ours)
+    assert bool(back[seat()]) is known
+    if not known:
+        assert precompute.tables._sightings[ours.bytes()] == 1
+        assert not step(fill[nxt - 4].bytes(), fill[nxt])[seat()]
+        assert step(fill[nxt - 3].bytes(), fill[nxt + 1])[seat()]
+        # ours, and the two other newcomers whose third batch fell here
+        assert precompute.tables.lookup(ours.bytes()) is not None
+        assert precompute.tables.stats()["builds"] > builds

@@ -17,7 +17,7 @@ from tendermint_tpu.crypto import ed25519_ref as ref
 from tendermint_tpu.crypto.keys import Ed25519PrivKey
 from tendermint_tpu.libs.metrics import OpsMetrics, Registry
 from tendermint_tpu.ops import ed25519_batch, precompute, resident
-from tests.helpers import make_validators
+from tests.helpers import CHAIN_ID, make_validators
 
 
 @pytest.fixture(autouse=True)
@@ -284,3 +284,172 @@ def test_tenant_pin_quota_caps_one_namespace():
     # the denied key was NOT pinned: only a's first two made the store
     _, has_table = precompute.tables.gather(a_pks)
     assert has_table[:2].all() and not has_table[2]
+
+
+# --- a committee that replaces one validator a step (PR 32) -------------------
+
+
+def _spans(tracer, *names):
+    return [
+        (e["name"], e["args"]) for e in tracer.export(clear=True)["traceEvents"]
+        if e.get("ph") == "X" and e["name"] in names
+    ]
+
+
+def _acquire(pks, backend=None):
+    """What ``verify_batch`` does before it builds its jobs."""
+    _, has_table = precompute.tables.gather(pks)
+    return resident.acquire(pks, has_table, backend=backend)
+
+
+def _evict(monkeypatch):
+    monkeypatch.setenv("TENDERMINT_TPU_PRECOMPUTE_CAP", "4")
+    more, _, _ = _batch(1, seed=90)
+    precompute.pin_pubkeys(more)
+    precompute.tables.gather(more)  # the fifth table pushes the oldest out
+
+
+def _rotate(monkeypatch):
+    for off in range(2, 2 + precompute._ACTIVE_SETS_CAP):
+        precompute.activate_validator_set(_vset(off)[1])
+
+
+@pytest.mark.parametrize(
+    "drop, reason",
+    [
+        pytest.param(_rotate, "rotation", id="rotation"),
+        pytest.param(_evict, "evict", id="evict"),
+        pytest.param(lambda monkeypatch: precompute.tables.clear(), "clear", id="clear"),
+    ],
+)
+def test_drop_and_upload_spans_say_why_and_what_the_counters_say(drop, reason, ring_tracer, monkeypatch):
+    """The device copy's life in spans: ``resident_upload`` carries why
+    it was sent (``first``, ``joined``, ``context``, ``dropped``) and
+    how wide, ``resident_drop`` why it was forgotten and how many
+    columns went; each is counted by ``stats()`` once."""
+    _, vset = _vset(1, n=4)
+    pks = [v.pub_key.bytes() for v in vset.validators]
+    precompute.activate_validator_set(vset)
+    assert _acquire(pks[:3]) is not None
+    assert _acquire(pks) is not None  # the fourth key joins
+    assert _acquire(pks) is not None  # nothing to send
+    assert _acquire(pks, backend="cpu") is not None  # asked for by name: another context
+    ups = _spans(ring_tracer, "resident_upload", "resident_drop")
+    assert [(n, a["reason"], a["keys"], a["width"]) for n, a in ups] == [
+        ("resident_upload", "first", 3, 64),
+        ("resident_upload", "joined", 4, 64),
+        ("resident_upload", "context", 4, 64),
+    ]
+    assert resident.stats()["uploads"] == 3 and resident.stats()["invalidations"] == 0
+    assert resident.store._tab_dev.shape[-1] == 64
+
+    drop(monkeypatch)
+    drops = _spans(ring_tracer, "resident_upload", "resident_drop")
+    assert [(n, a["reason"], a["keys"]) for n, a in drops] == [("resident_drop", reason, 4)]
+    if reason != "clear":
+        assert 1 <= drops[0][1]["departed"] <= 4
+    s = resident.stats()
+    assert s["invalidations"] == 1 and s["resident_keys"] == 0
+    # a second event finds no copy: no span, no count
+    precompute.tables.clear()
+    assert _spans(ring_tracer, "resident_drop") == [] and resident.stats()["invalidations"] == 1
+
+    precompute.pin_pubkeys(pks[:2])
+    assert _acquire(pks[:2]) is not None
+    again = _spans(ring_tracer, "resident_upload")
+    assert [(a["reason"], a["keys"]) for _, a in again] == [("dropped", 2)]
+    assert resident.stats()["uploads"] == 4
+    resident.reset()
+    precompute.pin_pubkeys(pks[:2])
+    assert _acquire(pks[:2]) is not None
+    assert [a["reason"] for _, a in _spans(ring_tracer, "resident_upload")] == ["first"]
+
+
+def _plain_block(vset_plain, commit):
+    return vset_plain, [
+        (cs.block_id_flag, commit.vote_sign_bytes(CHAIN_ID, i) if cs.signature else b"", cs.signature)
+        for i, cs in enumerate(commit.signatures)
+    ]
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_random_one_seat_changes_agree_with_the_plain_reference_block_by_block(seed):
+    """Twelve validators, one replaced a step by a new key or one that
+    left earlier; a window of two commits a step through
+    ``verify_commits_pipelined`` on the device path (resident, gathered
+    and legacy lanes in one call). Every block's verdict is the plain
+    light reference's over the plain rotation reference's own set, a
+    signature by the key that just left in the newcomer's seat included;
+    a key outside every live set holds no column; the store is never
+    wider than the next power of two over the keys still remembered."""
+    import random
+
+    from chipbench import reference_light, reference_rotation
+    from tendermint_tpu.parallel.pipeline import CommitTask, verify_commits_pipelined
+    from tendermint_tpu.types.validator import Validator
+    from tendermint_tpu.types.validator_set import ValidatorSet
+    from tests.helpers import make_block_id, make_commit
+
+    rng = random.Random(seed)
+    privs = {}
+    for i in range(20):
+        p = Ed25519PrivKey.from_seed((9_000_000 + 100 * seed + i).to_bytes(32, "big"))
+        privs[p.pub_key().bytes()] = p
+    pool = list(privs)
+    vset = ValidatorSet([Validator(privs[pk].pub_key(), 10) for pk in pool[:12]])
+    plain = [(v.pub_key.bytes(), 10) for v in vset.validators]
+    live, height = [], 1
+    for step in range(14):
+        leaves = joins = None
+        if step:
+            seated = [v.pub_key.bytes() for v in vset.validators]
+            leaves = rng.choice(seated)
+            joins = rng.choice([pk for pk in pool if pk not in seated])
+            nxt = vset.copy()
+            gone = next(v for v in nxt.validators if v.pub_key.bytes() == leaves).copy()
+            gone.voting_power = 0
+            nxt.update_with_change_set([gone, Validator(privs[joins].pub_key(), 10)])
+            vset = nxt
+            plain = reference_rotation.apply_updates(plain, [(leaves, 0), (joins, 10)])
+        assert [(v.pub_key.bytes(), v.voting_power) for v in vset.validators] == plain
+        order = [privs[pk] for pk, _ in plain]
+        tasks = []
+        for b in range(2):
+            bid = make_block_id(b"rot %d %d" % (seed, height))
+            commit = make_commit(
+                bid, height, 0, vset, order,
+                absent={rng.randrange(12)}, nil_votes={rng.randrange(12)},
+            )
+            if joins and b == 1 and step % 3 == 0:
+                # the newcomer's seat, signed by the key that just left
+                idx = [pk for pk, _ in plain].index(joins)
+                if commit.signatures[idx].signature:
+                    commit.signatures[idx].signature = privs[leaves].sign(
+                        commit.vote_sign_bytes(CHAIN_ID, idx)
+                    )
+            tasks.append(CommitTask(CHAIN_ID, vset, bid, height, commit))
+            height += 1
+        got = []
+        for v in verify_commits_pipelined(tasks):
+            if v.ok:
+                got.append(reference_light.OK)
+            elif "wrong signature" in str(v.error):
+                got.append(("wrong signature", int(str(v.error).split("(#")[1].split(")")[0])))
+            else:
+                got.append(reference_light.INSUFFICIENT)
+        assert got == [reference_light.verify_block(*_plain_block(plain, t.commit)) for t in tasks]
+        members = {pk for pk, _ in plain}
+        if members in live:
+            live.remove(members)
+        live = (live + [members])[-precompute._ACTIVE_SETS_CAP:]
+        remembered = set().union(*live)
+        index = resident.store._index
+        assert set(index) <= remembered
+        for pk in pool:
+            if pk not in remembered:
+                assert precompute.tables.lookup(pk) is None and pk not in index
+        width = resident.store._tab_dev.shape[-1]
+        assert width == max(64, 1 << len(index).bit_length()) <= max(64, 1 << len(remembered).bit_length())
+    s = resident.stats()
+    # every drop was followed by an upload, and no lane with a table missed the store
+    assert s["uploads"] > s["invalidations"] and s["misses"] == 0 and s["hits"] > 0
