@@ -2,12 +2,14 @@
 
 ``sha512_batch`` hashes N variable-length messages through a small C
 extension (``native/sha512_batch.c``, OpenMP-parallel from 1,024
-messages up, built lazily
-with the system compiler and loaded via ctypes) with a pure-hashlib
-fallback. ``sha512_batch_mod_l`` additionally reduces each 512-bit
-digest mod the ed25519 group order L with a vectorized numpy Barrett
-reduction — no per-signature Python arithmetic anywhere on the hot
-path.
+messages up, built lazily with the system's C compiler and loaded via
+ctypes) with a pure-hashlib fallback where there is no compiler.
+``sha512_batch_prefixed_mod_l`` is the verifier's challenge
+k = SHA-512(R || A || M) mod L: the same extension reduces each digest
+mod the ed25519 group order L in the loop that made it, so no
+per-signature Python or NumPy arithmetic sits on the hot path. The
+definition every path is held to is ``int.from_bytes(digest, "little")
+% L`` (:func:`reduce_mod_l_int`).
 
 Reference analog: the challenge hashing inside curve25519-voi's batch
 verifier (crypto/ed25519/ed25519.go:198-233).
@@ -18,9 +20,10 @@ from __future__ import annotations
 import ctypes
 import hashlib
 import os
+import shutil
 import subprocess
 import tempfile
-from typing import List, Optional, Sequence
+from typing import Optional, Sequence
 
 import numpy as np
 
@@ -29,222 +32,185 @@ L = 2**252 + 27742317777372353535851937790883648493
 _LIB: Optional[ctypes.CDLL] = None
 _LIB_TRIED = False
 
+_U8P = ctypes.POINTER(ctypes.c_uint8)
+_U64P = ctypes.POINTER(ctypes.c_uint64)
+
+# Every entry point this module calls, with its C signature. A library
+# is loaded only with all of them resolved, so one built from another
+# version of the source, or by a compiler that mangles names, never
+# answers for this one.
+_SYMBOLS = {
+    "sha512_batch": [_U8P, _U64P, ctypes.c_int64, _U8P],
+    "sha512_batch_prefixed_mod_l": [_U8P, _U8P, _U64P, ctypes.c_int64, _U8P],
+    "reduce512_mod_l": [_U8P, ctypes.c_int64, _U8P],
+}
+
+
+def _load(path: str) -> Optional[ctypes.CDLL]:
+    """The library at ``path`` with every symbol of ``_SYMBOLS``
+    resolved and typed, or None where it cannot be loaded or lacks one."""
+    try:
+        lib = ctypes.CDLL(path)
+        for name, argtypes in _SYMBOLS.items():
+            fn = getattr(lib, name)
+            fn.argtypes = argtypes
+            fn.restype = None
+    except (OSError, AttributeError):
+        return None
+    return lib
+
 
 def _build_and_load() -> Optional[ctypes.CDLL]:
-    """Compile the C extension once per machine and load it."""
+    """Compile the C extension once per machine and source, and load it.
+
+    The library is named by a hash of its source, so checkouts that
+    differ in ``sha512_batch.c`` (a parent commit and its change run in
+    turn on one machine) never load each other's build. It is compiled
+    under a name of this process's own, loaded and checked from there,
+    and only then renamed into place: processes that start together
+    each end with a whole library. None means there is nothing to build
+    with (no source, or neither ``cc`` nor ``gcc``: the hashlib path).
+    A build that should have worked and did not raises.
+    """
     src = os.path.join(os.path.dirname(os.path.dirname(__file__)), "native", "sha512_batch.c")
-    if not os.path.exists(src):
+    cc = shutil.which("cc") or shutil.which("gcc")  # never g++: it mangles the names
+    if not os.path.exists(src) or cc is None:
         return None
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
     build_dir = os.environ.get(
         "TENDERMINT_TPU_BUILD_DIR",
         os.path.join(tempfile.gettempdir(), "tendermint_tpu_native"),
     )
     os.makedirs(build_dir, exist_ok=True)
-    lib_path = os.path.join(build_dir, "libsha512batch.so")
-    if not os.path.exists(lib_path) or os.path.getmtime(lib_path) < os.path.getmtime(src):
-        for cc in ("cc", "gcc", "g++"):
-            try:
-                subprocess.run(
-                    [cc, "-O3", "-shared", "-fPIC", "-fopenmp", src, "-o", lib_path + ".tmp"],
-                    check=True,
-                    capture_output=True,
-                    timeout=120,
-                )
-                os.replace(lib_path + ".tmp", lib_path)
-                break
-            except Exception:
-                continue
-        else:
-            return None
-    try:
-        lib = ctypes.CDLL(lib_path)
-        lib.sha512_batch.argtypes = [
-            ctypes.POINTER(ctypes.c_uint8),
-            ctypes.POINTER(ctypes.c_uint64),
-            ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_uint8),
-        ]
-        lib.sha512_batch.restype = None
-        lib.sha512_batch_prefixed.argtypes = [
-            ctypes.POINTER(ctypes.c_uint8),
-            ctypes.POINTER(ctypes.c_uint8),
-            ctypes.POINTER(ctypes.c_uint64),
-            ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_uint8),
-        ]
-        lib.sha512_batch_prefixed.restype = None
+    lib_path = os.path.join(build_dir, f"libsha512batch-{digest}.so")
+    lib = _load(lib_path)
+    if lib is not None:
         return lib
-    except Exception:
-        return None
+    # Absent, or a file of that name that is not this source's library
+    # (cut short, built by other hands): build it, once.
+    fd, tmp = tempfile.mkstemp(prefix=f"libsha512batch-{digest}.", suffix=".so", dir=build_dir)
+    os.close(fd)
+    try:
+        proc = subprocess.run(
+            [cc, "-O3", "-shared", "-fPIC", "-fopenmp", src, "-o", tmp],
+            capture_output=True,
+            timeout=120,
+        )
+        if proc.returncode != 0:
+            raise RuntimeError(
+                f"{cc} could not build {src}: {proc.stderr.decode(errors='replace')[-2000:]}"
+            )
+        lib = _load(tmp)
+        if lib is None:
+            raise RuntimeError(f"{cc} built {src} into a library that lacks one of {sorted(_SYMBOLS)}")
+        os.chmod(tmp, 0o755)  # mkstemp made it the owner's alone
+        os.replace(tmp, lib_path)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    return lib
 
 
 def _lib() -> Optional[ctypes.CDLL]:
     global _LIB, _LIB_TRIED
     if not _LIB_TRIED:
+        _LIB = _build_and_load()  # raises again on the next call if it raised
         _LIB_TRIED = True
-        _LIB = _build_and_load()
     return _LIB
 
 
 def host_hash_impl() -> str:
     """Which host hashing path is live: ``"native"`` (the C extension
-    built and loaded) or ``"hashlib"`` (no source, no compiler, or a
-    failed build). The choice is otherwise silent; chip_smoke.py prints
-    it and treats ``hashlib`` as a failed build."""
+    built and loaded) or ``"hashlib"`` (no source or no C compiler).
+    The choice is otherwise silent; chip_smoke.py prints it and treats
+    ``hashlib`` as a failed build, and the engine's ``prep_chunk`` span
+    carries it as ``hash``."""
     return "native" if _lib() is not None else "hashlib"
+
+
+def reduce_mod_l_int(digest: bytes) -> bytes:
+    """A little-endian value mod L as 32 little-endian bytes, in Python
+    integers: the definition the C reduction (``reduce512_mod_l``) and
+    the device's (``ops/hash512``) are tested against, and what the
+    hashlib path computes."""
+    return (int.from_bytes(digest, "little") % L).to_bytes(32, "little")
+
+
+def _pack(msgs: Sequence[bytes]):
+    """``(buf, offsets)`` as the C entries take N messages: one
+    concatenated uint8 buffer and N + 1 uint64 offsets into it."""
+    offsets = np.zeros(len(msgs) + 1, dtype=np.uint64)
+    np.cumsum([len(m) for m in msgs], out=offsets[1:])
+    buf = np.frombuffer(b"".join(msgs), dtype=np.uint8)
+    if buf.size == 0:
+        buf = np.zeros(1, dtype=np.uint8)
+    return buf, offsets
+
+
+def _ptr(arr: np.ndarray, typ=_U8P):
+    return arr.ctypes.data_as(typ)
 
 
 def sha512_batch(msgs: Sequence[bytes]) -> np.ndarray:
     """N messages -> (N, 64) uint8 digests."""
     n = len(msgs)
+    out = np.empty((n, 64), dtype=np.uint8)
     if n == 0:
-        return np.zeros((0, 64), dtype=np.uint8)
+        return out
     lib = _lib()
     if lib is None:
-        out = np.empty((n, 64), dtype=np.uint8)
         for i, m in enumerate(msgs):
             out[i] = np.frombuffer(hashlib.sha512(m).digest(), dtype=np.uint8)
         return out
-    offsets = np.zeros(n + 1, dtype=np.uint64)
-    np.cumsum([len(m) for m in msgs], out=offsets[1:])
-    buf = np.frombuffer(b"".join(msgs), dtype=np.uint8)
-    if buf.size == 0:
-        buf = np.zeros(1, dtype=np.uint8)
-    out = np.empty((n, 64), dtype=np.uint8)
-    lib.sha512_batch(
-        buf.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-        offsets.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
-        n,
-        out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-    )
+    buf, offsets = _pack(msgs)
+    lib.sha512_batch(_ptr(buf), _ptr(offsets, _U64P), n, _ptr(out))
     return out
 
 
-def sha512_batch_prefixed(prefix: np.ndarray, msgs: Sequence[bytes]) -> np.ndarray:
-    """Hash prefix_i || msg_i for a (N, 64) uint8 prefix block -> (N, 64).
+def sha512_batch_prefixed_mod_l(prefix: np.ndarray, msgs: Sequence[bytes]) -> np.ndarray:
+    """The challenge scalars SHA-512(prefix_i || msg_i) mod L for a
+    (N, 64) uint8 prefix block -> (N, 32) uint8, little-endian.
 
-    The verifier's challenge is SHA-512(R || A || M); R and A already
-    live in (N, 32) arrays, so the 64-byte prefix block costs one
-    concatenate instead of N Python byte-string builds.
+    The verifier's challenge is SHA-512(R || A || M) mod L; R and A
+    already live in (N, 32) arrays, so the 64-byte prefix block costs
+    one concatenate instead of N Python byte-string builds, and the C
+    loop that hashes a lane reduces its digest too.
     """
     n = len(msgs)
-    assert prefix.shape == (n, 64) and prefix.dtype == np.uint8
+    if prefix.shape != (n, 64) or prefix.dtype != np.uint8:
+        raise ValueError(f"prefix must be ({n}, 64) uint8, got {prefix.shape} {prefix.dtype}")
+    out = np.empty((n, 32), dtype=np.uint8)
     if n == 0:
-        return np.zeros((0, 64), dtype=np.uint8)
+        return out
+    pb = np.ascontiguousarray(prefix)
     lib = _lib()
     if lib is None:
-        out = np.empty((n, 64), dtype=np.uint8)
-        pb = np.ascontiguousarray(prefix)
         for i, m in enumerate(msgs):
             h = hashlib.sha512(pb[i].tobytes())
             h.update(m)
-            out[i] = np.frombuffer(h.digest(), dtype=np.uint8)
+            out[i] = np.frombuffer(reduce_mod_l_int(h.digest()), dtype=np.uint8)
         return out
-    offsets = np.zeros(n + 1, dtype=np.uint64)
-    np.cumsum([len(m) for m in msgs], out=offsets[1:])
-    buf = np.frombuffer(b"".join(msgs), dtype=np.uint8)
-    if buf.size == 0:
-        buf = np.zeros(1, dtype=np.uint8)
-    out = np.empty((n, 64), dtype=np.uint8)
-    pb = np.ascontiguousarray(prefix)
-    lib.sha512_batch_prefixed(
-        pb.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-        buf.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-        offsets.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
-        n,
-        out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-    )
+    buf, offsets = _pack(msgs)
+    lib.sha512_batch_prefixed_mod_l(_ptr(pb), _ptr(buf), _ptr(offsets, _U64P), n, _ptr(out))
     return out
 
 
-# --- vectorized Barrett reduction mod L -------------------------------------
-#
-# Values are little-endian 16-bit limb vectors; all products accumulate
-# in int64 (max column ~ 40 * 2^32 < 2^38, exact). Barrett with
-# mu = floor(2^512 / L): q = floor(floor(x / 2^248) * mu / 2^264),
-# r = x - q*L, then at most two conditional subtracts of L.
-
-_NL16 = 16  # limbs of a 256-bit value
-_L_LIMBS = np.array([(L >> (16 * i)) & 0xFFFF for i in range(16)], dtype=np.int64)
-_MU = (1 << 512) // L
-_MU_LIMBS = np.array([(_MU >> (16 * i)) & 0xFFFF for i in range((_MU.bit_length() + 15) // 16)], dtype=np.int64)
-
-
-def _carry16(cols: np.ndarray, nlimbs: int) -> np.ndarray:
-    """Carry-propagate int64 columns into nlimbs 16-bit limbs (drop overflow)."""
-    out = np.zeros((cols.shape[0], nlimbs), dtype=np.int64)
-    c = np.zeros(cols.shape[0], dtype=np.int64)
-    for i in range(nlimbs):
-        v = c + (cols[:, i] if i < cols.shape[1] else 0)
-        out[:, i] = v & 0xFFFF
-        c = v >> 16
-    return out
-
-
-def _mul_const(x: np.ndarray, const_limbs: np.ndarray) -> np.ndarray:
-    """(N, a) 16-bit limbs times constant (b,) limbs -> (N, a+b) columns."""
-    n, a = x.shape
-    b = const_limbs.shape[0]
-    cols = np.zeros((n, a + b), dtype=np.int64)
-    for j in range(b):
-        cols[:, j : j + a] += x * const_limbs[j]
-    return cols
-
-
-def _ge(x: np.ndarray, y_limbs: np.ndarray) -> np.ndarray:
-    """(N, 16) >= const (16,) comparison, little-endian limbs."""
-    diff = x - y_limbs[None, :]
-    nz = diff != 0
-    rev = nz[:, ::-1]
-    first = np.argmax(rev, axis=1)
-    rows = np.arange(x.shape[0])
-    val = diff[:, ::-1][rows, first]
-    any_nz = nz.any(axis=1)
-    return np.where(any_nz, val > 0, True)
-
-
-def reduce_mod_l(digests: np.ndarray) -> np.ndarray:
+def reduce512_mod_l(digests: np.ndarray) -> np.ndarray:
     """(N, 64) uint8 little-endian 512-bit values -> (N, 32) uint8 mod L."""
     n = digests.shape[0]
-    x16 = (
-        digests.reshape(n, 32, 2).astype(np.int64)[:, :, 0]
-        + (digests.reshape(n, 32, 2).astype(np.int64)[:, :, 1] << 8)
-    )  # (N, 32) 16-bit limbs, little-endian
-    # q1 = floor(x / 2^248) -> drop 15.5 limbs; use 2^240 (15 limbs) for a
-    # slightly larger q1*mu, then shift 2^272 total. Keep it simple and
-    # exact: q = floor( floor(x/2^240) * mu / 2^272 ).
-    q1 = x16[:, 15:]  # (N, 17) limbs: x >> 240
-    q2 = _mul_const(q1, _MU_LIMBS)  # x/2^240 * mu, columns
-    q2 = _carry16(q2, q2.shape[1])
-    q = q2[:, 17:]  # >> 272
-    # r = x - q*L (mod 2^256 is safe: r < 2L < 2^253)
-    ql = _carry16(_mul_const(q, _L_LIMBS), 16)
-    r = np.zeros((n, 16), dtype=np.int64)
-    borrow = np.zeros(n, dtype=np.int64)
-    for i in range(16):
-        v = x16[:, i] - ql[:, i] - borrow
-        borrow = (v < 0).astype(np.int64)
-        r[:, i] = v + (borrow << 16)
-    # Barrett error bound for this shift choice: r < 4L -> up to 3 subtracts.
-    for _ in range(3):
-        ge = _ge(r, _L_LIMBS)
-        borrow = np.zeros(n, dtype=np.int64)
-        sub = np.zeros_like(r)
-        for i in range(16):
-            v = r[:, i] - _L_LIMBS[i] - borrow
-            borrow = (v < 0).astype(np.int64)
-            sub[:, i] = v + (borrow << 16)
-        r = np.where(ge[:, None], sub, r)
-    out = np.zeros((n, 32), dtype=np.uint8)
-    out[:, 0::2] = (r & 0xFF).astype(np.uint8)
-    out[:, 1::2] = ((r >> 8) & 0xFF).astype(np.uint8)
+    if digests.shape != (n, 64) or digests.dtype != np.uint8:
+        raise ValueError(f"digests must be (N, 64) uint8, got {digests.shape} {digests.dtype}")
+    out = np.empty((n, 32), dtype=np.uint8)
+    lib = _lib()
+    if lib is None:
+        for i in range(n):
+            out[i] = np.frombuffer(reduce_mod_l_int(digests[i].tobytes()), dtype=np.uint8)
+    else:
+        lib.reduce512_mod_l(_ptr(np.ascontiguousarray(digests)), n, _ptr(out))
     return out
 
 
-def sha512_batch_mod_l(msgs: Sequence[bytes]) -> List[bytes]:
-    """N messages -> N 32-byte little-endian scalars SHA-512(m) mod L."""
-    if not msgs:
-        return []
-    digests = sha512_batch(msgs)
-    reduced = reduce_mod_l(digests)
-    return [reduced[i].tobytes() for i in range(reduced.shape[0])]
+def sha512_batch_mod_l(msgs: Sequence[bytes]) -> np.ndarray:
+    """N messages -> (N, 32) uint8 little-endian scalars SHA-512(m) mod L."""
+    return reduce512_mod_l(sha512_batch(msgs))

@@ -152,6 +152,8 @@ class _RemoteAnchor:
 
     __slots__ = ("name", "trace_id", "span_id")
 
+    live = False  # an anchor, not a span: it takes no tags
+
     def __init__(self, ctx: TraceContext):
         self.name = "remote"
         self.trace_id = ctx.trace_id
@@ -496,6 +498,13 @@ class Tracer:
             return None
         return TraceContext(top.trace_id, top.span_id, 1)
 
+    def tag(self, **tags: Any) -> None:
+        """Tags on this thread's innermost open span, from code that
+        runs under it without holding it (no-op when none is open)."""
+        stack = self._stack()
+        if stack and stack[-1].live:
+            stack[-1].set(**tags)
+
     def _annotate(self, name: str) -> Any:
         """An entered ``jax.profiler.TraceAnnotation`` for a span that
         is being recorded (tens of ns while no profiler session is
@@ -813,6 +822,10 @@ def span(
 
 def instant(name: str, **args: Any) -> None:
     tracer.instant(name, **args)
+
+
+def tag(**tags: Any) -> None:
+    tracer.tag(**tags)
 
 
 def attach(ctx: Optional[TraceContext]):

@@ -5,7 +5,10 @@
  * implements FIPS 180-4 SHA-512 from the spec and exposes one batch
  * entry point that hashes N variable-length messages (concatenated
  * buffer + offsets) into N 64-byte digests, parallelized with OpenMP
- * from PARALLEL_MIN_BATCH messages up.
+ * from PARALLEL_MIN_BATCH messages up. The prefixed entry the verifier
+ * uses (sha512_batch_prefixed_mod_l) hands back each digest already
+ * reduced mod the group order L: the challenge scalar leaves this file
+ * as the 32 bytes the kernels take.
  *
  * Replaces the reference's reliance on Go's crypto/sha512 inside
  * curve25519-voi's batch verifier (crypto/ed25519/ed25519.go:198-233).
@@ -130,7 +133,7 @@ void sha512_batch(const uint8_t *buf, const uint64_t *offsets, int64_t n,
   }
 }
 
-/* Streaming variant used by the prefixed batch below. */
+/* Streaming variant used by the prefixed batch at the end. */
 typedef struct {
   uint64_t h[8];
   uint8_t buf[128];
@@ -186,19 +189,90 @@ static void sha512_final(sha512_ctx *c, uint8_t out[64]) {
       out[j * 8 + b] = (uint8_t)(c->h[j] >> (56 - 8 * b));
 }
 
-/* Hash n messages of the form prefix_i || msg_i where every prefix is a
- * fixed 64 bytes (the verifier's R || A) laid out contiguously. Saves
- * the host from materializing n concatenated byte strings. */
-void sha512_batch_prefixed(const uint8_t *prefix, const uint8_t *buf,
-                           const uint64_t *offsets, int64_t n, uint8_t *out) {
+/* --- reduction mod L --------------------------------------------------------
+ *
+ * A 512-bit little-endian value mod the ed25519 group order
+ * L = 2^252 + 27742317777372353535851937790883648493, by Barrett's
+ * method (Handbook of Applied Cryptography 14.42) on 64-bit limbs:
+ * with b = 2^64, k = 4 and mu = floor(b^(2k) / L),
+ *   q = floor(floor(x / b^(k-1)) * mu / b^(k+1)),  r = x - q*L < 3L,
+ * computed mod b^(k+1) and followed by at most two subtractions of L.
+ * The result is the one residue in [0, L): byte for byte
+ * int.from_bytes(digest, "little") % L. Not constant time, and need
+ * not be: a challenge is public. */
+
+typedef unsigned __int128 u128;
+
+static const uint64_t L_LIMBS[5] = {0x5812631a5cf5d3edULL, 0x14def9dea2f79cd6ULL,
+                                    0, 0x1000000000000000ULL, 0};
+static const uint64_t MU_LIMBS[5] = {0xed9ce5a30a2c131bULL, 0x2106215d086329a7ULL,
+                                     0xffffffffffffffebULL, 0xffffffffffffffffULL,
+                                     0xf};
+
+/* out[0 .. na+nb) = a * b, schoolbook. */
+static void mul_limbs(const uint64_t *a, int na, const uint64_t *b, int nb,
+                      uint64_t *out) {
+  memset(out, 0, (size_t)(na + nb) * sizeof(uint64_t));
+  for (int i = 0; i < na; i++) {
+    uint64_t carry = 0;
+    for (int j = 0; j < nb; j++) {
+      u128 t = (u128)a[i] * b[j] + out[i + j] + carry;
+      out[i + j] = (uint64_t)t;
+      carry = (uint64_t)(t >> 64);
+    }
+    out[i + nb] = carry;
+  }
+}
+
+/* out = (a - b) mod 2^320; returns the borrow out of the top limb. */
+static uint64_t sub5(const uint64_t a[5], const uint64_t b[5], uint64_t out[5]) {
+  uint64_t borrow = 0;
+  for (int i = 0; i < 5; i++) {
+    u128 t = (u128)a[i] - b[i] - borrow;
+    out[i] = (uint64_t)t;
+    borrow = (uint64_t)(t >> 64) & 1;
+  }
+  return borrow;
+}
+
+static void reduce512_one(const uint8_t in[64], uint8_t out[32]) {
+  uint64_t x[8], q2[10], ql[10], r[5], s[5];
+  for (int i = 0; i < 8; i++) {
+    x[i] = 0;
+    for (int b = 0; b < 8; b++) x[i] |= (uint64_t)in[i * 8 + b] << (8 * b);
+  }
+  mul_limbs(x + 3, 5, MU_LIMBS, 5, q2);  /* floor(x / b^3) * mu */
+  mul_limbs(q2 + 5, 5, L_LIMBS, 5, ql);  /* q * L, q = q2 / b^5 */
+  sub5(x, ql, r);                        /* 0 <= x - q*L < 3L < 2^320 */
+  while (!sub5(r, L_LIMBS, s)) memcpy(r, s, sizeof(r));
+  for (int i = 0; i < 4; i++)
+    for (int b = 0; b < 8; b++) out[i * 8 + b] = (uint8_t)(r[i] >> (8 * b));
+}
+
+/* n 64-byte little-endian values -> n 32-byte little-endian residues. */
+void reduce512_mod_l(const uint8_t *in, int64_t n, uint8_t *out) {
+  for (int64_t i = 0; i < n; i++)
+    reduce512_one(in + (uint64_t)i * 64, out + (uint64_t)i * 32);
+}
+
+/* The challenge scalars of n messages of the form prefix_i || msg_i,
+ * where every prefix is a fixed 64 bytes (the verifier's R || A) laid
+ * out contiguously: saves the host from materializing n concatenated
+ * byte strings. Each digest is reduced mod L where it was made: out
+ * holds n * 32 bytes, SHA-512(prefix_i || msg_i) mod L. */
+void sha512_batch_prefixed_mod_l(const uint8_t *prefix, const uint8_t *buf,
+                                 const uint64_t *offsets, int64_t n,
+                                 uint8_t *out) {
 #ifdef _OPENMP
 #pragma omp parallel for schedule(static) if (n >= PARALLEL_MIN_BATCH)
 #endif
   for (int64_t i = 0; i < n; i++) {
     sha512_ctx c;
+    uint8_t digest[64];
     sha512_init(&c);
     sha512_update(&c, prefix + (uint64_t)i * 64, 64);
     sha512_update(&c, buf + offsets[i], offsets[i + 1] - offsets[i]);
-    sha512_final(&c, out + (uint64_t)i * 64);
+    sha512_final(&c, digest);
+    reduce512_one(digest, out + (uint64_t)i * 32);
   }
 }

@@ -49,7 +49,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tendermint_tpu.crypto.hashing import L, sha512_batch_mod_l
+from tendermint_tpu.crypto.hashing import (
+    L,
+    host_hash_impl,
+    sha512_batch_mod_l,
+    sha512_batch_prefixed_mod_l,
+)
 from tendermint_tpu.libs import tracing
 from tendermint_tpu.ops import (
     curve32 as curve,
@@ -594,7 +599,7 @@ def _pad_k() -> bytes:
     if _PAD_K is None:
         _PAD_K = sha512_batch_mod_l(
             [_PAD_SIG[:32] + _PAD_PK + _PAD_MSG]
-        )[0]
+        )[0].tobytes()
     return _PAD_K
 
 
@@ -688,15 +693,17 @@ def _challenge_k(
     Where device hashing is on and applies (ops/hash512: fixed-width
     vote batches) the fused kernel hashes on the accelerator and the
     host's share of prep shrinks to byte packing; batches it does not
-    apply to take the hashlib/C-extension host path. A failing device
-    kernel raises — it does not become host hashing. ``stage_times``
+    apply to take the host path, hashed and reduced mod L in one pass of
+    the C extension (hashlib where that has no compiler). A failing
+    device kernel raises — it does not become host hashing. The open
+    span (the engine's ``prep_chunk``) is tagged with the path that ran,
+    ``hash="device"|"native"|"hashlib"``. ``stage_times``
     (bench) accumulates the hashing wall time
     under ``hash_ms`` plus which path ran, so prep_ms can be split into
     hash vs pack.
     """
     import time as _time
 
-    from tendermint_tpu.crypto.hashing import reduce_mod_l, sha512_batch_prefixed
     from tendermint_tpu.ops import hash512
 
     t0 = _time.perf_counter()
@@ -705,8 +712,9 @@ def _challenge_k(
         k_arr = np.asarray(k_dev)
         device = True
     else:
-        k_arr = reduce_mod_l(sha512_batch_prefixed(prefix, list(msgs)))
+        k_arr = sha512_batch_prefixed_mod_l(prefix, msgs)
         device = False
+    tracing.tag(hash="device" if device else host_hash_impl())
     if stage_times is not None:
         stage_times["hash_ms"] = stage_times.get("hash_ms", 0.0) + (
             _time.perf_counter() - t0
@@ -772,11 +780,8 @@ def prepare_batch(
         host_ok &= _s_canonical(s_arr)
         k_arr = np.zeros((n, 32), dtype=np.uint8)
         if hash_inputs:
-            k_list = sha512_batch_mod_l(hash_inputs)
-            rows = np.asarray(hash_rows)
-            k_arr[rows] = np.frombuffer(b"".join(k_list), dtype=np.uint8).reshape(
-                -1, 32
-            )
+            k_arr[np.asarray(hash_rows)] = sha512_batch_mod_l(hash_inputs)
+            tracing.tag(hash=host_hash_impl())
 
     inputs = dict(pk=pk_arr, r=r_arr, s=s_arr, k=k_arr)
     m = pad_to if pad_to is not None else _bucket(n)

@@ -12,11 +12,13 @@ stage shrinks to byte packing.
 
 Representation: one 64-bit SHA word is an (hi, lo) pair of uint32 lane
 vectors — f64/i64 are banned on this accelerator path (tpulint TPJ003),
-and uint32 pairs map directly onto the VPU. The mod-L reduction mirrors
-crypto/hashing.reduce_mod_l limb for limb (radix 2^8 in int32 columns,
-``q = floor(floor(x/2^240) * mu / 2^272)``, three conditional
-subtracts), so device and host scalars are bit-identical — pinned by
-the parity battery in tests/test_device_hash.py.
+and uint32 pairs map directly onto the VPU. The mod-L reduction is
+Barrett's in radix 2^8 on int32 columns (``q = floor(floor(x/2^240) *
+mu / 2^272)``, three conditional subtracts) and gives the one residue
+in [0, L), as the host's does (crypto/hashing.reduce_mod_l_int is the
+definition: ``int.from_bytes(digest, "little") % L``), so device and
+host scalars are bit-identical — pinned by the parity battery in
+tests/test_device_hash.py.
 
 Constants are derived, not transcribed: round constants are the
 fractional cube roots of the first 80 primes and the init state the
@@ -231,8 +233,8 @@ def _sha512_blocks(data: jnp.ndarray) -> jnp.ndarray:
 
 # --- byte-limb Barrett reduction mod L ---------------------------------------
 #
-# Mirror of crypto/hashing.reduce_mod_l in radix 2^8 / int32: column
-# magnitudes stay below 36 * 255^2 < 2^22, far inside int32.
+# The residue crypto/hashing.reduce_mod_l_int defines, in radix 2^8 /
+# int32: column magnitudes stay below 36 * 255^2 < 2^22, far inside int32.
 
 
 def _mul_const_bytes(x: jnp.ndarray, const_bytes, out_len: int) -> jnp.ndarray:
@@ -271,9 +273,9 @@ def _sub_l_bytes(x: jnp.ndarray):
 def _reduce_mod_l_bytes(digest: jnp.ndarray) -> jnp.ndarray:
     """(N, 64) uint8 little-endian 512-bit values -> (N, 32) uint8 mod L.
 
-    Same shift choices as the host Barrett (q from x >> 240, then
-    >> 272; up to three conditional subtracts), so verdicts match the
-    host path bit for bit.
+    Barrett with q from x >> 240, then >> 272, so r = x - q*L < 4L and
+    three conditional subtracts leave the residue in [0, L): the host
+    path's bytes, bit for bit.
     """
     x = digest.astype(jnp.int32)
     q1 = x[:, 30:]  # (N, 34): x >> 240
@@ -281,7 +283,7 @@ def _reduce_mod_l_bytes(digest: jnp.ndarray) -> jnp.ndarray:
     q2 = _carry_bytes(_mul_const_bytes(q1, _MU_BYTES, q2_len), q2_len)
     q = q2[:, 34:]  # >> 272; q < 2^261 fits the remaining limbs
     ql_cols = _mul_const_bytes(q, _L_BYTES, q.shape[1] + 32)
-    ql = _carry_bytes(ql_cols, 32)  # mod 2^256, as on host
+    ql = _carry_bytes(ql_cols, 32)  # mod 2^256: r < 4L < 2^255 fits
     outs = []
     borrow = jnp.zeros(x.shape[0], dtype=jnp.int32)
     for i in range(32):

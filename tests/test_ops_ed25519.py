@@ -5,6 +5,8 @@ compiles the kernel once (persisted across runs via the repo-local XLA
 cache).
 """
 
+import hashlib
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -12,6 +14,8 @@ import pytest
 from tendermint_tpu.crypto import ed25519_ref as ref
 from tendermint_tpu.ops import verify_batch
 from tendermint_tpu.ops import curve32 as curve, field32 as field
+from tendermint_tpu.libs import tracing
+from tendermint_tpu.ops import ed25519_batch
 from tendermint_tpu.ops.ed25519_batch import (
     _bytes_to_fe,
     _s_canonical,
@@ -96,6 +100,91 @@ def test_s_canonical_boundary():
         [np.frombuffer(v.to_bytes(32, "little"), dtype=np.uint8) for v in vals]
     )
     assert list(_s_canonical(arr)) == [True, True, True, False, False, False]
+
+
+# --- host prep: the challenge scalar ------------------------------------------
+
+
+def _int_challenge(pk, msg, sig):
+    """k = SHA-512(R || A || M) mod L in Python integers: the definition."""
+    h = hashlib.sha512(sig[:32] + pk + msg).digest()
+    return (int.from_bytes(h, "little") % ref.L).to_bytes(32, "little")
+
+
+@pytest.fixture(scope="module")
+def commit_lanes():
+    """The lanes of a 40-validator commit whose vote times straddle the
+    2^28 ns mark, so its sign-bytes are of two lengths as real votes'
+    are (a 4- or a 5-byte nanos varint)."""
+    from tests.helpers import CHAIN_ID, make_block_id, make_commit, make_validators
+
+    privs, vset = make_validators(40)
+    commit = make_commit(
+        make_block_id(), 9, 0, vset, privs,
+        time_ns=1_700_000_000 * 10**9 + 2**28 - 20,
+    )
+    pks = [v.pub_key.bytes() for v in vset.validators]
+    msgs = [commit.vote_sign_bytes(CHAIN_ID, i) for i in range(40)]
+    sigs = [cs.signature for cs in commit.signatures]
+    assert len({len(m) for m in msgs}) == 2
+    return pks, msgs, sigs
+
+
+@pytest.mark.parametrize("branch", ["well_formed", "ill_formed"])
+def test_prep_k_is_the_python_integer_challenge(commit_lanes, branch):
+    """``_prep_rows`` (every well-formed batch) and ``prepare_batch``'s
+    lane-by-lane branch (a batch with an ill-formed lane) both hand the
+    kernels k = SHA-512(R || A || M) mod L, byte for byte."""
+    pks, msgs, sigs = (list(x) for x in commit_lanes)
+    want = [_int_challenge(*lane) for lane in zip(pks, msgs, sigs)]
+    if branch == "well_formed":
+        pk, r, s, k, host_ok = ed25519_batch._prep_rows(pks, msgs, sigs, None)
+        assert host_ok.all()
+    else:
+        pks[3] = pks[3][:31]  # a short key
+        sigs[17] = sigs[17] + b"\x00"  # a long signature
+        inputs, host_ok = ed25519_batch.prepare_batch(pks, msgs, sigs)
+        assert list(np.nonzero(~host_ok)[0]) == [3, 17]
+        want[3] = want[17] = bytes(32)  # never hashed, never answered by the device
+        pk, r, s, k = (inputs[name][:40] for name in ("pk", "r", "s", "k"))
+        # the padded tail carries the pad triple's own challenge
+        assert inputs["k"].shape == (64, 32)
+        assert all(row.tobytes() == ed25519_batch._pad_k() for row in inputs["k"][40:])
+    assert k.dtype == np.uint8 and k.shape == (40, 32)
+    assert [row.tobytes() for row in k] == want
+    for i in set(range(40)) - {3, 17}:
+        assert pk[i].tobytes() == pks[i]
+        assert r[i].tobytes() + s[i].tobytes() == sigs[i]
+
+
+def test_pad_k_is_unchanged():
+    """The pad lanes' challenge, as every earlier build computed it."""
+    assert ed25519_batch._pad_k().hex() == (
+        "5aecdc673e79d981a7f0fb1ce4c819c2c241f90f7bc1e50c3f15bfaf5190e00f"
+    )
+    assert ed25519_batch._pad_k() == _int_challenge(
+        ed25519_batch._PAD_PK, ed25519_batch._PAD_MSG, ed25519_batch._PAD_SIG
+    )
+    assert ref.verify_zip215(
+        ed25519_batch._PAD_PK, ed25519_batch._PAD_MSG, ed25519_batch._PAD_SIG
+    )
+
+
+@pytest.mark.parametrize("branch", ["well_formed", "ill_formed"])
+def test_prep_tags_the_open_span_with_the_hash_path(commit_lanes, branch):
+    pks, msgs, sigs = (list(x) for x in commit_lanes)
+    if branch == "ill_formed":
+        pks[0] = b""
+    tracing.configure("ring")
+    try:
+        tracing.tracer.clear()
+        with tracing.span("prep_chunk", stage="prep"):
+            ed25519_batch.prepare_batch(pks, msgs, sigs)
+        (ev,) = [e for e in tracing.tracer.export()["traceEvents"] if e["name"] == "prep_chunk"]
+        assert ev["args"]["hash"] == "native"
+    finally:
+        tracing.configure("off")
+        tracing.tracer.clear()
 
 
 @pytest.fixture(scope="module")

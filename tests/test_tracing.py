@@ -63,6 +63,26 @@ def test_nested_spans_record_parent_and_args(ring):
     assert out["otherData"]["mode"] == "ring"
 
 
+def test_tag_lands_on_the_innermost_open_span(ring):
+    """``tracing.tag`` is for code that runs under a span it does not
+    hold (the engine's challenge hashing under ``prep_chunk``)."""
+    tracing.tag(hash="nowhere")  # no span open: nothing to tag, no error
+    with tracing.span("outer"):
+        with tracing.span("inner"):
+            tracing.tag(hash="native")
+        tracing.tag(lanes=3)
+    with tracing.attach(tracing.TraceContext("ab" * 8, "cd" * 8, 1)):
+        tracing.tag(hash="anchor")  # a remote anchor is not a span
+    events = {e["name"]: e for e in _complete_events(ring.export())}
+    assert events["inner"]["args"]["hash"] == "native"
+    assert events["outer"]["args"]["lanes"] == 3
+    assert "hash" not in events["outer"]["args"]
+    tracing.configure("off")
+    with tracing.span("unrecorded"):
+        tracing.tag(hash="native")  # the shared no-op span stays clean
+    assert len(ring) == 2
+
+
 def test_instant_events(ring):
     tracing.instant("device_health_transition", from_state="healthy")
     (ev,) = ring.export()["traceEvents"][-1:]
@@ -327,6 +347,9 @@ def test_verify_commit_traced_end_to_end(ring, monkeypatch):
         assert ev["args"]["stage"] == "prep"
         assert ev["args"]["engine"] == "ed25519"
         assert ev["args"]["parent"] == "verify_batch"
+        # which path made the challenge scalars (the CPU keeps device
+        # hashing off; the C extension builds here)
+        assert ev["args"]["hash"] == "native"
     dispatched = "dispatch_chunk" in by_name
     fell_back = "host_fallback" in by_name
     assert dispatched or fell_back  # every lane was answered somewhere
