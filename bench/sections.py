@@ -101,13 +101,15 @@ def run_throughput(beat) -> dict:
 def run_stages(beat) -> dict:
     """One instrumented pass: prep / H2D / kernel / D2H wall times, with
     prep further split into challenge hashing (hash_ms — on-device when
-    ops/hash512 is active) and host packing (pack_ms), plus a two-pass
+    ops/hash512 is active; the ``hash_us`` the tracer puts on a
+    ``prep_chunk`` span) and host packing (pack_ms), plus a two-pass
     table-H2D probe over a pinned validator set (per-batch table upload
     bytes; flat-at-zero on pass 2 when the resident store holds them)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    from tendermint_tpu.libs import tracing
     from tendermint_tpu.ops import ed25519_batch, precompute, resident
 
     batch = env_int("BENCH_BATCH", 8192)
@@ -117,14 +119,21 @@ def run_stages(beat) -> dict:
     pks, msgs, sigs = make_workload(rng, batch)
 
     beat("prep")
-    st: dict = {}
-    t0 = time.perf_counter()
-    inputs, host_ok = ed25519_batch.prepare_batch(
-        pks, msgs, sigs, pad_to=ed25519_batch._bucket(len(pks)),
-        backend=backend, stage_times=st,
-    )
-    t_prep = time.perf_counter() - t0
-    t_hash = st.get("hash_ms", 0.0) / 1e3
+    # the split is the tracer's: record for the length of the prep
+    was_off = not tracing.tracer.enabled
+    if was_off:
+        tracing.configure("ring")
+    try:
+        t0 = time.perf_counter()
+        with tracing.span("prep_chunk", lanes=len(pks)) as psp:
+            inputs, host_ok = ed25519_batch.prepare_batch(
+                pks, msgs, sigs, pad_to=ed25519_batch._bucket(len(pks)), backend=backend
+            )
+        t_prep = time.perf_counter() - t0
+    finally:
+        if was_off:
+            tracing.configure("off")
+    t_hash = psp.args.get("hash_us", 0.0) / 1e6
 
     m = inputs["pk"].shape[0]
     chunk = ed25519_batch.CHUNK
@@ -209,7 +218,7 @@ def run_stages(beat) -> dict:
             "kernel_ms": round(t_kernel * 1e3, 2),
             "d2h_ms": round(t_d2h * 1e3, 2),
         },
-        "hash_device": bool(st.get("hash_device", False)),
+        "hash_device": psp.args.get("hash") == "device",
         "table_h2d": {
             "lanes": table_lanes,
             "pass1_bytes": b1 - b0,

@@ -58,6 +58,25 @@ while the tracer records:
 - every span also enters a ``jax.profiler.TraceAnnotation`` of its
   name, so a ``jax.profiler`` capture holds the program's spans on the
   trace's own clock beside "XLA Ops".
+- CPU time beside wall time (ISSUE 34): a recorded span that opens
+  with no span open under it on its thread (a call's outermost span,
+  a worker thread's own) carries ``cpu_us``, its thread's CPU time
+  between enter and exit (``time.thread_time_ns``): ``dur`` well above
+  it, less what the thread waited for by design (the device, another
+  thread's verdicts), is time it was descheduled, or waited for a lock
+  or the GIL. A span that asked for it (``sp.process_cpu()``; the
+  engine's ``verify_batch``) also carries ``proc_cpu_us``, the
+  process's CPU time over all threads (``time.process_time_ns``):
+  several times the span's ``dur`` means threads are spinning. Nested
+  spans carry neither: where the machine with the chip runs, a read of
+  either clock costs ~6 us (a read of ``perf_counter`` 0.08) and the
+  clock moves in ticks of 10 ms, so thirty reads a 5 ms call would
+  cost a tenth of the call and say nothing of any one span. Nor do
+  spans somebody else timed (``gc_pause``, ``xla_compile``).
+- one clock: ``tracer.epoch_ns`` is the ``perf_counter_ns`` of
+  ``ts = 0`` (``otherData["epoch_perf_ns"]`` in an export), so a reader
+  that timed a call with ``perf_counter_ns`` can place the call's spans
+  without a probe span of its own.
 """
 
 from __future__ import annotations
@@ -180,6 +199,9 @@ class _NopSpan:
     def timed(self, phase: str, fn: Callable) -> Callable:
         return fn
 
+    def process_cpu(self) -> None:
+        pass
+
 
 NOP_SPAN = _NopSpan()
 
@@ -212,6 +234,8 @@ class _Span:
         "_remote",
         "_phases",
         "_annotation",
+        "_cpu0",
+        "_proc0",
     )
     live = True
 
@@ -233,6 +257,8 @@ class _Span:
         self._remote = remote
         self._phases: Optional[Dict[str, Callable]] = None
         self._annotation: Any = None
+        self._cpu0 = -1  # thread CPU ns at enter; -1: not read
+        self._proc0 = -1  # process CPU ns; -1: not asked for
 
     def set(self, **tags: Any) -> None:
         """Attach tags discovered mid-span (hit counts, verdicts)."""
@@ -262,6 +288,13 @@ class _Span:
         self._phases[phase] = lambda: (total, count)
         return timed_call
 
+    def process_cpu(self) -> None:
+        """From here to the span's end, also count the process's CPU
+        time over all threads: recorded as ``proc_cpu_us``. Called
+        right after entering, by the one span a call that wants it."""
+        if self._tracer._recording:
+            self._proc0 = time.process_time_ns()
+
     def context(self) -> TraceContext:
         """Propagation context naming this span as the remote parent."""
         return TraceContext(self.trace_id, self.span_id, 1)
@@ -282,12 +315,20 @@ class _Span:
         else:
             self.trace_id = _new_trace_id()
         self.span_id = _new_span_id()
+        outermost = not (stack and stack[-1].live)
         stack.append(self)
         self._annotation = self._tracer._annotate(self.name)
         self._t0 = time.perf_counter()
+        if outermost and self._tracer._recording:
+            self._cpu0 = time.thread_time_ns()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
+        # the CPU clocks are read inside the wall interval on both sides
+        if self._cpu0 >= 0:
+            self.args["cpu_us"] = (time.thread_time_ns() - self._cpu0) / 1e3
+        if self._proc0 >= 0:
+            self.args["proc_cpu_us"] = (time.process_time_ns() - self._proc0) / 1e3
         t1 = time.perf_counter()
         if self._annotation is not None:
             self._annotation.__exit__(None, None, None)
@@ -331,7 +372,10 @@ class Tracer:
         # third sink slot: the kernel profiler (ops/introspect.py), fed
         # (name, args, seconds) like _observer, read racily like it
         self._profile: Optional[Callable[[str, Dict[str, Any], float], None]] = None  # guarded-by: none(racy hot-path read)
-        self._epoch = time.perf_counter()
+        # one reading for both forms: ``ts`` counts from ``_epoch``
+        # (seconds), and ``epoch_ns`` says which perf_counter_ns that is
+        self._epoch_ns = time.perf_counter_ns()
+        self._epoch = self._epoch_ns / 1e9
         self._pid = os.getpid()
         self._thread_names: Dict[int, str] = {}  # guarded-by: _lock
         self._atexit_registered = False  # guarded-by: _lock
@@ -409,6 +453,12 @@ class Tracer:
     @property
     def enabled(self) -> bool:
         return self._recording
+
+    @property
+    def epoch_ns(self) -> int:
+        """``time.perf_counter_ns()`` of the instant every event's
+        ``ts`` counts from."""
+        return self._epoch_ns
 
     def set_metrics_observer(
         self, observer: Optional[Callable[[str, Dict[str, Any], float], None]]
@@ -504,6 +554,15 @@ class Tracer:
         stack = self._stack()
         if stack and stack[-1].live:
             stack[-1].set(**tags)
+
+    def timed(self, phase: str, fn: Callable) -> Callable:
+        """``fn`` timed as a phase of this thread's innermost open
+        span, from code that runs under it without holding it; ``fn``
+        itself when none is open."""
+        stack = self._stack()
+        if stack and stack[-1].live:
+            return stack[-1].timed(phase, fn)
+        return fn
 
     def _annotate(self, name: str) -> Any:
         """An entered ``jax.profiler.TraceAnnotation`` for a span that
@@ -696,6 +755,7 @@ class Tracer:
             "dropped": dropped,
             "pid": self._pid,
             "epoch_unix_us": round(self._epoch_unix_us(), 1),
+            "epoch_perf_ns": self._epoch_ns,
         }
         return meta, events, other
 
@@ -826,6 +886,10 @@ def instant(name: str, **args: Any) -> None:
 
 def tag(**tags: Any) -> None:
     tracer.tag(**tags)
+
+
+def timed(phase: str, fn: Callable) -> Callable:
+    return tracer.timed(phase, fn)
 
 
 def attach(ctx: Optional[TraceContext]):

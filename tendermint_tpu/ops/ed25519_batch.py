@@ -479,7 +479,13 @@ def _mul_impl_for_chunk(impl: str, backend: Optional[str], lanes: int) -> str:
     return autotune.mul_impl_for(backend, lanes)
 
 
-def _run_chunk(kind: ChunkKind, inputs: dict, backend: Optional[str], plan=None):
+def _run_chunk(
+    kind: ChunkKind,
+    inputs: dict,
+    backend: Optional[str],
+    plan=None,
+    sp=tracing.NOP_SPAN,
+):
     """Dispatch one padded chunk: the one place a kernel is chosen.
 
     Returns ``(result, plan_used, impl)``: ``plan_used`` is the (possibly
@@ -496,6 +502,11 @@ def _run_chunk(kind: ChunkKind, inputs: dict, backend: Optional[str], plan=None)
     batch, or run_chunk_mesh gave up — the store's columns are pulled
     to host, gathered per lane, and the chunk re-enters as a gathered-
     table chunk (rare, and still device compute).
+
+    ``sp`` is the caller's ``dispatch_chunk`` span, which gets the
+    chunk's two phases: ``h2d`` (the puts of the per-batch arrays) and
+    ``launch`` (the kernel's entry point returning). A sharded call
+    takes host arrays and transfers inside itself: ``launch`` alone.
     """
     # TENDERMINT_TPU_VERIFY_IMPL=mxu forces the int8 contraction; the
     # autotuned (or field-level default) impl is honored otherwise.
@@ -509,7 +520,9 @@ def _run_chunk(kind: ChunkKind, inputs: dict, backend: Optional[str], plan=None)
         not bound or inputs["mesh_key"] == tuple(plan.device_ids)
     ):
         try:
-            out, used = mesh_sharding.run_chunk_mesh(kind, inputs, mul_impl, plan)
+            out, used = mesh_sharding.run_chunk_mesh(
+                kind, inputs, mul_impl, plan, sp
+            )
             return out, used, "xla"
         except mesh_sharding.MeshUnavailableError:
             # Every device excluded: degrade to THIS backend's single-
@@ -525,16 +538,19 @@ def _run_chunk(kind: ChunkKind, inputs: dict, backend: Optional[str], plan=None)
         gathered = dict(
             tab=tab, ok=inputs["ok"], r=inputs["r"], s=inputs["s"], k=inputs["k"]
         )
-        return _run_chunk(KINDS["tables"], gathered, backend, None)
+        return _run_chunk(KINDS["tables"], gathered, backend, None, sp)
+    put = sp.timed("h2d", jnp.asarray)
     args = tuple(
-        inputs[i.name] if i.lane_axis is None else jnp.asarray(inputs[i.name])
+        inputs[i.name] if i.lane_axis is None else put(inputs[i.name])
         for i in kind.inputs
     )
     if impl == "pallas":
         from tendermint_tpu.ops import pallas_verify
 
-        return getattr(pallas_verify, kind.pallas)(m)(*args), None, impl
-    return _compiled_kernel(kind, m, backend, mul_impl)(*args), None, impl
+        fn = getattr(pallas_verify, kind.pallas)(m)
+    else:
+        fn = _compiled_kernel(kind, m, backend, mul_impl)
+    return sp.timed("launch", fn)(*args), None, impl
 
 
 # --- host-side preparation --------------------------------------------------
@@ -683,10 +699,7 @@ def _s_canonical(s_arr: np.ndarray) -> np.ndarray:
 
 
 def _challenge_k(
-    prefix: np.ndarray,
-    msgs: Sequence[bytes],
-    backend: Optional[str],
-    stage_times: Optional[dict] = None,
+    prefix: np.ndarray, msgs: Sequence[bytes], backend: Optional[str]
 ) -> np.ndarray:
     """Challenge scalars k = SHA-512(R‖A‖M) mod L for well-formed lanes.
 
@@ -697,30 +710,18 @@ def _challenge_k(
     the C extension (hashlib where that has no compiler). A failing
     device kernel raises — it does not become host hashing. The open
     span (the engine's ``prep_chunk``) is tagged with the path that ran,
-    ``hash="device"|"native"|"hashlib"``. ``stage_times``
-    (bench) accumulates the hashing wall time
-    under ``hash_ms`` plus which path ran, so prep_ms can be split into
-    hash vs pack.
+    ``hash="device"|"native"|"hashlib"``, and ``_prep_rows`` times this
+    call as that span's ``hash`` phase: ``hash_us`` against the span's
+    time splits prep into hashing and packing.
     """
-    import time as _time
-
     from tendermint_tpu.ops import hash512
 
-    t0 = _time.perf_counter()
     k_dev = hash512.try_challenge_device(prefix, msgs, backend)
     if k_dev is not None:
-        k_arr = np.asarray(k_dev)
-        device = True
-    else:
-        k_arr = sha512_batch_prefixed_mod_l(prefix, msgs)
-        device = False
-    tracing.tag(hash="device" if device else host_hash_impl())
-    if stage_times is not None:
-        stage_times["hash_ms"] = stage_times.get("hash_ms", 0.0) + (
-            _time.perf_counter() - t0
-        ) * 1000.0
-        stage_times["hash_device"] = device
-    return k_arr
+        tracing.tag(hash="device")
+        return np.asarray(k_dev)
+    tracing.tag(hash=host_hash_impl())
+    return sha512_batch_prefixed_mod_l(prefix, msgs)
 
 
 def _prep_rows(
@@ -728,7 +729,6 @@ def _prep_rows(
     msgs: Sequence[bytes],
     sigs: Sequence[bytes],
     backend: Optional[str],
-    stage_times: Optional[dict] = None,
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
     """The head every kind's prep shares, for well-formed lanes: two
     joins + one prefixed C hash call, no per-signature Python work.
@@ -739,7 +739,7 @@ def _prep_rows(
     sig_arr = np.frombuffer(b"".join(sigs), dtype=np.uint8).reshape(n, 64)
     r_arr, s_arr = sig_arr[:, :32], sig_arr[:, 32:]
     prefix = np.concatenate([r_arr, pk_arr], axis=1)  # (n, 64) = R || A
-    k_arr = _challenge_k(prefix, msgs, backend, stage_times)
+    k_arr = tracing.timed("hash", _challenge_k)(prefix, msgs, backend)
     return pk_arr, r_arr, s_arr, k_arr, _s_canonical(s_arr)
 
 
@@ -749,7 +749,6 @@ def prepare_batch(
     sigs: Sequence[bytes],
     pad_to: Optional[int] = None,
     backend: Optional[str] = None,
-    stage_times: Optional[dict] = None,
 ) -> Tuple[dict, np.ndarray]:
     """Host prep: batch-hash challenges, stack raw bytes, pad to bucket.
 
@@ -758,9 +757,7 @@ def prepare_batch(
     n = len(pubkeys)
     if all(len(pk) == 32 and len(sg) == 64 for pk, sg in zip(pubkeys, sigs)):
         # Fast path (every batch from commit verification).
-        pk_arr, r_arr, s_arr, k_arr, host_ok = _prep_rows(
-            pubkeys, msgs, sigs, backend, stage_times
-        )
+        pk_arr, r_arr, s_arr, k_arr, host_ok = _prep_rows(pubkeys, msgs, sigs, backend)
     else:
         host_ok = np.ones(n, dtype=bool)
         pk_arr = np.zeros((n, 32), dtype=np.uint8)
@@ -987,6 +984,8 @@ def _run_jobs(
                         engine=engine,
                         kind=job.kind.name,
                         lanes=len(job.rows),
+                        chunk=j,
+                        chunks=len(jobs),
                     ) as dsp:
                         if dsp.live:
                             dsp.set(
@@ -994,7 +993,7 @@ def _run_jobs(
                                 h2d_bytes=job.kind.h2d_bytes(inputs),
                             )
                         job.out, job.plan, impl = _run_chunk(
-                            job.kind, inputs, backend, plan
+                            job.kind, inputs, backend, plan, dsp
                         )
                         if dsp.live:
                             dsp.set(impl=impl)
@@ -1022,7 +1021,7 @@ def _run_jobs(
     # surface at materialization; those too degrade per chunk.
     fallback_lanes = 0
     device_chunks_ok = 0
-    for job in jobs:
+    for j, job in enumerate(jobs):
         ok = None
         if job.out is not None:
             try:
@@ -1032,12 +1031,21 @@ def _run_jobs(
                     engine=engine,
                     kind=job.kind.name,
                     lanes=len(job.rows),
+                    chunk=j,
                 ) as csp:
                     fault_injection.fire(engine + ".collect")
+                    if csp.live:
+                        # the one blocking line, cut in two: until the
+                        # device is done, then what is left of the copy
+                        # back, which starts now, as np.asarray starts it
+                        job.out.copy_to_host_async()
+                        csp.timed("wait", jax.block_until_ready)(job.out)
                     if job.plan is not None:
-                        ok = mesh_sharding.collect_sharded(job.out, engine)
+                        ok = csp.timed("d2h", mesh_sharding.collect_sharded)(
+                            job.out, engine
+                        )
                     else:
-                        ok = np.asarray(job.out)
+                        ok = csp.timed("d2h", np.asarray)(job.out)
                     if csp.live:
                         csp.set(d2h_bytes=int(ok.nbytes))
                 device_chunks_ok += 1
@@ -1116,7 +1124,8 @@ def verify_batch(
     n = len(pubkeys)
     if n == 0:
         return []
-    with tracing.span("verify_batch", engine="ed25519", lanes=n):
+    with tracing.span("verify_batch", engine="ed25519", lanes=n) as vsp:
+        vsp.process_cpu()
         # The verdict cache is asked and filled once a batch
         # (precompute.ResultCache): the lookup derives the keys, the store
         # takes them back. ``cached`` None: every lane goes to the device

@@ -102,7 +102,11 @@ def sharded_verify_fn(mesh: Mesh):
 
 
 def run_chunk_mesh(
-    kind: ChunkKind, inputs: dict, mul_impl: str, plan: "mesh_mod.MeshPlan"
+    kind: ChunkKind,
+    inputs: dict,
+    mul_impl: str,
+    plan: "mesh_mod.MeshPlan",
+    sp=tracing.NOP_SPAN,
 ):
     """Dispatch one prepped chunk lane-sharded across ``plan``'s mesh.
 
@@ -114,9 +118,15 @@ def run_chunk_mesh(
     :class:`MeshUnavailableError` when no multi-device mesh remains,
     and re-raises unattributed failures for the engine's ordinary
     per-chunk handling.
+
+    ``sp`` (the engine's ``dispatch_chunk`` span) gets the sharded
+    call's time as its ``launch`` phase, every try of a degrading mesh
+    in the one total. The call takes host arrays and transfers them
+    inside itself, so there is no ``h2d`` phase on a mesh.
     """
     mgr = mesh_mod.manager
     lanes = kind.lanes(inputs)
+    launch = sp.timed("launch", lambda fn, *args: fn(*args))
     while True:
         # Every device gets an identical slab. The engines already pad to
         # ``_mesh_bucket`` multiples for the planned mesh; this re-pad
@@ -135,7 +145,7 @@ def run_chunk_mesh(
                 lanes=m,
             ):
                 fault_injection.fire(kind.engine + ".chunk")
-                out = fn(*kind.args(padded))
+                out = launch(fn, *kind.args(padded))
         except Exception as exc:
             culprit = mgr.on_failure(plan, exc)
             if culprit is None:

@@ -163,8 +163,15 @@ def test_program_still_emits_what_the_readers_match(kind, monkeypatch):
     ``resident_upload``'s ``reason`` and ``width``, and, once eight newer
     sets have pushed a carried one out, ``note_validator_set``'s
     ``retired`` / ``tables_dropped`` around a ``valset_hash`` and the
-    ``resident_drop`` span. A refactor that renames any of these fails
-    here, not on the chip."""
+    ``resident_drop`` span. And the chunk's life and the CPU clocks
+    (ISSUE 34): ``chunk`` / ``chunks`` and the phase totals ``h2d_us`` /
+    ``launch_us`` on ``dispatch_chunk``, ``chunk``, ``wait_us`` and
+    ``d2h_us`` on ``collect_chunk``, ``hash_us`` on ``prep_chunk``,
+    ``cpu_us`` on the call's outermost span alone and ``proc_cpu_us``
+    on ``verify_batch`` alone (here one span is both;
+    ``tests/test_mesh.py`` holds the same on the sharded path).
+    A refactor that renames any of these fails here, not on the
+    chip."""
     from chipbench.readers.pad_lane_share import KERNEL_OF_KIND
     from tendermint_tpu.crypto.keys import Ed25519PrivKey
     from tendermint_tpu.libs import tracing
@@ -211,6 +218,21 @@ def test_program_still_emits_what_the_readers_match(kind, monkeypatch):
     assert chunk["kind"] == kind and chunk["lanes"] == 3
     assert chunk["padded_lanes"] == 64 and chunk["impl"] == "xla"
     assert chunk["h2d_bytes"] > 0
+    assert (chunk["chunk"], chunk["chunks"], chunk["launch_n"]) == (0, 1, 1)
+    # one put an array that carries lanes: the store is on the device already
+    assert chunk["h2d_n"] == sum(
+        1 for i in ed25519_batch.KINDS[kind].inputs if i.lane_axis is not None
+    )
+    (dispatch,) = [e for e in events if e.get("name") == "dispatch_chunk"]
+    assert 0 < chunk["h2d_us"] + chunk["launch_us"] <= dispatch["dur"]
+    (collect,) = [e for e in events if e.get("name") == "collect_chunk"]
+    assert (collect["args"]["chunk"], collect["args"]["wait_n"], collect["args"]["d2h_n"]) == (0, 1, 1)
+    assert 0 < collect["args"]["wait_us"] + collect["args"]["d2h_us"] <= collect["dur"]
+    (prep,) = [e for e in events if e.get("name") == "prep_chunk"]
+    assert prep["args"]["hash_n"] == 1 and 0 < prep["args"]["hash_us"] <= prep["dur"]
+    (outer,) = [e for e in events if "cpu_us" in e.get("args", {})]
+    assert outer["name"] == "verify_batch" and 0 < outer["args"]["cpu_us"] <= outer["dur"]
+    assert [e["name"] for e in events if "proc_cpu_us" in e.get("args", {})] == ["verify_batch"]
     (compiled,) = [e["args"] for e in events if e.get("name") == "kernel_compile"]
     assert compiled["kernel"] == KERNEL_OF_KIND[kind]
     assert (compiled["engine"], compiled["lanes"]) == ("ed25519", 64)

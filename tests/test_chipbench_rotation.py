@@ -113,8 +113,12 @@ def test_benchmark_files_agree():
     assert windows == 18
     assert config["blocks"] == config["first_change_height"] - 1 + windows * 16
     assert [m["name"] for m in real.metrics_for("end_to_end", "sync500-rotation")] == ["sigs_per_s", "setup_s"]
-    rot = [m["name"] for m in real.metrics_for("per_layer", "sync500-rotation")]
-    assert len(rot) == 25 and all(name.endswith(".rot") for name in rot)
+    reported = [m["name"] for m in real.metrics_for("per_layer", "sync500-rotation")]
+    # its own twins, and since PR 34 the ten call-path metrics every
+    # ``sigs_per_s`` cell reports from one ``.stream`` file each
+    rot = [name for name in reported if name.endswith(".rot")]
+    stream = [name for name in reported if name.endswith(".stream")]
+    assert (len(rot), len(stream)) == (25, 10) and reported == rot + stream
     tiny = spec.Spec(BENCH)
     assert [m["name"] for m in tiny.metrics_for("per_layer", CELL)] == rot
 

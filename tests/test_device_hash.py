@@ -113,13 +113,36 @@ def test_challenge_counts_device_lanes():
 
 def test_challenge_k_helper_parity_and_stage_times():
     """The engine-side _challenge_k wrapper returns host bytes equal to
-    the host path and records the hash/pack split for bench."""
+    the host path, and the split of prep into hashing and packing is
+    the ``prep_chunk`` span's: ``hash`` names the path that ran and
+    ``hash_us`` is the one hashing call's time (the tracer's phase
+    total; there is no second timing system to ask)."""
+    from tendermint_tpu.libs import tracing
+
     prefix, msgs = _challenge_case(8, 40, 4)
-    st = {}
-    got = ed25519_batch._challenge_k(prefix, msgs, None, stage_times=st)
+    got = ed25519_batch._challenge_k(prefix, msgs, None)
     want = _challenge_oracle(prefix, msgs)
     np.testing.assert_array_equal(got, want)
-    assert st["hash_device"] is True and st["hash_ms"] >= 0.0
+
+    pk, r = prefix[:, 32:], prefix[:, :32]
+    pks = [row.tobytes() for row in pk]
+    sigs = [row.tobytes() + bytes(32) for row in r]
+    tracing.tracer.set_metrics_observer(None)
+    tracing.configure("ring")
+    tracing.tracer.clear()
+    try:
+        with tracing.span("prep_chunk", lanes=8):
+            _, _, _, k, _ = ed25519_batch._prep_rows(pks, msgs, sigs, None)
+        (ev,) = tracing.tracer.export(clear=True)["traceEvents"][-1:]
+    finally:
+        tracing.configure("off")
+        tracing.tracer.clear()
+    np.testing.assert_array_equal(k, want)
+    assert ev["name"] == "prep_chunk" and ev["args"]["hash"] == "device"
+    assert ev["args"]["hash_n"] == 1
+    assert 0.0 < ev["args"]["hash_us"] <= ev["dur"]
+    # no span open: nothing is timed, and the call is the plain one
+    assert tracing.timed("hash", ed25519_batch._challenge_k) is ed25519_batch._challenge_k
 
 
 # --- fallback ladder --------------------------------------------------------

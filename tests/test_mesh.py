@@ -193,6 +193,33 @@ def test_sharded_engine_spans_devices(ring, triples):
     assert len(collected) == 8
 
 
+def test_sharded_chunk_carries_what_the_call_path_readers_match(ring, triples):
+    """The mesh half of ``tests/test_chipbench_readers.py``'s contract
+    (ISSUE 34): a sharded chunk's ``dispatch_chunk`` carries ``chunk``,
+    ``chunks`` and the sharded call's time as ``launch_us`` — and no
+    ``h2d_us``, the call takes host arrays and transfers inside itself
+    — and its ``collect_chunk`` the wait on the whole sharded output as
+    ``wait_us`` and the per-device copies as ``d2h_us``."""
+    pks, msgs, sigs = triples
+    assert all(ed25519_batch.verify_batch(pks, msgs, sigs))
+    events = [e for e in ring.export()["traceEvents"] if e.get("ph") == "X"]
+    (dispatch,) = [e for e in events if e["name"] == "dispatch_chunk"]
+    (inner,) = [e for e in events if e["name"] == "mesh_dispatch"]
+    args = dispatch["args"]
+    assert (args["chunk"], args["chunks"], args["launch_n"]) == (0, 1, 1)
+    assert 0 < args["launch_us"] <= inner["dur"] <= dispatch["dur"]
+    assert "h2d_us" not in args
+    (collect,) = [e for e in events if e["name"] == "collect_chunk"]
+    shards = [e for e in events if e["name"] == "collect_device"]
+    assert len(shards) == 8
+    args = collect["args"]
+    assert (args["chunk"], args["wait_n"], args["d2h_n"]) == (0, 1, 1)
+    assert sum(e["dur"] for e in shards) <= args["d2h_us"]
+    assert args["wait_us"] + args["d2h_us"] <= collect["dur"]
+    assert [e["name"] for e in events if "cpu_us" in e["args"]] == ["verify_batch"]
+    assert [e["name"] for e in events if "proc_cpu_us" in e["args"]] == ["verify_batch"]
+
+
 def test_sharded_matches_host_oracle(triples):
     """Sharded verdicts == the host ZIP-215 oracle lane-for-lane, with
     corruptions spread across device shards."""

@@ -324,7 +324,7 @@ def test_run_chunk_picks_the_kernel(batch8, monkeypatch, kind, case):
     monkeypatch.setattr(
         sharding,
         "run_chunk_mesh",
-        lambda k, inputs, mul_impl, plan: (
+        lambda k, inputs, mul_impl, plan, sp: (
             calls.append(("mesh", k.lanes(inputs), k.name)),
             plan,
         ),

@@ -647,6 +647,178 @@ def test_timed_phase_accumulates_on_the_span(ring):
     assert "never_n" not in ev["args"]
 
 
+# --- CPU time beside wall time, one clock (ISSUE 34) ---------------------------
+
+
+def test_cpu_us_of_a_sleeping_span_is_far_under_its_dur(ring):
+    import time
+
+    with tracing.span("asleep"):
+        time.sleep(0.05)
+    (ev,) = _complete_events(ring.export())
+    assert ev["dur"] >= 50_000
+    assert 0 <= ev["args"]["cpu_us"] < 0.2 * ev["dur"]
+    assert "proc_cpu_us" not in ev["args"]  # only a span that asked
+
+
+def test_cpu_us_of_a_busy_span_is_about_its_dur(ring):
+    import time
+
+    # the best of three: a busy thread can still lose its core for a while
+    shares = []
+    for _ in range(3):
+        with tracing.span("busy"):
+            until = time.perf_counter() + 0.05
+            while time.perf_counter() < until:
+                pass
+        (ev,) = _complete_events(ring.export(clear=True))
+        assert ev["args"]["cpu_us"] <= ev["dur"]  # read inside the wall interval
+        shares.append(ev["args"]["cpu_us"] / ev["dur"])
+    assert max(shares) > 0.8
+
+
+def test_only_a_threads_outermost_span_reads_the_cpu_clock(ring):
+    """Two reads of the thread's clock a call, not two a span: nested
+    spans carry no ``cpu_us``, a worker thread's own outermost span
+    does, and a span opened under a remote parent is its thread's
+    outermost."""
+    def worker(ctx):
+        with tracing.span("scheduler_dispatch", parent_ctx=ctx):
+            with tracing.span("sched_flush"):
+                pass
+
+    with tracing.span("verify_commit") as outer:
+        with tracing.span("verify_batch") as sp:
+            sp.process_cpu()
+            with tracing.span("prep_chunk"):
+                pass
+        t = threading.Thread(target=worker, args=(outer.context(),))
+        t.start()
+        t.join()
+    events = {e["name"]: e["args"] for e in _complete_events(ring.export())}
+    assert sorted(n for n, a in events.items() if "cpu_us" in a) == ["scheduler_dispatch", "verify_commit"]
+    assert [n for n, a in events.items() if "proc_cpu_us" in a] == ["verify_batch"]
+
+
+def test_process_cpu_counts_the_other_threads(ring):
+    """``proc_cpu_us`` is the whole process's: a second thread spinning
+    beside a sleeping caller shows there and not in ``cpu_us``."""
+    import time
+
+    stop = threading.Event()
+
+    def spin():
+        while not stop.is_set():
+            pass
+
+    worker = threading.Thread(target=spin)
+    worker.start()
+    try:
+        with tracing.span("verify_batch") as sp:
+            sp.process_cpu()
+            time.sleep(0.05)
+    finally:
+        stop.set()
+        worker.join()
+    (ev,) = _complete_events(ring.export())
+    assert ev["args"]["cpu_us"] < 0.2 * ev["dur"]
+    assert ev["args"]["proc_cpu_us"] > 0.5 * ev["dur"]
+
+
+def test_externally_timed_spans_carry_no_cpu_time(ring):
+    ring._record_interval("gc_pause", 1.0, 1.5, {"generation": 2, "collected": 0})
+    ring._record_interval("xla_compile", 2.0, 2.5, {"event": "x"})
+    assert [sorted(e["args"]) for e in _complete_events(ring.export())] == [
+        ["collected", "generation"], ["event"],
+    ]
+
+
+def test_observer_alone_reads_no_cpu_clock():
+    """Off with a metrics observer bound: spans are timed for the
+    histograms and nothing else is read or stored."""
+    seen = []
+    tracing.configure("off")
+    tracing.tracer.set_metrics_observer(lambda name, args, secs: seen.append(dict(args)))
+    try:
+        with tracing.span("verify_batch", stage="x", engine="e") as sp:
+            sp.process_cpu()
+    finally:
+        tracing.tracer.set_metrics_observer(None)
+    assert seen == [{"stage": "x", "engine": "e"}]
+
+
+def test_epoch_ns_places_a_span_on_the_perf_counter_ns_clock(ring):
+    """What the benchmark's readers do: a call timed with
+    ``perf_counter_ns`` around a span finds the span inside it."""
+    import time
+
+    before = time.perf_counter_ns()
+    with tracing.span("inside"):
+        pass
+    after = time.perf_counter_ns()
+    doc = ring.export()
+    (ev,) = _complete_events(doc)
+    start = ring.epoch_ns + ev["ts"] * 1000.0
+    assert before - 1000 <= start <= start + ev["dur"] * 1000.0 <= after + 1000
+    assert doc["otherData"]["epoch_perf_ns"] == ring.epoch_ns
+    with pytest.raises(AttributeError):
+        ring.epoch_ns = 0  # read-only
+
+
+@pytest.mark.parametrize("mode", ["off", "ring"])
+def test_collect_chunk_waits_apart_from_its_copy_only_when_live(mode, monkeypatch):
+    """The untraced path keeps its one blocking line, ``np.asarray``;
+    a live ``collect_chunk`` cuts it in two: ``block_until_ready``
+    (``wait_us``), then the copy (``d2h_us``)."""
+    import numpy as np
+
+    from tendermint_tpu.crypto.keys import Ed25519PrivKey
+    from tendermint_tpu.ops import ed25519_batch
+
+    waits, copies = [], []
+    real_wait, real_copy = ed25519_batch.jax.block_until_ready, np.asarray
+
+    def wait(x):
+        waits.append(type(x).__name__)
+        return real_wait(x)
+
+    class CountingNumpy:
+        """``np`` as the engine sees it, counting device arrays copied."""
+
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        @staticmethod
+        def asarray(x, *args, **kwargs):
+            if hasattr(x, "block_until_ready"):
+                copies.append(type(x).__name__)
+            return real_copy(x, *args, **kwargs)
+
+    pks, msgs, sigs = _raw_lanes(3)
+    assert ed25519_batch.verify_batch(pks, msgs, sigs) == [True] * 3  # compiled, tables noted
+    monkeypatch.setattr(ed25519_batch.jax, "block_until_ready", wait)
+    monkeypatch.setattr(ed25519_batch, "np", CountingNumpy())
+    msgs = [m + b"-again" for m in msgs]
+    sigs = [Ed25519PrivKey.from_seed(bytes([i + 1]) * 32).sign(m) for i, m in enumerate(msgs)]
+    tracing.tracer.set_metrics_observer(None)
+    tracing.configure(mode)
+    tracing.tracer.clear()
+    try:
+        assert ed25519_batch.verify_batch(pks, msgs, sigs) == [True] * 3
+        events = _complete_events(tracing.tracer.export(clear=True))
+    finally:
+        tracing.configure("off")
+        tracing.tracer.clear()
+    assert len(copies) == 1
+    if mode == "off":
+        assert waits == [] and events == []
+        return
+    assert len(waits) == 1
+    (ev,) = [e for e in events if e["name"] == "collect_chunk"]
+    assert ev["args"]["wait_n"] == ev["args"]["d2h_n"] == 1
+    assert ev["args"]["wait_us"] + ev["args"]["d2h_us"] <= ev["dur"]
+
+
 def test_gc_collect_inside_a_span_yields_nested_gc_pause(ring, monkeypatch):
     import gc
 
@@ -748,8 +920,8 @@ def test_dispatch_chunk_names_the_implementation_only_when_live(mode, monkeypatc
     handed = []
     real = ed25519_batch._run_chunk
 
-    def recording(kind, inputs, backend, plan=None):
-        out = real(kind, inputs, backend, plan)
+    def recording(kind, inputs, backend, plan=None, sp=tracing.NOP_SPAN):
+        out = real(kind, inputs, backend, plan, sp)
         handed.append(out[2])
         return out
 
@@ -799,7 +971,7 @@ def test_chunk_impl_is_what_the_chunk_was_handed_to(monkeypatch, active, sharded
         lambda kind, n, backend, mul_impl: lambda *args: mul_impl,
     )
     monkeypatch.setattr(
-        sharding, "run_chunk_mesh", lambda kind, inputs, mul_impl, plan: ("mesh", plan)
+        sharding, "run_chunk_mesh", lambda kind, inputs, mul_impl, plan, sp: ("mesh", plan)
     )
     pks, msgs, sigs = _raw_lanes(3)
     inputs, _ = ed25519_batch.prepare_batch(pks, msgs, sigs)
