@@ -26,8 +26,15 @@ jax, but touches no device).
   windows of 16 commits at 500 validators through
   ``parallel/pipeline.verify_commits_pipelined`` (one sound, one with a
   block tampered on both sides of its early exit). Verdicts are
-  compared with the host oracle (``crypto/ed25519_ref.py``). The second
-  run proves the compile cache: it may add no entry.
+  compared with the host oracle (``crypto/ed25519_ref.py``). Where more
+  than one chip is present the edge vectors also go through the sharded
+  path (``parallel/sharding.verify_batch_sharded``), which under
+  ``pallas`` runs the Pallas kernel per shard from a stored lowered
+  program (``ops/kernel_store.py``): each sharded kernel's first call is
+  printed with its ``stored`` (``miss``: the kernel body was walked;
+  ``hit``: not) and seconds, cold in the first run and warm in the
+  second. The second run proves the compile cache and the kernel store:
+  it may add no entry, and every sharded first call must be a ``hit``.
 - **served** (driven from the parent): ``python -m tendermint_tpu
   verifyd`` started through the CLI, warmed one request at a time, then
   four concurrent ``verifyd.client`` clients built with
@@ -287,11 +294,12 @@ def _check_dispatch(spans: list, sent: dict, what: str) -> None:
 
 def _compiles(spans: list, impl: str) -> list:
     """(implementation, kernel, lanes, seconds) for each kernel the
-    window compiled (or loaded from the cache). On one device the
-    legacy, table and resident kernels must be the implementation
-    ``auto`` resolved to. The sharded kernels are exempt: they are the
-    XLA graph only and record no ``kernel_compile`` span (they show
-    under ``sharded_xla``)."""
+    window compiled (or loaded from the cache), and for a mesh's Pallas
+    kernel (parallel/sharding.py) two more: the devices, and what the
+    kernel store did (``hit`` | ``miss``). The legacy, table and
+    resident kernels must be the implementation ``auto`` resolved to, on
+    one device and per shard of a mesh. A mesh's XLA-graph kernels
+    record no ``kernel_compile`` span (they show under ``sharded``)."""
     out = []
     for e in spans:
         if e["name"] != "kernel_compile":
@@ -304,10 +312,91 @@ def _compiles(spans: list, impl: str) -> list:
                 "active_impl() is %r but the %s kernel at %s lanes ran %r",
                 impl, a.get("kernel"), a.get("lanes"), ran,
             )
-        out.append(
-            [ran, a.get("kernel"), a.get("lanes"), round(e["dur"] / 1e6, 2)]
+        row = [ran, a.get("kernel"), a.get("lanes"), round(e["dur"] / 1e6, 2)]
+        if "devices" in a:
+            row += [a["devices"], a.get("stored")]
+        out.append(row)
+    return out
+
+
+def _first_calls(report: dict) -> list:
+    """Every row of :func:`_compiles` in a library run's report."""
+    parts = [report["edge"], report.get("sharded_edge", {})] + report["sizes"]
+    return [c for part in parts for c in part.get("compiles", ())]
+
+
+def _sharded_first_calls(report: dict) -> list:
+    """The rows among them that are a mesh's kernels."""
+    return [c for c in _first_calls(report) if len(c) > 4]
+
+
+def _check_store_warm(run: int, report: dict) -> None:
+    """A process that finds the kernel store warm walks no kernel body:
+    every sharded first call of a second run is a ``hit``."""
+    cold = [c for c in _sharded_first_calls(report) if c[5] != "hit"]
+    check(
+        not cold,
+        "library run %d traced a sharded kernel the store should have held: %r",
+        run, cold,
+    )
+
+
+def _sharded(spans: list, impl: str) -> list:
+    """(job kind, devices, padded lanes, implementation) of the sharded
+    dispatches. A mesh has the implementations one device has
+    (parallel/sharding._sharded_kernel): every ed25519 kind has a Pallas
+    entry point, so each must have run what ``auto`` resolved to."""
+    out = sorted(
+        {
+            (e["args"]["kind"], e["args"]["devices"], e["args"]["lanes"],
+             e["args"].get("impl"))
+            for e in spans
+            if e["name"] == "mesh_dispatch"
+        }
+    )
+    for kind, devices, lanes, ran in out:
+        check(
+            ran == impl,
+            "active_impl() is %r but the sharded %s chunk (%s lanes over %s "
+            "devices) ran %r", impl, kind, lanes, devices, ran,
         )
     return out
+
+
+def _run_sharded_edge(dev: dict, impl: str) -> dict:
+    """The oracle's lanes through the sharded path, whatever their
+    number (the mesh floor would keep 20 lanes on one device): the
+    legacy kernel on every device's slab, lane for lane against the
+    oracle."""
+    from tendermint_tpu.parallel import sharding
+
+    _drain_spans()
+    before = _counters()
+    pks, msgs, sigs = edge_vectors()
+    t0 = time.monotonic()
+    verdicts = sharding.verify_batch_sharded(
+        pks, msgs, sigs, mesh=sharding.make_mesh(), min_lanes=0
+    )
+    wall = time.monotonic() - t0
+    _check_oracle(pks, msgs, sigs, verdicts, range(len(pks)), "sharded edge vectors")
+    spans = _drain_spans()
+    _check_dispatch(spans, {"legacy": len(pks)}, "sharded edge vectors")
+    d = _delta(before)
+    _check_health(d, "sharded edge vectors")
+    sharded = _sharded(spans, impl)
+    check(
+        d["mesh_dispatches"] > 0 and sharded
+        and all(row[1] == dev["count"] for row in sharded),
+        "sharded edge vectors: dispatches %r over %d devices",
+        sharded, dev["count"],
+    )
+    return {
+        "lanes": len(pks),
+        "accepted": int(sum(map(bool, verdicts))),
+        "wall_s": round(wall, 2),
+        "compiles": _compiles(spans, impl),
+        "sharded": sharded,
+    }
 
 
 def _batch_verify(pub_key, pks, msgs, sigs) -> list:
@@ -444,15 +533,7 @@ def _run_size(n: int, heights: int, dev: dict, impl: str, paths: dict) -> dict:
         if e["name"] == "collect_device":
             dev_id = e["args"]["device"]
             shards[dev_id] = shards.get(dev_id, 0) + int(e["args"]["lanes"])
-    # (job kind, devices, padded lanes) of the sharded dispatches; the
-    # sharded kernels are the XLA graph only (parallel/sharding.py).
-    sharded = sorted(
-        {
-            (e["args"]["kind"], e["args"]["devices"], e["args"]["lanes"])
-            for e in spans
-            if e["name"] == "mesh_dispatch"
-        }
-    )
+    sharded = _sharded(spans, impl)
     if dev["count"] >= 2 and n >= mesh.MIN_MESH_LANES:
         check(
             len(shards) == dev["count"] and len(set(shards.values())) == 1,
@@ -472,7 +553,7 @@ def _run_size(n: int, heights: int, dev: dict, impl: str, paths: dict) -> dict:
         "counters": d,
         "compiles": _compiles(spans, impl),
         "lanes_per_device": shards,
-        "sharded_xla": sharded,
+        "sharded": sharded,
     }
 
 
@@ -622,6 +703,14 @@ def library_phase(
             "oracle; compiled %(compiles)r" % report["edge"]
         )
 
+        if dev["count"] >= 2:
+            report["sharded_edge"] = _run_sharded_edge(dev, impl)
+            say(
+                "sharded edge vectors: %(lanes)d lanes, %(accepted)d accepted, "
+                "as the oracle, %(wall_s)ss; sharded %(sharded)r; compiled "
+                "%(compiles)r" % report["sharded_edge"]
+            )
+
         report["sizes"] = []
         for n in sizes:
             rep = _run_size(n, heights, dev, impl, paths)
@@ -629,10 +718,7 @@ def library_phase(
             say("%(validators)d validators: set-up %(setup_s)ss, walls %(walls)r" % rep)
             say("  counters %(counters)r" % rep)
             say("  compiled %(compiles)r" % rep)
-            say(
-                "  lanes/device %(lanes_per_device)r sharded (xla) "
-                "%(sharded_xla)r" % rep
-            )
+            say("  lanes/device %(lanes_per_device)r sharded %(sharded)r" % rep)
 
         if sync:
             rep = _run_pipelined_windows(sync[0], sync[1], paths, impl)
@@ -926,10 +1012,7 @@ def _run_library_child(run: int, workdir: str, deadline: float) -> dict:
     with open(report_path) as f:
         report = json.load(f)
     report["wall_s"] = round(time.monotonic() - t0, 1)
-    parts = [report["edge"]] + report["sizes"]
-    report["compile_s"] = round(
-        sum(c[3] for part in parts for c in part["compiles"]), 1
-    )
+    report["compile_s"] = round(sum(c[3] for c in _first_calls(report)), 1)
     return report
 
 
@@ -971,6 +1054,14 @@ def run_smoke() -> dict:
             "run 2 added %d entries to a cache run 1 had filled",
             entries2 - entries1,
         )
+        for run, rep in ((1, run1), (2, run2)):
+            for ran, kernel, lanes, secs, devices, stored in _sharded_first_calls(rep):
+                say(
+                    "library run %d: sharded %s kernel %s, %s lanes a device on %s "
+                    "devices: first call %.2fs, stored program: %s"
+                    % (run, ran, kernel, lanes, devices, secs, stored)
+                )
+        _check_store_warm(2, run2)
         check(
             time.monotonic() < deadline,
             "out of the smoke's time budget before the served phase",

@@ -445,10 +445,10 @@ _IMPL_ENV = "TENDERMINT_TPU_VERIFY_IMPL"
 # compiled on a TPU v5e at 64/256/1024/4096 lanes and agreed with the
 # host oracle lane for lane (PR 21's chip run; chip_smoke.py repeats
 # it). CPU stays on the XLA graph (Pallas interpret mode is a test
-# vehicle, far too slow for real batches). Note what ``pallas`` covers:
-# legacy, gathered-table and resident-store chunks on one device. Every
-# mesh-sharded chunk (parallel/sharding.py) has only an XLA-graph
-# kernel and runs that whatever this says.
+# vehicle, far too slow for real batches). ``pallas`` covers legacy,
+# gathered-table and resident-store chunks, on one device and per shard
+# of a mesh (parallel/sharding.py; PR 36's chip run); a kind without a
+# Pallas entry point (sr25519) runs its XLA graph whatever this says.
 _AUTO_IMPL = {"tpu": "pallas", "cpu": "xla"}
 # Device-vs-host fallback state lives in ops/device_policy.py, shared
 # with the sr25519 engine so a broken backend is broken once.
@@ -490,11 +490,12 @@ def _run_chunk(
 
     Returns ``(result, plan_used, impl)``: ``plan_used`` is the (possibly
     degraded) mesh plan when the chunk went out lane-sharded, else None;
-    ``impl`` is what the chunk was actually handed to. A usable plan
-    sends the chunk to the mesh, whose kernels are the XLA graph only;
-    a mesh that loses all usable devices falls through to the single-
-    device dispatch below — never to host. On one device ``pallas``
-    takes the kind's Pallas entry point, anything else the XLA graph.
+    ``impl`` is what the chunk was actually handed to. ``pallas`` takes
+    the kind's Pallas entry point, anything else (and a kind without
+    one) the XLA graph — on one device and, a usable plan given, on the
+    mesh alike (parallel/sharding._sharded_kernel). A mesh that loses
+    all usable devices falls through to the single-device dispatch
+    below — never to host.
 
     A resident chunk's store is committed to the context it was
     uploaded for (``mesh_key``: one mesh's devices, or None for one
@@ -521,9 +522,9 @@ def _run_chunk(
     ):
         try:
             out, used = mesh_sharding.run_chunk_mesh(
-                kind, inputs, mul_impl, plan, sp
+                kind, inputs, impl, mul_impl, plan, sp
             )
-            return out, used, "xla"
+            return out, used, impl
         except mesh_sharding.MeshUnavailableError:
             # Every device excluded: degrade to THIS backend's single-
             # device dispatch below; host fallback stays with the caller.

@@ -232,6 +232,7 @@ class DeviceMemAccountant:
         self._bytes: Dict[str, int] = {}  # guarded-by: _lock
         self._compiles: Dict[str, int] = {}  # guarded-by: _lock
         self._exec_entries: Dict[str, int] = {}  # guarded-by: _lock
+        self._stored: Dict[str, int] = {}  # guarded-by: _lock
         self._metrics = None  # guarded-by: none(racy hot-path read)
 
     def bind_metrics(self, metrics) -> None:
@@ -327,6 +328,12 @@ class DeviceMemAccountant:
         with self._lock:
             self._exec_entries[str(engine)] = int(entries)
 
+    def note_stored_program(self, outcome: str) -> None:
+        """One fetch from ops/kernel_store: a ``hit`` staged a stored
+        lowered program, a ``miss`` traced and lowered the kernel."""
+        with self._lock:
+            self._stored[outcome] = self._stored.get(outcome, 0) + 1
+
     # --- export --------------------------------------------------------------
 
     def snapshot(self) -> Dict[str, Any]:
@@ -336,6 +343,7 @@ class DeviceMemAccountant:
                 "device_bytes_total": sum(self._bytes.values()),
                 "compile_events": dict(sorted(self._compiles.items())),
                 "exec_cache_entries": dict(sorted(self._exec_entries.items())),
+                "stored_programs": dict(sorted(self._stored.items())),
             }
 
     def clear(self) -> None:
@@ -345,6 +353,7 @@ class DeviceMemAccountant:
             self._bytes.clear()
             self._compiles.clear()
             self._exec_entries.clear()
+            self._stored.clear()
 
 
 def _env_on() -> bool:
@@ -393,13 +402,18 @@ def note_compile(engine: str, entries: Optional[int] = None) -> None:
     accountant.note_compile(engine, entries)
 
 
-def traced_first_call(fn: Callable, engine: str, kernel: str, lanes: int):
+def note_stored_program(outcome: str) -> None:
+    accountant.note_stored_program(outcome)
+
+
+def traced_first_call(fn: Callable, engine: str, kernel: str, lanes: int, **span_args):
     """Wrap a freshly jitted callable so its FIRST invocation — the one
     that traces and compiles — runs under a ``kernel_compile`` span
-    (feeding the profiler's compile digests) and lands one
-    ``note_compile`` tick. Steady-state calls pay one bool check.
-    Same pattern as pallas_verify._trace_first_call; this is the XLA-
-    graph engines' version."""
+    (feeding the profiler's compile digests; ``span_args`` are further
+    arguments of it) and lands one ``note_compile`` tick. Steady-state
+    calls pay one bool check. Same pattern as
+    pallas_verify._trace_first_call; this is the version of the XLA-
+    graph engines and of the mesh's kernels (parallel/sharding)."""
     state = {"first": True}
 
     @functools.wraps(fn)  # keeps the jitted program's name; ``__wrapped__`` is it
@@ -410,7 +424,7 @@ def traced_first_call(fn: Callable, engine: str, kernel: str, lanes: int):
 
             note_compile(engine)
             with tracing.tracer.span(
-                "kernel_compile", engine=engine, kernel=kernel, lanes=lanes
+                "kernel_compile", engine=engine, kernel=kernel, lanes=lanes, **span_args
             ):
                 return fn(*args, **kwargs)
         return fn(*args, **kwargs)

@@ -30,6 +30,10 @@ in-kernel; :func:`compiled_verify_tables` takes the gathered
 (ops/precompute.py) and skips decompression of A and the table build;
 :func:`compiled_verify_resident` gathers that input on the device from
 the resident store (ops/resident.py) and runs the same table kernel.
+A mesh runs the same three per shard (:func:`stored_shard_program`,
+parallel/sharding.py), from a lowered program kept by
+ops/kernel_store.py, so that only the process that first meets a slab
+shape walks the kernel body.
 
 Reference semantics: crypto/ed25519/ed25519.go:24-31 (ZIP-215 verify
 options), crypto/ed25519/ed25519.go:198-233 (batch verifier),
@@ -38,6 +42,7 @@ types/validation.go:154 (the commit-verification caller).
 
 from __future__ import annotations
 
+import sys
 from functools import lru_cache
 from typing import List, Sequence, Tuple
 
@@ -48,7 +53,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tendermint_tpu.libs import tracing
-from tendermint_tpu.ops import field32
+from tendermint_tpu.ops import field32, kernel_store
 
 NLIMBS = 32
 RADIX = 256.0
@@ -740,4 +745,59 @@ def compiled_verify_resident(n: int, block: int = BLOCK, interpret: bool = False
         ),
         "verify_resident",
         n,
+    )
+
+
+# --- the per-shard program of a mesh ----------------------------------------
+
+# What each entry point jits, by the entry point's name (a ChunkKind's
+# ``pallas``); looked up when a program is built.
+_SHARD_BODY = {
+    "compiled_verify": "verify_fn",
+    "compiled_verify_tables": "verify_tables_fn",
+    "compiled_verify_resident": "verify_resident_fn",
+}
+
+
+def shard_lanes(n: int, block: int = BLOCK) -> int:
+    """Lanes a slab of ``n`` must be padded to for the kernels' grid:
+    one block up to ``block`` lanes, whole blocks above."""
+    return n if n <= block else -(-n // block) * block
+
+
+@lru_cache(maxsize=1)
+def _program_digest() -> str:
+    """What the lowered kernels are made from besides their arguments:
+    this file, the field code and constants it takes from field32, and
+    the constant tables that enter the program by value."""
+    import hashlib
+
+    h = hashlib.sha256(kernel_store.source_digest(field32, sys.modules[__name__]).encode())
+    for arr in (_CONSTS,) + _b_tables():
+        h.update(np.ascontiguousarray(arr).tobytes())
+    return h.hexdigest()
+
+
+def stored_shard_program(entry: str, kernel: str, avals, device, block: int = BLOCK):
+    """``(callable, "hit" | "miss")``: what entry point ``entry`` jits,
+    at one shard's ``avals``, as a lowered program from the kernel
+    store, for calling under ``shard_map`` on a mesh of ``device``'s
+    kind. Off the TPU the kernel runs in interpret mode (a test
+    vehicle, as everywhere in this module)."""
+    n = avals[-1].shape[0]
+    blk = min(block, n)
+    if n % blk:
+        raise ValueError(f"a slab of {n} lanes is not whole blocks of {blk}")
+    interpret = device.platform != "tpu"
+    body = globals()[_SHARD_BODY[entry]]
+
+    def shard(*args):
+        return body(*args, block=blk, interpret=interpret)
+
+    return kernel_store.fetch(
+        kernel,
+        shard,
+        avals,
+        device.platform,
+        key=(device.device_kind, blk, interpret, _program_digest()),
     )

@@ -5,10 +5,17 @@ The TPU analog of the reference's task-level concurrency inventory
 verify kernel — the ed25519 chunk kinds of ops/ed25519_batch.KINDS and
 sr25519's (ops/sr25519_batch.SR25519) — is lane-local with no
 cross-signature communication, so sharding the lane axis over an ICI
-mesh partitions with zero collectives; XLA emits per-device slices and
-the only sync is the final per-lane bool gather. What a kernel takes
+mesh partitions with zero collectives; every device runs its own slab
+and the only sync is the final per-lane bool gather. What a kernel takes
 and where its lanes lie comes from its :class:`ChunkKind` record
 (ops/chunk_kinds.py); nothing here names a kind.
+
+A mesh has the implementations one device has (:func:`_sharded_kernel`):
+the kind's XLA graph partitioned by GSPMD, or, for ``pallas`` and a kind
+with a Pallas entry point, that entry point's program run per shard
+under ``shard_map`` — the same Mosaic kernel one device runs, taken
+from ops/kernel_store.py so that a process which finds the store warm
+never walks the kernel body.
 
 This module is the mechanism half of the mesh engine: compile-cached
 sharded kernels, slab padding to a device multiple, the
@@ -60,34 +67,97 @@ def make_mesh(n_devices: Optional[int] = None) -> Mesh:
     return Mesh(np.asarray(devices), (SIG_AXIS,))
 
 
+def _lane_spec(axis: Optional[int]) -> P:
+    """Lanes along ``axis`` over the mesh; None (an input without
+    lanes: the resident store) is replicated."""
+    return P() if axis is None else P(*(None,) * axis, SIG_AXIS)
+
+
+def _shard_shape(shape: tuple, axis: Optional[int], n_dev: int) -> tuple:
+    """One device's part of ``shape`` under :func:`_lane_spec`."""
+    if axis is None:
+        return tuple(shape)
+    return (*shape[:axis], shape[axis] // n_dev, *shape[axis + 1 :])
+
+
+def _runs_pallas(kind: ChunkKind, impl: str) -> bool:
+    return impl == "pallas" and kind.pallas is not None
+
+
 @lru_cache(maxsize=32)
-def _sharded_kernel(mesh: Mesh, kind: ChunkKind, mul_impl: str):
-    """Jitted lane-sharded kernel per (mesh, chunk kind, field-mul
-    impl). The mul impl is a trace-time switch on field32, pinned inside
-    the traced fn (same rules as ops/ed25519_batch._compiled_kernel) and
-    therefore part of the cache key.
+def _sharded_kernel(
+    mesh: Mesh, kind: ChunkKind, impl: str, mul_impl: str, avals: Optional[tuple] = None
+):
+    """Jitted lane-sharded kernel per (mesh, chunk kind, implementation,
+    field-mul impl), and for ``pallas`` per argument shapes. The mul
+    impl is a trace-time switch on field32, pinned inside the traced fn
+    (same rules as ops/ed25519_batch._compiled_kernel) and therefore
+    part of the cache key.
 
     Each input is sharded along its lane axis, so a device holds only
     its own lanes' rows (and, for the gathered ``(8, 4, 32, N)`` table
     input, its own lanes' tables). An input without lanes — the
     resident store, keyed by distinct pubkey (a committee is ~100 KiB)
     — is replicated, so the per-lane take inside the kernel is device-
-    local and comes out lane-sharded."""
+    local and comes out lane-sharded.
 
-    def spec(axis: Optional[int]) -> NamedSharding:
-        if axis is None:
-            return NamedSharding(mesh, P())
-        return NamedSharding(mesh, P(*(None,) * axis, SIG_AXIS))
+    ``impl`` ``pallas`` (for a kind that has a Pallas entry point) runs
+    that entry point's program on every device's slab under
+    ``shard_map``: no collective, nothing for GSPMD to partition.
+    ``avals`` — the ``(shape, dtype)`` of every argument — is part of
+    the key there, because the program is not traced here but fetched,
+    already lowered, from ops/kernel_store.py at one shard's shapes;
+    the fetch and the first call run under a ``kernel_compile`` span
+    whose ``stored`` says whether the store had it (``hit``) or the
+    kernel body was walked (``miss``). Anything else is the kind's XLA
+    graph under GSPMD."""
+    specs = tuple(_lane_spec(i.lane_axis) for i in kind.inputs)
+    shardings = dict(
+        in_shardings=tuple(NamedSharding(mesh, p) for p in specs),
+        out_shardings=NamedSharding(mesh, _lane_spec(0)),
+    )
+    if _runs_pallas(kind, impl):
+        from tendermint_tpu.ops import introspect, pallas_verify
+
+        n_dev = mesh.devices.size
+        shard_avals = tuple(
+            jax.ShapeDtypeStruct(_shard_shape(shape, i.lane_axis, n_dev), np.dtype(dtype))
+            for i, (shape, dtype) in zip(kind.inputs, avals)
+        )
+        program = []
+
+        def first_then(*args):
+            if not program:
+                shard, stored = pallas_verify.stored_shard_program(
+                    kind.pallas, kind.kernel_name, shard_avals, mesh.devices.flat[0]
+                )
+                tracing.tag(stored=stored)
+
+                def run(*a):
+                    return jax.shard_map(
+                        shard,
+                        mesh=mesh,
+                        in_specs=specs,
+                        out_specs=_lane_spec(0),
+                        check_vma=False,
+                    )(*a)
+
+                program.append(jax.jit(run, **shardings))
+            return program[0](*args)
+
+        return introspect.traced_first_call(
+            first_then,
+            "pallas",
+            kind.kernel_name,
+            shard_avals[-1].shape[0],
+            devices=n_dev,
+        )
 
     def run(*args):
         with field.pinned_mul_impl(mul_impl):
             return kind.kernel(*args)
 
-    return jax.jit(
-        run,
-        in_shardings=tuple(spec(i.lane_axis) for i in kind.inputs),
-        out_shardings=spec(0),
-    )
+    return jax.jit(run, **shardings)
 
 
 def sharded_verify_fn(mesh: Mesh):
@@ -95,7 +165,9 @@ def sharded_verify_fn(mesh: Mesh):
     ``mesh`` (back-compat entry point; see :func:`_sharded_kernel`)."""
     from tendermint_tpu.ops import ed25519_batch
 
-    return _sharded_kernel(mesh, ed25519_batch.KINDS["legacy"], field.get_mul_impl())
+    return _sharded_kernel(
+        mesh, ed25519_batch.KINDS["legacy"], "xla", field.get_mul_impl()
+    )
 
 
 # --- dispatch / collect -------------------------------------------------------
@@ -104,11 +176,13 @@ def sharded_verify_fn(mesh: Mesh):
 def run_chunk_mesh(
     kind: ChunkKind,
     inputs: dict,
+    impl: str,
     mul_impl: str,
     plan: "mesh_mod.MeshPlan",
     sp=tracing.NOP_SPAN,
 ):
-    """Dispatch one prepped chunk lane-sharded across ``plan``'s mesh.
+    """Dispatch one prepped chunk lane-sharded across ``plan``'s mesh,
+    on the mesh's kernel for ``impl`` (:func:`_sharded_kernel`).
 
     Returns ``(device_result, plan_used)`` — ``plan_used`` may be a
     smaller rebuilt plan if a device was excluded mid-dispatch. A
@@ -127,14 +201,23 @@ def run_chunk_mesh(
     mgr = mesh_mod.manager
     lanes = kind.lanes(inputs)
     launch = sp.timed("launch", lambda fn, *args: fn(*args))
+    pallas = _runs_pallas(kind, impl)
+    if pallas:
+        from tendermint_tpu.ops import pallas_verify
     while True:
         # Every device gets an identical slab. The engines already pad to
         # ``_mesh_bucket`` multiples for the planned mesh; this re-pad
         # covers dispatch on a DEGRADED mesh (8-way prep retried 7-way:
-        # 512 -> 518).
-        m = -(-lanes // plan.n_dev) * plan.n_dev
+        # 512 -> 518), and for the Pallas kernels makes a slab above one
+        # block whole blocks.
+        slab = -(-lanes // plan.n_dev)
+        if pallas:
+            slab = pallas_verify.shard_lanes(slab)
+        m = slab * plan.n_dev
         padded = kind.pad_lanes(inputs, m - lanes)
-        fn = _sharded_kernel(plan.mesh, kind, mul_impl)
+        args = kind.args(padded)
+        avals = tuple((a.shape, str(a.dtype)) for a in args) if pallas else None
+        fn = _sharded_kernel(plan.mesh, kind, impl, mul_impl, avals)
         try:
             with tracing.span(
                 "mesh_dispatch",
@@ -143,9 +226,10 @@ def run_chunk_mesh(
                 kind=kind.name,
                 devices=plan.n_dev,
                 lanes=m,
+                impl=impl,
             ):
                 fault_injection.fire(kind.engine + ".chunk")
-                out = launch(fn, *kind.args(padded))
+                out = launch(fn, *args)
         except Exception as exc:
             culprit = mgr.on_failure(plan, exc)
             if culprit is None:
@@ -170,10 +254,9 @@ def run_chunk_mesh(
             plan = nxt
             continue
         mgr.note_dispatch(plan, m)
-        per_dev = m // plan.n_dev
         for did in plan.device_ids:
             tracing.instant(
-                "mesh_device_dispatch", device=did, engine=kind.engine, lanes=per_dev
+                "mesh_device_dispatch", device=did, engine=kind.engine, lanes=slab
             )
         return out, plan
 

@@ -85,6 +85,14 @@ def test_edge_vectors_phase_passes_on_cpu():
     edge = report["edge"]
     assert 0 < edge["accepted"] < edge["lanes"]
     assert report["sr25519"] == "not run"
+    # more than one device: the same lanes through the sharded path, on
+    # the 512-lane 8-way legacy kernel tests/test_mesh.py compiles
+    sharded = report["sharded_edge"]
+    assert (sharded["lanes"], sharded["accepted"]) == (edge["lanes"], edge["accepted"])
+    assert sharded["sharded"] == [("legacy", 8, 512, "xla")]
+    # the XLA graph's mesh kernels record no first-call span
+    assert chip_smoke._sharded_first_calls(report) == []
+    json.dumps(report)
 
 
 def test_kernel_failure_fails_the_phase_instead_of_passing_on_the_oracle(
@@ -155,6 +163,76 @@ def test_compiles_holds_one_device_kernels_to_the_resolved_impl(kernel):
     ]
     with pytest.raises(chip_smoke.SmokeFailure, match=kernel + " kernel at 256"):
         chip_smoke._compiles([span("ed25519")], "pallas")
+
+
+def _sharded_compile_span(stored, kernel="verify_resident", engine="pallas"):
+    return {
+        "name": "kernel_compile",
+        "dur": 3.25e6,
+        "args": {"engine": engine, "kernel": kernel, "lanes": 4096, "devices": 4,
+                 "stored": stored},
+    }
+
+
+@pytest.mark.parametrize("stored", ["hit", "miss"])
+def test_compiles_reads_a_sharded_first_call(stored):
+    """A mesh's Pallas kernel: the row carries the devices and what the
+    kernel store did, and is held to the resolved implementation too."""
+    one_device = {
+        "name": "kernel_compile", "dur": 1e6,
+        "args": {"engine": "pallas", "kernel": "verify", "lanes": 64},
+    }
+    rows = chip_smoke._compiles([one_device, _sharded_compile_span(stored)], "pallas")
+    assert rows == [
+        ["pallas", "verify", 64, 1.0],
+        ["pallas", "verify_resident", 4096, 3.25, 4, stored],
+    ]
+    with pytest.raises(chip_smoke.SmokeFailure, match="verify_resident kernel at 4096"):
+        chip_smoke._compiles([_sharded_compile_span(stored)], "xla")
+
+
+def test_second_run_must_find_the_kernel_store_warm():
+    """Run 1 may walk the kernel body (a cold store); run 2 may not."""
+
+    def report(stored_edge, stored_big):
+        rows = lambda stored, kernel: chip_smoke._compiles(
+            [_sharded_compile_span(stored, kernel)], "pallas"
+        )
+        return {
+            "edge": {"compiles": [["pallas", "verify", 64, 1.0]]},
+            "sharded_edge": {"compiles": rows(stored_edge, "verify")},
+            "sizes": [{"compiles": []}, {"compiles": rows(stored_big, "verify_resident")}],
+        }
+
+    cold = report("miss", "miss")
+    assert [c[1:] for c in chip_smoke._sharded_first_calls(cold)] == [
+        ["verify", 4096, 3.25, 4, "miss"],
+        ["verify_resident", 4096, 3.25, 4, "miss"],
+    ]
+    chip_smoke._check_store_warm(2, report("hit", "hit"))
+    with pytest.raises(chip_smoke.SmokeFailure, match="run 2 traced a sharded kernel"):
+        chip_smoke._check_store_warm(2, report("hit", "miss"))
+    # one device: no sharded part in the report at all
+    alone = {"edge": {"compiles": []}, "sizes": []}
+    assert chip_smoke._sharded_first_calls(alone) == []
+    chip_smoke._check_store_warm(2, alone)
+
+
+@pytest.mark.parametrize("ran,ok", [("pallas", True), ("xla", False), (None, False)])
+def test_sharded_chunks_are_held_to_the_resolved_impl(ran, ok):
+    span = {
+        "name": "mesh_dispatch",
+        "args": {"kind": "resident", "devices": 4, "lanes": 16384},
+    }
+    if ran is not None:
+        span["args"]["impl"] = ran
+    if ok:
+        assert chip_smoke._sharded([span, span], "pallas") == [
+            ("resident", 4, 16384, "pallas")
+        ]
+    else:
+        with pytest.raises(chip_smoke.SmokeFailure, match="sharded resident chunk"):
+            chip_smoke._sharded([span], "pallas")
 
 
 # --- §5: a compile cache that can be placed from outside ----------------------

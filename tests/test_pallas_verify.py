@@ -56,17 +56,18 @@ def test_pallas_valid_batch(batch8):
     assert pallas_verify_batch(pks, msgs, sigs) == [True] * 8
 
 
-def test_pallas_flags_bad_entries(batch8):
+def lanes_bad_entries(batch8):
+    """(lanes, verdicts): wrong s, wrong message, R replaced, wrong key."""
     pks, msgs, sigs = (list(x) for x in batch8)
     sigs[1] = sigs[1][:32] + bytes(32)  # wrong s
     msgs[3] = b"tampered"  # wrong msg
     sigs[5] = bytes(32) + sigs[5][32:]  # R replaced (y=0 IS on curve)
     pks[6] = keypair(7)[1]  # wrong key
-    got = pallas_verify_batch(pks, msgs, sigs)
-    assert got == [True, False, True, False, True, False, False, True]
+    return (pks, msgs, sigs), [True, False, True, False, True, False, False, True]
 
 
-def test_pallas_zip215_edge_cases(batch8):
+def lanes_zip215_edge_cases(batch8):
+    """(lanes, verdicts): the ZIP-215 edge cases of crypto/ed25519/ed25519.go:24-31."""
     pks, msgs, sigs = (list(x) for x in batch8)
     # identity pubkey: R = [s]B verifies for any msg (small-order accepted)
     ident = (1).to_bytes(32, "little")
@@ -79,11 +80,12 @@ def test_pallas_zip215_edge_cases(batch8):
     pks[1], msgs[1], sigs[1] = (ref.P + 1).to_bytes(32, "little"), b"x", sig215
     # s >= L must be rejected even though the curve equation would hold
     pks[2], msgs[2], sigs[2] = ident, b"x", rb + (s + ref.L).to_bytes(32, "little")
-    got = pallas_verify_batch(pks, msgs, sigs)
-    assert got == [True, True, False, True, True, True, True, True]
+    return (pks, msgs, sigs), [True, True, False, True, True, True, True, True]
 
 
-def test_pallas_off_curve_and_mutations(batch8):
+def lanes_off_curve_and_mutations(batch8):
+    """(lanes, verdicts): an off-curve key and single-bit mutations of
+    R, s and the key; the verdicts are the oracle's."""
     pks, msgs, sigs = (list(x) for x in batch8)
     rng = np.random.RandomState(7)
     pks[0] = bytes([2] + [0] * 31)  # y=2: off-curve, must reject
@@ -102,8 +104,22 @@ def test_pallas_off_curve_and_mutations(batch8):
             pks[i] = bytes(pk)
         sigs[i] = bytes(b)
     want = [ref.verify_zip215(pk, m, s) for pk, m, s in zip(pks, msgs, sigs)]
-    got = pallas_verify_batch(pks, msgs, sigs)
-    assert got == want
+    return (pks, msgs, sigs), want
+
+
+def test_pallas_flags_bad_entries(batch8):
+    lanes, want = lanes_bad_entries(batch8)
+    assert pallas_verify_batch(*lanes) == want
+
+
+def test_pallas_zip215_edge_cases(batch8):
+    lanes, want = lanes_zip215_edge_cases(batch8)
+    assert pallas_verify_batch(*lanes) == want
+
+
+def test_pallas_off_curve_and_mutations(batch8):
+    lanes, want = lanes_off_curve_and_mutations(batch8)
+    assert pallas_verify_batch(*lanes) == want
 
 
 def pallas_verify_batch_tables(pks, msgs, sigs):
@@ -129,14 +145,22 @@ def pallas_verify_batch_tables(pks, msgs, sigs):
     return list(np.logical_and(np.asarray(out)[:n], host_ok))
 
 
-@pytest.mark.slow  # interpret-mode XLA compile of this kernel runs ~8 min
-def test_pallas_table_path_parity(batch8):
+def lanes_table_edges(batch8):
+    """(lanes, verdicts) for the table kernels: what a table and its
+    ``ok`` bit must carry (off-curve and non-canonical keys) beside a
+    flipped s and a wrong message; the verdicts are the oracle's."""
     pks, msgs, sigs = (list(x) for x in batch8)
     pks[0] = bytes([2] + [0] * 31)  # off-curve: identity table, ok=False
     sigs[1] = sigs[1][:33] + bytes([sigs[1][33] ^ 1]) + sigs[1][34:]
     msgs[2] = b"tampered"
     pks[3] = (ref.P + 1).to_bytes(32, "little")  # non-canonical encoding
     want = [ref.verify_zip215(pk, m, s) for pk, m, s in zip(pks, msgs, sigs)]
+    return (pks, msgs, sigs), want
+
+
+@pytest.mark.slow  # interpret-mode XLA compile of this kernel runs ~8 min
+def test_pallas_table_path_parity(batch8):
+    (pks, msgs, sigs), want = lanes_table_edges(batch8)
     assert pallas_verify_batch_tables(pks, msgs, sigs) == want
 
 
@@ -181,12 +205,7 @@ def _resident_args(inputs):
 def test_pallas_resident_path_parity(batch8):
     """The resident entry: same edge lanes as the table path, seven
     lanes so that the eighth is a pad lane on column 0."""
-    pks, msgs, sigs = (list(x) for x in batch8)
-    pks[0] = bytes([2] + [0] * 31)  # off-curve: identity table, ok=False
-    sigs[1] = sigs[1][:33] + bytes([sigs[1][33] ^ 1]) + sigs[1][34:]
-    msgs[2] = b"tampered"
-    pks[3] = (ref.P + 1).to_bytes(32, "little")  # non-canonical encoding
-    want = [ref.verify_zip215(pk, m, s) for pk, m, s in zip(pks, msgs, sigs)]
+    (pks, msgs, sigs), want = lanes_table_edges(batch8)
     inputs, _, host_ok = _resident_chunk((pks, msgs, sigs), 7, 8)
     fn = pallas_verify.compiled_verify_resident(8, block=8, interpret=True)
     out = np.asarray(fn(*_resident_args(inputs)))
@@ -298,10 +317,12 @@ _RUNNER_CASES = [
 
 @pytest.mark.parametrize("kind,case", _RUNNER_CASES)
 def test_run_chunk_picks_the_kernel(batch8, monkeypatch, kind, case):
-    """The one runner, for every chunk kind: on one device it follows
-    active_impl; a sharded chunk and a resident store committed
-    elsewhere never reach the kind's own Pallas entry; and the ``impl``
-    it returns is what the chunk was handed to."""
+    """The one runner, for every chunk kind: on one device and on a
+    mesh it follows active_impl (the mesh is handed the implementation
+    and runs its own kernel for it: the one-device entry points are not
+    reached); a resident store committed elsewhere re-enters as a
+    gathered-table chunk; and the ``impl`` it returns is what the chunk
+    was handed to."""
     from types import SimpleNamespace
 
     from tendermint_tpu.parallel import sharding
@@ -324,8 +345,8 @@ def test_run_chunk_picks_the_kernel(batch8, monkeypatch, kind, case):
     monkeypatch.setattr(
         sharding,
         "run_chunk_mesh",
-        lambda k, inputs, mul_impl, plan, sp: (
-            calls.append(("mesh", k.lanes(inputs), k.name)),
+        lambda k, inputs, impl, mul_impl, plan, sp: (
+            calls.append(("mesh", k.lanes(inputs), (k.name, impl))),
             plan,
         ),
     )
@@ -347,7 +368,7 @@ def test_run_chunk_picks_the_kernel(batch8, monkeypatch, kind, case):
     ((name, n, got),) = calls
     assert n == 8 and used is plan
     if case == "mesh":
-        assert (name, got, got_impl) == ("mesh", kind, "xla")
+        assert (name, got, got_impl) == ("mesh", (kind, "pallas"), "pallas")
     elif case == "context_mismatch":
         # re-gathered on the host and re-entered as a gathered-table chunk
         assert (name, got_impl) == ("pallas:tables", "pallas")

@@ -950,7 +950,10 @@ def test_dispatch_chunk_names_the_implementation_only_when_live(mode, monkeypatc
         ("pallas", False, "pallas"),
         ("mxu", False, "mxu"),
         ("xla", False, "xla"),
-        ("pallas", True, "xla"),  # the sharded kernels are the XLA graph only
+        # a mesh has the implementations one device has (PR 36)
+        ("pallas", True, "pallas"),
+        ("mxu", True, "mxu"),
+        ("xla", True, "xla"),
     ],
 )
 def test_chunk_impl_is_what_the_chunk_was_handed_to(monkeypatch, active, sharded, want):
@@ -971,7 +974,9 @@ def test_chunk_impl_is_what_the_chunk_was_handed_to(monkeypatch, active, sharded
         lambda kind, n, backend, mul_impl: lambda *args: mul_impl,
     )
     monkeypatch.setattr(
-        sharding, "run_chunk_mesh", lambda kind, inputs, mul_impl, plan, sp: ("mesh", plan)
+        sharding,
+        "run_chunk_mesh",
+        lambda kind, inputs, impl, mul_impl, plan, sp: ("mesh", plan),
     )
     pks, msgs, sigs = _raw_lanes(3)
     inputs, _ = ed25519_batch.prepare_batch(pks, msgs, sigs)
