@@ -25,6 +25,8 @@ under the name of a device metric.
 
 from __future__ import annotations
 
+import collections
+import json
 import os
 import sys
 
@@ -59,8 +61,23 @@ def test_files():
             check(m["moves"] in e2e, "%s moves an unknown metric" % m["name"])
             check(set(m.get("workloads", cells)) <= cells, "%s lists an unknown cell" % m["name"])
             if s is real:
+                # an entry without ``workloads`` is reported by every cell that reports its ``moves``
                 for key in ("layer", "unit", "better", "source", "moves", "workloads"):
-                    check(doc[key] == m[key], "%s: %s differs between BENCHMARK.json and its file" % (m["name"], key))
+                    check(doc.get(key) == m.get(key), "%s: %s differs between BENCHMARK.json and its file" % (m["name"], key))
+    # the converse: no file without its entry
+    files = {f[: -len(".json")] for f in os.listdir(os.path.join(spec.HERE, "layer_metrics"))}
+    orphans = files - {m["name"] for m in real.doc["per_layer"]}
+    check(not orphans, "layer_metrics/ holds files no entry names: %s" % sorted(orphans))
+    # merging, renaming or sharing entries loses no cell a definition
+    # it reported: reader and arguments letter for letter, under any name
+    frozen = spec.load_json(os.path.join(spec.HERE, "testdata", "definitions_at_pr36.json"))
+    for cell, ids in frozen["cells"].items():
+        got = collections.Counter(
+            json.dumps([spec.layer_metric(m["name"])[key] for key in frozen["keys"]], sort_keys=True)
+            for m in real.metrics_for("per_layer", cell)
+        )
+        want = collections.Counter(json.dumps(frozen["definitions"][i], sort_keys=True) for i in ids)
+        check(not want - got, "%s no longer reports %s" % (cell, sorted((want - got).elements())))
     peaks = spec.load_json(os.path.join(spec.HERE, "peaks.json"))
     for kind, row in peaks.items():
         check(row["source"] and row["bf16_flops_per_s"] > 0 and row["hbm_bytes_per_s"] > 0, kind)
