@@ -25,8 +25,14 @@ jax, but touches no device).
   ``ops.verify_batch`` call on ZIP-215 edge vectors; then two blocksync
   windows of 16 commits at 500 validators through
   ``parallel/pipeline.verify_commits_pipelined`` (one sound, one with a
-  block tampered on both sides of its early exit). Verdicts are
-  compared with the host oracle (``crypto/ed25519_ref.py``). Where more
+  block tampered on both sides of its early exit); then sr25519 lanes
+  (valid, tampered R, tampered s, ``s >= L``, a non-canonical ``A``)
+  through ``ops/sr25519_batch.verify_batch_sr`` at each of its kernel's
+  four buckets, and a 150-validator committee of ed25519, sr25519 and
+  secp256k1 keys through ``verify_commit``, sound and with one lane of
+  each type tampered. Verdicts are compared with the host oracles
+  (``crypto/ed25519_ref.py``, ``crypto/sr25519.verify``, the keys' own
+  ``verify_signature``). Where more
   than one chip is present the edge vectors also go through the sharded
   path (``parallel/sharding.verify_batch_sharded``), which under
   ``pallas`` runs the Pallas kernel per shard from a stored lowered
@@ -48,7 +54,7 @@ store and (on more than one chip) sharding must have served lanes where
 ``auto`` turns them on; server ``host_direct_lanes`` /
 ``admission_rejections`` / ``deadline_expired`` and client
 ``fallback_calls`` all zero. Python warnings are errors in every
-process. sr25519 and the mixed committee: not run.
+process.
 
 Exit code 0 and, as the last line of stdout, one JSON object
 ``{"ok": true, "device": {...}}`` only if every phase passed. Any
@@ -81,6 +87,8 @@ SIZES = (150, 10_000)
 HEIGHTS = 3  # heights verified over the same set after the cold pass
 SYNC_VALS = 500  # the blocksync window: BASELINE.json config 4's committee
 SYNC_WINDOW = 16  # blocksync/syncer.DEFAULT_VERIFY_WINDOW
+SR_BUCKETS = (64, 256, 1024, 4096)  # every width an sr25519 chunk is padded to
+MIXED_VALS = 150  # BASELINE.json config 5's three key types at config 2's size
 SERVED_VALS = 150
 CLIENTS = 4
 REQUESTS_PER_CLIENT = 3
@@ -298,7 +306,7 @@ def _compiles(spans: list, impl: str) -> list:
     kernel (parallel/sharding.py) two more: the devices, and what the
     kernel store did (``hit`` | ``miss``). The legacy, table and
     resident kernels must be the implementation ``auto`` resolved to, on
-    one device and per shard of a mesh. A mesh's XLA-graph kernels
+    one device and per shard of a mesh, and so must sr25519's. A mesh's XLA-graph kernels
     record no ``kernel_compile`` span (they show under ``sharded``)."""
     out = []
     for e in spans:
@@ -306,7 +314,7 @@ def _compiles(spans: list, impl: str) -> list:
             continue
         a = e["args"]
         ran = "pallas" if a.get("engine") == "pallas" else "xla"
-        if a.get("kernel") in ("verify", "verify_tables", "verify_resident"):
+        if a.get("kernel") in ("verify", "verify_tables", "verify_resident", "verify_sr"):
             check(
                 ran == ("pallas" if impl == "pallas" else "xla"),
                 "active_impl() is %r but the %s kernel at %s lanes ran %r",
@@ -631,9 +639,136 @@ def _run_pipelined_windows(n: int, window: int, paths: dict, impl: str) -> dict:
     }
 
 
+def _sr25519_lanes(n: int):
+    """n sr25519 lanes over 40 signatures made once, every other one
+    broken, one of four ways in turn (a flipped R bit, a flipped s bit,
+    s + L under the marker bit, an odd and so non-canonical A)."""
+    from tendermint_tpu.crypto import ristretto
+    from tendermint_tpu.crypto.sr25519 import Sr25519PrivKey
+
+    lanes = []
+    for i in range(40):
+        priv = Sr25519PrivKey.from_secret(b"chip-smoke sr25519 %d" % i)
+        msg = b"chip-smoke sr25519 vote %d" % i
+        pub, sig = priv.pub_key().bytes(), bytearray(priv.sign(msg))
+        if i % 8 == 1:
+            sig[3] ^= 0x01
+        elif i % 8 == 3:
+            sig[32] ^= 0x01
+        elif i % 8 == 5:
+            s = (int.from_bytes(sig[32:], "little") & ((1 << 255) - 1)) + ristretto.L
+            sig[32:] = (s | 1 << 255).to_bytes(32, "little")
+        elif i % 8 == 7:
+            pub = bytes([pub[0] | 1]) + pub[1:]
+        lanes.append((pub, msg, bytes(sig)))
+    return [list(col) for col in zip(*(lanes[(i * 7) % 40] for i in range(n)))]
+
+
+def _run_sr25519(n: int, impl: str) -> dict:
+    """One full bucket of sr25519 lanes through the engine, against the
+    host schnorrkel oracle lane for lane."""
+    from tendermint_tpu.crypto.sr25519 import verify as verify_sr
+    from tendermint_tpu.ops import sr25519_batch
+
+    pks, msgs, sigs = _sr25519_lanes(n)
+    _drain_spans()
+    before = _counters()
+    verdicts = sr25519_batch.verify_batch_sr(pks, msgs, sigs)
+    oracle = {}
+    for lane in zip(pks, msgs, sigs):
+        if lane not in oracle:
+            oracle[lane] = verify_sr(*lane)
+    wrong = [i for i, lane in enumerate(zip(pks, msgs, sigs)) if verdicts[i] != oracle[lane]]
+    check(not wrong, "sr25519 at %d lanes: device and oracle disagree on lanes %r", n, wrong[:16])
+    check(
+        set(oracle.values()) == {True, False},
+        "sr25519 at %d lanes: the oracle gave one verdict for every lane", n,
+    )
+    spans = _drain_spans()
+    _check_dispatch(spans, {"sr25519": n}, "sr25519 at %d lanes" % n)
+    _check_health(_delta(before), "sr25519 at %d lanes" % n)
+    return {
+        "lanes": n,
+        "accepted": int(sum(map(bool, verdicts))),
+        "compiles": _compiles(spans, impl),
+    }
+
+
+def _run_mixed(n: int, impl: str) -> dict:
+    """A committee of the three key types (n // 15 secp256k1 keys, the
+    rest halves) through verify_commit: sound, then with one lane of
+    each type tampered, the first blamed and every lane's verdict its
+    own key's. The secp256k1 lanes are the host's by design
+    (``host_lanes``); nothing else may be."""
+    from bench.workload import load_helpers
+    from tendermint_tpu.crypto import batch as crypto_batch
+    from tendermint_tpu.types import validation
+
+    helpers = load_helpers()
+    n_secp = n // 15
+    n_ed = (n - n_secp) // 2
+    privs, vset = helpers.make_mixed_validators(n_ed, n - n_secp - n_ed, n_secp)
+    block_id = helpers.make_block_id(b"chip-smoke-mixed-%d-%d" % (SEED, n))
+    commit = helpers.make_commit(block_id, 1, 0, vset, privs)
+    types = [v.pub_key.type for v in vset.validators]
+    sent = {kt: types.count(kt) for kt in set(types)}
+    _fresh_node()
+    _drain_spans()
+    before = _counters()
+    validation.verify_commit(helpers.CHAIN_ID, vset, block_id, 1, commit)
+    spans = _drain_spans()
+    names = {e["name"] for e in spans}
+    check(
+        not names & {"single_verify", "host_fallback"},
+        "mixed committee: the commit left the batch path: %r", sorted(names),
+    )
+    host = {e["args"]["key_type"]: e["args"]["lanes"] for e in spans if e["name"] == "host_lanes"}
+    check(host == {"secp256k1": n_secp}, "mixed committee: host lanes %r", host)
+    by_engine = {}
+    for e in spans:
+        if e["name"] == "dispatch_chunk":
+            eng = e["args"]["engine"]
+            by_engine[eng] = by_engine.get(eng, 0) + int(e["args"]["lanes"])
+    check(
+        by_engine == {kt: sent[kt] for kt in ("ed25519", "sr25519")},
+        "mixed committee: lanes dispatched by engine %r != lanes sent %r", by_engine, sent,
+    )
+    # one lane of each type tampered: the first is blamed, and every
+    # lane's verdict is its own key's
+    bad = sorted(types.index(kt) + 1 for kt in sent)
+    for idx in bad:
+        sig = bytearray(commit.signatures[idx].signature)
+        sig[40] ^= 0x01
+        commit.signatures[idx].signature = bytes(sig)
+    try:
+        validation.verify_commit(helpers.CHAIN_ID, vset, block_id, 1, commit)
+    except validation.InvalidCommitError as exc:
+        check("(#%d)" % bad[0] in str(exc), "mixed committee: blame %s, want lane %d", exc, bad[0])
+    else:
+        check(False, "mixed committee: a commit with three tampered lanes was accepted")
+    bv = crypto_batch.MultiBatchVerifier()
+    lanes = [
+        (v.pub_key, commit.vote_sign_bytes(helpers.CHAIN_ID, i), commit.signatures[i].signature)
+        for i, v in enumerate(vset.validators)
+    ]
+    for lane in lanes:
+        bv.add(*lane)
+    _, verdicts = bv.verify()
+    wrong = [i for i, (pk, m, sg) in enumerate(lanes) if verdicts[i] != pk.verify_signature(m, sg)]
+    check(not wrong, "mixed committee: device and oracles disagree on lanes %r", wrong[:16])
+    check(
+        [i for i, ok in enumerate(verdicts) if not ok] == bad,
+        "mixed committee: refused lanes %r, tampered %r",
+        [i for i, ok in enumerate(verdicts) if not ok], bad,
+    )
+    spans += _drain_spans()
+    _check_health(_delta(before), "mixed committee")
+    return {"validators": n, "sent": sent, "tampered": bad, "compiles": _compiles(spans, impl)}
+
+
 def library_phase(
     expect_platform: str, sizes=SIZES, heights: int = HEIGHTS,
-    sync=(SYNC_VALS, SYNC_WINDOW),
+    sync=(SYNC_VALS, SYNC_WINDOW), sr_buckets=SR_BUCKETS, mixed: int = MIXED_VALS,
 ) -> dict:
     """The library phase, in this process. Raises SmokeFailure."""
     from tendermint_tpu.ops import backend as ops_backend
@@ -731,6 +866,16 @@ def library_phase(
             say("  counters %(counters)r" % rep)
             say("  compiled %(compiles)r" % rep)
 
+        report["sr25519"] = [_run_sr25519(n, impl) for n in sr_buckets]
+        for rep in report["sr25519"]:
+            say("sr25519: %(lanes)d lanes, %(accepted)d accepted, as the oracle; compiled %(compiles)r" % rep)
+        report["mixed_committee"] = _run_mixed(mixed, impl) if mixed else None
+        if mixed:
+            say(
+                "mixed committee: %(validators)d validators %(sent)r, lanes %(tampered)r "
+                "tampered and refused; compiled %(compiles)r" % report["mixed_committee"]
+            )
+
         snap = health.snapshot()
         check(snap["state"] == HEALTHY, "device health ended %r", snap["state"])
         tuned = autotune.stats()
@@ -739,7 +884,6 @@ def library_phase(
             "autotune selections: %(selections)r timings_ms %(timings_ms)r"
             % report["autotune"]
         )
-        report["sr25519"] = report["mixed_committee"] = "not run"
         return report
     finally:
         tracing.configure(prev_mode)

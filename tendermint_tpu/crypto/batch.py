@@ -1,8 +1,9 @@
 """Batch-verifier dispatch.
 
 Mirrors crypto/batch/batch.go:11-33: only key types with batch support
-(ed25519, sr25519) get a batch verifier; callers fall back to
-one-at-a-time verification otherwise. The ed25519 batch verifier routes
+(ed25519, sr25519) get a batch verifier; in a mixed set the lanes of
+any other type (secp256k1) are verified on the host beside them
+(MultiBatchVerifier). The ed25519 batch verifier routes
 to the TPU engine (tendermint_tpu.ops) above a size threshold and to the
 host oracle below it.
 """
@@ -229,17 +230,51 @@ def create_batch_verifier(pub_key: PubKey) -> BatchVerifier:
     raise ValueError(f"key type {pub_key.type} does not support batching")
 
 
+class HostLanesVerifier(BatchVerifier):
+    """The lanes of one key type that has no batch verifier
+    (secp256k1): each is verified on the host by its key's own
+    ``verify_signature``, under the ``host_lanes`` span. Host by design
+    and on every call: not the health machine's ``host_fallback``,
+    which says that a device failed."""
+
+    def __init__(self, key_type: str):
+        self.key_type = key_type
+        self._lanes: List[Tuple[PubKey, bytes, bytes]] = []
+
+    def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> None:
+        if pub_key.type != self.key_type:
+            raise ValueError(f"{self.key_type} host lanes got {pub_key.type} key")
+        self._lanes.append((pub_key, msg, sig))
+
+    def __len__(self) -> int:
+        return len(self._lanes)
+
+    def verify(self) -> Tuple[bool, List[bool]]:
+        if not self._lanes:
+            return False, []
+        n = len(self._lanes)
+        # batch_verify as every sub-verifier opens it; host_lanes says
+        # that these lanes are the host's by design
+        with tracing.span(
+            "batch_verify", key_type=self.key_type, lanes=n, route="host"
+        ), tracing.span("host_lanes", key_type=self.key_type, lanes=n):
+            oks = [bool(pk.verify_signature(msg, sig)) for pk, msg, sig in self._lanes]
+        return all(oks), oks
+
+
 class MultiBatchVerifier(BatchVerifier):
     """Per-key-type sub-batching for MIXED validator sets.
 
-    A 10k-validator commit with ed25519 AND sr25519 signers (BASELINE
-    config 5) splits into one sub-verifier per key type — each riding
-    its own device kernel — and the verdicts merge back in submission
-    order. Key types with no batch support (secp256k1) raise on ``add``,
-    which validation's caller answers with the single-verify fallback,
-    the same contract create_batch_verifier has for an unsupported
-    proposer key (reference crypto/batch/batch.go:11-22 dispatches on
-    ONE key type; this is the mixed-set generalisation)."""
+    A 10k-validator commit with ed25519, sr25519 AND secp256k1 signers
+    (BASELINE config 5) splits into one sub-verifier per key type — the
+    two that batch each riding its own device kernel, a type with no
+    batch support (secp256k1) on the host, lane by lane
+    (:class:`HostLanesVerifier`) — and the verdicts merge back in
+    submission order. The sub-verifiers run one after the other: the
+    device's by their type's name, then the host lanes, whatever seat
+    each type first appeared in, so that a call's shape does not depend
+    on the set's address order (reference crypto/batch/batch.go:11-22
+    dispatches on ONE key type; this is the mixed-set generalisation)."""
 
     def __init__(self):
         self._subs: dict = {}
@@ -249,7 +284,11 @@ class MultiBatchVerifier(BatchVerifier):
         kt = pub_key.type
         sub = self._subs.get(kt)
         if sub is None:
-            sub = self._subs[kt] = create_batch_verifier(pub_key)
+            if supports_batch_verifier(pub_key):
+                sub = create_batch_verifier(pub_key)
+            else:
+                sub = HostLanesVerifier(kt)
+            self._subs[kt] = sub
         sub.add(pub_key, msg, sig)
         self._order.append((kt, len(sub) - 1))
 
@@ -260,9 +299,10 @@ class MultiBatchVerifier(BatchVerifier):
         if not self._order:
             return False, []  # same empty contract as every BatchVerifier
         results = {}
-        for kt, sub in self._subs.items():
-            _, oks = sub.verify()
-            results[kt] = oks
+        for kt in sorted(
+            self._subs, key=lambda kt: (isinstance(self._subs[kt], HostLanesVerifier), kt)
+        ):
+            _, results[kt] = self._subs[kt].verify()
         with tracing.span("merge_verdicts", lanes=len(self._order)):
             merged = [bool(results[kt][i]) for kt, i in self._order]
             return all(merged), merged

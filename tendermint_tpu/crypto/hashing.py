@@ -9,7 +9,10 @@ k = SHA-512(R || A || M) mod L: the same extension reduces each digest
 mod the ed25519 group order L in the loop that made it, so no
 per-signature Python or NumPy arithmetic sits on the hot path. The
 definition every path is held to is ``int.from_bytes(digest, "little")
-% L`` (:func:`reduce_mod_l_int`).
+% L`` (:func:`reduce_mod_l_int`). ``sr25519_challenges_mod_l`` is the
+sr25519 verifier's challenge, a Merlin transcript a lane
+(``native/merlin_batch.c``, in the same library; ``crypto/merlin.py`` is
+the definition it is held to).
 
 Reference analog: the challenge hashing inside curve25519-voi's batch
 verifier (crypto/ed25519/ed25519.go:198-233).
@@ -43,7 +46,12 @@ _SYMBOLS = {
     "sha512_batch": [_U8P, _U64P, ctypes.c_int64, _U8P],
     "sha512_batch_prefixed_mod_l": [_U8P, _U8P, _U64P, ctypes.c_int64, _U8P],
     "reduce512_mod_l": [_U8P, ctypes.c_int64, _U8P],
+    "sr25519_challenges_mod_l": [_U8P, _U8P, _U8P, _U64P, ctypes.c_int64, _U8P],
 }
+
+# The library's translation units, in the order they are hashed and
+# handed to the compiler.
+_SOURCES = ("sha512_batch.c", "merlin_batch.c")
 
 
 def _load(path: str) -> Optional[ctypes.CDLL]:
@@ -63,8 +71,8 @@ def _load(path: str) -> Optional[ctypes.CDLL]:
 def _build_and_load() -> Optional[ctypes.CDLL]:
     """Compile the C extension once per machine and source, and load it.
 
-    The library is named by a hash of its source, so checkouts that
-    differ in ``sha512_batch.c`` (a parent commit and its change run in
+    The library is named by a hash of its sources, so checkouts that
+    differ in one of ``_SOURCES`` (a parent commit and its change run in
     turn on one machine) never load each other's build. It is compiled
     under a name of this process's own, loaded and checked from there,
     and only then renamed into place: processes that start together
@@ -72,12 +80,16 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
     with (no source, or neither ``cc`` nor ``gcc``: the hashlib path).
     A build that should have worked and did not raises.
     """
-    src = os.path.join(os.path.dirname(os.path.dirname(__file__)), "native", "sha512_batch.c")
+    native = os.path.join(os.path.dirname(os.path.dirname(__file__)), "native")
+    srcs = [os.path.join(native, name) for name in _SOURCES]
     cc = shutil.which("cc") or shutil.which("gcc")  # never g++: it mangles the names
-    if not os.path.exists(src) or cc is None:
+    if not all(os.path.exists(src) for src in srcs) or cc is None:
         return None
-    with open(src, "rb") as f:
-        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    h = hashlib.sha256()
+    for src in srcs:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    digest = h.hexdigest()[:12]
     build_dir = os.environ.get(
         "TENDERMINT_TPU_BUILD_DIR",
         os.path.join(tempfile.gettempdir(), "tendermint_tpu_native"),
@@ -93,17 +105,17 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
     os.close(fd)
     try:
         proc = subprocess.run(
-            [cc, "-O3", "-shared", "-fPIC", "-fopenmp", src, "-o", tmp],
+            [cc, "-O3", "-shared", "-fPIC", "-fopenmp", *srcs, "-o", tmp],
             capture_output=True,
             timeout=120,
         )
         if proc.returncode != 0:
             raise RuntimeError(
-                f"{cc} could not build {src}: {proc.stderr.decode(errors='replace')[-2000:]}"
+                f"{cc} could not build {srcs}: {proc.stderr.decode(errors='replace')[-2000:]}"
             )
         lib = _load(tmp)
         if lib is None:
-            raise RuntimeError(f"{cc} built {src} into a library that lacks one of {sorted(_SYMBOLS)}")
+            raise RuntimeError(f"{cc} built {srcs} into a library that lacks one of {sorted(_SYMBOLS)}")
         os.chmod(tmp, 0o755)  # mkstemp made it the owner's alone
         os.replace(tmp, lib_path)
     finally:
@@ -208,6 +220,38 @@ def reduce512_mod_l(digests: np.ndarray) -> np.ndarray:
             out[i] = np.frombuffer(reduce_mod_l_int(digests[i].tobytes()), dtype=np.uint8)
     else:
         lib.reduce512_mod_l(_ptr(np.ascontiguousarray(digests)), n, _ptr(out))
+    return out
+
+
+def sr25519_challenges_mod_l(
+    pubs: np.ndarray, rs: np.ndarray, msgs: Sequence[bytes]
+) -> np.ndarray:
+    """The schnorrkel challenge scalars of N lanes, one call: (N, 32)
+    uint8 public keys and R encodings and the N messages -> (N, 32)
+    uint8 little-endian, the 64-byte ``sign:c`` challenge of the Merlin
+    signing transcript (empty context) mod L — what
+    ``crypto/sr25519._challenge(_signing_transcript(msg), pub, r)``
+    gives lane by lane, and what computes it where there is no compiler."""
+    n = len(msgs)
+    for arr in (pubs, rs):
+        if arr.shape != (n, 32) or arr.dtype != np.uint8:
+            raise ValueError(f"keys and R must be ({n}, 32) uint8, got {arr.shape} {arr.dtype}")
+    out = np.empty((n, 32), dtype=np.uint8)
+    if n == 0:
+        return out
+    pubs, rs = np.ascontiguousarray(pubs), np.ascontiguousarray(rs)
+    lib = _lib()
+    if lib is None:
+        from tendermint_tpu.crypto.sr25519 import _challenge, _signing_transcript
+
+        for i, m in enumerate(msgs):
+            k = _challenge(_signing_transcript(m), pubs[i].tobytes(), rs[i].tobytes())
+            out[i] = np.frombuffer(k.to_bytes(32, "little"), dtype=np.uint8)
+        return out
+    buf, offsets = _pack(msgs)
+    lib.sr25519_challenges_mod_l(
+        _ptr(pubs), _ptr(rs), _ptr(buf), _ptr(offsets, _U64P), n, _ptr(out)
+    )
     return out
 
 

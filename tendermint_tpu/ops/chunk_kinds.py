@@ -39,6 +39,10 @@ class ChunkKind:
     kernel: Callable  # the XLA graph
     pallas: Optional[str]  # entry point in ops/pallas_verify, if it has one
     inputs: Tuple[ChunkInput, ...]  # in the kernel's argument order
+    # what the jitted function is called, so the program's name on a
+    # device trace (``jit_<program>``): ``run*`` is what the
+    # benchmark's kernel metrics match
+    program: str = "run"
 
     @property
     def store_bound(self) -> bool:

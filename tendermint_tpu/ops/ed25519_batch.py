@@ -422,6 +422,7 @@ def _compiled_kernel(kind: ChunkKind, n: int, backend: Optional[str], mul_impl: 
         with field.pinned_mul_impl(mul_impl):
             return kind.kernel(*args)
 
+    run.__name__ = kind.program
     return introspect.traced_first_call(
         jax.jit(run, backend=backend), kind.engine, kind.kernel_name, n
     )
@@ -448,7 +449,8 @@ _IMPL_ENV = "TENDERMINT_TPU_VERIFY_IMPL"
 # vehicle, far too slow for real batches). ``pallas`` covers legacy,
 # gathered-table and resident-store chunks, on one device and per shard
 # of a mesh (parallel/sharding.py; PR 36's chip run); a kind without a
-# Pallas entry point (sr25519) runs its XLA graph whatever this says.
+# Pallas entry point runs its XLA graph whatever this says (every kind
+# has one since the sr25519 kernel of PR 40).
 _AUTO_IMPL = {"tpu": "pallas", "cpu": "xla"}
 # Device-vs-host fallback state lives in ops/device_policy.py, shared
 # with the sr25519 engine so a broken backend is broken once.

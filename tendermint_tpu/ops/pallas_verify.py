@@ -24,13 +24,15 @@ table are MXU matmuls (exact: both operands are small integers); the
 per-lane table lives in an ``(8, 128, block)`` VMEM scratch — half the
 footprint and half the select bandwidth of the unsigned scheme.
 
-Three entry points: :func:`compiled_verify` builds the lane tables
-in-kernel; :func:`compiled_verify_tables` takes the gathered
+Four entry points: :func:`compiled_verify` builds the lane tables
+in-kernel; :func:`compiled_verify_sr` is its sr25519 twin (ristretto
+DECODE in place of decompression, no cofactor doublings, the identity's
+coset as the accept test; ops/sr25519_batch.py); :func:`compiled_verify_tables` takes the gathered
 ``(8, 4, 32, N)`` table input from the validator-set precompute cache
 (ops/precompute.py) and skips decompression of A and the table build;
 :func:`compiled_verify_resident` gathers that input on the device from
 the resident store (ops/resident.py) and runs the same table kernel.
-A mesh runs the same three per shard (:func:`stored_shard_program`,
+A mesh runs the same four per shard (:func:`stored_shard_program`,
 parallel/sharding.py), from a lowered program kept by
 ops/kernel_store.py, so that only the process that first meets a slab
 shape walks the kernel body.
@@ -214,6 +216,16 @@ def fe_is_zero(a: Fe) -> jnp.ndarray:
     return _tight_is_zero(fe_tight(a))
 
 
+def _tight_parity(t: Fe) -> jnp.ndarray:
+    """(1, n) f32 in {0, 1}: the low bit of the canonical value of a
+    tight element (t in [0, 3p): each p taken off flips limb 0's)."""
+    k = _ge_const(t, field32._P_LIMBS).astype(jnp.float32) + _ge_const(
+        t, field32._2P_LIMBS
+    ).astype(jnp.float32)
+    pv = t[0:1] + k
+    return pv - 2.0 * jnp.floor(pv * 0.5)
+
+
 def fe_select(cond: jnp.ndarray, a: Fe, b: Fe) -> Fe:
     """cond: (1, n) bool."""
     return jnp.where(cond, a, b)
@@ -344,13 +356,52 @@ def pt_decompress(
     xt = fe_tight(x)
     x_is_zero = _tight_is_zero(xt)
     valid = on_curve & ~(x_is_zero & (sign == 1.0))
-    k = _ge_const(xt, field32._P_LIMBS).astype(jnp.float32) + _ge_const(
-        xt, field32._2P_LIMBS
-    ).astype(jnp.float32)
-    pv = xt[0:1] + k
-    parity = pv - 2.0 * jnp.floor(pv * 0.5)
-    x = fe_select(parity != sign, fe_neg(x), x)
+    x = fe_select(_tight_parity(xt) != sign, fe_neg(x), x)
     pt: Point = (x, y, one, fe_mul(x, y))
+    ident = pt_identity(n)
+    sel = lambda a, b: fe_select(valid, a, b)
+    return tuple(map(sel, pt, ident)), valid  # type: ignore[return-value]
+
+
+def _abs(a: Fe) -> Fe:
+    """The non-negative one of a and -a (RFC 9496: even canonical value)."""
+    return fe_select(_tight_parity(fe_tight(a)) == 1.0, fe_neg(a), a)
+
+
+def ristretto_decode(
+    s: Fe, d_fe: jnp.ndarray, sqrtm1_fe: jnp.ndarray
+) -> Tuple[Point, jnp.ndarray]:
+    """RFC 9496 4.3.1 DECODE (sr25519_batch.ristretto_decompress
+    semantics): the (32, n) limbs of an encoding the host has checked
+    canonical (< p) and non-negative (even) -> (point, (1, n) valid);
+    invalid lanes hold the identity."""
+    n = s.shape[1]
+    one = pt_identity(n)[1]
+    ss = fe_sq(s)
+    u1 = fe_sub(one, ss)
+    u2 = fe_add(one, ss)
+    u2s = fe_sq(u2)
+    # v = -(d * u1^2) - u2^2
+    v = fe_sub(fe_neg(fe_mul_col(fe_sq(u1), d_fe)), u2s)
+    # SQRT_RATIO_M1(1, w) for w = v * u2^2: r = w^3 * (w^7)^((p-5)/8)
+    w = fe_mul(v, u2s)
+    w3 = fe_mul(fe_sq(w), w)
+    w7 = fe_mul(fe_sq(w3), w)
+    r = fe_mul(w3, fe_pow22523(w7))
+    check = fe_mul(w, fe_sq(r))
+    correct = fe_is_zero(fe_sub(check, one))
+    flipped = fe_is_zero(fe_add(check, one))
+    flipped_i = fe_is_zero(fe_add(check, jnp.broadcast_to(sqrtm1_fe, check.shape)))
+    r = fe_select(flipped | flipped_i, fe_mul_col(r, sqrtm1_fe), r)
+    was_square = correct | flipped
+    r = _abs(r)
+    den_x = fe_mul(r, u2)
+    den_y = fe_mul(fe_mul(r, den_x), v)
+    x = _abs(fe_mul(fe_add(s, s), den_x))
+    y = fe_mul(u1, den_y)
+    t = fe_mul(x, y)
+    valid = was_square & (_tight_parity(fe_tight(t)) != 1.0) & ~fe_is_zero(y)
+    pt: Point = (x, y, one, t)
     ident = pt_identity(n)
     sel = lambda a, b: fe_select(valid, a, b)
     return tuple(map(sel, pt, ident)), valid  # type: ignore[return-value]
@@ -447,6 +498,22 @@ def _straus_loop(tab_ref, swin_ref, kwin_ref, byp, bym, bt2, n: int) -> Point:
     )
 
 
+def _build_lane_table(tab_ref, a_pt: Point, d2_c) -> None:
+    """Per-lane cached table of [1..8](-A) into the (8, 128, block)
+    VMEM scratch (row t holds (t+1)(-A); the identity for digit 0 is
+    synthesized at select)."""
+    neg_a = pt_neg(a_pt)
+    cp = pt_to_cached(neg_a, d2_c)
+    tab_ref[0] = _stack(cp)
+
+    def tbody(i, acc128):
+        nxt = pt_add_cached(_unstack(acc128), cp)
+        tab_ref[pl.ds(i, 1)] = _stack(pt_to_cached(nxt, d2_c))[None]
+        return _stack(nxt)
+
+    jax.lax.fori_loop(1, 8, tbody, _stack(neg_a), unroll=False)
+
+
 def _verify_kernel(
     ay_ref,
     asign_ref,
@@ -478,19 +545,7 @@ def _verify_kernel(
     r_pt = tuple(c[:, n:] for c in pt2)
     a_ok, r_ok = ok2[:, :n], ok2[:, n:]
 
-    # Per-lane cached table of [1..8](-A) in VMEM scratch (row t holds
-    # (t+1)(-A); the identity for digit 0 is synthesized at select).
-    neg_a = pt_neg(a_pt)
-    cp = pt_to_cached(neg_a, d2_c)
-    tab_ref[0] = _stack(cp)
-
-    def tbody(i, acc128):
-        nxt = pt_add_cached(_unstack(acc128), cp)
-        tab_ref[pl.ds(i, 1)] = _stack(pt_to_cached(nxt, d2_c))[None]
-        return _stack(nxt)
-
-    jax.lax.fori_loop(1, 8, tbody, _stack(neg_a), unroll=False)
-
+    _build_lane_table(tab_ref, a_pt, d2_c)
     byp = byp_ref[:, :].T  # (32, 8)
     bym = bym_ref[:, :].T
     bt2 = bt2_ref[:, :].T
@@ -499,6 +554,43 @@ def _verify_kernel(
     for _ in range(3):
         acc = pt_double(acc)
     ok = pt_is_identity(acc) & a_ok & r_ok
+    out_ref[:, :] = ok.astype(jnp.float32)
+
+
+def _verify_sr_kernel(
+    a_ref,
+    r_ref,
+    swin_ref,
+    kwin_ref,
+    byp_ref,
+    bym_ref,
+    bt2_ref,
+    consts_ref,
+    out_ref,
+    tab_ref,
+):
+    """One lane-block of schnorrkel verification: ristretto-decode A
+    and R, build the [1..8](-A) table, the same Straus loop, and
+    [s]B - [k]A - R in the identity's coset (X = 0 or Y = 0; no
+    cofactor doublings: ristretto255 is the quotient)."""
+    n = a_ref.shape[1]
+    d_c = consts_ref[:, 0:1]
+    m1_c = consts_ref[:, 1:2]
+    d2_c = consts_ref[:, 2:3]
+    # Decode A and R as one 2n-wide batch, as the ed25519 kernel does.
+    pt2, ok2 = ristretto_decode(
+        jnp.concatenate([a_ref[:, :], r_ref[:, :]], axis=1), d_c, m1_c
+    )
+    a_pt = tuple(c[:, :n] for c in pt2)
+    r_pt = tuple(c[:, n:] for c in pt2)
+    a_ok, r_ok = ok2[:, :n], ok2[:, n:]
+    _build_lane_table(tab_ref, a_pt, d2_c)
+    byp = byp_ref[:, :].T  # (32, 8)
+    bym = bym_ref[:, :].T
+    bt2 = bt2_ref[:, :].T
+    acc = _straus_loop(tab_ref, swin_ref, kwin_ref, byp, bym, bt2, n)
+    x, y, _, _ = pt_add_cached(acc, pt_to_cached(pt_neg(r_pt), d2_c))
+    ok = (fe_is_zero(x) | fe_is_zero(y)) & a_ok & r_ok
     out_ref[:, :] = ok.astype(jnp.float32)
 
 
@@ -617,6 +709,47 @@ def verify_fn(pk_bytes, r_bytes, s_bytes, k_bytes, *, block: int, interpret: boo
         scratch_shapes=[pltpu.VMEM((8, 4 * NLIMBS, block), jnp.float32)],
         interpret=interpret,
     )(a_y, a_sign, r_y, r_sign, s_win, k_win, byp, bym, bt2, _CONSTS)
+    return out[0] != 0.0
+
+
+def verify_sr_fn(pk_bytes, r_bytes, s_bytes, k_bytes, *, block: int, interpret: bool):
+    """sr25519: (N, 32) uint8 x4 -> (N,) bool. N must be a multiple of
+    block. A and R enter as the 32 limbs of their ristretto encodings
+    (no sign bit to strip: the host refuses an encoding >= p or odd),
+    s with its marker bit cleared, k the Merlin challenge mod L."""
+    n = pk_bytes.shape[0]
+    s_win = _to_windows_signed(s_bytes)
+    k_win = _to_windows_signed(k_bytes)
+    byp, bym, bt2 = _b_tables()
+    lane_spec = lambda rows: pl.BlockSpec((rows, block), lambda i: (0, i))
+    const_spec = pl.BlockSpec((8, NLIMBS), lambda i: (0, 0))
+    out = pl.pallas_call(
+        _verify_sr_kernel,
+        grid=(n // block,),
+        in_specs=[
+            lane_spec(32),
+            lane_spec(32),
+            lane_spec(64),
+            lane_spec(64),
+            const_spec,
+            const_spec,
+            const_spec,
+            pl.BlockSpec((NLIMBS, 3), lambda i: (0, 0)),
+        ],
+        out_specs=lane_spec(1),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((8, 4 * NLIMBS, block), jnp.float32)],
+        interpret=interpret,
+    )(
+        pk_bytes.astype(jnp.float32).T,
+        r_bytes.astype(jnp.float32).T,
+        s_win,
+        k_win,
+        byp,
+        bym,
+        bt2,
+        _CONSTS,
+    )
     return out[0] != 0.0
 
 
@@ -748,6 +881,20 @@ def compiled_verify_resident(n: int, block: int = BLOCK, interpret: bool = False
     )
 
 
+@lru_cache(maxsize=8)
+def compiled_verify_sr(n: int, block: int = BLOCK, interpret: bool = False):
+    """Jitted sr25519 verify for a fixed padded batch size n. The
+    program is called ``run_sr25519`` (``SR25519.program``), which tells
+    it from the ed25519 entry points' ``_lambda_`` on a device trace."""
+    blk = min(block, n)
+    assert n % blk == 0, (n, blk)
+
+    def run_sr25519(pk, r, s, k):
+        return verify_sr_fn(pk, r, s, k, block=blk, interpret=interpret)
+
+    return _trace_first_call(jax.jit(run_sr25519), "verify_sr", n)
+
+
 # --- the per-shard program of a mesh ----------------------------------------
 
 # What each entry point jits, by the entry point's name (a ChunkKind's
@@ -756,6 +903,7 @@ _SHARD_BODY = {
     "compiled_verify": "verify_fn",
     "compiled_verify_tables": "verify_tables_fn",
     "compiled_verify_resident": "verify_resident_fn",
+    "compiled_verify_sr": "verify_sr_fn",
 }
 
 

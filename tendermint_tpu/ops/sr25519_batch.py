@@ -151,6 +151,40 @@ def verify_kernel_sr(
 # --- host-side preparation --------------------------------------------------
 
 
+def _lane_arrays(
+    pubkeys: Sequence[bytes], sigs: Sequence[bytes]
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """``(pk, r, s, host_ok)``: the raw (n, 32) uint8 rows the kernel
+    takes (s with its marker bit cleared) and the (n,) bool structural
+    verdicts — lengths, the schnorrkel marker bit, s < L, and A and R
+    canonical (< p) and non-negative (even). Two joins where every lane
+    is well-formed, as every batch from commit verification is; an
+    ill-formed lane is a zero row that ``host_ok`` refuses."""
+    n = len(pubkeys)
+    if all(len(pk) == 32 and len(sg) == 64 for pk, sg in zip(pubkeys, sigs)):
+        pk_arr = np.frombuffer(b"".join(pubkeys), dtype=np.uint8).reshape(n, 32)
+        sig_arr = np.frombuffer(b"".join(sigs), dtype=np.uint8).reshape(n, 64)
+        host_ok = np.ones(n, dtype=bool)
+    else:
+        pk_arr = np.zeros((n, 32), dtype=np.uint8)
+        sig_arr = np.zeros((n, 64), dtype=np.uint8)
+        host_ok = np.zeros(n, dtype=bool)
+        for i, (pub, sig) in enumerate(zip(pubkeys, sigs)):
+            if len(pub) == 32 and len(sig) == 64:
+                pk_arr[i] = np.frombuffer(pub, dtype=np.uint8)
+                sig_arr[i] = np.frombuffer(sig, dtype=np.uint8)
+                host_ok[i] = True
+    r_arr = sig_arr[:, :32]
+    s_arr = sig_arr[:, 32:].copy()
+    host_ok &= (s_arr[:, 31] & 0x80) != 0  # the schnorrkel marker
+    s_arr[:, 31] &= 0x7F
+    host_ok &= canonical_lt(s_arr, _l_bytes_be())
+    for enc in (pk_arr, r_arr):
+        host_ok &= canonical_lt(enc, _P_BYTES_BE)
+        host_ok &= (enc[:, 0] & 1) == 0
+    return pk_arr, r_arr, s_arr, host_ok
+
+
 def verify_batch_sr(
     pubkeys: Sequence[bytes],
     msgs: Sequence[bytes],
@@ -160,79 +194,67 @@ def verify_batch_sr(
     """Per-entry schnorrkel batch verification on the device, host
     Merlin challenges. Chunks go through the dispatch loop shared with
     ed25519 (ops/ed25519_batch._run_jobs), double-buffered: the Merlin
-    transcript challenges of chunk j+1 — the expensive, sequential
-    host work on this path — are computed while the device crunches
+    transcript challenges of chunk j+1 — one call of the C extension a
+    chunk (crypto/hashing.sr25519_challenges_mod_l), under the
+    ``merlin_challenge`` span — are computed while the device crunches
     chunk j (JAX async dispatch), instead of hashing the whole batch
     up front. Device failure degrades per CHUNK to the host oracle
     under the process-wide health state machine shared with ed25519
     (ops/device_policy.py), which cools down, probes, and re-promotes
-    the device path by itself."""
-    from tendermint_tpu.crypto.sr25519 import (
-        _challenge,
-        _signing_transcript,
-        verify as verify_host,
-    )
-
-    health = device_policy.shared
+    the device path by itself. The call runs under the engines' common
+    ``verify_batch`` span; its lanes do not enter the verdict cache
+    (ops/precompute.results holds ed25519 verdicts only)."""
     n = len(pubkeys)
     if n == 0:
         return []
-    attempt = health.begin_attempt("sr25519")
-    if attempt is None:
-        health.count_fallback("sr25519", n)
-        with tracing.span(
-            "host_fallback", stage="fallback", engine="sr25519", lanes=n
-        ):
-            return [
-                verify_host(p, m, s) for p, m, s in zip(pubkeys, msgs, sigs)
-            ]
+    with tracing.span("verify_batch", engine="sr25519", lanes=n) as vsp:
+        vsp.process_cpu()
+        verdicts = _verify_lanes(pubkeys, msgs, sigs, backend)
+        with tracing.span("merge_results", lanes=n):
+            return verdicts.tolist()
 
-    host_ok = np.ones(n, dtype=bool)
-    pk_arr = np.zeros((n, 32), dtype=np.uint8)
-    r_arr = np.zeros((n, 32), dtype=np.uint8)
-    s_arr = np.zeros((n, 32), dtype=np.uint8)
-    for i, (pub, _msg, sig) in enumerate(zip(pubkeys, msgs, sigs)):
-        if len(pub) != 32 or len(sig) != 64 or not sig[63] & 0x80:
-            host_ok[i] = False
-            continue
-        pk_arr[i] = np.frombuffer(pub, dtype=np.uint8)
-        r_arr[i] = np.frombuffer(sig[:32], dtype=np.uint8)
-        s_raw = bytearray(sig[32:64])
-        s_raw[31] &= 0x7F
-        s_arr[i] = np.frombuffer(bytes(s_raw), dtype=np.uint8)
-    has_fields = host_ok.copy()  # lanes whose challenge is worth hashing
-    # scalar canonicity: s < L; encodings canonical (< p) and
-    # non-negative (even) for both A and R
-    host_ok &= canonical_lt(s_arr, _l_bytes_be())
-    for enc in (pk_arr, r_arr):
-        host_ok &= canonical_lt(enc, _P_BYTES_BE)
-        host_ok &= (enc[:, 0] & 1) == 0
 
-    def prep_job(job: _Job, pad_to: int) -> Tuple[dict, np.ndarray]:
-        """Merlin challenges + padding for one chunk's rows — the host
-        half of the double buffer."""
-        rows = job.rows
-        k_c = np.zeros((len(rows), 32), dtype=np.uint8)
-        for j, i in enumerate(rows):
-            if has_fields[i]:
-                k = _challenge(
-                    _signing_transcript(msgs[i]), pubkeys[i], sigs[i][:32]
-                )
-                k_c[j] = np.frombuffer(k.to_bytes(32, "little"), dtype=np.uint8)
-        inputs = dict(pk=pk_arr[rows], r=r_arr[rows], s=s_arr[rows], k=k_c)
-        return SR25519.pad_lanes(inputs, pad_to - len(rows)), host_ok[rows]
+def _verify_lanes(
+    pubkeys: Sequence[bytes],
+    msgs: Sequence[bytes],
+    sigs: Sequence[bytes],
+    backend: Optional[str],
+) -> np.ndarray:
+    from tendermint_tpu.crypto.hashing import sr25519_challenges_mod_l
+    from tendermint_tpu.crypto.sr25519 import verify as verify_host
 
     def host_verify(rows) -> np.ndarray:
         return np.array(
             [verify_host(pubkeys[i], msgs[i], sigs[i]) for i in rows], dtype=bool
         )
 
+    health = device_policy.shared
+    n = len(pubkeys)
+    attempt = health.begin_attempt("sr25519")
+    if attempt is None:
+        health.count_fallback("sr25519", n)
+        with tracing.span(
+            "host_fallback", stage="fallback", engine="sr25519", lanes=n
+        ):
+            return host_verify(range(n))
+
+    pk_arr, r_arr, s_arr, host_ok = _lane_arrays(pubkeys, sigs)
+
+    def prep_job(job: _Job, pad_to: int) -> Tuple[dict, np.ndarray]:
+        """Merlin challenges + padding for one chunk's rows — the host
+        half of the double buffer."""
+        rows = job.rows
+        pk_c, r_c = pk_arr[rows], r_arr[rows]
+        with tracing.span("merlin_challenge", lanes=len(rows)):
+            k_c = sr25519_challenges_mod_l(pk_c, r_c, [msgs[i] for i in rows])
+        inputs = dict(pk=pk_c, r=r_c, s=s_arr[rows], k=k_c)
+        return SR25519.pad_lanes(inputs, pad_to - len(rows)), host_ok[rows]
+
     plan, span = _mesh_span(n)
     jobs = [_Job(SR25519, rows) for rows in _chunk_rows(np.arange(n), span)]
-    verdicts = _run_jobs(
+    return _run_jobs(
         "sr25519", n, jobs, prep_job, host_verify, backend, plan, attempt
     )
-    return [bool(v) for v in verdicts]
 
 
 _PAD: Optional[Tuple[np.ndarray, ...]] = None
@@ -264,12 +286,14 @@ def _pad_entry() -> Tuple[np.ndarray, ...]:
     return _PAD
 
 
-# What the kernel takes, and its pad lanes (ops/chunk_kinds.py). No
-# Pallas entry point: on every platform it is the XLA graph.
+# What the kernel takes, and its pad lanes (ops/chunk_kinds.py). Its
+# programs, the XLA graph and the Pallas entry point alike, are called
+# ``run_sr25519``: the device trace tells them from ed25519's ``run``.
 SR25519 = ChunkKind(
-    "sr25519", "sr25519", "verify_sr", verify_kernel_sr, None,
+    "sr25519", "sr25519", "verify_sr", verify_kernel_sr, "compiled_verify_sr",
     tuple(
         ChunkInput(name, 0, lambda i=i: _pad_entry()[i])
         for i, name in enumerate(("pk", "r", "s", "k"))
     ),
+    program="run_sr25519",
 )

@@ -59,11 +59,12 @@ class InvalidCommitError(ValueError):
     pass
 
 
-def _should_batch_verify(vals: ValidatorSet, commit: Commit) -> bool:
-    """validation.go:14-16."""
-    return len(commit.signatures) >= BATCH_VERIFY_THRESHOLD and (
-        crypto_batch.supports_batch_verifier(vals.get_proposer().pub_key)
-    )
+def _should_batch_verify(commit: Commit) -> bool:
+    """validation.go:14-16, less its look at the proposer's key: the
+    batch path takes a set of any key types (MultiBatchVerifier puts
+    the lanes of a type that cannot batch on the host), so a secp256k1
+    proposer no longer sends a mixed commit to single verification."""
+    return len(commit.signatures) >= BATCH_VERIFY_THRESHOLD
 
 
 def verify_commit(
@@ -83,7 +84,7 @@ def verify_commit(
         voting_power_needed = vals.total_voting_power() * 2 // 3
         ignore = lambda c: c.block_id_flag == BLOCK_ID_FLAG_ABSENT
         count = lambda c: c.block_id_flag == BLOCK_ID_FLAG_COMMIT
-        if _should_batch_verify(vals, commit):
+        if _should_batch_verify(commit):
             return _verify_commit_batch(
                 chain_id, vals, commit, voting_power_needed, ignore, count,
                 True, True,
@@ -111,7 +112,7 @@ def verify_commit_light(
         voting_power_needed = vals.total_voting_power() * 2 // 3
         ignore = lambda c: c.block_id_flag != BLOCK_ID_FLAG_COMMIT
         count = lambda c: True
-        if _should_batch_verify(vals, commit):
+        if _should_batch_verify(commit):
             return _verify_commit_batch(
                 chain_id, vals, commit, voting_power_needed, ignore, count,
                 False, True,
@@ -143,7 +144,7 @@ def verify_commit_light_trusting(
     count = lambda c: True
     # Trusting verification only happens on the light-client path.
     with _classify(_CLASS_LIGHT):
-        if _should_batch_verify(vals, commit):
+        if _should_batch_verify(commit):
             return _verify_commit_batch(
                 chain_id, vals, commit, voting_power_needed, ignore, count,
                 False, False,
@@ -166,12 +167,13 @@ def _verify_commit_batch(
 ) -> None:
     """validation.go:151-258.
 
-    Divergence (improvement): a mixed ed25519+sr25519 commit sub-batches
-    per key type (crypto/batch.MultiBatchVerifier), each type on its own
-    device kernel — the reference's single-key-type verifier would fail
-    the whole commit. Only keys with no batch support at all (secp256k1)
-    drop to single verification, which is what the reference's comment
-    declares (validation.go:49-50) but its code never does.
+    Divergence (improvement): a mixed commit sub-batches per key type
+    (crypto/batch.MultiBatchVerifier), ed25519 and sr25519 each on its
+    own device kernel and the lanes of a type with no batch support
+    (secp256k1) on the host under ``host_lanes`` — the reference's
+    single-key-type verifier would fail the whole commit. Only an entry
+    its own verifier refuses to take (a malformed ed25519 key or
+    signature) sends the commit to single verification.
     """
     tallied = 0
     seen_vals = {}
@@ -180,7 +182,7 @@ def _verify_commit_batch(
     # the second commit from the same validators skips its table builds.
     crypto_batch.note_validator_set_traced(vals)
     # Mixed validator sets sub-batch per key type (BASELINE config 5);
-    # an unsupported key (secp256k1) raises on add -> single fallback.
+    # a malformed entry raises on add -> single fallback.
     bv = crypto_batch.MultiBatchVerifier()
     unbatchable = False
     # One span for the whole loop; its per-lane steps are phase totals

@@ -56,6 +56,24 @@ def make_validators(
     return privs_sorted, vset
 
 
+def make_mixed_validators(n_ed: int, n_sr: int, n_secp: int, power: int = 10):
+    """``make_validators`` over the three key types a set may hold
+    (BASELINE config 5): ``(privs in the set's order, ValidatorSet)``.
+    Sixteen lanes of a type that batches reach the device
+    (crypto.batch.DEVICE_THRESHOLD)."""
+    from tendermint_tpu.crypto.keys import Secp256k1PrivKey
+    from tendermint_tpu.crypto.sr25519 import Sr25519PrivKey
+
+    def factory(i):
+        if i < n_ed:
+            return Ed25519PrivKey.from_seed(i.to_bytes(32, "big"))
+        if i < n_ed + n_sr:
+            return Sr25519PrivKey.from_secret(b"mixed-sr %d" % i)
+        return Secp256k1PrivKey(hashlib.sha256(b"mixed-secp %d" % i).digest())
+
+    return make_validators(n_ed + n_sr + n_secp, power=power, key_factory=factory)
+
+
 def make_commit(
     block_id: BlockID,
     height: int,
@@ -89,7 +107,9 @@ def make_commit(
     return commit
 
 
-def rehearse_cell(bench: str, cell: str, seed: int, trace: int, *extra, timeout: int = 300):
+def rehearse_cell(
+    bench: str, cell: str, seed: int, trace: int, *extra, timeout: int = 300, prelude: str = ""
+):
     """(result line, standard output) of one CPU rehearsal of a
     benchmark cell's tiny twin: ``chipbench.run --rehearse`` in a child.
 
@@ -97,12 +117,18 @@ def rehearse_cell(bench: str, cell: str, seed: int, trace: int, *extra, timeout:
     before and after its window, and a traced run profiles into it. The
     rehearsals of several test files, which xdist gives to several
     workers, therefore share a lock file: a traced run holds it alone,
-    untraced ones hold it together."""
+    untraced ones hold it together. ``prelude`` is Python the child
+    runs before ``chipbench.run``'s ``main``: a fault planted in the
+    program where ``chipbench/breaks.py`` knows none."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    cmd = [
-        sys.executable, "-m", "chipbench.run", "--workload", cell, "--seed", str(seed),
-        "--seconds", "1", "--trace", str(trace), "--rehearse", "--bench-file", bench, *extra,
+    args = [
+        "--workload", cell, "--seed", str(seed), "--seconds", "1", "--trace", str(trace),
+        "--rehearse", "--bench-file", bench, *extra,
     ]
+    cmd = [sys.executable, "-m", "chipbench.run", *args]
+    if prelude:
+        code = prelude + "\nimport sys, chipbench.run\nsys.exit(chipbench.run.main(sys.argv[1:]))\n"
+        cmd = [sys.executable, "-c", code, *args]
     lock = os.path.join(
         tempfile.gettempdir(),
         "chipbench_trace_%s.lock" % hashlib.sha256(root.encode()).hexdigest()[:12],

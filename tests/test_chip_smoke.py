@@ -68,7 +68,7 @@ def test_main_exits_nonzero_alone_in_a_directory(tmp_path):
 
 def test_phase_fails_on_the_wrong_platform():
     with pytest.raises(chip_smoke.SmokeFailure, match="want 'tpu'"):
-        chip_smoke.library_phase("tpu", sizes=(), sync=None)
+        chip_smoke.library_phase("tpu", sizes=(), sync=None, sr_buckets=(), mixed=0)
 
 
 # --- the library phase's checks, live, on the CPU -----------------------------
@@ -79,12 +79,12 @@ def test_edge_vectors_phase_passes_on_cpu():
     ops.verify_batch, lane for lane against the oracle, lanes
     dispatched == lanes sent, health counters flat — on the 64-lane
     legacy kernel other suites compile anyway."""
-    report = chip_smoke.library_phase("cpu", sizes=(), sync=None)
+    report = chip_smoke.library_phase("cpu", sizes=(), sync=None, sr_buckets=(), mixed=0)
     assert report["device"]["platform"] == "cpu"
     assert report["impl"] == "xla" and report["host_hash"] == "native"
     edge = report["edge"]
     assert 0 < edge["accepted"] < edge["lanes"]
-    assert report["sr25519"] == "not run"
+    assert report["sr25519"] == [] and report["mixed_committee"] is None
     # more than one device: the same lanes through the sharded path, on
     # the 512-lane 8-way legacy kernel tests/test_mesh.py compiles
     sharded = report["sharded_edge"]
@@ -92,6 +92,21 @@ def test_edge_vectors_phase_passes_on_cpu():
     assert sharded["sharded"] == [("legacy", 8, 512, "xla")]
     # the XLA graph's mesh kernels record no first-call span
     assert chip_smoke._sharded_first_calls(report) == []
+    json.dumps(report)
+
+
+def test_sr25519_and_the_mixed_committee_pass_on_cpu():
+    """The phase's last steps at their smallest: a full 64-lane bucket
+    of sr25519 lanes against the schnorrkel oracle, and a committee of
+    the three key types through verify_commit, sound and tampered."""
+    report = chip_smoke.library_phase(
+        "cpu", sizes=(), sync=None, sr_buckets=(64,), mixed=45
+    )
+    (sr,) = report["sr25519"]
+    assert sr["lanes"] == 64 and 0 < sr["accepted"] < 64
+    mixed = report["mixed_committee"]
+    assert mixed["sent"] == {"ed25519": 21, "sr25519": 21, "secp256k1": 3}
+    assert len(mixed["tampered"]) == 3
     json.dumps(report)
 
 
@@ -107,7 +122,7 @@ def test_kernel_failure_fails_the_phase_instead_of_passing_on_the_oracle(
     monkeypatch.setattr(ed25519_batch, "_compiled_kernel", boom)
     with pytest.warns(UserWarning, match="CPU fallback"):
         with pytest.raises(chip_smoke.SmokeFailure, match="host oracle"):
-            chip_smoke.library_phase("cpu", sizes=(), sync=None)
+            chip_smoke.library_phase("cpu", sizes=(), sync=None, sr_buckets=(), mixed=0)
 
 
 def test_failing_implementation_is_counted_not_switched(monkeypatch):

@@ -238,8 +238,13 @@ def _parent_source(tmp_path):
 
 
 def _hashed_name():
-    with open(_SRC, "rb") as f:
-        return "libsha512batch-%s.so" % hashlib.sha256(f.read()).hexdigest()[:12]
+    """The library's name: a hash of every translation unit
+    (``hashing._SOURCES``; two since PR 40's ``merlin_batch.c``)."""
+    h = hashlib.sha256()
+    for name in hashing._SOURCES:
+        with open(os.path.join(os.path.dirname(_SRC), name), "rb") as f:
+            h.update(f.read())
+    return "libsha512batch-%s.so" % h.hexdigest()[:12]
 
 
 def _assert_whole(lib):

@@ -349,11 +349,12 @@ def test_other_implementations_keep_the_xla_graph(monkeypatch, impl, stand_in):
 
 
 def test_a_kind_without_a_pallas_entry_keeps_the_xla_graph(stand_in):
-    """sr25519's kind has ``pallas`` None: whatever the implementation
-    says, its mesh kernel is the XLA graph."""
+    """A kind whose ``pallas`` is None (sr25519's was until PR 40; a
+    stand-in here): whatever the implementation says, its mesh kernel
+    is the XLA graph."""
     from tendermint_tpu.ops.sr25519_batch import SR25519
 
-    assert SR25519.pallas is None
+    assert SR25519.pallas == "compiled_verify_sr" and SR25519.pallas in pallas_verify._SHARD_BODY
     ran = []
 
     def graph(*args):

@@ -190,11 +190,24 @@ def test_multibatch_merges_in_submission_order():
     assert oks == [True, True, True, False, True, True]
 
 
-def test_multibatch_rejects_unsupported_key():
-    from tendermint_tpu.crypto.batch import MultiBatchVerifier
-    from tendermint_tpu.crypto.keys import Secp256k1PrivKey
+def test_multibatch_takes_an_unsupported_key_as_host_lanes():
+    """A key type without a batch verifier (secp256k1) no longer makes
+    ``add`` raise: its lanes are a host sub-batch, verified lane by
+    lane, merged in submission order like the others."""
+    from tendermint_tpu.crypto.batch import HostLanesVerifier, MultiBatchVerifier
+    from tendermint_tpu.crypto.keys import Ed25519PrivKey, Secp256k1PrivKey
 
     mb = MultiBatchVerifier()
-    priv = Secp256k1PrivKey.generate()
+    secp = Secp256k1PrivKey.generate()
+    ed = Ed25519PrivKey.from_seed(b"\x05" * 32)
+    mb.add(secp.pub_key(), b"m0", secp.sign(b"m0"))
+    mb.add(ed.pub_key(), b"m1", ed.sign(b"m1"))
+    mb.add(secp.pub_key(), b"m2", secp.sign(b"another message"))
+    assert isinstance(mb._subs["secp256k1"], HostLanesVerifier)
+    assert len(mb) == 3
+    assert mb.verify() == (False, [True, True, False])
+    # the host sub-verifier keeps the contract of every BatchVerifier
+    lanes = HostLanesVerifier("secp256k1")
+    assert lanes.verify() == (False, [])
     with pytest.raises(ValueError):
-        mb.add(priv.pub_key(), b"m", priv.sign(b"m"))
+        lanes.add(ed.pub_key(), b"m", ed.sign(b"m"))

@@ -1014,3 +1014,63 @@ def test_profiler_capture_holds_the_program_spans(ring, tmp_path):
             for line in plane.lines:
                 names.update(e.name for e in line.events)
     assert {"verify_batch", "prep_chunk", "dispatch_chunk", "collect_chunk"} <= names
+
+
+# --- a committee of mixed key types: both engines under the same names --------
+
+
+def test_a_mixed_commit_names_both_engines_alike(ring, monkeypatch):
+    """What the benchmark's shared commit-cell entries read, present for
+    the sr25519 engine as for ed25519's (ISSUE 40): ``verify_batch``
+    with ``engine`` / ``lanes`` / ``proc_cpu_us``, ``prep_chunk``,
+    ``dispatch_chunk`` (``kind``, ``padded_lanes``, ``h2d_bytes``,
+    ``h2d_us``, ``launch_us``, ``impl``), ``collect_chunk`` (``wait_us``,
+    ``d2h_us``, ``d2h_bytes``), ``merge_results``, and a
+    ``kernel_compile`` (``engine``, ``kernel``, ``lanes``) at a shape's
+    first call; and the spans of what is sr25519's or the host's alone:
+    ``merlin_challenge`` (``lanes``) inside its ``prep_chunk``,
+    ``host_lanes`` (``key_type``, ``lanes``) inside its
+    ``batch_verify``."""
+    from tendermint_tpu.ops import ed25519_batch
+    from tendermint_tpu.types import validation
+    from tests.helpers import make_mixed_validators
+
+    # an uncached factory, so that this call meets each shape first
+    monkeypatch.setattr(ed25519_batch, "_compiled_kernel", ed25519_batch._compiled_kernel.__wrapped__)
+    privs, vset = make_mixed_validators(17, 19, 2)
+    block_id = make_block_id(b"issue-40-names")
+    commit = make_commit(block_id, 6, 0, vset, privs)
+    validation.verify_commit(CHAIN_ID, vset, block_id, 6, commit)
+    events = _complete_events(ring.export())
+    by_engine = {"ed25519": {}, "sr25519": {}}
+    for e in events:
+        engine = e["args"].get("engine")
+        if engine in by_engine:
+            by_engine[engine].setdefault(e["name"], []).append(e["args"])
+    for engine, lanes in (("ed25519", 17), ("sr25519", 19)):
+        spans = by_engine[engine]
+        (batch,) = spans["verify_batch"]
+        assert batch["lanes"] == lanes and batch["proc_cpu_us"] >= 0
+        assert batch["parent"] == "batch_verify"
+        (prep,) = spans["prep_chunk"]
+        assert prep["lanes"] == lanes and prep["parent"] == "verify_batch"
+        (sent,) = spans["dispatch_chunk"]
+        assert (sent["lanes"], sent["padded_lanes"], sent["impl"]) == (lanes, 64, "xla")
+        assert sent["h2d_bytes"] > 0 and sent["h2d_n"] >= 4
+        assert sent["h2d_us"] >= 0 and sent["launch_us"] > 0 and (sent["chunk"], sent["chunks"]) == (0, 1)
+        (got,) = spans["collect_chunk"]
+        assert got["lanes"] == lanes and got["d2h_bytes"] == 64
+        assert got["wait_us"] >= 0 and got["d2h_us"] >= 0
+        (first,) = spans["kernel_compile"]
+        assert first["lanes"] == 64
+    assert by_engine["sr25519"]["dispatch_chunk"][0]["kind"] == "sr25519"
+    assert by_engine["sr25519"]["kernel_compile"][0]["kernel"] == "verify_sr"
+    assert sum(1 for e in events if e["name"] == "merge_results") == 2
+    (merlin,) = [e["args"] for e in events if e["name"] == "merlin_challenge"]
+    assert (merlin["lanes"], merlin["parent"]) == (19, "prep_chunk")
+    (host,) = [e["args"] for e in events if e["name"] == "host_lanes"]
+    assert (host["key_type"], host["lanes"], host["parent"]) == ("secp256k1", 2, "batch_verify")
+    names = {e["name"] for e in events}
+    assert not names & {"single_verify", "host_fallback"}
+    # one cpu_us a call, on the thread's outermost span, as before
+    assert [e["name"] for e in events if "cpu_us" in e["args"]] == ["verify_commit"]
