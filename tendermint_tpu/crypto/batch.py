@@ -10,7 +10,7 @@ host oracle below it.
 
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+from typing import Callable, List, Optional, Tuple
 
 from tendermint_tpu.crypto.keys import (
     ED25519_KEY_TYPE,
@@ -125,6 +125,18 @@ def note_validator_set_traced(
     return noted
 
 
+class PendingVerify:
+    """What :meth:`BatchVerifier.begin` returns: a verify whose device
+    work, if it has any, is dispatched and not yet collected.
+    ``finish()`` returns what ``verify()`` would have, once;
+    ``lanes_inflight`` is the lanes on the device until then (0 for a
+    batch that was answered at ``begin``)."""
+
+    def __init__(self, finish: Callable[[], Tuple[bool, List[bool]]], lanes_inflight: int = 0):
+        self.finish = finish
+        self.lanes_inflight = lanes_inflight
+
+
 class BatchVerifier:
     """crypto.BatchVerifier contract (crypto/crypto.go:58-76): Add entries,
     then Verify once; returns (all_valid, per-entry validity)."""
@@ -135,8 +147,37 @@ class BatchVerifier:
     def verify(self) -> Tuple[bool, List[bool]]:
         raise NotImplementedError
 
+    def begin(self) -> PendingVerify:
+        """``verify()`` in two steps, for a caller with host work to do
+        while the device runs: ``begin().finish()`` is ``verify()``.
+        Here, as for every batch that stays on the host or goes to a
+        remote: verify now, hand back the answer."""
+        verdict = self.verify()
+        return PendingVerify(lambda: verdict)
+
     def __len__(self) -> int:
         raise NotImplementedError
+
+
+def begin_on_device(key_type: str, lanes: int, begin_batch) -> PendingVerify:
+    """A device batch verifier's ``begin``: ``begin_batch()`` — the
+    engine's ``ops.begin_verify_batch`` / ``begin_verify_batch_sr`` on
+    the verifier's lanes, which it lays out under the span, as
+    ``verify()`` does — and, later, its ``finish``, each under a
+    ``batch_verify`` span as ``verify()`` opens one (``phase``
+    ``dispatch`` / ``collect``). One caller's thread does one thing at
+    a time, so the two spans of a batch never overlap what ran between
+    them."""
+    tags = dict(key_type=key_type, lanes=lanes, route="device")
+    with tracing.span("batch_verify", phase="dispatch", **tags):
+        pending = begin_batch()
+
+    def finish() -> Tuple[bool, List[bool]]:
+        with tracing.span("batch_verify", phase="collect", **tags):
+            oks = pending.finish()
+        return all(oks), list(oks)
+
+    return PendingVerify(finish, pending.lanes_inflight)
 
 
 class Ed25519BatchVerifier(BatchVerifier):
@@ -182,33 +223,51 @@ class Ed25519BatchVerifier(BatchVerifier):
         ) as span:
             return self._verify(span)
 
-    def _verify(self, span) -> Tuple[bool, List[bool]]:
+    def _route(self):
+        """``(route, how)``: ``device`` and the engine's module,
+        ``remote`` and the backend's ``verify_fn``, or ``host``."""
         n = len(self._pks)
-        if n == 0:
-            return False, []
         use_device = self.use_device
         if use_device is None:
             use_device = n >= self.device_threshold
-        if use_device:
+        if n and use_device:
             # A configured verifyd remote owns the accelerator for this
             # process: ship device-worthy batches to it (it amortizes
             # across clients; its client falls back to host verify on
             # transport failure, so verdicts never hang on the wire).
             remote = remote_verify_backend()
             if remote is not None:
-                span.set(route="remote")
-                oks = remote(self._pks, self._msgs, self._sigs)
-                return all(oks), list(oks)
+                return "remote", remote
             try:
-                from tendermint_tpu.ops import verify_batch
+                from tendermint_tpu import ops
             except ImportError:  # device engine unavailable: fail safe to host
-                use_device = False
+                pass
             else:
-                span.set(route="device")
-                oks = verify_batch(self._pks, self._msgs, self._sigs)
-        if not use_device:
+                return "device", ops
+        return "host", None
+
+    def _verify(self, span) -> Tuple[bool, List[bool]]:
+        if not self._pks:
+            return False, []
+        route, how = self._route()
+        span.set(route=route)
+        if route == "remote":
+            oks = how(self._pks, self._msgs, self._sigs)
+        elif route == "device":
+            oks = how.verify_batch(self._pks, self._msgs, self._sigs)
+        else:
             oks = host_verify_ed25519(self._pks, self._msgs, self._sigs)
         return all(oks), list(oks)
+
+    def begin(self) -> PendingVerify:
+        route, ops = self._route()
+        if route != "device":
+            return super().begin()
+        return begin_on_device(
+            ED25519_KEY_TYPE,
+            len(self._pks),
+            lambda: ops.begin_verify_batch(self._pks, self._msgs, self._sigs),
+        )
 
 
 def supports_batch_verifier(pub_key: Optional[PubKey]) -> bool:
@@ -249,7 +308,11 @@ class HostLanesVerifier(BatchVerifier):
     def __len__(self) -> int:
         return len(self._lanes)
 
-    def verify(self) -> Tuple[bool, List[bool]]:
+    def verify(self, device_lanes_inflight: int = 0) -> Tuple[bool, List[bool]]:
+        """``device_lanes_inflight``: the lanes the caller has on the
+        device while these are verified (MultiBatchVerifier's dispatched
+        sub-batches), for the span to say whether the host's work hid
+        behind the device's."""
         if not self._lanes:
             return False, []
         n = len(self._lanes)
@@ -257,7 +320,12 @@ class HostLanesVerifier(BatchVerifier):
         # that these lanes are the host's by design
         with tracing.span(
             "batch_verify", key_type=self.key_type, lanes=n, route="host"
-        ), tracing.span("host_lanes", key_type=self.key_type, lanes=n):
+        ), tracing.span(
+            "host_lanes",
+            key_type=self.key_type,
+            lanes=n,
+            device_lanes_inflight=device_lanes_inflight,
+        ):
             oks = [bool(pk.verify_signature(msg, sig)) for pk, msg, sig in self._lanes]
         return all(oks), oks
 
@@ -270,11 +338,21 @@ class MultiBatchVerifier(BatchVerifier):
     two that batch each riding its own device kernel, a type with no
     batch support (secp256k1) on the host, lane by lane
     (:class:`HostLanesVerifier`) — and the verdicts merge back in
-    submission order. The sub-verifiers run one after the other: the
-    device's by their type's name, then the host lanes, whatever seat
-    each type first appeared in, so that a call's shape does not depend
-    on the set's address order (reference crypto/batch/batch.go:11-22
-    dispatches on ONE key type; this is the mixed-set generalisation)."""
+    submission order. A set of one type is that type's ``verify()``.
+    A mixed batch is verified in three phases, all on the caller's
+    thread: every device sub-verifier, in the order of its type's name
+    (whatever seat each type first appeared in, so that a call's shape
+    does not depend on the set's address order), does everything up to
+    and including its last dispatch (``begin()``); the host-only lanes
+    are verified while those kernels run; then each device sub-batch is
+    collected, stored and merged (``finish()``), in the same order. Not
+    a thread beside them: ``cryptography``'s ECDSA verify never gives
+    the GIL up (PERF.md §6, PR 41), so a helper thread would make the
+    caller wait out the switch interval each time it comes back from a
+    put, a hash or a wait. Every sub-batch is verified whatever another found: the
+    caller names the first bad lane across types (reference
+    crypto/batch/batch.go:11-22 dispatches on ONE key type; this is the
+    mixed-set generalisation)."""
 
     def __init__(self):
         self._subs: dict = {}
@@ -295,14 +373,41 @@ class MultiBatchVerifier(BatchVerifier):
     def __len__(self) -> int:
         return len(self._order)
 
+    def _verify_in_phases(self) -> dict:
+        """Key type -> verdicts of a batch of two sub-verifiers or more."""
+        host = sorted(
+            kt for kt, sub in self._subs.items() if isinstance(sub, HostLanesVerifier)
+        )
+        results = {}
+        pending = []  # (key type, PendingVerify): begun and not finished
+        try:
+            for kt in sorted(set(self._subs) - set(host)):
+                pending.append((kt, self._subs[kt].begin()))
+            inflight = sum(p.lanes_inflight for _, p in pending)
+            for kt in host:
+                _, results[kt] = self._subs[kt].verify(device_lanes_inflight=inflight)
+            while pending:
+                kt, p = pending.pop(0)
+                _, results[kt] = p.finish()
+        finally:
+            # a begin, the host lanes or a finish raised: what is still
+            # in flight is collected all the same, so that no in-flight
+            # gauge stays up and no probe stays latched
+            for _, p in pending:
+                try:
+                    p.finish()
+                except Exception:
+                    pass  # the first exception is the caller's
+        return results
+
     def verify(self) -> Tuple[bool, List[bool]]:
         if not self._order:
             return False, []  # same empty contract as every BatchVerifier
-        results = {}
-        for kt in sorted(
-            self._subs, key=lambda kt: (isinstance(self._subs[kt], HostLanesVerifier), kt)
-        ):
-            _, results[kt] = self._subs[kt].verify()
+        if len(self._subs) == 1:
+            ((kt, sub),) = self._subs.items()
+            results = {kt: sub.verify()[1]}
+        else:
+            results = self._verify_in_phases()
         with tracing.span("merge_verdicts", lanes=len(self._order)):
             merged = [bool(results[kt][i]) for kt, i in self._order]
             return all(merged), merged

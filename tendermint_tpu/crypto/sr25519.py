@@ -27,6 +27,7 @@ import os
 from typing import List, Optional, Tuple
 
 from tendermint_tpu.crypto import ristretto
+from tendermint_tpu.crypto.batch import BatchVerifier, PendingVerify, begin_on_device
 from tendermint_tpu.crypto.keys import (
     ADDRESS_LEN,
     SR25519_KEY_TYPE,
@@ -232,7 +233,7 @@ class Sr25519PrivKey(PrivKey):
 _OPS_IMPORT_WARNED = False  # one warning per process for a jax-less install
 
 
-class Sr25519BatchVerifier:
+class Sr25519BatchVerifier(BatchVerifier):
     """Batch verifier with a device path and a host fallback.
 
     Above ``device_threshold`` entries the batch rides the ristretto
@@ -272,16 +273,17 @@ class Sr25519BatchVerifier:
         ) as span:
             return self._verify(span)
 
-    def _verify(self, span) -> Tuple[bool, List[bool]]:
+    def _device_engine(self):
+        """``ops.sr25519_batch`` where this batch goes to the device,
+        else None (under the threshold, switched off, or no engine in
+        this install)."""
         n = len(self._entries)
-        if n == 0:
-            return False, []
         use_device = self.use_device
         if use_device is None:
             use_device = n >= self.device_threshold
-        if use_device:
+        if n and use_device:
             try:
-                from tendermint_tpu.ops.sr25519_batch import verify_batch_sr
+                from tendermint_tpu.ops import sr25519_batch
             except ImportError:
                 # No device engine in this install (jax absent): warn
                 # once, then stop trying for the life of the process.
@@ -296,16 +298,34 @@ class Sr25519BatchVerifier:
                     )
                 self.use_device = False
             else:
-                # verify_batch_sr handles device failures itself
-                # (warn + shared sticky policy) and returns host-oracle
-                # verdicts on fallback.
-                span.set(route="device")
-                oks = verify_batch_sr(
-                    [e[0] for e in self._entries],
-                    [e[1] for e in self._entries],
-                    [e[2] for e in self._entries],
-                )
-                return all(oks), list(oks)
+                return sr25519_batch
+        return None
+
+    def _columns(self):
+        return tuple([e[i] for e in self._entries] for i in range(3))
+
+    def begin(self) -> PendingVerify:
+        engine = self._device_engine()
+        if engine is None:
+            return super().begin()
+        return begin_on_device(
+            SR25519_KEY_TYPE,
+            len(self._entries),
+            lambda: engine.begin_verify_batch_sr(*self._columns()),
+        )
+
+    def _verify(self, span) -> Tuple[bool, List[bool]]:
+        n = len(self._entries)
+        if n == 0:
+            return False, []
+        engine = self._device_engine()
+        if engine is not None:
+            # verify_batch_sr handles device failures itself
+            # (warn + shared sticky policy) and returns host-oracle
+            # verdicts on fallback.
+            span.set(route="device")
+            oks = engine.verify_batch_sr(*self._columns())
+            return all(oks), list(oks)
         parsed = []
         for pub, msg, sig in self._entries:
             a_point = decompress(pub) if len(pub) == PUBKEY_SIZE else None

@@ -8,6 +8,7 @@ vmap-free hand-batched ZIP-215 verifier, shardable over device meshes
 """
 
 from tendermint_tpu.ops.ed25519_batch import (  # noqa: F401
+    begin_verify_batch,
     prepare_batch,
     verify_batch,
     verify_kernel,

@@ -22,10 +22,12 @@ device; :func:`verify_kernel_tables` accepts a gathered
 cache (ops/precompute.py) and skips both; :func:`verify_kernel_resident`
 gathers that input on device from the resident store. verify_batch
 consults the digest-keyed result cache first, partitions lanes between
-the kinds, and hands the chunks to :func:`_run_jobs`, the dispatch loop
-it shares with sr25519, which double-buffers (host prep of chunk i+1
-overlaps the kernel of chunk i) and reaches every kernel through
-:func:`_run_chunk`.
+the kinds, and hands the chunks to :func:`_dispatch_jobs`, the dispatch
+loop it shares with sr25519, which double-buffers (host prep of chunk
+i+1 overlaps the kernel of chunk i) and reaches every kernel through
+:func:`_run_chunk`; ``collect()`` of what that returns is the loop's
+second half. ``begin_verify_batch`` hands the caller the batch between
+the two (crypto/batch.MultiBatchVerifier has host work for that time).
 
 Layout is transfer-minimal: the host uploads only the raw 32-byte
 strings (A, R, S, and the SHA-512 challenge k reduced mod L) as uint8;
@@ -922,11 +924,13 @@ def _mesh_collect_retry(job: _Job, backend: Optional[str], exc: Exception):
         return None
 
 
-def _run_jobs(
+def _dispatch_jobs(
     engine: str, n: int, jobs: List[_Job], prep_job, host_verify, backend, plan, attempt
-) -> np.ndarray:
-    """Prepare, dispatch and collect ``jobs``: the one loop both
-    engines reach the device through. Returns the (n,) bool verdicts.
+) -> "_PendingJobs":
+    """Prepare and dispatch ``jobs``: the first half of the one loop both
+    engines reach the device through. Returns the batch as it stands
+    after its last dispatch, its chunks in flight; ``collect()`` of that
+    is the second half.
 
     ``prep_job(job, pad_to)`` is the engine's host prep: it returns
     ``(inputs, host_ok)``, the chunk's kernel inputs padded to
@@ -938,14 +942,13 @@ def _run_jobs(
 
     Dispatch is double-buffered: job j's kernel is enqueued (JAX async
     dispatch), then job j+1's host prep runs while the device crunches
-    job j. A job whose prep, dispatch or materialization fails is
-    re-verified on the oracle while the rest stay on the device, if the
-    health machine (ops/device_policy.py) still admits them.
+    job j. A job whose prep or dispatch fails is left for the oracle at
+    collect time while the rest stay on the device, if the health
+    machine (ops/device_policy.py) still admits them.
     """
     import warnings
 
     health = device_policy.shared
-    results = np.ones(n, dtype=bool)
     host_ok_all = np.ones(n, dtype=bool)
     mesh_used = False
 
@@ -1019,75 +1022,195 @@ def _run_jobs(
         # Planned but never dispatched sharded (e.g. the shared health
         # machine denied every chunk): release probe reservations.
         _mesh_abandon(plan)
+    return _PendingJobs(engine, n, jobs, host_verify, backend, attempt, host_ok_all)
 
-    # Collect phase: JAX dispatch is async, so runtime errors can
-    # surface at materialization; those too degrade per chunk.
-    fallback_lanes = 0
-    device_chunks_ok = 0
-    for j, job in enumerate(jobs):
-        ok = None
-        if job.out is not None:
-            try:
-                with tracing.span(
-                    "collect_chunk",
-                    stage="collect",
-                    engine=engine,
-                    kind=job.kind.name,
-                    lanes=len(job.rows),
-                    chunk=j,
-                ) as csp:
-                    fault_injection.fire(engine + ".collect")
-                    if csp.live:
-                        # the one blocking line, cut in two: until the
-                        # device is done, then what is left of the copy
-                        # back, which starts now, as np.asarray starts it
-                        job.out.copy_to_host_async()
-                        csp.timed("wait", jax.block_until_ready)(job.out)
-                    if job.plan is not None:
-                        ok = csp.timed("d2h", mesh_sharding.collect_sharded)(
-                            job.out, engine
-                        )
-                    else:
-                        ok = csp.timed("d2h", np.asarray)(job.out)
-                    if csp.live:
-                        csp.set(d2h_bytes=int(ok.nbytes))
-                device_chunks_ok += 1
-                if job.plan is not None:
-                    _mesh_on_success(job.plan)
-            except Exception as exc:
-                if job.plan is not None:
-                    ok = _mesh_collect_retry(job, backend, exc)
-                if ok is not None:
+
+class _PendingJobs:
+    """A batch between its last dispatch and its first collect: what
+    :func:`_dispatch_jobs` returns. The caller may do other host work
+    while the chunks run (crypto/batch.MultiBatchVerifier dispatches a
+    second engine's and verifies its host-only lanes); ``collect()``
+    then finishes the batch, once."""
+
+    def __init__(self, engine, n, jobs, host_verify, backend, attempt, host_ok_all):
+        self.engine = engine
+        self.n = n
+        self.jobs = jobs
+        self.host_verify = host_verify
+        self.backend = backend
+        self.attempt = attempt
+        self.host_ok_all = host_ok_all  # (n,) bool structural verdicts from prep
+
+    @property
+    def lanes_inflight(self) -> int:
+        """Lanes dispatched and not yet collected."""
+        return sum(len(job.rows) for job in self.jobs if job.out is not None)
+
+    def collect(self) -> np.ndarray:
+        """Collect every chunk; returns the (n,) bool verdicts. JAX
+        dispatch is async, so runtime errors can surface at
+        materialization: a chunk that fails here, as one that was never
+        dispatched, is re-verified on the oracle alone."""
+        import warnings
+
+        engine, attempt, host_ok_all = self.engine, self.attempt, self.host_ok_all
+        health = device_policy.shared
+        results = np.ones(self.n, dtype=bool)
+        fallback_lanes = 0
+        device_chunks_ok = 0
+        for j, job in enumerate(self.jobs):
+            ok = None
+            if job.out is not None:
+                try:
+                    with tracing.span(
+                        "collect_chunk",
+                        stage="collect",
+                        engine=engine,
+                        kind=job.kind.name,
+                        lanes=len(job.rows),
+                        chunk=j,
+                    ) as csp:
+                        fault_injection.fire(engine + ".collect")
+                        if csp.live:
+                            # the one blocking line, cut in two: until the
+                            # device is done, then what is left of the copy
+                            # back, which starts now, as np.asarray starts it
+                            job.out.copy_to_host_async()
+                            csp.timed("wait", jax.block_until_ready)(job.out)
+                        if job.plan is not None:
+                            ok = csp.timed("d2h", mesh_sharding.collect_sharded)(
+                                job.out, engine
+                            )
+                        else:
+                            ok = csp.timed("d2h", np.asarray)(job.out)
+                        if csp.live:
+                            csp.set(d2h_bytes=int(ok.nbytes))
                     device_chunks_ok += 1
-                else:
-                    health.record_failure(exc, attempt)
-                    attempt = None
-                    warnings.warn(
-                        f"device chunk ({job.kind.name}, {len(job.rows)} lanes) "
-                        f"failed at collect ({exc!r}); CPU fallback for the "
-                        f"chunk (device state={health.state})"
-                    )
-            finally:
-                health.note_inflight(engine, -len(job.rows))
-        if not len(job.rows):
-            continue
-        if ok is None:
-            fallback_lanes += len(job.rows)
-            with tracing.span(
-                "host_fallback", stage="fallback", engine=engine, lanes=len(job.rows)
-            ):
-                results[job.rows] = host_verify(job.rows)
-            host_ok_all[job.rows] = True  # oracle verdicts are final
-        else:
-            results[job.rows] = ok[: len(job.rows)]
+                    if job.plan is not None:
+                        _mesh_on_success(job.plan)
+                except Exception as exc:
+                    if job.plan is not None:
+                        ok = _mesh_collect_retry(job, self.backend, exc)
+                    if ok is not None:
+                        device_chunks_ok += 1
+                    else:
+                        health.record_failure(exc, attempt)
+                        attempt = None
+                        warnings.warn(
+                            f"device chunk ({job.kind.name}, {len(job.rows)} lanes) "
+                            f"failed at collect ({exc!r}); CPU fallback for the "
+                            f"chunk (device state={health.state})"
+                        )
+                finally:
+                    health.note_inflight(engine, -len(job.rows))
+            if not len(job.rows):
+                continue
+            if ok is None:
+                fallback_lanes += len(job.rows)
+                with tracing.span(
+                    "host_fallback", stage="fallback", engine=engine, lanes=len(job.rows)
+                ):
+                    results[job.rows] = self.host_verify(job.rows)
+                host_ok_all[job.rows] = True  # oracle verdicts are final
+            else:
+                results[job.rows] = ok[: len(job.rows)]
 
-    if fallback_lanes:
-        health.count_fallback(engine, fallback_lanes)
-    if attempt is not None and device_chunks_ok:
-        # No failure consumed the attempt and device work round-tripped:
-        # re-promote (clears DEGRADED, completes a half-open probe).
-        health.record_success(attempt)
-    return np.logical_and(results, host_ok_all)
+        if fallback_lanes:
+            health.count_fallback(engine, fallback_lanes)
+        if attempt is not None and device_chunks_ok:
+            # No failure consumed the attempt and device work round-tripped:
+            # re-promote (clears DEGRADED, completes a half-open probe).
+            health.record_success(attempt)
+        return np.logical_and(results, host_ok_all)
+
+
+def _undispatched(engine: str, n: int, host_verify) -> _PendingJobs:
+    """The batch the health machine did not admit (DISABLED, or cooling
+    down while another caller holds the probe slot): one chunk that was
+    never dispatched, so ``collect()`` hands every lane to the oracle
+    under ``host_fallback`` and counts it — the circuit breaker never
+    blocks, and nothing is prepared for a device that will not be
+    asked."""
+    jobs = [_Job(None, np.arange(n))]
+    return _PendingJobs(engine, n, jobs, host_verify, None, None, np.ones(n, dtype=bool))
+
+
+class PendingBatch:
+    """An engine's batch whose chunks are dispatched and not collected:
+    what ``begin_verify_batch`` / ``begin_verify_batch_sr`` return.
+    ``finish()`` collects them and returns the per-entry verdicts, under
+    a ``verify_batch`` span of its own (``phase`` ``collect``; the begin
+    ran under one with ``phase`` ``dispatch``), so that the two never
+    hold what the caller did in between."""
+
+    def __init__(self, engine: str, lanes: int, pending: Optional[_PendingJobs], settle):
+        self.engine = engine
+        self.lanes = lanes
+        self._pending = pending  # None: no lane was left for the device
+        self._settle = settle  # (n,) bool verdicts of the pending lanes -> List[bool]
+
+    @property
+    def lanes_inflight(self) -> int:
+        return 0 if self._pending is None else self._pending.lanes_inflight
+
+    def finish(self) -> List[bool]:
+        with tracing.span(
+            "verify_batch", engine=self.engine, lanes=self.lanes, phase="collect"
+        ) as vsp:
+            vsp.process_cpu()
+            pending, self._pending = self._pending, None
+            return self._settle(None if pending is None else pending.collect())
+
+
+class _CacheFront:
+    """The verdict cache around one batch (precompute.ResultCache),
+    asked and filled once each: the constructor's lookup derives the
+    keys, ``settle`` takes them back with the verdicts of ``lanes``,
+    the ``(pubkeys, msgs, sigs)`` no entry answered (None where every
+    lane hit). With no hit at all, or the cache off, ``lanes`` is the
+    whole batch (what a batch of unseen votes sends)."""
+
+    def __init__(self, pubkeys, msgs, sigs):
+        from tendermint_tpu.ops import precompute
+
+        self._results = precompute.results
+        self.n = n = len(pubkeys)
+        self.keys = self.cached = self.missed = None
+        if precompute.result_cache_enabled():
+            with tracing.span(
+                "cache_lookup", stage="cache_lookup", engine="ed25519", lanes=n
+            ) as csp:
+                self.keys, self.cached = self._results.get_many(pubkeys, msgs, sigs)
+                self.missed = [i for i, v in enumerate(self.cached or ()) if v is None]
+                csp.set(hits=0 if self.cached is None else n - len(self.missed))
+        if self.cached is None:
+            self.lanes = (pubkeys, msgs, sigs)
+        elif self.missed:
+            self.lanes = tuple([seq[i] for i in self.missed] for seq in (pubkeys, msgs, sigs))
+        else:
+            self.lanes = None
+
+    def settle(self, out: Optional[np.ndarray]) -> List[bool]:
+        """Store the engine's verdicts of ``lanes`` (the store stays
+        behind the device's or the oracle's answer), merge them with
+        the hits, and return the batch's verdicts in its own order."""
+        if self.cached is None:
+            verdicts = out
+            if self.keys is not None:
+                with tracing.span("cache_store", lanes=self.n) as ssp:
+                    ssp.set(evicted=self._results.put_many(self.keys, verdicts))
+        else:
+            verdicts = np.array([v is True for v in self.cached], dtype=bool)
+            if self.missed:
+                with tracing.span("cache_store", lanes=len(self.missed)) as ssp:
+                    ssp.set(
+                        evicted=self._results.put_many(
+                            [self.keys[i] for i in self.missed], out
+                        )
+                    )
+                verdicts[self.missed] = out
+        with tracing.span("merge_results", lanes=self.n):
+            return verdicts.tolist()
 
 
 def verify_batch(
@@ -1122,48 +1245,40 @@ def verify_batch(
     state machine alone decides when the device is cooling down or
     disabled, and it recovers via half-open probe batches.
     """
-    from tendermint_tpu.ops import precompute
-
     n = len(pubkeys)
     if n == 0:
         return []
     with tracing.span("verify_batch", engine="ed25519", lanes=n) as vsp:
         vsp.process_cpu()
-        # The verdict cache is asked and filled once a batch
-        # (precompute.ResultCache): the lookup derives the keys, the store
-        # takes them back. ``cached`` None: every lane goes to the device
-        # (what a batch of unseen votes sends), or the cache is off.
-        keys = cached = None
-        if precompute.result_cache_enabled():
-            with tracing.span(
-                "cache_lookup", stage="cache_lookup", engine="ed25519", lanes=n
-            ) as csp:
-                keys, cached = precompute.results.get_many(pubkeys, msgs, sigs)
-                pending = [i for i, v in enumerate(cached or ()) if v is None]
-                csp.set(hits=0 if cached is None else n - len(pending))
-        if cached is None:
-            verdicts = _verify_uncached(pubkeys, msgs, sigs, backend)
-            if keys is not None:
-                with tracing.span("cache_store", lanes=n) as ssp:
-                    ssp.set(evicted=precompute.results.put_many(keys, verdicts))
-        else:
-            verdicts = np.array([v is True for v in cached], dtype=bool)
-            if pending:
-                out = _verify_uncached(
-                    [pubkeys[i] for i in pending],
-                    [msgs[i] for i in pending],
-                    [sigs[i] for i in pending],
-                    backend,
-                )
-                with tracing.span("cache_store", lanes=len(pending)) as ssp:
-                    ssp.set(
-                        evicted=precompute.results.put_many(
-                            [keys[i] for i in pending], out
-                        )
-                    )
-                verdicts[pending] = out
-        with tracing.span("merge_results", lanes=n):
-            return verdicts.tolist()
+        front = _CacheFront(pubkeys, msgs, sigs)
+        # by its name: the seam tests and chipbench/breaks.py stand in
+        return front.settle(
+            _verify_uncached(*front.lanes, backend) if front.lanes else None
+        )
+
+
+def begin_verify_batch(
+    pubkeys: Sequence[bytes],
+    msgs: Sequence[bytes],
+    sigs: Sequence[bytes],
+    backend: Optional[str] = None,
+) -> PendingBatch:
+    """:func:`verify_batch` in two steps, for a caller with host work to
+    do while the device runs (crypto/batch.MultiBatchVerifier): this one
+    does everything up to and including the last ``dispatch_chunk``
+    (lookup, route, gather, the double-buffered prep/dispatch loop),
+    ``finish()`` of what it returns the collects, the cache store and
+    the merge. ``begin_verify_batch(...).finish()`` is ``verify_batch``
+    statement for statement, under two ``verify_batch`` spans (``phase``
+    ``dispatch`` / ``collect``) where that opens one."""
+    n = len(pubkeys)
+    if n == 0:
+        return PendingBatch("ed25519", 0, None, lambda out: [])
+    with tracing.span("verify_batch", engine="ed25519", lanes=n, phase="dispatch") as vsp:
+        vsp.process_cpu()
+        front = _CacheFront(pubkeys, msgs, sigs)
+        pending = _begin_uncached(*front.lanes, backend) if front.lanes else None
+    return PendingBatch("ed25519", n, pending, front.settle)
 
 
 def _verify_uncached(
@@ -1173,19 +1288,27 @@ def _verify_uncached(
     backend: Optional[str] = None,
 ) -> np.ndarray:
     """Device verification of lanes the result cache could not answer."""
+    return _begin_uncached(pubkeys, msgs, sigs, backend).collect()
+
+
+def _begin_uncached(
+    pubkeys: Sequence[bytes],
+    msgs: Sequence[bytes],
+    sigs: Sequence[bytes],
+    backend: Optional[str] = None,
+) -> _PendingJobs:
+    """:func:`_verify_uncached` up to and including its last dispatch."""
     from tendermint_tpu.ops import precompute
 
     health = device_policy.shared
     n = len(pubkeys)
+
+    def host_verify(rows) -> np.ndarray:
+        return _host_verify_rows(pubkeys, msgs, sigs, rows)
+
     attempt = health.begin_attempt("ed25519")
     if attempt is None:
-        # DISABLED, or cooling down (another caller may hold the probe
-        # slot). Instant answer — the circuit breaker never blocks.
-        health.count_fallback("ed25519", n)
-        with tracing.span(
-            "host_fallback", stage="fallback", engine="ed25519", lanes=n
-        ):
-            return _host_verify_rows(pubkeys, msgs, sigs, range(n))
+        return _undispatched("ed25519", n, host_verify)
 
     # Partition: lanes whose key has a cached (or eligible, host-built)
     # table take the table kernel; ill-formed lanes must stay on the
@@ -1262,9 +1385,6 @@ def _verify_uncached(
             )
         return prepare_batch(pks, ms, sgs, pad_to=pad_to, backend=backend)
 
-    def host_verify(rows) -> np.ndarray:
-        return _host_verify_rows(pubkeys, msgs, sigs, rows)
-
-    return _run_jobs(
+    return _dispatch_jobs(
         "ed25519", n, jobs, prep_job, host_verify, backend, plan, attempt
     )

@@ -12,7 +12,7 @@ Sites currently instrumented:
   in ops/ed25519_batch._run_chunk (one device) or
   parallel/sharding.run_chunk_mesh (a mesh)
 - ``ed25519.collect`` / ``sr25519.collect`` — materialization of a
-  dispatched chunk's result in ops/ed25519_batch._run_jobs
+  dispatched chunk's result in ops/ed25519_batch._PendingJobs.collect
 
 When no plan is installed the hook is a single global read — zero
 overhead on the hot path. Plans are process-global and thread-safe

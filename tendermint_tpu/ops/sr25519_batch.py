@@ -34,12 +34,15 @@ from tendermint_tpu.libs import tracing
 from tendermint_tpu.ops import curve32 as curve, device_policy, field32 as field
 from tendermint_tpu.ops.chunk_kinds import ChunkInput, ChunkKind
 from tendermint_tpu.ops.ed25519_batch import (
+    PendingBatch,
     _bytes_to_fe,
     _chunk_rows,
+    _dispatch_jobs,
     _Job,
     _mesh_span,
-    _run_jobs,
+    _PendingJobs,
     _to_windows_signed,
+    _undispatched,
     canonical_lt,
     straus_sb_minus_ka,
 )
@@ -193,9 +196,10 @@ def verify_batch_sr(
 ) -> List[bool]:
     """Per-entry schnorrkel batch verification on the device, host
     Merlin challenges. Chunks go through the dispatch loop shared with
-    ed25519 (ops/ed25519_batch._run_jobs), double-buffered: the Merlin
-    transcript challenges of chunk j+1 — one call of the C extension a
-    chunk (crypto/hashing.sr25519_challenges_mod_l), under the
+    ed25519 (ops/ed25519_batch._dispatch_jobs, then ``collect()`` of
+    what it returns), double-buffered: the Merlin transcript challenges
+    of chunk j+1 — one call of the C extension a chunk
+    (crypto/hashing.sr25519_challenges_mod_l), under the
     ``merlin_challenge`` span — are computed while the device crunches
     chunk j (JAX async dispatch), instead of hashing the whole batch
     up front. Device failure degrades per CHUNK to the host oracle
@@ -209,17 +213,42 @@ def verify_batch_sr(
         return []
     with tracing.span("verify_batch", engine="sr25519", lanes=n) as vsp:
         vsp.process_cpu()
-        verdicts = _verify_lanes(pubkeys, msgs, sigs, backend)
-        with tracing.span("merge_results", lanes=n):
-            return verdicts.tolist()
+        return _merge(_begin_lanes(pubkeys, msgs, sigs, backend).collect())
 
 
-def _verify_lanes(
+def begin_verify_batch_sr(
+    pubkeys: Sequence[bytes],
+    msgs: Sequence[bytes],
+    sigs: Sequence[bytes],
+    backend: Optional[str] = None,
+) -> PendingBatch:
+    """:func:`verify_batch_sr` in two steps, as ed25519's
+    ``begin_verify_batch``: lane arrays, Merlin challenges and every
+    ``dispatch_chunk`` here, the collects and the merge in ``finish()``
+    of what this returns, each step under a ``verify_batch`` span of
+    its own (``phase`` ``dispatch`` / ``collect``)."""
+    n = len(pubkeys)
+    if n == 0:
+        return PendingBatch("sr25519", 0, None, lambda out: [])
+    with tracing.span("verify_batch", engine="sr25519", lanes=n, phase="dispatch") as vsp:
+        vsp.process_cpu()
+        pending = _begin_lanes(pubkeys, msgs, sigs, backend)
+    return PendingBatch("sr25519", n, pending, _merge)
+
+
+def _merge(verdicts: np.ndarray) -> List[bool]:
+    with tracing.span("merge_results", lanes=len(verdicts)):
+        return verdicts.tolist()
+
+
+def _begin_lanes(
     pubkeys: Sequence[bytes],
     msgs: Sequence[bytes],
     sigs: Sequence[bytes],
     backend: Optional[str],
-) -> np.ndarray:
+) -> _PendingJobs:
+    """Every lane prepared and dispatched; ``collect()`` of the result
+    gives the (n,) bool verdicts."""
     from tendermint_tpu.crypto.hashing import sr25519_challenges_mod_l
     from tendermint_tpu.crypto.sr25519 import verify as verify_host
 
@@ -232,11 +261,7 @@ def _verify_lanes(
     n = len(pubkeys)
     attempt = health.begin_attempt("sr25519")
     if attempt is None:
-        health.count_fallback("sr25519", n)
-        with tracing.span(
-            "host_fallback", stage="fallback", engine="sr25519", lanes=n
-        ):
-            return host_verify(range(n))
+        return _undispatched("sr25519", n, host_verify)
 
     pk_arr, r_arr, s_arr, host_ok = _lane_arrays(pubkeys, sigs)
 
@@ -252,7 +277,7 @@ def _verify_lanes(
 
     plan, span = _mesh_span(n)
     jobs = [_Job(SR25519, rows) for rows in _chunk_rows(np.arange(n), span)]
-    return _run_jobs(
+    return _dispatch_jobs(
         "sr25519", n, jobs, prep_job, host_verify, backend, plan, attempt
     )
 

@@ -170,10 +170,13 @@ def _verify_commit_batch(
     Divergence (improvement): a mixed commit sub-batches per key type
     (crypto/batch.MultiBatchVerifier), ed25519 and sr25519 each on its
     own device kernel and the lanes of a type with no batch support
-    (secp256k1) on the host under ``host_lanes`` — the reference's
-    single-key-type verifier would fail the whole commit. Only an entry
-    its own verifier refuses to take (a malformed ed25519 key or
-    signature) sends the commit to single verification.
+    (secp256k1) on the host under ``host_lanes``, verified while the
+    device runs the two sub-batches, which are both dispatched before
+    either is collected — the reference's single-key-type verifier
+    would fail the whole commit. Every sub-batch is verified whatever
+    another found, and the first bad lane across types is named. Only
+    an entry its own verifier refuses to take (a malformed ed25519 key
+    or signature) sends the commit to single verification.
     """
     tallied = 0
     seen_vals = {}

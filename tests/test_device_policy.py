@@ -342,8 +342,9 @@ def test_env_plan_parsing():
 # --- acceptance: the real verify path under injected faults ------------------
 #
 # Both engines reach the device through one loop (ops/ed25519_batch
-# ._run_jobs), so each of these runs once an engine: ``eng.name`` is the
-# engine's fault-site prefix, ``eng.verify`` its batch entry point and
+# ._dispatch_jobs, then ``collect()``), so each of these runs once an
+# engine: ``eng.name`` is the engine's fault-site prefix, ``eng.verify``
+# its batch entry point and
 # ``eng.batch(bad=...)`` well-formed lanes with wrong signatures at
 # ``bad`` (sr25519 batches are small: its host prep is pure Python).
 

@@ -101,6 +101,51 @@ def test_device_batch_matches_host_with_tampering():
     assert want[9] is False
 
 
+def _batch(engine, n=12):
+    if engine == "sr25519":
+        privs = _keys(n, salt=b"two-steps")
+    else:
+        from tendermint_tpu.crypto.keys import Ed25519PrivKey
+
+        privs = [Ed25519PrivKey.from_seed(bytes([i]) * 32) for i in range(n)]
+    pks = [priv.pub_key().bytes() for priv in privs]
+    msgs = [b"two steps %d" % i for i in range(n)]
+    sigs = [priv.sign(m) for priv, m in zip(privs, msgs)]
+    msgs[5] = b"swapped message"
+    return pks, msgs, sigs
+
+
+@pytest.mark.parametrize("engine", ["ed25519", "sr25519"])
+def test_begin_then_finish_is_the_one_call_in_two_steps(engine, ring_tracer):
+    """``begin_verify_batch`` / ``begin_verify_batch_sr`` (ISSUE 41):
+    every lane in flight when it returns and no collect yet, ``finish()``
+    the verdicts ``verify_batch`` / ``verify_batch_sr`` give, each step
+    under a ``verify_batch`` span of its own."""
+    from tendermint_tpu.ops import ed25519_batch, sr25519_batch
+
+    begin, whole = {
+        "ed25519": (ed25519_batch.begin_verify_batch, ed25519_batch.verify_batch),
+        "sr25519": (sr25519_batch.begin_verify_batch_sr, verify_batch_sr),
+    }[engine]
+    pks, msgs, sigs = _batch(engine)
+    want = whole(pks, msgs, sigs)
+    assert want == [i != 5 for i in range(12)]
+    ring_tracer.clear()
+    pending = begin(pks, msgs, sigs)
+    assert (pending.engine, pending.lanes, pending.lanes_inflight) == (engine, 12, 12)
+    so_far = [e["name"] for e in ring_tracer.export()["traceEvents"] if e.get("ph") == "X"]
+    assert "dispatch_chunk" in so_far and "collect_chunk" not in so_far
+    assert pending.finish() == want
+    assert pending.lanes_inflight == 0
+    phases = [
+        (e["args"]["phase"], e["args"]["engine"], e["args"]["lanes"])
+        for e in ring_tracer.export()["traceEvents"]
+        if e.get("ph") == "X" and e["name"] == "verify_batch"
+    ]
+    assert phases == [("dispatch", engine, 12), ("collect", engine, 12)]
+    assert begin([], [], []).finish() == []
+
+
 def test_batch_verifier_routes_to_device():
     privs = _keys(20, salt=b"route")
     bv = Sr25519BatchVerifier(device_threshold=8)

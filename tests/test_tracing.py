@@ -1029,8 +1029,9 @@ def test_a_mixed_commit_names_both_engines_alike(ring, monkeypatch):
     ``kernel_compile`` (``engine``, ``kernel``, ``lanes``) at a shape's
     first call; and the spans of what is sr25519's or the host's alone:
     ``merlin_challenge`` (``lanes``) inside its ``prep_chunk``,
-    ``host_lanes`` (``key_type``, ``lanes``) inside its
-    ``batch_verify``."""
+    ``host_lanes`` (``key_type``, ``lanes``, ``device_lanes_inflight``)
+    inside its ``batch_verify``. A device sub-batch of a mixed call
+    opens ``batch_verify`` / ``verify_batch`` once a phase."""
     from tendermint_tpu.ops import ed25519_batch
     from tendermint_tpu.types import validation
     from tests.helpers import make_mixed_validators
@@ -1049,9 +1050,11 @@ def test_a_mixed_commit_names_both_engines_alike(ring, monkeypatch):
             by_engine[engine].setdefault(e["name"], []).append(e["args"])
     for engine, lanes in (("ed25519", 17), ("sr25519", 19)):
         spans = by_engine[engine]
-        (batch,) = spans["verify_batch"]
-        assert batch["lanes"] == lanes and batch["proc_cpu_us"] >= 0
-        assert batch["parent"] == "batch_verify"
+        # one a phase of a mixed call (ISSUE 41), alike but for ``phase``
+        assert sorted(b["phase"] for b in spans["verify_batch"]) == ["collect", "dispatch"]
+        for batch in spans["verify_batch"]:
+            assert batch["lanes"] == lanes and batch["proc_cpu_us"] >= 0
+            assert batch["parent"] == "batch_verify"
         (prep,) = spans["prep_chunk"]
         assert prep["lanes"] == lanes and prep["parent"] == "verify_batch"
         (sent,) = spans["dispatch_chunk"]
@@ -1070,6 +1073,13 @@ def test_a_mixed_commit_names_both_engines_alike(ring, monkeypatch):
     assert (merlin["lanes"], merlin["parent"]) == (19, "prep_chunk")
     (host,) = [e["args"] for e in events if e["name"] == "host_lanes"]
     assert (host["key_type"], host["lanes"], host["parent"]) == ("secp256k1", 2, "batch_verify")
+    assert host["device_lanes_inflight"] == 17 + 19
+    outer = [e["args"] for e in events if e["name"] == "batch_verify"]
+    assert sorted((o["key_type"], o["route"], o.get("phase", "")) for o in outer) == [
+        ("ed25519", "device", "collect"), ("ed25519", "device", "dispatch"),
+        ("secp256k1", "host", ""),
+        ("sr25519", "device", "collect"), ("sr25519", "device", "dispatch"),
+    ]
     names = {e["name"] for e in events}
     assert not names & {"single_verify", "host_fallback"}
     # one cpu_us a call, on the thread's outermost span, as before

@@ -6,9 +6,13 @@ of the three host oracles, lane for lane."""
 
 import pytest
 
+from tendermint_tpu.crypto import batch as crypto_batch
 from tendermint_tpu.crypto.ed25519_ref import verify_zip215
-from tendermint_tpu.crypto.sr25519 import verify as verify_sr
+from tendermint_tpu.crypto.keys import Secp256k1PubKey
+from tendermint_tpu.crypto.sr25519 import Sr25519BatchVerifier, verify as verify_sr
 from tendermint_tpu.libs import tracing
+from tendermint_tpu.libs.metrics import OpsMetrics, Registry
+from tendermint_tpu.ops import device_policy, precompute
 from tendermint_tpu.types import validation
 from tests.helpers import CHAIN_ID, make_block_id, make_commit, make_mixed_validators
 
@@ -39,6 +43,11 @@ def traced(fn):
     return raised, events
 
 
+def in_order(events):
+    """By start, an enclosing span before what it holds."""
+    return sorted(events, key=lambda e: (e["ts"], -e["dur"]))
+
+
 def lanes_of(vset, key_type):
     return [i for i, v in enumerate(vset.validators) if v.pub_key.type == key_type]
 
@@ -53,13 +62,22 @@ def test_a_mixed_commit_is_accepted_on_the_batch_path(mixed):
     (host,) = [e for e in events if e["name"] == "host_lanes"]
     assert (host["args"]["key_type"], host["args"]["lanes"]) == ("secp256k1", N_SECP)
     assert host["args"]["parent"] == "batch_verify"
-    routes = {e["args"]["key_type"]: (e["args"]["lanes"], e["args"]["route"])
-              for e in events if e["name"] == "batch_verify"}
-    assert routes == {
-        "ed25519": (N_ED, "device"), "sr25519": (N_SR, "device"), "secp256k1": (N_SECP, "host"),
-    }
-    engines = {e["args"]["engine"]: e["args"]["lanes"] for e in events if e["name"] == "verify_batch"}
-    assert engines == {"ed25519": N_ED, "sr25519": N_SR}
+    # one batch_verify / verify_batch a phase for a device sub-batch, in
+    # the order the phases run; the host lanes have no second phase
+    routes = [(e["args"]["key_type"], e["args"]["lanes"], e["args"]["route"], e["args"].get("phase"))
+              for e in in_order(events) if e["name"] == "batch_verify"]
+    assert routes == [
+        ("ed25519", N_ED, "device", "dispatch"), ("sr25519", N_SR, "device", "dispatch"),
+        ("secp256k1", N_SECP, "host", None),
+        ("ed25519", N_ED, "device", "collect"), ("sr25519", N_SR, "device", "collect"),
+    ]
+    engines = [(e["args"]["engine"], e["args"]["lanes"], e["args"]["phase"], e["args"]["parent"])
+               for e in in_order(events) if e["name"] == "verify_batch"]
+    assert engines == [
+        ("ed25519", N_ED, "dispatch", "batch_verify"), ("sr25519", N_SR, "dispatch", "batch_verify"),
+        ("ed25519", N_ED, "collect", "batch_verify"), ("sr25519", N_SR, "collect", "batch_verify"),
+    ]
+    assert all(e["args"]["proc_cpu_us"] >= 0 for e in events if e["name"] == "verify_batch")
     dispatched = sum(e["args"]["lanes"] for e in events if e["name"] == "dispatch_chunk")
     assert dispatched == N_ED + N_SR
 
@@ -111,7 +129,10 @@ def test_a_set_of_secp256k1_keys_alone_is_verified_as_host_lanes():
     privs, vset = make_mixed_validators(0, 0, 4)
     block_id = make_block_id(b"secp-only")
     commit = make_commit(block_id, 3, 0, vset, privs)
-    validation.verify_commit(CHAIN_ID, vset, block_id, 3, commit)
+    raised, events = traced(lambda: validation.verify_commit(CHAIN_ID, vset, block_id, 3, commit))
+    assert raised is None
+    (host,) = [e for e in events if e["name"] == "host_lanes"]
+    assert (host["args"]["lanes"], host["args"]["device_lanes_inflight"]) == (4, 0)
     sig = bytearray(commit.signatures[2].signature)
     sig[5] ^= 0x01
     commit.signatures[2].signature = bytes(sig)
@@ -130,3 +151,174 @@ def test_a_malformed_ed25519_entry_still_takes_the_single_path(mixed):
     raised, events = traced(lambda: validation.verify_commit(CHAIN_ID, vset, block_id, 11, commit))
     assert raised is not None and "#%d" % lane in str(raised)
     assert "single_verify" in {e["name"] for e in events}
+
+
+# --- a mixed batch in phases (ISSUE 41) ---------------------------------------
+
+
+@pytest.fixture
+def verdict_cache(monkeypatch):
+    """On, as a node runs it (the suite pins it off: conftest.py)."""
+    monkeypatch.setenv(precompute._RESULT_ENV, "1")
+    return precompute.results
+
+
+def test_a_mixed_commit_dispatches_both_sub_batches_then_verifies_the_host_lanes_then_collects(
+    mixed, verdict_cache
+):
+    privs, vset, block_id = mixed
+    commit = make_commit(block_id, 12, 0, vset, privs)
+    raised, events = traced(lambda: validation.verify_commit(CHAIN_ID, vset, block_id, 12, commit))
+    assert raised is None
+    (host,) = [e for e in events if e["name"] == "host_lanes"]
+    sent = [e for e in events if e["name"] == "dispatch_chunk"]
+    got = [e for e in events if e["name"] == "collect_chunk"]
+    assert {e["args"]["engine"] for e in sent} == {e["args"]["engine"] for e in got} == {"ed25519", "sr25519"}
+    assert all(e["ts"] + e["dur"] <= host["ts"] for e in sent)
+    assert all(host["ts"] + host["dur"] <= e["ts"] for e in got)
+    assert host["args"]["device_lanes_inflight"] == N_ED + N_SR
+    assert sum(e["args"]["lanes"] for e in got) == N_ED + N_SR
+    # no span of a phase holds the host lanes, and none overlaps another's
+    phases = [e for e in events if e["name"] in ("batch_verify", "verify_batch") and "phase" in e["args"]]
+    assert all(e["ts"] + e["dur"] <= host["ts"] or host["ts"] + host["dur"] <= e["ts"] for e in phases)
+    outer = in_order(e for e in events if e["name"] == "batch_verify")
+    assert all(a["ts"] + a["dur"] <= b["ts"] for a, b in zip(outer, outer[1:]))
+    # the store stays behind the device's answer, the merges behind the store
+    names = [e["name"] for e in in_order(events)]
+    assert names.index("host_lanes") < names.index("collect_chunk") < names.index("cache_store")
+    assert names.index("cache_store") < names.index("merge_results") < names.index("merge_verdicts")
+
+
+def commit_lanes(vset, commit):
+    return [
+        (val.pub_key, commit.vote_sign_bytes(CHAIN_ID, i), commit.signatures[i].signature)
+        for i, val in enumerate(vset.validators)
+    ]
+
+
+def multi_of(lanes):
+    bv = crypto_batch.MultiBatchVerifier()
+    for lane in lanes:
+        bv.add(*lane)
+    return bv
+
+
+def back_to_back(bv):
+    """Each sub-verifier's ``begin().finish()`` one after the other, in
+    the order a mixed call begins them, merged as ``verify()`` merges:
+    the serial order, made of the phased call's own two steps."""
+    order = sorted(bv._subs, key=lambda kt: (isinstance(bv._subs[kt], crypto_batch.HostLanesVerifier), kt))
+    results = {kt: bv._subs[kt].begin().finish()[1] for kt in order}
+    return [results[kt][i] for kt, i in bv._order]
+
+
+@pytest.mark.parametrize("tampered", [None, "ed25519", "sr25519", "secp256k1"])
+def test_phased_and_back_to_back_give_the_same_verdicts_and_the_same_verdict_cache(
+    mixed, verdict_cache, tampered
+):
+    privs, vset, block_id = mixed
+    commit = make_commit(block_id, 13, 0, vset, privs)
+    lane = None
+    if tampered is not None:
+        lane = lanes_of(vset, tampered)[2]
+        sig = bytearray(commit.signatures[lane].signature)
+        sig[33] ^= 0x04
+        commit.signatures[lane].signature = bytes(sig)
+    lanes = commit_lanes(vset, commit)
+    left = {}
+    for name, run in (("phased", lambda bv: bv.verify()[1]), ("back_to_back", back_to_back)):
+        verdict_cache.clear()
+        verdicts = run(multi_of(lanes))
+        left[name] = (verdicts, list(verdict_cache._entries.items()))
+    assert left["phased"] == left["back_to_back"]
+    verdicts, cached = left["phased"]
+    assert verdicts == [i != lane for i in range(len(lanes))]
+    # ed25519 verdicts alone enter the cache, the tampered lane's as False
+    assert len(cached) == N_ED
+    assert [v for _, v in cached].count(False) == (1 if tampered == "ed25519" else 0)
+
+
+class Boom(Exception):
+    pass
+
+
+@pytest.mark.parametrize("health_state", ["healthy", "half_open"])
+@pytest.mark.parametrize("fault", ["host_lane_raises", "second_begin_raises"])
+def test_a_phase_that_raises_leaves_nothing_in_flight_and_no_probe_latched(
+    mixed, monkeypatch, fault, health_state
+):
+    """The handles already begun are finished on the way out: the
+    in-flight gauge is back at 0, the one-prober latch is free (with the
+    device half open the ed25519 sub-batch holds it, and the sr25519
+    sub-batch is the host oracle's, as a second caller's would be), and
+    the caller sees the first exception."""
+    privs, vset, block_id = mixed
+    commit = make_commit(block_id, 14, 0, vset, privs)
+    now = [1000.0]
+    health = device_policy.DeviceHealth(retry_budget=1, cooldown_base=1.0, clock=lambda: now[0])
+    ops_metrics = OpsMetrics(Registry())
+    health.bind_metrics(ops_metrics)
+    monkeypatch.setattr(device_policy, "shared", health)
+    if health_state == "half_open":
+        health.record_failure(RuntimeError("UNAVAILABLE: planted"), health.begin_attempt("ed25519"))
+        assert health.state == device_policy.COOLDOWN
+        now[0] += 5.0
+    if fault == "host_lane_raises":
+        def verify_signature(self, msg, sig):
+            raise Boom("host lane")
+        monkeypatch.setattr(Secp256k1PubKey, "verify_signature", verify_signature)
+    else:
+        def begin(self):
+            raise Boom("second begin")
+        monkeypatch.setattr(Sr25519BatchVerifier, "begin", begin)
+    began = []
+    begin_ed = crypto_batch.Ed25519BatchVerifier.begin
+    monkeypatch.setattr(
+        crypto_batch.Ed25519BatchVerifier, "begin",
+        lambda self: began.append(health.snapshot()["probe_inflight"]) or begin_ed(self),
+    )
+    bv = multi_of(commit_lanes(vset, commit))
+    with pytest.raises(Boom):
+        bv.verify()
+    assert began == [False]  # the ed25519 sub-batch was begun, once
+    assert health.snapshot()["probe_inflight"] is False
+    # the gauge went up, and came back
+    assert ops_metrics.inflight_lanes.collect()[0] == 'tendermint_ops_inflight_lanes{engine="ed25519"} 0'
+    assert all(line.endswith(" 0") for line in ops_metrics.inflight_lanes.collect())
+    assert health.state == device_policy.HEALTHY  # the ed25519 chunk round-tripped
+
+
+PINNED = {  # the parent's (PR 40) list for a warm commit of one key type, by start
+    "ed25519": [
+        "verify_commit", "note_validator_set", "build_lanes", "batch_verify", "verify_batch",
+        "cache_lookup", "gather_tables", "route_lanes", "prep_chunk", "dispatch_chunk",
+        "collect_chunk", "cache_store", "merge_results", "merge_verdicts",
+    ],
+    "sr25519": [
+        "verify_commit", "note_validator_set", "build_lanes", "batch_verify", "verify_batch",
+        "prep_chunk", "merlin_challenge", "dispatch_chunk", "collect_chunk", "merge_results",
+        "merge_verdicts",
+    ],
+    "secp256k1": [
+        "verify_commit", "note_validator_set", "build_lanes", "batch_verify", "host_lanes",
+        "merge_verdicts",
+    ],
+}
+
+
+@pytest.mark.parametrize(
+    "key_type,shape", [("ed25519", (20, 0, 0)), ("sr25519", (0, 18, 0)), ("secp256k1", (0, 0, 3))]
+)
+def test_a_commit_of_one_key_type_records_the_spans_it_always_did(verdict_cache, key_type, shape):
+    """The control for the cells that build one sub-verifier: their call
+    is that sub-verifier's ``verify()``, one ``batch_verify`` and one
+    ``verify_batch`` with no ``phase``."""
+    privs, vset = make_mixed_validators(*shape)
+    block_id = make_block_id(b"pinned-" + key_type.encode())
+    warm = make_commit(block_id, 7, 0, vset, privs)
+    validation.verify_commit(CHAIN_ID, vset, block_id, 7, warm)  # a shape's first call compiles
+    commit = make_commit(block_id, 8, 0, vset, privs)
+    raised, events = traced(lambda: validation.verify_commit(CHAIN_ID, vset, block_id, 8, commit))
+    assert raised is None
+    assert [e["name"] for e in in_order(events)] == PINNED[key_type]
+    assert not any("phase" in e["args"] for e in events)
