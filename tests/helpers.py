@@ -6,7 +6,9 @@ makeCommit / deterministicValidatorSet).
 
 from __future__ import annotations
 
+import collections
 import fcntl
+import functools
 import hashlib
 import json
 import os
@@ -15,6 +17,7 @@ import sys
 import tempfile
 from typing import List, Optional, Tuple
 
+from chipbench import spec
 from tendermint_tpu.crypto.keys import Ed25519PrivKey
 from tendermint_tpu.encoding.canonical import Timestamp
 from tendermint_tpu.types import (
@@ -141,3 +144,78 @@ def rehearse_cell(
         )
     assert proc.returncode == 0, proc.stderr[-2000:]
     return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stdout
+
+
+# --- the benchmark's per-layer metrics, named through the cell that reports them ------
+# A test says what a cell measures, never what an entry is called: no suffix spelled, no
+# entry counted, so that data files alone can merge, rename or share entries.
+REAL_BENCH = os.path.join(spec.ROOT, "BENCHMARK.json")
+# what ``chipbench/selftest.py::test_files`` compares (``testdata/definitions_at_pr36.json``'s ``keys``)
+DEFINITION_KEYS = ("reader", "args", "layer", "unit", "better", "source", "moves")
+bench_spec = functools.lru_cache(maxsize=None)(spec.Spec)  # read once a process; nobody writes into either
+layer_metric = functools.lru_cache(maxsize=None)(spec.layer_metric)
+
+
+def span(name, ts, dur, tid=1, **args):
+    """A span as the tracer exports it: times in microseconds."""
+    return {"name": name, "ts": float(ts), "dur": float(dur), "tid": tid, "args": args}
+
+
+class Evidence:
+    """What a reader is handed of a window of ``calls`` calls."""
+
+    def __init__(self, spans, calls=1):
+        self.spans, self.calls = spans, [{}] * calls
+
+
+def stem_of(name: str) -> str:
+    return name.split(".", 1)[0]
+
+
+def metric(bench: str, cell: str, stem: str, **like) -> str:
+    """The name of the one per-layer entry of the benchmark file ``bench`` that
+    ``cell`` reports under ``stem`` (the name, or the name up to a dot). Where a cell
+    reports two of one stem, ``like`` tells them apart by what they measure: a key of
+    the definition (``reader``, ``moves``) or an item of its ``args``. None or
+    several is an error, never a neighbour's entry."""
+    found = []
+    for m in bench_spec(bench).metrics_for("per_layer", cell):
+        if m["name"] == stem or m["name"].startswith(stem + "."):
+            doc = layer_metric(m["name"])
+            if all(doc.get(key, doc["args"].get(key)) == value for key, value in like.items()):
+                found.append(m["name"])
+    if len(found) != 1:
+        raise LookupError("%s reports %s under %r %s" % (cell, found or "nothing", stem, like or ""))
+    return found[0]
+
+
+def read(ev, bench: str, cell: str, stem: str, **like):
+    """What that entry's reader makes of the evidence ``ev``."""
+    doc = layer_metric(metric(bench, cell, stem, **like))
+    return spec.reader(doc["reader"]).read(ev, **doc["args"])
+
+
+def definitions(bench: str, cell: str, stems=None, but=()) -> collections.Counter:
+    """The definitions ``cell`` reports under whatever names, each as
+    ``selftest.test_files`` writes it: of ``stems`` alone, without ``but``."""
+    return collections.Counter(
+        json.dumps([layer_metric(m["name"])[key] for key in DEFINITION_KEYS], sort_keys=True)
+        for m in bench_spec(bench).metrics_for("per_layer", cell)
+        if (stems is None or stem_of(m["name"]) in stems) and stem_of(m["name"]) not in but
+    )
+
+
+def sound(out: dict, said: str, compared, bench=None, cell=None):
+    """A rehearsal came out correct with each of ``compared`` at 0; a traced one
+    (``bench``, ``cell``) printed every per-layer metric of the cell: ``value(stem, **like)``."""
+    assert out["correct"] is True and out["failed"] == 0 and out["attempted"] > 0
+    for name in compared:
+        assert "compared: %s = 0 (limit 0)" % name in said, name
+    for m in bench_spec(bench).metrics_for("per_layer", cell) if bench else ():
+        assert isinstance(out["metrics"][m["name"]]["value"], float), m["name"]
+    return lambda stem, **like: out["metrics"][metric(bench, cell, stem, **like)]["value"]
+
+
+def over_limit(said: str) -> list:
+    """The comparisons a run's output marks over their limit, in its order."""
+    return [ln.split("compared: ", 1)[1].split(" = ")[0] for ln in said.splitlines() if ln.endswith("<-- over")]

@@ -11,6 +11,7 @@ own, 7 s ahead of the host's here.
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 
@@ -19,15 +20,11 @@ import pytest
 from chipbench import selftest, spec
 from chipbench.readers import call_path, device_call_path, span_off_cpu_per_call
 from tendermint_tpu.libs import tracing
-from tests.helpers import rehearse_cell
+from tests.helpers import REAL_BENCH, definitions, read, rehearse_cell, sound, span
 
 EPOCH = tracing.tracer.epoch_ns
 TRACE_AHEAD = 7_000_000_000  # trace clock less host clock, ns
 PATTERNS = ["jit_run*", "jit__lambda*"]
-
-
-def span(name, ts, dur, tid=1, **args):
-    return {"name": name, "ts": float(ts), "dur": float(dur), "tid": tid, "args": args}
 
 
 def call(at_us, wall_us, spans, profiled=False):
@@ -218,86 +215,95 @@ def test_off_cpu_is_the_outermost_spans_less_their_waits_by_design():
 
 # --- a program that says none of it ---------------------------------------------------
 
-BASES = [
-    "dispatch_ms", "h2d_put_ms", "launch_ms", "d2h_ms", "pre_dispatch_ms", "chain_ms",
-    "post_collect_ms", "device_chain_gap_ms", "launch_lag_ms", "readback_lag_ms",
-    "off_cpu_ms", "engine_proc_cpu_ms",
-]
-NEW = [
-    base + suffix
-    for base in BASES
-    for suffix in (".commit", ".stream")
-    if os.path.exists(os.path.join(spec.HERE, "layer_metrics", base + suffix + ".json"))
-]
-# the two that read what the parent's program already had: a span's
-# time, and the device's own trace
+BASES = ["dispatch_ms", "h2d_put_ms", "launch_ms", "d2h_ms", "pre_dispatch_ms", "chain_ms", "post_collect_ms",
+         "device_chain_gap_ms", "launch_lag_ms", "readback_lag_ms", "off_cpu_ms", "engine_proc_cpu_ms"]
+REAL = spec.Spec(REAL_BENCH)
+CELLS = {w["name"]: w["chips"] for w in REAL.doc["workloads"]}
+# the cells of PR 34; ``mixed10k`` reports four of the stems in copies of its own (``tests/test_chipbench_mixed.py``)
+COMMIT_CELLS = ["hub150-warm", "big10k-warm", "big10k-x4"]
+STREAM_CELLS = ["big10k-flood", "sync500-catchup", "light1k-chain", "sync500-rotation"]
+COMMIT_ALONE = {"d2h_ms", "engine_proc_cpu_ms"}  # read inside the engine's own call: no stream cell reports them
+# every entry under a call-path stem, named through each cell that reports it
+NEW = [(cell, stem) for cell in CELLS for stem in BASES if definitions(REAL_BENCH, cell, [stem])]
+# the two that read what the parent's program already had: a span's time, and the device's own trace
 READ_ON_THE_PARENT = {"dispatch_ms", "device_chain_gap_ms"}
 
 
-def test_the_new_metric_files_are_the_twenty_two_the_cap_leaves_room_for():
-    real = spec.Spec(os.path.join(spec.ROOT, "BENCHMARK.json"))
-    assert len(NEW) == 22 and len(real.doc["per_layer"]) <= 128
-    names = [m["name"] for m in real.doc["per_layer"]]
-    first = names.index(NEW[0])
-    assert names[first : first + 22] == NEW  # appended, in this order; later PRs' entries behind them
+def test_the_new_metric_files_are_within_the_cap_and_agree_with_their_entries():
+    assert len(REAL.doc["per_layer"]) <= 128  # the driver's cap
+    assert {cell for cell, _ in NEW} >= set(COMMIT_CELLS + STREAM_CELLS) and {stem for _, stem in NEW} == set(BASES)
     selftest.test_files()
 
 
-@pytest.mark.parametrize("name", NEW)
-def test_a_program_without_the_new_arguments_reads_none_not_zero(name, monkeypatch):
-    """The parent's program under this PR's benchmark files: spans
-    without ``chunk``, phase totals or ``cpu_us``, a tracer that does
-    not say its epoch. Every new metric but the two that read what the
-    parent already had is left out of the result line; none raises."""
+@pytest.mark.parametrize("cell,stem", NEW)
+def test_a_program_without_the_new_arguments_reads_none_not_zero(cell, stem, monkeypatch):
+    """The parent's program under this PR's benchmark files: spans without ``chunk``, phase totals
+    or ``cpu_us``, a tracer that does not say its epoch. Every new metric but the two that read
+    what the parent already had is left out of the result line; none raises."""
     monkeypatch.setattr(call_path, "tracer_epoch_ns", lambda: None)
     ev = profiled_evidence()
     ev.calls, ev.spans = ev.profiled_calls, ev.profiled_spans
     for s in ev.spans:
         s["args"] = {"lanes": 4}
-    doc = spec.layer_metric(name)
-    got = spec.reader(doc["reader"]).read(ev, **doc["args"])
-    if name.split(".")[0] in READ_ON_THE_PARENT:
-        assert got > 0
-    else:
-        assert got is None
+    got = read(ev, REAL_BENCH, cell, stem)
+    assert got > 0 if stem in READ_ON_THE_PARENT else got is None
 
 
-@pytest.mark.parametrize("name", [n for n in NEW if n.endswith(".stream")])
-def test_a_stream_file_is_its_commit_twin_but_for_its_cells(name):
-    stream = spec.layer_metric(name)
-    commit = spec.layer_metric(name.replace(".stream", ".commit"))
-    assert (stream["moves"], commit["moves"]) == ("sigs_per_s", "commit_p50_ms")
-    assert stream["workloads"] == ["big10k-flood", "sync500-catchup", "sync500-rotation", "light1k-chain"]
-    for key in ("layer", "unit", "better", "source", "reader"):
-        assert stream[key] == commit[key], key
-    assert stream["args"] == commit["args"]
-    # a sharded call transfers inside itself: no h2d phase on the mesh
-    assert ("big10k-x4" in commit["workloads"]) == (name != "h2d_put_ms.stream")
+@pytest.mark.parametrize("stem", BASES)
+def test_a_call_path_stem_is_one_definition_in_every_cell_that_reports_it(stem):
+    """Each commit cell under ``commit_p50_ms`` and, but for the engine's own two, each stream
+    cell under ``sigs_per_s``: one reader on one set of arguments. A sharded call transfers
+    inside itself: no h2d phase on the mesh."""
+    want = {c: "commit_p50_ms" for c in COMMIT_CELLS if (stem, CELLS[c]) != ("h2d_put_ms", 4)}
+    if stem not in COMMIT_ALONE:
+        want.update((c, "sigs_per_s") for c in STREAM_CELLS)
+    moves, measured = {}, set()
+    for cell in COMMIT_CELLS + STREAM_CELLS:
+        for text in definitions(REAL_BENCH, cell, [stem]).elements():
+            *definition, moves[cell] = json.loads(text)
+            measured.add(json.dumps(definition))
+    assert moves == want and len(measured) == 1
 
 
 # --- the tiny twin ------------------------------------------------------------------
 
 BENCH = os.path.join(spec.HERE, "testdata", "tiny-path-benchmark.json")
+# each tiny benchmark file: its cell, and what of which real cells it rehearses (stems kept, stems left out)
+TWINS = {
+    "tiny-path-benchmark.json": ("tiny-hub-warm", {"hub150-warm": (BASES, ()), "big10k-flood": (BASES, ())}),
+    "tiny-sync-benchmark.json": ("tiny-sync-catchup", {"sync500-catchup": (None, BASES)}),
+    "tiny-light-benchmark.json": ("tiny-light-chain", {"light1k-chain": (None, BASES)}),
+    "tiny-rotation-benchmark.json": ("tiny-sync-rotation", {"sync500-rotation": (None, BASES)}),
+    "tiny-mixed-benchmark.json": ("tiny-mixed", {"mixed10k": (None, ())}),
+}
+
+
+@pytest.mark.parametrize("bench", TWINS)
+def test_a_tiny_twin_reports_what_its_real_cell_reports(bench):
+    """Every definition of the real cell's but the call path's, which has
+    a twin of its own; none else; under the names the real cell reports."""
+    path = os.path.join(spec.HERE, "testdata", bench)
+    cell, real_cells = TWINS[bench]
+    want, names = collections.Counter(), set()
+    for real_cell, (stems, but) in real_cells.items():
+        want += definitions(REAL_BENCH, real_cell, stems, but)
+        names |= {m["name"] for m in REAL.metrics_for("per_layer", real_cell)}
+    got = definitions(path, cell)
+    assert got == want, (sorted((want - got).elements()), sorted((got - want).elements()))
+    assert {m["name"] for m in spec.Spec(path).metrics_for("per_layer", cell)} <= names
 
 
 def test_tiny_twin_reports_every_new_metric():
-    out, said = rehearse_cell(BENCH, "tiny-hub-warm", 2**31 + 34, 1)
-    assert out["correct"] is True and out["failed"] == 0 and out["attempted"] > 0
-    tiny = spec.Spec(BENCH)
-    assert {m["name"] for m in tiny.metrics_for("per_layer", "tiny-hub-warm")} == set(NEW)
-    got = {name: out["metrics"][name]["value"] for name in NEW}
-    assert all(isinstance(v, float) for v in got.values()), json.dumps(got)
-    # what holds whatever the machine: a phase lies inside its span, the
-    # device cannot start what has not been dispatched, and the parts
-    # of a call are each part of it
-    assert got["h2d_put_ms.commit"] + got["launch_ms.commit"] <= got["dispatch_ms.commit"]
-    for name in ("launch_lag_ms.commit", "readback_lag_ms.commit", "device_chain_gap_ms.commit",
-                 "pre_dispatch_ms.commit", "chain_ms.commit", "post_collect_ms.commit",
-                 "d2h_ms.commit"):
-        assert got[name] >= 0.0, (name, got[name])
-    # a difference of two clocks less the waits by design: about 0 in a
-    # sound call, either side of it by what the thread's clock rounds to
-    # and by the CPU the thread used inside a wait
-    wall = got["pre_dispatch_ms.commit"] + got["chain_ms.commit"] + got["post_collect_ms.commit"]
-    assert abs(got["off_cpu_ms.commit"]) < wall
-    assert got["engine_proc_cpu_ms.commit"] > 0.0
+    value = sound(*rehearse_cell(BENCH, "tiny-hub-warm", 2**31 + 34, 1), (), BENCH, "tiny-hub-warm")
+    got = {stem: value(stem, moves="commit_p50_ms") for stem in BASES}
+    # what holds whatever the machine: a phase lies inside its span, the device cannot start what
+    # has not been dispatched, and the parts of a call are each part of it
+    assert got["h2d_put_ms"] + got["launch_ms"] <= got["dispatch_ms"]
+    for stem in ("launch_lag_ms", "readback_lag_ms", "device_chain_gap_ms", "pre_dispatch_ms", "chain_ms",
+                 "post_collect_ms", "d2h_ms"):
+        assert got[stem] >= 0.0, (stem, got[stem])
+    # a difference of two clocks less the waits by design: about 0 in a sound call, either side of
+    # it by what the thread's clock rounds to and by the CPU the thread used inside a wait
+    wall = got["pre_dispatch_ms"] + got["chain_ms"] + got["post_collect_ms"]
+    assert abs(got["off_cpu_ms"]) < wall
+    assert got["engine_proc_cpu_ms"] > 0.0

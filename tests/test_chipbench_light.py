@@ -17,10 +17,11 @@ import pytest
 from chipbench import reference_lightclient as plain
 from chipbench import selftest, spec, workload
 from chipbench.generators import lightclient
-from tests.helpers import rehearse_cell
+from tests.helpers import REAL_BENCH, Evidence, over_limit, read, rehearse_cell, sound, span
 
 BENCH = os.path.join(spec.HERE, "testdata", "tiny-light-benchmark.json")
-CELL = "tiny-light-chain"
+CELL, SEED = "tiny-light-chain", 2**31 + 30
+REAL = (REAL_BENCH, "light1k-chain")  # the cell a hand-made reading is named through
 N, JUMP, SHORT = 40, 28, 27  # the tiny twin's: 27 pass 2/3, 14 pass 1/3
 
 
@@ -152,7 +153,7 @@ def test_reference_walk_faults(chain):
 
 def test_benchmark_files_agree():
     selftest.test_files()
-    real = spec.Spec(os.path.join(spec.ROOT, "BENCHMARK.json"))
+    real = spec.Spec(REAL_BENCH)
     cell = real.cell("light1k-chain")
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("light1k", "skipping-updates", 1)
     config, traffic = real.config("light1k"), real.traffic("skipping-updates")
@@ -166,100 +167,65 @@ def test_benchmark_files_agree():
 
     assert cycle_length(traffic, 1334, 65536) == 53
     assert [m["name"] for m in real.metrics_for("end_to_end", "light1k-chain")] == ["sigs_per_s", "setup_s"]
-    ours = [m for m in real.doc["per_layer"] if m.get("workloads") == ["light1k-chain"]]
-    # and since PR 34 the ten ``.stream`` call-path metrics of every ``sigs_per_s`` cell
-    assert len(ours) == 29 == len(real.metrics_for("per_layer", "light1k-chain")) - 10
-    assert {m["layer"] for m in ours} == {"Light", "Scheduler", "Tables", "Engine", "Kernels", "Device"}
-    tiny = spec.Spec(BENCH)
-    assert [m["name"] for m in tiny.doc["per_layer"]] == [m["name"] for m in ours]
+    # its own layers and, since PR 34, the call path's (Engine, Device)
+    assert {m["layer"] for m in real.metrics_for("per_layer", "light1k-chain")} == {
+        "Light", "Scheduler", "Tables", "Engine", "Kernels", "Device"}
     # the reference imports nothing of the program
     with open(os.path.join(spec.HERE, "reference_lightclient.py")) as fh:
         assert "tendermint_tpu" not in fh.read().replace("tendermint v0.35.9", "")
 
 
-def test_light_metrics_add_up_on_nested_spans():
-    """One call: light_verify 0..2000 holding the trusted block's load
-    10..60, the target's light_block_checks 60..95 and valset_hash
-    100..200 (the client's validate_basic), light_round 300..1900 with
-    light_plan 310..900 (phases 60 + 40 + 200; inside it a pivot's
-    light_block_checks 370..390 and valset_hash 400..480, and two
-    note_validator_set of 30) and light_super_batch 910..1890, in which
-    scheduler_dispatch 950..1850 holds sched_assemble 960..1000 and
-    verify_batch 1010..1800; then the pivot's save 1900..1950, the
-    detector 1950..1960 and the target's save 1960..1995."""
-
-    class Evidence:
-        calls = [{}]
-
-    def span(name, ts, dur, **args):
-        return {"name": name, "ts": float(ts), "dur": float(dur), "args": args}
-
-    ev = Evidence()
-    ev.spans = [
-        span("light_verify", 0, 2000, target=801, hops=2),
-        span("light_store_load", 10, 50, bytes=200000),
-        span("light_block_checks", 60, 35),
-        span("valset_hash", 100, 100),
-        span("light_round", 300, 1600),
-        span("light_block_checks", 370, 20),
-        span("light_plan", 310, 590, header_checks_us=60.0, header_checks_n=3,
-             valset_hash_us=80.0, valset_hash_n=1, tally_us=40.0, tally_n=5,
-             sign_bytes_us=200.0, sign_bytes_n=54),
-        span("valset_hash", 400, 80),
-        span("note_validator_set", 500, 30), span("note_validator_set", 700, 30),
-        span("light_super_batch", 910, 980, lanes=54),
-        span("scheduler_dispatch", 950, 900, lanes=54),
-        span("sched_assemble", 960, 40),
-        span("sched_flush", 1005, 800),
-        span("verify_batch", 1010, 790),
+def one_update():
+    """One call: light_verify 0..2000 holding the trusted block's load 10..60, the target's
+    light_block_checks 60..95 and valset_hash 100..200 (the client's validate_basic), light_round
+    300..1900 with light_plan 310..900 (phases 60 + 40 + 200; inside it a pivot's light_block_checks
+    370..390 and valset_hash 400..480, and two note_validator_set of 30) and light_super_batch 910..1890,
+    in which scheduler_dispatch 950..1850 holds sched_assemble 960..1000 and verify_batch 1010..1800; then
+    the pivot's save 1900..1950, the detector 1950..1960 and the target's save 1960..1995."""
+    return [
+        span("light_verify", 0, 2000, target=801, hops=2), span("light_store_load", 10, 50, bytes=200000),
+        span("light_block_checks", 60, 35), span("valset_hash", 100, 100),
+        span("light_round", 300, 1600), span("light_block_checks", 370, 20),
+        span("light_plan", 310, 590, header_checks_us=60.0, header_checks_n=3, valset_hash_us=80.0,
+             valset_hash_n=1, tally_us=40.0, tally_n=5, sign_bytes_us=200.0, sign_bytes_n=54),
+        span("valset_hash", 400, 80), span("note_validator_set", 500, 30), span("note_validator_set", 700, 30),
+        span("light_super_batch", 910, 980, lanes=54), span("scheduler_dispatch", 950, 900, lanes=54),
+        span("sched_assemble", 960, 40), span("sched_flush", 1005, 800), span("verify_batch", 1010, 790),
         span("gather_tables", 1020, 10, builds=0), span("gather_tables", 1040, 30, builds=2),
-        span("light_store_save", 1900, 50, height=401),
-        span("light_detect", 1950, 10, witnesses=1),
+        span("light_store_save", 1900, 50, height=401), span("light_detect", 1950, 10, witnesses=1),
         span("light_store_save", 1960, 35, height=801),
     ]
 
-    def read(name):
-        doc = spec.layer_metric(name)
-        return spec.reader(doc["reader"]).read(ev, **doc["args"])
 
-    assert read("light_host_ms") == pytest.approx(1.210)
-    assert read("valset_hash_ms") == pytest.approx(0.180)
-    assert read("note_set_ms.light") == pytest.approx(0.060)
-    assert read("sign_bytes_ms.light") == pytest.approx(0.200)
-    assert read("tally_ms") == pytest.approx(0.040)
-    assert read("header_checks_ms") == pytest.approx(0.060)
-    assert read("sched_handoff_ms") == pytest.approx(0.080)
-    assert read("sched_assemble_ms") == pytest.approx(0.040)
-    assert read("table_build_ms.light") == pytest.approx(0.030)
-    assert read("store_save_ms") == pytest.approx(0.085)
-    assert read("store_load_ms") == pytest.approx(0.050)
-    assert read("block_checks_ms") == pytest.approx(0.055)
-    assert read("detector_ms") == pytest.approx(0.010)
-    # outside plan and super-batch 2000 - 590 - 980 = 430, less the
-    # hash, checks, load, saves and detector there (100 + 35 + 50 + 85
-    # + 10) = 150; the plan's 590 less its phases 300 and the 160 of
-    # the spans inside it = 130
-    assert read("light_unnamed_ms") == pytest.approx(0.280)
-    # the identity: the call's host time is its named parts, the
-    # scheduler's share (the super-batch less the engine) and the rest
-    scheduler = (980 - 790) / 1000.0
-    named = (read("valset_hash_ms") + read("note_set_ms.light") + read("sign_bytes_ms.light")
-             + read("tally_ms") + read("header_checks_ms") + read("store_save_ms")
-             + read("store_load_ms") + read("block_checks_ms") + read("detector_ms") + scheduler)
-    assert named + read("light_unnamed_ms") == pytest.approx(read("light_host_ms"))
+# light_unnamed_ms: outside plan and super-batch 2000 - 590 - 980 = 430, less the hash, checks, load,
+# saves and detector there (100 + 35 + 50 + 85 + 10) = 150; the plan's 590 less its phases 300 and the
+# 160 of the spans inside it = 130
+NAMED = [("valset_hash_ms", 0.180), ("note_set_ms", 0.060), ("sign_bytes_ms", 0.200), ("tally_ms", 0.040),
+         ("header_checks_ms", 0.060), ("store_save_ms", 0.085), ("store_load_ms", 0.050),
+         ("block_checks_ms", 0.055), ("detector_ms", 0.010)]
+LIGHT = NAMED + [("light_host_ms", 1.210), ("sched_handoff_ms", 0.080), ("sched_assemble_ms", 0.040),
+                 ("table_build_ms", 0.030), ("light_unnamed_ms", 0.280)]
+
+
+@pytest.mark.parametrize("stem,want", LIGHT)
+def test_light_metric_on_nested_spans(stem, want):
+    assert read(Evidence(one_update()), *REAL, stem) == pytest.approx(want)
+
+
+def test_light_metrics_add_up_on_nested_spans():
+    """The call's host time is its named parts, the scheduler's share (the super-batch less the engine) and the rest."""
+    got = {stem: read(Evidence(one_update()), *REAL, stem) for stem, _ in LIGHT}
+    named = (980 - 790) / 1000.0 + sum(got[stem] for stem, _ in NAMED)
+    assert named + got["light_unnamed_ms"] == pytest.approx(got["light_host_ms"])
     # a program without the light spans (the parent): nothing to read
-    ev.spans = [span("verify_batch", 1010, 790), span("gather_tables", 1020, 10, builds=0)]
-    for name in ("light_host_ms", "sign_bytes_ms.light", "tally_ms", "light_unnamed_ms", "sched_handoff_ms",
+    ev = Evidence([span("verify_batch", 1010, 790), span("gather_tables", 1020, 10, builds=0)])
+    for stem in ("light_host_ms", "sign_bytes_ms", "tally_ms", "light_unnamed_ms", "sched_handoff_ms",
                  "store_save_ms", "store_load_ms", "block_checks_ms", "detector_ms"):
-        assert read(name) is None, name
-    assert read("table_build_ms.light") == 0.0
+        assert read(ev, *REAL, stem) is None, stem
+    assert read(ev, *REAL, "table_build_ms") == 0.0
 
 
 # --- the program against the reference, end to end ---------------------------------------
-
-
-def rehearse(trace: int, *extra, seed=2**31 + 30):
-    return rehearse_cell(BENCH, CELL, seed, trace, *extra, timeout=420)
 
 
 COMPARED = ("verdict_cache_hits_in_window", "compilations_in_window", "timed_calls_refused",
@@ -272,21 +238,11 @@ def test_tiny_twin_of_light1k_chain_rehearses_on_the_cpu():
     size: every timed call's block and store against the reference's
     walk, the four faults, the sampled lanes; and lanes dispatched =
     the reference's distinct checked signatures (``failed`` 0)."""
-    out, said = rehearse(1)
-    assert out["correct"] is True and out["failed"] == 0 and out["attempted"] > 0
-    tiny = spec.Spec(BENCH)
-    want = {m["name"] for m in tiny.metrics_for("per_layer", CELL)}
-    assert len(want) == 29
-    for name in want:
-        assert isinstance(out["metrics"][name]["value"], float), name
-    for name in COMPARED:
-        assert "compared: %s = 0 (limit 0)" % name in said, name
-    m = {k: v["value"] for k, v in out["metrics"].items()}
-    # pivots' keys are met twice at most and get no table, and neither
-    # does the anchor of the client restarted where the cycle starts
-    # over: the window builds none and no lane finds one
-    assert m["resident_hit_share.light"] == 0.0 and m["table_build_ms.light"] == 0.0
-    assert m["light_unnamed_ms"] < m["light_host_ms"]
+    value = sound(*rehearse_cell(BENCH, CELL, SEED, 1, timeout=420), COMPARED, BENCH, CELL)
+    # pivots' keys are met twice at most and get no table, and neither does the anchor of the client
+    # restarted where the cycle starts over: the window builds none and no lane finds one
+    assert value("resident_hit_share") == 0.0 and value("table_build_ms") == 0.0
+    assert value("light_unnamed_ms") < value("light_host_ms")
 
 
 @pytest.mark.parametrize(
@@ -302,13 +258,9 @@ def test_tiny_twin_of_light1k_chain_rehearses_on_the_cpu():
     ],
 )
 def test_tiny_twin_broken_on_purpose_comes_out_not_correct(brk, over):
-    """The controls (``breaks.py``) have to show in the cell's own
-    comparisons, not in the harness's two."""
-    out, said = rehearse(0, "--break", brk)
+    """The controls (``breaks.py``) have to show in the cell's own comparisons, not in the harness's two."""
+    out, said = rehearse_cell(BENCH, CELL, SEED, 0, "--break", brk, timeout=420)
     assert out["correct"] is False
-    got = [
-        ln.split("compared: ", 1)[1].split(" = ")[0]
-        for ln in said.splitlines() if ln.endswith("<-- over")
-    ]
+    got = over_limit(said)
     assert set(got) <= set(over) and got, got
     assert set(got) & {"fault_calls_with_a_wrong_verdict", "timed_calls_refused"}

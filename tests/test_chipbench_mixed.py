@@ -7,7 +7,9 @@ with a fault planted (a rehearsal proves paths, never numbers)."""
 
 from __future__ import annotations
 
+import collections
 import hashlib
+import json
 import os
 
 import numpy as np
@@ -15,13 +17,17 @@ import pytest
 
 from chipbench import opcount, opcount_sr25519, reference_mixed, selftest, spec
 from chipbench.run import Context, Evidence
-from tests.helpers import rehearse_cell
+from tests.helpers import (
+    DEFINITION_KEYS, REAL_BENCH, definitions, over_limit, read, rehearse_cell, sound, span,
+)
 
 BENCH = os.path.join(spec.HERE, "testdata", "tiny-mixed-benchmark.json")
 CELL = "tiny-mixed"
 SEED = 2**31 + 40
-SHARED = 19  # the per-layer entries every commit cell reports (PERF.md section 3)
-OWN = 14
+REAL = (REAL_BENCH, "mixed10k")  # the cell a hand-made reading is named through
+# the cell reports two entries under each of two stems: both engines' and sr25519's alone
+SR_PROGRAMS, ALL_PROGRAMS = ({"line": "modules", "patterns": p} for p in (["jit_run_sr25519*"], ["jit_run*", "jit__lambda*"]))
+SR_PREP, ALL_PREP = {"where": {"engine": "sr25519"}}, {"reader": "span_time_per_call"}
 
 
 # --- the plain reference --------------------------------------------------------
@@ -164,7 +170,7 @@ def test_opcount_sr25519():
 
 def test_benchmark_files_agree():
     selftest.test_files()
-    real = spec.Spec(os.path.join(spec.ROOT, "BENCHMARK.json"))
+    real = spec.Spec(REAL_BENCH)
     assert len(real.doc["per_layer"]) <= 128
     cell = real.cell("mixed10k")
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("mixed10k", "warm-mixed-commits", 1)
@@ -176,32 +182,79 @@ def test_benchmark_files_agree():
     assert entry["source"] == config["source"] and len(entry["source"]) <= 200
     assert config["env"] == real.config("big10k")["env"]
     assert [m["name"] for m in real.metrics_for("end_to_end", "mixed10k")] == ["commit_p50_ms", "setup_s"]
-    reported = real.metrics_for("per_layer", "mixed10k")
-    own = [m for m in reported if "workloads" in m]
-    assert len(reported) == SHARED + OWN and len(own) == OWN
-    assert all(m["workloads"] == ["mixed10k"] for m in own)
-    # the tiny twin reports the same entries, and no accepted cell any of the new ones
-    tiny = spec.Spec(BENCH)
-    assert [m["name"] for m in tiny.metrics_for("per_layer", CELL)] == [m["name"] for m in reported]
-    for other in real.doc["workloads"]:
-        if other["name"] != "mixed10k":
-            names = {m["name"] for m in real.metrics_for("per_layer", other["name"])}
-            assert not names & {m["name"] for m in own}, other["name"]
-    # a copy differs from its elder in name, cells and doc alone
-    for name in ("device_chain_gap_ms", "pre_dispatch_ms", "chain_ms", "post_collect_ms"):
-        copy, elder = spec.layer_metric(name + ".mixed"), spec.layer_metric(name + ".commit")
-        assert {k: copy[k] for k in ("reader", "args", "layer", "unit", "better", "source", "moves")} == {
-            k: elder[k] for k in ("reader", "args", "layer", "unit", "better", "source", "moves")
-        }, name
+
+
+MS, SHARE, SETUP = ("ms", "lower"), ("%", "lower"), ("s", "lower", "program_span", "setup_s")
+SPAN, TRACE = ("program_span", "commit_p50_ms"), ("device_trace", "commit_p50_ms")
+# what ``mixed10k`` brought (PR 40) as definitions (``DEFINITION_KEYS``: reader, args, layer, unit, better,
+# source, moves), under whatever names. Seven new with the cell ...
+NEW = [
+    ["trace_kernel_time", SR_PROGRAMS, "Kernels", *MS, *TRACE],
+    ["roofline_sr25519", SR_PROGRAMS, "Kernels", "%", "higher", *TRACE],
+    ["span_time_per_call", {"spans": ["merlin_challenge"]}, "Hashing", *MS, *SPAN],
+    ["span_time_matching_per_call", {"span": "prep_chunk", **SR_PREP}, "Engine", *MS, *SPAN],
+    ["span_time_per_call", {"spans": ["host_lanes"]}, "Entry", *MS, *SPAN],
+    ["span_arg_share", {"span": "host_lanes", "arg": "lanes", "of_span": "build_lanes", "of_arg": "lanes"},
+     "Entry", *SHARE, *SPAN],
+    ["span_arg_share", {"span": "dispatch_chunk", "arg": "lanes", "where": {"kind": "sr25519"},
+                        "of_span": "dispatch_chunk", "of_arg": "lanes"}, "Engine", "%", "higher", *SPAN],
+]
+# ... six its elders have too, the first four ``ELDERS``', and the padding counted over a map of kinds
+ELDERS = ["device_chain_gap_ms", "pre_dispatch_ms", "chain_ms", "post_collect_ms"]
+KINDS = {"legacy": "verify", "tables": "verify_tables", "resident": "verify_resident", "sr25519": "verify_sr"}
+OWN = NEW + [
+    ["device_call_path", {"part": "gap", "patterns": ALL_PROGRAMS["patterns"]}, "Device", *MS, *TRACE],
+    *(["call_path", {"part": part}, "Engine", *MS, *SPAN] for part in ("pre", "chain", "post")),
+    ["setup_span_sum", {"span": "kernel_compile"}, "Kernels", *SETUP],
+    ["setup_span_sum", {"span": "gather_tables", "min_args": {"builds": 1}}, "Tables", *SETUP],
+    ["pad_lane_share_kinds", {"kernel_of_kind": KINDS}, "Engine", *SHARE, *SPAN],
+]
+# and what every commit cell reports (PERF.md section 3), held to ``big10k-warm``'s
+SHARED = [
+    "entry_host_ms", "entry_unnamed_ms", "sign_bytes_ms", "batch_add_ms", "note_set_ms", "prep_ms", "device_wait_ms",
+    "cache_store_ms", "route_ms", "engine_unnamed_ms", "h2d_bytes", "d2h_ms", "engine_proc_cpu_ms", "gc_pause_ms",
+    "kernel_ms", "device_idle", "resident_hit_share", "device_hash_share", "mesh_lane_share",
+]
+FROZEN = spec.load_json(os.path.join(spec.HERE, "testdata", "definitions_at_pr36.json"))  # seven cells, as of PR 36
+
+
+def as_counted(rows):
+    return collections.Counter(json.dumps(row, sort_keys=True) for row in rows)
+
+
+@pytest.mark.parametrize("cell", [*FROZEN["cells"], "mixed10k"])
+def test_a_cell_reports_every_definition_it_is_held_to(cell):
+    """Merging, renaming or sharing entries loses no cell a definition: the seven cells
+    of PR 36 are held to what they reported then (as ``selftest.test_files`` holds
+    them), ``mixed10k`` to what it brought and to its elders' shared ones."""
+    assert tuple(FROZEN["keys"]) == DEFINITION_KEYS
+    if cell == "mixed10k":
+        want = as_counted(OWN) + definitions(REAL_BENCH, "big10k-warm", SHARED)
+        assert all(definitions(REAL_BENCH, "big10k-warm", [stem]) for stem in SHARED)
+    else:
+        want = as_counted(FROZEN["definitions"][i] for i in FROZEN["cells"][cell])
+    lost = want - definitions(REAL_BENCH, cell)
+    assert not lost, "%s no longer reports %s" % (cell, sorted(lost.elements()))
+
+
+@pytest.mark.parametrize("cell", FROZEN["cells"])
+def test_no_other_cell_reports_what_is_new_with_mixed10k(cell):
+    """No cell older than it, and of the seven new definitions alone: the copies are what a merge lets cells share."""
+    assert not definitions(REAL_BENCH, cell) & as_counted(NEW), cell
+
+
+@pytest.mark.parametrize("stem", ELDERS)
+def test_mixed10k_reports_its_elders_definition_of_the_call_path(stem):
+    """In a copy of its own or from the elder's entry: reader, arguments and the rest letter for letter."""
+    mine = definitions(*REAL, [stem])
+    assert mine and mine == definitions(REAL_BENCH, "big10k-warm", [stem])
 
 
 # --- the new readers, on hand-made spans ---------------------------------------------
 
 
-def test_mixed_metrics_on_hand_made_spans():
-    def span(name, ts, dur, **args):
-        return {"name": name, "ts": float(ts), "dur": float(dur), "args": args}
-
+def one_commit():
+    ED, SR = {"engine": "ed25519", "kind": "resident"}, {"engine": "sr25519", "kind": "sr25519"}
     ev = Evidence()
     ev.calls = [{"start_ns": 0, "end_ns": 1}, {"start_ns": 2, "end_ns": 3}]
     ev.setup_spans = [
@@ -211,35 +264,38 @@ def test_mixed_metrics_on_hand_made_spans():
     ]
     ev.spans = [
         span("build_lanes", 0, 100, lanes=10000),
-        span("prep_chunk", 100, 700, engine="ed25519", kind="resident", lanes=4096),
-        span("dispatch_chunk", 800, 50, engine="ed25519", kind="resident", lanes=4096),
-        span("prep_chunk", 900, 3000, engine="sr25519", kind="sr25519", lanes=4096),
-        span("merlin_challenge", 950, 2500, lanes=4096),
-        span("dispatch_chunk", 4000, 50, engine="sr25519", kind="sr25519", lanes=4096),
-        span("prep_chunk", 4100, 1000, engine="sr25519", kind="sr25519", lanes=854),
-        span("merlin_challenge", 4150, 500, lanes=854),
-        span("dispatch_chunk", 5200, 50, engine="sr25519", kind="sr25519", lanes=854),
+        span("prep_chunk", 100, 700, lanes=4096, **ED), span("dispatch_chunk", 800, 50, lanes=4096, **ED),
+        span("prep_chunk", 900, 3000, lanes=4096, **SR), span("merlin_challenge", 950, 2500, lanes=4096),
+        span("dispatch_chunk", 4000, 50, lanes=4096, **SR),
+        span("prep_chunk", 4100, 1000, lanes=854, **SR), span("merlin_challenge", 4150, 500, lanes=854),
+        span("dispatch_chunk", 5200, 50, lanes=854, **SR),
         span("host_lanes", 6000, 40000, key_type="secp256k1", lanes=100),
     ]
+    return ev
 
-    def read(name):
-        doc = spec.layer_metric(name)
-        return spec.reader(doc["reader"]).read(ev, **doc["args"])
 
-    assert read("merlin_ms") == pytest.approx(1.5)
-    assert read("prep_ms.sr") == pytest.approx(2.0)
-    assert read("prep_ms.commit") == pytest.approx(2.35)  # both engines' prep
-    assert read("host_lanes_ms") == pytest.approx(20.0)
-    assert read("host_lane_share") == pytest.approx(1.0)
-    assert read("sr25519_lane_share") == pytest.approx(100.0 * 4950 / 9046)
-    assert read("pad_lane_share.mixed") == pytest.approx(100.0 * (1 - 9046 / (3 * 4096 + 0 + 1024 - 4096)))
-    assert read("kernel_ms.sr") is None and read("sr25519_roofline") is None  # no device trace
-    # a program without the spans (the parent): nothing to read, no error
+@pytest.mark.parametrize(
+    "stem,like,want",
+    [
+        ("merlin_ms", {}, 1.5), ("prep_ms", SR_PREP, 2.0), ("prep_ms", ALL_PREP, 2.35),  # both engines' prep
+        ("host_lanes_ms", {}, 20.0), ("host_lane_share", {}, 1.0), ("sr25519_lane_share", {}, 100.0 * 4950 / 9046),
+        ("pad_lane_share", {}, 100.0 * (1 - 9046 / (3 * 4096 + 0 + 1024 - 4096))),
+        ("kernel_ms", SR_PROGRAMS, None), ("sr25519_roofline", {}, None),  # no device trace
+    ],
+)
+def test_mixed_metric_on_hand_made_spans(stem, like, want):
+    got = read(one_commit(), *REAL, stem, **like)
+    assert got is None if want is None else got == pytest.approx(want)
+
+
+def test_mixed_metrics_on_hand_made_spans():
+    """A program without the spans (the parent): nothing to read, no error."""
+    ev = one_commit()
     ev.spans = [s for s in ev.spans if s["name"] in ("build_lanes",) or s["args"].get("engine") == "ed25519"]
-    assert read("prep_ms.sr") is None and read("host_lane_share") is None
-    assert read("sr25519_lane_share") is None and read("merlin_ms") == 0.0
+    assert read(ev, *REAL, "prep_ms", **SR_PREP) is None and read(ev, *REAL, "host_lane_share") is None
+    assert read(ev, *REAL, "sr25519_lane_share") is None and read(ev, *REAL, "merlin_ms") == 0.0
     ev.setup_spans = []
-    assert read("pad_lane_share.mixed") is None
+    assert read(ev, *REAL, "pad_lane_share") is None
 
 
 # --- the generator ----------------------------------------------------------------------
@@ -324,30 +380,22 @@ COMPARED = (
 
 def test_tiny_twin_of_mixed10k_rehearses_on_the_cpu_traced():
     out, said = rehearse_cell(BENCH, CELL, SEED, 1)
-    assert out["correct"] is True and out["failed"] == 0 and out["attempted"] > 0
-    want = {m["name"] for m in spec.Spec(BENCH).metrics_for("per_layer", CELL)}
-    assert len(want) == SHARED + OWN
-    value = {name: out["metrics"][name]["value"] for name in want}  # every name printed
-    assert all(isinstance(v, float) for v in value.values())
-    assert value["resident_hit_share"] == 50.0 and value["sr25519_lane_share"] == 50.0
-    assert value["mesh_lane_share"] == 0.0 and value["device_hash_share"] == 0.0
-    assert value["host_lane_share"] == pytest.approx(100.0 * 4 / 36)
-    assert value["pad_lane_share.mixed"] == 75.0  # two 64-lane kernels for 32 lanes
-    assert 0 < value["merlin_ms"] < value["prep_ms.sr"] < value["prep_ms.commit"]
-    assert 0 < value["kernel_ms.sr"] < value["kernel_ms.commit"]
-    assert 0 < value["sr25519_roofline"] < 100
-    assert value["host_lanes_ms"] > 0 and value["device_chain_gap_ms.mixed"] > 0
+    value = sound(out, said, COMPARED, BENCH, CELL)  # every name printed
+    assert value("resident_hit_share") == 50.0 and value("sr25519_lane_share") == 50.0
+    assert value("mesh_lane_share") == 0.0 and value("device_hash_share") == 0.0
+    assert value("host_lane_share") == pytest.approx(100.0 * 4 / 36)
+    assert value("pad_lane_share") == 75.0  # two 64-lane kernels for 32 lanes
+    assert 0 < value("merlin_ms") < value("prep_ms", **SR_PREP) < value("prep_ms", **ALL_PREP)
+    assert 0 < value("kernel_ms", **SR_PROGRAMS) < value("kernel_ms", **ALL_PROGRAMS)
+    assert 0 < value("sr25519_roofline") < 100
+    assert value("host_lanes_ms") > 0 and value("device_chain_gap_ms") > 0
     assert "engine sr25519 kernel verify_sr lanes 64" in said
-    for name in COMPARED:
-        assert "compared: %s = 0 (limit 0)" % name in said, name
 
 
 def test_tiny_twin_of_mixed10k_rehearses_on_the_cpu_untraced():
     out, said = rehearse_cell(BENCH, CELL, 4_000_000_007, 0)
-    assert out["correct"] is True and out["failed"] == 0 and out["attempted"] > 0
+    sound(out, said, COMPARED)
     assert set(out["metrics"]) == {"commit_p50_ms", "setup_s"}
-    for name in COMPARED:
-        assert "compared: %s = 0 (limit 0)" % name in said, name
 
 
 # planted after the warm-up calls, which have to stay sound
@@ -386,10 +434,4 @@ SECP_FORCED_TRUE = PLANT % """
 )
 def test_tiny_twin_with_a_planted_fault_comes_out_not_correct(prelude, over):
     out, said = rehearse_cell(BENCH, CELL, SEED, 0, prelude=prelude)
-    assert out["correct"] is False
-    for name in over:
-        assert any(
-            line.startswith("chipbench[rehearsal, not a measurement]: compared: %s = " % name)
-            and line.endswith("<-- over")
-            for line in said.splitlines()
-        ), (name, said[-1500:])
+    assert out["correct"] is False and set(over) <= set(over_limit(said)), said[-1500:]

@@ -13,16 +13,7 @@ from chipbench.readers import (
     span_time_per_later_call,
     span_unnamed_per_call,
 )
-
-
-class Evidence:
-    def __init__(self, spans, calls=1):
-        self.spans = spans
-        self.calls = [{}] * calls
-
-
-def span(name, ts, dur, **args):
-    return {"name": name, "ts": float(ts), "dur": float(dur), "args": args}
+from tests.helpers import REAL_BENCH, Evidence, read, span
 
 
 def one_call(at=0.0):
@@ -133,6 +124,17 @@ def test_the_parts_of_the_entry_add_up_to_its_self_time():
     assert parts == pytest.approx(entry_host)
 
 
+ENTRY = [("entry_host_ms", 0.54), ("sign_bytes_ms", 0.2), ("batch_add_ms", 0.04), ("note_set_ms", 0.05),
+         ("entry_unnamed_ms", 0.16), ("engine_unnamed_ms", 0.42), ("h2d_bytes", 8192 + 4096)]
+
+
+@pytest.mark.parametrize("cell", ["hub150-warm", "big10k-x4", "mixed10k"])
+@pytest.mark.parametrize("stem,want", ENTRY)
+def test_the_entrys_metrics_as_a_commit_cell_reports_them(cell, stem, want):
+    """The same call through the files: every commit cell reads it with the readers and arguments above."""
+    assert read(Evidence(one_call()), REAL_BENCH, cell, stem) == pytest.approx(want)
+
+
 def test_later_calls_leave_out_what_ran_before_the_window():
     """run.py collects garbage itself between set-up and the window;
     that pause is drained with the first call and is no call's."""
@@ -151,27 +153,19 @@ def test_later_calls_leave_out_what_ran_before_the_window():
 
 @pytest.mark.parametrize("kind", ["legacy", "tables", "resident"])
 def test_program_still_emits_what_the_readers_match(kind, monkeypatch):
-    """What ``chipbench`` reads from a verify call, held on one CPU
-    ``verify_batch`` a chunk kind: the ``dispatch_chunk`` span names the
-    kind and carries ``lanes``/``padded_lanes``/``h2d_bytes``/``impl``;
-    the ``kernel_compile`` span names the kernel
-    ``pad_lane_share.KERNEL_OF_KIND`` maps the kind to; and the jitted
-    program is called ``run…``, which is what ``kernel_ms.*`` and
-    ``verify_roofline.*`` find on the device trace (``jit_run*``). And
-    what the ``.rot`` metrics read of a set change (PR 32):
-    ``route_lanes``' ``legacy``, ``gather_tables``' ``builds``,
-    ``resident_upload``'s ``reason`` and ``width``, and, once eight newer
-    sets have pushed a carried one out, ``note_validator_set``'s
-    ``retired`` / ``tables_dropped`` around a ``valset_hash`` and the
-    ``resident_drop`` span. And the chunk's life and the CPU clocks
-    (ISSUE 34): ``chunk`` / ``chunks`` and the phase totals ``h2d_us`` /
-    ``launch_us`` on ``dispatch_chunk``, ``chunk``, ``wait_us`` and
-    ``d2h_us`` on ``collect_chunk``, ``hash_us`` on ``prep_chunk``,
-    ``cpu_us`` on the call's outermost span alone and ``proc_cpu_us``
-    on ``verify_batch`` alone (here one span is both;
-    ``tests/test_mesh.py`` holds the same on the sharded path).
-    A refactor that renames any of these fails here, not on the
-    chip."""
+    """What ``chipbench`` reads from a verify call, held on one CPU ``verify_batch`` a chunk kind: the
+    ``dispatch_chunk`` span names the kind and carries ``lanes``/``padded_lanes``/``h2d_bytes``/``impl``;
+    the ``kernel_compile`` span names the kernel ``pad_lane_share.KERNEL_OF_KIND`` maps the kind to; and
+    the jitted program is called ``run…``, which is what the kernel-time and roofline entries find on the
+    device trace (``jit_run*``). And what ``sync500-rotation``'s metrics read of a set change (PR 32):
+    ``route_lanes``' ``legacy``, ``gather_tables``' ``builds``, ``resident_upload``'s ``reason`` and
+    ``width``, and, once eight newer sets have pushed a carried one out, ``note_validator_set``'s
+    ``retired`` / ``tables_dropped`` around a ``valset_hash`` and the ``resident_drop`` span. And the
+    chunk's life and the CPU clocks (ISSUE 34): ``chunk`` / ``chunks`` and the phase totals ``h2d_us`` /
+    ``launch_us`` on ``dispatch_chunk``, ``chunk``, ``wait_us`` and ``d2h_us`` on ``collect_chunk``,
+    ``hash_us`` on ``prep_chunk``, ``cpu_us`` on the call's outermost span alone and ``proc_cpu_us`` on
+    ``verify_batch`` alone (here one span is both; ``tests/test_mesh.py`` holds the same on the sharded
+    path). A refactor that renames any of these fails here, not on the chip."""
     from chipbench.readers.pad_lane_share import KERNEL_OF_KIND
     from tendermint_tpu.crypto.keys import Ed25519PrivKey
     from tendermint_tpu.libs import tracing
@@ -284,13 +278,10 @@ def _a_carried_set_pushed_out_by_eight_newer_ones() -> list:
 
 
 def test_program_still_emits_the_cache_spans_the_readers_match(monkeypatch):
-    """``prep_ms.*`` reads ``cache_lookup``, ``cache_store_ms.*`` reads
-    ``cache_store`` and ``engine_unnamed_ms.*`` takes both and
-    ``merge_results`` off ``verify_batch``: one span each a call, with
-    ``hits`` on the lookup and ``lanes`` / ``evicted`` on the store. Held
-    on two CPU calls whose batches carry two repeats: inside one batch a
-    repeat misses (and is stored) like any lane, from an earlier call it
-    hits."""
+    """Every cell's ``prep_ms`` reads ``cache_lookup``, its ``cache_store_ms`` reads ``cache_store`` and its
+    ``engine_unnamed_ms`` takes both and ``merge_results`` off ``verify_batch``: one span each a call, with
+    ``hits`` on the lookup and ``lanes`` / ``evicted`` on the store. Held on two CPU calls whose batches carry two
+    repeats: inside one batch a repeat misses (and is stored) like any lane, from an earlier call it hits."""
     from tendermint_tpu.crypto.keys import Ed25519PrivKey
     from tendermint_tpu.libs import tracing
     from tendermint_tpu.ops import ed25519_batch, precompute

@@ -1,7 +1,7 @@
 """The benchmark's part of the ``sync500rot`` deployment, without a chip:
 the plain rotation reference on hand-made sets, the cell's files, the
 generator's windows against the syncer's own on a real chain, the
-old-key-in-the-new-seat fault, the ``.rot`` metrics reduced on hand-made
+old-key-in-the-new-seat fault, the cell's metrics reduced on hand-made
 spans, and the cell's tiny twin rehearsed end to end on the CPU (a
 rehearsal proves paths, never numbers).
 """
@@ -15,11 +15,13 @@ import pytest
 
 from chipbench import reference, reference_light, reference_rotation, selftest, spec, workload
 from chipbench.run import Context
-from tests.helpers import rehearse_cell
+from tests.helpers import REAL_BENCH, Evidence, over_limit, read, rehearse_cell, sound, span
+from tests.test_chipbench_sync import BROKEN
 
 BENCH = os.path.join(spec.HERE, "testdata", "tiny-rotation-benchmark.json")
 CELL = "tiny-sync-rotation"
 SEED = 2**31 + 26
+REAL = (REAL_BENCH, "sync500-rotation")  # the cell a hand-made reading is named through
 
 
 # --- the plain reference ------------------------------------------------------
@@ -88,7 +90,7 @@ def test_reference_rotation_imports_nothing_of_the_program():
 
 def test_benchmark_files_agree():
     selftest.test_files()
-    real = spec.Spec(os.path.join(spec.ROOT, "BENCHMARK.json"))
+    real = spec.Spec(REAL_BENCH)
     cell = real.cell("sync500-rotation")
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("sync500rot", "rotating-windows", 1)
     config, control = real.config("sync500rot"), real.config("sync500")
@@ -113,14 +115,6 @@ def test_benchmark_files_agree():
     assert windows == 18
     assert config["blocks"] == config["first_change_height"] - 1 + windows * 16
     assert [m["name"] for m in real.metrics_for("end_to_end", "sync500-rotation")] == ["sigs_per_s", "setup_s"]
-    reported = [m["name"] for m in real.metrics_for("per_layer", "sync500-rotation")]
-    # its own twins, and since PR 34 the ten call-path metrics every
-    # ``sigs_per_s`` cell reports from one ``.stream`` file each
-    rot = [name for name in reported if name.endswith(".rot")]
-    stream = [name for name in reported if name.endswith(".stream")]
-    assert (len(rot), len(stream)) == (25, 10) and reported == rot + stream
-    tiny = spec.Spec(BENCH)
-    assert [m["name"] for m in tiny.metrics_for("per_layer", CELL)] == rot
 
 
 # --- the generator, in this process -----------------------------------------------
@@ -239,106 +233,64 @@ def test_the_generators_windows_are_the_syncers_on_the_same_chain(tiny, monkeypa
         assert len({obj for _, _, obj in theirs}) == 1
 
 
-# --- the .rot metrics on hand-made spans ----------------------------------------------
+# --- the cell's metrics on hand-made spans ----------------------------------------------
+
+
+def one_window():
+    """One call: verify_commits_pipelined 0..1000 holding build_lanes 10..500 (phases 300 + 20; two
+    note_validator_set spans of 40 inside it, the first holding a valset_hash of 25), verify_batch 510..900 (a
+    building gather_tables, route_lanes holding resident_upload, the drop inside the first note), merge_verdicts 910..950."""
+    return [
+        span("verify_commits_pipelined", 0, 1000, tasks=2, lanes=8),
+        span("build_lanes", 10, 490, lanes=8, sign_bytes_us=300.0, sign_bytes_n=8, basic_checks_us=20.0, basic_checks_n=2),
+        span("note_validator_set", 20, 40, recognised=False, retired=1, tables_dropped=1),
+        span("valset_hash", 22, 25, validators=12), span("resident_drop", 50, 5, keys=11, departed=1, reason="rotation"),
+        span("note_validator_set", 260, 40, recognised=True), span("verify_batch", 510, 390),
+        span("gather_tables", 520, 60, builds=1, deferred=2),
+        span("route_lanes", 590, 50, resident=6, tables=0, legacy=2, jobs=2),
+        span("resident_upload", 600, 30, keys=11, width=64, reason="dropped"), span("merge_verdicts", 910, 40),
+    ]
+
+
+ROTATION = [("pipeline_host_ms", 0.610), ("sign_bytes_ms", 0.300), ("note_set_ms", 0.080), ("valset_hash_ms", 0.025),
+            ("table_build_ms", 0.060), ("resident_upload_ms", 0.030), ("resident_drop_ms", 0.005),
+            ("legacy_lanes", 2), ("tables_dropped", 1)]
+
+
+@pytest.mark.parametrize("stem,want", ROTATION)
+def test_rotation_metric_on_nested_spans(stem, want):
+    assert read(Evidence(one_window()), *REAL, stem) == pytest.approx(want)
 
 
 def test_rotation_metrics_add_up_on_nested_spans():
-    """One call: verify_commits_pipelined 0..1000 holding build_lanes
-    10..500 (phases 300 + 20; two note_validator_set spans of 40 inside
-    it, the first holding a valset_hash of 25), verify_batch 510..900
-    (a building gather_tables, route_lanes holding resident_upload, the
-    drop inside the first note), merge_verdicts 910..950."""
-
-    class Evidence:
-        calls = [{}]
-
-    def span(name, ts, dur, **args):
-        return {"name": name, "ts": float(ts), "dur": float(dur), "args": args}
-
-    ev = Evidence()
-    ev.spans = [
-        span("verify_commits_pipelined", 0, 1000, tasks=2, lanes=8),
-        span("build_lanes", 10, 490, lanes=8, sign_bytes_us=300.0, sign_bytes_n=8,
-             basic_checks_us=20.0, basic_checks_n=2),
-        span("note_validator_set", 20, 40, recognised=False, retired=1, tables_dropped=1),
-        span("valset_hash", 22, 25, validators=12),
-        span("resident_drop", 50, 5, keys=11, departed=1, reason="rotation"),
-        span("note_validator_set", 260, 40, recognised=True),
-        span("verify_batch", 510, 390),
-        span("gather_tables", 520, 60, builds=1, deferred=2),
-        span("route_lanes", 590, 50, resident=6, tables=0, legacy=2, jobs=2),
-        span("resident_upload", 600, 30, keys=11, width=64, reason="dropped"),
-        span("merge_verdicts", 910, 40),
-    ]
-
-    def read(name):
-        doc = spec.layer_metric(name)
-        return spec.reader(doc["reader"]).read(ev, **doc["args"])
-
-    assert read("pipeline_host_ms.rot") == pytest.approx(0.610)
-    assert read("sign_bytes_ms.rot") == pytest.approx(0.300)
-    assert read("note_set_ms.rot") == pytest.approx(0.080)
-    assert read("valset_hash_ms.rot") == pytest.approx(0.025)
+    ev = Evidence(one_window())
     named = 0.300 + 0.020 + 0.080 + 0.040  # sign-bytes, basic checks, note, merge
-    assert read("pipeline_unnamed_ms.rot") + named == pytest.approx(read("pipeline_host_ms.rot"))
-    assert read("table_build_ms.rot") == pytest.approx(0.060)
-    assert read("resident_upload_ms.rot") == pytest.approx(0.030)
-    assert read("resident_drop_ms.rot") == pytest.approx(0.005)
-    assert read("legacy_lanes.rot") == 2 and read("tables_dropped.rot") == 1
-    # a program without the new span and arguments (the parent): nothing
-    # to read, or zero, and no error
+    assert read(ev, *REAL, "pipeline_unnamed_ms") + named == pytest.approx(read(ev, *REAL, "pipeline_host_ms"))
+    # a program without the new span and arguments (the parent): nothing to read, or zero, and no error
     ev.spans = [s for s in ev.spans if s["name"] != "resident_drop"]
     for s in ev.spans:
         s["args"].pop("tables_dropped", None)
-    assert read("tables_dropped.rot") is None
-    assert read("resident_drop_ms.rot") == 0.0
+    assert read(ev, *REAL, "tables_dropped") is None
+    assert read(ev, *REAL, "resident_drop_ms") == 0.0
 
 
 # --- the tiny twin, end to end ----------------------------------------------------------
 
 
-def rehearse(trace: int, *extra):
-    return rehearse_cell(BENCH, CELL, SEED, trace, *extra)
-
-
 def test_tiny_twin_of_sync500_rotation_rehearses_on_the_cpu():
-    out, said = rehearse(1)
-    assert out["correct"] is True and out["failed"] == 0 and out["attempted"] > 0
-    want = {m["name"] for m in spec.Spec(BENCH).metrics_for("per_layer", CELL)}
-    assert len(want) == 25
-    value = {name: out["metrics"][name]["value"] for name in want}
-    assert all(isinstance(v, float) for v in value.values())
-    # every call shows the mechanism: a table dropped with the retired
-    # set, the store dropped and sent again, a newcomer's table built,
-    # the youngest keys' lanes on the legacy kernel
-    assert value["tables_dropped.rot"] == 1.0
-    assert 0 < value["legacy_lanes.rot"] <= 8
-    assert 0 < value["resident_hit_share.rot"] < 100
-    for name in ("valset_hash_ms.rot", "table_build_ms.rot", "resident_upload_ms.rot", "resident_drop_ms.rot"):
-        assert value[name] > 0, name
-    assert value["valset_hash_ms.rot"] < value["note_set_ms.rot"]
-    for name in ("verdict_cache_hits_in_window", "compilations_in_window", "timed_blocks_refused",
-                 "sets_registered_in_window", "windows_with_a_wrong_block_verdict",
-                 "lanes_where_reference_disagrees"):
-        assert "compared: %s = 0 (limit 0)" % name in said, name
+    compared = ("verdict_cache_hits_in_window", "compilations_in_window", "timed_blocks_refused",
+                "sets_registered_in_window", "windows_with_a_wrong_block_verdict", "lanes_where_reference_disagrees")
+    value = sound(*rehearse_cell(BENCH, CELL, SEED, 1), compared, BENCH, CELL)
+    # every call shows the mechanism: a table dropped with the retired set, the store dropped and sent
+    # again, a newcomer's table built, the youngest keys' lanes on the legacy kernel
+    assert value("tables_dropped") == 1.0 and 0 < value("legacy_lanes") <= 8 and 0 < value("resident_hit_share") < 100
+    for stem in ("valset_hash_ms", "table_build_ms", "resident_upload_ms", "resident_drop_ms"):
+        assert value(stem) > 0, stem
+    assert value("valset_hash_ms") < value("note_set_ms")
 
 
-@pytest.mark.parametrize(
-    "brk,over",
-    [
-        # one lane's verdict inverted where the engine returns it
-        ("flip_verdict", ["timed_blocks_refused", "windows_with_a_wrong_block_verdict",
-                          "lanes_where_reference_disagrees"]),
-        # the engine's s < L check off: the included s + L lane verifies
-        ("no_canonical_s", ["windows_with_a_wrong_block_verdict", "lanes_where_reference_disagrees"]),
-    ],
-)
+@pytest.mark.parametrize("brk,over", BROKEN)  # ``sync500``'s controls, over in the same comparisons
 def test_tiny_twin_broken_on_purpose_comes_out_not_correct(brk, over):
-    """The controls (``breaks.py``) have to show in the cell's own
-    comparisons, not in the harness's two."""
-    out, said = rehearse(0, "--break", brk)
-    assert out["correct"] is False
-    assert over == [
-        ln.split("compared: ", 1)[1].split(" = ")[0]
-        for ln in said.splitlines() if ln.endswith("<-- over")
-    ]
+    """The controls (``breaks.py``) have to show in the cell's own comparisons, not in the harness's two."""
+    out, said = rehearse_cell(BENCH, CELL, SEED, 0, "--break", brk)
+    assert out["correct"] is False and over_limit(said) == over

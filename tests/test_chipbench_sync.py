@@ -12,9 +12,10 @@ import os
 import pytest
 
 from chipbench import reference_light, selftest, spec, workload
-from tests.helpers import rehearse_cell
+from tests.helpers import REAL_BENCH, Evidence, over_limit, read, rehearse_cell, sound, span
 
 ABSENT, COMMIT, NIL = 1, 2, 3
+REAL = (REAL_BENCH, "sync500-catchup")  # the cell a hand-made reading is named through
 
 
 def block(n, flags, bad=(), powers=None):
@@ -68,7 +69,7 @@ def test_reference_light_blocks_do_not_look_at_their_neighbours():
 
 def test_benchmark_files_agree():
     selftest.test_files()
-    real = spec.Spec(os.path.join(spec.ROOT, "BENCHMARK.json"))
+    real = spec.Spec(REAL_BENCH)
     cell = real.cell("sync500-catchup")
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("sync500", "catchup-windows", 1)
     config = real.config("sync500")
@@ -83,82 +84,57 @@ def test_benchmark_files_agree():
     assert [m["name"] for m in real.metrics_for("end_to_end", "sync500-catchup")] == ["sigs_per_s", "setup_s"]
 
 
-def test_pipeline_metrics_add_up_on_nested_spans():
+def one_window():
     """One call: verify_commits_pipelined 0..1000 holding build_lanes
     10..500 (phases 300 + 20; two note_validator_set spans of 40 inside
     it), verify_batch 510..900, merge_verdicts 910..950."""
-
-    class Evidence:
-        calls = [{}]
-
-    def span(name, ts, dur, **args):
-        return {"name": name, "ts": float(ts), "dur": float(dur), "args": args}
-
-    ev = Evidence()
-    ev.spans = [
+    return [
         span("verify_commits_pipelined", 0, 1000, tasks=2, lanes=8),
-        span("build_lanes", 10, 490, lanes=8, sign_bytes_us=300.0, sign_bytes_n=8,
-             basic_checks_us=20.0, basic_checks_n=2),
+        span("build_lanes", 10, 490, lanes=8, sign_bytes_us=300.0, sign_bytes_n=8, basic_checks_us=20.0, basic_checks_n=2),
         span("note_validator_set", 20, 40), span("note_validator_set", 260, 40),
-        span("verify_batch", 510, 390),
-        span("merge_verdicts", 910, 40),
+        span("verify_batch", 510, 390), span("merge_verdicts", 910, 40),
     ]
 
-    def read(name):
-        doc = spec.layer_metric(name)
-        return spec.reader(doc["reader"]).read(ev, **doc["args"])
 
-    assert read("pipeline_host_ms") == pytest.approx(0.610)
-    assert read("sign_bytes_ms.sync") == pytest.approx(0.300)
-    assert read("note_set_ms.sync") == pytest.approx(0.080)
-    # gaps 10 + 10 + 10 + 50 = 80 outside the children; the loop's 490
-    # less its phases 320 and the 80 of the spans inside it = 90
-    assert read("pipeline_unnamed_ms") == pytest.approx(0.170)
+# gaps 10 + 10 + 10 + 50 = 80 outside the children; the loop's 490 less its phases 320 and the 80 of the spans inside it = 90
+PIPELINE = [("pipeline_host_ms", 0.610), ("sign_bytes_ms", 0.300), ("note_set_ms", 0.080), ("pipeline_unnamed_ms", 0.170)]
+
+
+@pytest.mark.parametrize("stem,want", PIPELINE)
+def test_pipeline_metric_on_nested_spans(stem, want):
+    assert read(Evidence(one_window()), *REAL, stem) == pytest.approx(want)
+
+
+def test_pipeline_metrics_add_up_on_nested_spans():
+    got = {stem: read(Evidence(one_window()), *REAL, stem) for stem, _ in PIPELINE}
     named = 0.300 + 0.020 + 0.080 + 0.040  # sign-bytes, basic checks, note, merge
-    assert read("pipeline_unnamed_ms") + named == pytest.approx(read("pipeline_host_ms"))
+    assert got["pipeline_unnamed_ms"] + named == pytest.approx(got["pipeline_host_ms"])
     # the parent's program records none of these spans: nothing to read
-    ev.spans = [span("verify_batch", 510, 390)]
-    for name in ("pipeline_host_ms", "sign_bytes_ms.sync", "pipeline_unnamed_ms"):
-        assert read(name) is None
+    ev = Evidence([span("verify_batch", 510, 390)])
+    for stem in ("pipeline_host_ms", "sign_bytes_ms", "pipeline_unnamed_ms"):
+        assert read(ev, *REAL, stem) is None
 
 
-BENCH = os.path.join(spec.HERE, "testdata", "tiny-sync-benchmark.json")
-
-
-def rehearse(trace: int, *extra):
-    return rehearse_cell(BENCH, "tiny-sync-catchup", 2**31 + 26, trace, *extra)
+BENCH, CELL, SEED = os.path.join(spec.HERE, "testdata", "tiny-sync-benchmark.json"), "tiny-sync-catchup", 2**31 + 26
 
 
 def test_tiny_twin_of_sync500_catchup_rehearses_on_the_cpu():
-    out, said = rehearse(1)
-    assert out["correct"] is True and out["failed"] == 0 and out["attempted"] > 0
-    tiny = spec.Spec(BENCH)
-    want = {m["name"] for m in tiny.metrics_for("per_layer", "tiny-sync-catchup")}
-    assert len(want) == 19
-    for name in want:
-        assert isinstance(out["metrics"][name]["value"], float), name
-    assert out["metrics"]["resident_hit_share.sync"]["value"] == 100.0
-    for name in ("verdict_cache_hits_in_window", "compilations_in_window", "timed_blocks_refused",
-                 "windows_with_a_wrong_block_verdict", "lanes_where_reference_disagrees"):
-        assert "compared: %s = 0 (limit 0)" % name in said, name
+    compared = ("verdict_cache_hits_in_window", "compilations_in_window", "timed_blocks_refused",
+                "windows_with_a_wrong_block_verdict", "lanes_where_reference_disagrees")
+    value = sound(*rehearse_cell(BENCH, CELL, SEED, 1), compared, BENCH, CELL)
+    assert value("resident_hit_share") == 100.0
 
 
-@pytest.mark.parametrize(
-    "brk,over",
-    [
-        # one lane's verdict inverted where the engine returns it
-        ("flip_verdict", ["timed_blocks_refused", "windows_with_a_wrong_block_verdict",
-                          "lanes_where_reference_disagrees"]),
-        # the engine's s < L check off: the included s + L lane verifies
-        ("no_canonical_s", ["windows_with_a_wrong_block_verdict", "lanes_where_reference_disagrees"]),
-    ],
-)
+BROKEN = [  # ``tests/test_chipbench_rotation.py`` holds its cell, this deployment with a set that changes, to the same
+    # one lane's verdict inverted where the engine returns it
+    ("flip_verdict", ["timed_blocks_refused", "windows_with_a_wrong_block_verdict", "lanes_where_reference_disagrees"]),
+    # the engine's s < L check off: the included s + L lane verifies
+    ("no_canonical_s", ["windows_with_a_wrong_block_verdict", "lanes_where_reference_disagrees"]),
+]
+
+
+@pytest.mark.parametrize("brk,over", BROKEN)
 def test_tiny_twin_broken_on_purpose_comes_out_not_correct(brk, over):
-    """The controls (``breaks.py``) have to show in the cell's own
-    comparisons, not in the harness's two."""
-    out, said = rehearse(0, "--break", brk)
-    assert out["correct"] is False
-    assert over == [
-        ln.split("compared: ", 1)[1].split(" = ")[0]
-        for ln in said.splitlines() if ln.endswith("<-- over")
-    ]
+    """The controls (``breaks.py``) have to show in the cell's own comparisons, not in the harness's two."""
+    out, said = rehearse_cell(BENCH, CELL, SEED, 0, "--break", brk)
+    assert out["correct"] is False and over_limit(said) == over
