@@ -22,7 +22,10 @@ jax, but touches no device).
   device hashing on fixed-width sign-bytes), then a commit with a
   flipped ``R``, a flipped ``s`` byte and an ``s >= L`` whose failing
   lanes must be attributed exactly; beside them one direct
-  ``ops.verify_batch`` call on ZIP-215 edge vectors; then two blocksync
+  ``ops.verify_batch`` call on ZIP-215 edge vectors; then one engine
+  job + 204 lanes of keys of no set added lane by lane to a
+  ``crypto.BatchVerifier``, which begins the full job before
+  ``verify()``, tampered on both sides of that seam; then two blocksync
   windows of 16 commits at 500 validators through
   ``parallel/pipeline.verify_commits_pipelined`` (one sound, one with a
   block tampered on both sides of its early exit); then sr25519 lanes
@@ -85,6 +88,7 @@ SEED = 21
 # VerifyCommit p50 at (three 4096-lane chunks, ~10 MiB of resident tables).
 SIZES = (150, 10_000)
 HEIGHTS = 3  # heights verified over the same set after the cold pass
+EARLY_TAIL = 204  # lanes past one full engine job in the early-begin batch (pads to 256)
 SYNC_VALS = 500  # the blocksync window: BASELINE.json config 4's committee
 SYNC_WINDOW = 16  # blocksync/syncer.DEFAULT_VERIFY_WINDOW
 SR_BUCKETS = (64, 256, 1024, 4096)  # every width an sr25519 chunk is padded to
@@ -565,6 +569,62 @@ def _run_size(n: int, heights: int, dev: dict, impl: str, paths: dict) -> dict:
     }
 
 
+def _run_early_begin(tail: int, impl: str) -> dict:
+    """One full engine job and ``tail`` lanes more, of keys no set
+    holds, added lane by lane to a ``crypto.BatchVerifier``: the
+    verifier begins the job's lanes when lane job + 1 arrives (the
+    early begin, crypto/batch.DeviceBatchVerifier) and the rest at
+    ``verify()``. The lanes on both sides of the seam and the last are
+    tampered and must be refused, each at its place and nowhere else.
+    On one chip 4,096 + 204 -> 256 lanes of the legacy kernel, widths
+    the sizes above have compiled."""
+    import numpy as np
+
+    from tendermint_tpu.crypto.keys import Ed25519PrivKey
+    from tendermint_tpu.ops import ed25519_batch
+
+    _fresh_node()
+    job = ed25519_batch.job_lanes()
+    n = job + tail
+    what = "early begin at %d + %d lanes" % (job, tail)
+    privs = [
+        Ed25519PrivKey.from_seed((77_000_000 + i).to_bytes(32, "big")) for i in range(n)
+    ]
+    pks = [p.pub_key().bytes() for p in privs]
+    # lengths differ inside every chunk: hashed on the host, so that no
+    # device hash kernel is compiled for the tail's lane count
+    msgs = [b"chip-smoke early begin %d " % i + b"." * (i % 5) for i in range(n)]
+    sigs = [p.sign(m) for p, m in zip(privs, msgs)]
+    picks = [job - 1, job, n - 1]
+    for i in picks:
+        sig = bytearray(sigs[i])
+        sig[32] ^= 0x01
+        sigs[i] = bytes(sig)
+    before = _counters()
+    _drain_spans()
+    verdicts = _batch_verify(privs[0].pub_key(), pks, msgs, sigs)
+    refused = [i for i, v in enumerate(verdicts) if not v]
+    check(refused == picks, "%s: refused lanes %r, tampered lanes %r", what, refused[:16], picks)
+    rng = np.random.default_rng(SEED)
+    _check_oracle(
+        pks, msgs, sigs, verdicts, sorted(set(picks) | set(rng.permutation(n)[:256].tolist())), what
+    )
+    spans = _drain_spans()
+    _check_dispatch(spans, {"legacy": n}, what)
+    _check_health(_delta(before), what)
+    begun = [
+        (int(e["args"]["lanes"]), e["args"].get("early", 0))
+        for e in sorted(spans, key=lambda e: e["ts"])
+        if e["name"] == "batch_verify" and e["args"].get("phase") == "dispatch"
+    ]
+    check(
+        begun == [(job, 1), (tail, 0)],
+        "%s: blocks begun (lanes, early) %r, want the job early and the rest at verify()",
+        what, begun,
+    )
+    return {"job": job, "tail": tail, "refused": refused, "compiles": _compiles(spans, impl)}
+
+
 def _run_pipelined_windows(n: int, window: int, paths: dict, impl: str) -> dict:
     """Two windows of ``window`` commits over one ``n``-validator set
     through ``verify_commits_pipelined``, called as the block syncer
@@ -769,6 +829,7 @@ def _run_mixed(n: int, impl: str) -> dict:
 def library_phase(
     expect_platform: str, sizes=SIZES, heights: int = HEIGHTS,
     sync=(SYNC_VALS, SYNC_WINDOW), sr_buckets=SR_BUCKETS, mixed: int = MIXED_VALS,
+    early_tail: int = EARLY_TAIL,
 ) -> dict:
     """The library phase, in this process. Raises SmokeFailure."""
     from tendermint_tpu.ops import backend as ops_backend
@@ -854,6 +915,13 @@ def library_phase(
             say("  counters %(counters)r" % rep)
             say("  compiled %(compiles)r" % rep)
             say("  lanes/device %(lanes_per_device)r sharded %(sharded)r" % rep)
+
+        if early_tail:
+            rep = report["early_begin"] = _run_early_begin(early_tail, impl)
+            say(
+                "early begin: %(job)d lanes begun at the add after them, %(tail)d at "
+                "verify(), lanes %(refused)r tampered and refused; compiled %(compiles)r" % rep
+            )
 
         if sync:
             rep = _run_pipelined_windows(sync[0], sync[1], paths, impl)

@@ -141,6 +141,11 @@ class BatchVerifier:
     """crypto.BatchVerifier contract (crypto/crypto.go:58-76): Add entries,
     then Verify once; returns (all_valid, per-entry validity)."""
 
+    # True once the verifier holds a full engine job of lanes it has not
+    # begun: what a caller's loop may look at, an attribute read a lane.
+    # A verifier with no device path never has one.
+    ready = False
+
     def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> None:
         raise NotImplementedError
 
@@ -155,21 +160,38 @@ class BatchVerifier:
         verdict = self.verify()
         return PendingVerify(lambda: verdict)
 
+    def begin_ready(self) -> int:
+        """Begin on the device, a job at a time, the full jobs of lanes
+        held and not yet begun; returns the lanes so begun. ``add`` does
+        it by itself one lane later, so a caller need not; one that
+        looks at ``ready`` after each ``add`` starts the device that
+        much sooner, and outside whatever it times around ``add``.
+        Nothing to begin here, as for every verifier with no device
+        path."""
+        return 0
+
+    def close(self) -> None:
+        """Collect what was begun and never finished and drop the
+        verdicts: for a caller that leaves without ``verify()``, so that
+        no in-flight gauge stays up and no probe stays latched. Does
+        nothing after ``verify()``, or twice."""
+
     def __len__(self) -> int:
         raise NotImplementedError
 
 
-def begin_on_device(key_type: str, lanes: int, begin_batch) -> PendingVerify:
+def begin_on_device(key_type: str, lanes: int, begin_batch, early: bool = False) -> PendingVerify:
     """A device batch verifier's ``begin``: ``begin_batch()`` — the
     engine's ``ops.begin_verify_batch`` / ``begin_verify_batch_sr`` on
     the verifier's lanes, which it lays out under the span, as
     ``verify()`` does — and, later, its ``finish``, each under a
     ``batch_verify`` span as ``verify()`` opens one (``phase``
-    ``dispatch`` / ``collect``). One caller's thread does one thing at
+    ``dispatch`` / ``collect``; ``early=1`` on the dispatch of a block
+    begun before ``verify()``). One caller's thread does one thing at
     a time, so the two spans of a batch never overlap what ran between
     them."""
     tags = dict(key_type=key_type, lanes=lanes, route="device")
-    with tracing.span("batch_verify", phase="dispatch", **tags):
+    with tracing.span("batch_verify", phase="dispatch", **(dict(tags, early=1) if early else tags)):
         pending = begin_batch()
 
     def finish() -> Tuple[bool, List[bool]]:
@@ -180,7 +202,181 @@ def begin_on_device(key_type: str, lanes: int, begin_batch) -> PendingVerify:
     return PendingVerify(finish, pending.lanes_inflight)
 
 
-class Ed25519BatchVerifier(BatchVerifier):
+def _drop(blocks: List[PendingVerify]) -> None:
+    """Finish ``blocks`` for what finishing settles (the in-flight
+    gauge, the health machine's probe) and drop the verdicts; an
+    exception of theirs is not the caller's first, or the caller has
+    left."""
+    for block in blocks:
+        try:
+            block.finish()
+        except Exception:
+            pass  # the first exception is the caller's, or nobody is left to tell
+
+
+def finish_in_order(blocks: List[PendingVerify]) -> PendingVerify:
+    """The blocks of one batch as one ``PendingVerify``: each finished
+    in the order begun, the verdicts concatenated. One that raises
+    leaves the rest finished all the same."""
+
+    def finish() -> Tuple[bool, List[bool]]:
+        left = list(blocks)
+        verdicts: List[bool] = []
+        try:
+            while left:
+                verdicts += left.pop(0).finish()[1]
+        finally:
+            _drop(left)
+        return all(verdicts), verdicts
+
+    return PendingVerify(finish, sum(block.lanes_inflight for block in blocks))
+
+
+_NEVER = 1 << 62  # lanes no batch holds
+
+
+class DeviceBatchVerifier(BatchVerifier):
+    """What the two verifiers with a device engine share
+    (:class:`Ed25519BatchVerifier`, ``crypto.sr25519.Sr25519BatchVerifier``):
+    the route's rule, ``verify()``'s span, and the early begin.
+
+    The early begin: the engine need not wait for ``verify()`` to hear
+    of lanes the verifier already holds. Whenever it holds one full
+    engine job of lanes not yet begun (``ops.ed25519_batch.job_lanes``:
+    what one launch takes, on every device a batch that large would
+    span) and the batch is this process's device's, those lanes are
+    begun as a block of their own — lookup, gather, route, prep,
+    dispatch — and the kernel runs while the caller goes on adding.
+    ``verify()`` / ``begin()`` begin what is left, then finish every
+    block in the order begun: the verdicts are those of one
+    ``verify()`` of the whole batch, lane for lane, from the same
+    kernels at the same widths (the engine cuts a batch at the same
+    lanes). A batch under one job, a remote's or the host's begins
+    nothing early. ``add`` keeps ``ready`` and begins a ready job when
+    the next lane arrives; ``begin_ready()`` is for the caller that
+    looked.
+
+    A subclass names its ``key_type`` and gives ``__len__``,
+    ``_verify(span)`` (the whole batch, now) and ``_device_begin()``.
+    """
+
+    key_type = ""
+
+    def __init__(self, device_threshold: int, use_device: Optional[bool]):
+        self.device_threshold = device_threshold
+        self.use_device = use_device  # None = auto
+        self.ready = False
+        self._begun = 0  # lanes in the blocks begun early
+        self._blocks: List[PendingVerify] = []  # those blocks, in the order begun, until finished
+        self._job = 0  # lanes one engine job holds, once asked
+        self._look_at = 1  # lanes held at which add looks next (_look)
+
+    def _wants_device(self) -> bool:
+        """The route's rule: ``use_device``, or by the threshold."""
+        n = len(self)
+        use_device = self.use_device
+        if use_device is None:
+            use_device = n >= self.device_threshold
+        return bool(n and use_device)
+
+    def _device_begin(self):
+        """``begin(lo, hi, early)`` -> the engine's ``PendingBatch`` of
+        lanes ``lo:hi``, where this batch is this process's device's;
+        else None."""
+        raise NotImplementedError
+
+    def _verify(self, span) -> Tuple[bool, List[bool]]:
+        raise NotImplementedError
+
+    def _begins_warm(self, lo: int, hi: int) -> bool:
+        """Whether beginning lanes ``lo:hi`` now is what ``verify()`` of
+        the whole batch would do for them, only sooner: not before this
+        engine's first full job in the process, whose launch compiles
+        or loads the kernel (a process's first calls keep their order)."""
+        from tendermint_tpu.ops.ed25519_batch import job_has_run
+
+        return job_has_run(self.key_type)
+
+    def _look(self) -> None:
+        """``add``'s look, when the lanes held reach ``_look_at``. While
+        the batch is not the device's (under the threshold, a remote's)
+        it looks again at twice the lanes; from then on at the end of
+        the next job, where it sets ``ready``, and one lane later, where
+        it begins what a caller that never looked has left ready."""
+        n = len(self)
+        if self.ready:
+            self.begin_ready()
+        elif not self._job:
+            if self._device_begin() is None:
+                self._look_at = 2 * n
+                return
+            from tendermint_tpu.ops.ed25519_batch import job_lanes
+
+            self._look_at = self._job = job_lanes()
+        if n >= self._look_at:
+            self.ready = True
+            self._look_at = n + 1
+
+    def begin_ready(self) -> int:
+        if not self.ready:
+            return 0
+        self.ready = False
+        begin_lanes = self._device_begin()
+        if begin_lanes is None:  # no longer the device's: verify() will say
+            self._look_at = _NEVER
+            return 0
+        first = self._begun
+        while len(self) - self._begun >= self._job:
+            lo, hi = self._begun, self._begun + self._job
+            if not self._begins_warm(lo, hi):
+                self._look_at = _NEVER  # verify() takes them with the rest
+                return self._begun - first
+            self._blocks.append(
+                begin_on_device(self.key_type, hi - lo, lambda: begin_lanes(lo, hi, True), early=True)
+            )
+            self._begun = hi
+        self._look_at = self._begun + self._job
+        return self._begun - first
+
+    def verify(self) -> Tuple[bool, List[bool]]:
+        if self._blocks:
+            return self.begin().finish()
+        with tracing.span(
+            "batch_verify", key_type=self.key_type, lanes=len(self), route="host"
+        ) as span:
+            return self._verify(span)
+
+    def begin(self) -> PendingVerify:
+        begin_lanes = self._device_begin()
+        blocks, self._blocks = self._blocks, []
+        lo, n = self._begun, len(self)
+        # from here the batch is verified as a whole, whoever asks again
+        self.ready, self._begun, self._look_at = False, 0, _NEVER
+        if begin_lanes is None:
+            _drop(blocks)  # begun for a device this batch is no longer for
+            return super().begin()
+        if lo < n or not blocks:
+            try:
+                blocks.append(
+                    begin_on_device(self.key_type, n - lo, lambda: begin_lanes(lo, n, False))
+                )
+            except BaseException:
+                _drop(blocks)
+                raise
+        return blocks[0] if len(blocks) == 1 else finish_in_order(blocks)
+
+    def close(self) -> None:
+        blocks, self._blocks = self._blocks, []
+        _drop(blocks)
+
+    def __del__(self):
+        # dropped with blocks in flight; nothing to do for one whose
+        # __init__ never ran to its end
+        if getattr(self, "_blocks", None):
+            self.close()
+
+
+class Ed25519BatchVerifier(DeviceBatchVerifier):
     """Accumulate-then-flush ed25519 batch verification.
 
     Above ``device_threshold`` entries the batch is verified on the
@@ -190,16 +386,17 @@ class Ed25519BatchVerifier(BatchVerifier):
     types/validation.go:12-16).
     """
 
+    key_type = ED25519_KEY_TYPE
+
     def __init__(
         self,
         device_threshold: int = DEVICE_THRESHOLD,
         use_device: Optional[bool] = None,
     ):
+        super().__init__(device_threshold, use_device)
         self._pks: List[bytes] = []
         self._msgs: List[bytes] = []
         self._sigs: List[bytes] = []
-        self.device_threshold = device_threshold
-        self.use_device = use_device  # None = auto
 
     def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> None:
         if pub_key.type != ED25519_KEY_TYPE:
@@ -210,27 +407,16 @@ class Ed25519BatchVerifier(BatchVerifier):
         self._pks.append(pk)
         self._msgs.append(msg)
         self._sigs.append(sig)
+        if len(self._sigs) >= self._look_at:
+            self._look()
 
     def __len__(self) -> int:
         return len(self._pks)
 
-    def verify(self) -> Tuple[bool, List[bool]]:
-        with tracing.span(
-            "batch_verify",
-            key_type=ED25519_KEY_TYPE,
-            lanes=len(self._pks),
-            route="host",
-        ) as span:
-            return self._verify(span)
-
     def _route(self):
         """``(route, how)``: ``device`` and the engine's module,
         ``remote`` and the backend's ``verify_fn``, or ``host``."""
-        n = len(self._pks)
-        use_device = self.use_device
-        if use_device is None:
-            use_device = n >= self.device_threshold
-        if n and use_device:
+        if self._wants_device():
             # A configured verifyd remote owns the accelerator for this
             # process: ship device-worthy batches to it (it amortizes
             # across clients; its client falls back to host verify on
@@ -259,14 +445,22 @@ class Ed25519BatchVerifier(BatchVerifier):
             oks = host_verify_ed25519(self._pks, self._msgs, self._sigs)
         return all(oks), list(oks)
 
-    def begin(self) -> PendingVerify:
+    def _begins_warm(self, lo: int, hi: int) -> bool:
+        # not where the engine would build tables for the block: the
+        # device store's width, and with it the kernel's compiled
+        # shape, would follow the block (precompute.would_build)
+        from tendermint_tpu.ops import precompute
+
+        return super()._begins_warm(lo, hi) and not precompute.tables.would_build(
+            self._pks[lo:hi]
+        )
+
+    def _device_begin(self):
         route, ops = self._route()
         if route != "device":
-            return super().begin()
-        return begin_on_device(
-            ED25519_KEY_TYPE,
-            len(self._pks),
-            lambda: ops.begin_verify_batch(self._pks, self._msgs, self._sigs),
+            return None
+        return lambda lo, hi, early: ops.begin_verify_batch(
+            self._pks[lo:hi], self._msgs[lo:hi], self._sigs[lo:hi], early=early
         )
 
 
@@ -357,6 +551,7 @@ class MultiBatchVerifier(BatchVerifier):
     def __init__(self):
         self._subs: dict = {}
         self._order: List[Tuple[str, int]] = []  # (key type, idx in sub)
+        self.ready = False  # a sub-verifier is
 
     def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> None:
         kt = pub_key.type
@@ -369,9 +564,20 @@ class MultiBatchVerifier(BatchVerifier):
             self._subs[kt] = sub
         sub.add(pub_key, msg, sig)
         self._order.append((kt, len(sub) - 1))
+        if sub.ready:
+            self.ready = True
 
     def __len__(self) -> int:
         return len(self._order)
+
+    def begin_ready(self) -> int:
+        """Each sub-verifier's, in the order of its type's name."""
+        self.ready = False
+        return sum(self._subs[kt].begin_ready() for kt in sorted(self._subs))
+
+    def close(self) -> None:
+        for sub in self._subs.values():
+            sub.close()
 
     def _verify_in_phases(self) -> dict:
         """Key type -> verdicts of a batch of two sub-verifiers or more."""
@@ -393,11 +599,7 @@ class MultiBatchVerifier(BatchVerifier):
             # a begin, the host lanes or a finish raised: what is still
             # in flight is collected all the same, so that no in-flight
             # gauge stays up and no probe stays latched
-            for _, p in pending:
-                try:
-                    p.finish()
-                except Exception:
-                    pass  # the first exception is the caller's
+            _drop([p for _, p in pending])
         return results
 
     def verify(self) -> Tuple[bool, List[bool]]:

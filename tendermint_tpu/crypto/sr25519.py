@@ -27,7 +27,7 @@ import os
 from typing import List, Optional, Tuple
 
 from tendermint_tpu.crypto import ristretto
-from tendermint_tpu.crypto.batch import BatchVerifier, PendingVerify, begin_on_device
+from tendermint_tpu.crypto.batch import DEVICE_THRESHOLD, DeviceBatchVerifier
 from tendermint_tpu.crypto.keys import (
     ADDRESS_LEN,
     SR25519_KEY_TYPE,
@@ -48,7 +48,6 @@ from tendermint_tpu.crypto.ristretto import (
     scalar_from_canonical,
     scalar_from_wide,
 )
-from tendermint_tpu.libs import tracing
 
 PUBKEY_SIZE = 32
 SIGNATURE_SIZE = 64
@@ -233,7 +232,7 @@ class Sr25519PrivKey(PrivKey):
 _OPS_IMPORT_WARNED = False  # one warning per process for a jax-less install
 
 
-class Sr25519BatchVerifier(BatchVerifier):
+class Sr25519BatchVerifier(DeviceBatchVerifier):
     """Batch verifier with a device path and a host fallback.
 
     Above ``device_threshold`` entries the batch rides the ristretto
@@ -246,42 +245,30 @@ class Sr25519BatchVerifier(BatchVerifier):
     (types/validation.go:244-251).
     """
 
+    key_type = SR25519_KEY_TYPE
+
     def __init__(self, device_threshold: Optional[int] = None,
                  use_device: Optional[bool] = None):
-        from tendermint_tpu.crypto.batch import DEVICE_THRESHOLD
-
-        self._entries: List[Tuple[bytes, bytes, bytes]] = []
-        self.device_threshold = (
-            DEVICE_THRESHOLD if device_threshold is None else device_threshold
+        super().__init__(
+            DEVICE_THRESHOLD if device_threshold is None else device_threshold, use_device
         )
-        self.use_device = use_device  # None = auto
+        self._entries: List[Tuple[bytes, bytes, bytes]] = []
 
     def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> None:
         if pub_key.type != SR25519_KEY_TYPE:
             raise ValueError("sr25519 batch: pubkey is not sr25519")
         self._entries.append((pub_key.bytes(), msg, sig))
+        if len(self._entries) >= self._look_at:
+            self._look()
 
     def __len__(self) -> int:
         return len(self._entries)
-
-    def verify(self) -> Tuple[bool, List[bool]]:
-        with tracing.span(
-            "batch_verify",
-            key_type=SR25519_KEY_TYPE,
-            lanes=len(self._entries),
-            route="host",
-        ) as span:
-            return self._verify(span)
 
     def _device_engine(self):
         """``ops.sr25519_batch`` where this batch goes to the device,
         else None (under the threshold, switched off, or no engine in
         this install)."""
-        n = len(self._entries)
-        use_device = self.use_device
-        if use_device is None:
-            use_device = n >= self.device_threshold
-        if n and use_device:
+        if self._wants_device():
             try:
                 from tendermint_tpu.ops import sr25519_batch
             except ImportError:
@@ -301,17 +288,15 @@ class Sr25519BatchVerifier(BatchVerifier):
                 return sr25519_batch
         return None
 
-    def _columns(self):
-        return tuple([e[i] for e in self._entries] for i in range(3))
+    def _columns(self, lo: int = 0, hi: Optional[int] = None):
+        return tuple([e[i] for e in self._entries[lo:hi]] for i in range(3))
 
-    def begin(self) -> PendingVerify:
+    def _device_begin(self):
         engine = self._device_engine()
         if engine is None:
-            return super().begin()
-        return begin_on_device(
-            SR25519_KEY_TYPE,
-            len(self._entries),
-            lambda: engine.begin_verify_batch_sr(*self._columns()),
+            return None
+        return lambda lo, hi, early: engine.begin_verify_batch_sr(
+            *self._columns(lo, hi), early=early
         )
 
     def _verify(self, span) -> Tuple[bool, List[bool]]:

@@ -892,6 +892,39 @@ def _mesh_span(lanes: int):
     return plan, CHUNK * (plan.n_dev if plan is not None else 1)
 
 
+# Engines that have dispatched a full job in this process: the kernel
+# of a job's width is compiled, its first call behind it (job_has_run).
+_ENGINES_WITH_A_JOB_RUN: set = set()
+
+
+def job_has_run(engine: str) -> bool:
+    """Whether ``engine`` (``ed25519`` | ``sr25519``) has dispatched a
+    chunk of a full job's lanes in this process. Until it has, a
+    job's first launch compiles, or loads, its kernel for seconds: a
+    caller that hands the engine a batch a block at a time
+    (crypto/batch.DeviceBatchVerifier) sends that batch whole, so that
+    a process's first calls come in the order, and from the stack, they
+    always did (the compile cache's key holds the stack)."""
+    return engine in _ENGINES_WITH_A_JOB_RUN
+
+
+def job_lanes() -> int:
+    """The lanes one job holds in a batch of a job's lanes or more:
+    ``CHUNK`` a device a plan for that batch would span — what
+    :func:`_mesh_span` answers there, asked without ``manager.plan()``,
+    which reserves the probe attempts of devices cooling down. For a
+    caller that cuts its own batch at the engine's seams
+    (crypto/batch.DeviceBatchVerifier). A device the mesh has excluded
+    is counted all the same: its plan's jobs are then narrower than
+    this, and a block of these lanes is cut in two."""
+    try:
+        forced = mesh_mod.manager.forced_mesh()
+        n_dev = forced.devices.size if forced is not None else mesh_mod.manager.device_count()
+    except Exception:  # as _mesh_plan: any trouble means 'unsharded'
+        n_dev = 1
+    return CHUNK * max(1, n_dev)
+
+
 def _mesh_collect_retry(job: _Job, backend: Optional[str], exc: Exception):
     """A sharded chunk died at materialization. If the failure is
     attributable to one device, exclude it, rebuild a smaller mesh, and
@@ -1007,6 +1040,8 @@ def _dispatch_jobs(
                         mesh_used = True
                         plan = job.plan  # degraded: later chunks follow
                     health.note_inflight(engine, len(job.rows))
+                    if engine not in _ENGINES_WITH_A_JOB_RUN and len(job.rows) >= job_lanes():
+                        _ENGINES_WITH_A_JOB_RUN.add(engine)
                 except Exception as exc:
                     health.record_failure(exc, attempt)
                     attempt = None
@@ -1162,6 +1197,11 @@ class PendingBatch:
             return self._settle(None if pending is None else pending.collect())
 
 
+def _early_tag(early: bool) -> dict:
+    """The dispatch span's mark of a block begun before ``verify()``."""
+    return {"early": 1} if early else {}
+
+
 class _CacheFront:
     """The verdict cache around one batch (precompute.ResultCache),
     asked and filled once each: the constructor's lookup derives the
@@ -1262,6 +1302,7 @@ def begin_verify_batch(
     msgs: Sequence[bytes],
     sigs: Sequence[bytes],
     backend: Optional[str] = None,
+    early: bool = False,
 ) -> PendingBatch:
     """:func:`verify_batch` in two steps, for a caller with host work to
     do while the device runs (crypto/batch.MultiBatchVerifier): this one
@@ -1270,11 +1311,16 @@ def begin_verify_batch(
     ``finish()`` of what it returns the collects, the cache store and
     the merge. ``begin_verify_batch(...).finish()`` is ``verify_batch``
     statement for statement, under two ``verify_batch`` spans (``phase``
-    ``dispatch`` / ``collect``) where that opens one."""
+    ``dispatch`` / ``collect``) where that opens one. ``early`` says
+    that the lanes are a block of a batch still being added to
+    (crypto/batch.DeviceBatchVerifier): ``early=1`` on the dispatch
+    span, and nothing else."""
     n = len(pubkeys)
     if n == 0:
         return PendingBatch("ed25519", 0, None, lambda out: [])
-    with tracing.span("verify_batch", engine="ed25519", lanes=n, phase="dispatch") as vsp:
+    with tracing.span(
+        "verify_batch", engine="ed25519", lanes=n, phase="dispatch", **_early_tag(early)
+    ) as vsp:
         vsp.process_cpu()
         front = _CacheFront(pubkeys, msgs, sigs)
         pending = _begin_uncached(*front.lanes, backend) if front.lanes else None

@@ -385,6 +385,37 @@ class PrecomputeCache:
         self.builds_deferred += 1
         return False
 
+    def would_build(self, pubkeys: Sequence[bytes]) -> bool:
+        """Whether a :meth:`gather` of ``pubkeys`` now would build a
+        table, answered without building, counting a sighting or
+        touching the LRU. For a caller that hands the engine a batch a
+        block at a time (crypto/batch.DeviceBatchVerifier): a block that
+        would build is not begun early, because the device store is
+        sized, and its kernel compiled, for the tables that exist when
+        it is uploaded — a committee's first commit sent in blocks would
+        upload the store at the width of its first block, and compile
+        for a width no other call has. Where every eligible key has its
+        table — any warm committee, and a process that knows no set —
+        nothing is looked up."""
+        if not table_cache_enabled():
+            return False
+        with self._lock:
+            build_all = _mode() == "all"
+            # auto: the entries are eligible keys, so as many means all
+            if not build_all and len(self._entries) >= len(self._eligible):
+                return False
+            for pk in pubkeys:
+                if pk in self._entries:
+                    continue
+                if build_all or pk in self._founders:
+                    return True
+                if (
+                    pk in self._eligible
+                    and self._sightings.get(pk, 0) + 1 >= BUILD_AT_SIGHTING
+                ):
+                    return True
+        return False
+
     # --- cache body ---------------------------------------------------------
 
     def _insert_locked(self, pk: bytes, table: np.ndarray, ok: bool) -> None:

@@ -38,6 +38,7 @@ from tendermint_tpu.ops.ed25519_batch import (
     _bytes_to_fe,
     _chunk_rows,
     _dispatch_jobs,
+    _early_tag,
     _Job,
     _mesh_span,
     _PendingJobs,
@@ -221,16 +222,20 @@ def begin_verify_batch_sr(
     msgs: Sequence[bytes],
     sigs: Sequence[bytes],
     backend: Optional[str] = None,
+    early: bool = False,
 ) -> PendingBatch:
     """:func:`verify_batch_sr` in two steps, as ed25519's
     ``begin_verify_batch``: lane arrays, Merlin challenges and every
     ``dispatch_chunk`` here, the collects and the merge in ``finish()``
     of what this returns, each step under a ``verify_batch`` span of
-    its own (``phase`` ``dispatch`` / ``collect``)."""
+    its own (``phase`` ``dispatch`` / ``collect``; ``early=1`` on the
+    first where the caller says the lanes are an early block)."""
     n = len(pubkeys)
     if n == 0:
         return PendingBatch("sr25519", 0, None, lambda out: [])
-    with tracing.span("verify_batch", engine="sr25519", lanes=n, phase="dispatch") as vsp:
+    with tracing.span(
+        "verify_batch", engine="sr25519", lanes=n, phase="dispatch", **_early_tag(early)
+    ) as vsp:
         vsp.process_cpu()
         pending = _begin_lanes(pubkeys, msgs, sigs, backend)
     return PendingBatch("sr25519", n, pending, _merge)

@@ -188,42 +188,69 @@ def _verify_commit_batch(
     # a malformed entry raises on add -> single fallback.
     bv = crypto_batch.MultiBatchVerifier()
     unbatchable = False
-    # One span for the whole loop; its per-lane steps are phase totals
-    # in the span's arguments (wrapped once here: the loop itself holds
-    # no tracing call, and on the no-op span these are the callables
-    # themselves).
-    with tracing.span("build_lanes") as lsp:
-        encoder = commit.sign_bytes_encoder(chain_id)
-        sign_bytes = lsp.timed("sign_bytes", encoder.lane)
-        batch_add = lsp.timed("batch_add", bv.add)
-        val_lookup = lsp.timed("val_lookup", vals.get_by_address)
-        for idx, commit_sig in enumerate(commit.signatures):
-            if ignore_sig(commit_sig):
-                continue
-            if look_up_by_index:
-                val = vals.validators[idx]
-            else:
-                val_idx, val = val_lookup(commit_sig.validator_address)
-                if val is None:
-                    continue
-                if val_idx in seen_vals:
-                    raise InvalidCommitError(
-                        f"double vote from validator {val_idx} "
-                        f"({seen_vals[val_idx]} and {idx})"
-                    )
-                seen_vals[val_idx] = idx
-            vote_sign_bytes = sign_bytes(idx)
-            try:
-                batch_add(val.pub_key, vote_sign_bytes, commit_sig.signature)
-            except ValueError:
-                unbatchable = True
-                break
-            batch_sig_idxs.append(idx)
-            if count_sig(commit_sig):
-                tallied += val.voting_power
-            if not count_all_signatures and tallied > voting_power_needed:
-                break
-        lsp.set(lanes=len(batch_sig_idxs), sign_bytes_prefixes=encoder.prefixes)
+    early_lanes = 0
+    encoder = commit.sign_bytes_encoder(chain_id)
+    n_sigs = len(commit.signatures)
+    entries = enumerate(commit.signatures)
+    try:
+        # The loop runs a block at a time: once the verifier holds a full
+        # engine job of lanes (bv.ready) it is told to begin them, and the
+        # device works while the next block is built. One build_lanes span
+        # a block, the begin between two of them and never inside one: a
+        # span's per-lane steps are phase totals in its arguments (wrapped
+        # once a span: the loop itself holds no tracing call, and on the
+        # no-op span these are the callables themselves), and none of them
+        # holds engine time.
+        building = True
+        while building:
+            building = False
+            with tracing.span("build_lanes") as lsp:
+                sign_bytes = lsp.timed("sign_bytes", encoder.lane)
+                batch_add = lsp.timed("batch_add", bv.add)
+                val_lookup = lsp.timed("val_lookup", vals.get_by_address)
+                held = len(batch_sig_idxs)
+                for idx, commit_sig in entries:
+                    if ignore_sig(commit_sig):
+                        continue
+                    if look_up_by_index:
+                        val = vals.validators[idx]
+                    else:
+                        val_idx, val = val_lookup(commit_sig.validator_address)
+                        if val is None:
+                            continue
+                        if val_idx in seen_vals:
+                            raise InvalidCommitError(
+                                f"double vote from validator {val_idx} "
+                                f"({seen_vals[val_idx]} and {idx})"
+                            )
+                        seen_vals[val_idx] = idx
+                    vote_sign_bytes = sign_bytes(idx)
+                    try:
+                        batch_add(val.pub_key, vote_sign_bytes, commit_sig.signature)
+                    except ValueError:
+                        unbatchable = True
+                        break
+                    batch_sig_idxs.append(idx)
+                    if count_sig(commit_sig):
+                        tallied += val.voting_power
+                    if not count_all_signatures and tallied > voting_power_needed:
+                        break
+                    if bv.ready and idx + 1 < n_sigs:
+                        building = True
+                        break
+                lsp.set(
+                    lanes=len(batch_sig_idxs) - held, sign_bytes_prefixes=encoder.prefixes
+                )
+            if building:
+                early_lanes += bv.begin_ready()
+        tracing.tag(early_lanes=early_lanes)  # on the caller's verify_commit span
+        if not unbatchable:
+            if tallied <= voting_power_needed:
+                raise NotEnoughVotingPowerError(got=tallied, needed=voting_power_needed)
+            ok, valid_sigs = bv.verify()
+    finally:
+        # whatever left before verify() had lanes on the device: collected
+        bv.close()
     if unbatchable:
         return _verify_commit_single(
             chain_id,
@@ -235,9 +262,6 @@ def _verify_commit_batch(
             count_all_signatures,
             look_up_by_index,
         )
-    if tallied <= voting_power_needed:
-        raise NotEnoughVotingPowerError(got=tallied, needed=voting_power_needed)
-    ok, valid_sigs = bv.verify()
     if ok:
         return
     with tracing.span("merge_verdicts", lanes=len(valid_sigs), scan="first_bad"):

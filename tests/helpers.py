@@ -156,6 +156,28 @@ bench_spec = functools.lru_cache(maxsize=None)(spec.Spec)  # read once a process
 layer_metric = functools.lru_cache(maxsize=None)(spec.layer_metric)
 
 
+def traced(fn, catch=Exception):
+    """``(what fn raised of ``catch`` or None, the spans it recorded)``
+    with the tracer's ring on for the call: complete events by start,
+    an enclosing span before what it holds."""
+    from tendermint_tpu.libs import tracing
+
+    tracing.tracer.set_metrics_observer(None)
+    tracing.configure("ring")
+    tracing.tracer.clear()
+    try:
+        raised = None
+        try:
+            fn()
+        except catch as exc:
+            raised = exc
+        events = [e for e in tracing.tracer.export(clear=True)["traceEvents"] if e.get("ph") == "X"]
+    finally:
+        tracing.configure("off")
+        tracing.tracer.clear()
+    return raised, sorted(events, key=lambda e: (e["ts"], -e["dur"]))
+
+
 def span(name, ts, dur, tid=1, **args):
     """A span as the tracer exports it: times in microseconds."""
     return {"name": name, "ts": float(ts), "dur": float(dur), "tid": tid, "args": args}

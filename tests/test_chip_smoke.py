@@ -68,7 +68,7 @@ def test_main_exits_nonzero_alone_in_a_directory(tmp_path):
 
 def test_phase_fails_on_the_wrong_platform():
     with pytest.raises(chip_smoke.SmokeFailure, match="want 'tpu'"):
-        chip_smoke.library_phase("tpu", sizes=(), sync=None, sr_buckets=(), mixed=0)
+        chip_smoke.library_phase("tpu", sizes=(), sync=None, sr_buckets=(), mixed=0, early_tail=0)
 
 
 # --- the library phase's checks, live, on the CPU -----------------------------
@@ -79,7 +79,7 @@ def test_edge_vectors_phase_passes_on_cpu():
     ops.verify_batch, lane for lane against the oracle, lanes
     dispatched == lanes sent, health counters flat — on the 64-lane
     legacy kernel other suites compile anyway."""
-    report = chip_smoke.library_phase("cpu", sizes=(), sync=None, sr_buckets=(), mixed=0)
+    report = chip_smoke.library_phase("cpu", sizes=(), sync=None, sr_buckets=(), mixed=0, early_tail=0)
     assert report["device"]["platform"] == "cpu"
     assert report["impl"] == "xla" and report["host_hash"] == "native"
     edge = report["edge"]
@@ -95,12 +95,44 @@ def test_edge_vectors_phase_passes_on_cpu():
     json.dumps(report)
 
 
+def test_the_early_begin_case_passes_on_cpu(monkeypatch):
+    """One engine job + a tail through ``crypto.BatchVerifier`` lane by
+    lane, at a job of 32 lanes (the accessor stood in: the chip's is
+    4,096): the job is begun before ``verify()``, the lanes on both
+    sides of the seam and the last are refused, on 64-lane legacy
+    kernels."""
+    monkeypatch.setattr(ed25519_batch, "job_lanes", lambda: 32)
+    # as after the sizes, which this call leaves out: a full job has run
+    monkeypatch.setattr(ed25519_batch, "_ENGINES_WITH_A_JOB_RUN", {"ed25519"})
+    report = chip_smoke.library_phase(
+        "cpu", sizes=(), sync=None, sr_buckets=(), mixed=0, early_tail=20
+    )
+    assert report["early_begin"]["refused"] == [31, 32, 51]
+    assert (report["early_begin"]["job"], report["early_begin"]["tail"]) == (32, 20)
+    json.dumps(report)
+
+
+def test_the_early_begin_case_fails_where_nothing_is_begun_early(monkeypatch):
+    """A verifier that waits for ``verify()`` is what the case exists
+    to catch: with ``add`` kept from looking, the verdicts are still
+    right and the phase fails on the blocks it reads."""
+    from tendermint_tpu.crypto import batch as crypto_batch
+
+    monkeypatch.setattr(ed25519_batch, "job_lanes", lambda: 32)
+    monkeypatch.setattr(ed25519_batch, "_ENGINES_WITH_A_JOB_RUN", {"ed25519"})
+    monkeypatch.setattr(crypto_batch.DeviceBatchVerifier, "_look", lambda self: None)
+    with pytest.raises(chip_smoke.SmokeFailure, match="blocks begun"):
+        chip_smoke.library_phase(
+            "cpu", sizes=(), sync=None, sr_buckets=(), mixed=0, early_tail=20
+        )
+
+
 def test_sr25519_and_the_mixed_committee_pass_on_cpu():
     """The phase's last steps at their smallest: a full 64-lane bucket
     of sr25519 lanes against the schnorrkel oracle, and a committee of
     the three key types through verify_commit, sound and tampered."""
     report = chip_smoke.library_phase(
-        "cpu", sizes=(), sync=None, sr_buckets=(64,), mixed=45
+        "cpu", sizes=(), sync=None, sr_buckets=(64,), mixed=45, early_tail=0
     )
     (sr,) = report["sr25519"]
     assert sr["lanes"] == 64 and 0 < sr["accepted"] < 64
@@ -122,7 +154,7 @@ def test_kernel_failure_fails_the_phase_instead_of_passing_on_the_oracle(
     monkeypatch.setattr(ed25519_batch, "_compiled_kernel", boom)
     with pytest.warns(UserWarning, match="CPU fallback"):
         with pytest.raises(chip_smoke.SmokeFailure, match="host oracle"):
-            chip_smoke.library_phase("cpu", sizes=(), sync=None, sr_buckets=(), mixed=0)
+            chip_smoke.library_phase("cpu", sizes=(), sync=None, sr_buckets=(), mixed=0, early_tail=0)
 
 
 def test_failing_implementation_is_counted_not_switched(monkeypatch):
@@ -297,10 +329,14 @@ def _auto_paths_on(monkeypatch):
 
 
 @pytest.mark.slow
-def test_library_phase_at_40_validators(_auto_paths_on):
+def test_library_phase_at_40_validators(_auto_paths_on, monkeypatch):
     # two sizes and the windows, as on the chip: each is a node of its
     # own (chip_smoke._fresh_node), so each gets its tables at once
-    report = chip_smoke.library_phase("cpu", sizes=(24, 40), heights=2, sync=(20, 4))
+    monkeypatch.setattr(ed25519_batch, "job_lanes", lambda: 32)  # the chip's: 4,096
+    report = chip_smoke.library_phase(
+        "cpu", sizes=(24, 40), heights=2, sync=(20, 4), early_tail=20
+    )
+    assert report["early_begin"]["refused"] == [31, 32, 51]
     small, size = report["sizes"]
     assert small["counters"]["resident_hits"] == 24 * 4
     assert small["counters"]["resident_uploads"] == 1

@@ -1,0 +1,547 @@
+"""The early begin (ISSUE 44): a device batch verifier hands each full
+engine job of lanes to the engine as soon as it holds them, and
+``verify()`` begins the rest and finishes every block in the order
+begun. The answer is one ``verify()``'s, lane for lane; what changes is
+when the device starts. The job is the engine's accessor's
+(``ops.ed25519_batch.job_lanes``: 4,096 lanes a device), stood in here
+at 16 so that the CPU's 64-lane kernels serve."""
+
+import gc
+import os
+import re
+import time
+
+import numpy as np
+import pytest
+
+from chipbench.readers import call_path
+from tendermint_tpu.crypto import batch as crypto_batch
+from tendermint_tpu.crypto.keys import Ed25519PrivKey, Secp256k1PrivKey
+from tendermint_tpu.crypto.sr25519 import Sr25519BatchVerifier, Sr25519PrivKey
+from tendermint_tpu.libs import tracing
+from tendermint_tpu.libs.metrics import OpsMetrics, Registry
+from tendermint_tpu.ops import device_policy, ed25519_batch, fault_injection, precompute
+from tendermint_tpu.parallel import mesh
+from tendermint_tpu.types import validation
+from tests.helpers import (
+    CHAIN_ID,
+    REAL_BENCH,
+    make_block_id,
+    make_commit,
+    make_validators,
+    read,
+    rehearse_cell,
+    sound,
+    traced,
+)
+
+JOB = 16  # lanes a job, stood in; crypto.batch.DEVICE_THRESHOLD, so the route says device by then
+SIZES = [1, JOB - 1, JOB, JOB + 1, 2 * JOB + 5]
+MOST = max(SIZES)
+NO_JOB = 1 << 30  # a job no batch fills: the one-shot verify() of before
+
+
+def jobs_have_run(monkeypatch):
+    """Both engines as in a process whose first full job is behind it
+    (until then a batch goes whole: the first launch compiles)."""
+    monkeypatch.setattr(ed25519_batch, "_ENGINES_WITH_A_JOB_RUN", {"ed25519", "sr25519"})
+
+
+@pytest.fixture
+def job(monkeypatch):
+    monkeypatch.setattr(ed25519_batch, "job_lanes", lambda: JOB)
+    jobs_have_run(monkeypatch)
+    return JOB
+
+
+def seams(n):
+    """The lanes on both sides of every block seam of an ``n``-lane
+    batch, and its two ends."""
+    return sorted({i for i in (0, n - 1, *(s + d for s in range(JOB, n, JOB) for d in (-1, 0))) if 0 <= i < n})
+
+
+def _signed(privs, tag):
+    lanes = []
+    for i, priv in enumerate(privs):
+        msg = b"%s lane %d" % (tag, i) + b"." * (i % 3)
+        lanes.append((priv.pub_key(), msg, priv.sign(msg)))
+    return lanes
+
+
+@pytest.fixture(scope="module")
+def signed():
+    """``MOST`` signed lanes of each key type, signed once."""
+    return {
+        "ed25519": _signed([Ed25519PrivKey.from_seed(b"early-%026d" % i) for i in range(MOST)], b"ed"),
+        "sr25519": _signed([Sr25519PrivKey.from_secret(b"early-sr %d" % i) for i in range(MOST)], b"sr"),
+        "secp256k1": _signed([Secp256k1PrivKey(bytes([7, i + 1]) * 16) for i in range(3)], b"secp"),
+    }
+
+
+def flip(lanes, picks):
+    out = list(lanes)
+    for i in picks:
+        pub, msg, sig = out[i]
+        out[i] = (pub, msg, sig[:33] + bytes([sig[33] ^ 0x04]) + sig[34:])
+    return out
+
+
+def batch_of(kind, signed, n):
+    """``(verifier factory, lanes)`` of an ``n``-lane batch of ``kind``,
+    tampered on both sides of every seam; a mixed batch holds ``n``
+    such lanes of each device type, interleaved, and three secp256k1
+    lanes among them, the second tampered."""
+    if kind != "mixed":
+        factory = crypto_batch.Ed25519BatchVerifier if kind == "ed25519" else Sr25519BatchVerifier
+        return factory, flip(signed[kind][:n], seams(n))
+    ed, sr = flip(signed["ed25519"][:n], seams(n)), flip(signed["sr25519"][:n], seams(n))
+    lanes = [lane for pair in zip(ed, sr) for lane in pair]
+    for at, lane in zip((0, n, 2 * n + 2), flip(signed["secp256k1"], [1])):
+        lanes.insert(at, lane)
+    return crypto_batch.MultiBatchVerifier, lanes
+
+
+def drive(bv, lanes, looks):
+    """Add every lane; a caller that ``looks`` begins what is ready
+    after each ``add``, as ``_verify_commit_batch`` does."""
+    begun = 0
+    for lane in lanes:
+        bv.add(*lane)
+        if looks and bv.ready:
+            begun += bv.begin_ready()
+    return begun
+
+
+def blocks_of(bv):
+    """Blocks begun and not finished: a device verifier's, or a mixed
+    batch's ed25519 and sr25519 sub-verifiers' (its host lanes have none)."""
+    if not isinstance(bv, crypto_batch.MultiBatchVerifier):
+        return [len(bv._blocks)]
+    assert not hasattr(bv._subs["secp256k1"], "_blocks")
+    return [len(bv._subs[kt]._blocks) for kt in ("ed25519", "sr25519")]
+
+
+def oracle(lane):
+    pub, msg, sig = lane
+    return bool(pub.verify_signature(msg, sig))
+
+
+@pytest.mark.parametrize("looks", [False, True], ids=["adds", "looks"])
+@pytest.mark.parametrize("n", SIZES)
+@pytest.mark.parametrize("kind", ["ed25519", "sr25519", "mixed"])
+def test_verdicts_are_those_of_one_verify_whatever_was_begun_early(monkeypatch, signed, kind, n, looks):
+    factory, lanes = batch_of(kind, signed, n)
+    jobs_have_run(monkeypatch)
+    monkeypatch.setattr(ed25519_batch, "job_lanes", lambda: NO_JOB)
+    whole = factory()
+    assert drive(whole, lanes, looks) == 0 and not any(blocks_of(whole))
+    want = whole.verify()
+    assert want[1] == [oracle(lane) for lane in lanes] and want[0] == all(want[1])
+    assert want[1].count(False) == len(seams(n)) * (2 if kind == "mixed" else 1) + (kind == "mixed")
+
+    monkeypatch.setattr(ed25519_batch, "job_lanes", lambda: JOB)
+    bv = factory()
+    begun = drive(bv, lanes, looks)
+    # a caller that looks begins a job with its last lane, add alone one lane later
+    early = (n if looks else n - 1) // JOB if n >= JOB else 0
+    subs = 2 if kind == "mixed" else 1
+    assert blocks_of(bv) == [early] * subs
+    if looks:
+        assert begun == early * JOB * subs
+    assert bv.verify() == want
+    assert not any(blocks_of(bv))
+    assert bv.verify() == want  # asked again: the whole batch, as before
+
+
+def test_a_batch_under_one_job_begins_nothing_before_verify(job, signed):
+    bv = crypto_batch.Ed25519BatchVerifier()
+    lanes = signed["ed25519"][: JOB - 1]
+    raised, events = traced(lambda: drive(bv, lanes, looks=True))
+    assert raised is None and events == [] and not bv.ready and bv._blocks == []
+    # and a full job is ready with its last lane, begun by nobody until asked
+    bv.add(*signed["ed25519"][JOB - 1])
+    assert bv.ready and bv._blocks == []
+
+
+class Engine:
+    """The engine's seam stood in: ``ops.begin_verify_batch`` and
+    ``ops.verify_batch`` answer every lane True and record what they
+    were handed; a begun batch counts as in flight until finished."""
+
+    def __init__(self, monkeypatch, nap=0.0):
+        from tendermint_tpu import ops
+
+        self.begun, self.whole, self.unfinished, self.nap = [], [], 0, nap
+        monkeypatch.setattr(ops, "begin_verify_batch", self.begin)
+        monkeypatch.setattr(ops, "verify_batch", self.verify)
+
+    def begin(self, pks, msgs, sigs, backend=None, early=False):
+        with tracing.span("verify_batch", engine="ed25519", lanes=len(pks), phase="dispatch"):
+            time.sleep(self.nap)
+            with tracing.span("dispatch_chunk", lanes=len(pks)):
+                pass
+        self.begun.append((len(pks), early))
+        self.unfinished += 1
+        engine = self
+
+        class Pending:
+            lanes_inflight = len(pks)
+
+            def finish(self):
+                engine.unfinished -= 1
+                with tracing.span("verify_batch", engine="ed25519", lanes=len(pks), phase="collect"):
+                    with tracing.span("collect_chunk", lanes=len(pks)):
+                        pass
+                return [True] * len(pks)
+
+        return Pending()
+
+    def verify(self, pks, msgs, sigs, backend=None):
+        self.whole.append(len(pks))
+        return [True] * len(pks)
+
+
+def warm(vset):
+    """The committee as a node that has verified a commit of it knows
+    it: live, every key's table built, so that no call here builds one
+    (a block that would is not begun early)."""
+    crypto_batch.note_validator_set(vset)
+    precompute.tables.gather([v.pub_key.bytes() for v in vset.validators])
+
+
+def add_many(bv, lane, n):
+    for _ in range(n):
+        bv.add(*lane)
+
+
+@pytest.mark.parametrize("route", ["remote", "host", "use_device_false"])
+def test_a_batch_that_is_not_this_processes_devices_never_begins_early(monkeypatch, job, signed, route):
+    engine = Engine(monkeypatch)
+    sent = []
+    if route == "remote":
+        monkeypatch.setattr(
+            crypto_batch, "remote_verify_backend",
+            lambda: lambda pks, msgs, sigs: sent.append(len(pks)) or [True] * len(pks),
+        )
+        bv = crypto_batch.Ed25519BatchVerifier()
+    elif route == "host":
+        monkeypatch.setattr(
+            crypto_batch, "host_verify_ed25519", lambda pks, m, s: sent.append(len(pks)) or [True] * len(pks)
+        )
+        bv = crypto_batch.Ed25519BatchVerifier(device_threshold=1000)
+    else:
+        monkeypatch.setattr(
+            crypto_batch, "host_verify_ed25519", lambda pks, m, s: sent.append(len(pks)) or [True] * len(pks)
+        )
+        bv = crypto_batch.Ed25519BatchVerifier(use_device=False)
+    for lane in signed["ed25519"]:
+        bv.add(*lane)
+        assert not bv.ready and bv.begin_ready() == 0
+    assert bv.verify() == (True, [True] * MOST)
+    assert sent == [MOST] and engine.begun == [] and engine.whole == []
+
+
+@pytest.mark.parametrize("devices,want", [(1, [(4096, True), (4096, True)]), (4, [])])
+def test_the_jobs_lanes_follow_the_engines_accessor(monkeypatch, signed, devices, want):
+    """10,000 lanes: two jobs begun early on one device, none where a
+    plan would span four (a job is 16,384 lanes there). The accessor
+    reserves no probe: it never asks the manager for a plan."""
+    engine = Engine(monkeypatch)
+    jobs_have_run(monkeypatch)
+    monkeypatch.setattr(mesh.manager, "device_count", lambda: devices)
+    monkeypatch.setattr(mesh.manager, "plan", lambda: pytest.fail("job_lanes() asked for a plan"))
+    assert ed25519_batch.job_lanes() == ed25519_batch.CHUNK * devices
+    bv = crypto_batch.Ed25519BatchVerifier()
+    add_many(bv, signed["ed25519"][0], 10_000)
+    assert engine.begun == want
+    assert bv.verify() == (True, [True] * 10_000)
+    if want:
+        assert engine.begun == want + [(10_000 - 2 * 4096, False)] and engine.whole == []
+    else:
+        assert engine.begun == [] and engine.whole == [10_000]
+    assert engine.unfinished == 0
+
+
+def test_the_accessor_counts_a_forced_meshes_devices(monkeypatch):
+    class Forced:
+        devices = np.empty((2, 3), dtype=object)
+
+    with mesh.manager.forced(Forced()):
+        assert ed25519_batch.job_lanes() == 6 * ed25519_batch.CHUNK
+    monkeypatch.setattr(mesh.manager, "device_count", lambda: 1 / 0)
+    assert ed25519_batch.job_lanes() == ed25519_batch.CHUNK  # any trouble: unsharded
+
+
+def test_an_engines_first_full_job_goes_to_it_whole(monkeypatch, signed):
+    """A job's first launch compiles, or loads, its kernel: until an
+    engine has dispatched one full job in the process a batch is not
+    begun early, so that a process's first calls come in the order they
+    always did; the next batch is."""
+    monkeypatch.setattr(ed25519_batch, "job_lanes", lambda: JOB)
+    monkeypatch.setattr(ed25519_batch, "_ENGINES_WITH_A_JOB_RUN", set())
+    lanes = signed["ed25519"]
+    begun = []
+    for _ in range(2):
+        bv = crypto_batch.Ed25519BatchVerifier()
+        drive(bv, lanes, looks=False)
+        begun.append(len(bv._blocks))
+        assert bv.verify() == (True, [True] * MOST)
+    assert begun == [0, MOST // JOB]
+    assert ed25519_batch._ENGINES_WITH_A_JOB_RUN == {"ed25519"}
+    assert ed25519_batch.job_has_run("ed25519") and not ed25519_batch.job_has_run("sr25519")
+
+
+def test_a_block_that_would_build_tables_waits_for_verify(monkeypatch, job):
+    """A committee's first commit builds its tables in the gather: sent
+    in blocks, the device store would be uploaded at the width of the
+    first block and a kernel compiled for it. Such a block is not begun
+    early, and the commit after it is."""
+    engine = Engine(monkeypatch)
+    privs, vset = make_validators(2 * JOB + 5)
+    block_id = make_block_id(b"early-cold")
+    for height, early in ((1, []), (2, [(JOB, True), (JOB, True)])):
+        commit = make_commit(block_id, height, 0, vset, privs)
+        del engine.begun[:], engine.whole[:]
+        if height == 2:  # what the first commit's gather does, the engine being stood in
+            precompute.tables.gather([v.pub_key.bytes() for v in vset.validators])
+        validation.verify_commit(CHAIN_ID, vset, block_id, height, commit)
+        assert engine.begun == early + ([(5, False)] if early else [])
+        assert engine.whole == ([] if early else [2 * JOB + 5])
+    keys = [v.pub_key.bytes() for v in vset.validators]
+    assert not precompute.tables.would_build(keys)
+    assert not precompute.tables.would_build([b"\x07" * 32])  # of no set: never built
+    precompute.tables.pin([b"\x07" * 32])
+    assert precompute.tables.would_build(keys[:3] + [b"\x07" * 32])
+
+
+# --- every way out of _verify_commit_batch that does not reach verify() ---------
+
+
+class Boom(Exception):
+    pass
+
+
+@pytest.fixture
+def health(monkeypatch):
+    now = [1000.0]
+    machine = device_policy.DeviceHealth(retry_budget=1, cooldown_base=1.0, clock=lambda: now[0])
+    machine.now = now
+    machine.ops_metrics = OpsMetrics(Registry())
+    machine.bind_metrics(machine.ops_metrics)
+    monkeypatch.setattr(device_policy, "shared", machine)
+    return machine
+
+
+def half_open(health):
+    health.record_failure(RuntimeError("UNAVAILABLE: planted"), health.begin_attempt("ed25519"))
+    assert health.state == device_policy.COOLDOWN
+    health.now[0] += 5.0
+
+
+def nothing_in_flight(health):
+    assert health.snapshot()["probe_inflight"] is False
+    lines = health.ops_metrics.inflight_lanes.collect()
+    assert lines and all(line.endswith(" 0") for line in lines), lines
+
+
+N_VALS = 40
+
+
+@pytest.fixture(scope="module")
+def committee():
+    privs, vset = make_validators(N_VALS)
+    return privs, vset, make_block_id(b"early-abort")
+
+
+def unbatchable(privs, vset, block_id):
+    commit = make_commit(block_id, 5, 0, vset, privs)
+    commit.signatures[30].signature = commit.signatures[30].signature[:63]
+    return (lambda: validation.verify_commit(CHAIN_ID, vset, block_id, 5, commit)), (
+        validation.InvalidCommitError, r"wrong signature \(#30\)")
+
+
+def not_enough_power(privs, vset, block_id):
+    commit = make_commit(block_id, 5, 0, vset, privs, nil_votes=set(range(0, N_VALS, 2)))
+    return (lambda: validation.verify_commit(CHAIN_ID, vset, block_id, 5, commit)), (
+        validation.NotEnoughVotingPowerError, "insufficient voting power")
+
+
+def double_vote(privs, vset, block_id):
+    commit = make_commit(block_id, 5, 0, vset, privs)
+    commit.signatures[20] = commit.signatures[3]
+    return (lambda: validation.verify_commit_light_trusting(
+        CHAIN_ID, vset, commit, validation.Fraction(9, 10))), (
+        validation.InvalidCommitError, r"double vote from validator 3 \(3 and 20\)")
+
+
+def add_raises(privs, vset, block_id, monkeypatch):
+    commit = make_commit(block_id, 5, 0, vset, privs)
+    add = crypto_batch.MultiBatchVerifier.add
+
+    def failing(self, pub_key, msg, sig):
+        if len(self) == 20:
+            raise Boom("add")
+        return add(self, pub_key, msg, sig)
+
+    monkeypatch.setattr(crypto_batch.MultiBatchVerifier, "add", failing)
+    return (lambda: validation.verify_commit(CHAIN_ID, vset, block_id, 5, commit)), (Boom, "add")
+
+
+@pytest.mark.parametrize("health_state", ["healthy", "half_open"])
+@pytest.mark.parametrize("way_out", ["unbatchable", "not_enough_power", "double_vote", "add_raises"])
+def test_leaving_before_verify_raises_what_it_did_and_leaves_nothing_in_flight(
+    monkeypatch, job, committee, health, way_out, health_state
+):
+    """Each is reached with a block on the device (the first job's 16
+    lanes, begun by the loop): the error and its precedence are those of
+    a verifier that waits for ``verify()``, the block is collected on
+    the way out, and with the device half open the probe it held is
+    settled."""
+    warm(committee[1])
+    if health_state == "half_open":
+        half_open(health)
+    if way_out == "add_raises":
+        call, (error, match) = add_raises(*committee, monkeypatch)
+    else:
+        call, (error, match) = {"unbatchable": unbatchable, "not_enough_power": not_enough_power,
+                                "double_vote": double_vote}[way_out](*committee)
+    raised, events = traced(call)
+    assert isinstance(raised, error) and re.search(match, str(raised)), raised
+    early = [e for e in events if e["name"] == "batch_verify" and e["args"].get("early")]
+    assert len(early) >= 1 and all(e["args"]["lanes"] == JOB for e in early)
+    sent = sum(e["args"]["lanes"] for e in events if e["name"] == "dispatch_chunk")
+    got = sum(e["args"]["lanes"] for e in events if e["name"] == "collect_chunk")
+    assert sent == got and sent >= JOB  # (half open: the second block is the oracle's, as a second caller's)
+    nothing_in_flight(health)
+    assert health.state == device_policy.HEALTHY  # the block round-tripped
+
+
+def test_a_verifier_dropped_with_a_block_in_flight_collects_it(job, signed, health):
+    half_open(health)
+    bv = crypto_batch.Ed25519BatchVerifier()
+    for lane in signed["ed25519"][: JOB + 1]:
+        bv.add(*lane)
+    assert len(bv._blocks) == 1 and health.snapshot()["probe_inflight"] is True
+    assert 'engine="ed25519"} %d' % JOB in health.ops_metrics.inflight_lanes.collect()[0]
+    del bv
+    gc.collect()
+    nothing_in_flight(health)
+    assert health.state == device_policy.HEALTHY
+
+
+def test_close_is_idempotent_and_verify_after_it_verifies_the_whole_batch(job, signed, health):
+    lanes = flip(signed["ed25519"], [JOB])
+    bv = crypto_batch.Ed25519BatchVerifier()
+    drive(bv, lanes, looks=True)
+    assert len(bv._blocks) == 2
+    bv.close()
+    bv.close()
+    nothing_in_flight(health)
+    assert bv._blocks == []
+    multi = crypto_batch.MultiBatchVerifier()
+    drive(multi, lanes, looks=True)
+    multi.close()
+    nothing_in_flight(health)
+
+
+def test_a_fault_at_the_collect_of_an_early_block_sends_that_block_to_the_oracle(job, signed, health):
+    lanes = flip(signed["ed25519"], [3, JOB, MOST - 1])
+    want = [i not in (3, JOB, MOST - 1) for i in range(MOST)]
+    bv = crypto_batch.Ed25519BatchVerifier()
+    drive(bv, lanes, looks=False)
+    assert len(bv._blocks) == 2
+    got = []
+    with fault_injection.inject(site="ed25519.collect", fail_calls=(1,)), pytest.warns(
+        UserWarning, match="failed at collect"
+    ):
+        raised, events = traced(lambda: got.append(bv.verify()))
+    assert raised is None and got == [(False, want)]
+    (fallback,) = [e for e in events if e["name"] == "host_fallback"]
+    assert fallback["args"]["lanes"] == JOB  # the first block alone
+    assert sum(e["args"]["lanes"] for e in events if e["name"] == "collect_chunk") == MOST
+    nothing_in_flight(health)
+
+
+# --- span hygiene ---------------------------------------------------------------
+
+
+def test_the_spans_of_a_two_job_commit_keep_the_engine_out_of_the_entrys_names(monkeypatch, job):
+    """A traced commit of two jobs and five lanes, the engine stood in by
+    one that sleeps 20 ms a begin: ``batch_verify`` lies beside
+    ``build_lanes`` under ``verify_commit``, never inside it, so the
+    loop's phase totals hold no engine time and the readers that
+    subtract both names subtract each millisecond once."""
+    nap = 0.02
+    engine = Engine(monkeypatch, nap=nap)
+    n = 2 * JOB + 5
+    privs, vset = make_validators(n)
+    block_id = make_block_id(b"early-spans")
+    warm(vset)
+    commit = make_commit(block_id, 3, 0, vset, privs)
+    t0 = time.perf_counter_ns()
+    raised, events = traced(lambda: validation.verify_commit(CHAIN_ID, vset, block_id, 3, commit))
+    t1 = time.perf_counter_ns()
+    assert raised is None and engine.begun == [(JOB, True), (JOB, True), (5, False)]
+    by = lambda name: [e for e in events if e["name"] == name]
+    (outer,) = by("verify_commit")
+    assert outer["args"]["early_lanes"] == 2 * JOB and outer["args"]["sigs"] == n
+    loops, batches = by("build_lanes"), by("batch_verify")
+    assert [e["args"]["lanes"] for e in loops] == [JOB, JOB, 5]
+    assert [e["args"]["batch_add_n"] for e in loops] == [JOB, JOB, 5]
+    assert [(e["args"]["lanes"], e["args"]["phase"], e["args"].get("early")) for e in batches] == [
+        (JOB, "dispatch", 1), (JOB, "dispatch", 1), (5, "dispatch", None),
+        (JOB, "collect", None), (JOB, "collect", None), (5, "collect", None),
+    ]
+    assert {e["args"]["parent"] for e in loops + batches} == {"verify_commit"}
+    for b in batches:  # beside every loop span, inside none
+        assert all(b["ts"] + b["dur"] <= l["ts"] or l["ts"] + l["dur"] <= b["ts"] for l in loops)
+    # three begins slept 60 ms; the loops and their batch_add phase hold none of it
+    assert sum(e["dur"] for e in batches) >= 3 * nap * 1e6
+    assert sum(e["dur"] for e in loops) < nap * 1e6
+    assert sum(e["args"]["batch_add_us"] for e in loops) < nap * 1e6
+    # chipbench's readers on this evidence: big10k-warm's entries, found by what they measure
+    call = {"start_ns": t0, "end_ns": t1, "spans": events}
+    ev = type("Evidence", (), {"calls": [call], "spans": events})()
+    unnamed = read(ev, REAL_BENCH, "big10k-warm", "entry_unnamed_ms")
+    assert 0 <= unnamed < nap * 1e3
+    entry = read(ev, REAL_BENCH, "big10k-warm", "entry_host_ms")
+    assert unnamed <= entry < outer["dur"] / 1e3 - 3 * nap * 1e3 + 1e-6
+    assert read(ev, REAL_BENCH, "big10k-warm", "batch_add_ms") < nap * 1e3
+    parts = [call_path.read(ev, part=part) for part in ("pre", "chain", "post")]
+    assert all(p >= 0 for p in parts) and sum(parts) == pytest.approx((t1 - t0) / 1e6)
+    # the first dispatch is the first block's: the chain holds the building of the other two
+    first = by("dispatch_chunk")[0]
+    assert first["ts"] < loops[1]["ts"] and parts[1] > 2 * nap * 1e3
+
+
+# --- the benchmark's own harness on a call made of blocks -----------------------
+
+PATH_BENCH = os.path.join(os.path.dirname(REAL_BENCH), "chipbench", "testdata", "tiny-path-benchmark.json")
+# the child's job is 16 lanes, so that the tiny twin's 24-lane commits are a block begun early and
+# eight lanes at verify(); it leaves with 3 if no block was begun early
+ENGAGED = """
+import atexit, os
+from tendermint_tpu.crypto import batch
+from tendermint_tpu.ops import ed25519_batch
+ed25519_batch.job_lanes = lambda: 16
+begun_early, begin_on_device = [], batch.begin_on_device
+def counting(key_type, lanes, begin_batch, early=False):
+    begun_early.extend([lanes] * early)
+    return begin_on_device(key_type, lanes, begin_batch, early)
+batch.begin_on_device = counting
+atexit.register(lambda: set(begun_early) == {16} or os._exit(3))
+"""
+
+
+def test_the_benchmarks_harness_reads_a_call_made_of_blocks():
+    """``chipbench.run``, traced, on the call-path twin with the early
+    begin engaged in every timed call: ``correct``, nothing ``failed``
+    (the lanes dispatched and collected are the lanes sent), every
+    per-layer metric of the cell a number, and the call's three parts
+    each part of it."""
+    value = sound(
+        *rehearse_cell(PATH_BENCH, "tiny-hub-warm", 2**31 + 44, 1, prelude=ENGAGED), (), PATH_BENCH, "tiny-hub-warm"
+    )
+    parts = [value(stem, moves="commit_p50_ms") for stem in ("pre_dispatch_ms", "chain_ms", "post_collect_ms")]
+    assert all(p > 0 for p in parts)
+    assert value("device_chain_gap_ms", moves="commit_p50_ms") >= 0

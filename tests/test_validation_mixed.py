@@ -10,11 +10,11 @@ from tendermint_tpu.crypto import batch as crypto_batch
 from tendermint_tpu.crypto.ed25519_ref import verify_zip215
 from tendermint_tpu.crypto.keys import Secp256k1PubKey
 from tendermint_tpu.crypto.sr25519 import Sr25519BatchVerifier, verify as verify_sr
-from tendermint_tpu.libs import tracing
 from tendermint_tpu.libs.metrics import OpsMetrics, Registry
 from tendermint_tpu.ops import device_policy, precompute
 from tendermint_tpu.types import validation
 from tests.helpers import CHAIN_ID, make_block_id, make_commit, make_mixed_validators
+from tests.helpers import traced as traced_catching
 
 N_ED, N_SR, N_SECP = 20, 18, 3
 
@@ -27,20 +27,7 @@ def mixed():
 
 
 def traced(fn):
-    tracing.tracer.set_metrics_observer(None)
-    tracing.configure("ring")
-    tracing.tracer.clear()
-    try:
-        raised = None
-        try:
-            fn()
-        except validation.InvalidCommitError as exc:
-            raised = exc
-        events = [e for e in tracing.tracer.export(clear=True)["traceEvents"] if e.get("ph") == "X"]
-    finally:
-        tracing.configure("off")
-        tracing.tracer.clear()
-    return raised, events
+    return traced_catching(fn, catch=validation.InvalidCommitError)
 
 
 def in_order(events):
