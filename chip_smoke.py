@@ -205,6 +205,44 @@ def tamper(commit) -> dict:
     return picks
 
 
+def _blame_block_edges(helpers, vset, commit, height: int, edges, what: str) -> None:
+    """``commit``, sound, with one lane at a time refused, each lane of
+    ``edges`` (the first and last lane of a block of the entry's loop):
+    ``verify_commit`` names that lane and no other. The commit is left
+    as it was."""
+    from tendermint_tpu.types.validation import InvalidCommitError, verify_commit
+
+    for idx in edges:
+        sound = commit.signatures[idx].signature
+        sig = bytearray(sound)
+        sig[32] ^= 0x01
+        commit.signatures[idx].signature = bytes(sig)
+        try:
+            verify_commit(helpers.CHAIN_ID, vset, commit.block_id, height, commit)
+        except InvalidCommitError as exc:
+            check("(#%d)" % idx in str(exc), "%s: blame %s, want the block's edge, lane %d", what, exc, idx)
+        else:
+            raise SmokeFailure("%s: accepted a commit with lane %d tampered" % (what, idx))
+        finally:
+            commit.signatures[idx].signature = sound
+
+
+def _check_blocks(spans: list, what: str) -> int:
+    """Every ``verify_commit`` among ``spans`` built its lanes a block
+    at a time: each ``build_lanes`` span handed all its lanes over by
+    one ``add_many`` and the call counts its spans. Returns the most
+    blocks a call was made of."""
+    loops = [e for e in spans if e["name"] == "build_lanes"]
+    check(
+        loops and all(e["args"]["block_lanes"] == e["args"]["lanes"] for e in loops),
+        "%s: lanes that went through add: %r",
+        what, [(e["args"]["lanes"], e["args"].get("block_lanes")) for e in loops][:8],
+    )
+    calls = [e["args"]["blocks"] for e in spans if e["name"] == "verify_commit"]
+    check(sum(calls) == len(loops), "%s: blocks %r, build_lanes spans %d", what, calls, len(loops))
+    return max(calls)
+
+
 def edge_vectors():
     """The ZIP-215 edge cases tests/test_ed25519_ref.py and
     tests/test_ops_ed25519.py hold (small-order and identity keys,
@@ -447,6 +485,7 @@ def _fresh_node() -> None:
 def _run_size(n: int, heights: int, dev: dict, impl: str, paths: dict) -> dict:
     import numpy as np
 
+    from tendermint_tpu.ops import ed25519_batch
     from tendermint_tpu.parallel import mesh
     from tendermint_tpu.types.validation import InvalidCommitError, verify_commit
 
@@ -479,6 +518,13 @@ def _run_size(n: int, heights: int, dev: dict, impl: str, paths: dict) -> dict:
         walls["heights_s"].append(round(time.monotonic() - t0, 2))
         if gathered_after_first is None:
             gathered_after_first = _counters()["gathered_h2d_bytes"]
+
+    # The loop's blocks: a lane refused at each edge of each is named.
+    job = ed25519_batch.job_lanes()
+    edges = sorted({0, n - 1} | {lane for lane in (job - 1, job) if lane < n})
+    t0 = time.monotonic()
+    _blame_block_edges(helpers, vset, commits[heights], heights, edges, what)
+    walls["edges_s"] = round(time.monotonic() - t0, 2)
 
     # Tampered commit: verify_commit names the first bad signature; the
     # per-lane verdicts name all of them, and agree with the oracle.
@@ -513,15 +559,17 @@ def _run_size(n: int, heights: int, dev: dict, impl: str, paths: dict) -> dict:
     # What served the lanes.
     spans = _drain_spans()
     d = _delta(before)
-    table_lanes = n * (heights + 2)
+    table_lanes = n * (heights + 2 + len(edges))
     table_kind = "resident" if paths["resident"] else "tables"
     _check_dispatch(spans, {"legacy": n, table_kind: table_lanes}, what)
     _check_health(d, what)
+    blocks = _check_blocks(spans, what)
+    check(blocks == -(-n // job), "%s: a call of %d blocks, the job is %d lanes", what, blocks, job)
     if paths["device_hash"]:
         check(
-            d["hash_device_lanes"] == n * (heights + 3),
+            d["hash_device_lanes"] == n + table_lanes,
             "%s: device hashing served %d of %d lanes",
-            what, d["hash_device_lanes"], n * (heights + 3),
+            what, d["hash_device_lanes"], n + table_lanes,
         )
     if paths["resident"]:
         check(
@@ -566,6 +614,8 @@ def _run_size(n: int, heights: int, dev: dict, impl: str, paths: dict) -> dict:
         "compiles": _compiles(spans, impl),
         "lanes_per_device": shards,
         "sharded": sharded,
+        "blocks": blocks,
+        "edges": edges,
     }
 
 
@@ -762,6 +812,7 @@ def _run_mixed(n: int, impl: str) -> dict:
     (``host_lanes``); nothing else may be."""
     from bench.workload import load_helpers
     from tendermint_tpu.crypto import batch as crypto_batch
+    from tendermint_tpu.ops import ed25519_batch
     from tendermint_tpu.types import validation
 
     helpers = load_helpers()
@@ -793,6 +844,8 @@ def _run_mixed(n: int, impl: str) -> dict:
         by_engine == {kt: sent[kt] for kt in ("ed25519", "sr25519")},
         "mixed committee: lanes dispatched by engine %r != lanes sent %r", by_engine, sent,
     )
+    # the committee is one block of the loop: a lane refused at either edge is named
+    _blame_block_edges(helpers, vset, commit, 1, [0, n - 1], "mixed committee")
     # one lane of each type tampered: the first is blamed, and every
     # lane's verdict is its own key's
     bad = sorted(types.index(kt) + 1 for kt in sent)
@@ -823,6 +876,9 @@ def _run_mixed(n: int, impl: str) -> dict:
     )
     spans += _drain_spans()
     _check_health(_delta(before), "mixed committee")
+    blocks = _check_blocks(spans, "mixed committee")
+    if max(sent.values()) < ed25519_batch.job_lanes():
+        check(blocks == 1, "mixed committee: a commit under one job built in %d blocks", blocks)
     return {"validators": n, "sent": sent, "tampered": bad, "compiles": _compiles(spans, impl)}
 
 
@@ -915,6 +971,7 @@ def library_phase(
             say("  counters %(counters)r" % rep)
             say("  compiled %(compiles)r" % rep)
             say("  lanes/device %(lanes_per_device)r sharded %(sharded)r" % rep)
+            say("  the entry's loop: %(blocks)d blocks a call at most, lanes %(edges)r refused one at a time and named" % rep)
 
         if early_tail:
             rep = report["early_begin"] = _run_early_begin(early_tail, impl)

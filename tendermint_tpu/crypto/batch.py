@@ -10,7 +10,8 @@ host oracle below it.
 
 from __future__ import annotations
 
-from typing import Callable, List, Optional, Tuple
+from itertools import repeat
+from typing import Callable, List, Optional, Sequence, Tuple
 
 from tendermint_tpu.crypto.keys import (
     ED25519_KEY_TYPE,
@@ -137,6 +138,14 @@ class PendingVerify:
         self.lanes_inflight = lanes_inflight
 
 
+_NEVER = 1 << 62  # lanes no batch holds
+
+
+def key_types(pub_keys: Sequence[PubKey]) -> set:
+    """The key types among ``pub_keys``."""
+    return {pub_key.type for pub_key in pub_keys}
+
+
 class BatchVerifier:
     """crypto.BatchVerifier contract (crypto/crypto.go:58-76): Add entries,
     then Verify once; returns (all_valid, per-entry validity)."""
@@ -148,6 +157,22 @@ class BatchVerifier:
 
     def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> None:
         raise NotImplementedError
+
+    def add_many(self, pub_keys: Sequence[PubKey], msgs: Sequence[bytes], sigs: Sequence[bytes]) -> None:
+        """``add`` of each lane in turn, a block of them in one call: a
+        lane ``add`` refuses raises what it raises there, with the
+        lanes before it taken. Here the loop itself; a verifier that
+        keeps columns checks the block as a whole and extends them."""
+        for lane in zip(pub_keys, msgs, sigs, strict=True):
+            self.add(*lane)
+
+    def room(self, pub_keys: Sequence[PubKey] = ()) -> int:
+        """Where a caller's block ends: of the lanes to come, whose
+        keys are ``pub_keys`` in that order, how many the verifier
+        takes until it holds a full job ready to begin; more than there
+        are where they fill none, as for every verifier with no device
+        path. Only a verifier of several key types looks at the keys."""
+        return _NEVER
 
     def verify(self) -> Tuple[bool, List[bool]]:
         raise NotImplementedError
@@ -232,9 +257,6 @@ def finish_in_order(blocks: List[PendingVerify]) -> PendingVerify:
     return PendingVerify(finish, sum(block.lanes_inflight for block in blocks))
 
 
-_NEVER = 1 << 62  # lanes no batch holds
-
-
 class DeviceBatchVerifier(BatchVerifier):
     """What the two verifiers with a device engine share
     (:class:`Ed25519BatchVerifier`, ``crypto.sr25519.Sr25519BatchVerifier``):
@@ -271,18 +293,19 @@ class DeviceBatchVerifier(BatchVerifier):
         self._job = 0  # lanes one engine job holds, once asked
         self._look_at = 1  # lanes held at which add looks next (_look)
 
-    def _wants_device(self) -> bool:
-        """The route's rule: ``use_device``, or by the threshold."""
-        n = len(self)
+    def _wants_device(self, lanes: Optional[int] = None) -> bool:
+        """The route's rule for a batch of ``lanes`` (those held):
+        ``use_device``, or by the threshold."""
+        n = len(self) if lanes is None else lanes
         use_device = self.use_device
         if use_device is None:
             use_device = n >= self.device_threshold
         return bool(n and use_device)
 
-    def _device_begin(self):
+    def _device_begin(self, lanes: Optional[int] = None):
         """``begin(lo, hi, early)`` -> the engine's ``PendingBatch`` of
-        lanes ``lo:hi``, where this batch is this process's device's;
-        else None."""
+        lanes ``lo:hi``, where this batch, or one of ``lanes``, is this
+        process's device's; else None."""
         raise NotImplementedError
 
     def _verify(self, span) -> Tuple[bool, List[bool]]:
@@ -306,16 +329,31 @@ class DeviceBatchVerifier(BatchVerifier):
         n = len(self)
         if self.ready:
             self.begin_ready()
-        elif not self._job:
-            if self._device_begin() is None:
-                self._look_at = 2 * n
-                return
-            from tendermint_tpu.ops.ed25519_batch import job_lanes
-
-            self._look_at = self._job = job_lanes()
+        elif not self._job and not self._learn_job(n):
+            self._look_at = 2 * n
+            return
         if n >= self._look_at:
             self.ready = True
             self._look_at = n + 1
+
+    def _learn_job(self, lanes: int) -> bool:
+        """The engine's job, once: where a batch of ``lanes`` is this
+        process's device's."""
+        if self._device_begin(lanes) is None:
+            return False
+        from tendermint_tpu.ops.ed25519_batch import job_lanes
+
+        self._look_at = self._job = job_lanes()
+        return True
+
+    def room(self, pub_keys: Sequence[PubKey] = ()) -> int:
+        """The lanes the next job still takes; asked of a batch still
+        under the threshold, what it will take once over it."""
+        if self._look_at >= _NEVER:
+            return _NEVER
+        if not self._job and not self._learn_job(max(len(self), self.device_threshold)):
+            return _NEVER
+        return self._job - (len(self) - self._begun) % self._job
 
     def begin_ready(self) -> int:
         if not self.ready:
@@ -410,13 +448,30 @@ class Ed25519BatchVerifier(DeviceBatchVerifier):
         if len(self._sigs) >= self._look_at:
             self._look()
 
+    def add_many(self, pub_keys: Sequence[PubKey], msgs: Sequence[bytes], sigs: Sequence[bytes]) -> None:
+        pks = [pub_key.bytes() for pub_key in pub_keys]
+        if not (
+            key_types(pub_keys) <= {ED25519_KEY_TYPE}
+            and set(map(len, pks)) <= {32}
+            and set(map(len, sigs)) <= {64}
+            and len(pks) == len(msgs) == len(sigs)
+        ):
+            # a lane add refuses, or columns of unequal length: lane by lane, for add to say
+            return super().add_many(pub_keys, msgs, sigs)
+        self._pks += pks
+        self._msgs += msgs
+        self._sigs += sigs
+        if len(self._sigs) >= self._look_at:
+            self._look()
+
     def __len__(self) -> int:
         return len(self._pks)
 
-    def _route(self):
-        """``(route, how)``: ``device`` and the engine's module,
-        ``remote`` and the backend's ``verify_fn``, or ``host``."""
-        if self._wants_device():
+    def _route(self, lanes: Optional[int] = None):
+        """``(route, how)`` of this batch, or of one of ``lanes``:
+        ``device`` and the engine's module, ``remote`` and the backend's
+        ``verify_fn``, or ``host``."""
+        if self._wants_device(lanes):
             # A configured verifyd remote owns the accelerator for this
             # process: ship device-worthy batches to it (it amortizes
             # across clients; its client falls back to host verify on
@@ -455,8 +510,8 @@ class Ed25519BatchVerifier(DeviceBatchVerifier):
             self._pks[lo:hi]
         )
 
-    def _device_begin(self):
-        route, ops = self._route()
+    def _device_begin(self, lanes: Optional[int] = None):
+        route, ops = self._route(lanes)
         if route != "device":
             return None
         return lambda lo, hi, early: ops.begin_verify_batch(
@@ -498,6 +553,11 @@ class HostLanesVerifier(BatchVerifier):
         if pub_key.type != self.key_type:
             raise ValueError(f"{self.key_type} host lanes got {pub_key.type} key")
         self._lanes.append((pub_key, msg, sig))
+
+    def add_many(self, pub_keys: Sequence[PubKey], msgs: Sequence[bytes], sigs: Sequence[bytes]) -> None:
+        if not (key_types(pub_keys) <= {self.key_type} and len(pub_keys) == len(msgs) == len(sigs)):
+            return super().add_many(pub_keys, msgs, sigs)
+        self._lanes += zip(pub_keys, msgs, sigs)
 
     def __len__(self) -> int:
         return len(self._lanes)
@@ -550,10 +610,12 @@ class MultiBatchVerifier(BatchVerifier):
 
     def __init__(self):
         self._subs: dict = {}
-        self._order: List[Tuple[str, int]] = []  # (key type, idx in sub)
+        self._order: List[str] = []  # each lane's key type: a sub-verifier keeps its lanes in the order added
         self.ready = False  # a sub-verifier is
 
-    def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> None:
+    def _sub(self, pub_key: PubKey) -> BatchVerifier:
+        """The sub-verifier of ``pub_key``'s type, made when its first
+        key is met."""
         kt = pub_key.type
         sub = self._subs.get(kt)
         if sub is None:
@@ -562,10 +624,70 @@ class MultiBatchVerifier(BatchVerifier):
             else:
                 sub = HostLanesVerifier(kt)
             self._subs[kt] = sub
+        return sub
+
+    def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> None:
+        kt = pub_key.type
+        sub = self._subs.get(kt)
+        if sub is None:
+            sub = self._sub(pub_key)
         sub.add(pub_key, msg, sig)
-        self._order.append((kt, len(sub) - 1))
+        self._order.append(kt)
         if sub.ready:
             self.ready = True
+
+    def _seats_by_type(self, pub_keys: Sequence[PubKey]) -> dict:
+        """``pub_keys`` grouped by key type once: key type -> the seats
+        its keys hold among them, in order; each type's sub-verifier
+        made. By the keys' classes, a class being of one type: one
+        read of ``type`` a class, not a lane."""
+        if len(set(map(type, pub_keys))) == 1:
+            self._sub(pub_keys[0])
+            return {pub_keys[0].type: range(len(pub_keys))}
+        by_class: dict = {}
+        for seat, cls in enumerate(map(type, pub_keys)):
+            try:
+                by_class[cls].append(seat)
+            except KeyError:
+                by_class[cls] = [seat]
+        seats: dict = {}
+        for cls_seats in by_class.values():
+            kt = pub_keys[cls_seats[0]].type
+            self._sub(pub_keys[cls_seats[0]])
+            # two classes of one type: their seats back in order
+            seats[kt] = sorted(seats[kt] + cls_seats) if kt in seats else cls_seats
+        return seats
+
+    def add_many(self, pub_keys: Sequence[PubKey], msgs: Sequence[bytes], sigs: Sequence[bytes]) -> None:
+        """A block one of whose lanes is refused is taken type by type,
+        not up to that lane, and leaves ``len`` behind: the caller gives
+        such a batch up, as the commit's loop does."""
+        if not len(pub_keys) == len(msgs) == len(sigs):
+            return super().add_many(pub_keys, msgs, sigs)
+        order: List = [None] * len(pub_keys)
+        for kt, seats in self._seats_by_type(pub_keys).items():
+            sub = self._subs[kt]
+            if len(seats) == len(pub_keys):
+                sub.add_many(pub_keys, msgs, sigs)
+                order = repeat(kt, len(seats))
+            else:
+                sub.add_many(*([column[seat] for seat in seats] for column in (pub_keys, msgs, sigs)))
+                for seat in seats:
+                    order[seat] = kt
+            if sub.ready:
+                self.ready = True
+        self._order += order
+
+    def room(self, pub_keys: Sequence[PubKey]) -> int:
+        """The first lane that fills a sub-verifier's job, by the seats
+        of each key type among ``pub_keys``: which sub-verifier that is
+        depends on how the types interleave."""
+        cut = _NEVER
+        for kt, seats in self._seats_by_type(pub_keys).items():
+            lanes = self._subs[kt].room()
+            if lanes <= len(seats):
+                cut = min(cut, seats[lanes - 1] + 1)
+        return cut
 
     def __len__(self) -> int:
         return len(self._order)
@@ -611,7 +733,12 @@ class MultiBatchVerifier(BatchVerifier):
         else:
             results = self._verify_in_phases()
         with tracing.span("merge_verdicts", lanes=len(self._order)):
-            merged = [bool(results[kt][i]) for kt, i in self._order]
+            if len(results) == 1:
+                ((_, verdicts),) = results.items()
+                merged = list(map(bool, verdicts))
+            else:
+                lanes = {kt: iter(verdicts) for kt, verdicts in results.items()}
+                merged = [bool(next(lanes[kt])) for kt in self._order]
             return all(merged), merged
 
 

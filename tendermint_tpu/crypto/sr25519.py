@@ -24,10 +24,10 @@ from __future__ import annotations
 
 import hashlib
 import os
-from typing import List, Optional, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 from tendermint_tpu.crypto import ristretto
-from tendermint_tpu.crypto.batch import DEVICE_THRESHOLD, DeviceBatchVerifier
+from tendermint_tpu.crypto.batch import DEVICE_THRESHOLD, DeviceBatchVerifier, key_types
 from tendermint_tpu.crypto.keys import (
     ADDRESS_LEN,
     SR25519_KEY_TYPE,
@@ -261,14 +261,21 @@ class Sr25519BatchVerifier(DeviceBatchVerifier):
         if len(self._entries) >= self._look_at:
             self._look()
 
+    def add_many(self, pub_keys: Sequence[PubKey], msgs: Sequence[bytes], sigs: Sequence[bytes]) -> None:
+        if not (key_types(pub_keys) <= {SR25519_KEY_TYPE} and len(pub_keys) == len(msgs) == len(sigs)):
+            return super().add_many(pub_keys, msgs, sigs)  # lane by lane, for add to say
+        self._entries += zip([pub_key.bytes() for pub_key in pub_keys], msgs, sigs)
+        if len(self._entries) >= self._look_at:
+            self._look()
+
     def __len__(self) -> int:
         return len(self._entries)
 
-    def _device_engine(self):
-        """``ops.sr25519_batch`` where this batch goes to the device,
-        else None (under the threshold, switched off, or no engine in
-        this install)."""
-        if self._wants_device():
+    def _device_engine(self, lanes: Optional[int] = None):
+        """``ops.sr25519_batch`` where this batch, or one of ``lanes``,
+        goes to the device, else None (under the threshold, switched
+        off, or no engine in this install)."""
+        if self._wants_device(lanes):
             try:
                 from tendermint_tpu.ops import sr25519_batch
             except ImportError:
@@ -291,8 +298,8 @@ class Sr25519BatchVerifier(DeviceBatchVerifier):
     def _columns(self, lo: int = 0, hi: Optional[int] = None):
         return tuple([e[i] for e in self._entries[lo:hi]] for i in range(3))
 
-    def _device_begin(self):
-        engine = self._device_engine()
+    def _device_begin(self, lanes: Optional[int] = None):
+        engine = self._device_engine(lanes)
         if engine is None:
             return None
         return lambda lo, hi, early: engine.begin_verify_batch_sr(

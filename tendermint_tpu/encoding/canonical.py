@@ -14,7 +14,8 @@ PartSetHeader inside CanonicalBlockID.
 
 from __future__ import annotations
 
-from typing import Callable, Dict, NamedTuple, Optional
+import functools
+from typing import Callable, Dict, Iterable, List, NamedTuple, Optional, Tuple
 
 from tendermint_tpu.encoding.proto import (
     WIRE_BYTES,
@@ -75,6 +76,19 @@ def encode_canonical_block_id(
 
 _NANOS_TAG = tag(2, WIRE_VARINT)  # Timestamp.nanos
 
+# The varint of a nanos field without a loop a lane: under 2**16 it is
+# one entry of the second table, from there to 2**30 (a valid nanos is
+# under 10**9) its low 14 bits from the first, both bytes marked as
+# continued, and the rest from the second. Built by the first block that
+# asks (~35 ms, ~4 MB): a process that encodes lane by lane never does.
+_NANOS_BY_TABLES = 1 << 30
+
+
+@functools.lru_cache(maxsize=None)
+def _nanos_tables() -> Tuple[List[bytes], List[bytes]]:
+    low14 = [bytes((k & 0x7F | 0x80, k >> 7 | 0x80)) for k in range(1 << 14)]
+    return low14, [encode_varint(k) for k in range(1 << 16)]
+
 
 class VoteSignBytesEncoder:
     """Sign-bytes of votes that share chain id, type, height and round
@@ -103,7 +117,8 @@ class VoteSignBytesEncoder:
         self, block_id_hash: bytes, psh_total: int, psh_hash: bytes
     ) -> Callable[[Timestamp], bytes]:
         """``timestamp -> sign-bytes`` for this encoder's votes for one
-        block id (all-empty arguments: a nil vote)."""
+        block id (all-empty arguments: a nil vote). Its ``many`` is the
+        same for a block of votes, ``timestamps -> [sign-bytes]``."""
         self.prefixes += 1
         bid = encode_canonical_block_id(block_id_hash, psh_total, psh_hash)
         # everything up to the timestamp's own length byte
@@ -118,6 +133,10 @@ class VoteSignBytesEncoder:
         # timestamp body length -> message length prefix + head + body
         # length byte; a body is at most 22 bytes, so its length is one
         fronts: Dict[int, bytes] = {}
+
+        def front_of(n: int) -> bytes:
+            front = fronts[n] = encode_varint(rest + n) + head + bytes((n,))
+            return front
 
         def encode(timestamp: Timestamp) -> bytes:
             seconds, nanos = timestamp
@@ -134,9 +153,38 @@ class VoteSignBytesEncoder:
             try:
                 front = fronts[n]
             except KeyError:
-                front = fronts[n] = encode_varint(rest + n) + head + bytes((n,))
+                front = front_of(n)
             return front + body + tail
 
+        # seconds -> width of the nanos varint (1 to 5) -> all that lies before it
+        starts_by_second: Dict[int, List[bytes]] = {}
+
+        def starts_of(seconds: int) -> List[bytes]:
+            field = encode_varint_field(1, seconds) + _NANOS_TAG
+            starts = starts_by_second[seconds] = [b""] + [
+                front_of(len(field) + width) + field for width in range(1, 6)
+            ]
+            return starts
+
+        def many(timestamps: Iterable[Timestamp]) -> List[bytes]:
+            low14, varint16 = _nanos_tables()
+            out = []
+            for seconds, nanos in timestamps:
+                if 0 < nanos < _NANOS_BY_TABLES:
+                    try:
+                        starts = starts_by_second[seconds]
+                    except KeyError:
+                        starts = starts_of(seconds)
+                    if nanos < 65536:
+                        digits = varint16[nanos]
+                    else:
+                        digits = low14[nanos & 16383] + varint16[nanos >> 14]
+                    out.append(starts[len(digits)] + digits + tail)
+                else:  # no nanos field, or one no valid time has
+                    out.append(encode((seconds, nanos)))
+            return out
+
+        encode.many = many
         return encode
 
 

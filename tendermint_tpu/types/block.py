@@ -13,8 +13,9 @@ an unset time.Time.
 from __future__ import annotations
 
 import hashlib
+import operator
 from dataclasses import dataclass, field as dc_field
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Sequence
 
 from tendermint_tpu.crypto import merkle
 from tendermint_tpu.crypto.keys import ADDRESS_LEN, PubKey
@@ -300,6 +301,9 @@ class CommitSig:
 
 MAX_SIGNATURE_SIZE = 64  # ed25519/sr25519; secp256k1 is also 64 here
 
+_BLOCK_ID_FLAG = operator.attrgetter("block_id_flag")
+_TIMESTAMP = operator.attrgetter("timestamp")
+
 
 class CommitSignBytes:
     """Canonical sign-bytes of one commit's precommits, for one loop.
@@ -333,11 +337,45 @@ class CommitSignBytes:
         try:
             encode = self._by_flag[cs.block_id_flag]
         except KeyError:
-            bid = cs.block_id(self._block_id)  # ValueError on an unknown flag
-            encode = self._by_flag[cs.block_id_flag] = self._encoder.for_block_id(
-                bid.hash, bid.part_set_header.total, bid.part_set_header.hash
-            )
+            encode = self._prefix(cs)
         return encode(cs.timestamp)
+
+    def _prefix(self, cs: CommitSig) -> Callable[[Timestamp], bytes]:
+        """The encoder of the votes that carry ``cs``'s BlockIDFlag,
+        built when the first of them is met."""
+        bid = cs.block_id(self._block_id)  # ValueError on an unknown flag
+        encode = self._by_flag[cs.block_id_flag] = self._encoder.for_block_id(
+            bid.hash, bid.part_set_header.total, bid.part_set_header.hash
+        )
+        return encode
+
+    def lanes(self, val_idxs: Sequence[int]) -> List[bytes]:
+        """``[lane(i) for i in val_idxs]``, a block of votes in one call:
+        the votes of one BlockIDFlag go to the encoder together, and a
+        block that holds two flags is put back in its order."""
+        signatures = self._signatures
+        votes = [signatures[i] for i in val_idxs]
+        flags = set(map(_BLOCK_ID_FLAG, votes))
+        if len(flags) <= 1:
+            return self._encode_many(flags.pop(), votes) if votes else []
+        seats_by_flag: Dict[int, List[int]] = {}  # in the order the flags are met, as lane meets them
+        for seat, cs in enumerate(votes):
+            try:
+                seats_by_flag[cs.block_id_flag].append(seat)
+            except KeyError:
+                seats_by_flag[cs.block_id_flag] = [seat]
+        out = [b""] * len(votes)
+        for flag, seats in seats_by_flag.items():
+            for seat, sign_bytes in zip(seats, self._encode_many(flag, [votes[seat] for seat in seats])):
+                out[seat] = sign_bytes
+        return out
+
+    def _encode_many(self, flag: int, votes: List[CommitSig]) -> List[bytes]:
+        try:
+            encode = self._by_flag[flag]
+        except KeyError:
+            encode = self._prefix(votes[0])
+        return encode.many(map(_TIMESTAMP, votes))
 
 
 @dataclass

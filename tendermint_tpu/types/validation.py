@@ -12,6 +12,7 @@ launch verifies every signature in the commit.
 
 from __future__ import annotations
 
+from itertools import accumulate
 from typing import Callable, NamedTuple, Optional
 
 from tendermint_tpu.crypto import batch as crypto_batch
@@ -155,6 +156,77 @@ def verify_commit_light_trusting(
         )
 
 
+def _by_flag(predicate: Callable[[CommitSig], bool], flags) -> dict:
+    """What one of a rule's predicates says of a commit signature, by
+    its BlockIDFlag: the predicate is asked once a flag the commit
+    carries, not once a lane (the three rules read nothing else of an
+    entry, validation.go:28-135)."""
+    return {flag: bool(predicate(CommitSig(block_id_flag=flag))) for flag in set(flags)}
+
+
+def _select_lanes(
+    vals: ValidatorSet,
+    commit: Commit,
+    voting_power_needed: int,
+    ignore_sig: Callable[[CommitSig], bool],
+    count_sig: Callable[[CommitSig], bool],
+    count_all_signatures: bool,
+    look_up_by_index: bool,
+    val_lookup: Callable,
+):
+    """The commit's entries that become lanes, chosen and tallied
+    without a call into the verifier: ``(idxs, validators, tallied,
+    fault)``, the lanes in the commit's order up to the one at which a
+    light rule's tally first passes ``voting_power_needed``. ``fault``
+    is the double vote that ends them, for the caller to raise once the
+    lanes before it are in and none of them was refused."""
+    entries = commit.signatures
+    flags = [commit_sig.block_id_flag for commit_sig in entries]
+    ignored, counted = _by_flag(ignore_sig, flags), _by_flag(count_sig, flags)
+    idxs = [idx for idx, flag in enumerate(flags) if not ignored[flag]]
+    tallied = 0
+    if not look_up_by_index:
+        kept, seats, seen_vals = [], [], {}
+        for idx in idxs:
+            val_idx, val = val_lookup(entries[idx].validator_address)
+            if val is None:
+                continue
+            if val_idx in seen_vals:
+                return kept, seats, tallied, InvalidCommitError(
+                    f"double vote from validator {val_idx} ({seen_vals[val_idx]} and {idx})"
+                )
+            seen_vals[val_idx] = idx
+            kept.append(idx)
+            seats.append(val)
+            if counted[flags[idx]]:
+                tallied += val.voting_power
+            if not count_all_signatures and tallied > voting_power_needed:
+                break
+        return kept, seats, tallied, None
+    validators = vals.validators
+    seats = [validators[idx] for idx in idxs]
+    powers = [val.voting_power if counted[flags[idx]] else 0 for idx, val in zip(idxs, seats)]
+    if not count_all_signatures:
+        for lanes, tallied in enumerate(accumulate(powers), 1):
+            if tallied > voting_power_needed:
+                return idxs[:lanes], seats[:lanes], tallied, None
+    return idxs, seats, sum(powers), None
+
+
+def _add_lane_by_lane(bv, encoder, entries, idxs, pub_keys) -> bool:
+    """A block holding an entry no sign-bytes are made of (an unknown
+    BlockIDFlag): lane by lane up to it, where ``lane`` raises what it
+    always did, so that a lane before it that the verifier refuses is
+    still the one that decides (False: it refused one)."""
+    for idx, pub_key in zip(idxs, pub_keys):
+        vote_sign_bytes = encoder.lane(idx)
+        try:
+            bv.add(pub_key, vote_sign_bytes, entries[idx].signature)
+        except ValueError:
+            return False
+    return True
+
+
 def _verify_commit_batch(
     chain_id: str,
     vals: ValidatorSet,
@@ -178,9 +250,6 @@ def _verify_commit_batch(
     an entry its own verifier refuses to take (a malformed ed25519 key
     or signature) sends the commit to single verification.
     """
-    tallied = 0
-    seen_vals = {}
-    batch_sig_idxs = []
     # Make this set's keys eligible for the device precompute cache —
     # the second commit from the same validators skips its table builds.
     crypto_batch.note_validator_set_traced(vals)
@@ -188,63 +257,56 @@ def _verify_commit_batch(
     # a malformed entry raises on add -> single fallback.
     bv = crypto_batch.MultiBatchVerifier()
     unbatchable = False
-    early_lanes = 0
+    early_lanes = blocks = at = 0
     encoder = commit.sign_bytes_encoder(chain_id)
-    n_sigs = len(commit.signatures)
-    entries = enumerate(commit.signatures)
+    entries = commit.signatures
+    batch_sig_idxs = None
     try:
-        # The loop runs a block at a time: once the verifier holds a full
-        # engine job of lanes (bv.ready) it is told to begin them, and the
-        # device works while the next block is built. One build_lanes span
-        # a block, the begin between two of them and never inside one: a
-        # span's per-lane steps are phase totals in its arguments (wrapped
-        # once a span: the loop itself holds no tracing call, and on the
-        # no-op span these are the callables themselves), and none of them
-        # holds engine time.
-        building = True
-        while building:
-            building = False
+        # The lanes are built a block at a time, a block being the lanes
+        # up to the one that fills the verifier's next engine job (bv.room
+        # says which: in a mixed set it depends on the seats) or the rest
+        # of the commit: its sign-bytes in one call, one add_many, and
+        # then the verifier is told to begin the job, so that the device
+        # works while the next block is built. One build_lanes span a
+        # block, the begin between two of them and never inside one: a
+        # span's steps are phase totals in its arguments (wrapped once a
+        # span: on the no-op span these are the callables themselves),
+        # and none of them holds engine time. The first span also holds
+        # the choice of the commit's lanes and their tally.
+        while True:
             with tracing.span("build_lanes") as lsp:
-                sign_bytes = lsp.timed("sign_bytes", encoder.lane)
-                batch_add = lsp.timed("batch_add", bv.add)
-                val_lookup = lsp.timed("val_lookup", vals.get_by_address)
-                held = len(batch_sig_idxs)
-                for idx, commit_sig in entries:
-                    if ignore_sig(commit_sig):
-                        continue
-                    if look_up_by_index:
-                        val = vals.validators[idx]
-                    else:
-                        val_idx, val = val_lookup(commit_sig.validator_address)
-                        if val is None:
-                            continue
-                        if val_idx in seen_vals:
-                            raise InvalidCommitError(
-                                f"double vote from validator {val_idx} "
-                                f"({seen_vals[val_idx]} and {idx})"
-                            )
-                        seen_vals[val_idx] = idx
-                    vote_sign_bytes = sign_bytes(idx)
+                if batch_sig_idxs is None:
+                    batch_sig_idxs, seats, tallied, fault = _select_lanes(
+                        vals, commit, voting_power_needed, ignore_sig, count_sig,
+                        count_all_signatures, look_up_by_index,
+                        lsp.timed("val_lookup", vals.get_by_address),
+                    )
+                    pub_keys = [val.pub_key for val in seats]
+                sign_bytes = lsp.timed("sign_bytes", encoder.lanes)
+                batch_add = lsp.timed("batch_add", bv.add_many)
+                end = min(at + bv.room(pub_keys[at:]), len(pub_keys))
+                idxs, keys = batch_sig_idxs[at:end], pub_keys[at:end]
+                blocks += 1
+                block_lanes = 0
+                try:
+                    vote_sign_bytes = sign_bytes(idxs)
+                except ValueError:
+                    unbatchable = not _add_lane_by_lane(bv, encoder, entries, idxs, keys)
+                else:
                     try:
-                        batch_add(val.pub_key, vote_sign_bytes, commit_sig.signature)
+                        batch_add(keys, vote_sign_bytes, [entries[idx].signature for idx in idxs])
+                        block_lanes = len(idxs)
                     except ValueError:
                         unbatchable = True
-                        break
-                    batch_sig_idxs.append(idx)
-                    if count_sig(commit_sig):
-                        tallied += val.voting_power
-                    if not count_all_signatures and tallied > voting_power_needed:
-                        break
-                    if bv.ready and idx + 1 < n_sigs:
-                        building = True
-                        break
-                lsp.set(
-                    lanes=len(batch_sig_idxs) - held, sign_bytes_prefixes=encoder.prefixes
-                )
-            if building:
-                early_lanes += bv.begin_ready()
-        tracing.tag(early_lanes=early_lanes)  # on the caller's verify_commit span
+                lsp.set(lanes=len(idxs), block_lanes=block_lanes, sign_bytes_prefixes=encoder.prefixes)
+            at = end
+            if unbatchable or at == len(batch_sig_idxs):
+                break
+            early_lanes += bv.begin_ready()
+        tracing.tag(early_lanes=early_lanes, blocks=blocks)  # on the caller's verify_commit span
         if not unbatchable:
+            if fault is not None:
+                raise fault
             if tallied <= voting_power_needed:
                 raise NotEnoughVotingPowerError(got=tallied, needed=voting_power_needed)
             ok, valid_sigs = bv.verify()

@@ -241,3 +241,135 @@ def sound(out: dict, said: str, compared, bench=None, cell=None):
 def over_limit(said: str) -> list:
     """The comparisons a run's output marks over their limit, in its order."""
     return [ln.split("compared: ", 1)[1].split(" = ")[0] for ln in said.splitlines() if ln.endswith("<-- over")]
+
+
+# --- the commit entry's loop as it ran lane by lane, and what a verifier was handed ------
+
+
+def lane_by_lane_commit_batch(
+    chain_id, vals, commit, voting_power_needed, ignore_sig, count_sig, count_all_signatures, look_up_by_index
+):
+    """``types/validation._verify_commit_batch`` as it stood until PR 45:
+    one turn of a ``for`` loop a commit signature, ``encoder.lane`` and
+    ``bv.add`` a lane. The reference the block-wise loop is held against;
+    stand it in with ``monkeypatch.setattr(validation, "_verify_commit_batch", ...)``."""
+    from tendermint_tpu.crypto import batch as crypto_batch
+    from tendermint_tpu.libs import tracing
+    from tendermint_tpu.types import validation
+    from tendermint_tpu.types.validation import InvalidCommitError, NotEnoughVotingPowerError
+
+    tallied = 0
+    seen_vals = {}
+    batch_sig_idxs = []
+    crypto_batch.note_validator_set_traced(vals)
+    bv = crypto_batch.MultiBatchVerifier()
+    unbatchable = False
+    early_lanes = 0
+    encoder = commit.sign_bytes_encoder(chain_id)
+    n_sigs = len(commit.signatures)
+    entries = enumerate(commit.signatures)
+    try:
+        building = True
+        while building:
+            building = False
+            with tracing.span("build_lanes") as lsp:
+                held = len(batch_sig_idxs)
+                for idx, commit_sig in entries:
+                    if ignore_sig(commit_sig):
+                        continue
+                    if look_up_by_index:
+                        val = vals.validators[idx]
+                    else:
+                        val_idx, val = vals.get_by_address(commit_sig.validator_address)
+                        if val is None:
+                            continue
+                        if val_idx in seen_vals:
+                            raise InvalidCommitError(
+                                f"double vote from validator {val_idx} ({seen_vals[val_idx]} and {idx})"
+                            )
+                        seen_vals[val_idx] = idx
+                    vote_sign_bytes = encoder.lane(idx)
+                    try:
+                        bv.add(val.pub_key, vote_sign_bytes, commit_sig.signature)
+                    except ValueError:
+                        unbatchable = True
+                        break
+                    batch_sig_idxs.append(idx)
+                    if count_sig(commit_sig):
+                        tallied += val.voting_power
+                    if not count_all_signatures and tallied > voting_power_needed:
+                        break
+                    if bv.ready and idx + 1 < n_sigs:
+                        building = True
+                        break
+                lsp.set(lanes=len(batch_sig_idxs) - held)
+            if building:
+                early_lanes += bv.begin_ready()
+        tracing.tag(early_lanes=early_lanes)
+        if not unbatchable:
+            if tallied <= voting_power_needed:
+                raise NotEnoughVotingPowerError(got=tallied, needed=voting_power_needed)
+            ok, valid_sigs = bv.verify()
+    finally:
+        bv.close()
+    if unbatchable:
+        return validation._verify_commit_single(
+            chain_id, vals, commit, voting_power_needed, ignore_sig, count_sig,
+            count_all_signatures, look_up_by_index,
+        )
+    if ok:
+        return
+    for i, sig_ok in enumerate(valid_sigs):
+        if not sig_ok:
+            idx = batch_sig_idxs[i]
+            raise InvalidCommitError(
+                f"wrong signature (#{idx}): {commit.signatures[idx].signature.hex().upper()}"
+            )
+    raise InvalidCommitError("BUG: batch verification failed with no invalid signatures")
+
+
+def record_verifier(monkeypatch) -> list:
+    """Stands a ``MultiBatchVerifier`` in that writes down what it is
+    handed and told, in order: ``("lane", key bytes, msg, sig)`` a lane,
+    whether by ``add`` or by ``add_many`` and whether or not it then
+    took it, ``("begun", lanes)`` where ``begin_ready()`` began some,
+    ``("verify",)``, ``("close",)``. The list is what comes back; empty
+    it between two calls to compare."""
+    from tendermint_tpu.crypto import batch as crypto_batch
+
+    said = []
+
+    class Recording(crypto_batch.MultiBatchVerifier):
+        def add(self, pub_key, msg, sig):
+            said.append(("lane", pub_key.bytes(), msg, sig))
+            super().add(pub_key, msg, sig)
+
+        def add_many(self, pub_keys, msgs, sigs):
+            said.extend(("lane", key.bytes(), msg, sig) for key, msg, sig in zip(pub_keys, msgs, sigs))
+            super().add_many(pub_keys, msgs, sigs)
+
+        def begin_ready(self):
+            begun = super().begin_ready()
+            if begun:
+                said.append(("begun", begun))
+            return begun
+
+        def verify(self):
+            said.append(("verify",))
+            return super().verify()
+
+        def close(self):
+            said.append(("close",))
+            super().close()
+
+    monkeypatch.setattr(crypto_batch, "MultiBatchVerifier", Recording)
+    return said
+
+
+def outcome(fn):
+    """``(type, message)`` of what ``fn`` raised, or None."""
+    try:
+        fn()
+    except Exception as exc:  # noqa: BLE001 - whatever it is, it is compared
+        return type(exc).__name__, str(exc)
+    return None

@@ -338,11 +338,14 @@ def test_library_phase_at_40_validators(_auto_paths_on, monkeypatch):
     )
     assert report["early_begin"]["refused"] == [31, 32, 51]
     small, size = report["sizes"]
-    assert small["counters"]["resident_hits"] == 24 * 4
+    # two heights, the tampered commit twice, and one commit a block's edge
+    assert (small["edges"], small["blocks"]) == ([0, 23], 1)
+    assert small["counters"]["resident_hits"] == 24 * (4 + 2)
     assert small["counters"]["resident_uploads"] == 1
+    assert (size["edges"], size["blocks"]) == ([0, 31, 32, 39], 2)
     c = size["counters"]
-    assert c["hash_device_lanes"] == 40 * 5
-    assert c["resident_hits"] == 40 * 4 and c["resident_uploads"] == 1
+    assert c["hash_device_lanes"] == 40 * (5 + 4)
+    assert c["resident_hits"] == 40 * (4 + 4) and c["resident_uploads"] == 1
     assert c["gathered_h2d_bytes"] == 0 and c["fallback_batches"] == 0
     p = report["pipelined"]
     assert p["lanes_per_window"] == 4 * 14

@@ -26,10 +26,13 @@ from tendermint_tpu.types import validation
 from tests.helpers import (
     CHAIN_ID,
     REAL_BENCH,
+    lane_by_lane_commit_batch,
     make_block_id,
     make_commit,
     make_validators,
+    outcome,
     read,
+    record_verifier,
     rehearse_cell,
     sound,
     traced,
@@ -376,14 +379,14 @@ def double_vote(privs, vset, block_id):
 
 def add_raises(privs, vset, block_id, monkeypatch):
     commit = make_commit(block_id, 5, 0, vset, privs)
-    add = crypto_batch.MultiBatchVerifier.add
+    add_many = crypto_batch.MultiBatchVerifier.add_many
 
-    def failing(self, pub_key, msg, sig):
-        if len(self) == 20:
+    def failing(self, pub_keys, msgs, sigs):
+        if len(self) == JOB:  # the second block
             raise Boom("add")
-        return add(self, pub_key, msg, sig)
+        return add_many(self, pub_keys, msgs, sigs)
 
-    monkeypatch.setattr(crypto_batch.MultiBatchVerifier, "add", failing)
+    monkeypatch.setattr(crypto_batch.MultiBatchVerifier, "add_many", failing)
     return (lambda: validation.verify_commit(CHAIN_ID, vset, block_id, 5, commit)), (Boom, "add")
 
 
@@ -462,6 +465,205 @@ def test_a_fault_at_the_collect_of_an_early_block_sends_that_block_to_the_oracle
     nothing_in_flight(health)
 
 
+# --- a block of lanes in one call (ISSUE 45) -------------------------------------
+
+
+def state_of(bv):
+    """What a verifier holds, column by column, a mixed one's by key type."""
+    if isinstance(bv, crypto_batch.MultiBatchVerifier):
+        return list(bv._order), {kt: state_of(sub) for kt, sub in bv._subs.items()}, bv.ready
+    columns = [getattr(bv, name) for name in ("_pks", "_msgs", "_sigs", "_entries", "_lanes") if hasattr(bv, name)]
+    return [list(column) for column in columns], len(bv), bv.ready
+
+
+def in_blocks(bv, lanes):
+    """Hand ``lanes`` over as ``_verify_commit_batch`` does: a block up
+    to where ``room`` says, begin what is ready, the next."""
+    keys = [lane[0] for lane in lanes]
+    at = begun = 0
+    ends = []
+    while at < len(lanes):
+        if at:
+            begun += bv.begin_ready()
+        end = min(at + bv.room(keys[at:]), len(lanes))
+        bv.add_many(*zip(*lanes[at:end]))
+        ends.append(end)
+        at = end
+    return begun, ends
+
+
+@pytest.mark.parametrize("n", SIZES)
+@pytest.mark.parametrize("kind", ["ed25519", "sr25519", "secp256k1", "mixed"])
+def test_add_many_is_add_of_each_lane(monkeypatch, signed, kind, n):
+    """One ``add_many`` of a batch leaves each of the four verifiers
+    holding what ``add`` of each lane does, in the same order, and
+    gives the same verdicts; so do the blocks ``room`` cuts, which are
+    begun where a caller that looks after each ``add`` begins them."""
+    if kind == "secp256k1":
+        factory, lanes = (lambda: crypto_batch.HostLanesVerifier("secp256k1")), flip(signed[kind] * n, [0])[: max(n, 3)]
+    else:
+        factory, lanes = batch_of(kind, signed, n)
+    jobs_have_run(monkeypatch)
+    monkeypatch.setattr(ed25519_batch, "job_lanes", lambda: NO_JOB)
+    one_by_one, at_once = factory(), factory()
+    for lane in lanes:
+        one_by_one.add(*lane)
+    at_once.add_many(*zip(*lanes))
+    assert state_of(at_once) == state_of(one_by_one) and len(at_once) == len(lanes)
+    want = one_by_one.verify()
+    assert at_once.verify() == want and want[1] == [oracle(lane) for lane in lanes]
+
+    monkeypatch.setattr(ed25519_batch, "job_lanes", lambda: JOB)
+    looked, cut = factory(), factory()
+    begun = 0
+    for at, lane in enumerate(lanes):  # the lane-by-lane loop: a look after each add while lanes are left
+        looked.add(*lane)
+        if looked.ready and at + 1 < len(lanes):
+            begun += looked.begin_ready()
+    got, ends = in_blocks(cut, lanes)
+    assert got == begun and state_of(cut) == state_of(looked)
+    if kind != "secp256k1":
+        assert blocks_of(cut) == blocks_of(looked)
+    if kind in ("ed25519", "sr25519"):
+        assert ends == sorted({*range(JOB, n, JOB), n})  # a block a job, the rest: never a lane a block
+    assert len(ends) <= 2 * (n // JOB) + 1
+    assert cut.verify() == want
+
+
+@pytest.mark.parametrize("kind", ["ed25519", "sr25519", "mixed"])
+def test_add_after_add_many_and_the_reverse_begin_a_ready_job(job, signed, kind):
+    factory, lanes = batch_of(kind, signed, MOST)
+    subs = 2 if kind == "mixed" else 1
+    full = JOB * subs + (2 if kind == "mixed" else 0)  # the lanes that give every device type a job (two host lanes among them)
+    bv = factory()
+    bv.add_many(*zip(*lanes[:full]))
+    assert bv.ready and not any(blocks_of(bv))
+    bv.add(*lanes[full])  # the next lane begins what was left ready, as after JOB adds
+    assert sum(blocks_of(bv)) >= 1
+    other = factory()
+    for lane in lanes[:full]:
+        other.add(*lane)
+    assert other.ready
+    other.add_many(*zip(*lanes[full:]))
+    # what was ready is begun, with the jobs the block filled behind it (a sub-verifier
+    # whose job an add had already begun is left ready once more)
+    assert all(1 <= begun <= MOST // JOB for begun in blocks_of(other)) and MOST // JOB in blocks_of(other)
+    want = [oracle(lane) for lane in lanes]
+    bv.add_many(*zip(*lanes[full + 1:]))
+    assert bv.verify()[1] == want and other.verify()[1] == want
+
+
+@pytest.mark.parametrize("kind", ["ed25519", "sr25519", "secp256k1", "mixed"])
+def test_add_many_refuses_what_add_refuses(signed, kind):
+    """A key of another type, a short signature or key, columns of
+    unequal length: ``ValueError``, as lane by lane."""
+    if kind == "secp256k1":
+        factory, lanes = (lambda: crypto_batch.HostLanesVerifier("secp256k1")), list(signed[kind])
+    else:
+        factory, lanes = batch_of(kind, signed, JOB)
+    keys, msgs, sigs = (list(column) for column in zip(*lanes))
+    with pytest.raises(ValueError):
+        factory().add_many(keys, msgs[:-1], sigs)
+    assert len(factory()) == 0
+    if kind == "mixed":
+        return
+    stranger = signed["sr25519" if kind == "ed25519" else "ed25519"][0]
+    for bad in ([stranger] + lanes[1:], lanes[:2] + [stranger]):
+        by_block, by_lane = factory(), factory()
+        with pytest.raises(ValueError) as refused:
+            by_block.add_many(*zip(*bad))
+        with pytest.raises(ValueError) as refused_by_lane:
+            for lane in bad:
+                by_lane.add(*lane)
+        assert str(refused.value) == str(refused_by_lane.value)
+        assert state_of(by_block) == state_of(by_lane)  # the lanes before it taken, as by add
+    if kind == "ed25519":
+        short = [(keys[0], msgs[0], sigs[0][:63])]
+        with pytest.raises(ValueError, match="malformed ed25519 entry"):
+            factory().add_many(*zip(*(lanes[:3] + short + lanes[3:])))
+
+
+class SrEngine:
+    """``Engine`` for the sr25519 seam."""
+
+    def __init__(self, monkeypatch):
+        from tendermint_tpu.ops import sr25519_batch
+
+        self.begun = []
+        monkeypatch.setattr(sr25519_batch, "begin_verify_batch_sr", self.begin)
+        monkeypatch.setattr(sr25519_batch, "verify_batch_sr", lambda pks, msgs, sigs, backend=None: [True] * len(pks))
+
+    def begin(self, pks, msgs, sigs, backend=None, early=False):
+        self.begun.append((len(pks), early))
+        return type("Pending", (), {"lanes_inflight": len(pks), "finish": lambda self: [True] * len(pks)})()
+
+
+def big_commit(n_ed, n_sr=0, n_secp=0):
+    """A commit of that many validators of each type, signed by nobody
+    (the engines are stood in): keys and signatures from a seed."""
+    from tendermint_tpu.crypto.keys import Ed25519PubKey
+    from tendermint_tpu.crypto.sr25519 import Sr25519PubKey
+    from tendermint_tpu.encoding.canonical import Timestamp
+    from tendermint_tpu.types import BLOCK_ID_FLAG_COMMIT, Commit, CommitSig, Validator, ValidatorSet
+
+    rng = np.random.default_rng(45)
+    secp = Secp256k1PrivKey(bytes([7, 1]) * 16).pub_key()
+    vals = [Validator(Ed25519PubKey(rng.bytes(32)), 10) for _ in range(n_ed)]
+    vals += [Validator(Sr25519PubKey(rng.bytes(32)), 10) for _ in range(n_sr)]
+    vals += [Validator(secp, 10, address=rng.bytes(20)) for _ in range(n_secp)]
+    vset = ValidatorSet(vals)
+    block_id = make_block_id(b"early-10k")
+    commit = Commit(height=3, round=0, block_id=block_id)
+    commit.signatures = [
+        CommitSig(BLOCK_ID_FLAG_COMMIT, v.address, Timestamp(1_700_000_000, 1 + 99_991 * i), rng.bytes(64))
+        for i, v in enumerate(vset.validators)
+    ]
+    return vset, block_id, commit
+
+
+@pytest.mark.parametrize(
+    "devices,shape,blocks,early",
+    [
+        (1, (10_000, 0, 0), [4096, 4096, 1808], {"ed25519": [(4096, True), (4096, True), (1808, False)]}),
+        (4, (10_000, 0, 0), [10_000], {"ed25519": []}),
+        (1, (4950, 4950, 100), None, {"ed25519": [(4096, True), (854, False)], "sr25519": [(4096, True), (854, False)]}),
+    ],
+    ids=["one_chip", "four_chips", "mixed_one_chip"],
+)
+def test_a_10000_lane_commit_is_begun_where_the_lane_by_lane_loop_began_it(monkeypatch, devices, shape, blocks, early):
+    """The chip's own job (4,096 lanes a device), the engines stood in:
+    4,096 and 8,192 of 10,000 lanes are begun early on one device, none
+    where a plan spans four, 4,096 of each device type in a mixed set;
+    lane for lane and begin for begin what the lane-by-lane loop did."""
+    engine, sr_engine = Engine(monkeypatch), SrEngine(monkeypatch)
+    jobs_have_run(monkeypatch)
+    monkeypatch.setattr(mesh.manager, "device_count", lambda: devices)
+    monkeypatch.setattr(precompute.tables, "would_build", lambda keys: False)
+    monkeypatch.setattr(
+        crypto_batch.HostLanesVerifier, "verify", lambda self, device_lanes_inflight=0: (True, [True] * len(self))
+    )
+    vset, block_id, commit = big_commit(*shape)
+    call = lambda: validation.verify_commit(CHAIN_ID, vset, block_id, 3, commit)
+    said = record_verifier(monkeypatch)
+    raised, events = traced(call)
+    assert raised is None
+    assert engine.begun == early["ed25519"] and sr_engine.begun == early.get("sr25519", [])
+    assert engine.whole == ([10_000] if devices == 4 else [])
+    loops = [e["args"] for e in events if e["name"] == "build_lanes"]
+    (outer,) = [e["args"] for e in events if e["name"] == "verify_commit"]
+    assert outer["blocks"] == len(loops) <= 4  # a handful, however the types interleave
+    assert outer["early_lanes"] == sum(lanes for begun in early.values() for lanes, is_early in begun if is_early)
+    assert all(a["block_lanes"] == a["lanes"] for a in loops) and sum(a["lanes"] for a in loops) == 10_000
+    if blocks is not None:
+        assert [a["lanes"] for a in loops] == blocks
+    new = list(said)
+    del said[:], engine.begun[:], sr_engine.begun[:], engine.whole[:]
+    monkeypatch.setattr(validation, "_verify_commit_batch", lane_by_lane_commit_batch)
+    assert outcome(call) is None
+    assert said == new
+    assert engine.begun == early["ed25519"] and sr_engine.begun == early.get("sr25519", [])
+
+
 # --- span hygiene ---------------------------------------------------------------
 
 
@@ -486,8 +688,12 @@ def test_the_spans_of_a_two_job_commit_keep_the_engine_out_of_the_entrys_names(m
     (outer,) = by("verify_commit")
     assert outer["args"]["early_lanes"] == 2 * JOB and outer["args"]["sigs"] == n
     loops, batches = by("build_lanes"), by("batch_verify")
-    assert [e["args"]["lanes"] for e in loops] == [JOB, JOB, 5]
-    assert [e["args"]["batch_add_n"] for e in loops] == [JOB, JOB, 5]
+    assert [e["args"]["lanes"] for e in loops] == [JOB, JOB, 5] and outer["args"]["blocks"] == 3
+    # every lane handed over by its block's one add_many, its sign-bytes made in one call
+    assert [e["args"]["block_lanes"] for e in loops] == [JOB, JOB, 5]
+    assert [(e["args"]["sign_bytes_n"], e["args"]["batch_add_n"]) for e in loops] == [(1, 1)] * 3
+    assert all(e["args"]["sign_bytes_us"] > 0 and e["args"]["batch_add_us"] > 0 for e in loops)
+    assert all(e["args"]["sign_bytes_us"] + e["args"]["batch_add_us"] <= e["dur"] for e in loops)
     assert [(e["args"]["lanes"], e["args"]["phase"], e["args"].get("early")) for e in batches] == [
         (JOB, "dispatch", 1), (JOB, "dispatch", 1), (5, "dispatch", None),
         (JOB, "collect", None), (JOB, "collect", None), (5, "collect", None),

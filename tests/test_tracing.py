@@ -518,7 +518,7 @@ def test_commit_yields_each_new_span_once_under_its_parent(
     "name,args",
     [
         ("note_validator_set", {"validators": N_LANES, "newly_active": False, "recognised": True}),
-        ("build_lanes", {"lanes": N_LANES, "sign_bytes_prefixes": 1}),
+        ("build_lanes", {"lanes": N_LANES, "block_lanes": N_LANES, "sign_bytes_prefixes": 1}),
         ("batch_verify", {"key_type": "ed25519", "lanes": N_LANES, "route": "device"}),
         ("merge_verdicts", {"lanes": N_LANES}),
         ("route_lanes", {"lanes": N_LANES, "resident": N_LANES, "tables": 0, "legacy": 0, "jobs": 1}),
@@ -537,9 +537,12 @@ def test_commit_span_arguments(commit_capture, name, args):
 def test_build_lanes_phase_totals(commit_capture):
     (ev,) = [e for e in commit_capture["events"] if e["name"] == "build_lanes"]
     a = ev["args"]
-    assert a["sign_bytes_n"] == a["batch_add_n"] == a["lanes"] == N_LANES
+    # one timed call a block each (ISSUE 45): the block's sign-bytes, its add_many
+    assert a["sign_bytes_n"] == a["batch_add_n"] == 1 and a["block_lanes"] == a["lanes"] == N_LANES
     assert a["sign_bytes_us"] > 0 and a["batch_add_us"] > 0
     assert a["sign_bytes_us"] + a["batch_add_us"] <= ev["dur"]
+    (outer,) = [e for e in commit_capture["events"] if e["name"] == "verify_commit"]
+    assert outer["args"]["blocks"] == 1 and outer["args"]["early_lanes"] == 0
     # the index path never looks a validator up by address
     assert "val_lookup_us" not in a and "val_lookup_n" not in a
 
@@ -567,9 +570,10 @@ def test_off_mode_leaves_nothing_behind(commit_capture):
 
 @pytest.mark.parametrize("n", [12, 48])
 def test_off_mode_the_lane_loop_calls_the_encoder_itself(monkeypatch, n):
-    """Tracer off, ``build_lanes`` hands its loop the commit encoder's
-    own bound method (no wrapper, no tracing call per lane) and opens
-    the same spans whatever the lanes."""
+    """Tracer off, ``build_lanes`` calls the commit encoder's and the
+    verifier's own bound methods (no wrapper, no tracing call a lane or
+    a block) and opens the same spans whatever the lanes."""
+    from tendermint_tpu.crypto import batch as crypto_batch
     from tendermint_tpu.types import validation
     from tendermint_tpu.types.block import CommitSignBytes
 
@@ -591,7 +595,8 @@ def test_off_mode_the_lane_loop_calls_the_encoder_itself(monkeypatch, n):
     monkeypatch.setattr(tracing, "span", counting)
     monkeypatch.setattr(tracing._NopSpan, "timed", timed)
     validation.verify_commit(CHAIN_ID, vset, block_id, 3, commit)
-    assert phases["sign_bytes"].__func__ is CommitSignBytes.lane
+    assert phases["sign_bytes"].__func__ is CommitSignBytes.lanes
+    assert phases["batch_add"].__func__ is crypto_batch.MultiBatchVerifier.add_many
     ours = ("note_validator_set", "build_lanes", "single_verify", "sign_bytes")
     assert [name for name in opened if name in ours] == ["note_validator_set", "build_lanes"]
     assert len(opened) < 40
@@ -613,7 +618,55 @@ def test_build_lanes_counts_a_second_prefix_where_a_nil_vote_is_sent():
         tracing.tracer.clear()
     (loop,) = [e for e in events if e["name"] == "build_lanes"]
     a = loop["args"]
-    assert (a["lanes"], a["sign_bytes_n"], a["sign_bytes_prefixes"]) == (63, 63, 2)
+    assert (a["lanes"], a["block_lanes"], a["sign_bytes_n"], a["sign_bytes_prefixes"]) == (63, 63, 1, 2)
+
+
+@pytest.mark.parametrize("entry", ["verify_commit", "verify_commit_light_trusting", "unknown_flag"])
+def test_one_build_lanes_span_a_block_and_the_blocks_sum_to_the_call(monkeypatch, entry):
+    """A commit of three jobs' lanes (ISSUE 45): one ``build_lanes`` a
+    block, every ``batch_verify`` beside them, their ``lanes`` the
+    call's, ``block_lanes`` the lanes a span handed over by its one
+    ``add_many`` (0 where they went through ``add``: a block holding an
+    entry of an unknown flag), the by-address lookups a phase of the
+    span that chooses the lanes."""
+    from tendermint_tpu.crypto import batch as crypto_batch
+    from tendermint_tpu.ops import ed25519_batch, precompute
+    from tendermint_tpu.types import validation
+    from tests.helpers import traced
+
+    monkeypatch.setattr(ed25519_batch, "job_lanes", lambda: 16)
+    monkeypatch.setattr(ed25519_batch, "_ENGINES_WITH_A_JOB_RUN", {"ed25519", "sr25519"})
+    privs, vset = make_validators(40)
+    crypto_batch.note_validator_set(vset)
+    precompute.tables.gather([v.pub_key.bytes() for v in vset.validators])
+    block_id = make_block_id(b"issue-45-spans")
+    commit = make_commit(block_id, 4, 0, vset, privs, absent={2})
+    if entry == "verify_commit_light_trusting":
+        call = lambda: validation.verify_commit_light_trusting(CHAIN_ID, vset, commit, validation.Fraction(9, 10))
+    else:
+        call = lambda: validation.verify_commit(CHAIN_ID, vset, block_id, 4, commit)
+    if entry == "unknown_flag":
+        commit.signatures[37].block_id_flag = 7
+    raised, events = traced(call)
+    assert (raised is None) == (entry != "unknown_flag")
+    loops = [e for e in events if e["name"] == "build_lanes"]
+    batches = [e for e in events if e["name"] == "batch_verify"]
+    # 39 signed seats; nine tenths of 400 are passed at the 37th; the span an exception leaves carries no count
+    want = {"verify_commit": [16, 16, 7], "verify_commit_light_trusting": [16, 16, 5], "unknown_flag": [16, 16, None]}[entry]
+    assert [e["args"].get("lanes") for e in loops] == want
+    assert [e["args"].get("block_lanes") for e in loops] == want  # all by add_many, or lane by lane up to the fault
+    for b in batches:  # beside every loop span, inside none
+        assert all(b["ts"] + b["dur"] <= l["ts"] or l["ts"] + l["dur"] <= b["ts"] for l in loops)
+    assert [b["args"].get("early") for b in batches[:2]] == [1, 1]
+    if entry == "verify_commit":
+        (outer,) = [e for e in events if e["name"] == "verify_commit"]
+        assert (outer["args"]["blocks"], outer["args"]["early_lanes"]) == (3, 32)
+    if entry == "unknown_flag":
+        assert str(raised) == "unknown BlockIDFlag: 7"
+        assert "batch_add_n" not in loops[2]["args"]  # the lanes before the fault went through add, untimed
+    assert [e["args"].get("sign_bytes_n") for e in loops] == [1, 1, 1 if raised is None else None]
+    looked_up = [e["args"].get("val_lookup_n") for e in loops]
+    assert looked_up == ([37, None, None] if entry == "verify_commit_light_trusting" else [None] * 3)
 
 
 def test_off_mode_holds_no_jax_listener():

@@ -171,3 +171,164 @@ class TestBatchSingleEquivalence:
                 except Exception as e:
                     results.append(type(e).__name__)
             assert results[0] == results[1], f"corrupt={corrupt}: {results}"
+
+
+# --- the block-wise loop against the lane-by-lane one it replaced (ISSUE 45) -----------
+
+import copy
+
+from tendermint_tpu.crypto import batch as crypto_batch
+from tendermint_tpu.ops import ed25519_batch, precompute
+from tendermint_tpu.types import Validator, ValidatorSet, validation
+from tests.helpers import lane_by_lane_commit_batch, outcome, record_verifier
+
+JOB = 16  # lanes an engine job, stood in: a 40-lane commit is three blocks
+N_VALS = 40
+
+
+@pytest.fixture(scope="module")
+def net():
+    privs, vset = make_validators(N_VALS)
+    block_id = make_block_id(b"issue-45")
+    return privs, vset, block_id, make_commit(block_id, 7, 0, vset, privs, absent={2, 17}, nil_votes={5, 30})
+
+
+@pytest.fixture
+def blocks(monkeypatch, net):
+    """A node that has verified a commit of the set, at a job of 16
+    lanes: every full job is begun early, as on the chip at 4,096."""
+    monkeypatch.setattr(ed25519_batch, "job_lanes", lambda: JOB)
+    monkeypatch.setattr(ed25519_batch, "_ENGINES_WITH_A_JOB_RUN", {"ed25519", "sr25519"})
+    crypto_batch.note_validator_set(net[1])
+    precompute.tables.gather([v.pub_key.bytes() for v in net[1].validators])
+
+
+def _tampered(commit, idx):
+    sig = bytearray(commit.signatures[idx].signature)
+    sig[33] ^= 0x04
+    commit.signatures[idx].signature = bytes(sig)
+
+
+def _malformed(commit, idx):
+    commit.signatures[idx].signature = commit.signatures[idx].signature[:63]
+
+
+def _double_vote(commit, idx, of):
+    commit.signatures[idx] = commit.signatures[of]
+
+
+def _unknown_flag(commit, idx):
+    commit.signatures[idx].block_id_flag = 7
+
+
+# name -> what is done to a copy of the sound commit (lanes: every entry but 2 and 17)
+SCENARIOS = {
+    "sound": [],
+    "bad_first_lane": [(_tampered, 0)],
+    "bad_last_lane_of_block_1": [(_tampered, 16)],  # lane 15: entries 2 is absent
+    "bad_first_lane_of_block_2": [(_tampered, 18)],  # lane 16: entries 2 and 17 are absent
+    "bad_last_lane": [(_tampered, 39)],
+    "two_bad_lanes": [(_tampered, 33), (_tampered, 9)],
+    "malformed": [(_malformed, 21)],
+    "malformed_in_block_1_bad_before_it": [(_malformed, 12), (_tampered, 4)],
+    "double_vote": [(_double_vote, 20, 3)],
+    "double_vote_then_malformed": [(_double_vote, 12, 3), (_malformed, 31)],
+    "malformed_then_double_vote": [(_malformed, 10), (_double_vote, 20, 3)],
+    "unknown_flag": [(_unknown_flag, 25)],
+    "malformed_then_unknown_flag": [(_malformed, 10), (_unknown_flag, 25)],
+    "unknown_flag_then_malformed": [(_unknown_flag, 10), (_malformed, 25)],
+    "bad_then_unknown_flag": [(_tampered, 3), (_unknown_flag, 25)],
+    "unknown_flag_in_a_nil_vote_seat": [(_unknown_flag, 5), (_double_vote, 36, 1)],
+}
+# what is done to the set the commit is held against
+SETS = {
+    "its_own": lambda vset: vset,
+    "a_third_of_it": lambda vset: ValidatorSet([v.copy() for v in vset.validators[4::3]]),
+    "heavy_seats": lambda vset: ValidatorSet(
+        [Validator(v.pub_key, 1000 if i < 2 else 1) for i, v in enumerate(vset.validators)]  # the order stays
+    ),
+}
+
+
+def _entry(name, vset, block_id, commit):
+    if name == "verify_commit":
+        return lambda: verify_commit(CHAIN_ID, vset, block_id, 7, commit)
+    if name == "verify_commit_light":
+        return lambda: verify_commit_light(CHAIN_ID, vset, block_id, 7, commit)
+    return lambda: verify_commit_light_trusting(CHAIN_ID, vset, commit, Fraction(1, 3))
+
+
+ENTRIES = ["verify_commit", "verify_commit_light", "verify_commit_light_trusting"]
+
+
+def _both_ways(monkeypatch, call):
+    """``call`` through the block-wise loop and through the lane-by-lane
+    one: what each raised and what each handed its verifier."""
+    said = record_verifier(monkeypatch)
+    new = outcome(call), list(said)
+    del said[:]
+    monkeypatch.setattr(validation, "_verify_commit_batch", lane_by_lane_commit_batch)
+    old = outcome(call), list(said)
+    return new, old
+
+
+@pytest.mark.parametrize("scenario", SCENARIOS)
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_the_blockwise_loop_hands_over_what_the_lane_by_lane_loop_did(monkeypatch, net, blocks, entry, scenario):
+    """Same lanes in the same order, the same jobs begun after the same
+    lanes, the same way out: error by type and message, whichever of two
+    faults comes first, ``verify()`` reached or not, ``close()`` last."""
+    _, vset, block_id, sound = net
+    commit = copy.deepcopy(sound)
+    for fault, *at in SCENARIOS[scenario]:
+        fault(commit, *at)
+    (raised, said), (raised_old, said_old) = _both_ways(monkeypatch, _entry(entry, vset, block_id, commit))
+    assert raised == raised_old
+    if said != said_old:
+        # only where the verifier refused a lane: it was handed that lane's block whole, the
+        # lanes behind the refused one too, and the commit went to single verification all the same
+        assert "malformed" in scenario and ("verify",) not in said + said_old
+        lanes, lanes_old = ([step for step in steps if step[0] == "lane"] for steps in (said, said_old))
+        assert lanes[: len(lanes_old)] == lanes_old and len(lanes[len(lanes_old):]) < JOB
+        assert len(lanes_old[-1][3]) == 63  # the last lane the old loop handed over is the refused one
+        assert [step for step in said if step[0] != "lane"] == [step for step in said_old if step[0] != "lane"]
+    assert said[-1] == ("close",)
+    if scenario == "sound":
+        lanes = [step for step in said if step[0] == "lane"]
+        # every entry but the absent two; 27 of 40 seats pass 2/3, 14 a third (the nil votes lie among them)
+        want = {"verify_commit": 38, "verify_commit_light": 27, "verify_commit_light_trusting": 14}
+        assert raised is None and len(lanes) == want[entry]
+        assert (("begun", JOB) in said) == (want[entry] > JOB)
+
+
+@pytest.mark.parametrize("of", list(SETS)[1:])
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_the_blockwise_loop_tallies_and_skips_as_the_lane_by_lane_loop_did(monkeypatch, net, blocks, entry, of):
+    """Against another set than the commit's own: seats of unknown
+    address are skipped on the by-address path (the other two refuse the
+    set's size before any lane), and two heavy seats end a light rule's
+    lanes at once."""
+    _, vset, block_id, commit = net
+    (raised, said), (raised_old, said_old) = _both_ways(monkeypatch, _entry(entry, SETS[of](vset), block_id, commit))
+    assert (raised, said) == (raised_old, said_old)
+    lanes = [step for step in said if step[0] == "lane"]
+    if of == "heavy_seats":  # 2,038 of power: seat 0 alone passes a third, seats 0 and 1 two thirds
+        want = {"verify_commit": 38, "verify_commit_light": 2, "verify_commit_light_trusting": 1}
+        assert raised is None and len(lanes) == want[entry]
+    if of == "a_third_of_it" and entry == "verify_commit_light_trusting":
+        assert raised is None and 0 < len(lanes) <= 12
+
+
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_not_enough_power_is_raised_before_any_verify(monkeypatch, net, blocks, entry):
+    privs, vset, block_id, _ = net
+    commit = make_commit(block_id, 7, 0, vset, privs, nil_votes=set(range(0, N_VALS, 2)))
+    if entry == "verify_commit_light_trusting":  # a third is there: ask for more
+        call = lambda: verify_commit_light_trusting(CHAIN_ID, vset, commit, Fraction(2, 3))
+    else:
+        call = _entry(entry, vset, block_id, commit)
+    (raised, said), old = _both_ways(monkeypatch, call)
+    assert (raised, said) == old
+    assert raised[0] == "NotEnoughVotingPowerError" and "got 200, needed more than 266" in raised[1]
+    assert ("verify",) not in said and said[-1] == ("close",)
+    assert ("begun", JOB) in said  # a job was on the device by then: collected by close()

@@ -195,8 +195,8 @@ def back_to_back(bv):
     the order a mixed call begins them, merged as ``verify()`` merges:
     the serial order, made of the phased call's own two steps."""
     order = sorted(bv._subs, key=lambda kt: (isinstance(bv._subs[kt], crypto_batch.HostLanesVerifier), kt))
-    results = {kt: bv._subs[kt].begin().finish()[1] for kt in order}
-    return [results[kt][i] for kt, i in bv._order]
+    results = {kt: iter(bv._subs[kt].begin().finish()[1]) for kt in order}
+    return [next(results[kt]) for kt in bv._order]
 
 
 @pytest.mark.parametrize("tampered", [None, "ed25519", "sr25519", "secp256k1"])
@@ -309,3 +309,96 @@ def test_a_commit_of_one_key_type_records_the_spans_it_always_did(verdict_cache,
     assert raised is None
     assert [e["name"] for e in in_order(events)] == PINNED[key_type]
     assert not any("phase" in e["args"] for e in events)
+
+
+# --- a mixed commit built a block at a time (ISSUE 45) ---------------------------
+
+import copy  # noqa: E402
+
+from tendermint_tpu.ops import ed25519_batch  # noqa: E402
+from tests.helpers import lane_by_lane_commit_batch, outcome, record_verifier  # noqa: E402
+
+JOB = 16  # lanes an engine job, stood in: 20 ed25519 and 18 sr25519 lanes each fill one
+
+
+@pytest.fixture
+def blocks(monkeypatch, mixed):
+    monkeypatch.setattr(ed25519_batch, "job_lanes", lambda: JOB)
+    monkeypatch.setattr(ed25519_batch, "_ENGINES_WITH_A_JOB_RUN", {"ed25519", "sr25519"})
+    crypto_batch.note_validator_set(mixed[1])
+    precompute.tables.gather([v.pub_key.bytes() for v in mixed[1].validators if v.pub_key.type == "ed25519"])
+
+
+def _entries(vset, block_id, commit):
+    return {
+        "verify_commit": lambda: validation.verify_commit(CHAIN_ID, vset, block_id, 14, commit),
+        "verify_commit_light": lambda: validation.verify_commit_light(CHAIN_ID, vset, block_id, 14, commit),
+        "verify_commit_light_trusting": lambda: validation.verify_commit_light_trusting(
+            CHAIN_ID, vset, commit, validation.Fraction(9, 10)
+        ),
+    }
+
+
+@pytest.fixture(scope="module")
+def mixed_commit(mixed):
+    privs, vset, block_id = mixed
+    return make_commit(block_id, 14, 0, vset, privs, absent={4}, nil_votes={11})
+
+
+@pytest.mark.parametrize("bad", [None, ("ed25519", 1), ("ed25519", -1), ("sr25519", 0), ("sr25519", -1),
+                                 ("secp256k1", 0), ("secp256k1", -1), "one_of_each"], ids=str)
+@pytest.mark.parametrize("entry", ["verify_commit", "verify_commit_light", "verify_commit_light_trusting"])
+def test_a_mixed_commit_in_blocks_is_the_lane_by_lane_commit(monkeypatch, mixed, mixed_commit, blocks, entry, bad):
+    """The three key types interleave by address: which sub-verifier
+    fills its job first, and after which lane, is the verifier's to
+    say. The block-wise loop hands over the same lanes, begins the
+    same jobs after the same lanes and names the same lane as the
+    lane-by-lane loop, whichever type the refused signature is of."""
+    _, vset, block_id = mixed
+    commit = copy.deepcopy(mixed_commit)
+    picks = [(kt, 1) for kt in ("ed25519", "sr25519", "secp256k1")] if bad == "one_of_each" else [bad] if bad else []
+    lanes = [[i for i in lanes_of(vset, kt) if i not in (4, 11)][at] for kt, at in picks]
+    for lane in lanes:
+        sig = bytearray(commit.signatures[lane].signature)
+        sig[40] ^= 0x01
+        commit.signatures[lane].signature = bytes(sig)
+    call = _entries(vset, block_id, commit)[entry]
+    said = record_verifier(monkeypatch)
+    raised, new = outcome(call), list(said)
+    del said[:]
+    monkeypatch.setattr(validation, "_verify_commit_batch", lane_by_lane_commit_batch)
+    assert (outcome(call), list(said)) == (raised, new)
+    handed = [step for step in new if step[0] == "lane"]
+    if entry == "verify_commit":
+        assert len(handed) == N_ED + N_SR + N_SECP - 1
+        assert [step for step in new if step[0] == "begun"] == [("begun", JOB), ("begun", JOB)]
+        if lanes:
+            assert raised == ("InvalidCommitError", "wrong signature (#%d): %s" % (
+                min(lanes), commit.signatures[min(lanes)].signature.hex().upper()))
+    if not lanes:
+        assert raised is None
+
+
+def test_room_says_which_sub_verifier_fills_its_job_first(monkeypatch, mixed, blocks):
+    """``room`` over the keys to come: the lane that gives a device
+    sub-verifier its sixteenth, however the types interleave, and more
+    than there are where no job fills; after ``add_many`` of that
+    block the verifier is ready, and not a lane sooner."""
+    privs, vset, block_id = mixed
+    keys = [v.pub_key for v in vset.validators]
+    types = [key.type for key in keys]
+    bv = crypto_batch.MultiBatchVerifier()
+    first = {kt: [i for i, t in enumerate(types) if t == kt][JOB - 1] + 1 for kt in ("ed25519", "sr25519")}
+    assert bv.room(keys) == min(first.values())
+    assert bv.room(keys[: min(first.values()) - 1]) > len(keys)  # one lane short: no job fills
+    assert bv.room([k for k in keys if k.type == "secp256k1"]) > len(keys)  # host lanes fill none
+    assert bv.room([]) > len(keys)
+    lanes = commit_lanes(vset, make_commit(block_id, 15, 0, vset, privs))
+    cut = bv.room(keys)
+    bv.add_many(*zip(*lanes[: cut - 1]))
+    assert not bv.ready and bv.room(keys[cut - 1:]) == 1
+    bv.add_many(*zip(*lanes[cut - 1: cut]))
+    assert bv.ready and bv.begin_ready() == JOB
+    # the other type's job fills next, counted from where the block ended
+    assert cut + bv.room(keys[cut:]) == max(first.values())
+    bv.close()
