@@ -270,10 +270,18 @@ class EvloopServer:
             self._dirty.add(t)
         self._wake()
 
-    def _gauge(self) -> None:
-        self.metrics.connections.labels(server=self.name).set(
-            len(self._conns)
-        )
+    def _count(self, t: Transport, present: bool) -> None:
+        """Put ``t`` into ``_conns`` or take it out, the gauge first: a
+        reader that has seen ``connection_count()`` change then reads a
+        gauge that has changed too (it may lead the count, never lag)."""
+        held = t._fd in self._conns
+        gauge = self.metrics.connections.labels(server=self.name)
+        if present:
+            gauge.set(len(self._conns) + (not held))
+            self._conns[t._fd] = t
+        else:
+            gauge.set(len(self._conns) - held)
+            self._conns.pop(t._fd, None)
 
     def _set_interest(self, t: Transport, want: int) -> None:
         if t._gone:
@@ -313,12 +321,11 @@ class EvloopServer:
             except (KeyError, ValueError, OSError):
                 pass  # fd may already be dead; drop proceeds either way
             t._registered = False
-        self._conns.pop(t._fd, None)
+        self._count(t, present=False)
         try:
             t.sock.close()
         except OSError:
             pass  # best-effort close of an already-broken socket
-        self._gauge()
         proto = t.proto
         if proto is not None:
             try:
@@ -334,8 +341,7 @@ class EvloopServer:
             except (KeyError, ValueError, OSError):
                 pass  # detach proceeds even if the fd vanished mid-poll
             t._registered = False
-        self._conns.pop(t._fd, None)
-        self._gauge()
+        self._count(t, present=False)
         evt.set()
 
     def _on_accept(self) -> None:
@@ -366,11 +372,10 @@ class EvloopServer:
                 except OSError:
                     pass  # factory failed; close is best-effort cleanup
                 continue
-            self._conns[t._fd] = t
+            self._count(t, present=True)
             self._sel.register(conn, selectors.EVENT_READ, t)
             t._registered = True
             t._interest = selectors.EVENT_READ
-            self._gauge()
 
     def _flush_writes(self, t: Transport) -> None:
         while True:

@@ -7,14 +7,18 @@ makeCommit / deterministicValidatorSet).
 from __future__ import annotations
 
 import collections
+import contextlib
 import fcntl
 import functools
 import hashlib
 import json
 import os
+import random
+import socket
 import subprocess
 import sys
 import tempfile
+import time
 from typing import List, Optional, Tuple
 
 from chipbench import spec
@@ -110,6 +114,100 @@ def make_commit(
     return commit
 
 
+@functools.lru_cache(maxsize=None)
+def warm_verify(lanes: int = 16) -> None:
+    """``lanes`` valid ed25519 lanes through ``verify_batch`` twice, once
+    a process: with keys met for the first time (the ``legacy`` kernel)
+    and with the keys pinned, as a validator set's are (``tables``). The
+    two kernels of their bucket are then compiled, or read from the
+    compile cache, before a test starts a clock that a first compile
+    must not run on: a node that joins a chain whose blocks are pruned
+    behind it, a caller with a timeout of its own."""
+    from tendermint_tpu.ops import ed25519_batch, precompute
+
+    privs = [Ed25519PrivKey.from_seed(bytes([i + 1]) * 32) for i in range(lanes)]
+    pks = [p.pub_key().bytes() for p in privs]
+    try:
+        for pinned in (False, True):
+            if pinned:
+                precompute.pin_pubkeys(set(pks))
+            # lanes of each pass's own: the result cache, where it is on, has met none of them
+            msgs = [b"warm %d, pinned: %d" % (i, pinned) for i in range(lanes)]
+            sigs = [p.sign(m) for p, m in zip(privs, msgs)]
+            assert ed25519_batch.verify_batch(pks, msgs, sigs) == [True] * lanes
+    finally:
+        precompute.reset()
+
+
+def _below_the_kernels_own_ports(n: int):
+    """Bases of ``n``-port blocks under the range the kernel takes
+    ``bind(0)`` and a ``connect()``'s own end from, drawn apart for
+    every process."""
+    try:
+        with open("/proc/sys/net/ipv4/ip_local_port_range") as f:
+            top = int(f.read().split()[0])
+    except (OSError, ValueError):
+        top = 32768
+    draw = random.Random(os.getpid() * 1_000_003 + time.monotonic_ns())
+    while True:
+        yield draw.randrange(10_000, top - n)
+
+
+def free_port_block(n: int, candidates=None) -> int:
+    """The first of ``n`` consecutive ports of 127.0.0.1, all of which
+    this process held bound at one moment, and released only then; a
+    block with a taken port is left for the next of ``candidates``.
+    Asking the kernel for one free port and assuming its neighbours
+    hands a node the port another worker's socket was given a moment
+    before: by default the blocks lie where the kernel gives none away
+    (:func:`_below_the_kernels_own_ports`)."""
+    for tried, base in enumerate(candidates or _below_the_kernels_own_ports(n)):
+        held = []
+        try:
+            for port in range(base, base + n):
+                sock = socket.socket()
+                held.append(sock)
+                sock.bind(("127.0.0.1", port))
+            return base
+        except OSError:
+            if tried >= 100:
+                raise
+        finally:
+            for sock in held:
+                sock.close()
+    raise OSError("no block of %d free ports among the candidates" % n)
+
+
+@contextlib.contextmanager
+def trace_turn(root: str, exclusive: bool, bound: float, every: float = 0.2):
+    """This process's turn at the one ``.chipbench_trace`` of the
+    checkout at ``root``: a lock file held alone (``exclusive``, a
+    traced run) or together with other untraced runs. Asked for without
+    blocking, again every ``every`` seconds, for ``bound`` seconds at
+    most: a test that cannot have its turn fails and says that it
+    waited, where a blocking ``flock`` would book another test's
+    minutes to it, or hang the run."""
+    path = os.path.join(
+        tempfile.gettempdir(),
+        "chipbench_trace_%s.lock" % hashlib.sha256(root.encode()).hexdigest()[:12],
+    )
+    mode = (fcntl.LOCK_EX if exclusive else fcntl.LOCK_SH) | fcntl.LOCK_NB
+    deadline = time.monotonic() + bound
+    with open(path, "w") as turn:
+        while True:
+            try:
+                fcntl.flock(turn, mode)
+                break
+            except BlockingIOError:
+                if time.monotonic() >= deadline:
+                    raise AssertionError(
+                        "waited %.0f s for the trace lock %s and did not get it: "
+                        "another rehearsal holds it" % (bound, path)
+                    ) from None
+                time.sleep(every)
+        yield
+
+
 def rehearse_cell(
     bench: str, cell: str, seed: int, trace: int, *extra, timeout: int = 300, prelude: str = ""
 ):
@@ -118,9 +216,10 @@ def rehearse_cell(
 
     Every run empties one directory of the checkout (``.chipbench_trace``)
     before and after its window, and a traced run profiles into it. The
-    rehearsals of several test files, which xdist gives to several
-    workers, therefore share a lock file: a traced run holds it alone,
-    untraced ones hold it together. ``prelude`` is Python the child
+    rehearsals, wherever xdist runs them, therefore take turns
+    (:func:`trace_turn`): a traced run has the directory alone, untraced
+    ones have it together, and none waits longer for it than the
+    child's own ``timeout``. ``prelude`` is Python the child
     runs before ``chipbench.run``'s ``main``: a fault planted in the
     program where ``chipbench/breaks.py`` knows none."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -132,16 +231,16 @@ def rehearse_cell(
     if prelude:
         code = prelude + "\nimport sys, chipbench.run\nsys.exit(chipbench.run.main(sys.argv[1:]))\n"
         cmd = [sys.executable, "-c", code, *args]
-    lock = os.path.join(
-        tempfile.gettempdir(),
-        "chipbench_trace_%s.lock" % hashlib.sha256(root.encode()).hexdigest()[:12],
-    )
-    with open(lock, "w") as turn:
-        fcntl.flock(turn, fcntl.LOCK_EX if trace else fcntl.LOCK_SH)
+    waited = time.monotonic()
+    with trace_turn(root, exclusive=bool(trace), bound=timeout):
+        began = time.monotonic()
         proc = subprocess.run(
             cmd, cwd=root, capture_output=True, text=True, timeout=timeout,
             env=dict(os.environ, JAX_PLATFORMS="cpu"),
         )
+    # captured with the test's output: what a layout of these cases is judged by
+    print("rehearsal %s trace=%d: waited %.1f s for the trace lock, ran %.1f s"
+          % (cell, trace, began - waited, time.monotonic() - began))
     assert proc.returncode == 0, proc.stderr[-2000:]
     return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stdout
 

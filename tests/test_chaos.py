@@ -29,7 +29,7 @@ from tendermint_tpu.ops.device_policy import (
     DeviceHealth,
 )
 from tendermint_tpu.types.validation import verify_commit
-from tests.helpers import CHAIN_ID, make_block_id, make_commit, make_validators
+from tests.helpers import CHAIN_ID, make_block_id, make_commit, make_validators, warm_verify
 
 pytestmark = [
     pytest.mark.chaos,
@@ -99,6 +99,8 @@ def test_scheduler_flood_survives_device_outage(monkeypatch):
     from tendermint_tpu.crypto.scheduler import VerifyScheduler
     from tendermint_tpu.ops.ed25519_batch import verify_batch
 
+    # the flood's kernel is compiled before the first caller starts its clock
+    warm_verify()
     h = DeviceHealth(retry_budget=1, cooldown_base=0.05, cooldown_max=0.1)
     monkeypatch.setattr(device_policy, "shared", h)
 
@@ -118,11 +120,17 @@ def test_scheduler_flood_survives_device_outage(monkeypatch):
         entries.append((pk, m, s, bool(i % 7)))
 
     results = [None] * n
-    stop_at = threading.Event()
+    timed_out = []
 
     def submitter(idx):
+        # ``sched.verify`` answers False for a caller that timed out (fail
+        # closed): here that is a caller that hung, not a verdict
         pk, m, s, _ = entries[idx]
-        results[idx] = sched.verify(pk, m, s, timeout=30.0)
+        entry = sched.submit(pk, m, s)
+        if entry.done.wait(timeout=30.0):
+            results[idx] = entry.ok
+        else:
+            timed_out.append(idx)
 
     threads = []
     try:
@@ -151,6 +159,7 @@ def test_scheduler_flood_survives_device_outage(monkeypatch):
         fault_injection.uninstall()
         sched.stop()
 
+    assert not timed_out, f"callers {sorted(timed_out)} timed out after 30 s (state={h.state})"
     for i, (_, _, _, genuine) in enumerate(entries):
         assert results[i] == genuine, (
             f"entry {i}: expected {genuine}, got {results[i]} "

@@ -1,7 +1,7 @@
-"""The call-path readers of ISSUE 34 on hand-made evidence, their
-metric files against ``BENCHMARK.json``, and one tiny twin rehearsed
-end to end on the CPU with every new metric in its result line (a
-rehearsal proves paths, never numbers).
+"""The call-path readers of ISSUE 34 on hand-made evidence and their
+metric files against ``BENCHMARK.json``. Their tiny twin is rehearsed
+end to end, with every new metric in its result line, in
+``tests/test_chipbench_rehearsals.py``.
 
 Times: spans in microseconds from the tracer's epoch, as the tracer
 exports them; calls in ``perf_counter_ns``, as ``run.py`` takes them;
@@ -20,7 +20,7 @@ import pytest
 from chipbench import selftest, spec
 from chipbench.readers import call_path, device_call_path, span_off_cpu_per_call
 from tendermint_tpu.libs import tracing
-from tests.helpers import REAL_BENCH, definitions, read, rehearse_cell, sound, span
+from tests.helpers import REAL_BENCH, definitions, read, span
 
 EPOCH = tracing.tracer.epoch_ns
 TRACE_AHEAD = 7_000_000_000  # trace clock less host clock, ns
@@ -291,19 +291,3 @@ def test_a_tiny_twin_reports_what_its_real_cell_reports(bench):
     got = definitions(path, cell)
     assert got == want, (sorted((want - got).elements()), sorted((got - want).elements()))
     assert {m["name"] for m in spec.Spec(path).metrics_for("per_layer", cell)} <= names
-
-
-def test_tiny_twin_reports_every_new_metric():
-    value = sound(*rehearse_cell(BENCH, "tiny-hub-warm", 2**31 + 34, 1), (), BENCH, "tiny-hub-warm")
-    got = {stem: value(stem, moves="commit_p50_ms") for stem in BASES}
-    # what holds whatever the machine: a phase lies inside its span, the device cannot start what
-    # has not been dispatched, and the parts of a call are each part of it
-    assert got["h2d_put_ms"] + got["launch_ms"] <= got["dispatch_ms"]
-    for stem in ("launch_lag_ms", "readback_lag_ms", "device_chain_gap_ms", "pre_dispatch_ms", "chain_ms",
-                 "post_collect_ms", "d2h_ms"):
-        assert got[stem] >= 0.0, (stem, got[stem])
-    # a difference of two clocks less the waits by design: about 0 in a sound call, either side of
-    # it by what the thread's clock rounds to and by the CPU the thread used inside a wait
-    wall = got["pre_dispatch_ms"] + got["chain_ms"] + got["post_collect_ms"]
-    assert abs(got["off_cpu_ms"]) < wall
-    assert got["engine_proc_cpu_ms"] > 0.0

@@ -2,9 +2,9 @@
 the plain skipping reference against the program's own hashes and
 sign-bytes and on seeded chains, the program's client against it (walk,
 store contents, the four faults), the Light and Scheduler metrics'
-files reduced on hand-made spans, ``BENCHMARK.json`` against its files,
-and the cell's tiny twin rehearsed end to end on the CPU (a rehearsal
-proves paths, never numbers).
+files reduced on hand-made spans and ``BENCHMARK.json`` against its
+files. The cell's tiny twin is rehearsed end to end in
+``tests/test_chipbench_rehearsals.py``.
 """
 
 from __future__ import annotations
@@ -17,7 +17,7 @@ import pytest
 from chipbench import reference_lightclient as plain
 from chipbench import selftest, spec, workload
 from chipbench.generators import lightclient
-from tests.helpers import REAL_BENCH, Evidence, over_limit, read, rehearse_cell, sound, span
+from tests.helpers import REAL_BENCH, Evidence, read, span
 
 BENCH = os.path.join(spec.HERE, "testdata", "tiny-light-benchmark.json")
 CELL, SEED = "tiny-light-chain", 2**31 + 30
@@ -223,44 +223,3 @@ def test_light_metrics_add_up_on_nested_spans():
                  "store_save_ms", "store_load_ms", "block_checks_ms", "detector_ms"):
         assert read(ev, *REAL, stem) is None, stem
     assert read(ev, *REAL, "table_build_ms") == 0.0
-
-
-# --- the program against the reference, end to end ---------------------------------------
-
-
-COMPARED = ("verdict_cache_hits_in_window", "compilations_in_window", "timed_calls_refused",
-            "calls_with_a_wrong_walk", "fault_calls_with_a_wrong_verdict",
-            "lanes_where_reference_disagrees")
-
-
-def test_tiny_twin_of_light1k_chain_rehearses_on_the_cpu():
-    """The traced rehearsal is the comparison the chip run makes at full
-    size: every timed call's block and store against the reference's
-    walk, the four faults, the sampled lanes; and lanes dispatched =
-    the reference's distinct checked signatures (``failed`` 0)."""
-    value = sound(*rehearse_cell(BENCH, CELL, SEED, 1, timeout=420), COMPARED, BENCH, CELL)
-    # pivots' keys are met twice at most and get no table, and neither does the anchor of the client
-    # restarted where the cycle starts over: the window builds none and no lane finds one
-    assert value("resident_hit_share") == 0.0 and value("table_build_ms") == 0.0
-    assert value("light_unnamed_ms") < value("light_host_ms")
-
-
-@pytest.mark.parametrize(
-    "brk,over",
-    [
-        # one lane's verdict inverted where the engine returns it: a
-        # timed call is refused, the walks after it start further back
-        # (and may meet a kernel shape the warm-up did not)
-        ("flip_verdict", ["compilations_in_window", "timed_calls_refused", "calls_with_a_wrong_walk",
-                          "fault_calls_with_a_wrong_verdict", "lanes_where_reference_disagrees"]),
-        # the engine's s < L check off: the trusting lane's s + L verifies
-        ("no_canonical_s", ["fault_calls_with_a_wrong_verdict", "lanes_where_reference_disagrees"]),
-    ],
-)
-def test_tiny_twin_broken_on_purpose_comes_out_not_correct(brk, over):
-    """The controls (``breaks.py``) have to show in the cell's own comparisons, not in the harness's two."""
-    out, said = rehearse_cell(BENCH, CELL, SEED, 0, "--break", brk, timeout=420)
-    assert out["correct"] is False
-    got = over_limit(said)
-    assert set(got) <= set(over) and got, got
-    assert set(got) & {"fault_calls_with_a_wrong_verdict", "timed_calls_refused"}

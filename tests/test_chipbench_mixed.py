@@ -1,9 +1,9 @@
 """The benchmark's part of the ``mixed10k`` deployment, without a chip:
 the plain reference of the three key types on published vectors and
 against the program, the sr25519 lane's count of operations, the cell's
-files, the new readers on hand-made spans, the generator's committee,
-and the cell's tiny twin rehearsed end to end on the CPU, sound and
-with a fault planted (a rehearsal proves paths, never numbers)."""
+files, the new readers on hand-made spans and the generator's committee.
+The cell's tiny twin is rehearsed end to end, sound and with a fault
+planted, in ``tests/test_chipbench_rehearsals.py``."""
 
 from __future__ import annotations
 
@@ -18,7 +18,7 @@ import pytest
 from chipbench import opcount, opcount_sr25519, reference_mixed, selftest, spec
 from chipbench.run import Context, Evidence
 from tests.helpers import (
-    DEFINITION_KEYS, REAL_BENCH, definitions, over_limit, read, rehearse_cell, sound, span,
+    DEFINITION_KEYS, REAL_BENCH, definitions, read, span,
 )
 
 BENCH = os.path.join(spec.HERE, "testdata", "tiny-mixed-benchmark.json")
@@ -368,70 +368,3 @@ def test_a_program_without_the_batch_merlin_stops_at_once(monkeypatch):
     ctx = Context(cell, bench.config(cell["config"]), bench.traffic(cell["traffic"]), 1, print)
     with pytest.raises(SystemExit, match="cannot run the mixed committee"):
         commits_mixed.build(ctx)
-
-
-# --- the tiny twin, end to end ------------------------------------------------------------
-
-COMPARED = (
-    "verdict_cache_hits_in_window", "compilations_in_window", "timed_commits_refused",
-    "tampered_commits_not_blamed_on_their_lane", "lanes_where_reference_disagrees",
-)
-
-
-def test_tiny_twin_of_mixed10k_rehearses_on_the_cpu_traced():
-    out, said = rehearse_cell(BENCH, CELL, SEED, 1)
-    value = sound(out, said, COMPARED, BENCH, CELL)  # every name printed
-    assert value("resident_hit_share") == 50.0 and value("sr25519_lane_share") == 50.0
-    assert value("mesh_lane_share") == 0.0 and value("device_hash_share") == 0.0
-    assert value("host_lane_share") == pytest.approx(100.0 * 4 / 36)
-    assert value("pad_lane_share") == 75.0  # two 64-lane kernels for 32 lanes
-    assert 0 < value("merlin_ms") < value("prep_ms", **SR_PREP) < value("prep_ms", **ALL_PREP)
-    assert 0 < value("kernel_ms", **SR_PROGRAMS) < value("kernel_ms", **ALL_PROGRAMS)
-    assert 0 < value("sr25519_roofline") < 100
-    assert value("host_lanes_ms") > 0 and value("device_chain_gap_ms") > 0
-    assert "engine sr25519 kernel verify_sr lanes 64" in said
-
-
-def test_tiny_twin_of_mixed10k_rehearses_on_the_cpu_untraced():
-    out, said = rehearse_cell(BENCH, CELL, 4_000_000_007, 0)
-    sound(out, said, COMPARED)
-    assert set(out["metrics"]) == {"commit_p50_ms", "setup_s"}
-
-
-# planted after the warm-up calls, which have to stay sound
-PLANT = """
-import chipbench.generators.commits_mixed as g
-warm = g.CommitsMixed.warm
-def warm_then_break(self):
-    warm(self)
-%s
-g.CommitsMixed.warm = warm_then_break
-"""
-FLIPPED_CHALLENGE = PLANT % """
-    import tendermint_tpu.crypto.hashing as hashing
-    sound = hashing.sr25519_challenges_mod_l
-    def flipped(pubs, rs, msgs):
-        out = sound(pubs, rs, msgs)
-        out[len(out) // 2, 7] ^= 0x10
-        return out
-    hashing.sr25519_challenges_mod_l = flipped
-"""
-SECP_FORCED_TRUE = PLANT % """
-    from tendermint_tpu.crypto.keys import Secp256k1PubKey
-    Secp256k1PubKey.verify_signature = lambda self, msg, sig: True
-"""
-
-
-@pytest.mark.parametrize(
-    "prelude,over",
-    [
-        # one sr25519 lane's challenge off by a bit: that lane is refused
-        (FLIPPED_CHALLENGE, ["timed_commits_refused", "lanes_where_reference_disagrees"]),
-        # the host lanes answer true whatever they are asked
-        (SECP_FORCED_TRUE, ["tampered_commits_not_blamed_on_their_lane"]),
-    ],
-    ids=["flipped_challenge_byte", "secp256k1_verdict_forced_true"],
-)
-def test_tiny_twin_with_a_planted_fault_comes_out_not_correct(prelude, over):
-    out, said = rehearse_cell(BENCH, CELL, SEED, 0, prelude=prelude)
-    assert out["correct"] is False and set(over) <= set(over_limit(said)), said[-1500:]

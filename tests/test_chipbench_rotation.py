@@ -1,9 +1,9 @@
 """The benchmark's part of the ``sync500rot`` deployment, without a chip:
 the plain rotation reference on hand-made sets, the cell's files, the
 generator's windows against the syncer's own on a real chain, the
-old-key-in-the-new-seat fault, the cell's metrics reduced on hand-made
-spans, and the cell's tiny twin rehearsed end to end on the CPU (a
-rehearsal proves paths, never numbers).
+old-key-in-the-new-seat fault and the cell's metrics reduced on
+hand-made spans. The cell's tiny twin is rehearsed end to end in
+``tests/test_chipbench_rehearsals.py``.
 """
 
 from __future__ import annotations
@@ -15,8 +15,7 @@ import pytest
 
 from chipbench import reference, reference_light, reference_rotation, selftest, spec, workload
 from chipbench.run import Context
-from tests.helpers import REAL_BENCH, Evidence, over_limit, read, rehearse_cell, sound, span
-from tests.test_chipbench_sync import BROKEN
+from tests.helpers import REAL_BENCH, Evidence, read, span
 
 BENCH = os.path.join(spec.HERE, "testdata", "tiny-rotation-benchmark.json")
 CELL = "tiny-sync-rotation"
@@ -272,25 +271,3 @@ def test_rotation_metrics_add_up_on_nested_spans():
         s["args"].pop("tables_dropped", None)
     assert read(ev, *REAL, "tables_dropped") is None
     assert read(ev, *REAL, "resident_drop_ms") == 0.0
-
-
-# --- the tiny twin, end to end ----------------------------------------------------------
-
-
-def test_tiny_twin_of_sync500_rotation_rehearses_on_the_cpu():
-    compared = ("verdict_cache_hits_in_window", "compilations_in_window", "timed_blocks_refused",
-                "sets_registered_in_window", "windows_with_a_wrong_block_verdict", "lanes_where_reference_disagrees")
-    value = sound(*rehearse_cell(BENCH, CELL, SEED, 1), compared, BENCH, CELL)
-    # every call shows the mechanism: a table dropped with the retired set, the store dropped and sent
-    # again, a newcomer's table built, the youngest keys' lanes on the legacy kernel
-    assert value("tables_dropped") == 1.0 and 0 < value("legacy_lanes") <= 8 and 0 < value("resident_hit_share") < 100
-    for stem in ("valset_hash_ms", "table_build_ms", "resident_upload_ms", "resident_drop_ms"):
-        assert value(stem) > 0, stem
-    assert value("valset_hash_ms") < value("note_set_ms")
-
-
-@pytest.mark.parametrize("brk,over", BROKEN)  # ``sync500``'s controls, over in the same comparisons
-def test_tiny_twin_broken_on_purpose_comes_out_not_correct(brk, over):
-    """The controls (``breaks.py``) have to show in the cell's own comparisons, not in the harness's two."""
-    out, said = rehearse_cell(BENCH, CELL, SEED, 0, "--break", brk)
-    assert out["correct"] is False and over_limit(said) == over

@@ -1,18 +1,16 @@
 """The benchmark's part of the ``sync500`` deployment, without a chip:
 the plain light reference on hand-made blocks, the Pipeline metrics'
-files reduced on hand-made spans, ``BENCHMARK.json`` against its files,
-and the cell's tiny twin rehearsed end to end on the CPU (a rehearsal
-proves paths, never numbers).
+files reduced on hand-made spans and ``BENCHMARK.json`` against its
+files. The cell's tiny twin is rehearsed end to end in
+``tests/test_chipbench_rehearsals.py``.
 """
 
 from __future__ import annotations
 
-import os
-
 import pytest
 
 from chipbench import reference_light, selftest, spec, workload
-from tests.helpers import REAL_BENCH, Evidence, over_limit, read, rehearse_cell, sound, span
+from tests.helpers import REAL_BENCH, Evidence, read, span
 
 ABSENT, COMMIT, NIL = 1, 2, 3
 REAL = (REAL_BENCH, "sync500-catchup")  # the cell a hand-made reading is named through
@@ -113,28 +111,3 @@ def test_pipeline_metrics_add_up_on_nested_spans():
     ev = Evidence([span("verify_batch", 510, 390)])
     for stem in ("pipeline_host_ms", "sign_bytes_ms", "pipeline_unnamed_ms"):
         assert read(ev, *REAL, stem) is None
-
-
-BENCH, CELL, SEED = os.path.join(spec.HERE, "testdata", "tiny-sync-benchmark.json"), "tiny-sync-catchup", 2**31 + 26
-
-
-def test_tiny_twin_of_sync500_catchup_rehearses_on_the_cpu():
-    compared = ("verdict_cache_hits_in_window", "compilations_in_window", "timed_blocks_refused",
-                "windows_with_a_wrong_block_verdict", "lanes_where_reference_disagrees")
-    value = sound(*rehearse_cell(BENCH, CELL, SEED, 1), compared, BENCH, CELL)
-    assert value("resident_hit_share") == 100.0
-
-
-BROKEN = [  # ``tests/test_chipbench_rotation.py`` holds its cell, this deployment with a set that changes, to the same
-    # one lane's verdict inverted where the engine returns it
-    ("flip_verdict", ["timed_blocks_refused", "windows_with_a_wrong_block_verdict", "lanes_where_reference_disagrees"]),
-    # the engine's s < L check off: the included s + L lane verifies
-    ("no_canonical_s", ["windows_with_a_wrong_block_verdict", "lanes_where_reference_disagrees"]),
-]
-
-
-@pytest.mark.parametrize("brk,over", BROKEN)
-def test_tiny_twin_broken_on_purpose_comes_out_not_correct(brk, over):
-    """The controls (``breaks.py``) have to show in the cell's own comparisons, not in the harness's two."""
-    out, said = rehearse_cell(BENCH, CELL, SEED, 0, "--break", brk)
-    assert out["correct"] is False and over_limit(said) == over

@@ -8,7 +8,6 @@ watch every node commit blocks over real TCP with filedb persistence.
 import json
 import os
 import signal
-import socket
 import subprocess
 import sys
 import time
@@ -18,18 +17,9 @@ import pytest
 
 from tendermint_tpu.config import Config
 from tendermint_tpu.cli import main as cli_main
+from tests.helpers import free_port_block
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _free_port_block(n: int) -> int:
-    """Find a base port with n*2 consecutive free ports (best effort)."""
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    base = s.getsockname()[1]
-    s.close()
-    # steer clear of the ephemeral range edge
-    return base if base + 2 * n < 65000 else base - 4 * n
 
 
 def _rpc_height(port: int) -> int:
@@ -142,31 +132,51 @@ def _fast_genesis_overwrite(home: str) -> None:
 
 class TestNodeLifecycle:
     def _spawn(self, home: str):
-        return subprocess.Popen(
-            [sys.executable, "-m", "tendermint_tpu", "--home", home, "start"],
-            cwd=REPO,
-            stdout=subprocess.PIPE,
-            stderr=subprocess.STDOUT,
-        )
+        """``start`` in a child that writes to ``<home>/start.log``: a
+        pipe that nothing reads holds 64 KiB, and a node that has said
+        more than that stops inside ``write()`` for good."""
+        with open(os.path.join(home, "start.log"), "wb") as log:
+            return subprocess.Popen(
+                [sys.executable, "-m", "tendermint_tpu", "--home", home, "start"],
+                cwd=REPO,
+                stdout=log,
+                stderr=subprocess.STDOUT,
+            )
+
+    @staticmethod
+    def _said(homes) -> str:
+        """The end of what each node wrote, for a failure's message."""
+        out = []
+        for home in homes:
+            with open(os.path.join(home, "start.log"), "rb") as log:
+                out.append("--- %s\n%s" % (home, log.read()[-1500:].decode(errors="replace")))
+        return "\n".join(out)
+
+    def _wait_heights(self, ports, target: int, timeout: float) -> list:
+        """The height each RPC port reports, polled until all are at
+        ``target`` or one deadline for all of them has passed (-1: never
+        answered)."""
+        deadline = time.monotonic() + timeout
+        heights = [-1] * len(ports)
+        while True:
+            for at, port in enumerate(ports):
+                if heights[at] < target:
+                    try:
+                        heights[at] = _rpc_height(port)
+                    except Exception:
+                        pass
+            if min(heights) >= target or time.monotonic() >= deadline:
+                return heights
+            time.sleep(0.5)
 
     def _wait_height(self, port: int, target: int, timeout: float) -> int:
-        deadline = time.monotonic() + timeout
-        height = -1
-        while time.monotonic() < deadline:
-            try:
-                height = _rpc_height(port)
-                if height >= target:
-                    return height
-            except Exception:
-                pass
-            time.sleep(0.5)
-        return height
+        return self._wait_heights([port], target, timeout)[0]
 
     def test_single_node_commits_and_persists(self, tmp_path):
         home = str(tmp_path / "n0")
         _run(["--home", home, "init", "--chain-id", "cli-one"])
         _fast_genesis_overwrite(home)
-        port = _free_port_block(1)
+        port = free_port_block(2)
         cfg = Config.load(home)
         cfg.p2p.laddr = f"127.0.0.1:{port}"
         cfg.rpc.laddr = f"127.0.0.1:{port + 1}"
@@ -196,7 +206,7 @@ class TestNodeLifecycle:
         """VERDICT round-2 item 10 'Done =': a 4-process localhost testnet
         starts from generated configs and commits blocks."""
         out_dir = str(tmp_path / "tn")
-        base = _free_port_block(4)
+        base = free_port_block(8)
         assert (
             _run(
                 [
@@ -218,11 +228,12 @@ class TestNodeLifecycle:
             _fast_genesis_overwrite(home)
         procs = [self._spawn(h) for h in homes]
         try:
-            heights = [
-                self._wait_height(base + 2 * i + 1, 2, timeout=90)
-                for i in range(4)
-            ]
-            assert all(h >= 2 for h in heights), f"heights: {heights}"
+            heights = self._wait_heights(
+                [base + 2 * i + 1 for i in range(4)], 2, timeout=90
+            )
+            assert all(h >= 2 for h in heights), (
+                f"heights after 90 s: {heights}\n{self._said(homes)}"
+            )
         finally:
             for p in procs:
                 p.send_signal(signal.SIGTERM)
@@ -238,7 +249,7 @@ class TestRollback:
         home = str(tmp_path / "n0")
         _run(["--home", home, "init", "--chain-id", "rb"])
         _fast_genesis_overwrite(home)
-        port = _free_port_block(1)
+        port = free_port_block(2)
         cfg = Config.load(home)
         cfg.p2p.laddr = f"127.0.0.1:{port}"
         cfg.rpc.laddr = f"127.0.0.1:{port + 1}"
@@ -355,7 +366,7 @@ class TestDebugTools:
         home = str(tmp_path / "h")
         _run(["--home", home, "init", "--chain-id", "ins"])
         _fast_genesis_overwrite(home)
-        port = _free_port_block(1)
+        port = free_port_block(2)
         cfg = Config.load(home)
         cfg.p2p.laddr = f"127.0.0.1:{port}"
         cfg.rpc.laddr = f"127.0.0.1:{port + 1}"
@@ -378,7 +389,7 @@ class TestDebugTools:
             proc.send_signal(signal.SIGTERM)
             proc.wait(timeout=15)
         # node stopped: serve the stores read-only
-        iport = _free_port_block(1)
+        iport = free_port_block(2)
         srv = subprocess.Popen(
             [sys.executable, "-m", "tendermint_tpu", "--home", home,
              "inspect", "--serve", f"127.0.0.1:{iport}"],
@@ -416,7 +427,7 @@ class TestDebugTools:
         home = str(tmp_path / "h")
         _run(["--home", home, "init", "--chain-id", "reidx"])
         _fast_genesis_overwrite(home)
-        port = _free_port_block(1)
+        port = free_port_block(2)
         cfg = Config.load(home)
         cfg.p2p.laddr = f"127.0.0.1:{port}"
         cfg.rpc.laddr = f"127.0.0.1:{port + 1}"

@@ -7,7 +7,6 @@ when the device starts. The job is the engine's accessor's
 at 16 so that the CPU's 64-lane kernels serve."""
 
 import gc
-import os
 import re
 import time
 
@@ -33,8 +32,6 @@ from tests.helpers import (
     outcome,
     read,
     record_verifier,
-    rehearse_cell,
-    sound,
     traced,
 )
 
@@ -718,36 +715,3 @@ def test_the_spans_of_a_two_job_commit_keep_the_engine_out_of_the_entrys_names(m
     # the first dispatch is the first block's: the chain holds the building of the other two
     first = by("dispatch_chunk")[0]
     assert first["ts"] < loops[1]["ts"] and parts[1] > 2 * nap * 1e3
-
-
-# --- the benchmark's own harness on a call made of blocks -----------------------
-
-PATH_BENCH = os.path.join(os.path.dirname(REAL_BENCH), "chipbench", "testdata", "tiny-path-benchmark.json")
-# the child's job is 16 lanes, so that the tiny twin's 24-lane commits are a block begun early and
-# eight lanes at verify(); it leaves with 3 if no block was begun early
-ENGAGED = """
-import atexit, os
-from tendermint_tpu.crypto import batch
-from tendermint_tpu.ops import ed25519_batch
-ed25519_batch.job_lanes = lambda: 16
-begun_early, begin_on_device = [], batch.begin_on_device
-def counting(key_type, lanes, begin_batch, early=False):
-    begun_early.extend([lanes] * early)
-    return begin_on_device(key_type, lanes, begin_batch, early)
-batch.begin_on_device = counting
-atexit.register(lambda: set(begun_early) == {16} or os._exit(3))
-"""
-
-
-def test_the_benchmarks_harness_reads_a_call_made_of_blocks():
-    """``chipbench.run``, traced, on the call-path twin with the early
-    begin engaged in every timed call: ``correct``, nothing ``failed``
-    (the lanes dispatched and collected are the lanes sent), every
-    per-layer metric of the cell a number, and the call's three parts
-    each part of it."""
-    value = sound(
-        *rehearse_cell(PATH_BENCH, "tiny-hub-warm", 2**31 + 44, 1, prelude=ENGAGED), (), PATH_BENCH, "tiny-hub-warm"
-    )
-    parts = [value(stem, moves="commit_p50_ms") for stem in ("pre_dispatch_ms", "chain_ms", "post_collect_ms")]
-    assert all(p > 0 for p in parts)
-    assert value("device_chain_gap_ms", moves="commit_p50_ms") >= 0

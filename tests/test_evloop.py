@@ -110,6 +110,38 @@ class TestBackpressure:
         finally:
             stop_evloop(srv, lsock)
 
+    def test_the_gauge_never_lags_the_count_a_reader_sees(self, monkeypatch):
+        """Open a connection, wait for ``connection_count()`` to say so,
+        read the gauge at once; close it, the same; 200 times. The gauge
+        is made as slow as a loaded host makes it (2 ms a ``set``), which
+        is the window a count changed before its gauge leaves open."""
+        from tendermint_tpu.libs import metrics
+
+        prompt_set = metrics._BoundGauge.set
+
+        def slow_set(self, v):
+            time.sleep(0.002)
+            prompt_set(self, v)
+
+        monkeypatch.setattr(metrics._BoundGauge, "set", slow_set)
+        reg = Registry()
+        srv, lsock = start_evloop(BlastProto, name="prompt", metrics=EvloopMetrics(reg))
+
+        def gauge_once_count_is(n):
+            deadline = time.monotonic() + 5
+            while srv.connection_count() != n:
+                assert time.monotonic() < deadline, "the count never reached %d" % n
+            return reg.expose()
+
+        try:
+            for turn in range(200):
+                conn = socket.create_connection(lsock.getsockname())
+                assert 'connections{server="prompt"} 1' in gauge_once_count_is(1), turn
+                conn.close()
+                assert 'connections{server="prompt"} 0' in gauge_once_count_is(0), turn
+        finally:
+            stop_evloop(srv, lsock)
+
 
 class TestMidFrameDisconnect:
     def test_grpc_survives_torn_frames(self):

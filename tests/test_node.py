@@ -19,6 +19,7 @@ from tendermint_tpu.p2p.transport import MemoryNetwork
 from tendermint_tpu.privval import FilePV
 from tendermint_tpu.types.genesis import GenesisDoc, GenesisValidator
 from tendermint_tpu.types.params import ConsensusParams, TimeoutParams
+from tests.helpers import warm_verify
 
 CHAIN = "node-chain"
 BASE_NS = 1_700_000_000_000_000_000
@@ -61,13 +62,18 @@ def make_node(tmp_path, name, privs, index=None, net=None, blocksync=True,
     return node, app
 
 
-def wait_for(fn, timeout=60.0, interval=0.05):
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
+def wait_for(fn, timeout=60.0, interval=0.05, deadline=None):
+    """Poll ``fn`` until it holds: for ``timeout`` seconds, or up to a
+    ``deadline`` (``time.monotonic()``) that several waits of one test
+    share, so that the test as a whole waits no longer than one of them."""
+    if deadline is None:
+        deadline = time.monotonic() + timeout
+    while True:
         if fn():
             return True
+        if time.monotonic() >= deadline:
+            return False
         time.sleep(interval)
-    return False
 
 
 @pytest.fixture()
@@ -137,8 +143,12 @@ class TestMemoryNetworkCluster:
                     f"{nodes[0].node_key.node_id}@v0"
                 ]
             node.start()
+        warm_verify()  # the observer's first window of blocks compiles nothing on the test's clock
+        deadline = time.monotonic() + 90  # for the whole of the test
         try:
-            assert wait_for(lambda: all(n.height >= 3 for n in nodes), timeout=90)
+            assert wait_for(lambda: all(n.height >= 3 for n in nodes), deadline=deadline), (
+                f"validators at {[n.height for n in nodes]} after 90 s"
+            )
             # A non-validator observer joins late and blocksyncs.
             observer, obs_app = make_node(
                 tmp_path, "observer", four_privs, index=None, net=net,
@@ -146,8 +156,9 @@ class TestMemoryNetworkCluster:
             )
             observer.start()
             target = max(n.height for n in nodes)
-            assert wait_for(lambda: observer.height >= target, timeout=90), (
-                f"observer at {observer.height}, target {target}"
+            assert wait_for(lambda: observer.height >= target, deadline=deadline), (
+                f"observer at {observer.height}, target {target}, "
+                f"validators at {[n.height for n in nodes]}"
             )
             observer.stop()
         finally:

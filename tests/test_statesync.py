@@ -21,6 +21,7 @@ from tendermint_tpu.privval import FilePV
 from tendermint_tpu.statesync import StateSyncConfig
 from tendermint_tpu.statesync.syncer import StateSyncer
 
+from tests.helpers import warm_verify
 from tests.test_node import fast_genesis, wait_for
 
 SNAPSHOT_INTERVAL = 4
@@ -128,6 +129,12 @@ class TestKVStoreSnapshots:
 
 class TestStateSyncJoin:
     def test_fresh_node_joins_via_snapshot(self, one_priv):
+        # A makes eight blocks a second and keeps the last hundred. B's first
+        # window of blocks must not be what compiles the verify kernels: those
+        # seconds (a minute, on a cold compile cache) are enough for A to prune
+        # the blocks B is asking for, and B then never moves again.
+        warm_verify()
+        deadline = time.monotonic() + 60  # for the whole of the test
         net = MemoryNetwork()
         node_a, app_a = _mk_node(
             "nodeA", one_priv, net, index=0, snapshot_interval=SNAPSHOT_INTERVAL
@@ -137,7 +144,7 @@ class TestStateSyncJoin:
         try:
             # A needs a snapshot at h with headers to h+2 available.
             assert wait_for(
-                lambda: node_a.height >= SNAPSHOT_INTERVAL * 2 + 3, timeout=60
+                lambda: node_a.height >= SNAPSHOT_INTERVAL * 2 + 3, deadline=deadline
             ), f"A stuck at {node_a.height}"
             trust_hash = node_a.block_store.load_block_meta(1).header.hash()
 
@@ -160,7 +167,7 @@ class TestStateSyncJoin:
             assert wait_for(
                 lambda: node_b.statesyncer is not None
                 and node_b.sm_state.last_block_height >= SNAPSHOT_INTERVAL,
-                timeout=60,
+                deadline=deadline,
             ), "state sync never completed"
             snap_height = node_b.sm_state.last_block_height
             assert snap_height % SNAPSHOT_INTERVAL == 0
@@ -183,8 +190,9 @@ class TestStateSyncJoin:
             # B block-syncs the gap and follows consensus past A's tip
             # at join time.
             target = node_a.height + 3
-            assert wait_for(lambda: node_b.height >= target, timeout=60), (
-                f"B stuck at {node_b.height}, target {target}"
+            assert wait_for(lambda: node_b.height >= target, deadline=deadline), (
+                f"B stuck at {node_b.height}, target {target}, A at {node_a.height} "
+                f"with its oldest block {node_a.block_store.base()}"
             )
             assert node_b.block_store.load_block(snap_height + 1) is not None
         finally:

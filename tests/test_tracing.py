@@ -24,6 +24,22 @@ from tendermint_tpu.libs.metrics import (
 from tests.helpers import CHAIN_ID, make_block_id, make_commit, make_validators
 
 
+@pytest.fixture(autouse=True, scope="module")
+def sinks_of_a_fresh_process():
+    """A node assembled earlier in this worker (tests/test_node.py, any
+    in-process ``Node``) leaves the kernel profiler in the tracer's
+    profile slot, and a daemon its flight recorder; with either set, no
+    span is the NOP span, which this file's off-mode tests assert. The
+    file is held to a process that has assembled neither, whatever ran
+    before it, and the slots get back what they held."""
+    profile, flight = tracing.tracer._profile, tracing.tracer._flight
+    tracing.tracer.set_profile_sink(None)
+    tracing.tracer.set_flight_sink(None)
+    yield
+    tracing.tracer.set_profile_sink(profile)
+    tracing.tracer.set_flight_sink(flight)
+
+
 @pytest.fixture
 def ring(monkeypatch):
     """Global tracer in ring mode, restored to off/empty afterwards."""
@@ -138,7 +154,9 @@ def test_concurrent_threads_yield_well_nested_untorn_output(ring):
         th.join(timeout=30)
     assert not errors
     out = ring.export()
-    events = _complete_events(out)
+    # the threads' own spans: a collection of 1 ms or more while they ran is a
+    # ``gc_pause`` span of the tracer's own in the same ring
+    events = [e for e in _complete_events(out) if e["name"].startswith(("outer-", "inner-"))]
     assert len(events) == n_threads * n_iters * 2
     # untorn: the JSON form parses back identical
     assert json.loads(json.dumps(out)) == out
@@ -714,20 +732,20 @@ def test_cpu_us_of_a_sleeping_span_is_far_under_its_dur(ring):
     assert "proc_cpu_us" not in ev["args"]  # only a span that asked
 
 
-def test_cpu_us_of_a_busy_span_is_about_its_dur(ring):
+def test_cpu_us_of_a_busy_span_is_the_cpu_time_its_thread_used(ring):
+    """A span that spins until its thread has used 50 ms of CPU, by the
+    thread's own clock: ``cpu_us`` is those 50 ms (to the clock's 10-ms
+    tick, twice) and no more than ``dur``, however long the thread was
+    kept off its core meanwhile. What share of ``dur`` that is depends
+    on the host, not on the tracer."""
     import time
 
-    # the best of three: a busy thread can still lose its core for a while
-    shares = []
-    for _ in range(3):
-        with tracing.span("busy"):
-            until = time.perf_counter() + 0.05
-            while time.perf_counter() < until:
-                pass
-        (ev,) = _complete_events(ring.export(clear=True))
-        assert ev["args"]["cpu_us"] <= ev["dur"]  # read inside the wall interval
-        shares.append(ev["args"]["cpu_us"] / ev["dur"])
-    assert max(shares) > 0.8
+    with tracing.span("busy"):
+        until = time.thread_time() + 0.05
+        while time.thread_time() < until:
+            pass
+    (ev,) = _complete_events(ring.export(clear=True))
+    assert 30_000 <= ev["args"]["cpu_us"] <= ev["dur"]  # both read inside the wall interval
 
 
 def test_only_a_threads_outermost_span_reads_the_cpu_clock(ring):
