@@ -10,7 +10,7 @@ driver's runs never pass ``--break``.
   step a faster engine would be tempted to drop; the ``s + L`` lane then
   verifies (guarantee 1).
 - ``flip_verdict``: one lane's verdict is inverted where the engine
-  returns it (guarantees 1 and 2).
+  returns it, in every batch it collects (guarantees 1 and 2).
 - ``host_answers``: the device health machine is disabled, so the host
   oracle answers every lane, correctly (guarantee 3: ``failed``).
 """
@@ -40,14 +40,17 @@ def after_setup(name: str, say) -> None:
     if name == "flip_verdict":
         from tendermint_tpu.ops import ed25519_batch
 
-        sound = ed25519_batch._verify_uncached
+        # where every path of both engines returns: a one-shot verify_batch,
+        # each block of a call made of blocks, each sub-batch of a phased
+        # call (sr25519_batch imports this class)
+        sound = ed25519_batch._PendingJobs.collect
 
-        def flipped(pubkeys, msgs, sigs, backend=None):
-            out = sound(pubkeys, msgs, sigs, backend).copy()
+        def flipped(self):
+            out = sound(self).copy()
             out[len(out) // 3] = not out[len(out) // 3]
             return out
 
-        ed25519_batch._verify_uncached = flipped
+        ed25519_batch._PendingJobs.collect = flipped
     elif name == "host_answers":
         from tendermint_tpu.ops.device_policy import shared as health
 
