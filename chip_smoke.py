@@ -42,7 +42,14 @@ jax, but touches no device).
   program (``ops/kernel_store.py``): each sharded kernel's first call is
   printed with its ``stored`` (``miss``: the kernel body was walked;
   ``hit``: not) and seconds, cold in the first run and warm in the
-  second. The second run proves the compile cache and the kernel store:
+  second; there the sr25519 buckets from the mesh floor (256 lanes) up
+  and a 4,950-lane batch (``BASELINE.json`` config 5's sr25519 half: a
+  4,096-lane slab a device on four) must go out sharded, on the sr25519
+  shard program, and the mixed committee is 600 validators, so that both
+  of its device sub-batches pass the floor and one ``verify_commit``
+  sends two sharded chunks with the host's lanes between
+  (``mesh_dispatch`` by kind; PR 48). The second run proves the compile
+  cache and the kernel store:
   it may add no entry, and every sharded first call must be a ``hit``.
 - **served** (driven from the parent): ``python -m tendermint_tpu
   verifyd`` started through the CLI, warmed one request at a time, then
@@ -92,7 +99,9 @@ EARLY_TAIL = 204  # lanes past one full engine job in the early-begin batch (pad
 SYNC_VALS = 500  # the blocksync window: BASELINE.json config 4's committee
 SYNC_WINDOW = 16  # blocksync/syncer.DEFAULT_VERIFY_WINDOW
 SR_BUCKETS = (64, 256, 1024, 4096)  # every width an sr25519 chunk is padded to
+SR_MESH_LANES = 4_950  # config 5's sr25519 half: on two to four devices a 4,096-lane slab each
 MIXED_VALS = 150  # BASELINE.json config 5's three key types at config 2's size
+MIXED_MESH_VALS = 600  # on a mesh: 280 lanes of each type that batches, over the mesh floor
 SERVED_VALS = 150
 CLIENTS = 4
 REQUESTS_PER_CLIENT = 3
@@ -372,6 +381,7 @@ def _compiles(spans: list, impl: str) -> list:
 def _first_calls(report: dict) -> list:
     """Every row of :func:`_compiles` in a library run's report."""
     parts = [report["edge"], report.get("sharded_edge", {})] + report["sizes"]
+    parts += report.get("sr25519", []) + [report.get("mixed_committee") or {}]
     return [c for part in parts for c in part.get("compiles", ())]
 
 
@@ -774,11 +784,39 @@ def _sr25519_lanes(n: int):
     return [list(col) for col in zip(*(lanes[(i * 7) % 40] for i in range(n)))]
 
 
-def _run_sr25519(n: int, impl: str) -> dict:
+def _check_sharded_kinds(spans: list, impl: str, n_mesh: int, kinds: set, what: str) -> list:
+    """The sharded dispatches among ``spans`` (:func:`_sharded`: each on
+    the implementation ``auto`` resolved to): every one over all
+    ``n_mesh`` devices, a chunk of each of ``kinds`` among them, and no
+    chunk of any kind left on one device."""
+    sharded = _sharded(spans, impl)
+    by_kind = {}
+    for kind, devices, _lanes, _ran in sharded:
+        by_kind.setdefault(kind, set()).add(devices)
+    check(
+        kinds <= set(by_kind) and all(d == {n_mesh} for d in by_kind.values()),
+        "%s: sharded dispatches %r, want kinds %r over %d devices",
+        what, sharded, sorted(kinds), n_mesh,
+    )
+    went_out = [m["ts"] for m in spans if m["name"] == "mesh_dispatch"]
+    local = [
+        (e["args"]["kind"], e["args"]["lanes"]) for e in spans
+        if e["name"] == "dispatch_chunk"
+        and not any(e["ts"] <= ts <= e["ts"] + e["dur"] for ts in went_out)
+    ]
+    check(not local, "%s: chunks %r stayed on one device", what, local)
+    return sharded
+
+
+def _run_sr25519(n: int, impl: str, n_mesh: int = 1) -> dict:
     """One full bucket of sr25519 lanes through the engine, against the
-    host schnorrkel oracle lane for lane."""
+    host schnorrkel oracle lane for lane. Where the mesh has ``n_mesh``
+    = two devices or more and the lanes reach its floor they go out
+    sharded, a slab a device, under ``pallas`` on the sr25519 shard
+    program."""
     from tendermint_tpu.crypto.sr25519 import verify as verify_sr
     from tendermint_tpu.ops import sr25519_batch
+    from tendermint_tpu.parallel import mesh
 
     pks, msgs, sigs = _sr25519_lanes(n)
     _drain_spans()
@@ -795,24 +833,48 @@ def _run_sr25519(n: int, impl: str) -> dict:
         "sr25519 at %d lanes: the oracle gave one verdict for every lane", n,
     )
     spans = _drain_spans()
-    _check_dispatch(spans, {"sr25519": n}, "sr25519 at %d lanes" % n)
-    _check_health(_delta(before), "sr25519 at %d lanes" % n)
+    what = "sr25519 at %d lanes" % n
+    _check_dispatch(spans, {"sr25519": n}, what)
+    d = _delta(before)
+    _check_health(d, what)
+    compiles = _compiles(spans, impl)
+    sharded = []
+    if n_mesh >= 2 and n >= mesh.MIN_MESH_LANES:
+        sharded = _check_sharded_kinds(spans, impl, n_mesh, {"sr25519"}, what)
+        check(d["mesh_dispatches"] > 0, what + ": no sharded dispatch")
+        if impl == "pallas":
+            # a width met for the first time in this process fetches its
+            # shard program; every one of them is the sr25519 kernel's
+            check(
+                all(len(c) > 4 and c[1] == "verify_sr" for c in compiles),
+                "%s: first calls %r, want the sr25519 shard program alone", what, compiles,
+            )
+    else:
+        check(
+            d["mesh_dispatches"] == 0 and not _sharded(spans, impl),
+            "%s: sharded below the mesh floor or on one device", what,
+        )
     return {
         "lanes": n,
         "accepted": int(sum(map(bool, verdicts))),
-        "compiles": _compiles(spans, impl),
+        "compiles": compiles,
+        "sharded": sharded,
     }
 
 
-def _run_mixed(n: int, impl: str) -> dict:
+def _run_mixed(n: int, impl: str, n_mesh: int = 1) -> dict:
     """A committee of the three key types (n // 15 secp256k1 keys, the
     rest halves) through verify_commit: sound, then with one lane of
     each type tampered, the first blamed and every lane's verdict its
     own key's. The secp256k1 lanes are the host's by design
-    (``host_lanes``); nothing else may be."""
+    (``host_lanes``), verified while both device sub-batches are in
+    flight; nothing else may be. Where the mesh has ``n_mesh`` = two
+    devices or more and each device type has the mesh floor's lanes,
+    both sub-batches go out sharded."""
     from bench.workload import load_helpers
     from tendermint_tpu.crypto import batch as crypto_batch
     from tendermint_tpu.ops import ed25519_batch
+    from tendermint_tpu.parallel import mesh
     from tendermint_tpu.types import validation
 
     helpers = load_helpers()
@@ -835,6 +897,21 @@ def _run_mixed(n: int, impl: str) -> dict:
     )
     host = {e["args"]["key_type"]: e["args"]["lanes"] for e in spans if e["name"] == "host_lanes"}
     check(host == {"secp256k1": n_secp}, "mixed committee: host lanes %r", host)
+    inflight = [e["args"].get("device_lanes_inflight") for e in spans if e["name"] == "host_lanes"]
+    check(
+        inflight == [n - n_secp],
+        "mixed committee: host lanes verified with %r device lanes in flight, want %d",
+        inflight, n - n_secp,
+    )
+    sharded = []
+    if n_mesh >= 2 and min(sent["ed25519"], sent["sr25519"]) >= mesh.MIN_MESH_LANES:
+        # both sub-batches sharded, the ed25519 one first (a chunk a kind), then sr25519's one
+        sharded = _check_sharded_kinds(spans, impl, n_mesh, {"sr25519"}, "mixed committee")
+        engines = [e["args"]["engine"] for e in spans if e["name"] == "mesh_dispatch"]
+        check(
+            engines[:1] == ["ed25519"] and engines.count("sr25519") == 1 and engines[-1] == "sr25519",
+            "mixed committee: sharded dispatches by engine %r, want ed25519's then sr25519's", engines,
+        )
     by_engine = {}
     for e in spans:
         if e["name"] == "dispatch_chunk":
@@ -879,7 +956,10 @@ def _run_mixed(n: int, impl: str) -> dict:
     blocks = _check_blocks(spans, "mixed committee")
     if max(sent.values()) < ed25519_batch.job_lanes():
         check(blocks == 1, "mixed committee: a commit under one job built in %d blocks", blocks)
-    return {"validators": n, "sent": sent, "tampered": bad, "compiles": _compiles(spans, impl)}
+    return {
+        "validators": n, "sent": sent, "tampered": bad, "compiles": _compiles(spans, impl),
+        "sharded": sharded,
+    }
 
 
 def library_phase(
@@ -904,6 +984,7 @@ def library_phase(
         autotune, ed25519_batch, hash512, precompute, resident,
     )
     from tendermint_tpu.ops.device_policy import HEALTHY, shared as health
+    from tendermint_tpu.parallel import mesh
 
     check(
         not precompute.result_cache_enabled(),
@@ -991,14 +1072,27 @@ def library_phase(
             say("  counters %(counters)r" % rep)
             say("  compiled %(compiles)r" % rep)
 
-        report["sr25519"] = [_run_sr25519(n, impl) for n in sr_buckets]
+        # the devices a batch over the mesh floor is sharded over: all of
+        # them, unless the operator capped the mesh ([ops] mesh_devices)
+        n_mesh = mesh.manager.device_count()
+        if n_mesh >= 2 and sr_buckets:
+            # a slab of the widest bucket on every device, and a mixed
+            # committee both of whose device sub-batches pass the mesh floor
+            sr_buckets = tuple(sr_buckets) + (SR_MESH_LANES,)
+        if n_mesh >= 2 and mixed:
+            mixed = max(mixed, MIXED_MESH_VALS)
+        report["sr25519"] = [_run_sr25519(n, impl, n_mesh) for n in sr_buckets]
         for rep in report["sr25519"]:
-            say("sr25519: %(lanes)d lanes, %(accepted)d accepted, as the oracle; compiled %(compiles)r" % rep)
-        report["mixed_committee"] = _run_mixed(mixed, impl) if mixed else None
+            say(
+                "sr25519: %(lanes)d lanes, %(accepted)d accepted, as the oracle; "
+                "sharded %(sharded)r; compiled %(compiles)r" % rep
+            )
+        report["mixed_committee"] = _run_mixed(mixed, impl, n_mesh) if mixed else None
         if mixed:
             say(
                 "mixed committee: %(validators)d validators %(sent)r, lanes %(tampered)r "
-                "tampered and refused; compiled %(compiles)r" % report["mixed_committee"]
+                "tampered and refused; sharded %(sharded)r; compiled %(compiles)r"
+                % report["mixed_committee"]
             )
 
         snap = health.snapshot()

@@ -84,6 +84,18 @@ def _runs_pallas(kind: ChunkKind, impl: str) -> bool:
     return impl == "pallas" and kind.pallas is not None
 
 
+def shard_program(kind: ChunkKind) -> str:
+    """What a kind's sharded program is called, so its module on a
+    device trace (``jit_<this>``): the one-chip program's name
+    (``kind.program``: ``run``, ``run_sr25519``) with ``_shard`` behind
+    its ``run`` — ``run_shard``, ``run_shard_sr25519``. It is another
+    program than the one-chip one — lowered at one shard's shapes, under
+    ``shard_map`` or GSPMD — and a process whose mesh degrades to one
+    device runs both; ``run*`` still matches, which is what the
+    benchmark's kernel metrics look for."""
+    return "run_shard" + kind.program.removeprefix("run")
+
+
 @lru_cache(maxsize=32)
 def _sharded_kernel(
     mesh: Mesh, kind: ChunkKind, impl: str, mul_impl: str, avals: Optional[tuple] = None
@@ -93,6 +105,9 @@ def _sharded_kernel(
     impl is a trace-time switch on field32, pinned inside the traced fn
     (same rules as ops/ed25519_batch._compiled_kernel) and therefore
     part of the cache key.
+
+    The jitted function is named by :func:`shard_program`, so each
+    kind's sharded program is a module of its own on a device trace.
 
     Each input is sharded along its lane axis, so a device holds only
     its own lanes' rows (and, for the gathered ``(8, 4, 32, N)`` table
@@ -142,6 +157,7 @@ def _sharded_kernel(
                         check_vma=False,
                     )(*a)
 
+                run.__name__ = shard_program(kind)
                 program.append(jax.jit(run, **shardings))
             return program[0](*args)
 
@@ -157,6 +173,7 @@ def _sharded_kernel(
         with field.pinned_mul_impl(mul_impl):
             return kind.kernel(*args)
 
+    run.__name__ = shard_program(kind)
     return jax.jit(run, **shardings)
 
 

@@ -209,7 +209,8 @@ def trace_turn(root: str, exclusive: bool, bound: float, every: float = 0.2):
 
 
 def rehearse_cell(
-    bench: str, cell: str, seed: int, trace: int, *extra, timeout: int = 300, prelude: str = ""
+    bench: str, cell: str, seed: int, trace: int, *extra, timeout: int = 300, prelude: str = "",
+    seconds: float = 1, env=None,
 ):
     """(result line, standard output) of one CPU rehearsal of a
     benchmark cell's tiny twin: ``chipbench.run --rehearse`` in a child.
@@ -221,10 +222,12 @@ def rehearse_cell(
     ones have it together, and none waits longer for it than the
     child's own ``timeout``. ``prelude`` is Python the child
     runs before ``chipbench.run``'s ``main``: a fault planted in the
-    program where ``chipbench/breaks.py`` knows none."""
+    program where ``chipbench/breaks.py`` knows none. ``seconds`` is the
+    window's length and ``env`` what the child's environment holds
+    besides this process's (a cell on virtual devices of its own)."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     args = [
-        "--workload", cell, "--seed", str(seed), "--seconds", "1", "--trace", str(trace),
+        "--workload", cell, "--seed", str(seed), "--seconds", str(seconds), "--trace", str(trace),
         "--rehearse", "--bench-file", bench, *extra,
     ]
     cmd = [sys.executable, "-m", "chipbench.run", *args]
@@ -236,7 +239,7 @@ def rehearse_cell(
         began = time.monotonic()
         proc = subprocess.run(
             cmd, cwd=root, capture_output=True, text=True, timeout=timeout,
-            env=dict(os.environ, JAX_PLATFORMS="cpu"),
+            env=dict(os.environ, JAX_PLATFORMS="cpu", **(env or {})),
         )
     # captured with the test's output: what a layout of these cases is judged by
     print("rehearsal %s trace=%d: waited %.1f s for the trace lock, ran %.1f s"

@@ -282,6 +282,53 @@ def test_sharded_chunks_are_held_to_the_resolved_impl(ran, ok):
             chip_smoke._sharded([span], "pallas")
 
 
+def _chunk(kind, engine, at, lanes, sharded_over=None, padded=None):
+    """A ``dispatch_chunk`` span and, where it went out over a mesh, the
+    ``mesh_dispatch`` inside it."""
+    out = [{"name": "dispatch_chunk", "ts": at, "dur": 100.0,
+            "args": {"kind": kind, "engine": engine, "lanes": lanes}}]
+    if sharded_over:
+        out.append({"name": "mesh_dispatch", "ts": at + 10.0, "dur": 80.0,
+                    "args": {"kind": kind, "engine": engine, "devices": sharded_over,
+                             "lanes": padded or lanes, "impl": "pallas"}})
+    return out
+
+
+def test_a_mixed_commits_two_sub_batches_are_held_to_the_mesh():
+    """PR 48: on two devices or more every chunk of the kinds named has
+    a ``mesh_dispatch`` over all of them, and no chunk stayed behind."""
+    both = _chunk("resident", "ed25519", 0.0, 280, 4, 1024) + _chunk("sr25519", "sr25519", 500.0, 280, 4, 1024)
+    assert chip_smoke._check_sharded_kinds(both, "pallas", 4, {"sr25519"}, "mixed") == [
+        ("resident", 4, 1024, "pallas"), ("sr25519", 4, 1024, "pallas"),
+    ]
+    with pytest.raises(chip_smoke.SmokeFailure, match="want kinds .'sr25519'. over 4 devices"):
+        chip_smoke._check_sharded_kinds(both[:2], "pallas", 4, {"sr25519"}, "mixed")  # no sr25519 chunk sharded
+    with pytest.raises(chip_smoke.SmokeFailure, match="over 4 devices"):  # a degraded mesh
+        chip_smoke._check_sharded_kinds(
+            both[:2] + _chunk("sr25519", "sr25519", 500.0, 280, 3, 1026), "pallas", 4, {"sr25519"}, "mixed"
+        )
+    local = both + _chunk("legacy", "ed25519", 900.0, 12)
+    with pytest.raises(chip_smoke.SmokeFailure, match="stayed on one device"):
+        chip_smoke._check_sharded_kinds(local, "pallas", 4, {"sr25519"}, "mixed")
+
+
+def test_the_store_warm_check_reads_the_sr25519_and_the_mixed_parts_too():
+    """A second run that walks the sr25519 shard program's body is caught
+    like one that walks an ed25519 one (PR 48)."""
+    rows = lambda stored: chip_smoke._compiles([_sharded_compile_span(stored, "verify_sr")], "pallas")
+    report = lambda sr, mixed: {
+        "edge": {"compiles": []}, "sizes": [],
+        "sr25519": [{"compiles": [["pallas", "verify_sr", 64, 1.0]]}, {"compiles": rows(sr)}],
+        "mixed_committee": {"compiles": rows(mixed)},
+    }
+    assert len(chip_smoke._sharded_first_calls(report("miss", "hit"))) == 2
+    chip_smoke._check_store_warm(2, report("hit", "hit"))
+    for cold in (report("miss", "hit"), report("hit", "miss")):
+        with pytest.raises(chip_smoke.SmokeFailure, match="run 2 traced a sharded kernel"):
+            chip_smoke._check_store_warm(2, cold)
+    chip_smoke._check_store_warm(2, {"edge": {"compiles": []}, "sizes": [], "sr25519": [], "mixed_committee": None})
+
+
 # --- §5: a compile cache that can be placed from outside ----------------------
 
 

@@ -26,6 +26,7 @@ from chipbench import spec
 from tests import test_chipbench_callpath as callpath
 from tests import test_chipbench_light as light
 from tests import test_chipbench_mixed as mixed
+from tests import test_chipbench_mixed_x4 as mixed_x4
 from tests import test_chipbench_rotation as rotation
 from tests.helpers import over_limit, rehearse_cell, sound
 
@@ -189,6 +190,30 @@ SECP_FORCED_TRUE = PLANT % """
 def test_tiny_twin_of_mixed10k_with_a_planted_fault_comes_out_not_correct(prelude, over):
     out, said = rehearse_cell(mixed.BENCH, mixed.CELL, mixed.SEED, 0, prelude=prelude)
     assert out["correct"] is False and set(over) <= set(over_limit(said)), said[-1500:]
+
+
+# --- ``mixed10k-x4``'s twin: the mixed committee on a mesh of four virtual devices ----------
+
+
+@pytest.mark.limit(600)  # two sharded XLA kernels compile cold, then ~5 s a call on four devices that share the cores
+def test_tiny_twin_of_mixed10k_x4_rehearses_on_four_virtual_devices_traced():
+    """520 validators, 256 lanes of each type that batches: both
+    sub-batches pass the mesh floor and go out sharded, a 64-lane slab a
+    device, the host lanes between. Every definition the real cell is
+    held to is printed but the one the XLA graph leaves silent, which
+    the twin does not list (``tests/test_chipbench_mixed_x4.py``)."""
+    bench, cell = mixed_x4.BENCH, mixed_x4.CELL
+    out, said = rehearse_cell(
+        bench, cell, mixed_x4.SEED, 1, timeout=580, seconds=mixed_x4.SECONDS, env=mixed_x4.ENV
+    )
+    assert "device_kind cpu, count 4" in said
+    value = sound(out, said, MIXED_COMPARED, bench, cell)  # every name the twin lists printed
+    assert value("mesh_lane_share") == 100.0 and value("slab_fill") == 100.0  # 4 x 64 lanes for 256
+    assert value("resident_hit_share") == 50.0
+    assert 0 < value("mesh_dispatch_ms") and value("h2d_bytes") > 0
+    assert 0 < value("kernel_ms", **mixed_x4.SR_SHARD) < value("kernel_ms", patterns=mixed.ALL_PROGRAMS["patterns"])
+    assert 0 < value("sr25519_shard_roofline") < 100
+    assert "calls completed" in said and "(512 useful lanes each)" in said
 
 
 # --- ``sync500-catchup``'s twin, and ``sync500-rotation``'s: this deployment with a set that changes

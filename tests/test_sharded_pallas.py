@@ -430,3 +430,121 @@ def test_table_kernels_sharded_agree_with_the_oracle(batch8, kind, fresh_store):
     got = np.asarray(out)
     assert list(np.logical_and(got[:7], host_ok[:7])) == want[:7]
     assert got[7:].all()
+
+
+# --- what a sharded program is called, and where the store keeps it (PR 48) ----------
+
+
+def _sr_stand_in(pk, r, s, k, *, block, interpret):
+    return (pk[:, 0] == r[:, 0]) & (s[:, 1] == k[:, 1])
+
+
+def _sharded_names(monkeypatch, kind, impl, inputs):
+    """The names of the functions ``jax.jit`` was handed while ``kind``'s
+    chunk went out over two devices under ``impl``."""
+    names, jit = [], jax.jit
+
+    def recording(fun, *args, **kwargs):
+        names.append(getattr(fun, "__name__", "?"))
+        return jit(fun, *args, **kwargs)
+
+    monkeypatch.setattr(jax, "jit", recording)
+    sharding._sharded_kernel.cache_clear()
+    out, _ = sharding.run_chunk_mesh(kind, inputs, impl, "vpu", plan_of(2))
+    jax.block_until_ready(out)
+    return names
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+@pytest.mark.parametrize(
+    "kind_name,want", [("tables", "run_shard"), ("sr25519", "run_shard_sr25519")]
+)
+def test_a_sharded_program_is_named_by_its_kind(monkeypatch, stand_in, impl, kind_name, want):
+    """``jit_run_shard`` for the ed25519 kinds, ``jit_run_shard_sr25519``
+    for sr25519's, under shard_map and under GSPMD alike: each a module
+    of its own on a device trace, told from the one-chip programs
+    (``jit_run``, ``jit_run_sr25519``), all of them ``jit_run*``."""
+    import fnmatch
+
+    from tendermint_tpu.ops.sr25519_batch import SR25519
+
+    monkeypatch.setattr(pallas_verify, "verify_sr_fn", _sr_stand_in)
+    if kind_name == "sr25519":
+        kind = SR25519
+        inputs = {i.name: np.zeros((16, 32), np.uint8) for i in kind.inputs}
+    else:
+        kind = KINDS["tables"]
+        _, inputs, _ = table_chunk(16, seed=5)
+    if impl == "xla":
+        # the name is what is asked about, not the graph: a cheap kernel of the kind's arguments
+        kind = type(kind)(
+            kind.name, kind.engine, kind.kernel_name,
+            lambda *a: jnp.ones((a[-1].shape[0],), bool), kind.pallas, kind.inputs,
+            program=kind.program,
+        )
+    assert sharding.shard_program(kind) == want
+    names = _sharded_names(monkeypatch, kind, impl, inputs)
+    assert want in names and kind.program not in names, names
+    module = "jit_" + want
+    assert fnmatch.fnmatch(module, "jit_run*")
+    assert not fnmatch.fnmatch(module, "jit_run_sr25519*")  # the one-chip sr25519 program's pattern
+    assert module != "jit_" + kind.program
+
+
+def test_every_kinds_sharded_program_has_a_name_of_its_own_pattern():
+    from tendermint_tpu.ops.sr25519_batch import SR25519
+
+    got = {k.name: sharding.shard_program(k) for k in [*KINDS.values(), SR25519]}
+    assert got == {"legacy": "run_shard", "tables": "run_shard", "resident": "run_shard",
+                   "sr25519": "run_shard_sr25519"}
+
+
+def test_a_stored_programs_file_name_does_not_hold_the_jitted_name(stand_in, fresh_store):
+    """The store's key is what it was (PR 36): jax and jaxlib versions,
+    platform, the kernel's name, one shard's argument shapes, and the
+    caller's key (device kind, block, interpret, the sources' digest).
+    What the sharded call is named is not in it, so a program stored
+    before the name changed is still a hit."""
+    import hashlib
+
+    import jaxlib
+
+    plan = plan_of(4)
+    _, gathered, _ = table_chunk(16, seed=6)
+    ed25519_batch._run_chunk(KINDS["tables"], gathered, None, plan)
+    shard = (((8, 4, 32, 4), "uint8"), ((4,), "uint8"), ((4, 32), "uint8"), ((4, 32), "uint8"),
+             ((4, 32), "uint8"))
+    key = (plan.mesh.devices.flat[0].device_kind, 4, True, pallas_verify._program_digest())
+    ident = repr((jax.__version__, jaxlib.__version__, "cpu", "verify_tables", shard,
+                  tuple(str(k) for k in key)))
+    want = "verify_tables-%s.jaxexport" % hashlib.sha256(ident.encode()).hexdigest()[:32]
+    assert os.listdir(fresh_store) == [want]
+
+
+@pytest.mark.slow  # the sr25519 body's interpret-mode compile for two devices: 483 s beside two busy processes (PR 48)
+@pytest.mark.limit(1800)
+def test_sr25519_kernel_sharded_agrees_with_the_oracle(fresh_store, ring):
+    """The real sr25519 shard body (``_SHARD_BODY["compiled_verify_sr"]``,
+    interpret mode), 64 lanes over two devices — 32 a device, padded to
+    the narrowest bucket, a 64-lane slab each — through the engine's own
+    entry: valid, tampered and non-canonical lanes against
+    the schnorrkel oracle lane for lane, from a stored program that a
+    second process finds. ``chip_smoke.py`` holds the real widths, on
+    the chip, compiled for real."""
+    from tendermint_tpu.ops import sr25519_batch
+    from tests.test_pallas_sr25519 import distinct_lanes
+
+    pubs, msgs, sigs, _, oracle = distinct_lanes()
+    with mesh_mod.manager.forced(sharding.make_mesh(2)):
+        got = sr25519_batch.verify_batch_sr(pubs, msgs, sigs)
+    assert got == oracle and True in got and False in got
+    (md,) = spans(ring, "mesh_dispatch")
+    assert (md["args"]["kind"], md["args"]["impl"], md["args"]["devices"], md["args"]["lanes"]) == (
+        "sr25519", "pallas", 2, 128,
+    )
+    (kc,) = spans(ring, "kernel_compile")
+    assert (kc["args"]["kernel"], kc["args"]["lanes"], kc["args"]["devices"], kc["args"]["stored"]) == (
+        "verify_sr", 64, 2, "miss",
+    )
+    assert [f.split("-")[0] for f in os.listdir(fresh_store)] == ["verify_sr"]
+    assert not spans(ring, "host_fallback")
