@@ -903,6 +903,11 @@ def _run_mixed(n: int, impl: str, n_mesh: int = 1) -> dict:
         "mixed committee: host lanes verified with %r device lanes in flight, want %d",
         inflight, n - n_secp,
     )
+    answered_by = [e["args"].get("impl") for e in spans if e["name"] == "host_lanes"]
+    check(
+        answered_by == ["native"],
+        "mixed committee: host lanes answered by %r, want the native routine's one call", answered_by,
+    )
     sharded = []
     if n_mesh >= 2 and min(sent["ed25519"], sent["sr25519"]) >= mesh.MIN_MESH_LANES:
         # both sub-batches sharded, the ed25519 one first (a chunk a kind), then sr25519's one
@@ -1000,6 +1005,12 @@ def library_phase(
         host_hash == "native",
         "native/sha512_batch.c did not build: host hashing fell to hashlib",
     )
+    host_secp = hashing.host_secp256k1_impl()
+    say("host secp256k1: %s" % host_secp)
+    check(
+        host_secp == "native",
+        "native/secp256k1_batch.c built without 128-bit integers: secp256k1 lanes fell to OpenSSL",
+    )
     impl = ed25519_batch.active_impl()
     paths = {
         "device_hash": hash512.device_hash_enabled(),
@@ -1015,6 +1026,7 @@ def library_phase(
     try:
         report = {
             "device": dev, "impl": impl, "paths": paths, "host_hash": host_hash,
+            "host_secp256k1": host_secp,
         }
 
         # ZIP-215 edge vectors straight into ops.verify_batch.

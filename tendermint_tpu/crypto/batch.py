@@ -540,10 +540,12 @@ def create_batch_verifier(pub_key: PubKey) -> BatchVerifier:
 
 class HostLanesVerifier(BatchVerifier):
     """The lanes of one key type that has no batch verifier
-    (secp256k1): each is verified on the host by its key's own
-    ``verify_signature``, under the ``host_lanes`` span. Host by design
-    and on every call: not the health machine's ``host_fallback``,
-    which says that a device failed."""
+    (secp256k1), verified on the host under the ``host_lanes`` span: by
+    one call of the key class's ``verify_many`` where it has one (the
+    span then says whose code that is, ``impl``), else each by its
+    key's own ``verify_signature``. Host by design and on every call:
+    not the health machine's ``host_fallback``, which says that a
+    device failed."""
 
     def __init__(self, key_type: str):
         self.key_type = key_type
@@ -579,8 +581,15 @@ class HostLanesVerifier(BatchVerifier):
             key_type=self.key_type,
             lanes=n,
             device_lanes_inflight=device_lanes_inflight,
-        ):
-            oks = [bool(pk.verify_signature(msg, sig)) for pk, msg, sig in self._lanes]
+        ) as span:
+            cls = type(self._lanes[0][0])
+            if getattr(cls, "verify_many", None) is not None and all(
+                type(pk) is cls for pk, _, _ in self._lanes
+            ):
+                span.set(impl=cls.verify_impl())
+                oks = cls.verify_many(*zip(*self._lanes))
+            else:
+                oks = [bool(pk.verify_signature(msg, sig)) for pk, msg, sig in self._lanes]
         return all(oks), oks
 
 
@@ -590,9 +599,10 @@ class MultiBatchVerifier(BatchVerifier):
     A 10k-validator commit with ed25519, sr25519 AND secp256k1 signers
     (BASELINE config 5) splits into one sub-verifier per key type — the
     two that batch each riding its own device kernel, a type with no
-    batch support (secp256k1) on the host, lane by lane
-    (:class:`HostLanesVerifier`) — and the verdicts merge back in
-    submission order. A set of one type is that type's ``verify()``.
+    batch support (secp256k1) on the host, in one call of its key
+    class's native routine (:class:`HostLanesVerifier`) — and the
+    verdicts merge back in submission order. A set of one type is that
+    type's ``verify()``.
     A mixed batch is verified in three phases, all on the caller's
     thread: every device sub-verifier, in the order of its type's name
     (whatever seat each type first appeared in, so that a call's shape
@@ -600,13 +610,13 @@ class MultiBatchVerifier(BatchVerifier):
     and including its last dispatch (``begin()``); the host-only lanes
     are verified while those kernels run; then each device sub-batch is
     collected, stored and merged (``finish()``), in the same order. Not
-    a thread beside them: ``cryptography``'s ECDSA verify never gives
-    the GIL up (PERF.md §6, PR 41), so a helper thread would make the
-    caller wait out the switch interval each time it comes back from a
-    put, a hash or a wait. Every sub-batch is verified whatever another found: the
-    caller names the first bad lane across types (reference
-    crypto/batch/batch.go:11-22 dispatches on ONE key type; this is the
-    mixed-set generalisation)."""
+    a thread beside them: a hundred host lanes are ten milliseconds
+    of one native call since PR 49 (they were 51 ms of OpenSSL's, which
+    never gave the GIL up: PERF.md §6, PR 41 and PR 49), less than a
+    helper thread's hand-offs would cost the caller. Every sub-batch is
+    verified whatever another found: the caller names the first bad
+    lane across types (reference crypto/batch/batch.go:11-22 dispatches
+    on ONE key type; this is the mixed-set generalisation)."""
 
     def __init__(self):
         self._subs: dict = {}

@@ -12,7 +12,11 @@ definition every path is held to is ``int.from_bytes(digest, "little")
 % L`` (:func:`reduce_mod_l_int`). ``sr25519_challenges_mod_l`` is the
 sr25519 verifier's challenge, a Merlin transcript a lane
 (``native/merlin_batch.c``, in the same library; ``crypto/merlin.py`` is
-the definition it is held to).
+the definition it is held to). The same library carries the one native
+routine that is not a hash, because the repo has one way to build C and
+this module is it: ``secp256k1_verify_native``, the ECDSA verification
+of a batch of secp256k1 lanes (``native/secp256k1_batch.c``; the rules
+are ``crypto/keys.Secp256k1PubKey``'s, which calls it).
 
 Reference analog: the challenge hashing inside curve25519-voi's batch
 verifier (crypto/ed25519/ed25519.go:198-233).
@@ -47,11 +51,16 @@ _SYMBOLS = {
     "sha512_batch_prefixed_mod_l": [_U8P, _U8P, _U64P, ctypes.c_int64, _U8P],
     "reduce512_mod_l": [_U8P, ctypes.c_int64, _U8P],
     "sr25519_challenges_mod_l": [_U8P, _U8P, _U8P, _U64P, ctypes.c_int64, _U8P],
+    "secp256k1_ecdsa_verify_batch": [
+        ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p
+    ],
 }
+# What an entry returns, where it returns anything.
+_RETURNS = {"secp256k1_ecdsa_verify_batch": ctypes.c_int}
 
 # The library's translation units, in the order they are hashed and
 # handed to the compiler.
-_SOURCES = ("sha512_batch.c", "merlin_batch.c")
+_SOURCES = ("sha512_batch.c", "merlin_batch.c", "secp256k1_batch.c")
 
 
 def _load(path: str) -> Optional[ctypes.CDLL]:
@@ -62,7 +71,7 @@ def _load(path: str) -> Optional[ctypes.CDLL]:
         for name, argtypes in _SYMBOLS.items():
             fn = getattr(lib, name)
             fn.argtypes = argtypes
-            fn.restype = None
+            fn.restype = _RETURNS.get(name)
     except (OSError, AttributeError):
         return None
     return lib
@@ -121,7 +130,8 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
     finally:
         if os.path.exists(tmp):
             os.unlink(tmp)
-    return lib
+    # the same file under the name every later process finds it by
+    return _load(lib_path) or lib
 
 
 def _lib() -> Optional[ctypes.CDLL]:
@@ -139,6 +149,34 @@ def host_hash_impl() -> str:
     ``hashlib`` as a failed build, and the engine's ``prep_chunk`` span
     carries it as ``hash``."""
     return "native" if _lib() is not None else "hashlib"
+
+
+def secp256k1_verify_native(keys: bytes, digests: bytes, sigs: bytes, n: int) -> Optional[bytes]:
+    """The verdicts of ``n`` secp256k1 ECDSA lanes, one byte a lane (1 =
+    good), from one call into ``native/secp256k1_batch.c``: ``keys`` 33
+    compressed bytes a lane, ``digests`` the 32 of SHA-256 over the
+    signed bytes, ``sigs`` 64 of r || s. None where there is no library,
+    or one built without 128-bit integers: the caller then has its own
+    way (OpenSSL's). ``n`` = 0 asks only which of the two it is."""
+    if not (len(keys) == 33 * n and len(digests) == 32 * n and len(sigs) == 64 * n):
+        raise ValueError(f"{n} lanes want {33 * n}, {32 * n} and {64 * n} bytes")
+    lib = _lib()
+    if lib is None:
+        return None
+    out = ctypes.create_string_buffer(n)
+    if not lib.secp256k1_ecdsa_verify_batch(keys, digests, sigs, n, out):
+        return None
+    return out.raw
+
+
+def host_secp256k1_impl() -> str:
+    """Which secp256k1 verification is live: ``"native"`` (the routine
+    of ``native/secp256k1_batch.c``) or ``"openssl"`` (``cryptography``'s,
+    lane by lane: no compiler, or none with 128-bit integers). As
+    :func:`host_hash_impl`: chip_smoke.py prints it and treats
+    ``openssl`` as a failed build, and the ``host_lanes`` span carries
+    it as ``impl``."""
+    return "native" if secp256k1_verify_native(b"", b"", b"", 0) is not None else "openssl"
 
 
 def reduce_mod_l_int(digest: bytes) -> bytes:

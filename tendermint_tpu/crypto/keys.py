@@ -14,9 +14,9 @@ from __future__ import annotations
 
 import hashlib
 from abc import ABC, abstractmethod
-from typing import Optional
+from typing import List, Optional, Sequence
 
-from tendermint_tpu.crypto import ed25519_ref
+from tendermint_tpu.crypto import ed25519_ref, hashing
 
 ADDRESS_LEN = 20
 
@@ -170,6 +170,7 @@ except Exception:  # pragma: no cover
     _HAVE_SECP = False
 
 SECP256K1_N = 0xFFFFFFFFFFFFFFFFFFFFFFFFFFFFFFFEBAAEDCE6AF48A03BBFD25E8CD0364141
+_NO_SIG = bytes(64)
 
 
 def _ripemd160_sha256(data: bytes) -> bytes:
@@ -194,6 +195,40 @@ class Secp256k1PubKey(PubKey):
         return self._bytes
 
     def verify_signature(self, msg: bytes, sig: bytes) -> bool:
+        return self.verify_many([self], [msg], [sig])[0]
+
+    @staticmethod
+    def verify_many(
+        pub_keys: Sequence["Secp256k1PubKey"], msgs: Sequence[bytes], sigs: Sequence[bytes]
+    ) -> List[bool]:
+        """Each lane's verdict, all from one call into the native
+        routine (``native/secp256k1_batch.c``); a single signature is a
+        batch of one, so a vote, an evidence check and a commit's lane
+        are answered by the same code. Upstream's rules
+        (crypto/secp256k1/secp256k1.go): r || s of 64 bytes, 0 < r < n,
+        0 < s <= n / 2, ECDSA over SHA-256. Where the library is not
+        there (:func:`hashing.host_secp256k1_impl`), OpenSSL's lane by
+        lane."""
+        if not len(pub_keys) == len(msgs) == len(sigs):
+            raise ValueError("as many keys, messages and signatures as lanes")
+        # a signature of another length is refused as r = 0 is
+        oks = hashing.secp256k1_verify_native(
+            b"".join(pk._bytes for pk in pub_keys),
+            b"".join(hashlib.sha256(msg).digest() for msg in msgs),
+            b"".join(sig if len(sig) == 64 else _NO_SIG for sig in sigs),
+            len(pub_keys),
+        )
+        if oks is None:
+            return [pk._verify_openssl(msg, sig) for pk, msg, sig in zip(pub_keys, msgs, sigs)]
+        return [ok == 1 for ok in oks]
+
+    @staticmethod
+    def verify_impl() -> str:
+        """Whose code answers :meth:`verify_many` in this process:
+        ``native`` or ``openssl``."""
+        return hashing.host_secp256k1_impl()
+
+    def _verify_openssl(self, msg: bytes, sig: bytes) -> bool:
         if not _HAVE_SECP or len(sig) != 64:
             return False
         r = int.from_bytes(sig[:32], "big")

@@ -42,20 +42,30 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
     if not os.path.exists(lib_path) or os.path.getmtime(lib_path) < os.path.getmtime(
         src
     ):
-        for cc in ("g++", "c++"):
-            try:
-                subprocess.run(
-                    [cc, "-O2", "-shared", "-fPIC", src, "-lz", "-o", lib_path + ".tmp"],
-                    check=True,
-                    capture_output=True,
-                    timeout=120,
-                )
-                os.replace(lib_path + ".tmp", lib_path)
-                break
-            except Exception:
-                continue
-        else:
-            return None
+        # built under a name of this process's own, then renamed:
+        # processes that start together (a test run's workers on a
+        # fresh build directory) each end with a whole library
+        fd, tmp = tempfile.mkstemp(prefix="libfiledb.", suffix=".so", dir=build_dir)
+        os.close(fd)
+        try:
+            for cc in ("g++", "c++"):
+                try:
+                    subprocess.run(
+                        [cc, "-O2", "-shared", "-fPIC", src, "-lz", "-o", tmp],
+                        check=True,
+                        capture_output=True,
+                        timeout=120,
+                    )
+                    os.chmod(tmp, 0o755)  # mkstemp made it the owner's alone
+                    os.replace(tmp, lib_path)
+                    break
+                except Exception:
+                    continue
+            else:
+                return None
+        finally:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
     try:
         lib = ctypes.CDLL(lib_path)
         lib.filedb_open.argtypes = [ctypes.c_char_p]

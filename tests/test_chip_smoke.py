@@ -81,7 +81,7 @@ def test_edge_vectors_phase_passes_on_cpu():
     legacy kernel other suites compile anyway."""
     report = chip_smoke.library_phase("cpu", sizes=(), sync=None, sr_buckets=(), mixed=0, early_tail=0)
     assert report["device"]["platform"] == "cpu"
-    assert report["impl"] == "xla" and report["host_hash"] == "native"
+    assert report["impl"] == "xla" and report["host_hash"] == report["host_secp256k1"] == "native"
     edge = report["edge"]
     assert 0 < edge["accepted"] < edge["lanes"]
     assert report["sr25519"] == [] and report["mixed_committee"] is None

@@ -173,7 +173,8 @@ FLIPPED_CHALLENGE = PLANT % """
 """
 SECP_FORCED_TRUE = PLANT % """
     from tendermint_tpu.crypto.keys import Secp256k1PubKey
-    Secp256k1PubKey.verify_signature = lambda self, msg, sig: True
+    # every secp256k1 verify is a verify_many call since PR 49, a lane alone too
+    Secp256k1PubKey.verify_many = staticmethod(lambda keys, msgs, sigs: [True] * len(keys))
 """
 
 

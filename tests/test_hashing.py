@@ -239,7 +239,7 @@ def _parent_source(tmp_path):
 
 def _hashed_name():
     """The library's name: a hash of every translation unit
-    (``hashing._SOURCES``; two since PR 40's ``merlin_batch.c``)."""
+    (``hashing._SOURCES``; three since PR 49's ``secp256k1_batch.c``)."""
     h = hashlib.sha256()
     for name in hashing._SOURCES:
         with open(os.path.join(os.path.dirname(_SRC), name), "rb") as f:
@@ -330,6 +330,17 @@ def test_two_processes_on_an_empty_directory_both_end_native(build_dir):
         assert proc.returncode == 0, err.decode()
         assert out.decode().split() == ["native", want]
     assert os.listdir(build_dir) == [_hashed_name()]  # no half-built file left
+
+
+def test_the_process_that_builds_holds_the_library_under_its_final_name(build_dir):
+    """Built under a name of the process's own, renamed, and loaded once
+    more from where every later process finds it: ``_name`` is the
+    hashed name in the first process of a machine too (a suite on a
+    fresh build directory asks, ``tests/test_merlin_native.py``)."""
+    lib = hashing._build_and_load()
+    _assert_whole(lib)
+    assert os.path.basename(lib._name) == _hashed_name()
+    assert os.listdir(build_dir) == [_hashed_name()]
 
 
 def test_loader_raises_where_the_build_should_have_worked(build_dir, monkeypatch):

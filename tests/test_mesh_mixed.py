@@ -21,6 +21,7 @@ from __future__ import annotations
 import pytest
 
 from chipbench import reference_mixed
+from tendermint_tpu.crypto import hashing
 from tendermint_tpu.ops.device_policy import shared as shared_health
 from tendermint_tpu.parallel import mesh
 from tendermint_tpu.types import validation
@@ -87,6 +88,7 @@ def test_a_mixed_commit_sends_two_sharded_sub_batches_and_verifies_the_host_lane
     (host,) = named(events, "host_lanes")
     assert (host["args"]["key_type"], host["args"]["lanes"]) == ("secp256k1", N_SECP)
     assert host["args"]["device_lanes_inflight"] == N_ED + N_SR
+    assert host["args"]["impl"] == hashing.host_secp256k1_impl()  # whose ECDSA (PR 49)
     got = named(events, "collect_chunk")
     assert all(c["ts"] + c["dur"] <= host["ts"] for c in chunks)
     assert all(host["ts"] + host["dur"] <= c["ts"] for c in got)

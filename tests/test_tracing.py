@@ -14,6 +14,7 @@ import threading
 
 import pytest
 
+from tendermint_tpu.crypto import hashing
 from tendermint_tpu.libs import tracing
 from tendermint_tpu.libs.metrics import (
     ConsensusMetrics,
@@ -1100,8 +1101,9 @@ def test_a_mixed_commit_names_both_engines_alike(ring, monkeypatch):
     ``kernel_compile`` (``engine``, ``kernel``, ``lanes``) at a shape's
     first call; and the spans of what is sr25519's or the host's alone:
     ``merlin_challenge`` (``lanes``) inside its ``prep_chunk``,
-    ``host_lanes`` (``key_type``, ``lanes``, ``device_lanes_inflight``)
-    inside its ``batch_verify``. A device sub-batch of a mixed call
+    ``host_lanes`` (``key_type``, ``lanes``, ``device_lanes_inflight``,
+    and since PR 49 ``impl``: whose ECDSA answered) inside its
+    ``batch_verify``. A device sub-batch of a mixed call
     opens ``batch_verify`` / ``verify_batch`` once a phase."""
     from tendermint_tpu.ops import ed25519_batch
     from tendermint_tpu.types import validation
@@ -1145,6 +1147,7 @@ def test_a_mixed_commit_names_both_engines_alike(ring, monkeypatch):
     (host,) = [e["args"] for e in events if e["name"] == "host_lanes"]
     assert (host["key_type"], host["lanes"], host["parent"]) == ("secp256k1", 2, "batch_verify")
     assert host["device_lanes_inflight"] == 17 + 19
+    assert host["impl"] == hashing.host_secp256k1_impl() == "native"
     outer = [e["args"] for e in events if e["name"] == "batch_verify"]
     assert sorted((o["key_type"], o["route"], o.get("phase", "")) for o in outer) == [
         ("ed25519", "device", "collect"), ("ed25519", "device", "dispatch"),

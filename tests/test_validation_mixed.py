@@ -7,6 +7,7 @@ of the three host oracles, lane for lane."""
 import pytest
 
 from tendermint_tpu.crypto import batch as crypto_batch
+from tendermint_tpu.crypto import hashing
 from tendermint_tpu.crypto.ed25519_ref import verify_zip215
 from tendermint_tpu.crypto.keys import Secp256k1PubKey
 from tendermint_tpu.crypto.sr25519 import Sr25519BatchVerifier, verify as verify_sr
@@ -49,6 +50,7 @@ def test_a_mixed_commit_is_accepted_on_the_batch_path(mixed):
     (host,) = [e for e in events if e["name"] == "host_lanes"]
     assert (host["args"]["key_type"], host["args"]["lanes"]) == ("secp256k1", N_SECP)
     assert host["args"]["parent"] == "batch_verify"
+    assert host["args"]["impl"] == hashing.host_secp256k1_impl()
     # one batch_verify / verify_batch a phase for a device sub-batch, in
     # the order the phases run; the host lanes have no second phase
     routes = [(e["args"]["key_type"], e["args"]["lanes"], e["args"]["route"], e["args"].get("phase"))
@@ -251,9 +253,9 @@ def test_a_phase_that_raises_leaves_nothing_in_flight_and_no_probe_latched(
         assert health.state == device_policy.COOLDOWN
         now[0] += 5.0
     if fault == "host_lane_raises":
-        def verify_signature(self, msg, sig):
+        def verify_many(pub_keys, msgs, sigs):  # where the host lanes go since PR 49
             raise Boom("host lane")
-        monkeypatch.setattr(Secp256k1PubKey, "verify_signature", verify_signature)
+        monkeypatch.setattr(Secp256k1PubKey, "verify_many", staticmethod(verify_many))
     else:
         def begin(self):
             raise Boom("second begin")
@@ -402,3 +404,93 @@ def test_room_says_which_sub_verifier_fills_its_job_first(monkeypatch, mixed, bl
     # the other type's job fills next, counted from where the block ended
     assert cut + bv.room(keys[cut:]) == max(first.values())
     bv.close()
+
+
+# --- the host lanes in one native call (ISSUE 49) ------------------------------
+
+
+@pytest.fixture(params=["native", "openssl"])
+def secp_impl(request, monkeypatch):
+    """The process with the native library, and as it is on a machine
+    with no C compiler: no library, OpenSSL lane by lane."""
+    if request.param == "openssl":
+        monkeypatch.setattr(hashing, "_LIB", None)
+        monkeypatch.setattr(hashing, "_LIB_TRIED", True)
+    assert hashing.host_secp256k1_impl() == request.param
+    return request.param
+
+
+def _tamper(commit, lanes):
+    for lane in lanes:
+        sig = bytearray(commit.signatures[lane].signature)
+        sig[7] ^= 0x10
+        commit.signatures[lane].signature = bytes(sig)
+
+
+@pytest.mark.parametrize("bad", [(), (0,), (1, 2), (0, 1, 2)], ids=["none", "first", "last_two", "all"])
+def test_the_host_lanes_give_one_verdict_list_whoever_verifies(mixed, secp_impl, bad):
+    """``HostLanesVerifier.verify``: the verdict of every lane is its
+    key's own ``verify_signature`` and OpenSSL's, with the library and
+    without, and the span says which answered."""
+    privs, vset, block_id = mixed
+    commit = make_commit(block_id, 16, 0, vset, privs)
+    secp = lanes_of(vset, "secp256k1")
+    _tamper(commit, [secp[i] for i in bad])
+    lanes = [lane for lane in commit_lanes(vset, commit) if lane[0].type == "secp256k1"]
+    bv = crypto_batch.HostLanesVerifier("secp256k1")
+    bv.add_many(*zip(*lanes))
+    got, events = traced(lambda: bv.verify(device_lanes_inflight=5))
+    assert got is None  # traced() returns what was raised
+    (host,) = [e["args"] for e in events if e["name"] == "host_lanes"]
+    assert host == dict(host, key_type="secp256k1", lanes=N_SECP, device_lanes_inflight=5, impl=secp_impl)
+    want = [i not in bad for i in range(N_SECP)]
+    assert bv.verify() == (not bad, want)
+    assert [pk.verify_signature(msg, sig) for pk, msg, sig in lanes] == want
+    assert [pk._verify_openssl(msg, sig) for pk, msg, sig in lanes] == want
+
+
+@pytest.mark.parametrize("tampered", [(), ("secp256k1",), ("secp256k1", "ed25519"), ("sr25519", "secp256k1")],
+                         ids=lambda t: "+".join(t) or "none")
+def test_a_mixed_commit_names_the_same_first_bad_lane_whoever_verifies_the_host_lanes(
+    mixed, secp_impl, tampered
+):
+    privs, vset, block_id = mixed
+    commit = make_commit(block_id, 17, 0, vset, privs)
+    bad = sorted(lanes_of(vset, key_type)[-1] for key_type in tampered)
+    _tamper(commit, bad)
+    lanes = commit_lanes(vset, commit)
+    assert multi_of(lanes).verify() == (not bad, [i not in bad for i in range(len(lanes))])
+    raised, events = traced(lambda: validation.verify_commit(CHAIN_ID, vset, block_id, 17, commit))
+    if bad:
+        assert "wrong signature (#%d)" % bad[0] in str(raised)
+    else:
+        assert raised is None
+    (host,) = [e["args"] for e in events if e["name"] == "host_lanes"]
+    assert (host["impl"], host["lanes"], host["device_lanes_inflight"]) == (secp_impl, N_SECP, N_ED + N_SR)
+
+
+class _LoopKey(Secp256k1PubKey):
+    """A key type with no batch form, as every type was before PR 49."""
+
+    verify_many = None
+
+    def verify_signature(self, msg, sig):
+        return self._verify_openssl(msg, sig)
+
+
+def test_a_key_class_without_a_batch_form_is_verified_lane_by_lane(mixed):
+    privs, vset, block_id = mixed
+    commit = make_commit(block_id, 18, 0, vset, privs)
+    _tamper(commit, [lanes_of(vset, "secp256k1")[1]])
+    lanes = [(_LoopKey(pk.bytes()), msg, sig) for pk, msg, sig in commit_lanes(vset, commit) if pk.type == "secp256k1"]
+    calls = []
+    bv = crypto_batch.HostLanesVerifier("secp256k1")
+    bv.add_many(*zip(*lanes))
+    got, events = traced(lambda: calls.append(bv.verify()))
+    assert calls == [(False, [True, False, True])]
+    (host,) = [e["args"] for e in events if e["name"] == "host_lanes"]
+    assert "impl" not in host and host["lanes"] == N_SECP
+    # and two classes under one type name are nobody's batch: lane by lane too
+    both = crypto_batch.HostLanesVerifier("secp256k1")
+    both.add_many(*zip(*(lanes[:1] + [lane for lane in commit_lanes(vset, commit) if lane[0].type == "secp256k1"][1:])))
+    assert both.verify() == (False, [True, False, True])
