@@ -39,18 +39,20 @@ jax, but touches no device).
   than one chip is present the edge vectors also go through the sharded
   path (``parallel/sharding.verify_batch_sharded``), which under
   ``pallas`` runs the Pallas kernel per shard from a stored lowered
-  program (``ops/kernel_store.py``): each sharded kernel's first call is
-  printed with its ``stored`` (``miss``: the kernel body was walked;
-  ``hit``: not) and seconds, cold in the first run and warm in the
-  second; there the sr25519 buckets from the mesh floor (256 lanes) up
-  and a 4,950-lane batch (``BASELINE.json`` config 5's sr25519 half: a
+  program (``ops/kernel_store.py``), as one device does since PR 50:
+  every Pallas first call carries its ``stored`` (``miss``: the kernel
+  body was walked; ``hit``: not), counted in each run's report line, and
+  each sharded one is printed with its seconds, cold in the first run
+  and warm in the second; there the sr25519 buckets from the mesh floor
+  (256 lanes) up and a 4,950-lane batch (``BASELINE.json`` config 5's sr25519 half: a
   4,096-lane slab a device on four) must go out sharded, on the sr25519
   shard program, and the mixed committee is 600 validators, so that both
   of its device sub-batches pass the floor and one ``verify_commit``
   sends two sharded chunks with the host's lanes between
   (``mesh_dispatch`` by kind; PR 48). The second run proves the compile
   cache and the kernel store:
-  it may add no entry, and every sharded first call must be a ``hit``.
+  it may add no entry, and every Pallas first call, on one device and
+  sharded, must be a ``hit``.
 - **served** (driven from the parent): ``python -m tendermint_tpu
   verifyd`` started through the CLI, warmed one request at a time, then
   four concurrent ``verifyd.client`` clients built with
@@ -353,9 +355,10 @@ def _check_dispatch(spans: list, sent: dict, what: str) -> None:
 
 def _compiles(spans: list, impl: str) -> list:
     """(implementation, kernel, lanes, seconds) for each kernel the
-    window compiled (or loaded from the cache), and for a mesh's Pallas
-    kernel (parallel/sharding.py) two more: the devices, and what the
-    kernel store did (``hit`` | ``miss``). The legacy, table and
+    window compiled (or loaded from the cache), and for a Pallas kernel,
+    whose program comes from the kernel store on one device and per
+    shard of a mesh alike, two more: the devices, and what the store
+    did (``hit`` | ``miss``). The legacy, table and
     resident kernels must be the implementation ``auto`` resolved to, on
     one device and per shard of a mesh, and so must sr25519's. A mesh's XLA-graph kernels
     record no ``kernel_compile`` span (they show under ``sharded``)."""
@@ -372,8 +375,8 @@ def _compiles(spans: list, impl: str) -> list:
                 impl, a.get("kernel"), a.get("lanes"), ran,
             )
         row = [ran, a.get("kernel"), a.get("lanes"), round(e["dur"] / 1e6, 2)]
-        if "devices" in a:
-            row += [a["devices"], a.get("stored")]
+        if "stored" in a:
+            row += [a.get("devices", 1), a["stored"]]
         out.append(row)
     return out
 
@@ -381,22 +384,35 @@ def _compiles(spans: list, impl: str) -> list:
 def _first_calls(report: dict) -> list:
     """Every row of :func:`_compiles` in a library run's report."""
     parts = [report["edge"], report.get("sharded_edge", {})] + report["sizes"]
+    parts += [report.get("early_begin", {}), report.get("pipelined", {})]
     parts += report.get("sr25519", []) + [report.get("mixed_committee") or {}]
     return [c for part in parts for c in part.get("compiles", ())]
 
 
-def _sharded_first_calls(report: dict) -> list:
-    """The rows among them that are a mesh's kernels."""
+def _stored_first_calls(report: dict) -> list:
+    """The rows among them whose program came through the kernel store."""
     return [c for c in _first_calls(report) if len(c) > 4]
+
+
+def _sharded_first_calls(report: dict) -> list:
+    """The rows among those that are a mesh's kernels."""
+    return [c for c in _stored_first_calls(report) if c[4] > 1]
+
+
+def _stored_counts(report: dict) -> tuple:
+    """(hits, misses) over a library run's first calls."""
+    stored = [c[5] for c in _stored_first_calls(report)]
+    return stored.count("hit"), stored.count("miss")
 
 
 def _check_store_warm(run: int, report: dict) -> None:
     """A process that finds the kernel store warm walks no kernel body:
-    every sharded first call of a second run is a ``hit``."""
-    cold = [c for c in _sharded_first_calls(report) if c[5] != "hit"]
+    every first call of a second run, on one device and sharded, is a
+    ``hit``."""
+    cold = [c for c in _stored_first_calls(report) if c[5] != "hit"]
     check(
         not cold,
-        "library run %d traced a sharded kernel the store should have held: %r",
+        "library run %d traced a kernel the store should have held: %r",
         run, cold,
     )
 
@@ -1416,9 +1432,9 @@ def run_smoke() -> dict:
         entries2 = _cache_entries()
         for run, rep, entries in ((1, run1, entries1), (2, run2, entries2)):
             say(
-                "library run %d: %.1fs wall, %.1fs in first calls of kernels, "
-                "cache %d entries"
-                % (run, rep["wall_s"], rep["compile_s"], entries)
+                "library run %d: %.1fs wall, %.1fs in first calls of kernels "
+                "(stored programs: %d hit, %d miss), cache %d entries"
+                % (run, rep["wall_s"], rep["compile_s"], *_stored_counts(rep), entries)
             )
         check(
             entries1 > 0,

@@ -411,9 +411,9 @@ def traced_first_call(fn: Callable, engine: str, kernel: str, lanes: int, **span
     that traces and compiles — runs under a ``kernel_compile`` span
     (feeding the profiler's compile digests; ``span_args`` are further
     arguments of it) and lands one ``note_compile`` tick. Steady-state
-    calls pay one bool check. Same pattern as
-    pallas_verify._trace_first_call; this is the version of the XLA-
-    graph engines and of the mesh's kernels (parallel/sharding)."""
+    calls pay one bool check. Every kernel factory wraps what it
+    jitted in this: the XLA graphs', the Pallas entry points'
+    (ops/pallas_verify) and the mesh's (parallel/sharding)."""
     state = {"first": True}
 
     @functools.wraps(fn)  # keeps the jitted program's name; ``__wrapped__`` is it

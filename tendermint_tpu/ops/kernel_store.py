@@ -4,17 +4,23 @@ jax's persistent compile cache is keyed by the lowered program, so a
 process must trace and lower a kernel before it can find out that the
 compile is already paid. For the Pallas verifier that walk is the
 dearer half: ~31,000 primitive binds and ~9,900 nested ``jit`` calls,
-4.3 s on one device and 12-30 s under ``shard_map`` (PERF.md, PR 35 and
-PR 36), every process, whatever the compile cache holds.
+6-7 s a kernel shape on one device (4.3 s of Python, 1.7 s of
+lowering) and 12-30 s under ``shard_map`` (PERF.md, PR 35 and PR 36),
+every process, whatever the compile cache holds.
 
 :func:`fetch` keeps the walk's result. A miss traces the function once,
 lowers it with :mod:`jax.export` (Pallas/TPU kernels are on export's
 list of custom calls with a stable ABI) and writes the serialised
 module; a hit reads it back, and what the caller then stages is one
 ``call_exported`` with no kernel body for Python to walk. The bytes are
-deterministic, so the compile cache still hits behind a stored program,
-and a program exported for one device may be called under ``shard_map``
-over any number of them.
+deterministic and hold no path of the process that loads them, so the
+compile cache hits behind a stored program whatever stack reached the
+first call, and a program exported for one device may be called under
+``shard_map`` over any number of them. One device and a mesh are served
+alike (ops/pallas_verify.stored_program, PR 50; the mesh since PR 36): a
+restart pays a load of each kernel shape it meets (tenths of a second),
+an upgrade — other sources, another jax — one walk and one compile a
+shape, once a directory.
 
 The files live in ``<compile cache dir>/kernel_store`` (the directory
 ``jax.config.jax_compilation_cache_dir`` names, see ops/ed25519_batch),
@@ -23,7 +29,8 @@ nothing is stored. A file's name is a hash of everything that decides
 the program: the jax and jaxlib versions, the platform, the name, every
 argument's shape and dtype, and what the caller names in ``key`` (the
 device kind, the block, a digest of the kernel's sources). A program
-made from other sources never answers: it has another name.
+made from other sources never answers: it has another name. A store
+that cannot be read or written costs a trace, never a call.
 """
 
 from __future__ import annotations

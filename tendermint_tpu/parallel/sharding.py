@@ -121,7 +121,8 @@ def _sharded_kernel(
     ``shard_map``: no collective, nothing for GSPMD to partition.
     ``avals`` — the ``(shape, dtype)`` of every argument — is part of
     the key there, because the program is not traced here but fetched,
-    already lowered, from ops/kernel_store.py at one shard's shapes;
+    already lowered, from ops/kernel_store.py at one shard's shapes
+    (``pallas_verify.stored_program``, which a single device jits);
     the fetch and the first call run under a ``kernel_compile`` span
     whose ``stored`` says whether the store had it (``hit``) or the
     kernel body was walked (``miss``). Anything else is the kind's XLA
@@ -143,8 +144,8 @@ def _sharded_kernel(
 
         def first_then(*args):
             if not program:
-                shard, stored = pallas_verify.stored_shard_program(
-                    kind.pallas, kind.kernel_name, shard_avals, mesh.devices.flat[0]
+                shard, stored = pallas_verify.stored_program(
+                    kind.pallas, shard_avals, mesh.devices.flat[0]
                 )
                 tracing.tag(stored=stored)
 

@@ -257,12 +257,59 @@ def test_second_run_must_find_the_kernel_store_warm():
         ["verify_resident", 4096, 3.25, 4, "miss"],
     ]
     chip_smoke._check_store_warm(2, report("hit", "hit"))
-    with pytest.raises(chip_smoke.SmokeFailure, match="run 2 traced a sharded kernel"):
+    with pytest.raises(chip_smoke.SmokeFailure, match="run 2 traced a kernel"):
         chip_smoke._check_store_warm(2, report("hit", "miss"))
     # one device: no sharded part in the report at all
     alone = {"edge": {"compiles": []}, "sizes": []}
     assert chip_smoke._sharded_first_calls(alone) == []
     chip_smoke._check_store_warm(2, alone)
+
+
+def _one_device_compile_span(stored, kernel="verify_resident"):
+    return {
+        "name": "kernel_compile", "dur": 0.25e6,
+        "args": {"engine": "pallas", "kernel": kernel, "lanes": 256, "impl": "pallas",
+                 "stored": stored},
+    }
+
+
+@pytest.mark.parametrize("kernel", ["verify", "verify_tables", "verify_resident", "verify_sr"])
+@pytest.mark.parametrize("stored", ["hit", "miss"])
+def test_compiles_reads_a_one_device_first_call_from_the_store(stored, kernel):
+    """PR 50: one device's Pallas programs come from the kernel store
+    too. The row says one device and what the store did; it is no
+    sharded first call."""
+    rows = chip_smoke._compiles([_one_device_compile_span(stored, kernel)], "pallas")
+    assert rows == [["pallas", kernel, 256, 0.25, 1, stored]]
+    report = {"edge": {"compiles": rows}, "sizes": []}
+    assert chip_smoke._stored_first_calls(report) == rows
+    assert chip_smoke._sharded_first_calls(report) == []
+    assert chip_smoke._stored_counts(report) == ((1, 0) if stored == "hit" else (0, 1))
+
+
+@pytest.mark.parametrize(
+    "part", ["edge", "sizes", "early_begin", "pipelined", "sr25519", "mixed_committee"]
+)
+def test_second_run_must_find_the_one_device_programs_in_the_store(part):
+    """Run 2 of the library phase may walk no kernel body on one device
+    either, in whichever part of the report the first call lies."""
+
+    def report(stored):
+        rows = chip_smoke._compiles(
+            [_one_device_compile_span(stored), _sharded_compile_span("hit")], "pallas"
+        )
+        rep = {"edge": {"compiles": []}, "sizes": [{"compiles": []}], "sr25519": [{"compiles": []}],
+               "early_begin": {"compiles": []}, "pipelined": {"compiles": []},
+               "mixed_committee": {"compiles": []}}
+        holder = rep[part][0] if isinstance(rep[part], list) else rep[part]
+        holder["compiles"] = rows
+        return rep
+
+    chip_smoke._check_store_warm(2, report("hit"))
+    assert chip_smoke._stored_counts(report("hit")) == (2, 0)
+    assert chip_smoke._stored_counts(report("miss")) == (1, 1)
+    with pytest.raises(chip_smoke.SmokeFailure, match="run 2 traced a kernel.*256, 0.25, 1, 'miss'"):
+        chip_smoke._check_store_warm(2, report("miss"))
 
 
 @pytest.mark.parametrize("ran,ok", [("pallas", True), ("xla", False), (None, False)])
@@ -324,7 +371,7 @@ def test_the_store_warm_check_reads_the_sr25519_and_the_mixed_parts_too():
     assert len(chip_smoke._sharded_first_calls(report("miss", "hit"))) == 2
     chip_smoke._check_store_warm(2, report("hit", "hit"))
     for cold in (report("miss", "hit"), report("hit", "miss")):
-        with pytest.raises(chip_smoke.SmokeFailure, match="run 2 traced a sharded kernel"):
+        with pytest.raises(chip_smoke.SmokeFailure, match="run 2 traced a kernel"):
             chip_smoke._check_store_warm(2, cold)
     chip_smoke._check_store_warm(2, {"edge": {"compiles": []}, "sizes": [], "sr25519": [], "mixed_committee": None})
 

@@ -167,6 +167,43 @@ def test_the_kernel_alone_refuses_what_is_no_element():
     assert not pallas[undecodable].any() and not xla[undecodable].any()
 
 
+@compiles_a_bucket
+def test_a_restarted_process_runs_the_real_kernel_from_the_store(monkeypatch):
+    """chip_smoke.py's sr25519 lanes (valid, a flipped R, a flipped s,
+    ``s >= L``, a non-canonical A) at the 64-lane bucket through the real
+    kernel, then again as a process that finds the kernel store warm
+    does: the factory has forgotten its program, the body raises if it
+    is walked, and lane for lane the verdicts are the first's and the
+    host oracle's. The real store, beside the compile cache that holds
+    the executable."""
+    import chip_smoke
+    from tendermint_tpu.ops import kernel_store
+
+    assert kernel_store.directory()
+    pubs, msgs, sigs = chip_smoke._sr25519_lanes(64)
+    want = [verify_host(p, m, s) for p, m, s in zip(pubs, msgs, sigs)]
+    assert True in want and want.count(False) >= 16
+    pk, r, s, host_ok = sr25519_batch._lane_arrays(pubs, sigs)
+    r = np.ascontiguousarray(r)
+    args = tuple(jnp.asarray(a) for a in (pk, r, s, sr25519_challenges_mod_l(pk, r, msgs)))
+
+    def verdicts():
+        out = pallas_verify.compiled_verify_sr(64, interpret=True)(*args)
+        return list(np.asarray(out) & host_ok)
+
+    try:
+        assert verdicts() == want
+        pallas_verify.compiled_verify_sr.cache_clear()
+
+        def boom(*args, **kwargs):
+            raise AssertionError("the kernel body was walked in a process that found the store warm")
+
+        monkeypatch.setattr(pallas_verify, "verify_sr_fn", boom)
+        assert verdicts() == want
+    finally:
+        pallas_verify.compiled_verify_sr.cache_clear()
+
+
 def test_the_sr25519_programs_are_called_run_sr25519():
     """``jit_run_sr25519``: what ``kernel_ms.sr`` matches on a device
     trace, and ``kernel_ms.commit``'s ``jit_run*`` with it."""

@@ -229,13 +229,18 @@ def test_pallas_resident_path_parity(batch8):
 
 
 @pytest.fixture
-def resident_entry(batch8, monkeypatch):
+def resident_entry(batch8, monkeypatch, tmp_path):
     """One first call of the resident entry point with the kernel behind
     it stubbed (no Pallas compile): what reached ``verify_tables_fn``,
-    the events of the call, and the program jit was given."""
+    the events of the call, and the program jit was given. The kernel
+    store is the fixture's own: a file's name does not know a stubbed
+    body, and the real store must never hold one."""
     import jax
 
     from tendermint_tpu.libs import tracing
+    from tendermint_tpu.ops import kernel_store
+
+    monkeypatch.setattr(kernel_store, "directory", lambda: str(tmp_path / "kernel_store"))
 
     def kernel_inputs(tab, a_ok, r, s, k, *, block, interpret):
         n = r.shape[0]
@@ -255,7 +260,6 @@ def resident_entry(batch8, monkeypatch):
     args = _resident_args(inputs)
     # past the lru_cache: a stubbed program must not stay in it
     fn = pallas_verify.compiled_verify_resident.__wrapped__(8)
-    monkeypatch.setattr(jax, "jit", real_jit)
     from tendermint_tpu.ops import introspect
 
     def counted():
@@ -275,7 +279,9 @@ def resident_entry(batch8, monkeypatch):
     finally:
         tracing.configure("off")
         tracing.tracer.clear()
-    (program,) = jitted
+    monkeypatch.setattr(jax, "jit", real_jit)
+    # the store's lowering of the (stubbed) body at the first call, then the program the calls run
+    _, program = jitted
     return {
         "first": first, "second": second, "events": events,
         "gathered": gathered, "program": program, "args": args,
@@ -310,6 +316,58 @@ def test_resident_entry_keeps_the_names_the_benchmark_reads(resident_entry):
     text = resident_entry["program"].lower(*resident_entry["args"]).as_text()
     name = re.search(r"module @(\S+)", text).group(1)
     assert fnmatch.fnmatch(name, "jit__lambda*"), name
+
+
+def pallas_verify_batch_resident(pks, msgs, sigs):
+    """The resident entry on up to eight lanes whose keys are the store's."""
+    n = len(pks)
+    inputs, _, host_ok = _resident_chunk((pks, msgs, sigs), n, 8)
+    fn = pallas_verify.compiled_verify_resident(8, block=8, interpret=True)
+    return list(np.logical_and(np.asarray(fn(*_resident_args(inputs)))[:n], host_ok))
+
+
+@pytest.mark.parametrize(
+    "entry,body,verify",
+    [
+        pytest.param("compiled_verify", "verify_fn", pallas_verify_batch, marks=compiles_the_kernel),
+        # the table kernel's interpret-mode compile, as above
+        pytest.param("compiled_verify_tables", "verify_tables_fn", pallas_verify_batch_tables,
+                     marks=pytest.mark.slow),
+        pytest.param("compiled_verify_resident", "verify_tables_fn", pallas_verify_batch_resident,
+                     marks=pytest.mark.slow),
+    ],
+)
+def test_a_restarted_process_runs_the_real_kernel_from_the_store(monkeypatch, entry, body, verify):
+    """chip_smoke.py's edge vectors — the ZIP-215 cases, ordinary lanes
+    and two forged ones — eight lanes a call through the real kernel,
+    then again as a process that finds the kernel store warm does: the
+    factory has forgotten its program, the body raises if it is walked,
+    and lane for lane the verdicts are the first's and the oracle's.
+    The real store, beside the compile cache that holds the executable
+    (a file's name holds the sources' digest: no stand-in is ever here)."""
+    import chip_smoke
+    from tendermint_tpu.ops import kernel_store
+
+    assert kernel_store.directory()
+    pks, msgs, sigs = (col[:16] for col in chip_smoke.edge_vectors())
+    want = [ref.verify_zip215(pk, m, s) for pk, m, s in zip(pks, msgs, sigs)]
+    assert want.count(False) >= 4 and not want[11] and not want[15]  # the forged lanes
+
+    def verdicts():
+        return [v for at in (0, 8) for v in verify(pks[at:at + 8], msgs[at:at + 8], sigs[at:at + 8])]
+
+    factory = getattr(pallas_verify, entry)
+    try:
+        assert verdicts() == want
+        factory.cache_clear()
+
+        def boom(*args, **kwargs):
+            raise AssertionError("the kernel body was walked in a process that found the store warm")
+
+        monkeypatch.setattr(pallas_verify, body, boom)
+        assert verdicts() == want
+    finally:
+        factory.cache_clear()
 
 
 def _stub_kernel(calls, name):
