@@ -33,7 +33,12 @@ jax, but touches no device).
   through ``ops/sr25519_batch.verify_batch_sr`` at each of its kernel's
   four buckets, and a 150-validator committee of ed25519, sr25519 and
   secp256k1 keys through ``verify_commit``, sound and with one lane of
-  each type tampered. Verdicts are compared with the host oracles
+  each type tampered; then one blocksync window of 16 commits over such
+  a committee, validators absent and voting nil at each height, through
+  ``verify_commits_pipelined`` with one included lane of each type
+  tampered: planned by key type into one sub-batch a device type and one
+  host call, each block's verdict the light rule's over the oracles.
+  Verdicts are compared with the host oracles
   (``crypto/ed25519_ref.py``, ``crypto/sr25519.verify``, the keys' own
   ``verify_signature``). Where more
   than one chip is present the edge vectors also go through the sharded
@@ -78,7 +83,9 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
+import random
 import re
 import shutil
 import signal
@@ -104,6 +111,7 @@ SR_BUCKETS = (64, 256, 1024, 4096)  # every width an sr25519 chunk is padded to
 SR_MESH_LANES = 4_950  # config 5's sr25519 half: on two to four devices a 4,096-lane slab each
 MIXED_VALS = 150  # BASELINE.json config 5's three key types at config 2's size
 MIXED_MESH_VALS = 600  # on a mesh: 280 lanes of each type that batches, over the mesh floor
+MIXED_SYNC = (150, 16)  # a blocksync window over that committee: ~750 lanes a device type, one 1,024-lane chunk each
 SERVED_VALS = 150
 CLIENTS = 4
 REQUESTS_PER_CLIENT = 3
@@ -386,6 +394,7 @@ def _first_calls(report: dict) -> list:
     parts = [report["edge"], report.get("sharded_edge", {})] + report["sizes"]
     parts += [report.get("early_begin", {}), report.get("pipelined", {})]
     parts += report.get("sr25519", []) + [report.get("mixed_committee") or {}]
+    parts += [report.get("mixed_window") or {}]
     return [c for part in parts for c in part.get("compiles", ())]
 
 
@@ -878,6 +887,17 @@ def _run_sr25519(n: int, impl: str, n_mesh: int = 1) -> dict:
     }
 
 
+def _mixed_committee(n: int):
+    """``(helpers, privs in the set's order, ValidatorSet)``: n // 15
+    secp256k1 keys, the rest halves of ed25519 and sr25519."""
+    from bench.workload import load_helpers
+
+    helpers = load_helpers()
+    n_secp = n // 15
+    n_ed = (n - n_secp) // 2
+    return (helpers, *helpers.make_mixed_validators(n_ed, n - n_secp - n_ed, n_secp))
+
+
 def _run_mixed(n: int, impl: str, n_mesh: int = 1) -> dict:
     """A committee of the three key types (n // 15 secp256k1 keys, the
     rest halves) through verify_commit: sound, then with one lane of
@@ -887,20 +907,17 @@ def _run_mixed(n: int, impl: str, n_mesh: int = 1) -> dict:
     flight; nothing else may be. Where the mesh has ``n_mesh`` = two
     devices or more and each device type has the mesh floor's lanes,
     both sub-batches go out sharded."""
-    from bench.workload import load_helpers
     from tendermint_tpu.crypto import batch as crypto_batch
     from tendermint_tpu.ops import ed25519_batch
     from tendermint_tpu.parallel import mesh
     from tendermint_tpu.types import validation
 
-    helpers = load_helpers()
-    n_secp = n // 15
-    n_ed = (n - n_secp) // 2
-    privs, vset = helpers.make_mixed_validators(n_ed, n - n_secp - n_ed, n_secp)
+    helpers, privs, vset = _mixed_committee(n)
     block_id = helpers.make_block_id(b"chip-smoke-mixed-%d-%d" % (SEED, n))
     commit = helpers.make_commit(block_id, 1, 0, vset, privs)
     types = [v.pub_key.type for v in vset.validators]
     sent = {kt: types.count(kt) for kt in set(types)}
+    n_secp = sent["secp256k1"]
     _fresh_node()
     _drain_spans()
     before = _counters()
@@ -983,10 +1000,112 @@ def _run_mixed(n: int, impl: str, n_mesh: int = 1) -> dict:
     }
 
 
+def _run_mixed_window(n: int, window: int, impl: str) -> dict:
+    """One blocksync window of ``window`` commits over a committee of the
+    three key types (``_run_mixed``'s split), 5% of the validators absent
+    and 1% voting nil at each height, through ``verify_commits_pipelined``
+    as the block syncer calls it, with one included lane of each key
+    type tampered, each in a block of its own. Every block's verdict is
+    held to the light rule over the keys' own ``verify_signature``, block
+    by block; and the window must have been planned by key type: each
+    device type's lanes begun once a window, the secp256k1 lanes of every
+    block in one ``host_lanes`` call made while both are in flight, no
+    block given to ``verify_commit_light`` alone."""
+    from tendermint_tpu.parallel.pipeline import CommitTask, verify_commits_pipelined
+    from tendermint_tpu.types import BLOCK_ID_FLAG_COMMIT
+
+    what = "%d-commit window at %d validators of three key types" % (window, n)
+    helpers, privs, vset = _mixed_committee(n)
+    types = [v.pub_key.type for v in vset.validators]
+    quorum = n * 2 // 3 + 1  # equal powers
+    rng = random.Random(SEED)
+    n_absent, n_nil = math.ceil(0.05 * n), math.ceil(0.01 * n)
+    tasks, included = [], []
+    for h in range(1, window + 1):
+        order = rng.sample(range(n), n)
+        block_id = helpers.make_block_id(b"chip-smoke-mixed-window-%d-%d" % (SEED, h))
+        commit = helpers.make_commit(
+            block_id, h, 0, vset, privs,
+            absent=set(order[:n_absent]), nil_votes=set(order[n_absent : n_absent + n_nil]),
+        )
+        tasks.append(CommitTask(helpers.CHAIN_ID, vset, block_id, h, commit))
+        included.append([
+            i for i, cs in enumerate(commit.signatures) if cs.block_id_flag == BLOCK_ID_FLAG_COMMIT
+        ][:quorum])
+    tampered = {}
+    for block, kt in zip(rng.sample(range(window), 3), ("ed25519", "sr25519", "secp256k1")):
+        idx = rng.choice([i for i in included[block] if types[i] == kt])
+        sig = bytearray(tasks[block].commit.signatures[idx].signature)
+        sig[40] ^= 0x01
+        tasks[block].commit.signatures[idx].signature = bytes(sig)
+        tampered[block] = idx
+    sent = {kt: sum(types[i] == kt for lanes in included for i in lanes) for kt in set(types)}
+    _fresh_node()
+    _drain_spans()
+    before = _counters()
+    t0 = time.monotonic()
+    verdicts = verify_commits_pipelined(tasks)
+    wall_s = round(time.monotonic() - t0, 2)
+    spans = _drain_spans()
+    for block, (task, lanes, verdict) in enumerate(zip(tasks, included, verdicts)):
+        bad = next(
+            (i for i in lanes if not vset.validators[i].pub_key.verify_signature(
+                task.commit.vote_sign_bytes(helpers.CHAIN_ID, i), task.commit.signatures[i].signature)),
+            None,
+        )
+        check(bad == tampered.get(block), "%s: the oracles refuse lane %r of block %d", what, bad, block)
+        check(
+            verdict.ok if bad is None else "(#%d)" % bad in str(verdict.error),
+            "%s: block %d got %s, the oracles say %s",
+            what, block, "ok" if verdict.ok else str(verdict.error)[:60], "ok" if bad is None else "#%d" % bad,
+        )
+    names = [e["name"] for e in spans]
+    check(
+        not set(names) & {"verify_commit", "single_verify", "host_fallback"},
+        "%s: a block left the window's plan: %r", what, sorted(set(names)),
+    )
+    begun = [
+        (e["args"]["key_type"], int(e["args"]["lanes"]))
+        for e in sorted(spans, key=lambda e: e["ts"])
+        if e["name"] == "batch_verify" and e["args"].get("phase") == "dispatch"
+    ]
+    check(
+        begun == [(kt, sent[kt]) for kt in ("ed25519", "sr25519")],
+        "%s: device sub-batches begun %r, want one a key type a window: %r", what, begun, sent,
+    )
+    host = [
+        (e["args"]["key_type"], e["args"]["lanes"], e["args"].get("device_lanes_inflight"), e["args"].get("impl"))
+        for e in spans if e["name"] == "host_lanes"
+    ]
+    check(
+        host == [("secp256k1", sent["secp256k1"], sent["ed25519"] + sent["sr25519"], "native")],
+        "%s: host lanes %r, want the window's %d in one native call under both sub-batches",
+        what, host, sent["secp256k1"],
+    )
+    by_engine = {}
+    for e in spans:
+        if e["name"] == "dispatch_chunk":
+            by_engine[e["args"]["engine"]] = by_engine.get(e["args"]["engine"], 0) + int(e["args"]["lanes"])
+    check(
+        by_engine == {kt: sent[kt] for kt in ("ed25519", "sr25519")},
+        "%s: lanes dispatched by engine %r != lanes sent %r", what, by_engine, sent,
+    )
+    (call,) = [e["args"] for e in spans if e["name"] == "verify_commits_pipelined"]
+    check(
+        (call["lanes"], call["sub_batches"], call["host_lanes"]) == (window * quorum, 3, sent["secp256k1"]),
+        "%s: pipeline span %r", what, call,
+    )
+    _check_health(_delta(before), what)
+    return {
+        "validators": n, "window": window, "sent": sent, "tampered": tampered, "wall_s": wall_s,
+        "launches": names.count("dispatch_chunk"), "compiles": _compiles(spans, impl),
+    }
+
+
 def library_phase(
     expect_platform: str, sizes=SIZES, heights: int = HEIGHTS,
     sync=(SYNC_VALS, SYNC_WINDOW), sr_buckets=SR_BUCKETS, mixed: int = MIXED_VALS,
-    early_tail: int = EARLY_TAIL,
+    early_tail: int = EARLY_TAIL, mixed_sync=MIXED_SYNC,
 ) -> dict:
     """The library phase, in this process. Raises SmokeFailure."""
     from tendermint_tpu.ops import backend as ops_backend
@@ -1121,6 +1240,14 @@ def library_phase(
                 "mixed committee: %(validators)d validators %(sent)r, lanes %(tampered)r "
                 "tampered and refused; sharded %(sharded)r; compiled %(compiles)r"
                 % report["mixed_committee"]
+            )
+
+        report["mixed_window"] = _run_mixed_window(*mixed_sync, impl) if mixed_sync else None
+        if mixed_sync:
+            say(
+                "mixed catch-up window: %(window)d commits at %(validators)d validators, lanes %(sent)r "
+                "in %(launches)d launches and one host call, %(wall_s)ss; blocks and lanes %(tampered)r "
+                "tampered and refused; compiled %(compiles)r" % report["mixed_window"]
             )
 
         snap = health.snapshot()

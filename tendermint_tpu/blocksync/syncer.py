@@ -5,7 +5,11 @@ VerifyCommitLight(first <- second.LastCommit), validate, save, apply
 (internal/blocksync/reactor.go:538-650). Here the loop peeks a WINDOW of
 consecutive blocks and verifies all their commits in one device batch
 (parallel/pipeline.py) before applying them in order — the multi-commit
-pipeline from SURVEY.md §7 step 8. A bad verdict falls back to
+pipeline from SURVEY.md §7 step 8. Over a validator set of mixed key
+types (ed25519, sr25519, secp256k1) the window is still one call: the
+pipeline plans its lanes by key type across the blocks, one device
+sub-batch a type that batches and the secp256k1 lanes of every block in
+one host call while those run. A bad verdict falls back to
 per-block attribution, bans the peer, and rescheduling.
 """
 

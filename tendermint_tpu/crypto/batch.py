@@ -527,14 +527,15 @@ def supports_batch_verifier(pub_key: Optional[PubKey]) -> bool:
     )
 
 
-def create_batch_verifier(pub_key: PubKey) -> BatchVerifier:
-    """crypto/batch/batch.go:11-22: dispatch on key type."""
+def create_batch_verifier(pub_key: PubKey, use_device: Optional[bool] = None) -> BatchVerifier:
+    """crypto/batch/batch.go:11-22: dispatch on key type. ``use_device``
+    is the verifier's own (None: by its threshold)."""
     if pub_key.type == ED25519_KEY_TYPE:
-        return Ed25519BatchVerifier()
+        return Ed25519BatchVerifier(use_device=use_device)
     if pub_key.type == SR25519_KEY_TYPE:
         from tendermint_tpu.crypto.sr25519 import Sr25519BatchVerifier
 
-        return Sr25519BatchVerifier()
+        return Sr25519BatchVerifier(use_device=use_device)
     raise ValueError(f"key type {pub_key.type} does not support batching")
 
 
@@ -616,9 +617,17 @@ class MultiBatchVerifier(BatchVerifier):
     helper thread's hand-offs would cost the caller. Every sub-batch is
     verified whatever another found: the caller names the first bad
     lane across types (reference crypto/batch/batch.go:11-22 dispatches
-    on ONE key type; this is the mixed-set generalisation)."""
+    on ONE key type; this is the mixed-set generalisation).
 
-    def __init__(self):
+    The lanes need not be one commit's: ``parallel/pipeline`` hands it a
+    blocksync window's — sixteen blocks' included lanes in one
+    ``add_many``, grouped by key type in one pass — and slices the
+    merged verdicts per block itself. ``use_device`` is handed to each
+    device sub-verifier (None: by its threshold; False: every type on
+    its host oracle)."""
+
+    def __init__(self, use_device: Optional[bool] = None):
+        self.use_device = use_device
         self._subs: dict = {}
         self._order: List[str] = []  # each lane's key type: a sub-verifier keeps its lanes in the order added
         self.ready = False  # a sub-verifier is
@@ -630,7 +639,7 @@ class MultiBatchVerifier(BatchVerifier):
         sub = self._subs.get(kt)
         if sub is None:
             if supports_batch_verifier(pub_key):
-                sub = create_batch_verifier(pub_key)
+                sub = create_batch_verifier(pub_key, self.use_device)
             else:
                 sub = HostLanesVerifier(kt)
             self._subs[kt] = sub
@@ -701,6 +710,17 @@ class MultiBatchVerifier(BatchVerifier):
 
     def __len__(self) -> int:
         return len(self._order)
+
+    def lanes_by_type(self) -> dict:
+        """Key type -> ``(lanes held, route)``, one entry a sub-batch:
+        ``device`` for a type that batches (which a batch under the
+        threshold, a remote's or ``use_device=False`` still sends
+        elsewhere: its ``batch_verify`` span says where), ``host`` for
+        one the host verifies by design."""
+        return {
+            kt: (len(sub), "host" if isinstance(sub, HostLanesVerifier) else "device")
+            for kt, sub in self._subs.items()
+        }
 
     def begin_ready(self) -> int:
         """Each sub-verifier's, in the order of its type's name."""

@@ -68,7 +68,7 @@ def test_main_exits_nonzero_alone_in_a_directory(tmp_path):
 
 def test_phase_fails_on_the_wrong_platform():
     with pytest.raises(chip_smoke.SmokeFailure, match="want 'tpu'"):
-        chip_smoke.library_phase("tpu", sizes=(), sync=None, sr_buckets=(), mixed=0, early_tail=0)
+        chip_smoke.library_phase("tpu", sizes=(), sync=None, sr_buckets=(), mixed=0, early_tail=0, mixed_sync=None)
 
 
 # --- the library phase's checks, live, on the CPU -----------------------------
@@ -79,7 +79,7 @@ def test_edge_vectors_phase_passes_on_cpu():
     ops.verify_batch, lane for lane against the oracle, lanes
     dispatched == lanes sent, health counters flat — on the 64-lane
     legacy kernel other suites compile anyway."""
-    report = chip_smoke.library_phase("cpu", sizes=(), sync=None, sr_buckets=(), mixed=0, early_tail=0)
+    report = chip_smoke.library_phase("cpu", sizes=(), sync=None, sr_buckets=(), mixed=0, early_tail=0, mixed_sync=None)
     assert report["device"]["platform"] == "cpu"
     assert report["impl"] == "xla" and report["host_hash"] == report["host_secp256k1"] == "native"
     edge = report["edge"]
@@ -105,7 +105,7 @@ def test_the_early_begin_case_passes_on_cpu(monkeypatch):
     # as after the sizes, which this call leaves out: a full job has run
     monkeypatch.setattr(ed25519_batch, "_ENGINES_WITH_A_JOB_RUN", {"ed25519"})
     report = chip_smoke.library_phase(
-        "cpu", sizes=(), sync=None, sr_buckets=(), mixed=0, early_tail=20
+        "cpu", sizes=(), sync=None, sr_buckets=(), mixed=0, early_tail=20, mixed_sync=None
     )
     assert report["early_begin"]["refused"] == [31, 32, 51]
     assert (report["early_begin"]["job"], report["early_begin"]["tail"]) == (32, 20)
@@ -123,7 +123,7 @@ def test_the_early_begin_case_fails_where_nothing_is_begun_early(monkeypatch):
     monkeypatch.setattr(crypto_batch.DeviceBatchVerifier, "_look", lambda self: None)
     with pytest.raises(chip_smoke.SmokeFailure, match="blocks begun"):
         chip_smoke.library_phase(
-            "cpu", sizes=(), sync=None, sr_buckets=(), mixed=0, early_tail=20
+            "cpu", sizes=(), sync=None, sr_buckets=(), mixed=0, early_tail=20, mixed_sync=None
         )
 
 
@@ -132,7 +132,7 @@ def test_sr25519_and_the_mixed_committee_pass_on_cpu():
     of sr25519 lanes against the schnorrkel oracle, and a committee of
     the three key types through verify_commit, sound and tampered."""
     report = chip_smoke.library_phase(
-        "cpu", sizes=(), sync=None, sr_buckets=(64,), mixed=45, early_tail=0
+        "cpu", sizes=(), sync=None, sr_buckets=(64,), mixed=45, early_tail=0, mixed_sync=None
     )
     (sr,) = report["sr25519"]
     assert sr["lanes"] == 64 and 0 < sr["accepted"] < 64
@@ -140,6 +140,38 @@ def test_sr25519_and_the_mixed_committee_pass_on_cpu():
     assert mixed["sent"] == {"ed25519": 21, "sr25519": 21, "secp256k1": 3}
     assert len(mixed["tampered"]) == 3
     json.dumps(report)
+
+
+def test_the_mixed_catch_up_window_passes_on_cpu():
+    """The phase's last step at its smallest: a window of 4 commits over
+    45 validators of three key types, absent and nil votes, one included
+    lane of each type tampered, through ``verify_commits_pipelined``:
+    two launches and one host call a window, every block's verdict the
+    oracles'."""
+    report = chip_smoke.library_phase(
+        "cpu", sizes=(), sync=None, sr_buckets=(), mixed=0, early_tail=0, mixed_sync=(45, 4)
+    )
+    window = report["mixed_window"]
+    assert window["sent"]["secp256k1"] > 0 and sum(window["sent"].values()) == 4 * 31
+    assert len(window["tampered"]) == 3 and window["launches"] == 2
+    assert report["mixed_committee"] is None
+    json.dumps(report)
+
+
+def test_the_mixed_catch_up_window_fails_where_each_block_is_verified_alone(monkeypatch):
+    """The road the pipeline took until PR 51 — a block holding a key
+    that is not ed25519 given to ``verify_commit_light`` alone — gives
+    every verdict right and is what the step exists to catch."""
+    from tendermint_tpu.parallel import pipeline
+
+    def block_by_block(tasks, mesh=None, use_device=None):
+        return [pipeline._verify_light_single(task) for task in tasks]
+
+    monkeypatch.setattr(pipeline, "verify_commits_pipelined", block_by_block)
+    with pytest.raises(chip_smoke.SmokeFailure, match="a block left the window's plan"):
+        chip_smoke.library_phase(
+            "cpu", sizes=(), sync=None, sr_buckets=(), mixed=0, early_tail=0, mixed_sync=(45, 4)
+        )
 
 
 def test_kernel_failure_fails_the_phase_instead_of_passing_on_the_oracle(
@@ -154,7 +186,7 @@ def test_kernel_failure_fails_the_phase_instead_of_passing_on_the_oracle(
     monkeypatch.setattr(ed25519_batch, "_compiled_kernel", boom)
     with pytest.warns(UserWarning, match="CPU fallback"):
         with pytest.raises(chip_smoke.SmokeFailure, match="host oracle"):
-            chip_smoke.library_phase("cpu", sizes=(), sync=None, sr_buckets=(), mixed=0, early_tail=0)
+            chip_smoke.library_phase("cpu", sizes=(), sync=None, sr_buckets=(), mixed=0, early_tail=0, mixed_sync=None)
 
 
 def test_failing_implementation_is_counted_not_switched(monkeypatch):
@@ -288,7 +320,7 @@ def test_compiles_reads_a_one_device_first_call_from_the_store(stored, kernel):
 
 
 @pytest.mark.parametrize(
-    "part", ["edge", "sizes", "early_begin", "pipelined", "sr25519", "mixed_committee"]
+    "part", ["edge", "sizes", "early_begin", "pipelined", "sr25519", "mixed_committee", "mixed_window"]
 )
 def test_second_run_must_find_the_one_device_programs_in_the_store(part):
     """Run 2 of the library phase may walk no kernel body on one device
@@ -300,7 +332,7 @@ def test_second_run_must_find_the_one_device_programs_in_the_store(part):
         )
         rep = {"edge": {"compiles": []}, "sizes": [{"compiles": []}], "sr25519": [{"compiles": []}],
                "early_begin": {"compiles": []}, "pipelined": {"compiles": []},
-               "mixed_committee": {"compiles": []}}
+               "mixed_committee": {"compiles": []}, "mixed_window": {"compiles": []}}
         holder = rep[part][0] if isinstance(rep[part], list) else rep[part]
         holder["compiles"] = rows
         return rep
@@ -428,7 +460,7 @@ def test_library_phase_at_40_validators(_auto_paths_on, monkeypatch):
     # own (chip_smoke._fresh_node), so each gets its tables at once
     monkeypatch.setattr(ed25519_batch, "job_lanes", lambda: 32)  # the chip's: 4,096
     report = chip_smoke.library_phase(
-        "cpu", sizes=(24, 40), heights=2, sync=(20, 4), early_tail=20
+        "cpu", sizes=(24, 40), heights=2, sync=(20, 4), early_tail=20, mixed_sync=None
     )
     assert report["early_begin"]["refused"] == [31, 32, 51]
     small, size = report["sizes"]

@@ -76,9 +76,9 @@ def test_benchmark_files_agree():
     # at most half of the cells, rounded down, may ask for four chips
     four = [w["name"] for w in real.doc["workloads"] if w["chips"] == 4]
     assert four == ["big10k-x4", "mixed10k-x4"] and len(four) <= len(real.doc["workloads"]) // 2
-    # the traffic file is mixed10k's, and both new things stand last in their lists
+    # the traffic file is mixed10k's, and both stand where PR 48 appended them: later ones after them
     assert real.cell("mixed10k")["traffic"] == cell["traffic"]
-    assert real.doc["workloads"][-1] is cell and real.doc["configs"][-1] is entry
+    assert real.doc["workloads"][8] is cell and real.doc["configs"][7] is entry
 
 
 def test_the_cell_reports_what_it_brought_and_what_every_commit_cell_on_a_mesh_reports():

@@ -1,5 +1,5 @@
 """Every rehearsal of the benchmark that tier-1 makes: ``chipbench.run
---rehearse`` on a cell's tiny twin, in a child, fifteen times, one
+--rehearse`` on a cell's tiny twin, in a child, eighteen times, one
 after another.
 
 Every run of ``chipbench.run`` empties the checkout's one
@@ -28,6 +28,7 @@ from tests import test_chipbench_light as light
 from tests import test_chipbench_mixed as mixed
 from tests import test_chipbench_mixed_x4 as mixed_x4
 from tests import test_chipbench_rotation as rotation
+from tests import test_chipbench_syncmixed as syncmixed
 from tests.helpers import over_limit, rehearse_cell, sound
 
 # --- the call path's twin (tests/test_chipbench_callpath.py) -----------------------------
@@ -261,4 +262,41 @@ def test_tiny_twin_of_sync500_rotation_rehearses_on_the_cpu():
 def test_tiny_twin_of_sync500_rotation_broken_on_purpose_comes_out_not_correct(brk, over):
     """The controls (``breaks.py``) have to show in the cell's own comparisons, not in the harness's two."""
     out, said = rehearse_cell(rotation.BENCH, rotation.CELL, rotation.SEED, 0, "--break", brk)
+    assert out["correct"] is False and over_limit(said) == over
+
+
+# --- ``syncmixed500-catchup``'s twin: a window over three key types, planned by key type -----------
+
+SYNCMIXED_COMPARED = ("verdict_cache_hits_in_window", "compilations_in_window", "timed_blocks_refused",
+                      "fault_window_blocks_with_a_wrong_verdict", "lanes_where_reference_disagrees")
+
+
+def test_tiny_twin_of_syncmixed500_catchup_rehearses_on_the_cpu():
+    """42 validators of three key types, windows of 4: each call two
+    launches and one host call made while both sub-batches are in flight
+    — a block alone would stay under the device's threshold, so the
+    parent's road comes out ``failed`` here — and the check's fresh
+    window with its five faults as the plain reference says."""
+    bench, cell = syncmixed.BENCH, syncmixed.CELL
+    out, said = rehearse_cell(bench, cell, syncmixed.SEED, 1)
+    value = sound(out, said, SYNCMIXED_COMPARED, bench, cell)  # every name the twin lists printed
+    assert value("device_launches") == 2.0
+    lanes = int(said.split("lanes a call sent to the device")[0].rsplit(" ", 2)[-2])
+    assert value("host_inflight_lanes") == lanes and "(%d useful lanes each)" % lanes in said
+    assert 0 < value("group_lanes_ms") < value("pipeline_host_ms")
+    assert value("kernel_ms") > 0 and value("h2d_bytes") > 0 and value("prep_ms") > 0
+
+
+@pytest.mark.parametrize(
+    "brk,over",
+    [
+        # one lane's verdict inverted where each engine returns its sub-batch
+        ("flip_verdict", ["timed_blocks_refused", "fault_window_blocks_with_a_wrong_verdict", "lanes_where_reference_disagrees"]),
+        # the ed25519 engine's s < L check off: the included ed25519 s + L lane verifies
+        ("no_canonical_s", ["fault_window_blocks_with_a_wrong_verdict", "lanes_where_reference_disagrees"]),
+    ],
+)
+def test_tiny_twin_of_syncmixed500_catchup_broken_on_purpose_comes_out_not_correct(brk, over):
+    """The controls (``breaks.py``) have to show in the cell's own comparisons, not in the harness's two."""
+    out, said = rehearse_cell(syncmixed.BENCH, syncmixed.CELL, syncmixed.SEED, 0, "--break", brk)
     assert out["correct"] is False and over_limit(said) == over

@@ -229,7 +229,11 @@ def test_task_that_fails_the_basic_checks_is_refused_alone(small):
 
 
 @pytest.mark.parametrize("bad", [False, True], ids=["sound", "tampered"])
-def test_block_with_an_sr25519_validator_gets_verify_commit_lights_verdict(bad):
+def test_window_mixing_an_ed25519_only_set_and_a_mixed_set_is_planned_by_key_type(bad, ring):
+    """A window that meets one set holding an sr25519 key goes to
+    MultiBatchVerifier whole, the ed25519-only blocks' lanes too: one
+    sub-batch a key type, no block verified alone, each block's verdict
+    verify_commit_light's."""
     from tendermint_tpu.crypto.sr25519 import Sr25519PrivKey
 
     def factory(i):
@@ -252,6 +256,12 @@ def test_block_with_an_sr25519_validator_gets_verify_commit_lights_verdict(bad):
         want[1] = ("wrong signature", idx)
     got = pipelined(tasks)
     assert got == want
+    outer = spans_named(ring, "verify_commits_pipelined")[0]["args"]
+    assert (outer["sub_batches"], outer["device_lanes_sr25519"], outer["host_lanes"]) == (2, 1, 0)
+    assert outer["device_lanes_ed25519"] == outer["lanes"] - 1 == sum(len(included(t)) for t in tasks) - 1
+    assert sorted(s["args"]["key_type"] for s in spans_named(ring, "batch_verify")) == ["ed25519", "sr25519"]
+    assert not spans_named(ring, "verify_commit")  # verify_commit_light's span: no block went alone
+    ring.clear()
     assert got == [light_alone(t) for t in tasks]
 
 
